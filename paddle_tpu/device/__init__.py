@@ -10,6 +10,7 @@ from .plugin import (  # noqa: F401
     load_custom_runtime_lib, load_custom_device_plugins, registered_plugins)
 from .xla_flags import (  # noqa: F401
     enable_overlap_flags, overlap_flags_active, OVERLAP_XLA_FLAGS)
+from .compile_cache import place_compile_cache  # noqa: F401
 
 __all__ = ["set_device", "get_device", "get_all_devices", "device_count",
            "is_compiled_with_cuda", "is_compiled_with_tpu", "cuda",
@@ -19,7 +20,8 @@ __all__ = ["set_device", "get_device", "get_all_devices", "device_count",
            "get_all_device_type", "get_all_custom_device_type",
            "Stream", "Event", "current_stream", "set_stream",
            "stream_guard", "synchronize",
-           "enable_overlap_flags", "overlap_flags_active"]
+           "enable_overlap_flags", "overlap_flags_active",
+           "place_compile_cache"]
 
 
 def get_available_device():

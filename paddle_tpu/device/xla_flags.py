@@ -7,37 +7,33 @@ only pays off if XLA's scheduler is allowed to run collectives
 asynchronously under compute. On TPU that is the latency-hiding
 scheduler plus the async-collective/collective-fusion passes; they are
 process-level compiler flags, not per-program options, so they must be
-in ``XLA_FLAGS``/``LIBTPU_INIT_ARGS`` before the backend initializes.
+staged before the backend initializes.
 
 ``enable_overlap_flags()`` is called by the hybrid entry points (fleet
 init, the MULTICHIP dryrun, bench) and is safe to call any time: it is
 idempotent, never overrides a flag the operator already set, and warns
 instead of lying when the backend is already up.
 
-The flag set is TPU-generation debug options: XLA builds that do not
-register them (the CPU wheel) ABORT the process at backend init when
-they appear in ``XLA_FLAGS`` (``parse_flags_from_env.cc`` is fatal on
-unknown names). The helper therefore always stages the flags in
-``LIBTPU_INIT_ARGS`` (read by libtpu alone — inert elsewhere) but
-touches ``XLA_FLAGS`` only when the process explicitly targets a TPU
-backend (``JAX_PLATFORMS``/``JAX_PLATFORM_NAME`` name tpu) or the
-operator forces it.
+The flags are libtpu's, so they travel in ``LIBTPU_INIT_ARGS`` — read by
+libtpu alone, inert on a host without one — and NEVER in ``XLA_FLAGS``:
+that variable is parsed by jaxlib's own XLA build, which does not
+register the TPU names and aborts the process at backend init on any it
+does not know (``parse_flags_from_env.cc``; seen on the v5e, PR 21).
 
 Env controls:
  - ``PT_XLA_OVERLAP_FLAGS=0`` — disable entirely (the helper becomes a
    no-op returning []).
- - ``PT_XLA_OVERLAP_FLAGS=force`` — apply even without a detectable TPU
-   runtime (operator asserts their XLA build knows the flags).
- - ``PT_XLA_OVERLAP_EXTRA`` — extra space-separated flags appended after
-   the defaults (operator escape hatch for per-generation tuning).
+ - ``PT_XLA_OVERLAP_EXTRA`` — extra space-separated libtpu flags
+   appended after the defaults (operator escape hatch for
+   per-generation tuning).
 """
 from __future__ import annotations
 
 import os
 import warnings
 
-__all__ = ["OVERLAP_XLA_FLAGS", "OVERLAP_LIBTPU_FLAGS",
-           "enable_overlap_flags", "overlap_flags_active"]
+__all__ = ["OVERLAP_XLA_FLAGS", "enable_overlap_flags",
+           "overlap_flags_active"]
 
 # Scheduler + async-collective set. The latency-hiding scheduler
 # reorders independent collectives under compute; the async flags make
@@ -52,11 +48,6 @@ OVERLAP_XLA_FLAGS = (
     "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
     "--xla_tpu_overlap_compute_collective_tc=true",
 )
-# libtpu reads the same debug options through LIBTPU_INIT_ARGS on real
-# TPU runtimes; keep both surfaces in sync.
-OVERLAP_LIBTPU_FLAGS = OVERLAP_XLA_FLAGS
-
-_applied = False
 
 
 def _flag_name(flag):
@@ -73,79 +64,43 @@ def _merge(env_value, flags):
 
 
 def _backend_initialized():
-    import jax
-    try:
-        # the public probe: backends() materializes the client, so ask
-        # the lower-level registry instead
-        from jax._src import xla_bridge
-        return xla_bridge.backends_are_initialized()
-    except Exception:
-        try:
-            import jax._src.xla_bridge as xb
-            return bool(getattr(xb, "_backends", None))
-        except Exception:
-            return False
-
-
-def _tpu_runtime_plausible():
-    """True when this process explicitly targets a TPU backend (the jax
-    platform envs name one). libtpu merely being importable is NOT
-    enough: on a TPU-less host jax falls back to the in-process CPU
-    client, whose flag table is the one that parses ``XLA_FLAGS``.
-    Must not touch jax (runs before backend init)."""
-    plat = (os.environ.get("JAX_PLATFORMS", "")
-            + " " + os.environ.get("JAX_PLATFORM_NAME", "")).lower()
-    return "tpu" in plat
+    # the public probe (jax.devices()) materializes the client, so ask
+    # the lower-level registry instead
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
 
 
 def overlap_flags_active():
-    """True when every overlap flag name is present in ``XLA_FLAGS`` or
-    ``LIBTPU_INIT_ARGS`` (on real TPU runtimes the libtpu surface is
-    the effective carrier)."""
+    """True when every overlap flag name is staged in
+    ``LIBTPU_INIT_ARGS``."""
     present = {_flag_name(f)
-               for env in ("XLA_FLAGS", "LIBTPU_INIT_ARGS")
-               for f in os.environ.get(env, "").split() if f}
+               for f in os.environ.get("LIBTPU_INIT_ARGS", "").split() if f}
     return all(_flag_name(f) in present for f in OVERLAP_XLA_FLAGS)
 
 
 def enable_overlap_flags(extra=(), warn_if_late=True):
-    """Install the overlap flag set into the process env (idempotent).
+    """Stage the overlap flag set in ``LIBTPU_INIT_ARGS`` (idempotent).
 
-    Returns the list of flags newly added to ``XLA_FLAGS`` (empty when
-    disabled via ``PT_XLA_OVERLAP_FLAGS=0``, no TPU runtime is present
-    and the set wasn't forced, already applied, or every name was
-    operator-set). Flags the operator already pinned — in ``XLA_FLAGS``
-    or via ``PT_XLA_OVERLAP_EXTRA`` — are never overridden, only absent
-    names are appended.
+    Returns the list of flags newly added (empty when disabled via
+    ``PT_XLA_OVERLAP_FLAGS=0`` or every name was already there). Flags
+    the operator already pinned — in ``LIBTPU_INIT_ARGS`` or via
+    ``PT_XLA_OVERLAP_EXTRA`` — are never overridden, only absent names
+    are appended.
     """
-    global _applied
-    mode = os.environ.get("PT_XLA_OVERLAP_FLAGS", "auto")
-    if mode in ("0", "false", "off"):
+    if os.environ.get("PT_XLA_OVERLAP_FLAGS", "auto") in ("0", "false",
+                                                          "off"):
         return []
     extra_env = tuple(os.environ.get("PT_XLA_OVERLAP_EXTRA", "").split())
     flags = tuple(OVERLAP_XLA_FLAGS) + tuple(extra) + extra_env
-    # LIBTPU_INIT_ARGS is read by libtpu alone — safe to stage on any
-    # host, and the effective flag carrier on real TPU runtimes
-    lib_merged, lib_added = _merge(
-        os.environ.get("LIBTPU_INIT_ARGS", ""), flags)
-    if lib_added:
-        os.environ["LIBTPU_INIT_ARGS"] = lib_merged
-    if mode not in ("1", "force", "always") and not _tpu_runtime_plausible():
-        # a CPU/GPU XLA build hard-aborts at backend init on the
-        # unknown TPU flag names — stay out of XLA_FLAGS unless the
-        # process explicitly targets tpu (or the operator forces it)
-        return []
-    merged, added = _merge(os.environ.get("XLA_FLAGS", ""), flags)
+    merged, added = _merge(os.environ.get("LIBTPU_INIT_ARGS", ""), flags)
     if not added:
-        _applied = True
         return []
     if _backend_initialized() and warn_if_late:
         warnings.warn(
             "enable_overlap_flags() called after the XLA backend "
             "initialized; the latency-hiding-scheduler flags will only "
-            "take effect in processes that set them before first device "
-            "use (export XLA_FLAGS or call this at import time)",
+            "take effect in processes that stage them before first device "
+            "use (export LIBTPU_INIT_ARGS or call this at import time)",
             RuntimeWarning, stacklevel=2)
-    os.environ["XLA_FLAGS"] = merged
-    _applied = True
+    os.environ["LIBTPU_INIT_ARGS"] = merged
     return added

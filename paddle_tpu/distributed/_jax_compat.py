@@ -1,107 +1,22 @@
-"""Shims over jax API moves/renames so one tree runs on old and new jax.
-
-The distributed stack is written against the current jax surface
-(``jax.shard_map``, ``jax.set_mesh``); older installs (< 0.5) expose the
-same machinery as ``jax.experimental.shard_map.shard_map`` (with
-``check_rep``/``auto`` instead of ``check_vma``/``axis_names``) and use
-the ``Mesh`` object itself as the ambient-mesh context manager.  These
-helpers pick whichever exists — a robustness requirement, not a
-convenience: the fault-tolerance drills must run on the jax the
-container actually has.
-"""
+"""The jax spellings the distributed stack is written against, in one
+place: ``jax.shard_map``, ``jax.set_mesh``, ``lax.axis_size`` (the
+installed jax 0.9.0 surface; no older version is supported)."""
 from __future__ import annotations
 
-import contextvars
-import functools
-
 import jax
+from jax import lax
 
-__all__ = ["shard_map", "use_mesh", "axis_size", "declared_manual_axes"]
+__all__ = ["shard_map", "use_mesh", "axis_size"]
 
-# Manual-axes declaration for the old-jax shard_map path. New jax honors
-# ``axis_names`` (undeclared mesh axes stay automatic, so
-# ``lax.axis_index`` on them fails and axis-scope probes answer "no").
-# Old jax runs fully manual over EVERY mesh axis, which makes physical
-# axis-env probes lie: an axis the caller left automatic still resolves,
-# flipping dual-mode layers (mp_layers) into their manual path while
-# their operands arrived replicated. We record the caller's declared set
-# here so ``collective._in_axis_scope`` can answer like new jax does.
-# ``None`` = no declaration active (plain traces, or shard_maps that
-# passed no axis_names and really do own every axis, e.g. the eager
-# collective submesh evaluator).
-_MANUAL_AXES: contextvars.ContextVar = contextvars.ContextVar(
-    "pt_manual_axes", default=None)
-
-
-def declared_manual_axes():
-    """The axis_names set of the innermost compat shard_map, or None."""
-    return _MANUAL_AXES.get()
-
-
-def in_compat_manual_region():
-    """True while tracing the body of an old-jax compat ``shard_map``.
-
-    There EVERY mesh axis is physically manual, so named sharding
-    constraints on mesh axes fail at lowering ("axis also found in
-    manual_axes") — hint emitters must skip rather than rely on
-    trace-time exception guards. Never True on new jax (the wrapper is
-    only installed on the experimental path)."""
-    return _MANUAL_AXES.get() is not None
-
-
-def _with_declared_axes(fn, axes):
-    @functools.wraps(fn)
-    def wrapped(*args, **kwargs):
-        token = _MANUAL_AXES.set(frozenset(axes))
-        try:
-            return fn(*args, **kwargs)
-        finally:
-            _MANUAL_AXES.reset(token)
-    return wrapped
+use_mesh = jax.set_mesh
+axis_size = lax.axis_size
 
 
 def shard_map(fn, mesh, in_specs, out_specs, axis_names=None,
               check_vma=False):
-    """``jax.shard_map`` when present, else the experimental spelling.
-
-    ``axis_names`` (new API: the axes manual inside the body) maps to the
-    old API's complement ``auto`` (the axes left automatic); ``check_vma``
-    maps to ``check_rep``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return sm(fn, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    # Old jax has no ``axis_names``; its ``auto`` complement triggers an
-    # unsupported PartitionId lowering under SPMD partitioning (notably on
-    # CPU), so run fully manual instead: axes the caller left automatic are
-    # simply unmentioned in the specs, i.e. replicated — correct, if less
-    # parallel, which is the right trade for a compatibility path.  The
-    # declaration context keeps axis-scope probes honest inside the body:
-    # without it, replicated-in operands would hit manual-mode layer paths
-    # (wrong math), the exact failure the dual-mode TP layers guard on.
-    if axis_names is not None:
-        fn = _with_declared_axes(fn, axis_names)
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` where it exists; older jax derives it from the
-    ambient axis environment (same mechanism, pre-rename spelling)."""
-    from jax import lax
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def use_mesh(mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` where it exists; on older
-    jax a ``Mesh`` is itself the context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """``jax.shard_map`` with this tree's defaults: ``check_vma`` off,
+    ``axis_names`` (the axes manual inside the body) accepted as any
+    iterable, ``None`` meaning every mesh axis."""
+    kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)

@@ -198,18 +198,9 @@ def _group_of(group) -> Group:
 
 
 def _in_axis_scope(name: str) -> bool:
-    """True when called under a trace with mesh axis `name` in scope.
-
-    Under the old-jax compat ``shard_map`` (fully manual over every mesh
-    axis) the physical axis env would say yes for ALL axes; honor the
-    caller's ``axis_names`` declaration instead so an axis left automatic
-    (operands replicated, not per-rank blocks) answers "no" exactly like
-    new jax — mp_layers' dual-mode dispatch depends on this.
-    """
-    from ._jax_compat import declared_manual_axes
-    declared = declared_manual_axes()
-    if declared is not None and name not in declared:
-        return False
+    """True when called under a trace with mesh axis `name` in scope
+    (manual inside a ``shard_map`` body; an axis left automatic answers
+    "no" — mp_layers' dual-mode dispatch depends on this)."""
     try:
         lax.axis_index(name)
         return True
@@ -257,12 +248,8 @@ def _timed(op):
             tr = get_tracer()
             if not (tel.enabled or tr.enabled):
                 return fn(*args, **kwargs)
-            try:
-                tracing = not jax.core.trace_state_clean()
-            except Exception:
-                tracing = True  # unknown trace state: don't time
-            if tracing:
-                return fn(*args, **kwargs)
+            if not jax.core.trace_ctx.is_top_level():
+                return fn(*args, **kwargs)  # a trace, not a step: don't time
             t0 = time.perf_counter_ns()
             try:
                 return fn(*args, **kwargs)

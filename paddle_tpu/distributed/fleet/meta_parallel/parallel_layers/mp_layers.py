@@ -51,14 +51,9 @@ def _mp_degree(mp_group):
 
 
 def _constraint(arr, spec):
-    """Sharding hint under jit when a global mesh exists; no-op eager.
-    Skipped inside an old-jax compat shard_map body: there every mesh
-    axis is manual and a named constraint fails at LOWERING time, past
-    any trace-time exception guard."""
-    from ...._jax_compat import in_compat_manual_region
+    """Sharding hint under jit when a global mesh exists; no-op eager."""
     mesh = _mesh_mod.get_mesh(create_default=False)
-    if mesh is None or not isinstance(arr, jax.core.Tracer) \
-            or in_compat_manual_region():
+    if mesh is None or not isinstance(arr, jax.core.Tracer):
         return arr
     try:
         return lax.with_sharding_constraint(arr, NamedSharding(mesh, spec))

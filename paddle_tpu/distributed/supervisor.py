@@ -711,8 +711,7 @@ def supervision_snapshot():
 
     Reflects the most recent :class:`Supervisor` in this process; a
     process that never supervised anything gets an all-zero block, so
-    consumers (bench.py's record emitter, including its
-    ``tpu_unreachable`` fast-fail path) can embed it unconditionally.
+    consumers (bench.py's record emitter) can embed it unconditionally.
     """
     if _LAST_SUPERVISOR is not None:
         return _LAST_SUPERVISOR.snapshot()

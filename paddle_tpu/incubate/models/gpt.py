@@ -77,9 +77,6 @@ def _seq_constraint(t: Tensor) -> Tensor:
     mesh = _mesh_mod.get_mesh(create_default=False)
     if mesh is None or not isinstance(t._data, jax.core.Tracer):
         return t
-    from ...distributed._jax_compat import in_compat_manual_region
-    if in_compat_manual_region():
-        return t
     from jax.sharding import NamedSharding
     from ...distributed.auto_parallel.spec_layout import default_layout
     try:

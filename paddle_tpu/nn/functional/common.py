@@ -16,6 +16,7 @@ from ...tensor import Tensor
 from ...ops.op_utils import ensure_tensor, nary, unary as _unary, maybe_autocast
 from ...framework import random as _random
 from ...framework import flags as _flags
+from ...framework import device as _device
 
 __all__ = [
     "linear", "dropout", "dropout2d", "dropout3d", "alpha_dropout",
@@ -354,24 +355,22 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     q, k_, v = ensure_tensor(query), ensure_tensor(key), ensure_tensor(value)
     q, k_, v = maybe_autocast("matmul", q, k_, v)
 
-    # canary last: it compiles a kernel, so only probe when the Pallas
-    # path is actually reachable for this call. Short sequences stay on
-    # XLA: its fused attention wins below ~flash_min_seq (the kernel's
-    # padding + grid overhead outweighs the O(S^2) saving).
+    # Selection is by shape, platform and mesh only (see
+    # device.pallas_dispatch) — a kernel the compiler refuses fails the
+    # program with the compiler's message. Short sequences stay on XLA:
+    # its fused attention wins below ~flash_min_seq (the kernel's padding
+    # + grid overhead outweighs the O(S^2) saving).
     use_pallas = (attn_mask is None
                   and q.shape[1] >= int(_flags.flag("flash_min_seq"))
                   and _flags.flag("use_pallas_kernels")
-                  and _on_tpu() and _flash_usable())
+                  and _device.pallas_dispatch())
     eff_drop = dropout_p if training else 0.0
     from ...ops.fused_kernels import record_dispatch as _record
     if use_pallas:
-        try:
-            from ...ops.pallas_ops import flash_attention as _fa
-            out = _fa(q, k_, v, causal=is_causal, dropout_p=eff_drop)
-            _record("flash_mha", "pallas")
-            return out
-        except Exception:
-            pass  # fall back to XLA path
+        from ...ops.pallas_ops import flash_attention as _fa
+        out = _fa(q, k_, v, causal=is_causal, dropout_p=eff_drop)
+        _record("flash_mha", "pallas")
+        return out
     _record("flash_mha", "fallback")
 
     key_rng = _random.next_key() if (dropout_p > 0.0 and training) else None
@@ -405,75 +404,6 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if attn_mask is not None:
         args.append(ensure_tensor(attn_mask))
     return nary(f, args, name="scaled_dot_product_attention")
-
-
-_CANARY_CACHE: dict = {}
-
-
-def _kernel_canary(key, probe):
-    """One-time eager canary compile+run of a kernel configuration.
-
-    A kernel that traces fine can still fail at LOWERING time, which
-    under ``jax.jit`` happens outside any try/except at the call site and
-    would kill the whole compiled train step (exactly how the r03 bench
-    lost its GPT number) — so probe eagerly once and cache the verdict
-    per ``key``. ``probe`` returns arrays to block on."""
-    if key not in _CANARY_CACHE:
-        try:
-            jax.block_until_ready(probe())
-            _CANARY_CACHE[key] = True
-        except Exception:
-            _CANARY_CACHE[key] = False
-    return _CANARY_CACHE[key]
-
-
-def _flash_usable():
-    def probe():
-        from ...ops.pallas_ops import mha
-        x = jnp.zeros((1, 1, 128, 64), jnp.bfloat16)
-        # exercise every lowering variant a train step can hit:
-        # fwd, fwd+dropout (SMEM seed path), and both bwd kernels
-        out = mha(x, x, x, causal=True, interpret=False)
-        seed = jnp.ones((), jnp.float32)
-        outd = mha(x, x, x, causal=True, dropout_p=0.1, seed=seed,
-                   interpret=False)
-        g = jax.grad(lambda q: mha(
-            q, x, x, causal=True, dropout_p=0.1, seed=seed,
-            interpret=False).astype(jnp.float32).sum())(x)
-        return out, outd, g
-    return _kernel_canary("flash_mha", probe)
-
-
-def _fused_ln_usable():
-    def probe():
-        from ...ops.fused_kernels import fused_layer_norm
-        x = jnp.zeros((8, 256), jnp.bfloat16)
-        w = jnp.ones((256,), jnp.bfloat16)
-        b = jnp.zeros((256,), jnp.bfloat16)
-        out = fused_layer_norm(x, w, b, interpret=False)
-        g = jax.grad(lambda a: fused_layer_norm(
-            a, w, b, interpret=False).astype(jnp.float32).sum())(x)
-        return out, g
-    return _kernel_canary("fused_layer_norm", probe)
-
-
-def _fused_xent_usable():
-    def probe():
-        from ...ops.fused_kernels import fused_softmax_xent
-        x = jnp.zeros((8, 384), jnp.float32)
-        y = jnp.zeros((8,), jnp.int32)
-        loss = fused_softmax_xent(x, y, interpret=False)
-        g = jax.grad(lambda a: fused_softmax_xent(a, y,
-                                                  interpret=False).sum())(x)
-        return loss, g
-    return _kernel_canary("fused_softmax_xent", probe)
-
-
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform not in ("cpu",)
-    except RuntimeError:
-        return False
 
 
 from ...ops.creation import diag_embed  # noqa: F401,E402  (F.diag_embed parity)

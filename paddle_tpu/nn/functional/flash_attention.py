@@ -45,16 +45,6 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
             q, k, v, dropout_p=dropout, is_causal=causal, training=training)
         return out, probs
     eff = dropout if training else 0.0
-    from ...ops.pallas_ops import _interpret_default
-    from .common import _on_tpu, _flash_usable
-    if not _interpret_default() and _on_tpu() and not _flash_usable():
-        # kernel cannot lower on this chip: keep the caller's jitted
-        # step alive via the XLA path (sdpa re-checks the same canary,
-        # so it cannot bounce back here)
-        from .common import scaled_dot_product_attention
-        out = scaled_dot_product_attention(
-            q, k, v, dropout_p=eff, is_causal=causal, training=training)
-        return out, None
     return _fa(q, k, v, causal=causal, dropout_p=eff), None
 
 
@@ -74,82 +64,6 @@ def _softmax_probs(q, k, v, causal):
     return nary(f, [q, k, v], name="flash_attention_softmax")
 
 
-def _packed_usable():
-    """One-time eager canary of the packed varlen kernel (shared
-    ``_kernel_canary`` mechanism, ``common.py``): Pallas kernels that
-    trace fine can still fail at LOWERING time on real TPU, and under
-    ``jax.jit`` that failure escapes call-site try/excepts. On failure
-    the unpadded entry drops to the exact padded-XLA fallback instead
-    of killing the caller's compiled step.
-
-    The probe must be REPRESENTATIVE of production lowering configs:
-    >512 packed tokens so the full 512-block tiles lower (a small probe
-    would cap ``bq`` below the production tile and miss VMEM-limit
-    failures), plus fwd+dropout and both backward kernels in bf16, and
-    a small f32 variant for dtype-specific tiling rules."""
-    from .common import _kernel_canary
-
-    def probe():
-        from ...ops.pallas_ops import mha_packed
-        x = jnp.zeros((640, 4, 64), jnp.bfloat16)  # > 512 => 512-blocks
-        cu = jnp.asarray([0, 128, 640], jnp.int32)
-        out = mha_packed(x, x, x, cu, cu, causal=True, interpret=False)
-        seed = jnp.ones((), jnp.float32)
-        g = jax.grad(lambda q: mha_packed(
-            q, x, x, cu, cu, causal=True, dropout_p=0.1, seed=seed,
-            interpret=False).astype(jnp.float32).sum())(x)
-        xf = jnp.zeros((96, 2, 64), jnp.float32)
-        cuf = jnp.asarray([0, 40, 96], jnp.int32)
-        outf = mha_packed(xf, xf, xf, cuf, cuf, causal=False,
-                          interpret=False)
-        return out, g, outf
-    return _kernel_canary("flash_mha_packed", probe)
-
-
-def _padded_fallback(qd, kd, vd, cu_q, cu_k, max_q, max_k, causal, scale,
-                     dropout_p, seed):
-    """Exact XLA fallback for the packed kernel: scatter packed rows into
-    a (B, max, H, D) batch, run masked attention (same bottom-right
-    causal alignment: col <= row + len_k - len_q), gather back. Compute
-    is O(B*max^2) — correct but without the packed kernel's off-band
-    tile skipping; only used when the kernel cannot lower."""
-    total_q, H, D = qd.shape
-    total_k = kd.shape[0]
-    B = cu_q.shape[0] - 1
-    lens_q = cu_q[1:] - cu_q[:-1]
-    lens_k = cu_k[1:] - cu_k[:-1]
-    iq = jnp.arange(max_q, dtype=jnp.int32)
-    ik = jnp.arange(max_k, dtype=jnp.int32)
-    valid_q = iq[None, :] < lens_q[:, None]                  # (B, max_q)
-    valid_k = ik[None, :] < lens_k[:, None]                  # (B, max_k)
-    tok_q = jnp.clip(cu_q[:-1, None] + iq[None, :], 0, max(total_q - 1, 0))
-    tok_k = jnp.clip(cu_k[:-1, None] + ik[None, :], 0, max(total_k - 1, 0))
-    qb = qd[tok_q] * valid_q[..., None, None]                # (B,max_q,H,D)
-    kb = kd[tok_k] * valid_k[..., None, None]
-    vb = vd[tok_k] * valid_k[..., None, None]
-    logits = jnp.einsum("bqhd,bkhd->bhqk", qb, kb) * scale
-    mask = valid_k[:, None, None, :]
-    if causal:
-        off = (lens_k - lens_q)[:, None, None, None]
-        mask = mask & (ik[None, None, None, :]
-                       <= iq[None, None, :, None] + off)
-    neg = jnp.finfo(jnp.float32).min
-    probs = jax.nn.softmax(
-        jnp.where(mask, logits.astype(jnp.float32), neg), axis=-1)
-    # fully-masked rows (len_q > len_k under causal) produce uniform
-    # softmax over garbage; zero them like the kernel does
-    probs = jnp.where(mask.any(-1, keepdims=True), probs, 0.0)
-    if dropout_p > 0.0 and seed is not None:
-        key = jax.random.PRNGKey(
-            jax.lax.bitcast_convert_type(seed, jnp.int32))
-        keep = jax.random.bernoulli(key, 1.0 - dropout_p, probs.shape)
-        probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
-    ob = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(qd.dtype), vb)
-    tq = jnp.arange(total_q, dtype=jnp.int32)
-    s_of = jnp.clip(jnp.searchsorted(cu_q, tq, side="right") - 1, 0, B - 1)
-    return ob[s_of, tq - cu_q[s_of]]                         # (total_q,H,D)
-
-
 def _validate_cu(cu, total, what, max_seqlen=None):
     import numpy as np
     c = np.asarray(cu)
@@ -157,9 +71,7 @@ def _validate_cu(cu, total, what, max_seqlen=None):
         raise ValueError(
             f"{what} must be nondecreasing, start at 0 and end at the "
             f"packed token count {total}; got {c.tolist()[:8]}...")
-    # max_seqlen is load-bearing on the padded fallback path (rows past
-    # it would be silently dropped + clamp-duplicated on gather-back);
-    # an understated value is caller error on either path — reject it.
+    # an understated max_seqlen is caller error — reject it
     if max_seqlen is not None and len(c) > 1:
         longest = int(np.diff(c).max())
         if longest > int(max_seqlen):
@@ -187,9 +99,8 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
     checked for free; set the ``check_varlen`` flag to validate inside
     the traced program via a host callback (debug mode).
     """
-    from ...ops.pallas_ops import mha_packed, _interpret_default
+    from ...ops.pallas_ops import mha_packed
     from ...framework import flags as _flags
-    from .common import _on_tpu
     q = ensure_tensor(query)
     k, v = ensure_tensor(key), ensure_tensor(value)
     cu_q = jnp.asarray(ensure_tensor(cu_seqlens_q)._data, jnp.int32)
@@ -201,11 +112,6 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
     eff = dropout if training else 0.0
     seeds = _seed_input(eff, True)
     check = bool(_flags.flag("check_varlen"))
-    # interpret mode (CPU) is always exact; on real TPU the kernel is
-    # used only after its eager canary proves it lowers — otherwise the
-    # exact padded-XLA fallback keeps the caller's jitted step alive
-    use_kernel = _interpret_default() or (_on_tpu() and _packed_usable())
-
     def f(qd, kd, vd, cu, cuk, *rest):
         if check:
             def _cb(c, ck):
@@ -215,11 +121,6 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
             # debug.callback is effectful — a pure_callback whose result
             # is unused would be dead-code-eliminated under jit
             jax.debug.callback(_cb, cu, cuk)
-        if not use_kernel:
-            return _padded_fallback(qd, kd, vd, cu, cuk,
-                                    int(max_seqlen_q), int(max_seqlen_k),
-                                    causal, scale, eff,
-                                    rest[0] if rest else None)
         return mha_packed(qd, kd, vd, cu, cuk, causal=causal,
                           sm_scale=scale, dropout_p=eff,
                           seed=rest[0] if rest else None)

@@ -96,9 +96,9 @@ def _maybe_fused_cross_entropy(input, label, *, weight, ignore_index,
                                reduction, soft_label, axis, use_softmax,
                                label_smoothing):
     """Route hard-label cross-entropy through the fused Pallas
-    softmax-xent kernel (same gate shape as
-    ``scaled_dot_product_attention``: flag + hardware + one-time
-    lowering canary, XLA fallback on any failure or ineligible shape).
+    softmax-xent kernel (same gate as ``scaled_dot_product_attention``:
+    flag + ``device.pallas_dispatch`` + eligible shape, no runtime
+    fallback).
     Returns the loss Tensor, or None when the caller should take the
     XLA path. Soft labels, class weights, and non-trailing class axes
     stay on XLA."""
@@ -118,8 +118,8 @@ def _maybe_fused_cross_entropy(input, label, *, weight, ignore_index,
     if not (eligible and _flags.flag("use_pallas_kernels")):
         _record("fused_softmax_xent", "fallback")
         return None
-    from .common import _on_tpu, _fused_xent_usable
-    if not (_on_tpu() and _fused_xent_usable()):
+    from ...framework import device as _device
+    if not _device.pallas_dispatch():
         _record("fused_softmax_xent", "fallback")
         return None
 
@@ -139,13 +139,9 @@ def _maybe_fused_cross_entropy(input, label, *, weight, ignore_index,
             return jnp.sum(loss) / jnp.maximum(jnp.sum(valid), 1.0)
         return _reduce(loss, reduction)
 
-    try:
-        out = nary(f, [input, label], name="cross_entropy")
-        _record("fused_softmax_xent", "pallas")
-        return out
-    except Exception:
-        _record("fused_softmax_xent", "fallback")
-        return None
+    out = nary(f, [input, label], name="cross_entropy")
+    _record("fused_softmax_xent", "pallas")
+    return out
 
 
 def softmax_with_cross_entropy(logits, label, soft_label=False,

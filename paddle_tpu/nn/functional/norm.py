@@ -99,16 +99,14 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
     n_axes = len(tuple(normalized_shape))
     axes = tuple(range(x.ndim - n_axes, x.ndim))
 
-    # fused Pallas path (same gate shape as scaled_dot_product_attention:
-    # flag + hardware + one-time lowering canary, XLA fallback on any
-    # failure); the kernel normalizes a flattened (rows, d) view
+    # fused Pallas path (same gate as scaled_dot_product_attention: flag
+    # + device.pallas_dispatch, no runtime fallback); the kernel
+    # normalizes a flattened (rows, d) view
     from ...framework import flags as _flags
+    from ...framework import device as _device
     from ...ops.fused_kernels import record_dispatch as _record
-    use_fused = False
-    if _flags.flag("use_pallas_kernels") and x.ndim >= n_axes > 0:
-        from .common import _on_tpu, _fused_ln_usable
-        use_fused = _on_tpu() and _fused_ln_usable()
-    if use_fused:
+    if _flags.flag("use_pallas_kernels") and x.ndim >= n_axes > 0 \
+            and _device.pallas_dispatch():
         d = int(np.prod(tuple(normalized_shape)))
 
         def f_fused(dd, *rest):
@@ -134,12 +132,9 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
             args.append(ensure_tensor(weight))
         if bias is not None:
             args.append(ensure_tensor(bias))
-        try:
-            out = nary(f_fused, args, name="layer_norm")
-            _record("fused_layer_norm", "pallas")
-            return out
-        except Exception:
-            pass  # fall back to XLA path
+        out = nary(f_fused, args, name="layer_norm")
+        _record("fused_layer_norm", "pallas")
+        return out
     _record("fused_layer_norm", "fallback")
 
     def f(d, *rest):

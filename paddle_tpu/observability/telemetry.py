@@ -147,7 +147,11 @@ class _CompileLogFilter:
             if msg.startswith("Compiling ") and record.args:
                 args = (record.args if isinstance(record.args, tuple)
                         else (record.args,))
+                # jax names the module "jit(<callable>)"; the sentinel
+                # and the AOT/capture feeds key on the bare callable
                 name = str(args[0])
+                if name.startswith("jit(") and name.endswith(")"):
+                    name = name[4:-1]
                 sig = "; ".join(str(a)[:400] for a in args[1:])
                 self._tel._on_compile(name, sig)
                 return not self._swallow
@@ -705,7 +709,8 @@ class TrainingTelemetry:
 
     def fusion_fallback(self, pattern, reason):
         """One rewritten cluster dispatched to the XLA fallback;
-        ``reason`` is tpu_unreachable or canary_failed."""
+        ``reason`` is ``not_tpu``, or ``gspmd_mesh`` on a TPU where
+        Mosaic cannot lower (``fusion_pass._backend``)."""
         pattern, reason = str(pattern), str(reason)
         key = f"{pattern}:{reason}"
         self._fusion_fallbacks[key] = \

@@ -15,7 +15,7 @@ the same contract as :class:`~.telemetry.TrainingTelemetry`:
    buffer — appends are GIL-atomic, so the hot path takes no lock; the
    lock guards only rare operations (enable/export/flight dumps).
 3. **Tracer-safe.**  Wall-clock phase spans are skipped inside a jax
-   trace (``jax.core.trace_state_clean``, same guard as
+   trace (``jax.core.trace_ctx.is_top_level``, same guard as
    ``distributed.collective._timed``): timing a tracer would record the
    trace, not the step.
 4. **Never sync the device, never take down the run.**  Spans carry
@@ -67,8 +67,8 @@ from .logs import get_logger
 from .metrics import get_registry, log_buckets
 
 __all__ = [
-    "Tracer", "Span", "PHASES", "PEAK_FLOPS", "peak_flops",
-    "program_flops", "get_tracer", "current_tracer", "reset_tracer",
+    "Tracer", "Span", "PHASES", "PEAK_FLOPS", "PEAK_HBM_BW", "peak_flops",
+    "peak_hbm_bw", "program_flops", "get_tracer", "current_tracer", "reset_tracer",
 ]
 
 logger = get_logger(__name__)
@@ -88,14 +88,21 @@ _PHASE_CAT = {
     "collective": "collective",
 }
 
-# bf16 peak FLOP/s per chip by device kind (public spec sheets).  The
-# "cpu" entry is a nominal one-core figure so CPU-only bench records
-# still carry an MFU estimate (the point is trend, not absolute truth).
+# Peak bf16 FLOP/s and HBM bytes/s per chip by device kind (Google Cloud
+# TPU system-architecture pages).  The "cpu" entries are nominal so
+# CPU-only records still carry an MFU estimate and the autotuner can
+# order candidates in interpret-mode tests (trend, not absolute truth).
 PEAK_FLOPS = {
     "TPU v4": 275e12, "TPU v5": 459e12, "TPU v5p": 459e12,
     "TPU v5e": 197e12, "TPU v5 lite": 197e12, "TPU v6e": 918e12,
     "TPU v6 lite": 918e12, "TPU v3": 123e12, "TPU v2": 45e12,
     "cpu": 1e11,
+}
+PEAK_HBM_BW = {
+    "TPU v4": 1.2e12, "TPU v5": 2.765e12, "TPU v5p": 2.765e12,
+    "TPU v5e": 819e9, "TPU v5 lite": 819e9, "TPU v6e": 1.64e12,
+    "TPU v6 lite": 1.64e12,
+    "cpu": 5e10,
 }
 
 # seconds between watchdog flight-recorder refreshes from the hot path
@@ -108,14 +115,29 @@ def _env_flag(name):
     return os.environ.get(name, "").strip().lower() in _TRUTHY
 
 
-def peak_flops(device_kind):
-    """Peak FLOP/s for ``device_kind`` (longest-prefix match so
-    "TPU v5 lite" never matches "TPU v5"); None when unknown."""
+def _peak(table, what, device_kind, strict):
     kind = (device_kind or "").lower()
-    for k in sorted(PEAK_FLOPS, key=len, reverse=True):
+    for k in sorted(table, key=len, reverse=True):
         if kind.startswith(k.lower()):
-            return PEAK_FLOPS[k]
+            return table[k]
+    if strict:
+        raise KeyError(f"no {what} for device kind {device_kind!r}: add "
+                       "it to the peak table in observability/trace.py")
     return None
+
+
+def peak_flops(device_kind, strict=False):
+    """Peak FLOP/s for ``device_kind`` (longest-prefix match so
+    "TPU v5 lite" never matches "TPU v5").  Unknown kind: None, or a
+    ``KeyError`` under ``strict`` (measurement paths use strict — a
+    device that is not in the table is an error, not a default)."""
+    return _peak(PEAK_FLOPS, "peak FLOP/s", device_kind, strict)
+
+
+def peak_hbm_bw(device_kind, strict=False):
+    """Peak HBM bytes/s for ``device_kind``; same matching and ``strict``
+    rule as :func:`peak_flops`."""
+    return _peak(PEAK_HBM_BW, "peak HBM bytes/s", device_kind, strict)
 
 
 def _device_kind():
@@ -134,15 +156,11 @@ def _device_kind():
 
 
 def _tracing():
-    """True when called under an open jax trace (or when jax's trace
-    state cannot be read — assume the worst, skip wall timing)."""
+    """True when called under an open jax trace."""
     jax = sys.modules.get("jax")
     if jax is None:
         return False
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return True
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def program_flops(jitted, *args, **kwargs):

@@ -28,7 +28,7 @@ import time
 
 __all__ = ["enabled", "set_enabled", "cache_get", "cache_put",
            "cache_clear", "save_cache", "load_cache", "time_candidates",
-           "search", "prune_candidates", "roofline_seconds",
+           "search", "prune_candidates", "roofline_seconds", "device_peaks",
            "analytic_seed", "generate_candidates", "bump_schema",
            "summary", "KERNEL_SCHEMA", "VMEM_LIMIT_BYTES"]
 
@@ -63,11 +63,6 @@ def bump_schema(kernel: str) -> int:
     KERNEL_SCHEMA[kernel] = KERNEL_SCHEMA.get(kernel, 1) + 1
     return KERNEL_SCHEMA[kernel]
 
-# Roofline constants: v4-class core (~275 TFLOP/s bf16 MXU, ~1.2 TB/s
-# HBM). Only the RATIO matters — the roofline orders candidates, the
-# timing loop decides.
-PEAK_FLOPS = 275e12
-HBM_BW = 1.2e12
 # ~16 MB vmem/core, minus headroom for Mosaic's own buffers.
 VMEM_LIMIT_BYTES = 12 * 1024 * 1024
 
@@ -90,11 +85,7 @@ def _env_fingerprint():
     """(device_kind, jax_version) of the process — part of every cache
     key so interpret-mode CPU tunings never leak onto real TPUs."""
     import jax
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = "unknown"
-    return str(kind), str(jax.__version__)
+    return str(jax.devices()[0].device_kind), str(jax.__version__)
 
 
 def _key(kernel: str, key: tuple) -> str:
@@ -178,10 +169,21 @@ def analytic_seed(fn, *example_args):
         return None
 
 
+def device_peaks():
+    """(peak FLOP/s, peak HBM bytes/s) of the device the candidates run
+    on, from the one peak table (``observability.trace``) by
+    ``device_kind``; an unknown kind raises."""
+    import jax
+    from ..observability.trace import peak_flops, peak_hbm_bw
+    kind = jax.devices()[0].device_kind
+    return peak_flops(kind, strict=True), peak_hbm_bw(kind, strict=True)
+
+
 def roofline_seconds(flops: float, bytes_: float) -> float:
     """Roofline time estimate: the kernel is bound by whichever of MXU
     throughput or HBM bandwidth it saturates first."""
-    return max(float(flops) / PEAK_FLOPS, float(bytes_) / HBM_BW)
+    peak, bw = device_peaks()
+    return max(float(flops) / peak, float(bytes_) / bw)
 
 
 def prune_candidates(candidates, cost, vmem_limit=None):

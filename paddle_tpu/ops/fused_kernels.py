@@ -44,8 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_ops import _CompilerParams, _LANES, _NEG_INF, _ceil_to, \
-    _interpret_default
+from .pallas_ops import _LANES, _NEG_INF, _ceil_to, _interpret_default
 
 __all__ = [
     "fused_layer_norm", "fused_softmax_xent",
@@ -207,7 +206,7 @@ def _ln_pallas_fwd(x, res, w, b, *, d, eps, block_rows, parallel, interpret):
             jax.ShapeDtypeStruct((rows_p, 1), jnp.float32),
             jax.ShapeDtypeStruct((rows_p, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel" if parallel else "arbitrary",)),
         interpret=interpret,
     )(*args)
@@ -250,7 +249,7 @@ def _ln_pallas_bwd(x, res, w, b, g, mean, rstd, *, d, block_rows,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)
@@ -462,7 +461,7 @@ def _xent_pallas_fwd(x, lab, *, V, block_rows, block_v, ignore_index,
             pltpu.VMEM((block_rows, _LANES), jnp.float32),
             pltpu.VMEM((block_rows, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lab, x)
@@ -486,7 +485,7 @@ def _xent_pallas_bwd(x, lab, lse, g, *, V, block_rows, block_v,
         ],
         out_specs=pl.BlockSpec((block_rows, block_v), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows_p, v_pad), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(lab, x, lse, g)
@@ -798,7 +797,7 @@ def _lnmm_pallas_fwd(x, res, lw, lb, w, mb, *, d, eps, block_rows,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows_p, n_pad), x.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel" if parallel else "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*args)
@@ -970,7 +969,7 @@ def _mbg_pallas_fwd(x, w, b, *, block_rows, block_n, approximate,
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((rows_p, n_pad), x.dtype),
                    jax.ShapeDtypeStruct((rows_p, n_pad), x.dtype)],
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel" if parallel else "arbitrary", "parallel")),
         interpret=interpret,
     )(*args)

@@ -7,7 +7,7 @@ eligible multi-op subgraphs to the block-fused Pallas kernels in
 with zero source changes.  Patterns matched:
 
 =================== =======================================================
-``layer_norm``       the XLA layernorm soup (mean / ``_var`` pjit / rsqrt /
+``layer_norm``       the XLA layernorm soup (mean / ``_var`` jit / rsqrt /
                      affine) → :func:`fused_kernels.fused_layer_norm`
 ``residual_ln``      residual add feeding that soup, add consumed only by
                      it (post-LN transformers) → fused residual+LN kernel
@@ -27,12 +27,14 @@ tape's backward re-traces the forward per-op, so forward clusters are
 closed and replaceable while the backward's recompute copy (whose
 interiors feed transposes) is left alone.
 
-Dispatch is canary-probed per pattern, resolved once per process: on a
-real TPU the cluster call runs the Pallas kernel; otherwise it runs an
-inline XLA reference that mirrors the matched soup (reason
-``tpu_unreachable`` — CPU timing and parity are unchanged, interpret
-mode is never on the rewritten path).  ``PT_FUSION_PASS=0`` kills the
-pass; ``PT_FUSION_DISABLE=pat1,pat2`` opts out individual patterns.
+Dispatch follows ``device.pallas_dispatch``: on a TPU, in a program
+Mosaic can lower, the cluster call runs the Pallas kernel (a kernel the
+compiler refuses fails the program); elsewhere it runs an inline XLA
+reference that mirrors the matched soup (reason ``not_tpu``, or
+``gspmd_mesh`` on a TPU under a multi-device GSPMD mesh — CPU timing
+and parity are unchanged, interpret mode is never on the rewritten
+path).  ``PT_FUSION_PASS=0`` kills the pass;
+``PT_FUSION_DISABLE=pat1,pat2`` opts out individual patterns.
 """
 from __future__ import annotations
 
@@ -40,7 +42,9 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
+
+from ..framework import device as _device
 
 __all__ = [
     "PATTERNS", "wrap", "match_jaxpr", "match_report", "count_patterns",
@@ -106,65 +110,14 @@ def _note_fallback(pattern, reason):
         pass
 
 
-# ---------------------------------------------------------------------------
-# canary-probed backend resolution (per pattern, cached per process)
-# ---------------------------------------------------------------------------
-_BACKEND_CACHE: dict = {}
-
-
-def _reset_dispatch_cache():
-    _BACKEND_CACHE.clear()
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
-def _canary(pattern):
-    """Run the pattern's fused kernel on a tiny probe eagerly; any
-    exception disqualifies the Pallas route for this process."""
-    from . import fused_kernels as fk
-    x = jnp.zeros((8, 128), jnp.float32)
-    if pattern in ("layer_norm", "residual_ln"):
-        out = fk.fused_layer_norm(x, residual=x, interpret=False)
-    elif pattern == "ln_matmul":
-        out = fk.fused_ln_matmul(x, jnp.zeros((128, 128), jnp.float32),
-                                 interpret=False)
-    elif pattern == "matmul_bias_gelu":
-        out = fk.fused_matmul_bias_gelu(
-            x, jnp.zeros((128, 128), jnp.float32), interpret=False)
-    elif pattern == "attention_block":
-        q = jnp.zeros((1, 1, 128, 64), jnp.float32)
-        out = fk.fused_attention_block(q, q, q, causal=True,
-                                       interpret=False)
-    else:
-        raise ValueError(pattern)
-    # one-shot offline self-test of a compiled kernel, not a step
-    # loop — the sync is the point
-    # tpu-lint: disable=TPU017
-    return bool(jnp.all(jnp.isfinite(out)))
-
-
-def _backend(pattern):
-    """``("pallas", None)`` or ``("xla", reason)`` for a pattern —
-    resolved eagerly the first time a cluster of that pattern is
-    rewritten, then cached (trace-safe: probes run on concrete zeros)."""
-    hit = _BACKEND_CACHE.get(pattern)
-    if hit is not None:
-        return hit
-    if not _on_tpu():
-        resolved = ("xla", "tpu_unreachable")
-    else:
-        try:
-            resolved = ("pallas", None) if _canary(pattern) \
-                else ("xla", "canary_failed")
-        except Exception:
-            resolved = ("xla", "canary_failed")
-    _BACKEND_CACHE[pattern] = resolved
-    return resolved
+def _backend():
+    """``("pallas", None)`` where ``device.pallas_dispatch`` selects the
+    kernels, else ``("xla", reason)``."""
+    if not _device.on_tpu():
+        return "xla", "not_tpu"
+    if not _device.mosaic_can_lower():
+        return "xla", "gspmd_mesh"
+    return "pallas", None
 
 
 # ---------------------------------------------------------------------------
@@ -330,7 +283,7 @@ def _match_ln(g, ri, claimed):
     var_v, eps = _split_lit(eqns[ai])
     if var_v is None:
         return None
-    vi = g.pe(var_v, "pjit")
+    vi = g.pe(var_v, "jit")
     if vi is None or eqns[vi].params.get("name") != "_var":
         return None
     # jnp.var(x, ddof): second operand must be the ddof literal 0
@@ -673,9 +626,9 @@ def _match_attention(g, pi):
     # causal mask: scores = _where(tril(...), scaled, -inf)
     causal = False
     wi = g.producer(scores)
-    if wi is not None and eqns[wi].primitive.name == "pjit" and \
+    if wi is not None and eqns[wi].primitive.name == "jit" and \
             eqns[wi].params.get("name") == "_where":
-        tri = g.pe(eqns[wi].invars[0], "pjit")
+        tri = g.pe(eqns[wi].invars[0], "jit")
         if tri is None or eqns[tri].params.get("name") != "tril":
             return None
         covered |= {wi, tri}
@@ -830,7 +783,7 @@ def _cluster_fn(cl):
     """Build the callable replacing cluster ``cl``: Pallas block kernel
     on TPU, inline XLA mirror of the matched soup otherwise."""
     pattern, meta = cl.pattern, cl.meta
-    backend, reason = _backend(pattern)
+    backend, reason = _backend()
     if backend != "pallas":
         _note_fallback(pattern, reason)
     from . import fused_kernels as fk
@@ -982,31 +935,27 @@ def wrap(fn):
     """Apply the fusion pass to ``fn`` at trace time: re-trace it to a
     jaxpr, rewrite matched clusters to block-fused kernel calls, and
     evaluate the rewritten graph (in the caller's trace, so this
-    composes with jit/grad/capture).  Falls back to ``fn`` untouched
-    when the pass is disabled, nothing matches, or anything about the
-    rewrite goes wrong — the pass must never break a model."""
+    composes with jit/grad/capture).  Declines (calls ``fn`` untouched)
+    when the pass is disabled or nothing matches; an exception inside
+    the pass propagates."""
     import functools
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
         if not fusion_enabled():
             return fn(*args, **kwargs)
-        try:
-            flat, in_tree = jax.tree_util.tree_flatten((args, kwargs))
+        flat, in_tree = jax.tree_util.tree_flatten((args, kwargs))
 
-            def flat_fn(*leaves):
-                a, kw = jax.tree_util.tree_unflatten(in_tree, leaves)
-                return fn(*a, **kw)
+        def flat_fn(*leaves):
+            a, kw = jax.tree_util.tree_unflatten(in_tree, leaves)
+            return fn(*a, **kw)
 
-            closed, out_shape = jax.make_jaxpr(
-                flat_fn, return_shape=True)(*flat)
-            plan = match_jaxpr(closed.jaxpr)
-            if not plan:
-                _stats["traces"] += 1
-                return fn(*args, **kwargs)
-        except Exception:
-            return fn(*args, **kwargs)
+        closed, out_shape = jax.make_jaxpr(
+            flat_fn, return_shape=True)(*flat)
+        plan = match_jaxpr(closed.jaxpr)
         _stats["traces"] += 1
+        if not plan:
+            return fn(*args, **kwargs)
         for cl in plan:
             _note_rewrite(cl.pattern)
         out_flat = _eval_rewritten(closed.jaxpr, closed.consts, flat,

@@ -13,15 +13,13 @@ first-class:
    independent by construction, which is what makes continuous
    batching bit-stable (a sequence's logits do not depend on its batch
    neighbours or on which physical pages it landed in).
- - :func:`paged_attention` — dispatcher: Pallas kernel on TPU (canary
-   probed once, silent XLA fallback — the :mod:`.fused_kernels`
-   convention), reference elsewhere.
+ - :func:`paged_attention` — dispatcher: Pallas kernel on TPU,
+   reference elsewhere; selection is by platform only.
  - ``_paged_attention_pallas`` — the kernel: grid ``(batch, pages)``
    with the per-sequence page table scalar-prefetched so each grid
    step's ``BlockSpec`` index map *is* the page-table lookup (the page
    gather never materialises in HBM), online-softmax accumulators in
-   VMEM scratch.  Interpret-runnable off-TPU; MXU tiling/tuning on a
-   real device is a follow-on (ROADMAP real-TPU evidence round).
+   VMEM scratch.  Interpret-runnable off-TPU.
 
 Shapes (one layer; the model loops layers):
   q            (B, H, D)        one query token per sequence
@@ -43,7 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_ops import _CompilerParams, _NEG_INF, _interpret_default
+from ..framework import device as _device
+from .pallas_ops import _LANES, _NEG_INF, _interpret_default
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_int8", "paged_attention_int8_reference",
@@ -76,113 +75,155 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
     return o.astype(q.dtype)
 
 
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, ps, max_pages, sm_scale):
+def _head_selectors(h, d):
+    """0/1 matrices that move between the flattened ``(.., H*D)`` lane
+    layout and one column per head: ``seg`` (H*D, LANES) sums each
+    head's D lanes into column h, ``seg.T`` spreads column h back over
+    them.  LANES pads H to the 128-lane tile so every matmul in the
+    kernel is tile-aligned; the padded columns meet only zero rows."""
+    lanes = -(-h // _LANES) * _LANES
+    seg = (jnp.arange(h * d, dtype=jnp.int32)[:, None] // d
+           == jnp.arange(lanes, dtype=jnp.int32)[None, :])
+    seg = seg.astype(jnp.float32)
+    return seg, seg.T
+
+
+def _dot(a, b):
+    # selector matmuls carry f32 scores/weights: keep the MXU at full
+    # f32 contract precision (the default would round them to bf16)
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _online_softmax_page(i, length, s, v, segt, m_scr, l_scr, acc_scr,
+                         *, ps, v_weight=None):
+    """One page of the online softmax, shared by both kernels. ``s`` is
+    (ps, LANES) scores with head h in column h, ``v`` (ps, H*D) values;
+    ``v_weight`` (ps, LANES) scales each (token, head) before the value
+    sum (the int8 pool's per-(token, head) v scale). The running max /
+    sum / accumulator live as 8 identical sublane rows so every operand
+    is a whole f32 tile."""
+    pos = i * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    valid = pos < length
+    s = jnp.where(valid, s, _NEG_INF)
+    m_prev, l_prev = m_scr[...], l_scr[...]              # (8, LANES)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    # re-mask after the exp: on a fully-dead page m_new stays at
+    # _NEG_INF and exp(s - m_new) would be exp(0) = 1 mass
+    p = jnp.where(valid, jnp.exp(s - m_new[:1]), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[:] = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
+    m_scr[:] = m_new
+    if v_weight is not None:
+        p = p * v_weight
+    pv = jnp.sum(_dot(p, segt) * v, axis=0, keepdims=True)   # (1, H*D)
+    acc_scr[:] = acc_scr[...] * _dot(alpha, segt) + pv
+
+
+def _finalize(o_ref, segt, l_scr, acc_scr):
+    l = l_scr[...]
+    l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[...] = (acc_scr[...] / _dot(l, segt))[:1].astype(o_ref.dtype)
+
+
+def _init_scratch(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+    l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+
+# Mosaic has no matmul whose batch (head) dim sits in the middle of a
+# 3-D operand ("hd,phd->hp"), so the kernels work on 2-D tiles: a page is
+# (ps, H*D), the one query row per sequence is (1, H*D), the per-head
+# contraction is a VPU multiply followed by a 0/1 selector matmul that
+# sums each head's D lanes (see _head_selectors).
+def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref,
+                  o_ref, m_scr, l_scr, acc_scr, *, ps, max_pages, sm_scale):
     b = pl.program_id(0)
     i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
-        m_scr[:] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+        _init_scratch(m_scr, l_scr, acc_scr)
 
     length = len_ref[b]
 
     @pl.when(i * ps < length)
     def _page():
-        q = q_ref[...].astype(jnp.float32)          # (H, D)
-        k = k_ref[...].astype(jnp.float32)          # (ps, H, D)
+        q = q_ref[...].astype(jnp.float32)          # (1, H*D)
+        k = k_ref[...].astype(jnp.float32)          # (ps, H*D)
         v = v_ref[...].astype(jnp.float32)
-        s = jnp.einsum("hd,phd->hp", q, k) * sm_scale
-        pos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        valid = pos < length                         # (1, ps)
-        s = jnp.where(valid, s, _NEG_INF)
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # re-mask after the exp: on a fully-dead page m_new stays at
-        # _NEG_INF and exp(s - m_new) would be exp(0) = 1 mass
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[...] * alpha \
-            + jnp.einsum("hp,phd->hd", p, v)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        s = _dot(q * k, seg_ref[...]) * sm_scale     # (ps, LANES)
+        _online_softmax_page(i, length, s, v, segt_ref[...], m_scr, l_scr,
+                             acc_scr, ps=ps)
 
     @pl.when(i == max_pages - 1)
-    def _finalize():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, segt_ref[...], l_scr, acc_scr)
+
+
+def _paged_call(kernel, q, pages, extra, extra_specs, page_tables, lengths,
+                *, batch_semantics, interpret):
+    """Shared pallas_call plumbing: q (B, H, D) and (P, ps, H, D) pools
+    enter flattened to H*D lanes; ``extra``/``extra_specs`` are the int8
+    kernel's scale operands."""
+    b, h, d = q.shape
+    ps = pages[0].shape[1]
+    hd = h * d
+    seg, segt = _head_selectors(h, d)
+    lanes = seg.shape[1]
+    page_spec = pl.BlockSpec((None, ps, hd),
+                             lambda bi, i, pt, ln: (pt[bi, i], 0, 0))
+    row_spec = pl.BlockSpec((None, 1, hd), lambda bi, i, pt, ln: (bi, 0, 0))
+
+    def resident(a):  # one block spanning the operand, fetched once
+        return pl.BlockSpec(a.shape, lambda bi, i, pt, ln: (0,) * a.ndim)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, page_tables.shape[1]),
+        in_specs=[row_spec] + [page_spec] * len(pages) + list(extra_specs)
+        + [resident(seg), resident(segt)],
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((8, lanes), jnp.float32),
+            pltpu.VMEM((8, lanes), jnp.float32),
+            pltpu.VMEM((8, hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(batch_semantics, "arbitrary")),
+        interpret=interpret,
+    )(page_tables, lengths, q.reshape(b, 1, hd),
+      *[p.reshape(p.shape[0], ps, hd) for p in pages], *extra, seg, segt)
+    return out.reshape(b, h, d)
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, page_tables, lengths,
                             *, sm_scale, interpret):
-    b, h, d = q.shape
-    ps = k_pages.shape[1]
-    max_pages = page_tables.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, max_pages),
-        in_specs=[
-            pl.BlockSpec((None, h, d), lambda bi, i, pt, ln: (bi, 0, 0)),
-            pl.BlockSpec((None, ps, h, d),
-                         lambda bi, i, pt, ln: (pt[bi, i], 0, 0, 0)),
-            pl.BlockSpec((None, ps, h, d),
-                         lambda bi, i, pt, ln: (pt[bi, i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, h, d), lambda bi, i, pt, ln: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_paged_kernel, ps=ps, max_pages=max_pages,
+    kernel = functools.partial(_paged_kernel, ps=k_pages.shape[1],
+                               max_pages=page_tables.shape[1],
                                sm_scale=sm_scale)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(page_tables, lengths, q, k_pages, v_pages)
-
-
-_canary_ok = None
-
-
-def _canary():
-    """One-shot probe: run the kernel at a toy shape before trusting it
-    for dispatch (the SDPA/fused-kernel convention — a broken lowering
-    degrades to XLA instead of poisoning the serve path)."""
-    global _canary_ok
-    if _canary_ok is None:
-        try:
-            q = jnp.zeros((2, 2, 8), jnp.float32)
-            kp = jnp.zeros((3, 4, 2, 8), jnp.float32)
-            pt = jnp.zeros((2, 2), jnp.int32)
-            ln = jnp.ones((2,), jnp.int32)
-            _paged_attention_pallas(q, kp, kp, pt, ln,
-                                    sm_scale=1.0,
-                                    interpret=_interpret_default())
-            _canary_ok = True
-        except Exception:
-            _canary_ok = False
-    return _canary_ok
+    return _paged_call(kernel, q, (k_pages, v_pages), (), (), page_tables,
+                       lengths, batch_semantics="parallel",
+                       interpret=interpret)
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
                     sm_scale=None, use_pallas=None, interpret=None):
-    """Dispatching entry: Pallas paged-attention kernel when eligible,
-    XLA gather+softmax reference otherwise.
+    """Dispatching entry: the Pallas paged-attention kernel on TPU, the
+    XLA gather+softmax reference elsewhere.
 
     Off-TPU the default is the reference (interpret-mode Pallas is a
     correctness vehicle, not a fast path); pass ``use_pallas=True`` to
-    force the kernel (tests).  Dispatch decisions are trace-time
-    events booked on ``pt_pallas_calls_total{kernel="paged_attention"}``.
+    force the kernel (tests).  Selection is by platform only: a kernel
+    the compiler refuses fails the program with the compiler's message.
+    Dispatch decisions are trace-time events booked on
+    ``pt_pallas_calls_total{kernel="paged_attention"}``.
     """
     from .fused_kernels import record_dispatch
     if sm_scale is None:
@@ -190,8 +231,8 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
     if interpret is None:
         interpret = _interpret_default()
     if use_pallas is None:
-        use_pallas = not interpret  # on-TPU default; reference on CPU
-    if use_pallas and _canary():
+        use_pallas = _device.pallas_dispatch()  # reference off the TPU
+    if use_pallas:
         record_dispatch("paged_attention", "pallas")
         return _paged_attention_pallas(q, k_pages, v_pages, page_tables,
                                        lengths, sm_scale=sm_scale,
@@ -233,110 +274,59 @@ def paged_attention_int8_reference(q, k_pages, v_pages, k_scale, v_scale,
 
 
 def _paged_kernel_int8(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                       vs_ref, o_ref, m_scr, l_scr, acc_scr, *, ps,
-                       max_pages, sm_scale):
+                       vs_ref, seg_ref, segt_ref, o_ref, m_scr, l_scr,
+                       acc_scr, *, ps, max_pages, sm_scale):
     b = pl.program_id(0)
     i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
-        m_scr[:] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+        _init_scratch(m_scr, l_scr, acc_scr)
 
     length = len_ref[b]
 
     @pl.when(i * ps < length)
     def _page():
-        q = q_ref[...].astype(jnp.float32)          # (H, D)
-        # unpack at the edge: int8 page * per-(token, head) scale
-        k = k_ref[...].astype(jnp.float32) * ks_ref[...][..., None]
-        v = v_ref[...].astype(jnp.float32) * vs_ref[...][..., None]
-        s = jnp.einsum("hd,phd->hp", q, k) * sm_scale
-        pos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        valid = pos < length                         # (1, ps)
-        s = jnp.where(valid, s, _NEG_INF)
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # re-mask after the exp (see _paged_kernel)
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[...] * alpha \
-            + jnp.einsum("hp,phd->hd", p, v)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        q = q_ref[...].astype(jnp.float32)          # (1, H*D)
+        k = k_ref[...].astype(jnp.float32)          # (ps, H*D) int8 -> f32
+        v = v_ref[...].astype(jnp.float32)
+        # unpack at the edge: the per-(token, head) scale is constant
+        # over a head's D lanes, so it factors out of the contraction
+        # and multiplies the per-head score / weight column instead
+        s = _dot(q * k, seg_ref[...]) * ks_ref[...] * sm_scale
+        _online_softmax_page(i, length, s, v, segt_ref[...], m_scr, l_scr,
+                             acc_scr, ps=ps, v_weight=vs_ref[...])
 
     @pl.when(i == max_pages - 1)
-    def _finalize():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, segt_ref[...], l_scr, acc_scr)
 
 
 def _paged_attention_int8_pallas(q, k_pages, v_pages, k_scale, v_scale,
                                  page_tables, lengths, *, sm_scale,
                                  interpret, batch_semantics="parallel"):
-    b, h, d = q.shape
+    h = q.shape[1]
     ps = k_pages.shape[1]
-    max_pages = page_tables.shape[1]
-    page_spec = pl.BlockSpec((None, ps, h, d),
-                             lambda bi, i, pt, ln: (pt[bi, i], 0, 0, 0))
-    scale_spec = pl.BlockSpec((None, ps, h),
+    lanes = -(-h // _LANES) * _LANES
+    # scales ride as (P, ps, LANES) rows aligned with the score columns
+    pad = ((0, 0), (0, 0), (0, lanes - h))
+    scale_spec = pl.BlockSpec((None, ps, lanes),
                               lambda bi, i, pt, ln: (pt[bi, i], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, max_pages),
-        in_specs=[
-            pl.BlockSpec((None, h, d), lambda bi, i, pt, ln: (bi, 0, 0)),
-            page_spec, page_spec, scale_spec, scale_spec,
-        ],
-        out_specs=pl.BlockSpec((None, h, d), lambda bi, i, pt, ln: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ],
-    )
     kernel = functools.partial(_paged_kernel_int8, ps=ps,
-                               max_pages=max_pages, sm_scale=sm_scale)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=(batch_semantics, "arbitrary")),
-        interpret=interpret,
-    )(page_tables, lengths, q, k_pages, v_pages, k_scale, v_scale)
-
-
-_canary_int8_ok = None
-
-
-def _canary_int8():
-    global _canary_int8_ok
-    if _canary_int8_ok is None:
-        try:
-            q = jnp.zeros((2, 2, 8), jnp.float32)
-            kp = jnp.zeros((3, 4, 2, 8), jnp.int8)
-            ks = jnp.ones((3, 4, 2), jnp.float32)
-            pt = jnp.zeros((2, 2), jnp.int32)
-            ln = jnp.ones((2,), jnp.int32)
-            _paged_attention_int8_pallas(q, kp, kp, ks, ks, pt, ln,
-                                         sm_scale=1.0,
-                                         interpret=_interpret_default())
-            _canary_int8_ok = True
-        except Exception:
-            _canary_int8_ok = False
-    return _canary_int8_ok
+                               max_pages=page_tables.shape[1],
+                               sm_scale=sm_scale)
+    return _paged_call(kernel, q, (k_pages, v_pages),
+                       (jnp.pad(k_scale, pad), jnp.pad(v_scale, pad)),
+                       (scale_spec, scale_spec), page_tables, lengths,
+                       batch_semantics=batch_semantics, interpret=interpret)
 
 
 def paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
                          page_tables, lengths, *, sm_scale=None,
                          use_pallas=None, interpret=None):
-    """Dispatching entry for the int8-KV pool: Pallas kernel when
-    eligible (canary-probed), XLA gather+dequant+softmax reference
-    otherwise — booked on
+    """Dispatching entry for the int8-KV pool: Pallas kernel on TPU, XLA
+    gather+dequant+softmax reference elsewhere (same rule as
+    :func:`paged_attention`) — booked on
     ``pt_pallas_calls_total{kernel="paged_attention_int8"}``."""
     from .fused_kernels import record_dispatch
     if sm_scale is None:
@@ -344,8 +334,8 @@ def paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
     if interpret is None:
         interpret = _interpret_default()
     if use_pallas is None:
-        use_pallas = not interpret  # on-TPU default; reference on CPU
-    if use_pallas and _canary_int8():
+        use_pallas = _device.pallas_dispatch()  # reference off the TPU
+    if use_pallas:
         from . import autotune as _at
         sem = "parallel"
         if _at.enabled():

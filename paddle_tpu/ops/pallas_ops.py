@@ -25,10 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# older jax (< 0.5) spells pltpu.CompilerParams as TPUCompilerParams;
-# the kwargs we pass (dimension_semantics) exist under both names
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from ..framework import device as _device
 
 __all__ = ["flash_attention", "mha", "mha_reference"]
 
@@ -69,10 +66,7 @@ def _tile_keep_mask(seed, bh, qi, ki, block_q, block_k, p_drop):
 
 
 def _interpret_default() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    return not _device.on_tpu()
 
 
 def _sds(shape, dtype, like):
@@ -251,7 +245,7 @@ def _fwd(q, k, v, seed, lens, shift, *, causal, sm_scale, block_q,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*seed_args, q, k, v)
@@ -410,7 +404,7 @@ def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=_sds((bh, sq, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*seed_args, q, k, v, do, lse, delta)
@@ -441,7 +435,7 @@ def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*seed_args, q, k, v, do, lse, delta)
@@ -663,10 +657,8 @@ def tune_mha(q, k, v, *, causal=False, interpret=None,
     state = {"q": q}
 
     def run(cfg):
-        # thread the output back in (fresh inputs per call) and fence
-        # with a host readback: remote-device backends can both cache
-        # identical repeated executions and no-op block_until_ready,
-        # which would make every candidate time the same
+        # thread the output back in (fresh inputs per call); the host
+        # readback is the fence
         out = mha(state["q"], k, v, causal=causal, block_q=cfg[0],
                   block_k=cfg[1], interpret=interpret)
         state["q"] = (out.astype(jnp.float32) * 1e-3).astype(q.dtype)
@@ -749,8 +741,11 @@ def _packed_mask(pq_ref, okq_ref, off_ref, pk_ref, okk_ref, causal,
                  block_q, block_k):
     pq = pq_ref[:, :1]                        # (bq, 1) int32
     okq = okq_ref[:, :1] > 0
-    pk = pk_ref[:, 0][None, :]                # (1, bk)
-    okk = okk_ref[:, 0][None, :] > 0
+    # k-side metadata arrives as (1, bk) lane-major rows: a column ->
+    # row relayout in the kernel costs Mosaic ~100 MB of scoped VMEM at
+    # 512-blocks under the causal compare (v5e, PR 21)
+    pk = pk_ref[...]                          # (1, bk) int32
+    okk = okk_ref[...] > 0
     mask = jnp.logical_and(okq, okk)
     if causal:
         off = off_ref[:, :1]
@@ -926,7 +921,7 @@ def _pk_fwd(q, k, v, seed, meta, *, causal, sm_scale, block_q, block_k,
                                   seed, jnp.int32).reshape(-1),))
                              if p_drop > 0.0 else ([], ()))
     row_spec_q = pl.BlockSpec((block_q, 1), lambda h, i, j: (i, 0))
-    row_spec_k = pl.BlockSpec((block_k, 1), lambda h, i, j: (j, 0))
+    row_spec_k = pl.BlockSpec((1, block_k), lambda h, i, j: (0, j))
     out, lse = pl.pallas_call(
         functools.partial(_pk_fwd_kernel, causal=causal, sm_scale=sm_scale,
                           block_q=block_q, block_k=block_k, p_drop=p_drop),
@@ -953,11 +948,11 @@ def _pk_fwd(q, k, v, seed, meta, *, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*seed_args, klo, khi, q, k, v, pos_q[:, None], ok_q[:, None],
-      off_q[:, None], pos_k[:, None], ok_k[:, None])
+      off_q[:, None], pos_k[None, :], ok_k[None, :])
     return out, lse
 
 
@@ -974,7 +969,7 @@ def _pk_bwd(q, k, v, out, lse, do, seed, meta, *, causal, sm_scale,
                                   seed, jnp.int32).reshape(-1),))
                              if p_drop > 0.0 else ([], ()))
     row_q = pl.BlockSpec((block_q, 1), lambda h, i, j: (i, 0))
-    row_k = pl.BlockSpec((block_k, 1), lambda h, i, j: (j, 0))
+    row_k = pl.BlockSpec((1, block_k), lambda h, i, j: (0, j))
     dq = pl.pallas_call(
         functools.partial(_pk_bwd_dq_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
@@ -994,14 +989,14 @@ def _pk_bwd(q, k, v, out, lse, do, seed, meta, *, causal, sm_scale,
         out_specs=pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0)),
         out_shape=_sds((H, capq, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*seed_args, klo, khi, q, k, v, do, lse, delta, pos_q[:, None],
-      ok_q[:, None], off_q[:, None], pos_k[:, None], ok_k[:, None])
+      ok_q[:, None], off_q[:, None], pos_k[None, :], ok_k[None, :])
 
     row_q2 = pl.BlockSpec((block_q, 1), lambda h, j, i: (i, 0))
-    row_k2 = pl.BlockSpec((block_k, 1), lambda h, j, i: (j, 0))
+    row_k2 = pl.BlockSpec((1, block_k), lambda h, j, i: (0, j))
     dk, dv = pl.pallas_call(
         functools.partial(_pk_bwd_dkv_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
@@ -1030,11 +1025,11 @@ def _pk_bwd(q, k, v, out, lse, do, seed, meta, *, causal, sm_scale,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*seed_args, qlo, qhi, q, k, v, do, lse, delta, pos_q[:, None],
-      ok_q[:, None], off_q[:, None], pos_k[:, None], ok_k[:, None])
+      ok_q[:, None], off_q[:, None], pos_k[None, :], ok_k[None, :])
     return dq, dk, dv
 
 

@@ -19,9 +19,8 @@ Three layers, matching the house kernel conventions
  - **w8a16_matmul** — activations in 16/32-bit, weights int8, f32 MXU
    accumulation, per-out-channel scale applied in the epilogue (AFTER
    the dot — the AUD006 dequant-placement contract: the int8→wide
-   convert feeds exactly one ``dot_general``).  Pallas kernel on TPU,
-   canary-probed with a bit-defined XLA mirror fallback so CPU tier-1
-   proves the numerics.
+   convert feeds exactly one ``dot_general``).  Pallas kernel on TPU, a
+   bit-defined XLA mirror elsewhere so CPU tier-1 proves the numerics.
  - **autotune** — :func:`tune_w8a16_matmul` routes (block_m, block_n)
    through :mod:`.autotune` ``search`` with a ``KERNEL_SCHEMA`` entry,
    same as the other fused kernels.
@@ -33,8 +32,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_ops import _CompilerParams, _interpret_default, _ceil_to
+from ..framework import device as _device
+from .pallas_ops import _interpret_default, _ceil_to
 
 __all__ = ["quantize_weight", "dequantize_weight", "quantize_kv",
            "dequantize_kv", "w8a16_matmul", "w8a16_matmul_reference",
@@ -106,7 +107,7 @@ def dequantize_kv(q, scale):
 def w8a16_matmul_reference(x, w_q, scale):
     """XLA mirror: widen the int8 weight, f32 dot, scale in the
     epilogue.  This IS the serve-path numerics definition on CPU (the
-    canary falls back here), so the order of operations is pinned:
+    off-TPU dispatch lands here), so the order of operations is pinned:
     convert → one dot → per-column scale."""
     acc = jax.lax.dot_general(
         x.astype(jnp.float32), w_q.astype(jnp.float32),
@@ -140,32 +141,11 @@ def _w8a16_pallas(x, w_q, scale, *, block_m, block_n, interpret):
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda mi, ni: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xp, wp, sp)
     return out[:m, :n]
-
-
-_canary_ok = None
-
-
-def _canary():
-    """One-shot probe before trusting the kernel for dispatch — a
-    broken lowering degrades to the XLA mirror instead of poisoning
-    the serve path (the fused-kernel convention)."""
-    global _canary_ok
-    if _canary_ok is None:
-        try:
-            x = jnp.zeros((4, 16), jnp.float32)
-            q = jnp.zeros((16, 8), jnp.int8)
-            s = jnp.ones((8,), jnp.float32)
-            _w8a16_pallas(x, q, s, block_m=8, block_n=128,
-                          interpret=_interpret_default())
-            _canary_ok = True
-        except Exception:
-            _canary_ok = False
-    return _canary_ok
 
 
 def w8a16_matmul(x, w_q, scale, *, block_m=None, block_n=None,
@@ -184,9 +164,9 @@ def w8a16_matmul(x, w_q, scale, *, block_m=None, block_n=None,
     if interpret is None:
         interpret = _interpret_default()
     if use_pallas is None:
-        use_pallas = not interpret
+        use_pallas = _device.pallas_dispatch()
     lead = x.shape[:-1]
-    if use_pallas and _canary():
+    if use_pallas:
         from . import autotune as _at
         x2 = x.reshape(-1, x.shape[-1])
         if block_m is None or block_n is None:
@@ -253,8 +233,7 @@ def tune_w8a16_matmul(x, w_q, scale, *, interpret=None):
 
     def run(cfg):
         # fresh inputs per call + host readback fence (the tune_mha
-        # discipline: identical repeated executions can be cached and
-        # block_until_ready no-opped by remote backends)
+        # discipline)
         out = w8a16_matmul(state["x"], w_q, scale, block_m=int(cfg[0]),
                            block_n=int(cfg[1]), use_pallas=True,
                            interpret=interpret)

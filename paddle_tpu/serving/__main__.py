@@ -67,6 +67,9 @@ def main(argv=None):
               file=sys.stderr)
         return 2
 
+    from ..device import place_compile_cache
+    place_compile_cache()
+
     if not args.no_telemetry:
         from ..observability.telemetry import get_telemetry
         get_telemetry().enable()

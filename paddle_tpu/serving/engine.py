@@ -296,18 +296,21 @@ class ServingEngine:
         # stay comparable; other precisions are distinct programs
         sfx = "" if cfg.precision == "fp32" else f"_{cfg.precision}"
 
+        # the two jitted functions are named like the programs they build
+        # (serve_prefill_s<S> / serve_decode_b<B>), so jax's compile log,
+        # the compile watcher and profiler traces all say "serve_*"
         if int8:
             # the scale pools are donated state exactly like the value
             # pools — the step rewrites both and the engine rebinds all
             # four (donate_argnums covers 1..4)
-            def _pf(params, k_flat, v_flat, k_scale, v_scale, tokens,
-                    length, page_table):
+            def serve_prefill(params, k_flat, v_flat, k_scale, v_scale,
+                              tokens, length, page_table):
                 return prefill_step(spec, params, k_flat, v_flat, tokens,
                                     length, page_table, page_size=ps,
                                     k_scale=k_scale, v_scale=v_scale)
 
-            def _dec(params, k_flat, v_flat, k_scale, v_scale, tokens,
-                     positions, page_tables):
+            def serve_decode(params, k_flat, v_flat, k_scale, v_scale,
+                             tokens, positions, page_tables):
                 return decode_step(spec, params, k_flat, v_flat, tokens,
                                    positions, page_tables, page_size=ps,
                                    k_scale=k_scale, v_scale=v_scale)
@@ -317,12 +320,13 @@ class ServingEngine:
                       "tokens", "positions", "page_tables")
             kv_args = (k_struct, k_struct, s_struct, s_struct)
         else:
-            def _pf(params, k_flat, v_flat, tokens, length, page_table):
+            def serve_prefill(params, k_flat, v_flat, tokens, length,
+                              page_table):
                 return prefill_step(spec, params, k_flat, v_flat, tokens,
                                     length, page_table, page_size=ps)
 
-            def _dec(params, k_flat, v_flat, tokens, positions,
-                     page_tables):
+            def serve_decode(params, k_flat, v_flat, tokens, positions,
+                             page_tables):
                 return decode_step(spec, params, k_flat, v_flat, tokens,
                                    positions, page_tables, page_size=ps)
 
@@ -331,8 +335,8 @@ class ServingEngine:
                       "positions", "page_tables")
             kv_args = (k_struct, k_struct)
 
-        pf_jit = jax.jit(_pf, donate_argnums=donate)
-        dec_jit = jax.jit(_dec, donate_argnums=donate)
+        pf_jit = jax.jit(serve_prefill, donate_argnums=donate)
+        dec_jit = jax.jit(serve_decode, donate_argnums=donate)
 
         # graph audit (tools/audit): when enabled, every bucket
         # program's traced jaxpr is audited during the build — load

@@ -31,7 +31,7 @@ import dataclasses
 import os
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 __all__ = ["Finding", "AuditProgram", "walk_jaxprs", "GraphView",
            "audit_disabled_rules", "run_rules", "sort_findings"]

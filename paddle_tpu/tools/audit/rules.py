@@ -446,7 +446,8 @@ class HostTransfer(Rule):
                  "tpu-lint TPU019")
 
     _HOST_PRIMS = frozenset(("pure_callback", "io_callback",
-                             "debug_callback", "infeed", "outfeed"))
+                             "debug_callback", "debug_print", "infeed",
+                             "outfeed"))
 
     def check(self, prog: AuditProgram) -> List[Finding]:
         severity = "error" if prog.kind == "serve" else "warning"

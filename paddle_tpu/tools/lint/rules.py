@@ -636,12 +636,11 @@ class RawPallasCall(Rule):
     name = "raw-pallas-call-outside-ops"
     rationale = ("direct pl.pallas_call outside paddle_tpu/ops/ bypasses "
                  "the kernel dispatch layer — the use_pallas_kernels "
-                 "flag, the one-time lowering canary with XLA fallback, "
+                 "flag, the platform gate (interpret mode off the TPU) "
                  "and the autotuner cache all live there; a raw call "
-                 "site can't be switched off, falls over instead of "
-                 "falling back when Mosaic rejects the kernel, and runs "
-                 "with unsearched launch configs. Wrap the kernel in "
-                 "paddle_tpu/ops/ and dispatch through nn.functional")
+                 "site can't be switched off and runs with unsearched "
+                 "launch configs. Wrap the kernel in paddle_tpu/ops/ "
+                 "and dispatch through nn.functional")
 
     _PALLAS_CALLS = {"pl.pallas_call", "pallas_call",
                      "pallas.pallas_call",
@@ -655,7 +654,7 @@ class RawPallasCall(Rule):
                        "raw pallas_call outside paddle_tpu/ops/; move "
                        "the kernel into paddle_tpu/ops/ and route "
                        "callers through the dispatch layer (flag + "
-                       "fallback canary + autotuner)")
+                       "platform gate + autotuner)")
 
 
 @register

@@ -189,16 +189,15 @@ class TestFusedSoftmaxXent:
 
 
 # ---------------------------------------------------------------------------
-# framework dispatch (flag + canary gate, XLA fallback)
+# framework dispatch (flag + platform gate)
 # ---------------------------------------------------------------------------
 def _force_cpu_dispatch(monkeypatch):
-    """Force the TPU-only gate open on CPU: the canary verdicts are
-    pinned True and _on_tpu patched, so the fused path runs in interpret
-    mode (the tests' stand-in for real hardware)."""
-    from paddle_tpu.nn.functional import common
-    monkeypatch.setitem(common._CANARY_CACHE, "fused_layer_norm", True)
-    monkeypatch.setitem(common._CANARY_CACHE, "fused_softmax_xent", True)
-    monkeypatch.setattr(common, "_on_tpu", lambda: True)
+    """Force the TPU-only gate open on CPU: the platform predicate is
+    patched while the kernels stay in interpret mode (the tests'
+    stand-in for real hardware)."""
+    from paddle_tpu.framework import device
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    monkeypatch.setattr(fk, "_interpret_default", lambda: True)
 
 
 @pytest.fixture
@@ -216,6 +215,35 @@ def fresh_metrics():
 
 
 class TestDispatch:
+
+    def test_mesh_rule_mirrors_what_mosaic_can_lower(self, monkeypatch):
+        """GSPMD cannot partition a Mosaic kernel (jax refuses the
+        lowering), so dispatch selects Pallas only in single-device
+        programs and in shard_map bodies manual over every mesh axis."""
+        from jax.sharding import Mesh, PartitionSpec as P
+        from paddle_tpu.framework import device
+        monkeypatch.setattr(device, "on_tpu", lambda: True)
+        seen = {}
+
+        def note(tag, x):
+            seen[tag] = device.pallas_dispatch()
+            return x
+
+        x = jnp.ones(4)
+        assert device.pallas_dispatch()            # no mesh at all
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+        with jax.set_mesh(mesh):
+            jax.jit(lambda a: note("gspmd", a))(x)
+            jax.jit(jax.shard_map(lambda a: note("manual", a), mesh=mesh,
+                                  in_specs=P("dp"), out_specs=P("dp")))(x)
+            jax.jit(jax.shard_map(lambda a: note("partial", a), mesh=mesh,
+                                  in_specs=P("dp"), out_specs=P("dp"),
+                                  axis_names={"dp"}))(x)
+        one = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "mp"))
+        with jax.set_mesh(one):
+            jax.jit(lambda a: note("one_device", a))(x)
+        assert seen == {"gspmd": False, "manual": True, "partial": False,
+                        "one_device": True}
 
     def test_layer_norm_picks_up_fused(self, monkeypatch, fresh_metrics):
         import paddle_tpu as pt
@@ -389,8 +417,9 @@ class TestAutotuneSearch:
 
     def test_roofline_ordering(self):
         # compute-bound vs bandwidth-bound: the max() of the two sides
-        assert at.roofline_seconds(at.PEAK_FLOPS, 0.0) == pytest.approx(1.0)
-        assert at.roofline_seconds(0.0, at.HBM_BW) == pytest.approx(1.0)
+        peak, bw = at.device_peaks()
+        assert at.roofline_seconds(peak, 0.0) == pytest.approx(1.0)
+        assert at.roofline_seconds(0.0, bw) == pytest.approx(1.0)
 
     def test_analytic_seed_from_cost_model(self):
         seed = at.analytic_seed(

@@ -239,7 +239,7 @@ class TestNearMisses:
 
 # ---------------------------------------------------------------------------
 # rewritten vs unrewritten parity (CPU: every cluster dispatches to the
-# inline XLA mirror, reason tpu_unreachable)
+# inline XLA mirror, reason not_tpu)
 # ---------------------------------------------------------------------------
 class TestRewriteParity:
 
@@ -255,7 +255,7 @@ class TestRewriteParity:
         s = fp.summary()
         assert s["rewrites"] == {"layer_norm": 1, "matmul_bias_gelu": 1,
                                  "residual_ln": 1}
-        assert all(k.endswith(":tpu_unreachable")
+        assert all(k.endswith(":not_tpu")
                    for k in s["fallbacks"])
         assert float(jnp.max(jnp.abs(base - fused))) <= 1e-5
 
@@ -330,7 +330,7 @@ class TestTelemetry:
         after = tel.snapshot()["fusion"]
         assert after["rewrites"].get("layer_norm", 0) == \
             before["rewrites"].get("layer_norm", 0) + 1
-        key = "layer_norm:tpu_unreachable"
+        key = "layer_norm:not_tpu"
         assert after["fallbacks"].get(key, 0) == \
             before["fallbacks"].get(key, 0) + 1
 

@@ -306,7 +306,7 @@ def test_rules_detect_hazards_in_nested_bodies():
     hits = fired(audit(AuditProgram("nested", jx, kind="serve")),
                  "AUD004")
     assert hits
-    assert "pjit" in hits[0].message
+    assert "inside jit" in hits[0].message
 
 
 def test_crashing_rule_becomes_finding_not_exception():

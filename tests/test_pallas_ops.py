@@ -358,10 +358,10 @@ def test_sdpa_flash_min_seq_gate(monkeypatch):
     808 vs 750 seq/s) and route long ones to the kernel."""
     import paddle_tpu as pt
     import paddle_tpu.nn.functional.common as C
+    from paddle_tpu.framework import device
 
     calls = []
-    monkeypatch.setattr(C, "_on_tpu", lambda: True)
-    monkeypatch.setattr(C, "_flash_usable", lambda: True)
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
 
     import paddle_tpu.ops.pallas_ops as po
     real_fa = po.flash_attention
@@ -553,116 +553,9 @@ def test_packed_varlen_fast_guard():
         _validate_cu(np.array([0, 20, 10], np.int32), 14, "cu_seqlens_k")
 
 
-class TestPackedFallback:
-    """The padded-XLA fallback behind ``_packed_usable`` must match the
-    packed kernel exactly — it is what a jitted train step silently
-    drops to when the kernel cannot lower on real TPU."""
-
-    def _force_fallback(self, monkeypatch):
-        from paddle_tpu.nn.functional import flash_attention as fa_mod
-        from paddle_tpu.ops import pallas_ops
-        from paddle_tpu.nn.functional import common
-        monkeypatch.setattr(pallas_ops, "_interpret_default", lambda: False)
-        monkeypatch.setattr(common, "_on_tpu", lambda: False)
-        # the canary verdict must not leak between forced/unforced runs
-        monkeypatch.setattr(common, "_CANARY_CACHE", {})
-        del fa_mod  # gate lives in common's shared cache now
-
-    def _run(self, causal):
-        import paddle_tpu as pt
-        from paddle_tpu.nn.functional import flash_attn_unpadded
-        rs = np.random.RandomState(11)
-        H, D = 2, 32
-        cu = np.cumsum([0, 12, 20, 7]).astype(np.int32)
-        cuk = np.cumsum([0, 16, 10, 7]).astype(np.int32)
-        q = rs.randn(int(cu[-1]), H, D).astype(np.float32)
-        k = rs.randn(int(cuk[-1]), H, D).astype(np.float32)
-        v = rs.randn(int(cuk[-1]), H, D).astype(np.float32)
-        out, _ = flash_attn_unpadded(
-            pt.to_tensor(q), pt.to_tensor(k), pt.to_tensor(v),
-            pt.to_tensor(cu), pt.to_tensor(cuk), 20, 16,
-            scale=1.0 / np.sqrt(D), causal=causal)
-        return out.numpy()
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("causal", [False, True])
-    def test_fallback_matches_kernel(self, monkeypatch, causal):
-        want = self._run(causal)           # kernel (interpret) path
-        self._force_fallback(monkeypatch)
-        got = self._run(causal)            # padded-XLA fallback path
-        np.testing.assert_allclose(got, want, atol=2e-3, rtol=2e-3)
-
-    @pytest.mark.slow
-    def test_fallback_grads_finite(self, monkeypatch):
-        self._force_fallback(monkeypatch)
-        import paddle_tpu as pt
-        from paddle_tpu.nn.functional import flash_attn_unpadded
-        from paddle_tpu import Tensor
-        rs = np.random.RandomState(12)
-        cu = np.cumsum([0, 9, 15]).astype(np.int32)
-        q = Tensor(rs.randn(int(cu[-1]), 1, 16).astype(np.float32),
-                   stop_gradient=False)
-        out, _ = flash_attn_unpadded(q, q, q, pt.to_tensor(cu),
-                                     pt.to_tensor(cu), 15, 15, scale=0.25,
-                                     causal=True)
-        pt.sum(out * out).backward()
-        assert q.grad is not None
-        assert np.isfinite(np.asarray(q.grad._data)).all()
-
-    @pytest.mark.slow
-    def test_fallback_dropout_scales(self, monkeypatch):
-        self._force_fallback(monkeypatch)
-        import paddle_tpu as pt
-        from paddle_tpu.nn.functional import flash_attn_unpadded
-        rs = np.random.RandomState(13)
-        cu = np.cumsum([0, 64]).astype(np.int32)
-        q = rs.randn(64, 1, 16).astype(np.float32)
-        out, _ = flash_attn_unpadded(
-            pt.to_tensor(q), pt.to_tensor(q), pt.to_tensor(q),
-            pt.to_tensor(cu), pt.to_tensor(cu), 64, 64, scale=0.25,
-            dropout=0.5, training=True)
-        a = out.numpy()
-        assert np.isfinite(a).all()
-        # dropout must actually do something (some outputs differ from
-        # the deterministic run)
-        det, _ = flash_attn_unpadded(
-            pt.to_tensor(q), pt.to_tensor(q), pt.to_tensor(q),
-            pt.to_tensor(cu), pt.to_tensor(cu), 64, 64, scale=0.25,
-            dropout=0.0)
-        assert np.abs(a - det.numpy()).max() > 1e-4
-
-
-def test_fallback_matches_oracle_fast(monkeypatch):
-    """FAST-tier guard for the padded-XLA fallback: tiny shapes, no
-    kernel (interpret-mode pallas is what makes the parity suite slow
-    — that cross-check lives in the slow tier)."""
-    import paddle_tpu as pt
-    from paddle_tpu.nn.functional import flash_attn_unpadded
-    TestPackedFallback()._force_fallback(monkeypatch)
-    rs = np.random.RandomState(21)
-    H, D = 1, 16
-    cu = np.cumsum([0, 6, 10]).astype(np.int32)
-    q = rs.randn(int(cu[-1]), H, D).astype(np.float32)
-    out, _ = flash_attn_unpadded(
-        pt.to_tensor(q), pt.to_tensor(q), pt.to_tensor(q),
-        pt.to_tensor(cu), pt.to_tensor(cu), 10, 10, scale=1.0 / np.sqrt(D),
-        causal=True)
-    outs = []
-    for b in range(2):
-        s_, e_ = int(cu[b]), int(cu[b + 1])
-        qq = q[s_:e_, 0]
-        lg = qq @ qq.T / np.sqrt(D)
-        lg = np.where(np.tril(np.ones_like(lg, dtype=bool)), lg, -1e30)
-        p_ = np.exp(lg - lg.max(-1, keepdims=True))
-        p_ /= p_.sum(-1, keepdims=True)
-        outs.append((p_ @ qq)[:, None, :])
-    np.testing.assert_allclose(out.numpy(), np.concatenate(outs),
-                               atol=2e-3, rtol=2e-3)
-
-
 def test_unpadded_rejects_understated_max_seqlen():
-    """max_seqlen is load-bearing on the fallback path — understating it
-    must raise eagerly on BOTH paths, not silently truncate."""
+    """Understating max_seqlen must raise eagerly, not silently
+    truncate."""
     import paddle_tpu as pt
     from paddle_tpu.nn.functional import flash_attn_unpadded
     rs = np.random.RandomState(3)

@@ -287,6 +287,9 @@ def test_peak_flops_prefix_matching():
     assert peak_flops("cpu") == PEAK_FLOPS["cpu"]
     assert peak_flops("Banana9000") is None
     assert peak_flops(None) is None
+    # measurement paths ask strictly: an unknown device is an error
+    with pytest.raises(KeyError, match="Banana9000"):
+        peak_flops("Banana9000", strict=True)
 
 
 def test_program_flops_and_mfu_on_cpu_jit():
