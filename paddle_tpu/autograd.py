@@ -25,6 +25,7 @@ from collections import deque
 
 import numpy as np
 import jax
+from jax._src import source_info_util as _source_info
 
 from .framework import flags as _flags
 
@@ -115,10 +116,16 @@ class Node:
              (bench_eager.py measures it). PyLayer / functional_call nodes
              still pass an explicit vjp_fn.
     outputs: weakrefs to produced Tensors (to locate incoming cotangents).
+    scope:   jax's name stack (``jax.named_scope``) at record time.  The
+             deferred ``jax.vjp`` runs at ``loss.backward()``, outside
+             every layer's scope; :meth:`pullback` re-enters this one, so
+             a backward operation carries its forward's scope in HLO op
+             names and profiler traces.
     """
 
     __slots__ = ("inputs", "vjp_fn", "fn", "datas", "out_refs", "out_avals",
-                 "name", "_hooks", "_released", "_unpack", "__weakref__")
+                 "name", "scope", "_hooks", "_released", "_unpack",
+                 "__weakref__")
 
     def __init__(self, inputs, vjp_fn, outputs, name="", fn=None,
                  datas=None):
@@ -129,6 +136,7 @@ class Node:
         self.out_refs = [weakref.ref(t) for t in outputs]
         self.out_avals = [(t.shape, t._data.dtype) for t in outputs]
         self.name = name
+        self.scope = _name_stack()
         self._hooks = None
         self._released = False
         self._unpack = None
@@ -138,6 +146,12 @@ class Node:
             raise RuntimeError(
                 "Trying to backward through the graph a second time; "
                 "set retain_graph=True if you need to.")
+        if self.scope.stack:
+            with _source_info.set_name_stack(self.scope):
+                return self._pullback(cot)
+        return self._pullback(cot)
+
+    def _pullback(self, cot):
         if self.vjp_fn is None:
             # deferred trace: input arrays were captured at record time, so
             # later in-place rebinds of the input Tensors don't corrupt it
@@ -161,6 +175,7 @@ _static_recorder = None
 _STATIC_SENTINEL = None
 
 _node_new = Node.__new__
+_name_stack = _source_info.current_name_stack
 _flag_values = _flags._values  # direct dict ref for the per-op hot path
 _wref = weakref.ref
 
@@ -336,6 +351,7 @@ def record(fn, tensors, outputs_wrap, name=""):
             d = t._data
             node.out_avals = ((d.shape, d.dtype),)
             node.name = name
+            node.scope = _name_stack()
             node._hooks = None
             node._released = False
             node._unpack = None
@@ -359,6 +375,7 @@ def record(fn, tensors, outputs_wrap, name=""):
         node.out_avals = [(t._data.shape, t._data.dtype)
                           for t in out_tensors]
         node.name = name
+        node.scope = _name_stack()
         node._hooks = None
         node._released = False
         node._unpack = None

@@ -257,8 +257,10 @@ class GPTForCausalLM(Layer):
         if self.tie:
             from ...ops.op_utils import nary
             w = self.gpt.embeddings.word_embeddings.weight
-            logits = nary(lambda h, wt: jnp.einsum("bsh,vh->bsv", h, wt),
-                          [x, w], name="lm_head_tied")
+            with jax.named_scope("lm_head"):
+                logits = nary(
+                    lambda h, wt: jnp.einsum("bsh,vh->bsv", h, wt),
+                    [x, w], name="lm_head_tied")
         else:
             logits = self.lm_head(x)
         return logits
@@ -266,6 +268,8 @@ class GPTForCausalLM(Layer):
 
 class GPTPretrainingCriterion(Layer):
     """Causal-LM loss over (possibly vocab-sharded) logits."""
+
+    _scope_name = "loss"
 
     def __init__(self, cfg: GPTConfig | None = None):
         super().__init__()

@@ -128,9 +128,10 @@ def _tel():
     return get_telemetry()
 
 
-def _tracer():
-    from ..observability.trace import get_tracer
-    return get_tracer()
+def _span(name, cat):
+    from ..observability.trace import get_tracer, span
+    get_tracer()    # PT_TRACE / PT_FLIGHT_RECORDER take effect on first use
+    return span(name, cat=cat)
 
 
 class _LiveState:
@@ -145,7 +146,7 @@ class _LiveState:
 
 class _Entry:
     __slots__ = ("jitted", "struct", "traced_idx", "sg_flags", "statics",
-                 "n_leaves", "sig", "name", "ran", "flops", "fusion",
+                 "n_leaves", "sig", "name", "ran", "fusion",
                  "memory", "monitored", "monitor_names", "sdc",
                  "sdc_names", "pure", "audit")
 
@@ -503,7 +504,6 @@ class CapturedStep:
         entry.sig = sig
         entry.name = pure.__name__
         entry.ran = False
-        entry.flops = None
         entry.fusion = None
         entry.memory = None
         entry.monitored = mon is not None
@@ -524,40 +524,38 @@ class CapturedStep:
         # lr-schedule change never retraces (train_step.py pattern)
         lrs = [float(opt.get_lr()) for opt in st.opts]
         call = entry.jitted
-        tr = _tracer()
-        was_compile = not entry.ran
-        if not entry.ran:
-            if tr.enabled and entry.flops is None:
-                # analytic MFU source: cost_analysis() at compile time,
-                # while the donated input arrays are still live. The AOT
-                # lower+compile is redundant with the call below but its
-                # XLA compile is cache-shared, and it only happens once
-                # per signature — the replay hot path never pays it.
-                from ..observability.trace import program_flops
-                entry.flops = program_flops(
-                    call, st.params, st.buffers, st.opt_states, st.rng_ctr,
-                    lrs, traced)
-                if entry.flops:
-                    tr.record_program_flops(entry.name, entry.flops)
+        # dispatch-side span: async under jax, so this is dispatch + any
+        # implicit materialization, never a forced device sync.  The
+        # first call is dominated by trace+compile and is billed as such
+        # — the goodput ledger classifies it as overhead, not productive
+        # compute.
+        if entry.ran:
+            with _span(entry.name, cat="compute"):
+                try:
+                    outs = call(st.params, st.buffers, st.opt_states,
+                                st.rng_ctr, lrs, traced)
+                except Exception as e:
+                    self._book_oom(entry, e)
+                    raise
+        else:
             from ..observability import memory as _memory
             _mm = _memory.get_memory_monitor()
             if _mm.enabled and entry.memory is None:
                 # compile-time footprint + pre-flight fit check:
-                # memory_analysis() harvested beside the FLOPs, from
-                # the same cache-shared AOT compile, BEFORE the first
-                # replay below can discover an unfit program as a raw
-                # RESOURCE_EXHAUSTED
+                # memory_analysis() from an AOT compile (cache-shared
+                # with the call below), BEFORE the first replay can
+                # discover an unfit program as a raw RESOURCE_EXHAUSTED
                 entry.memory = _mm.harvest_program(
                     entry.name, call, st.params, st.buffers,
                     st.opt_states, st.rng_ctr, lrs, traced)
             from ..ops import fusion_pass as _fusion
             fusion_before = _fusion.summary()["rewrites"]
-            with warnings.catch_warnings():
+            with warnings.catch_warnings(), \
+                    _span(f"compile:{entry.name}", cat="host"):
                 # backends without donation (cpu) warn once at compile;
                 # the annotation is still correct where it counts
                 warnings.filterwarnings(
                     "ignore", message="Some donated buffers were not usable")
-                t0 = time.perf_counter_ns()
                 try:
                     outs = call(st.params, st.buffers, st.opt_states,
                                 st.rng_ctr, lrs, traced)
@@ -587,8 +585,8 @@ class CapturedStep:
             if entry.audit is None:
                 # graph audit (tools/audit): static findings over the
                 # pre-fusion step jaxpr, harvested once per signature
-                # in the same compile-time window as the FLOPs/memory
-                # passes above — the replay hot path never pays it
+                # in the same compile-time window as the memory pass
+                # above — the replay hot path never pays it
                 from ..tools.audit import runtime as _audit_rt
                 if _audit_rt.audit_enabled():
                     entry.audit = _audit_rt.audit_captured_step(
@@ -596,26 +594,6 @@ class CapturedStep:
                         st.rng_ctr, lrs, traced)
                 else:
                     entry.audit = ()
-        else:
-            t0 = time.perf_counter_ns()
-            try:
-                outs = call(st.params, st.buffers, st.opt_states,
-                            st.rng_ctr, lrs, traced)
-            except Exception as e:
-                self._book_oom(entry, e)
-                raise
-        if tr.enabled:
-            # dispatch-side span: async under jax, so this is dispatch +
-            # any implicit materialization, never a forced device sync.
-            # The first call is dominated by trace+compile and is billed
-            # as such — the goodput ledger classifies it as overhead,
-            # not productive compute.
-            if was_compile:
-                tr.record_span(f"compile:{entry.name}", "host", t0,
-                               time.perf_counter_ns())
-            else:
-                tr.record_span(entry.name, "compute", t0,
-                               time.perf_counter_ns())
         step_idx = st.rng_ctr
         st.rng_ctr += 1
         outs = list(outs)

@@ -42,6 +42,8 @@ class Sequential(Layer):
 
 
 class LayerList(Layer):
+    _scope_transparent = True
+
     def __init__(self, sublayers=None):
         super().__init__()
         if sublayers is not None:
@@ -56,7 +58,7 @@ class LayerList(Layer):
 
     def __setitem__(self, idx, layer):
         keys = list(self._sub_layers)
-        self._sub_layers[keys[idx]] = layer
+        self.add_sublayer(keys[idx], layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -73,7 +75,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for l in layers:
@@ -104,6 +106,8 @@ class ParameterList(Layer):
 
 
 class LayerDict(Layer):
+    _scope_transparent = True
+
     def __init__(self, sublayers=None):
         super().__init__()
         if sublayers is not None:

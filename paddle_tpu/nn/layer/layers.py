@@ -15,6 +15,7 @@ import collections
 from typing import Callable, Iterator
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ...tensor import Tensor, Parameter
@@ -120,7 +121,23 @@ class Layer:
         raise NotImplementedError(
             f"{type(self).__name__} must implement forward()")
 
+    # the name this layer was registered under in its parent (attribute
+    # name, container index); loss layers default to "loss".  __call__
+    # runs under jax.named_scope(<it>), so nesting spells the layer's
+    # path in HLO op names and profiler traces ("gpt/layers/3/attn/...").
+    # A root layer has none and adds no scope.  A container that is never
+    # called itself (LayerList, LayerDict) is transparent: its children
+    # carry "<its name>/<their key>".
+    _scope_name = None
+    _scope_transparent = False
+
     def __call__(self, *inputs, **kwargs):
+        if self._scope_name is None:
+            return self._call_impl(inputs, kwargs)
+        with jax.named_scope(self._scope_name):
+            return self._call_impl(inputs, kwargs)
+
+    def _call_impl(self, inputs, kwargs):
         for hook in list(self._forward_pre_hooks.values()):
             result = hook(self, inputs)
             if result is not None:
@@ -164,6 +181,7 @@ class Layer:
         if sublayer is not None and not isinstance(sublayer, Layer):
             raise TypeError("add_sublayer expects a Layer or None")
         self._sub_layers[name] = sublayer
+        _set_scope_name(sublayer, name, self)
         return sublayer
 
     # -- attribute routing ---------------------------------------------------
@@ -181,6 +199,7 @@ class Layer:
                 raise RuntimeError("call Layer.__init__ before assigning layers")
             _remove_from(name, params, buffers, self.__dict__)
             layers[name] = value
+            _set_scope_name(value, name, self)
         elif params is not None and name in params:
             params[name] = value
         elif layers is not None and name in layers:
@@ -400,6 +419,19 @@ class Layer:
     def clear_gradients(self):
         for p in self.parameters():
             p.clear_grad()
+
+
+def _set_scope_name(layer, name, parent):
+    """Stamp ``layer`` with the name ``parent`` registered it under."""
+    if layer is None:
+        return
+    name = str(name)
+    if parent._scope_transparent and parent._scope_name:
+        name = f"{parent._scope_name}/{name}"
+    layer.__dict__["_scope_name"] = name
+    if layer._scope_transparent:
+        for key, sub in layer._sub_layers.items():
+            _set_scope_name(sub, key, layer)
 
 
 def _remove_from(name, *dicts):

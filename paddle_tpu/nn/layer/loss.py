@@ -14,7 +14,15 @@ __all__ = ["CrossEntropyLoss", "MSELoss", "L1Loss", "NLLLoss", "BCELoss",
            "RNNTLoss"]
 
 
-class CrossEntropyLoss(Layer):
+class _Loss(Layer):
+    """Base of the loss layers: called at the top of a step, outside any
+    parent layer, they run under the named scope ``loss`` (see
+    ``Layer._scope_name``) so a trace can tell the loss from the model."""
+
+    _scope_name = "loss"
+
+
+class CrossEntropyLoss(_Loss):
     def __init__(self, weight=None, ignore_index=-100, reduction="mean",
                  soft_label=False, axis=-1, use_softmax=True,
                  label_smoothing=0.0, name=None):
@@ -36,7 +44,7 @@ class CrossEntropyLoss(Layer):
                                label_smoothing=self.label_smoothing)
 
 
-class MSELoss(Layer):
+class MSELoss(_Loss):
     def __init__(self, reduction="mean"):
         super().__init__()
         self.reduction = reduction
@@ -45,7 +53,7 @@ class MSELoss(Layer):
         return F.mse_loss(input, label, self.reduction)
 
 
-class L1Loss(Layer):
+class L1Loss(_Loss):
     def __init__(self, reduction="mean", name=None):
         super().__init__()
         self.reduction = reduction
@@ -54,7 +62,7 @@ class L1Loss(Layer):
         return F.l1_loss(input, label, self.reduction)
 
 
-class NLLLoss(Layer):
+class NLLLoss(_Loss):
     def __init__(self, weight=None, ignore_index=-100, reduction="mean",
                  name=None):
         super().__init__()
@@ -67,7 +75,7 @@ class NLLLoss(Layer):
                           self.reduction)
 
 
-class BCELoss(Layer):
+class BCELoss(_Loss):
     def __init__(self, weight=None, reduction="mean", name=None):
         super().__init__()
         self.weight = weight
@@ -78,7 +86,7 @@ class BCELoss(Layer):
                                       self.reduction)
 
 
-class BCEWithLogitsLoss(Layer):
+class BCEWithLogitsLoss(_Loss):
     def __init__(self, weight=None, reduction="mean", pos_weight=None,
                  name=None):
         super().__init__()
@@ -91,7 +99,7 @@ class BCEWithLogitsLoss(Layer):
             logit, label, self.weight, self.reduction, self.pos_weight)
 
 
-class SmoothL1Loss(Layer):
+class SmoothL1Loss(_Loss):
     def __init__(self, reduction="mean", delta=1.0, name=None):
         super().__init__()
         self.reduction = reduction
@@ -101,7 +109,7 @@ class SmoothL1Loss(Layer):
         return F.smooth_l1_loss(input, label, self.reduction, self.delta)
 
 
-class KLDivLoss(Layer):
+class KLDivLoss(_Loss):
     def __init__(self, reduction="mean", log_target=False):
         super().__init__()
         self.reduction = reduction
@@ -111,7 +119,7 @@ class KLDivLoss(Layer):
         return F.kl_div(input, label, self.reduction, self.log_target)
 
 
-class MarginRankingLoss(Layer):
+class MarginRankingLoss(_Loss):
     def __init__(self, margin=0.0, reduction="mean", name=None):
         super().__init__()
         self.margin = margin
@@ -122,7 +130,7 @@ class MarginRankingLoss(Layer):
                                      self.reduction)
 
 
-class CTCLoss(Layer):
+class CTCLoss(_Loss):
     def __init__(self, blank=0, reduction="mean"):
         super().__init__()
         self.blank = blank
@@ -134,7 +142,7 @@ class CTCLoss(Layer):
                           self.blank, self.reduction, norm_by_times)
 
 
-class HingeEmbeddingLoss(Layer):
+class HingeEmbeddingLoss(_Loss):
     def __init__(self, margin=1.0, reduction="mean", name=None):
         super().__init__()
         self.margin = margin
@@ -145,7 +153,7 @@ class HingeEmbeddingLoss(Layer):
                                       self.reduction)
 
 
-class CosineEmbeddingLoss(Layer):
+class CosineEmbeddingLoss(_Loss):
     def __init__(self, margin=0.0, reduction="mean", name=None):
         super().__init__()
         self.margin = margin
@@ -156,7 +164,7 @@ class CosineEmbeddingLoss(Layer):
                                        self.reduction)
 
 
-class TripletMarginLoss(Layer):
+class TripletMarginLoss(_Loss):
     def __init__(self, margin=1.0, p=2.0, epsilon=1e-6, swap=False,
                  reduction="mean", name=None):
         super().__init__()
@@ -167,7 +175,7 @@ class TripletMarginLoss(Layer):
         return F.triplet_margin_loss(input, positive, negative, m, p, e, s, r)
 
 
-class TripletMarginWithDistanceLoss(Layer):
+class TripletMarginWithDistanceLoss(_Loss):
     def __init__(self, distance_function=None, margin=1.0, swap=False,
                  reduction="mean", name=None):
         super().__init__()
@@ -182,7 +190,7 @@ class TripletMarginWithDistanceLoss(Layer):
             self.swap, self.reduction)
 
 
-class SoftMarginLoss(Layer):
+class SoftMarginLoss(_Loss):
     def __init__(self, reduction="mean", name=None):
         super().__init__()
         self.reduction = reduction
@@ -191,7 +199,7 @@ class SoftMarginLoss(Layer):
         return F.soft_margin_loss(input, label, self.reduction)
 
 
-class MultiLabelSoftMarginLoss(Layer):
+class MultiLabelSoftMarginLoss(_Loss):
     def __init__(self, weight=None, reduction="mean", name=None):
         super().__init__()
         self.weight = weight
@@ -202,7 +210,7 @@ class MultiLabelSoftMarginLoss(Layer):
                                               self.reduction)
 
 
-class PoissonNLLLoss(Layer):
+class PoissonNLLLoss(_Loss):
     def __init__(self, log_input=True, full=False, epsilon=1e-8,
                  reduction="mean", name=None):
         super().__init__()
@@ -213,7 +221,7 @@ class PoissonNLLLoss(Layer):
         return F.poisson_nll_loss(input, label, li, f, e, r)
 
 
-class GaussianNLLLoss(Layer):
+class GaussianNLLLoss(_Loss):
     def __init__(self, full=False, epsilon=1e-6, reduction="mean", name=None):
         super().__init__()
         self.args = (full, epsilon, reduction)
@@ -223,7 +231,7 @@ class GaussianNLLLoss(Layer):
         return F.gaussian_nll_loss(input, label, variance, f, e, r)
 
 
-class SigmoidFocalLoss(Layer):
+class SigmoidFocalLoss(_Loss):
     def __init__(self, alpha=0.25, gamma=2.0, normalizer=None,
                  reduction="sum", name=None):
         super().__init__()
@@ -234,7 +242,7 @@ class SigmoidFocalLoss(Layer):
         return F.sigmoid_focal_loss(logit, label, n, a, g, r)
 
 
-class HSigmoidLoss(Layer):
+class HSigmoidLoss(_Loss):
     """Hierarchical sigmoid (ref ``layer/loss.py HSigmoidLoss``): owns the
     [num_classes-1, feature] node weights; see F.hsigmoid_loss for the
     tree encoding."""
@@ -266,7 +274,7 @@ class HSigmoidLoss(Layer):
                                is_sparse=self.is_sparse)
 
 
-class MultiMarginLoss(Layer):
+class MultiMarginLoss(_Loss):
     def __init__(self, p=1, margin=1.0, weight=None, reduction="mean",
                  name=None):
         super().__init__()
@@ -281,7 +289,7 @@ class MultiMarginLoss(Layer):
                                    reduction=self.reduction)
 
 
-class RNNTLoss(Layer):
+class RNNTLoss(_Loss):
     """RNN-Transducer loss layer over the functional ``F.rnnt_loss``
     (ref ``layer/loss.py RNNTLoss``)."""
 

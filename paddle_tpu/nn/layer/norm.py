@@ -108,7 +108,7 @@ class SyncBatchNorm(_BatchNormBase):
             out.register_buffer("_mean", layer._mean)
             out.register_buffer("_variance", layer._variance)
         for name, sub in list(layer._sub_layers.items()):
-            layer._sub_layers[name] = cls.convert_sync_batchnorm(sub)
+            layer.add_sublayer(name, cls.convert_sync_batchnorm(sub))
         return out
 
 

@@ -27,7 +27,12 @@ stays clean (unless the user had the config on already).  The
 :class:`RecompileSentinel` is the dynamic twin of lint rule TPU001's
 retrace-storm heuristics: N compiles of the SAME callable with N
 distinct signatures means shape/weak-type churn, and it names the
-offender at runtime.
+offender at runtime.  The log lines give names, not durations: beside
+the filter the watcher listens to jax's own ``jax.monitoring`` events
+and books what each compile stage cost
+(``pt_compile_seconds_total{stage=trace|lower|backend_compile|cache_load}``)
+and whether the persistent cache had the program
+(``pt_compile_cache_total{result=hit|miss}``).
 
 Enable explicitly (``configure(enabled=True, ...)``) or via env:
 ``PT_TELEMETRY=1`` [+ ``PT_TELEMETRY_DIR``, ``PT_METRICS_PORT``],
@@ -57,6 +62,19 @@ _TRUTHY = {"1", "true", "yes", "on"}
 
 # loggers jax emits per-compile records on (jit/pjit path + dispatch)
 _JAX_COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
+
+# jax.monitoring duration events -> pt_compile_seconds_total{stage}
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+# jax.monitoring plain events -> pt_compile_cache_total{result}
+_COMPILE_CACHE_RESULTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
 
 
 def _env_flag(name):
@@ -167,18 +185,26 @@ class _CompileLogFilter:
 
 
 class CompileWatcher:
-    """Hooks jax's compile path via ``jax_log_compiles`` + log filters.
+    """Hooks jax's compile path via ``jax_log_compiles`` + log filters,
+    and ``jax.monitoring`` listeners for the stage durations.
 
     Install is lazy and idempotent: a no-op until jax has been imported
     by someone else (telemetry never imports jax itself), retried from
     the step hooks so late jax imports still get coverage.  Uninstall
-    restores the user's prior ``jax_log_compiles`` value.
+    restores the user's prior ``jax_log_compiles`` value and removes
+    the listeners.
     """
 
     def __init__(self, telemetry):
         self._tel = telemetry
         self._filters: list = []
         self._prev_log_compiles = None
+        # a cache hit's load time is reported inside the backend-compile
+        # event that encloses it, on the same thread: kept here until
+        # that event closes, so the two stages add up to the whole
+        # and a jit traced inside another's trace reports a duration that
+        # the outer one's already covers: only the outermost is booked
+        self._local = threading.local()
         self.installed = False
 
     def install(self):
@@ -198,6 +224,10 @@ class CompileWatcher:
             f = _CompileLogFilter(self._tel, swallow=not prev)
             logging.getLogger(name).addFilter(f)
             self._filters.append((name, f))
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_scalar_listener(self._on_scalar)
         self.installed = True
         return True
 
@@ -207,12 +237,49 @@ class CompileWatcher:
         for name, f in self._filters:
             logging.getLogger(name).removeFilter(f)
         self._filters = []
+        jax = sys.modules["jax"]
+        for remove, fn in (
+                (jax.monitoring.unregister_event_duration_listener,
+                 self._on_duration),
+                (jax.monitoring.unregister_event_listener, self._on_event),
+                (jax.monitoring.unregister_scalar_listener,
+                 self._on_scalar)):
+            try:
+                remove(fn)
+            except Exception as e:   # already cleared by someone else
+                logger.debug("compile watcher: %s", e)
         if self._prev_log_compiles is False:
             try:
-                sys.modules["jax"].config.update("jax_log_compiles", False)
+                jax.config.update("jax_log_compiles", False)
             except Exception as e:
                 logger.debug("compile watcher: restore failed: %s", e)
         self.installed = False
+
+    def _on_scalar(self, event, value, **kwargs):
+        # jax reports a stage's start as a scalar: the trace stage nests
+        if _COMPILE_STAGES.get(event) == "trace":
+            self._local.depth = getattr(self._local, "depth", 0) + 1
+
+    def _on_duration(self, event, seconds, **kwargs):
+        stage = _COMPILE_STAGES.get(event)
+        if stage is None:
+            return
+        local = self._local
+        if stage == "trace":
+            local.depth = max(0, getattr(local, "depth", 0) - 1)
+            if local.depth:
+                return
+        elif stage == "cache_load":
+            local.cache_load = seconds
+        elif stage == "backend_compile":
+            seconds = max(0.0, seconds - getattr(local, "cache_load", 0.0))
+            local.cache_load = 0.0
+        self._tel.compile_stage(stage, seconds)
+
+    def _on_event(self, event, **kwargs):
+        result = _COMPILE_CACHE_RESULTS.get(event)
+        if result is not None:
+            self._tel.compile_cache(result)
 
 
 class StepTimer:
@@ -365,6 +432,16 @@ class TrainingTelemetry:
         self._m_storms = r.counter(
             "pt_recompile_storms_total",
             "callables that tripped the recompile sentinel")
+        self._m_compile_seconds = r.counter(
+            "pt_compile_seconds_total",
+            "host seconds spent building programs, by stage (trace = "
+            "python to jaxpr, lower = jaxpr to MLIR, backend_compile = "
+            "XLA, cache_load = reading the persistent compile cache)",
+            ("stage",))
+        self._m_compile_cache = r.counter(
+            "pt_compile_cache_total",
+            "persistent compile cache lookups, by result (hit|miss)",
+            ("result",))
         self._m_data_wait = r.histogram(
             "pt_data_wait_seconds",
             "time the training loop waited for the next batch")
@@ -725,6 +802,17 @@ class TrainingTelemetry:
         compile log (AOT pipelines, drills) — same metrics/sentinel
         path as the log filter."""
         self._on_compile(name, signature)
+
+    def compile_stage(self, stage, seconds):
+        """Seconds one compile stage took (``jax.monitoring`` feed of
+        the :class:`CompileWatcher`)."""
+        if self.enabled:
+            self._m_compile_seconds.inc(seconds, stage=stage)
+
+    def compile_cache(self, result):
+        """One persistent-cache lookup, ``hit`` or ``miss``."""
+        if self.enabled:
+            self._m_compile_cache.inc(result=result)
 
     def ensure_compile_watch(self):
         """Install the jax compile-log watcher without flipping the rest

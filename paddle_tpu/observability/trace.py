@@ -4,8 +4,10 @@ flight recorder.
 :class:`Tracer` is the process-wide span sink every instrumented layer
 feeds: ``core.RecordEvent`` begin/end pairs, the profiler's
 ``export_chrome_tracing``, and the step-phase hooks in ``hapi.Model``,
-``jit.capture``, ``DataLoader`` and the eager collectives.  It follows
-the same contract as :class:`~.telemetry.TrainingTelemetry`:
+``jit.capture``, ``DataLoader`` and the eager collectives, and the
+:func:`span` primitive of the serving request path and the captured
+step.  It follows the same contract as
+:class:`~.telemetry.TrainingTelemetry`:
 
 1. **Zero cost while disabled.**  Every hook starts with a plain
    attribute check; importing this module creates no threads, files or
@@ -25,6 +27,16 @@ the same contract as :class:`~.telemetry.TrainingTelemetry`:
 Every span is stamped with this process's ``(process_index, run_id)``
 identity so per-rank Chrome exports stitch into one cluster timeline
 (``python -m paddle_tpu.observability.merge --trace``, rank as pid).
+
+**Spans on the profiler's clock** (:class:`span`): ``with
+span("serve.decode.launch", rows=3, bucket=8):`` always opens a
+``jax.profiler.TraceAnnotation("pt:serve.decode.launch", ...)`` — one
+atomic load outside a profiler session; inside one the span lands in the
+host plane of the same ``.xplane.pb`` as the device operations, so the
+two share a clock by construction — and, only while the tracer is
+enabled, also appends to the ring (Chrome export, flight recorder).  The
+span measures itself either way (``.seconds``), which is what the
+serving scheduler's always-on time counters add up.
 
 **Phases** (``pt_step_phase_seconds{phase}``): ``data_wait`` /
 ``forward`` / ``backward`` / ``optimizer`` / ``checkpoint`` /
@@ -67,7 +79,7 @@ from .logs import get_logger
 from .metrics import get_registry, log_buckets
 
 __all__ = [
-    "Tracer", "Span", "PHASES", "PEAK_FLOPS", "PEAK_HBM_BW", "peak_flops",
+    "Tracer", "Span", "span", "PHASES", "PEAK_FLOPS", "PEAK_HBM_BW", "peak_flops",
     "peak_hbm_bw", "program_flops", "get_tracer", "current_tracer", "reset_tracer",
 ]
 
@@ -197,6 +209,50 @@ class _PhaseSpan:
         if self._t0 is not None and exc_type is None:
             self._tr.phase_record(self._phase, self._t0,
                                   time.perf_counter_ns())
+        return False
+
+
+_annotation = None   # jax.profiler.TraceAnnotation, once jax is imported
+
+
+class span:
+    """``with span(name, cat="host", **ids) as sp:`` — one span of the
+    program, named ``pt:<name>`` in a profiler session (``ids`` become
+    the event's arguments there) and ``<name>`` in the tracer's ring
+    while the tracer is enabled.  After the block ``sp.seconds`` is its
+    wall time.  Never imports jax and never touches a backend: before
+    jax is imported the annotation is skipped."""
+
+    __slots__ = ("name", "cat", "ids", "seconds", "_ann", "_t0")
+
+    def __init__(self, name, cat="host", **ids):
+        self.name = name
+        self.cat = cat
+        self.ids = ids
+        self.seconds = 0.0
+
+    def __enter__(self):
+        global _annotation
+        if _annotation is None:
+            jax = sys.modules.get("jax")
+            if jax is not None:
+                _annotation = jax.profiler.TraceAnnotation
+        if _annotation is not None:
+            self._ann = _annotation("pt:" + self.name, **self.ids)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter_ns()
+        self.seconds = (t1 - self._t0) / 1e9
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        tr = _tracer
+        if tr is not None and tr.enabled:
+            tr.record_span(self.name, self.cat, self._t0, t1)
         return False
 
 

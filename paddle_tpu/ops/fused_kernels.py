@@ -209,6 +209,7 @@ def _ln_pallas_fwd(x, res, w, b, *, d, eps, block_rows, parallel, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel" if parallel else "arbitrary",)),
         interpret=interpret,
+        name="layer_norm_fwd",
     )(*args)
 
 
@@ -252,6 +253,7 @@ def _ln_pallas_bwd(x, res, w, b, g, mean, rstd, *, d, block_rows,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="layer_norm_bwd",
     )(*args)
     dx = outs[0]
     dw = outs[1] if has_w else None
@@ -464,6 +466,7 @@ def _xent_pallas_fwd(x, lab, *, V, block_rows, block_v, ignore_index,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="softmax_xent_fwd",
     )(lab, x)
 
 
@@ -488,6 +491,7 @@ def _xent_pallas_bwd(x, lab, lse, g, *, V, block_rows, block_v,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="softmax_xent_bwd",
     )(lab, x, lse, g)
 
 
@@ -800,6 +804,7 @@ def _lnmm_pallas_fwd(x, res, lw, lb, w, mb, *, d, eps, block_rows,
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel" if parallel else "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="ln_matmul_fwd",
     )(*args)
 
 
@@ -972,6 +977,7 @@ def _mbg_pallas_fwd(x, w, b, *, block_rows, block_n, approximate,
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel" if parallel else "arbitrary", "parallel")),
         interpret=interpret,
+        name="matmul_bias_gelu_fwd",
     )(*args)
 
 

@@ -43,6 +43,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.extend import core as jcore
+from jax._src import source_info_util as _source_info
 
 from ..framework import device as _device
 
@@ -906,6 +907,7 @@ def _eval_rewritten(jaxpr, consts, args, plan):
     for v, a in zip(jaxpr.invars, args):
         write(v, a)
 
+    outer_stack = _source_info.current_name_stack()
     by_idx = {}
     for cl in plan:
         fn = _cluster_fn(cl)
@@ -914,15 +916,21 @@ def _eval_rewritten(jaxpr, consts, args, plan):
 
     for idx, eqn in enumerate(jaxpr.eqns):
         hit = by_idx.get(idx)
-        if hit is not None:
-            cl, fn = hit
-            if idx != cl.root:
-                continue
-            write(cl.outvar, fn(*[read(v) for v in cl.invars]))
+        if hit is not None and idx != hit[0].root:
             continue
-        subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
-        ans = eqn.primitive.bind(
-            *subfuns, *[read(v) for v in eqn.invars], **bind_params)
+        # re-binding drops the equation's source information: re-enter
+        # its name stack (jax.named_scope), as core.eval_jaxpr does, so
+        # a rewritten step keeps its scopes in HLO and profiler traces
+        # (a fused cluster takes its root equation's)
+        with _source_info.set_name_stack(
+                outer_stack + eqn.source_info.name_stack):
+            if hit is not None:
+                cl, fn = hit
+                write(cl.outvar, fn(*[read(v) for v in cl.invars]))
+                continue
+            subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
+            ans = eqn.primitive.bind(
+                *subfuns, *[read(v) for v in eqn.invars], **bind_params)
         if eqn.primitive.multiple_results:
             for v, a in zip(eqn.outvars, ans):
                 write(v, a)

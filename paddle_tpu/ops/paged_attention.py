@@ -163,7 +163,7 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref,
 
 
 def _paged_call(kernel, q, pages, extra, extra_specs, page_tables, lengths,
-                *, batch_semantics, interpret):
+                *, name, batch_semantics, interpret):
     """Shared pallas_call plumbing: q (B, H, D) and (P, ps, H, D) pools
     enter flattened to H*D lanes; ``extra``/``extra_specs`` are the int8
     kernel's scale operands."""
@@ -197,6 +197,7 @@ def _paged_call(kernel, q, pages, extra, extra_specs, page_tables, lengths,
         out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(batch_semantics, "arbitrary")),
+        name=name,
         interpret=interpret,
     )(page_tables, lengths, q.reshape(b, 1, hd),
       *[p.reshape(p.shape[0], ps, hd) for p in pages], *extra, seg, segt)
@@ -209,8 +210,8 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, lengths,
                                max_pages=page_tables.shape[1],
                                sm_scale=sm_scale)
     return _paged_call(kernel, q, (k_pages, v_pages), (), (), page_tables,
-                       lengths, batch_semantics="parallel",
-                       interpret=interpret)
+                       lengths, name="paged_attention",
+                       batch_semantics="parallel", interpret=interpret)
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
@@ -318,6 +319,7 @@ def _paged_attention_int8_pallas(q, k_pages, v_pages, k_scale, v_scale,
     return _paged_call(kernel, q, (k_pages, v_pages),
                        (jnp.pad(k_scale, pad), jnp.pad(v_scale, pad)),
                        (scale_spec, scale_spec), page_tables, lengths,
+                       name="paged_attention_int8",
                        batch_semantics=batch_semantics, interpret=interpret)
 
 

@@ -248,6 +248,7 @@ def _fwd(q, k, v, seed, lens, shift, *, causal, sm_scale, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*seed_args, q, k, v)
     return out, lse
 
@@ -407,6 +408,7 @@ def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*seed_args, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -438,6 +440,7 @@ def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*seed_args, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -951,6 +954,7 @@ def _pk_fwd(q, k, v, seed, meta, *, causal, sm_scale, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_packed_fwd",
     )(*seed_args, klo, khi, q, k, v, pos_q[:, None], ok_q[:, None],
       off_q[:, None], pos_k[None, :], ok_k[None, :])
     return out, lse
@@ -992,6 +996,7 @@ def _pk_bwd(q, k, v, out, lse, do, seed, meta, *, causal, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_packed_bwd_dq",
     )(*seed_args, klo, khi, q, k, v, do, lse, delta, pos_q[:, None],
       ok_q[:, None], off_q[:, None], pos_k[None, :], ok_k[None, :])
 
@@ -1028,6 +1033,7 @@ def _pk_bwd(q, k, v, out, lse, do, seed, meta, *, causal, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_packed_bwd_dkv",
     )(*seed_args, qlo, qhi, q, k, v, do, lse, delta, pos_q[:, None],
       ok_q[:, None], off_q[:, None], pos_k[None, :], ok_k[None, :])
     return dq, dk, dv

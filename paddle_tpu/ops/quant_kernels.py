@@ -144,6 +144,7 @@ def _w8a16_pallas(x, w_q, scale, *, block_m, block_n, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="w8a16_matmul",
     )(xp, wp, sp)
     return out[:m, :n]
 

@@ -114,6 +114,13 @@ class Optimizer:
 
     # -- eager step ----------------------------------------------------------
     def step(self):
+        # the named scope `optimizer` names the update's operations in
+        # HLO and in a profiler trace (a captured step is one program:
+        # this is how its update is told from its backward)
+        with jax.named_scope("optimizer"):
+            self._step()
+
+    def _step(self):
         if self._capture_hook is not None:
             self._capture_hook(self)
             return

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability.trace import span
 from .kv_cache import PagePool, NULL_PAGE, kv_page_budget
 from .model import ModelSpec, init_params, prefill_step, decode_step
 
@@ -245,6 +246,10 @@ class ServingEngine:
             self._weights_step = weights_step
             self._weights_lock = threading.Lock()
             self.unexpected_compiles = 0
+            # the request the scheduler is about to prefill: names the
+            # prefill spans (set by the scheduler's one thread, under
+            # its lock; None for a direct caller)
+            self.prefill_request_id: Optional[int] = None
             self._warmed = False
             self._prefill_exe: Dict[int, Any] = {}
             self._decode_exe: Dict[int, Any] = {}
@@ -478,41 +483,55 @@ class ServingEngine:
 
     def prefill(self, tokens: Sequence[int],
                 page_table: np.ndarray) -> int:
-        """Run one prompt; returns the first generated token."""
-        n = len(tokens)
-        s = self.prefill_bucket_for(n)
-        padded = np.zeros((s,), np.int32)
-        padded[:n] = np.asarray(tokens, np.int32)
-        with self._weights_lock:
-            params = self._params
-        *state, nxt, _ = self._prefill_exe[s](
-            params, *self._kv_state(),
-            padded, np.int32(n), np.asarray(page_table, np.int32))
-        self.pool.swap(*state)
-        return int(nxt)
+        """Run one prompt; returns the first generated token.
+
+        Three leaf spans (``observability.trace.span``): ``.prep`` is
+        the padding, ``.launch`` the executable call until it returns,
+        ``.fetch`` the pool rebind and the token's device-to-host copy
+        (which waits for the program).  They carry the ``request_id``
+        the scheduler left in ``prefill_request_id``."""
+        rid = self.prefill_request_id
+        with span("serve.prefill.prep", request_id=rid):
+            n = len(tokens)
+            s = self.prefill_bucket_for(n)
+            padded = np.zeros((s,), np.int32)
+            padded[:n] = np.asarray(tokens, np.int32)
+            table = np.asarray(page_table, np.int32)
+            with self._weights_lock:
+                params = self._params
+        with span("serve.prefill.launch", request_id=rid):
+            *state, nxt, _ = self._prefill_exe[s](
+                params, *self._kv_state(), padded, np.int32(n), table)
+        with span("serve.prefill.fetch", request_id=rid):
+            self.pool.swap(*state)
+            return int(nxt)
 
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
                page_tables: np.ndarray) -> np.ndarray:
         """One decode step over ``n`` active rows, padded to a bucket.
 
         Padding rows carry position 0 + the all-null page table, so
-        their (garbage) K/V writes land in the null page.
+        their (garbage) K/V writes land in the null page.  Leaf spans
+        as in :meth:`prefill`, carrying ``rows`` and ``bucket``.
         """
         n = tokens.shape[0]
         b = self.decode_bucket_for(max(n, 1))
-        maxp = self.max_pages_per_seq
-        tok = np.zeros((b,), np.int32)
-        pos = np.zeros((b,), np.int32)
-        pt = np.full((b, maxp), NULL_PAGE, np.int32)
-        tok[:n] = tokens
-        pos[:n] = positions
-        pt[:n] = page_tables
-        with self._weights_lock:
-            params = self._params
-        *state, nxt, _ = self._decode_exe[b](
-            params, *self._kv_state(), tok, pos, pt)
-        self.pool.swap(*state)
-        return np.asarray(nxt)[:n]
+        with span("serve.decode.prep", rows=n, bucket=b):
+            maxp = self.max_pages_per_seq
+            tok = np.zeros((b,), np.int32)
+            pos = np.zeros((b,), np.int32)
+            pt = np.full((b, maxp), NULL_PAGE, np.int32)
+            tok[:n] = tokens
+            pos[:n] = positions
+            pt[:n] = page_tables
+            with self._weights_lock:
+                params = self._params
+        with span("serve.decode.launch", rows=n, bucket=b):
+            *state, nxt, _ = self._decode_exe[b](
+                params, *self._kv_state(), tok, pos, pt)
+        with span("serve.decode.fetch", rows=n, bucket=b):
+            self.pool.swap(*state)
+            return np.asarray(nxt)[:n]
 
     # -- weights ------------------------------------------------------------
 

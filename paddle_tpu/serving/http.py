@@ -230,6 +230,7 @@ class ServeHTTPServer:
                         "tokens": [int(t) for t in stream.tokens],
                         "request_id": stream.request_id,
                         "latency_ms": wall * 1e3,
+                        "ttft_ms": stream.ttft * 1e3,
                         "weights_step": engine.weights_step,
                     })
                 elif isinstance(err, DeadlineExceeded):

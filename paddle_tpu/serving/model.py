@@ -21,6 +21,16 @@ physical page placement.  The one XLA exception is batch=1, which hits
 a gemv path with a different reduction order; the engine therefore
 clamps its decode bucket ladder to >= 2 rows (see
 ``ServeConfig._normalize``), and tests pin the bit-identity claim.
+
+Device-trace scopes: both steps run under ``jax.named_scope`` — ``embed``,
+per layer ``layer<i>/attn_qkv``, ``layer<i>/kv_write`` (the pool
+``.at[...].set`` and the int8 scale writes), ``layer<i>/kv_read`` (the
+per-layer pool slices handed to the kernel), ``layer<i>/attn``,
+``layer<i>/attn_out``, ``layer<i>/mlp``, then ``lm_head`` and ``sample``.
+Prefill writes every layer's K/V in one scatter after the stack, so its
+``kv_write`` is not under a layer.  Scopes are HLO metadata only
+(``op_name``): they name the operations in a profiler trace and change
+nothing the program computes.
 """
 from __future__ import annotations
 
@@ -177,7 +187,9 @@ def prefill_step(spec: ModelSpec, params, k_flat, v_flat,
     stacks.
     """
     s = tokens.shape[0]
-    h = params["embed"][tokens] + params["pos"][:s]
+    scope = jax.named_scope
+    with scope("embed"):
+        h = params["embed"][tokens] + params["pos"][:s]
     cdt = params["embed"].dtype
     pos_ids = jnp.arange(s, dtype=jnp.int32)
     # causal AND inside the true prompt: key j visible to query i iff
@@ -186,50 +198,57 @@ def prefill_step(spec: ModelSpec, params, k_flat, v_flat,
     scale = 1.0 / math.sqrt(spec.head_dim)
     ks, vs = [], []
     for i in range(spec.layers):
-        x = _ln(h, params[f"h{i}.ln1.w"],
-                params[f"h{i}.ln1.b"]).astype(cdt)
-        q = _matmul(params, f"h{i}.attn.wq", x,
-                    tap).reshape(s, spec.heads, spec.head_dim)
-        k = _matmul(params, f"h{i}.attn.wk", x,
-                    tap).reshape(s, spec.heads, spec.head_dim)
-        v = _matmul(params, f"h{i}.attn.wv", x,
-                    tap).reshape(s, spec.heads, spec.head_dim)
-        att = jnp.einsum("ihd,jhd->hij", q, k,
-                         preferred_element_type=jnp.float32) * scale
-        att = jnp.where(mask[None, :, :], att, -1e30)
-        w = jax.nn.softmax(att, axis=-1)
-        o = jnp.einsum("hij,jhd->ihd", w.astype(v.dtype), v,
-                       preferred_element_type=jnp.float32
-                       ).reshape(s, spec.hidden).astype(cdt)
-        h = h + _matmul(params, f"h{i}.attn.wo", o, tap)
-        x2 = _ln(h, params[f"h{i}.ln2.w"],
-                 params[f"h{i}.ln2.b"]).astype(cdt)
-        h = h + _mlp(spec, params, i, x2, tap)
+        with scope(f"layer{i}/attn_qkv"):
+            x = _ln(h, params[f"h{i}.ln1.w"],
+                    params[f"h{i}.ln1.b"]).astype(cdt)
+            q = _matmul(params, f"h{i}.attn.wq", x,
+                        tap).reshape(s, spec.heads, spec.head_dim)
+            k = _matmul(params, f"h{i}.attn.wk", x,
+                        tap).reshape(s, spec.heads, spec.head_dim)
+            v = _matmul(params, f"h{i}.attn.wv", x,
+                        tap).reshape(s, spec.heads, spec.head_dim)
+        with scope(f"layer{i}/attn"):
+            att = jnp.einsum("ihd,jhd->hij", q, k,
+                             preferred_element_type=jnp.float32) * scale
+            att = jnp.where(mask[None, :, :], att, -1e30)
+            w = jax.nn.softmax(att, axis=-1)
+            o = jnp.einsum("hij,jhd->ihd", w.astype(v.dtype), v,
+                           preferred_element_type=jnp.float32
+                           ).reshape(s, spec.hidden).astype(cdt)
+        with scope(f"layer{i}/attn_out"):
+            h = h + _matmul(params, f"h{i}.attn.wo", o, tap)
+        with scope(f"layer{i}/mlp"):
+            x2 = _ln(h, params[f"h{i}.ln2.w"],
+                     params[f"h{i}.ln2.b"]).astype(cdt)
+            h = h + _mlp(spec, params, i, x2, tap)
         ks.append(k)
         vs.append(v)
-    hf = _ln(h, params["lnf.w"], params["lnf.b"]).astype(cdt)
-    if tap is not None:
-        tap("head", hf)
-    logits_all = hf @ params["embed"].T                    # (S, V)
-    logits = jnp.take(logits_all, length - 1, axis=0)      # (V,)
-    next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with scope("lm_head"):
+        hf = _ln(h, params["lnf.w"], params["lnf.b"]).astype(cdt)
+        if tap is not None:
+            tap("head", hf)
+        logits_all = hf @ params["embed"].T                    # (S, V)
+        logits = jnp.take(logits_all, length - 1, axis=0)      # (V,)
+    with scope("sample"):
+        next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     # scatter prompt K/V into this sequence's pages; padding rows are
     # routed to flat row 0 (inside the reserved null page, never read
     # unmasked)
-    dest = jnp.where(pos_ids < length,
-                     _flat_dest(page_table, pos_ids, page_size), 0)
-    k_stack = jnp.stack(ks)                                # (L, S, H, D)
-    v_stack = jnp.stack(vs)
-    if k_flat.dtype == jnp.int8:
-        kq, ksc = quantize_kv(k_stack)
-        vq, vsc = quantize_kv(v_stack)
-        k_flat = k_flat.at[:, dest].set(kq)
-        v_flat = v_flat.at[:, dest].set(vq)
-        k_scale = k_scale.at[:, dest].set(ksc)
-        v_scale = v_scale.at[:, dest].set(vsc)
-    else:
-        k_flat = k_flat.at[:, dest].set(k_stack.astype(k_flat.dtype))
-        v_flat = v_flat.at[:, dest].set(v_stack.astype(v_flat.dtype))
+    with scope("kv_write"):
+        dest = jnp.where(pos_ids < length,
+                         _flat_dest(page_table, pos_ids, page_size), 0)
+        k_stack = jnp.stack(ks)                                # (L, S, H, D)
+        v_stack = jnp.stack(vs)
+        if k_flat.dtype == jnp.int8:
+            kq, ksc = quantize_kv(k_stack)
+            vq, vsc = quantize_kv(v_stack)
+            k_flat = k_flat.at[:, dest].set(kq)
+            v_flat = v_flat.at[:, dest].set(vq)
+            k_scale = k_scale.at[:, dest].set(ksc)
+            v_scale = v_scale.at[:, dest].set(vsc)
+        else:
+            k_flat = k_flat.at[:, dest].set(k_stack.astype(k_flat.dtype))
+            v_flat = v_flat.at[:, dest].set(v_stack.astype(v_flat.dtype))
     if k_scale is not None:
         return k_flat, v_flat, k_scale, v_scale, next_token, logits
     return k_flat, v_flat, next_token, logits
@@ -260,53 +279,60 @@ def decode_step(spec: ModelSpec, params, k_flat, v_flat,
     b = tokens.shape[0]
     num_pages = k_flat.shape[1] // page_size
     quant = k_flat.dtype == jnp.int8
-    dest = _flat_dest(page_tables, positions, page_size)   # (B,)
-    lengths = positions + 1
-    h = params["embed"][tokens] + params["pos"][positions]
+    scope = jax.named_scope
+    pages_of = lambda pool: pool.reshape(num_pages, page_size,
+                                         *pool.shape[1:])
+    with scope("embed"):
+        dest = _flat_dest(page_tables, positions, page_size)   # (B,)
+        lengths = positions + 1
+        h = params["embed"][tokens] + params["pos"][positions]
     cdt = params["embed"].dtype
     for i in range(spec.layers):
-        x = _ln(h, params[f"h{i}.ln1.w"],
-                params[f"h{i}.ln1.b"]).astype(cdt)
-        q = _matmul(params, f"h{i}.attn.wq", x,
-                    tap).reshape(b, spec.heads, spec.head_dim)
-        k = _matmul(params, f"h{i}.attn.wk", x,
-                    tap).reshape(b, spec.heads, spec.head_dim)
-        v = _matmul(params, f"h{i}.attn.wv", x,
-                    tap).reshape(b, spec.heads, spec.head_dim)
+        with scope(f"layer{i}/attn_qkv"):
+            x = _ln(h, params[f"h{i}.ln1.w"],
+                    params[f"h{i}.ln1.b"]).astype(cdt)
+            q = _matmul(params, f"h{i}.attn.wq", x,
+                        tap).reshape(b, spec.heads, spec.head_dim)
+            k = _matmul(params, f"h{i}.attn.wk", x,
+                        tap).reshape(b, spec.heads, spec.head_dim)
+            v = _matmul(params, f"h{i}.attn.wv", x,
+                        tap).reshape(b, spec.heads, spec.head_dim)
         if quant:
-            kq, ksc = quantize_kv(k)
-            vq, vsc = quantize_kv(v)
-            k_flat = k_flat.at[i, dest].set(kq)
-            v_flat = v_flat.at[i, dest].set(vq)
-            k_scale = k_scale.at[i, dest].set(ksc)
-            v_scale = v_scale.at[i, dest].set(vsc)
-            o = paged_attention_int8(
-                q,
-                k_flat[i].reshape(num_pages, page_size, spec.heads,
-                                  spec.head_dim),
-                v_flat[i].reshape(num_pages, page_size, spec.heads,
-                                  spec.head_dim),
-                k_scale[i].reshape(num_pages, page_size, spec.heads),
-                v_scale[i].reshape(num_pages, page_size, spec.heads),
-                page_tables, lengths)
+            with scope(f"layer{i}/kv_write"):
+                kq, ksc = quantize_kv(k)
+                vq, vsc = quantize_kv(v)
+                k_flat = k_flat.at[i, dest].set(kq)
+                v_flat = v_flat.at[i, dest].set(vq)
+                k_scale = k_scale.at[i, dest].set(ksc)
+                v_scale = v_scale.at[i, dest].set(vsc)
+            with scope(f"layer{i}/kv_read"):
+                kv = [pages_of(p[i])
+                      for p in (k_flat, v_flat, k_scale, v_scale)]
+            with scope(f"layer{i}/attn"):
+                o = paged_attention_int8(q, *kv, page_tables, lengths)
         else:
-            k_flat = k_flat.at[i, dest].set(k.astype(k_flat.dtype))
-            v_flat = v_flat.at[i, dest].set(v.astype(v_flat.dtype))
-            k_pages = k_flat[i].reshape(num_pages, page_size,
-                                        spec.heads, spec.head_dim)
-            v_pages = v_flat[i].reshape(num_pages, page_size,
-                                        spec.heads, spec.head_dim)
-            o = paged_attention(q, k_pages, v_pages, page_tables, lengths)
-        h = h + _matmul(params, f"h{i}.attn.wo",
-                        o.reshape(b, spec.hidden), tap)
-        x2 = _ln(h, params[f"h{i}.ln2.w"],
-                 params[f"h{i}.ln2.b"]).astype(cdt)
-        h = h + _mlp(spec, params, i, x2, tap)
-    hf = _ln(h, params["lnf.w"], params["lnf.b"]).astype(cdt)
-    if tap is not None:
-        tap("head", hf)
-    logits = hf @ params["embed"].T                        # (B, V)
-    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with scope(f"layer{i}/kv_write"):
+                k_flat = k_flat.at[i, dest].set(k.astype(k_flat.dtype))
+                v_flat = v_flat.at[i, dest].set(v.astype(v_flat.dtype))
+            with scope(f"layer{i}/kv_read"):
+                k_pages, v_pages = pages_of(k_flat[i]), pages_of(v_flat[i])
+            with scope(f"layer{i}/attn"):
+                o = paged_attention(q, k_pages, v_pages, page_tables,
+                                    lengths)
+        with scope(f"layer{i}/attn_out"):
+            h = h + _matmul(params, f"h{i}.attn.wo",
+                            o.reshape(b, spec.hidden), tap)
+        with scope(f"layer{i}/mlp"):
+            x2 = _ln(h, params[f"h{i}.ln2.w"],
+                     params[f"h{i}.ln2.b"]).astype(cdt)
+            h = h + _mlp(spec, params, i, x2, tap)
+    with scope("lm_head"):
+        hf = _ln(h, params["lnf.w"], params["lnf.b"]).astype(cdt)
+        if tap is not None:
+            tap("head", hf)
+        logits = hf @ params["embed"].T                        # (B, V)
+    with scope("sample"):
+        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     if k_scale is not None:
         return k_flat, v_flat, k_scale, v_scale, next_tokens, logits
     return k_flat, v_flat, next_tokens, logits

@@ -44,6 +44,24 @@ Resilience layer (the serving-chaos contract):
 The whole request path here is numpy + pre-compiled executables; a
 single stray jnp call would book an unexpected compile on the
 engine's sentinel (tpu-lint TPU019 polices this statically).
+
+Where the time goes (always on, ``observability.trace.span``): the
+scheduler thread's time is cut into leaf spans, none nested in another —
+``serve.wait`` (the loop's ``cv.wait`` with nothing to do, and a
+step's taking the lock back from submitters), ``serve.evict``,
+``serve.admit`` (each stretch of the admit loop's own work between
+engine calls),
+``serve.prefill.prep`` / ``.launch`` / ``.fetch`` and
+``serve.decode.prep`` / ``.launch`` / ``.fetch`` (the last six but the
+page-table half of ``decode.prep`` inside :mod:`.engine`), and
+``serve.book`` (token append, retirement, gauges).  Inside a profiler
+session they are ``pt:serve.*`` events on the device trace's clock; the
+same boundaries add to ``stats`` as float sums (``wait_s``, ``evict_s``,
+``admit_host_s``, ``prefill_s``, ``decode_prep_s``, ``decode_s``,
+``book_s``) beside the per-request ``lock_wait_s`` (entry of
+:meth:`~ContinuousScheduler.submit` to lock held), ``queue_wait_s``
+(enqueued to its prefill entered, count ``admitted``), ``ttft_s`` and
+``tpot_s`` (count ``tpot_requests``).
 """
 from __future__ import annotations
 
@@ -57,6 +75,9 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..observability.metrics import get_registry
+from ..observability.telemetry import get_telemetry
+from ..observability.trace import get_tracer, span
 from .kv_cache import KVPoolExhausted
 
 logger = logging.getLogger("paddle_tpu.serving")
@@ -109,17 +130,29 @@ class DeadlineExceeded(TimeoutError):
 
 
 class GenerationStream:
-    """Future-like handle for one submitted request."""
+    """Future-like handle for one submitted request.
+
+    Stamps (``time.monotonic``, None until reached), in order:
+    ``arrived_ts`` (entry of ``submit()``, before the scheduler lock),
+    ``submitted_ts`` (enqueued, the lock held), ``admitted_ts`` (its
+    prefill entered), ``first_token_ts``, ``last_token_ts``,
+    ``finished_ts``."""
 
     _ids = itertools.count()
 
     def __init__(self, prompt: List[int], max_new_tokens: int,
-                 deadline: Optional[float] = None):
+                 deadline: Optional[float] = None,
+                 arrived_ts: Optional[float] = None):
         self.request_id = next(self._ids)
         self.prompt = prompt
         self.max_new_tokens = max_new_tokens
         self.tokens: List[int] = []
         self.submitted_ts = time.monotonic()
+        self.arrived_ts = (self.submitted_ts if arrived_ts is None
+                           else arrived_ts)
+        self.admitted_ts: Optional[float] = None
+        self.first_token_ts: Optional[float] = None
+        self.last_token_ts: Optional[float] = None
         self.finished_ts: Optional[float] = None
         self.deadline = deadline        # absolute time.monotonic(), or None
         self.cancel_cause: Optional[str] = None
@@ -161,9 +194,32 @@ class GenerationStream:
 
     @property
     def latency(self) -> Optional[float]:
+        """Arrival to finish: the lock wait in ``submit()`` counts."""
         if self.finished_ts is None:
             return None
-        return self.finished_ts - self.submitted_ts
+        return self.finished_ts - self.arrived_ts
+
+    @property
+    def queue_wait(self) -> Optional[float]:
+        """Enqueued to admitted (its prefill entered)."""
+        if self.admitted_ts is None:
+            return None
+        return self.admitted_ts - self.submitted_ts
+
+    @property
+    def ttft(self) -> Optional[float]:
+        """Arrival to first token."""
+        if self.first_token_ts is None:
+            return None
+        return self.first_token_ts - self.arrived_ts
+
+    @property
+    def tpot(self) -> Optional[float]:
+        """Mean time per output token after the first."""
+        if self.last_token_ts is None or len(self.tokens) < 2:
+            return None
+        return ((self.last_token_ts - self.first_token_ts)
+                / (len(self.tokens) - 1))
 
     def _finish(self, error: Optional[BaseException] = None) -> None:
         self.finished_ts = time.monotonic()
@@ -212,13 +268,23 @@ class ContinuousScheduler:
             "peak_active": 0,
             "shed": 0, "cancelled": 0, "deadline_exceeded": 0,
             "failed": 0, "drain_seconds": None, "watchdog_trips": 0,
+            # seconds of the scheduler thread by phase (module docstring)
+            "wait_s": 0.0, "evict_s": 0.0, "admit_host_s": 0.0,
+            "prefill_s": 0.0, "decode_prep_s": 0.0, "decode_s": 0.0,
+            "book_s": 0.0,
+            # seconds of requests' waits, and what to divide them by
+            "lock_wait_s": 0.0, "queue_wait_s": 0.0, "admitted": 0,
+            "ttft_s": 0.0, "tpot_s": 0.0, "tpot_requests": 0,
         }
+        self._meter_registry = None     # the registry self._meters are of
+        self._meters: Dict[str, Any] = {}
 
     # -- submission ----------------------------------------------------------
 
     def submit(self, prompt: Sequence[int],
                max_new_tokens: Optional[int] = None,
                deadline_ms: Optional[float] = None) -> GenerationStream:
+        arrived = time.monotonic()
         cfg = self.engine.config
         spec = self.engine.spec
         prompt = [int(t) for t in prompt]
@@ -238,6 +304,7 @@ class ContinuousScheduler:
         deadline = (time.monotonic() + deadline_ms / 1e3
                     if deadline_ms > 0 else None)
         with self._cv:
+            self.stats["lock_wait_s"] += time.monotonic() - arrived
             if self._draining:
                 self._shed_locked("draining")
                 raise RequestShed("engine draining — admission closed",
@@ -270,7 +337,8 @@ class ContinuousScheduler:
                         f"estimated completion in {eta * 1e3:.0f}ms",
                         reason="deadline_infeasible",
                         retry_after=self._backlog_eta_locked())
-            st = GenerationStream(prompt, max_new, deadline=deadline)
+            st = GenerationStream(prompt, max_new, deadline=deadline,
+                                  arrived_ts=arrived)
             st._sched = self
             self._queue.append(st)
             self.stats["submitted"] += 1
@@ -373,130 +441,195 @@ class ContinuousScheduler:
     def step(self) -> bool:
         """One step boundary: evict / retire / admit / decode.  Returns
         whether any work was done."""
-        with self._lock:
-            self._evict_expired_locked()
+        stats = self.stats
+        # the loop gives the lock up between steps, which is when a
+        # submitter gets in; taking it back counts as waiting
+        with span("serve.wait") as sp:
+            # the wait `with self._lock:` made, inside a span; the one
+            # holder that can wedge is a step, which the watchdog watches
+            # tpu-lint: disable=TPU021
+            self._lock.acquire()
+        try:
+            stats["wait_s"] += sp.seconds
+            with span("serve.evict") as sp:
+                self._evict_expired_locked()
+            stats["evict_s"] += sp.seconds
             # draining closes submit(), not the internal queue: every
             # request accepted before SIGTERM still owes a response
             self._admit_locked()
             worked = self._decode_locked()
-            self.stats["steps"] += 1 if worked else 0
-            self._gauges_locked()
+            with span("serve.book") as sp:
+                stats["steps"] += 1 if worked else 0
+                self._gauges_locked()
+            stats["book_s"] += sp.seconds
             return worked or bool(self._queue)
+        finally:
+            self._lock.release()
 
     def _admit_locked(self) -> None:
-        pool = self.engine.pool
-        max_batch = self.engine.config.decode_buckets[-1]
-        while self._queue and len(self._active) < max_batch:
-            st = self._queue[0]
-            worst_case = pool.pages_needed(len(st.prompt) + st.max_new_tokens)
-            if not pool.can_admit(worst_case):
-                # head-of-line blocking is deliberate: skipping ahead
-                # would starve large requests under sustained load
-                self.stats["refused_kv"] += 1
-                self._book("pt_serve_admission_refusals_total",
-                           kind="counter", reason="kv_headroom")
-                break
-            self._queue.popleft()
+        stats = self.stats
+        engine = self.engine
+        seated = None   # (stream, first token, pages...) of the last prefill
+        while True:
+            # everything between two engine calls happens inside this one
+            # span, the bookkeeping of stamps too: what is left outside a
+            # span is idle time of the device that no span accounts for
+            with span("serve.admit") as sp:
+                if seated is not None:
+                    st = seated[0]
+                    stats["prefill_s"] += st.first_token_ts - st.admitted_ts
+                    stats["ttft_s"] += st.first_token_ts - st.arrived_ts
+                    self._seat_locked(*seated)
+                    seated = None
+                job = self._reserve_next_locked()
+                if job is not None:
+                    st, page_ids, page_table, reserved_left = job
+                    engine.prefill_request_id = st.request_id
+                    stats["admitted"] += 1
+                    st.admitted_ts = t0 = time.monotonic()
+                    stats["queue_wait_s"] += t0 - st.submitted_ts
+            stats["admit_host_s"] += sp.seconds
+            if job is None:
+                return
             try:
-                pool.reserve(worst_case)
-            except KVPoolExhausted:
-                self.stats["refused_kv"] += 1
-                self._queue.appendleft(st)
-                break
-            prompt_pages = pool.pages_needed(len(st.prompt))
-            page_ids = pool.alloc(prompt_pages, reserved=True)
-            reserved_left = worst_case - prompt_pages
-            page_table = pool.null_padded_table(
-                page_ids, self.engine.max_pages_per_seq)
-            try:
-                first = self.engine.prefill(st.prompt, page_table)
+                first = engine.prefill(st.prompt, page_table)
+                st.first_token_ts = st.last_token_ts = time.monotonic()
+                seated = (st, first, page_ids, page_table, reserved_left)
             except Exception as exc:  # resolve the caller, keep serving
-                pool.free(page_ids)
-                pool.release_reservation(reserved_left)
-                self.stats["failed"] += 1
+                stats["prefill_s"] += time.monotonic() - t0
+                engine.pool.free(page_ids)
+                engine.pool.release_reservation(reserved_left)
+                stats["failed"] += 1
                 self._book("pt_serve_request_failures_total",
                            kind="counter", stage="prefill")
                 st._finish(error=exc)
                 logger.exception("prefill failed for request %d",
                                  st.request_id)
-                continue
-            st.tokens.append(first)
-            self._book("pt_serve_tokens_total", kind="counter")
-            self.stats["tokens_generated"] += 1
-            act = _Active(st, page_ids, page_table, pos=len(st.prompt),
-                          last_token=first, reserved_left=reserved_left)
-            if self._is_finished(act):
-                self._retire_locked(act)
-            else:
-                self._active.append(act)
-                self.stats["peak_active"] = max(
-                    self.stats["peak_active"], len(self._active))
+
+    def _reserve_next_locked(self):
+        """Pop the head of the queue and reserve its worst-case pages:
+        ``(stream, page ids, page table, reserved pages left)``, or None
+        when nothing can be admitted now."""
+        pool = self.engine.pool
+        if not self._queue or \
+                len(self._active) >= self.engine.config.decode_buckets[-1]:
+            return None
+        st = self._queue[0]
+        worst_case = pool.pages_needed(len(st.prompt) + st.max_new_tokens)
+        if not pool.can_admit(worst_case):
+            # head-of-line blocking is deliberate: skipping ahead
+            # would starve large requests under sustained load
+            self.stats["refused_kv"] += 1
+            self._book("pt_serve_admission_refusals_total",
+                       kind="counter", reason="kv_headroom")
+            return None
+        self._queue.popleft()
+        try:
+            pool.reserve(worst_case)
+        except KVPoolExhausted:
+            self.stats["refused_kv"] += 1
+            self._queue.appendleft(st)
+            return None
+        prompt_pages = pool.pages_needed(len(st.prompt))
+        page_ids = pool.alloc(prompt_pages, reserved=True)
+        page_table = pool.null_padded_table(
+            page_ids, self.engine.max_pages_per_seq)
+        return st, page_ids, page_table, worst_case - prompt_pages
+
+    def _seat_locked(self, st, first, page_ids, page_table,
+                     reserved_left) -> None:
+        """Book a prefilled request's first token and seat it in the
+        batch (or retire it, if one token was all it asked for)."""
+        st.tokens.append(first)
+        self._book("pt_serve_tokens_total", kind="counter")
+        self.stats["tokens_generated"] += 1
+        act = _Active(st, page_ids, page_table, pos=len(st.prompt),
+                      last_token=first, reserved_left=reserved_left)
+        if self._is_finished(act):
+            self._retire_locked(act)
+        else:
+            self._active.append(act)
+            self.stats["peak_active"] = max(
+                self.stats["peak_active"], len(self._active))
 
     def _decode_locked(self) -> bool:
         if not self._active:
             return False
-        pool = self.engine.pool
-        ps = self.engine.config.page_size
-        # grow page tables for rows whose next write crosses a page
-        # boundary — drawn from the admission-time reservation, so this
-        # alloc cannot fail
-        for a in self._active:
-            need = a.pos // ps + 1
-            if need > len(a.page_ids):
-                new = pool.alloc(need - len(a.page_ids), reserved=True)
-                for pid in new:
-                    a.page_table[len(a.page_ids)] = pid
-                    a.page_ids.append(pid)
-                a.reserved_left -= len(new)
-        n = len(self._active)
-        tokens = np.asarray([a.last_token for a in self._active], np.int32)
-        positions = np.asarray([a.pos for a in self._active], np.int32)
-        tables = np.stack([a.page_table for a in self._active])
-        t0 = time.monotonic()
-        self._step_started = t0  # watchdog arms on the device call
+        stats = self.stats
+        with span("serve.decode.prep") as sp:
+            pool = self.engine.pool
+            ps = self.engine.config.page_size
+            # grow page tables for rows whose next write crosses a page
+            # boundary — drawn from the admission-time reservation, so
+            # this alloc cannot fail
+            for a in self._active:
+                need = a.pos // ps + 1
+                if need > len(a.page_ids):
+                    new = pool.alloc(need - len(a.page_ids), reserved=True)
+                    for pid in new:
+                        a.page_table[len(a.page_ids)] = pid
+                        a.page_ids.append(pid)
+                    a.reserved_left -= len(new)
+            n = len(self._active)
+            tokens = np.asarray([a.last_token for a in self._active],
+                                np.int32)
+            positions = np.asarray([a.pos for a in self._active], np.int32)
+            tables = np.stack([a.page_table for a in self._active])
+            # watchdog arms on the device call
+            self._step_started = t0 = time.monotonic()
+        stats["decode_prep_s"] += sp.seconds
         try:
             nxt = self.engine.decode(tokens, positions, tables)
+            now = time.monotonic()
         except Exception as exc:
             # a failed device step fails every resident request — with
             # their pages RETURNED — and the loop keeps serving the
             # queue; one poisoned batch must not wedge the engine
             self._step_started = None
+            stats["decode_s"] += time.monotonic() - t0
             self._fail_batch_locked(exc)
             return True
-        finally:
+        with span("serve.book") as sp:
             self._step_started = None
-        dt = time.monotonic() - t0
-        self._step_times.append(dt)
-        self._step_ewma = (dt if self._step_ewma is None
-                           else 0.2 * dt + 0.8 * self._step_ewma)
-        bucket = self.engine.decode_bucket_for(n)
-        self.stats["occupancy_sum"] += n / bucket
-        self.stats["occupancy_steps"] += 1
-        self._book("pt_serve_batch_occupancy", kind="gauge",
-                   value=n / bucket)
-        still = []
-        for a, t in zip(self._active, nxt):
-            try:
-                a.pos += 1
-                a.last_token = int(t)
-                a.stream.tokens.append(int(t))
-                self.stats["tokens_generated"] += 1
-                self._book("pt_serve_tokens_total", kind="counter")
-                if self._is_finished(a):
-                    self._retire_locked(a)
-                else:
-                    still.append(a)
-            except Exception as exc:
-                # per-row isolation: this request fails alone; its
-                # neighbours keep decoding and its pages come back
-                self._release_locked(a)
-                self.stats["failed"] += 1
-                self._book("pt_serve_request_failures_total",
-                           kind="counter", stage="step")
-                a.stream._finish(error=exc)
-                logger.exception("step bookkeeping failed for request %d",
-                                 a.stream.request_id)
-        self._active = still
+            dt = now - t0
+            stats["decode_s"] += dt
+            self._step_times.append(dt)
+            self._step_ewma = (dt if self._step_ewma is None
+                               else 0.2 * dt + 0.8 * self._step_ewma)
+            bucket = self.engine.decode_bucket_for(n)
+            stats["occupancy_sum"] += n / bucket
+            stats["occupancy_steps"] += 1
+            self._book("pt_serve_batch_occupancy", kind="gauge",
+                       value=n / bucket)
+            still = []
+            booked = 0
+            for a, t in zip(self._active, nxt):
+                try:
+                    a.pos += 1
+                    a.last_token = int(t)
+                    a.stream.tokens.append(int(t))
+                    a.stream.last_token_ts = now
+                    booked += 1
+                    if self._is_finished(a):
+                        self._retire_locked(a)
+                    else:
+                        still.append(a)
+                except Exception as exc:
+                    # per-row isolation: this request fails alone; its
+                    # neighbours keep decoding and its pages come back
+                    self._release_locked(a)
+                    stats["failed"] += 1
+                    self._book("pt_serve_request_failures_total",
+                               kind="counter", stage="step")
+                    a.stream._finish(error=exc)
+                    logger.exception(
+                        "step bookkeeping failed for request %d",
+                        a.stream.request_id)
+            stats["tokens_generated"] += booked
+            self._book("pt_serve_tokens_total", kind="counter",
+                       value=booked)
+            self._active = still
+        stats["book_s"] += sp.seconds
         return True
 
     def _fail_batch_locked(self, exc: BaseException) -> None:
@@ -522,11 +655,21 @@ class ContinuousScheduler:
         pool.free(a.page_ids)
         if a.reserved_left:
             pool.release_reservation(a.reserved_left)
-        a.stream._finish()
+        st = a.stream
+        st._finish()
         self.stats["completed"] += 1
-        lat = a.stream.latency
         self._book("pt_serve_request_latency_seconds", kind="histogram",
-                   value=lat)
+                   value=st.latency)
+        self._book("pt_serve_queue_wait_seconds", kind="histogram",
+                   value=st.queue_wait)
+        self._book("pt_serve_ttft_seconds", kind="histogram",
+                   value=st.ttft)
+        tpot = st.tpot
+        if tpot is not None:
+            self.stats["tpot_s"] += tpot
+            self.stats["tpot_requests"] += 1
+            self._book("pt_serve_tpot_seconds", kind="histogram",
+                       value=tpot)
         self._book("pt_serve_completed_total", kind="counter")
 
     # -- loop management -----------------------------------------------------
@@ -542,6 +685,7 @@ class ContinuousScheduler:
             self._thread = threading.Thread(
                 target=self._loop, name="pt-serve-scheduler", daemon=True)
             self._thread.start()
+        get_tracer()    # PT_TRACE / PT_FLIGHT_RECORDER take effect here
         self._start_watchdog()
 
     def stop(self, timeout: float = 5.0) -> None:
@@ -558,10 +702,12 @@ class ContinuousScheduler:
 
     def _loop(self) -> None:
         while not self._stop.is_set():
-            with self._cv:
-                while (not self._queue and not self._active
-                       and not self._stop.is_set()):
-                    self._cv.wait(0.05)
+            with span("serve.wait") as sp:
+                with self._cv:
+                    while (not self._queue and not self._active
+                           and not self._stop.is_set()):
+                        self._cv.wait(0.05)
+            self.stats["wait_s"] += sp.seconds  # this thread's key alone
             if self._stop.is_set():
                 return
             try:
@@ -694,7 +840,6 @@ class ContinuousScheduler:
             stuck, threshold, rids)
         self._book("pt_serve_hang_watchdog_trips_total", kind="counter")
         try:
-            from ..observability.trace import get_tracer
             get_tracer().flight_dump(
                 reason="serve-hang rid=%s stuck=%.3fs" %
                 (",".join(map(str, rids)) or "-", stuck))
@@ -718,6 +863,14 @@ class ContinuousScheduler:
                 "draining": self._draining,
                 "hang_detected": self.hang_detected,
                 "decode_step_ewma_s": self._step_ewma,
+                # running means over the process's life, seconds
+                "lock_wait_mean_s": _mean(self.stats, "lock_wait_s",
+                                          "submitted"),
+                "queue_wait_mean_s": _mean(self.stats, "queue_wait_s",
+                                           "admitted"),
+                "ttft_mean_s": _mean(self.stats, "ttft_s", "admitted"),
+                "tpot_mean_s": _mean(self.stats, "tpot_s",
+                                     "tpot_requests"),
                 **{k: v for k, v in self.stats.items()
                    if k not in ("occupancy_sum",)},
             }
@@ -730,27 +883,32 @@ class ContinuousScheduler:
 
     def _book(self, name: str, *, kind: str, value: float = 1.0,
               **labels) -> None:
-        """Metric booking; inert while telemetry is off (registry must
-        stay empty then)."""
+        """Metric booking; inert while telemetry is off (the registry
+        must stay empty then).  The registry's instruments are looked
+        up once each and kept, until the registry itself is replaced."""
         try:
-            from ..observability.metrics import get_registry
-            from ..observability.telemetry import get_telemetry
             if not get_telemetry().enabled:
                 return
             reg = get_registry()
-            help_ = _METRIC_HELP.get(name, "")
+            if reg is not self._meter_registry:
+                self._meter_registry, self._meters = reg, {}
+            m = self._meters.get(name)
+            if m is None:
+                m = self._meters[name] = getattr(reg, kind)(
+                    name, _METRIC_HELP.get(name, ""),
+                    labelnames=tuple(labels))
             if kind == "counter":
-                reg.counter(name, help_,
-                            labelnames=tuple(labels)).inc(value, **labels)
+                m.inc(value, **labels)
             elif kind == "gauge":
-                reg.gauge(name, help_,
-                          labelnames=tuple(labels)).set(value, **labels)
+                m.set(value, **labels)
             else:
-                reg.histogram(name, help_,
-                              labelnames=tuple(labels)).observe(
-                    value, **labels)
+                m.observe(value, **labels)
         except Exception:
             pass
+
+
+def _mean(stats, total, count):
+    return stats[total] / stats[count] if stats[count] else None
 
 
 _METRIC_HELP = {
@@ -779,5 +937,11 @@ _METRIC_HELP = {
     "pt_serve_batch_occupancy":
         "Active rows / decode bucket size of the last step",
     "pt_serve_request_latency_seconds":
-        "End-to-end request latency (submit to last token)",
+        "End-to-end request latency (entry of submit to last token)",
+    "pt_serve_queue_wait_seconds":
+        "Time a request waited in the queue (enqueued to its prefill)",
+    "pt_serve_ttft_seconds":
+        "Time to first token (entry of submit to the prefill's token)",
+    "pt_serve_tpot_seconds":
+        "Mean time per output token after the first, per request",
 }

@@ -1,0 +1,372 @@
+"""The per-layer metric readers that rest on what the program itself
+puts into a profiler trace and into its counters (PR 25): `pt:` host
+spans, `jax.named_scope` names of device operations, the scheduler's
+time counters, the compile-stage counters.
+
+Each reader is run as `benchmarks/run.py` runs it, on traces in the
+plain form `benchmarks/trace_reduce.py` documents, built here so that
+every expected number can be worked out by hand, and on the recorded cut
+of a chip trace in `benchmarks/testdata/`.  Where the program leaves no
+such mark (an older commit) a reader returns None and the metric is
+left out of the result line."""
+import importlib.util
+import json
+import os
+import sys
+
+import pytest
+
+import paddle_tpu.observability as obs
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+
+
+@pytest.fixture(scope="module")
+def bench():
+    spec = importlib.util.spec_from_file_location(
+        "bench_run_under_test", os.path.join(BENCH, "run.py"))
+    mod = importlib.util.module_from_spec(spec)
+    path = list(sys.path)
+    spec.loader.exec_module(mod)     # puts benchmarks/ on sys.path
+    yield mod
+    sys.path[:] = path
+
+
+def read(bench, name, run):
+    return bench.load_module("layer_metrics", name).read(run)
+
+
+DECODE, PREFILL, TRAIN = "111", "222", "333"
+
+
+def serve_trace(spans=True, scopes=True):
+    """20 us of one chip: a prefill run, then two decode runs.
+    Times in ns.  Device busy: [1000, 5000) [6000, 9000) [9500, 10000)
+    [11000, 14000) [14500, 15000); idle 9000 of the 20000 window."""
+    ops = [
+        # prefill run [1000, 5000)
+        ["fusion:fusion.1", 1000, 1500],     # layer0/attn (score tensor)
+        ["copy:copy.5", 1200, 700],          # a pool argument's copy, beside it
+        ["copy:copy.2", 2500, 2000],         # kv_write
+        ["fusion:fusion.3", 4500, 500],      # lm_head
+        # decode run [6000, 10000)
+        ["copy:copy.1", 6000, 1000],         # layer0/kv_read
+        ["fusion:fusion.2", 7000, 500],      # layer0/kv_write
+        ["pallas:paged_attention.3", 7500, 1500],   # layer0/attn
+        ["fusion:fusion.4", 9500, 500],      # lm_head
+        # decode run [11000, 15000): the same program again
+        ["copy:copy.1", 11000, 1000],
+        ["fusion:fusion.2", 12000, 500],
+        ["pallas:paged_attention.3", 12500, 1500],
+        ["fusion:fusion.4", 14500, 500],
+    ]
+    modules = [[f"jit_serve_prefill({PREFILL})", 1000, 4000],
+               [f"jit_serve_decode({DECODE})", 6000, 4000],
+               [f"jit_serve_decode({DECODE})", 11000, 4000]]
+    host = [["bench:window", 0, 20000]]
+    if spans:
+        host += [
+            ["pt:serve.evict", 0, 200],                 # idle 200
+            ["pt:serve.admit", 200, 300],               # idle 300
+            ["pt:serve.prefill.prep", 500, 200],        # idle 200
+            ["pt:serve.prefill.launch", 700, 500],      # idle 300
+            ["pt:serve.prefill.fetch", 1200, 3900],     # idle 100 (5000-5100)
+            ["pt:serve.admit", 5100, 400],              # idle 400
+            ["pt:serve.decode.prep", 5500, 300],        # idle 300
+            ["pt:serve.decode.launch", 5800, 400],      # idle 200
+            ["pt:serve.decode.fetch", 6200, 3900],      # idle 500 + 100
+            ["pt:serve.book", 10100, 600],              # idle 600
+            ["pt:serve.wait", 10700, 100],              # idle 100
+            ["pt:serve.decode.launch", 10800, 400],     # idle 200
+            ["pt:serve.decode.fetch", 11200, 3900],     # idle 500 + 100
+            ["pt:serve.book", 15100, 1900],             # idle 1900
+            # [17000, 20000) is under no span: 3000 unspanned
+        ]
+    trace = {"planes": [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": ops},
+            {"name": "XLA Modules", "events": modules}]},
+        {"name": "/host:CPU", "lines": [{"name": "python3",
+                                         "events": host}]}],
+        "text": {}}
+    if scopes:
+        trace["scopes"] = {
+            PREFILL: {"fusion.1": "jit(serve_prefill)/layer0/attn/dot_general:",
+                      "copy.2": "jit(serve_prefill)/kv_write/scatter:",
+                      "copy.5": "v_flat:",
+                      "fusion.3": "jit(serve_prefill)/lm_head/dot_general:"},
+            DECODE: {"copy.1": "jit(serve_decode)/layer0/kv_read/slice:",
+                     "fusion.2": "jit(serve_decode)/layer0/kv_write/scatter:",
+                     "paged_attention.3":
+                         "jit(serve_decode)/layer0/attn/pallas_call:",
+                     "fusion.4": "jit(serve_decode)/lm_head/dot_general:"}}
+    else:
+        # an older program: operations have op_names, none of them a scope
+        trace["scopes"] = {DECODE: {"copy.1": "jit(serve_decode)/slice:"}}
+    return trace
+
+
+def as_run(bench, trace, counters=None):
+    sys.path.insert(0, BENCH)
+    import trace_reduce
+    return {"trace": trace_reduce.Reduced(trace), "trace_dir": None,
+            "counters": counters or {}, "values": {}, "spans": {}}
+
+
+def test_idle_is_attributed_to_the_programs_spans(bench):
+    import program_trace
+    run = as_run(bench, serve_trace())
+    tr = run["trace"]
+    assert tr.window_s == pytest.approx(20e-6)
+    assert tr.window_s - tr.busy_s() == pytest.approx(9e-6)
+    by = program_trace.idle_by_span(tr)
+    assert {k: round(v * 1e9) for k, v in by.items()} == {
+        "serve.evict": 200, "serve.admit": 700, "serve.wait": 100,
+        "serve.book": 2500, "serve.prefill.prep": 200,
+        "serve.prefill.launch": 300, "serve.prefill.fetch": 100,
+        "serve.decode.prep": 300, "serve.decode.launch": 400,
+        "serve.decode.fetch": 1200, "unspanned": 3000}
+    # what no span covers, by the spans on either side: the tail only
+    assert program_trace.unspanned_by_neighbours(tr) == [
+        ["serve.book -> -", pytest.approx(3000e-9)]]
+    sched = 100.0 * (200 + 700 + 100 + 2500) / 20000
+    engine = 100.0 * (200 + 300 + 100 + 300 + 400 + 1200) / 20000
+    for cell in ("steady", "backlog"):
+        assert read(bench, f"idle_sched_pct.{cell}", run) == \
+            pytest.approx(sched)
+        assert read(bench, f"idle_engine_pct.{cell}", run) == \
+            pytest.approx(engine)
+    # with the unspanned rest they are the whole idle share
+    assert sched + engine + 100.0 * 3000 / 20000 == pytest.approx(
+        100.0 * 9000 / 20000)
+
+
+def test_scoped_operations_are_summed_a_program_run(bench):
+    run = as_run(bench, serve_trace())
+    # decode: (kv_read 1000 + kv_write 500) ns in each of 2 runs
+    assert read(bench, "decode_kv_ms_per_step", run) == \
+        pytest.approx(1500 / 1e6)
+    # prefill: the scatter, 2000 ns in its one run, and the copy XLA
+    # names after the pool argument, 700; the score tensor 1500
+    assert read(bench, "prefill_kv_ms_per_run", run) == \
+        pytest.approx(2700 / 1e6)
+    assert read(bench, "prefill_attn_ms_per_run", run) == \
+        pytest.approx(1500 / 1e6)
+
+
+def test_train_readers_go_by_kernel_name_and_scope(bench):
+    ops, at = [], 0
+    for name, dur, scope in [
+            ("pallas:jvp_flash_fwd_.7", 3000,
+             "gpt/layers/0/attn/jvp(flash_fwd):"),
+            ("pallas:layer_norm_fwd.8", 400, "gpt/layers/0/norm1/"),
+            ("fusion:fusion.9", 1000, "lm_head/dot_general:"),
+            ("pallas:softmax_xent_fwd.10", 700, "loss/pallas_call:"),
+            ("pallas:softmax_xent_bwd.11", 800,
+             "loss/transpose(jvp())/pallas_call:"),
+            ("fusion:fusion.12", 900, "lm_head/transpose(jvp())/dot_general:"),
+            ("pallas:flash_bwd_dq.13", 5000,
+             "gpt/layers/0/attn/transpose(jvp())/pallas_call:"),
+            ("pallas:flash_bwd_dkv.14", 6000,
+             "gpt/layers/0/attn/transpose(jvp())/pallas_call:"),
+            ("fusion:fusion.15", 1200, "optimizer/mul:")]:
+        ops.append([name, at, dur, scope])
+        at += dur
+    step = at
+    events = [[n, s + k * step, d] for k in range(2) for n, s, d, _ in ops]
+    trace = {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": events},
+        {"name": "XLA Modules", "events": [
+            [f"jit_captured_step_captured_({TRAIN})", k * step, step]
+            for k in range(2)]}]}],
+        "text": {},
+        "scopes": {TRAIN: {n.split(":")[1]:
+                           "jit(captured_step(captured))/" + sc
+                           for n, _, _, sc in ops}}}
+    run = as_run(bench, trace)
+    assert read(bench, "flash_kernel_ms_per_step", run) == \
+        pytest.approx((3000 + 5000 + 6000) / 1e6)
+    assert read(bench, "lm_head_loss_ms_per_step", run) == \
+        pytest.approx((1000 + 700 + 800 + 900) / 1e6)
+    assert read(bench, "optimizer_ms_per_step", run) == \
+        pytest.approx(1200 / 1e6)
+
+
+def test_scheduler_counter_ratios(bench):
+    counters = {"lock_wait_s": 1.2, "submitted": 480, "queue_wait_s": 9.6,
+                "admitted": 470, "evict_s": 0.01, "admit_host_s": 0.05,
+                "decode_prep_s": 0.03, "book_s": 0.11,
+                "occupancy_steps": 400}
+    run = {"counters": counters, "trace": None}
+    assert read(bench, "submit_lock_wait_ms_mean", run) == \
+        pytest.approx(1e3 * 1.2 / 480)
+    assert read(bench, "queue_wait_ms_mean", run) == \
+        pytest.approx(1e3 * 9.6 / 470)
+    assert read(bench, "sched_host_ms_per_step", run) == \
+        pytest.approx(1e3 * 0.2 / 400)
+
+
+def test_compile_counters_are_read_from_the_registry(bench):
+    obs.reset()
+    try:
+        tel = obs.get_telemetry().enable(compile_watch=False)
+        for stage, s in (("trace", 40.0), ("lower", 9.5),
+                         ("backend_compile", 30.0), ("cache_load", 2.5)):
+            tel.compile_stage(stage, s)
+        for _ in range(3):
+            tel.compile_cache("miss")
+        tel.compile_cache("hit")
+        run = {"trace": None, "counters": {}}
+        assert read(bench, "setup_host_trace_s", run) == 49.5
+        assert read(bench, "setup_backend_compile_s", run) == 32.5
+        assert read(bench, "setup_cache_misses", run) == 3.0
+    finally:
+        obs.reset()
+
+
+NEW_METRICS = [
+    "submit_lock_wait_ms_mean", "queue_wait_ms_mean",
+    "sched_host_ms_per_step", "idle_sched_pct.steady",
+    "idle_sched_pct.backlog", "idle_engine_pct.steady",
+    "idle_engine_pct.backlog", "decode_kv_ms_per_step",
+    "prefill_kv_ms_per_run", "prefill_attn_ms_per_run",
+    "flash_kernel_ms_per_step", "lm_head_loss_ms_per_step",
+    "optimizer_ms_per_step", "setup_host_trace_s",
+    "setup_backend_compile_s", "setup_cache_misses"]
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_reader_returns_none_where_the_program_leaves_no_mark(bench, name):
+    """The parent commit: no `pt:` span, no scope, no such counter or
+    registry metric, kernels called `jvp__.80`.  Traced and untraced."""
+    obs.reset()
+    old = as_run(bench, serve_trace(spans=False, scopes=False),
+                 counters={"submitted": 480, "occupancy_steps": 400})
+    assert read(bench, name, old) is None
+    assert read(bench, name, {"trace": None, "counters": {}}) is None
+
+
+def test_every_new_metric_has_a_reader_and_an_entry(bench):
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    entries = {m["name"]: m for m in spec["per_layer"]}
+    cells = {w["name"] for w in spec["workloads"]}
+    for name in NEW_METRICS:
+        assert os.path.exists(
+            os.path.join(BENCH, "layer_metrics", name + ".py")), name
+        assert set(entries[name].get("workloads", cells)) <= cells
+
+
+# -- the .xplane.pb itself --------------------------------------------------------
+
+def _varint(n):
+    out = bytearray()
+    while True:
+        b = n & 0x7F
+        n >>= 7
+        out.append(b | (0x80 if n else 0))
+        if not n:
+            return bytes(out)
+
+
+def _field(num, value):
+    if isinstance(value, int):
+        return _varint(num << 3) + _varint(value)
+    if isinstance(value, str):
+        value = value.encode()
+    return _varint(num << 3 | 2) + _varint(len(value)) + value
+
+
+def _entry(key, message):
+    return _field(1, key) + _field(2, message)
+
+
+def test_op_scopes_are_read_from_the_event_metadata(bench, tmp_path):
+    """xplane.proto written out by hand: the scope is the stat `tf_op`
+    of an operation's XEventMetadata, beside its `program_id`."""
+    import program_trace
+    stat_names = {1: "hlo_category", 2: "program_id", 3: "tf_op", 4: "flops"}
+
+    def event_metadata(key, line, display, stats):
+        body = _field(1, key) + _field(2, line) + _field(4, display)
+        for s in stats:
+            body += _field(5, s)
+        return _field(4, _entry(key, body))
+
+    def str_stat(which, text):
+        return _field(1, which) + _field(5, text)
+
+    plane = _field(1, 7) + _field(2, "/device:TPU:0")
+    plane += _field(3, _field(2, "XLA Ops"))            # a line, skipped
+    for key, name in stat_names.items():
+        plane += _field(5, _entry(key, _field(1, key) + _field(2, name)))
+    big = 15526790685050851769                          # needs 64 bits
+    plane += event_metadata(
+        1, "%copy.12 = f32[8]{0} copy(f32[8]{0} %p)", "copy.12",
+        [str_stat(1, "data formatting"), _field(1, 2) + _field(3, big),
+         str_stat(3, "jit(serve_decode)/layer3/kv_read/slice:"),
+         _field(1, 4) + _field(4, 0)])
+    plane += event_metadata(
+        2, "%paged_attention.29 = f32[32,1,1024] custom-call(...)",
+        "paged_attention.29",
+        [_field(1, 2) + _field(3, big),
+         str_stat(3, "jit(serve_decode)/layer3/attn/pallas_call:")])
+    plane += event_metadata(
+        3, "%copy.12 = f32[4]{0} copy(f32[4]{0} %q)", "copy.12",
+        [_field(1, 2) + _field(3, 42), str_stat(3, "jit(other)/x:")])
+    plane += event_metadata(4, "no stats at all", "orphan", [])
+    host = _field(2, "/host:CPU") + event_metadata(
+        9, "pt:serve.wait", "", [str_stat(3, "not a device plane")])
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_field(1, host) + _field(1, plane))
+    assert program_trace.read_op_scopes(str(path)) == {
+        str(big): {"copy.12": "jit(serve_decode)/layer3/kv_read/slice:",
+                   "paged_attention.29":
+                       "jit(serve_decode)/layer3/attn/pallas_call:"},
+        "42": {"copy.12": "jit(other)/x:"}}
+
+
+# -- the recorded cut of a chip trace -----------------------------------------------
+
+def test_readers_on_the_recorded_chip_cut(bench):
+    """`testdata/serve_scoped_trace.json`: one serve_prefill run and the
+    serve_decode run after it, cut from this PR's traced run of the
+    steady cell on a TPU v5 lite, `pt:` spans and scopes in it.  The
+    expected numbers were worked out from the JSON by plain loops."""
+    import program_trace
+    data = os.path.join(BENCH, "testdata")
+    trace = json.load(open(os.path.join(data, "serve_scoped_trace.json")))
+    want = json.load(open(os.path.join(data,
+                                       "serve_scoped_trace.expect.json")))
+    run = as_run(bench, trace)
+    tr = run["trace"]
+    assert (tr.t0, tr.t1) == (want["t0_ns"], want["t1_ns"])
+    names = {n for n, _, _ in program_trace.host_spans(trace)}
+    assert names == {"serve.admit", "serve.prefill.prep",
+                     "serve.prefill.launch", "serve.prefill.fetch",
+                     "serve.decode.prep", "serve.decode.launch",
+                     "serve.decode.fetch"}
+    assert read(bench, "decode_kv_ms_per_step", run) == \
+        pytest.approx(want["decode_kv_ms_per_step"], rel=1e-9)
+    assert read(bench, "prefill_kv_ms_per_run", run) == \
+        pytest.approx(want["prefill_kv_ms_per_run"], rel=1e-9)
+    assert program_trace.scoped_ms_per_run(
+        run, r"/layer\d+/attn/", "serve_decode") == \
+        pytest.approx(want["decode_attn_scope_ms"], rel=1e-9)
+    # at bucket 256 no operation of the score tensor reaches the 5 us
+    # the cut keeps, and no train program ran: nothing to read
+    assert read(bench, "prefill_attn_ms_per_run", run) is None
+    assert read(bench, "flash_kernel_ms_per_step", run) is None
+    by = program_trace.idle_by_span(tr)
+    assert {k: round(v * 1e9) for k, v in by.items()} == \
+        want["idle_by_span_ns"]
+    holes = program_trace.unspanned_by_neighbours(tr)
+    assert holes[0][0] == "serve.prefill.fetch -> serve.admit"
+    assert sum(v for _, v in holes) == pytest.approx(by["unspanned"])
+    assert read(bench, "idle_sched_pct.steady", run) == \
+        pytest.approx(want["idle_sched_pct"], rel=1e-9)
+    assert read(bench, "idle_engine_pct.steady", run) == \
+        pytest.approx(want["idle_engine_pct"], rel=1e-9)
+    # the kernel is found by the name the program gave it
+    assert tr.ops_per_run(r"^pallas:paged_attention\.", "serve_decode")[0] \
+        == tr.ops_per_run(r"^pallas:", "serve_decode")[0] > 0.005

@@ -211,3 +211,105 @@ def test_shape_change_compiles_second_entry():
     assert step.stats["compiles"] == 2
     assert step.stats["misses"] == 2
     assert step.stats["hits"] == 2
+
+
+# -- scope names and the one lowering (PR 25) ----------------------------------
+
+def _tiny_captured_step():
+    pt.seed(1)
+
+    class Net(pt.nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.blocks = pt.nn.LayerList(
+                [pt.nn.Linear(8, 8) for _ in range(2)])
+            self.head = pt.nn.Linear(8, 4)
+
+        def forward(self, x):
+            for b in self.blocks:
+                x = pt.nn.functional.relu(b(x))
+            return self.head(x)
+
+    model = Net()
+    opt = pt.optimizer.AdamW(learning_rate=1e-3,
+                             parameters=model.parameters())
+    ce = pt.nn.CrossEntropyLoss()
+
+    @pt.jit.capture_step
+    def step(x, y):
+        loss = ce(model(x), y)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    x = pt.to_tensor(np.random.randn(4, 8).astype("float32"))
+    y = pt.to_tensor(np.array([0, 1, 2, 3]))
+    return step, x, y
+
+
+def test_captured_step_carries_layer_loss_and_optimizer_scopes():
+    import re
+    step, x, y = _tiny_captured_step()
+    step(x, y)
+    (entry,) = step._cache.values()
+    st = step._state
+    lowered = entry.jitted.lower(
+        st.params, st.buffers, st.opt_states, st.rng_ctr,
+        [float(o.get_lr()) for o in st.opts], [x._data, y._data])
+    names = set(re.findall(r'"(jit\([^"]*)"',
+                           lowered.as_text(debug_info=True)))
+    scopes = {"/".join(n.split("/")[1:-1]) for n in names}
+    # a layer's scope is its attribute path from the root; a LayerList is
+    # transparent ("blocks/0", not "0"); the loss and the update have theirs
+    assert {"blocks/0", "blocks/1", "head", "loss", "optimizer"} <= scopes
+    # backward operations are emitted at loss.backward(), outside every
+    # layer's call, and still carry their forward's scope
+    assert any(n.startswith("jit(captured_step(step))/blocks/0/transpose(")
+               for n in names), sorted(names)[:20]
+    assert any("/loss/transpose(" in n for n in names)
+    assert not any("/optimizer/" in n and "/blocks/" in n for n in names)
+
+
+def test_layer_scope_names_follow_registration():
+    inner = pt.nn.LayerList([pt.nn.Linear(2, 2)])
+    root = pt.nn.Layer()
+    assert inner[0]._scope_name == "0" and root._scope_name is None
+    outer = pt.nn.LayerList([inner])
+    assert inner[0]._scope_name == "0/0"
+    root.stack = outer            # named after it was filled
+    assert outer._scope_name == "stack"
+    assert inner._scope_name == "stack/0"
+    assert inner[0]._scope_name == "stack/0/0"
+    inner.append(pt.nn.Linear(2, 2))
+    assert inner[1]._scope_name == "stack/0/1"
+    seq = pt.nn.Sequential(pt.nn.Linear(2, 2))
+    root.seq = seq                # a Sequential is called: its own scope
+    assert seq._scope_name == "seq" and seq[0]._scope_name == "0"
+    assert pt.nn.MSELoss()._scope_name == "loss"
+
+
+def test_traced_first_call_lowers_the_step_once():
+    """With the tracer on, the capture layer used to lower and compile
+    the whole step a second time to read cost_analysis()."""
+    import jax
+    from paddle_tpu.observability.trace import get_tracer, reset_tracer
+    step, x, y = _tiny_captured_step()
+    seen = []
+
+    def listen(event, seconds, **kw):
+        if "captured_step" in str(kw.get("fun_name")):
+            seen.append(event.rsplit("/", 1)[-1])
+
+    reset_tracer()
+    get_tracer().enable()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        step(x, y)
+        step(x, y)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+        reset_tracer()
+    assert seen.count("jaxpr_trace_duration") == 1, seen
+    assert seen.count("jaxpr_to_mlir_module_duration") == 1, seen
+    assert seen.count("backend_compile_duration") == 1, seen

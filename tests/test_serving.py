@@ -382,3 +382,230 @@ def test_http_saturation_returns_429():
         eng.scheduler.step = orig_step
         srv.stop()
         eng.close()
+
+
+# -- where the time goes: spans, counters, stamps (PR 25) ----------------------
+
+_PHASE_KEYS = ("wait_s", "evict_s", "admit_host_s", "prefill_s",
+               "decode_prep_s", "decode_s", "book_s")
+
+
+def _scheduler_thread_spans(engine, prompts, new_tokens):
+    """Run `prompts` through a fresh scheduler's loop with the tracer on;
+    returns (the loop thread's serve.* spans by start, the scheduler)."""
+    from paddle_tpu.observability.trace import get_tracer, reset_tracer
+    from paddle_tpu.serving.scheduler import ContinuousScheduler
+    reset_tracer()
+    tr = get_tracer().enable(capacity=1 << 16)
+    sched = ContinuousScheduler(engine)
+    try:
+        sched.start()
+        ident = sched._thread.ident & 0xFFFFFF
+        streams = [sched.submit(p, max_new_tokens=new_tokens)
+                   for p in prompts]
+        for st in streams:
+            st.result(timeout=60.0)
+        sched.stop(timeout=10.0)
+        spans = sorted((s for s in tr.spans()
+                        if s.name.startswith("serve.") and s.tid == ident),
+                       key=lambda s: s.t0_ns)
+    finally:
+        sched.stop(timeout=10.0)
+        reset_tracer()
+    return spans, sched
+
+
+def test_scheduler_leaf_spans_partition_its_threads_time(engine,
+                                                         monkeypatch):
+    import time
+    prompts = [[1 + i, 2, 3 + i, 4, 5][: 2 + i % 4] for i in range(10)]
+
+    def slowed(exe):
+        # a tiny model's step is under a millisecond on the CPU, of which
+        # the spans' own cost is several percent; a device step is not
+        def call(*args):
+            time.sleep(0.005)
+            return exe(*args)
+        return call
+
+    for exes in (engine._prefill_exe, engine._decode_exe):
+        for key, exe in list(exes.items()):
+            monkeypatch.setitem(exes, key, slowed(exe))
+    for attempt in range(3):     # a preempted test process opens a gap
+        spans, sched = _scheduler_thread_spans(engine, prompts, 6)
+        names = {s.name for s in spans}
+        assert {"serve.wait", "serve.evict", "serve.admit", "serve.book",
+                "serve.prefill.prep", "serve.prefill.launch",
+                "serve.prefill.fetch", "serve.decode.prep",
+                "serve.decode.launch", "serve.decode.fetch"} <= names
+        gaps = [b.t0_ns - a.t1_ns for a, b in zip(spans, spans[1:])]
+        assert min(gaps) >= 0, "leaf spans must not overlap or nest"
+        # the spans start at the loop's first wait and end with its last
+        wall = (spans[-1].t1_ns - spans[0].t0_ns) / 1e9
+        counted = sum(sched.stats[k] for k in _PHASE_KEYS)
+        if max(gaps) < 1e6 and abs(counted - wall) <= 0.02 * wall:
+            break
+    assert max(gaps) < 1e6, f"gap of {max(gaps) / 1e6:.3f} ms between spans"
+    assert abs(counted - wall) <= 0.02 * wall, (counted, wall)
+    assert sched.stats["admitted"] == len(prompts)
+    assert sched.stats["tpot_requests"] == len(prompts)
+
+
+def test_stream_stamps_ordered_and_lock_wait_counted(engine):
+    from paddle_tpu.serving.scheduler import ContinuousScheduler
+    sched = ContinuousScheduler(engine)
+    got = []
+    sched._lock.acquire()            # a step holds this from evict to decode
+    try:
+        t = threading.Thread(target=lambda: got.append(
+            sched.submit([5, 6, 7], max_new_tokens=4)))
+        t.start()
+        threading.Event().wait(0.05)
+        assert not got, "submit() must wait for the step's lock"
+    finally:
+        sched._lock.release()
+    t.join(timeout=10.0)
+    st = got[0]
+    assert sched.stats["lock_wait_s"] >= 0.04
+    assert st.submitted_ts - st.arrived_ts >= 0.04
+    assert st.admitted_ts is None and st.ttft is None and st.tpot is None
+    sched.drain()
+    assert len(st.result(timeout=10.0)) == 4
+    stamps = [st.arrived_ts, st.submitted_ts, st.admitted_ts,
+              st.first_token_ts, st.last_token_ts, st.finished_ts]
+    assert stamps == sorted(stamps)
+    assert st.latency == st.finished_ts - st.arrived_ts
+    assert st.ttft >= st.queue_wait + 0.04 > 0.04
+    assert st.tpot == pytest.approx(
+        (st.last_token_ts - st.first_token_ts) / 3)
+    snap = sched.snapshot()
+    assert snap["lock_wait_mean_s"] >= 0.04
+    assert snap["ttft_mean_s"] == pytest.approx(st.ttft)
+    assert snap["tpot_mean_s"] == pytest.approx(st.tpot)
+    assert snap["queue_wait_mean_s"] == pytest.approx(st.queue_wait)
+
+
+def test_counters_count_with_telemetry_off_and_registry_stays_empty(engine):
+    from paddle_tpu.observability import get_registry, reset_registry
+    from paddle_tpu.observability.telemetry import get_telemetry
+    from paddle_tpu.serving.scheduler import ContinuousScheduler
+    assert not get_telemetry().enabled
+    reset_registry()
+    sched = ContinuousScheduler(engine)
+    streams = [sched.submit([1, 2, 3], max_new_tokens=3) for _ in range(3)]
+    sched.drain()
+    assert all(len(st.result(timeout=10.0)) == 3 for st in streams)
+    assert get_registry().snapshot() == {}
+    s = sched.stats
+    assert s["admitted"] == 3 and s["tokens_generated"] == 9
+    assert s["prefill_s"] > 0 and s["decode_s"] > 0 and s["ttft_s"] > 0
+    assert s["admit_host_s"] > 0 and s["book_s"] > 0 and s["evict_s"] > 0
+
+
+def test_request_histograms_and_one_token_booking_a_step(engine):
+    from paddle_tpu import observability as obs
+    from paddle_tpu.serving.scheduler import ContinuousScheduler
+    assert not obs.get_telemetry().enabled
+    obs.reset_registry()
+    tel = obs.get_telemetry()
+    tel.enable(compile_watch=False)
+    try:
+        sched = ContinuousScheduler(engine)
+        streams = [sched.submit([1, 2, 3], max_new_tokens=5)
+                   for _ in range(3)]
+        sched.drain()
+        assert all(len(st.result(timeout=10.0)) == 5 for st in streams)
+        snap = obs.get_registry().snapshot()
+        for name in ("pt_serve_request_latency_seconds",
+                     "pt_serve_queue_wait_seconds", "pt_serve_ttft_seconds",
+                     "pt_serve_tpot_seconds"):
+            (series,) = snap[name]["series"].values()
+            assert series["count"] == 3, name
+        (tokens,) = snap["pt_serve_tokens_total"]["series"].values()
+        assert tokens == sched.stats["tokens_generated"] == 15
+        # the handles are looked up once, then kept
+        assert set(sched._meters) >= {"pt_serve_tokens_total",
+                                      "pt_serve_ttft_seconds"}
+    finally:
+        tel.enabled = False       # the module's engine keeps its sentinel
+        obs.reset_registry()
+
+
+def test_watchdog_flight_dump_names_serve_spans(engine, monkeypatch,
+                                                tmp_path):
+    from paddle_tpu.observability.trace import get_tracer, reset_tracer
+    from paddle_tpu.serving.scheduler import ContinuousScheduler
+    import time
+    monkeypatch.setenv("PT_SERVE_WATCHDOG", "1")
+    monkeypatch.setenv("PT_SERVE_WATCHDOG_FLOOR_S", "0.2")
+    reset_tracer()
+    tr = get_tracer().enable(flight_dir=str(tmp_path))
+    sched = ContinuousScheduler(engine)
+    orig = engine.decode
+    hang = threading.Event()
+
+    def decode(*args, **kw):
+        if hang.is_set():
+            time.sleep(1.0)
+        return orig(*args, **kw)
+
+    monkeypatch.setattr(engine, "decode", decode)
+    sched.start()
+    try:
+        sched.submit([1, 2, 3], max_new_tokens=3).result(timeout=30.0)
+        hang.set()
+        st = sched.submit([1, 2, 3], max_new_tokens=3)
+        deadline = time.monotonic() + 10.0
+        while not sched.hang_detected and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert sched.hang_detected
+        doc = json.load(open(tr.flight_path))
+        assert doc["reason"].startswith("serve-hang")
+        names = {s["name"] for s in doc["spans"]}
+        assert {"serve.decode.launch", "serve.prefill.launch",
+                "serve.admit"} <= names
+        st.result(timeout=10.0)
+    finally:
+        sched.stop(timeout=10.0)
+        reset_tracer()
+
+
+def _op_names(lowered):
+    import re
+    return set(re.findall(r'"(jit\([^"]*)"', lowered.as_text(debug_info=True)))
+
+
+def test_serve_programs_carry_scope_names():
+    import jax
+    from paddle_tpu.serving.model import decode_step, prefill_step
+    from paddle_tpu.serving.engine import aot_build_phase
+    ps, pages = 4, 8
+    i32 = jnp.int32
+    with aot_build_phase():      # the eager zeros compile: not an incident
+        params = init_params(SPEC, seed=0)
+        pool = jnp.zeros((SPEC.layers, pages * ps, SPEC.heads,
+                          SPEC.head_dim))
+        dec = _op_names(jax.jit(
+            lambda p, k, v, t, pos, pt: decode_step(
+                SPEC, p, k, v, t, pos, pt, page_size=ps)).lower(
+            params, pool, pool, jnp.zeros((2,), i32), jnp.zeros((2,), i32),
+            jnp.zeros((2, 16), i32)))
+        pre = _op_names(jax.jit(
+            lambda p, k, v, t, n, pt: prefill_step(
+                SPEC, p, k, v, t, n, pt, page_size=ps)).lower(
+            params, pool, pool, jnp.zeros((16,), i32), i32(3),
+            jnp.zeros((16,), i32)))
+
+    def scoped(names, scope):
+        return [n for n in names if f"/{scope}/" in n + "/"]
+
+    for scope in ("embed", "layer0/attn_qkv", "layer0/kv_write",
+                  "layer0/kv_read", "layer1/kv_read", "layer0/attn",
+                  "layer0/attn_out", "layer1/mlp", "lm_head", "sample"):
+        assert scoped(dec, scope), scope
+    assert any(n.endswith("kv_write/scatter") for n in dec)
+    for scope in ("embed", "layer0/attn_qkv", "layer0/attn", "layer1/mlp",
+                  "lm_head", "sample", "kv_write"):
+        assert scoped(pre, scope), scope
+    # prefill writes every layer's K and V in one scatter after the stack
+    assert not scoped(pre, "layer0/kv_write")
