@@ -44,8 +44,11 @@ FULL = {
     "serve": {
         "spec": dict(vocab_size=50304, hidden=1024, layers=24, heads=16,
                      max_seq_len=1024),
-        "prefill_buckets": (128, 256, 512), "decode_buckets": (4, 8),
-        "kv_pages": 384, "page_size": 16,
+        # the engine configuration the benchmark's serve cells face
+        # (benchmarks/configs/gpt-345m-serve.json): seven programs
+        "prefill_buckets": (128, 256, 512, 1024),
+        "decode_buckets": (8, 16, 32),
+        "kv_pages": 1024, "page_size": 16,
         "prompt_lens": (100, 500), "requests": 8, "max_new": 32,
     },
     # global batch 4: the one-chip oracle holds the whole model plus the
@@ -264,6 +267,23 @@ def serve_phase(size, rehearsal):
         f"compile watcher saw {aot_watched} serve compiles for "
         f"{engine.compiled_programs} AOT programs: {watched}")
 
+    # what each program keeps in memory (the engine's counter): the pools
+    # aliased through, and on the chip no temporary near a pool's size —
+    # a copied, sliced or re-laid pool would show here
+    program_bytes = engine.stats["program_bytes"]
+    pool_bytes = int(engine.pool.k_pool.nbytes)
+    for name, got in sorted(program_bytes.items()):
+        print(f"[chip_smoke] serve program bytes {name}: "
+              f"temp={got['temp']} argument={got['argument']} "
+              f"alias={got['alias']} (one pool: {pool_bytes})", flush=True)
+    assert len(program_bytes) == engine.compiled_programs, program_bytes
+    if not rehearsal:
+        for name, got in program_bytes.items():
+            assert got["alias"] >= 2 * pool_bytes, (name, got)
+            assert got["temp"] < pool_bytes / 10, (
+                f"{name} holds {got['temp']} bytes of temporaries beside "
+                f"pools of {pool_bytes}: a pool is being copied")
+
     rng = np.random.RandomState(1)
     lo, hi = size["prompt_lens"]
     prompts = [rng.randint(0, spec.vocab_size,
@@ -295,20 +315,20 @@ def serve_phase(size, rehearsal):
     maxp = engine.max_pages_per_seq
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(kq, (b, spec.heads, spec.head_dim), jnp.float32)
-    k_pages = jax.random.normal(
-        kk, (pages, ps, spec.heads, spec.head_dim), jnp.float32)
-    v_pages = jax.random.normal(
-        kv, (pages, ps, spec.heads, spec.head_dim), jnp.float32)
+    # whole pools as the engine holds them, (L, P, ps, H*D), two layers
+    # deep, and the second layer read: the kernel's block picks the layer
+    k_pool = jax.random.normal(kk, (2, pages, ps, spec.hidden), jnp.float32)
+    v_pool = jax.random.normal(kv, (2, pages, ps, spec.hidden), jnp.float32)
     tables = jnp.asarray(rng.randint(1, pages, (b, maxp)), jnp.int32)
     lengths = jnp.asarray(rng.randint(1, maxp * ps + 1, (b,)), jnp.int32)
     # the rehearsal forces the kernel (interpret mode) so its numerics
     # are checked off the chip too; on the chip it is the default route
     force = {"use_pallas": True} if rehearsal else {}
-    got = jax.jit(lambda *a: paged_attention(*a, **force))(
-        q, k_pages, v_pages, tables, lengths)
+    got = jax.jit(lambda *a: paged_attention(*a, layer=1, **force))(
+        q, k_pool, v_pool, tables, lengths)
     with jax.default_matmul_precision("highest"):
-        want = jax.jit(paged_attention_reference)(
-            q, k_pages, v_pages, tables, lengths)
+        want = jax.jit(lambda *a: paged_attention_reference(*a, layer=1))(
+            q, k_pool, v_pool, tables, lengths)
     err = float(jnp.max(jnp.abs(got - want)))
     assert err <= PAGED_ATOL, f"paged attention off its reference by {err}"
     routes = pallas_routes()
@@ -321,7 +341,8 @@ def serve_phase(size, rehearsal):
              "requests": len(prompts),
              "prompt_lens": [len(p) for p in prompts],
              "new_tokens": size["max_new"],
-             "paged_attention_max_err": err, "pallas_routes": routes}
+             "paged_attention_max_err": err, "pallas_routes": routes,
+             "program_bytes": program_bytes}
     if not rehearsal:
         n_tok = len(prompts) * size["max_new"]
         facts.update(aot_build_s=round(build_s, 2),

@@ -9,7 +9,7 @@ The reference stack reaches the same shape through
 first-class:
 
  - :func:`paged_attention_reference` — the XLA path: gather the page
-   window ``k_pages[page_tables]`` → masked softmax attention.  Row
+   window ``k_pool[layer, page_tables]`` → masked softmax attention.  Row
    independent by construction, which is what makes continuous
    batching bit-stable (a sequence's logits do not depend on its batch
    neighbours or on which physical pages it landed in).
@@ -21,9 +21,17 @@ first-class:
    gather never materialises in HBM), online-softmax accumulators in
    VMEM scratch.  Interpret-runnable off-TPU.
 
-Shapes (one layer; the model loops layers):
+Shapes (the model loops layers and passes the pools whole each time):
   q            (B, H, D)        one query token per sequence
-  k/v_pages    (P, ps, H, D)    the whole pool, P pages of ps tokens
+  k/v_pool     (L, P, ps, H*D)  every layer's pages, P pages of ps
+                                tokens, a token's heads side by side on
+                                the lanes; the kernel's block is one
+                                ``(ps, H*D)`` page of layer ``layer``,
+                                fetched where it lies: no slice, reshape
+                                or copy stands between pool and kernel
+  k/v_scale    (L, P, ps, H)    int8 pools only: f32 scale per (token,
+                                head), addressed like the values
+  layer        static int       which layer's pages to read
   page_tables  (B, max_pages)   int32 page ids, position t lives in
                                 page ``pt[b, t // ps]`` slot ``t % ps``
   lengths      (B,) int32       valid context per row (pos of the new
@@ -49,21 +57,20 @@ __all__ = ["paged_attention", "paged_attention_reference",
            "tune_paged_attention_int8"]
 
 
-def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
-                              *, sm_scale=None):
+def paged_attention_reference(q, k_pool, v_pool, page_tables, lengths,
+                              *, layer, sm_scale=None):
     """XLA reference: gather the page window, masked softmax attention.
 
     f32 scores/accumulation regardless of operand dtype (the MXU
     contract from :mod:`.pallas_ops`); output in ``q.dtype``.
     """
     b, h, d = q.shape
-    ps = k_pages.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    # (B, max_pages, ps, H, D) -> (B, C, H, D); position t sits at
+    # (B, max_pages, ps, H*D) -> (B, C, H, D); position t sits at
     # context index t because pages fill in order
-    k_ctx = k_pages[page_tables].reshape(b, -1, h, d)
-    v_ctx = v_pages[page_tables].reshape(b, -1, h, d)
+    k_ctx = k_pool[layer, page_tables].reshape(b, -1, h, d)
+    v_ctx = v_pool[layer, page_tables].reshape(b, -1, h, d)
     s = jnp.einsum("bhd,bchd->bhc", q, k_ctx,
                    preferred_element_type=jnp.float32) * sm_scale
     c = k_ctx.shape[1]
@@ -162,18 +169,24 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref,
         _finalize(o_ref, segt_ref[...], l_scr, acc_scr)
 
 
-def _paged_call(kernel, q, pages, extra, extra_specs, page_tables, lengths,
-                *, name, batch_semantics, interpret):
-    """Shared pallas_call plumbing: q (B, H, D) and (P, ps, H, D) pools
-    enter flattened to H*D lanes; ``extra``/``extra_specs`` are the int8
-    kernel's scale operands."""
+def _paged_call(kernel, q, pools, layer, extra, extra_specs, page_tables,
+                lengths, *, name, batch_semantics, interpret):
+    """Shared pallas_call plumbing: q (B, H, D) enters flattened to H*D
+    lanes, the (L, P, ps, H*D) pools enter whole and the page block's
+    index map picks ``(layer, pt[b, i])``; ``extra``/``extra_specs`` are
+    the int8 kernel's scale operands."""
     b, h, d = q.shape
-    ps = pages[0].shape[1]
     hd = h * d
+    ps = pools[0].shape[2]
+    if pools[0].shape[3] != hd:
+        raise ValueError(
+            f"pool rows hold {pools[0].shape[3]} lanes, q has {h} heads "
+            f"of {d}")
     seg, segt = _head_selectors(h, d)
     lanes = seg.shape[1]
-    page_spec = pl.BlockSpec((None, ps, hd),
-                             lambda bi, i, pt, ln: (pt[bi, i], 0, 0))
+    page_spec = pl.BlockSpec(
+        (None, None, ps, hd),
+        lambda bi, i, pt, ln: (layer, pt[bi, i], 0, 0))
     row_spec = pl.BlockSpec((None, 1, hd), lambda bi, i, pt, ln: (bi, 0, 0))
 
     def resident(a):  # one block spanning the operand, fetched once
@@ -182,7 +195,7 @@ def _paged_call(kernel, q, pages, extra, extra_specs, page_tables, lengths,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, page_tables.shape[1]),
-        in_specs=[row_spec] + [page_spec] * len(pages) + list(extra_specs)
+        in_specs=[row_spec] + [page_spec] * len(pools) + list(extra_specs)
         + [resident(seg), resident(segt)],
         out_specs=row_spec,
         scratch_shapes=[
@@ -199,25 +212,25 @@ def _paged_call(kernel, q, pages, extra, extra_specs, page_tables, lengths,
             dimension_semantics=(batch_semantics, "arbitrary")),
         name=name,
         interpret=interpret,
-    )(page_tables, lengths, q.reshape(b, 1, hd),
-      *[p.reshape(p.shape[0], ps, hd) for p in pages], *extra, seg, segt)
+    )(page_tables, lengths, q.reshape(b, 1, hd), *pools, *extra, seg, segt)
     return out.reshape(b, h, d)
 
 
-def _paged_attention_pallas(q, k_pages, v_pages, page_tables, lengths,
-                            *, sm_scale, interpret):
-    kernel = functools.partial(_paged_kernel, ps=k_pages.shape[1],
+def _paged_attention_pallas(q, k_pool, v_pool, page_tables, lengths,
+                            *, layer, sm_scale, interpret):
+    kernel = functools.partial(_paged_kernel, ps=k_pool.shape[2],
                                max_pages=page_tables.shape[1],
                                sm_scale=sm_scale)
-    return _paged_call(kernel, q, (k_pages, v_pages), (), (), page_tables,
-                       lengths, name="paged_attention",
+    return _paged_call(kernel, q, (k_pool, v_pool), layer, (), (),
+                       page_tables, lengths, name="paged_attention",
                        batch_semantics="parallel", interpret=interpret)
 
 
-def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
+def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
                     sm_scale=None, use_pallas=None, interpret=None):
     """Dispatching entry: the Pallas paged-attention kernel on TPU, the
-    XLA gather+softmax reference elsewhere.
+    XLA gather+softmax reference elsewhere.  Both read layer ``layer``
+    of the whole ``(L, P, ps, H*D)`` pools.
 
     Off-TPU the default is the reference (interpret-mode Pallas is a
     correctness vehicle, not a fast path); pass ``use_pallas=True`` to
@@ -235,12 +248,14 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
         use_pallas = _device.pallas_dispatch()  # reference off the TPU
     if use_pallas:
         record_dispatch("paged_attention", "pallas")
-        return _paged_attention_pallas(q, k_pages, v_pages, page_tables,
-                                       lengths, sm_scale=sm_scale,
+        return _paged_attention_pallas(q, k_pool, v_pool, page_tables,
+                                       lengths, layer=layer,
+                                       sm_scale=sm_scale,
                                        interpret=interpret)
     record_dispatch("paged_attention", "fallback")
-    return paged_attention_reference(q, k_pages, v_pages, page_tables,
-                                     lengths, sm_scale=sm_scale)
+    return paged_attention_reference(q, k_pool, v_pool, page_tables,
+                                     lengths, layer=layer,
+                                     sm_scale=sm_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -248,23 +263,28 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
 # ---------------------------------------------------------------------------
 # Same attention, but the pool stores int8 values with per-(token, head)
 # f32 scales riding beside them (``PagePool(dtype=int8, scale_pages=
-# True)``): k/v_pages are (P, ps, H, D) int8 and k/v_scale are
-# (P, ps, H) f32.  Dequantization happens at the attention's edge —
+# True)``): k/v_pool are (L, P, ps, H*D) int8 and k/v_scale are
+# (L, P, ps, H) f32.  Dequantization happens at the attention's edge —
 # scores and accumulation stay f32, so the math after the unpack is the
 # exact fp32 kernel above and the row-independence (bit-identity)
 # argument carries over unchanged.
 
-def paged_attention_int8_reference(q, k_pages, v_pages, k_scale, v_scale,
-                                   page_tables, lengths, *, sm_scale=None):
+def paged_attention_int8_reference(q, k_pool, v_pool, k_scale, v_scale,
+                                   page_tables, lengths, *, layer,
+                                   sm_scale=None):
     """XLA reference for int8 pages: gather values AND scales through
     the page table, dequantize, masked softmax attention (f32)."""
     b, h, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    k_ctx = (k_pages[page_tables].astype(jnp.float32)
-             * k_scale[page_tables][..., None]).reshape(b, -1, h, d)
-    v_ctx = (v_pages[page_tables].astype(jnp.float32)
-             * v_scale[page_tables][..., None]).reshape(b, -1, h, d)
+
+    def ctx(pool, scale):
+        vals = pool[layer, page_tables].astype(jnp.float32)
+        vals = vals.reshape(*vals.shape[:-1], h, d)
+        return (vals * scale[layer, page_tables][..., None]
+                ).reshape(b, -1, h, d)
+
+    k_ctx, v_ctx = ctx(k_pool, k_scale), ctx(v_pool, v_scale)
     s = jnp.einsum("bhd,bchd->bhc", q.astype(jnp.float32), k_ctx) * sm_scale
     c = k_ctx.shape[1]
     mask = jnp.arange(c, dtype=jnp.int32)[None, :] < lengths[:, None]
@@ -303,28 +323,31 @@ def _paged_kernel_int8(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
         _finalize(o_ref, segt_ref[...], l_scr, acc_scr)
 
 
-def _paged_attention_int8_pallas(q, k_pages, v_pages, k_scale, v_scale,
-                                 page_tables, lengths, *, sm_scale,
+def _paged_attention_int8_pallas(q, k_pool, v_pool, k_scale, v_scale,
+                                 page_tables, lengths, *, layer, sm_scale,
                                  interpret, batch_semantics="parallel"):
     h = q.shape[1]
-    ps = k_pages.shape[1]
+    ps = k_pool.shape[2]
     lanes = -(-h // _LANES) * _LANES
-    # scales ride as (P, ps, LANES) rows aligned with the score columns
+    # the layer's scales ride as (P, ps, LANES) rows aligned with the
+    # score columns: H lanes padded to a tile, a copy of one layer's
+    # scales each call (the value pools enter whole)
     pad = ((0, 0), (0, 0), (0, lanes - h))
     scale_spec = pl.BlockSpec((None, ps, lanes),
                               lambda bi, i, pt, ln: (pt[bi, i], 0, 0))
     kernel = functools.partial(_paged_kernel_int8, ps=ps,
                                max_pages=page_tables.shape[1],
                                sm_scale=sm_scale)
-    return _paged_call(kernel, q, (k_pages, v_pages),
-                       (jnp.pad(k_scale, pad), jnp.pad(v_scale, pad)),
+    return _paged_call(kernel, q, (k_pool, v_pool), layer,
+                       (jnp.pad(k_scale[layer], pad),
+                        jnp.pad(v_scale[layer], pad)),
                        (scale_spec, scale_spec), page_tables, lengths,
                        name="paged_attention_int8",
                        batch_semantics=batch_semantics, interpret=interpret)
 
 
-def paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
-                         page_tables, lengths, *, sm_scale=None,
+def paged_attention_int8(q, k_pool, v_pool, k_scale, v_scale,
+                         page_tables, lengths, *, layer, sm_scale=None,
                          use_pallas=None, interpret=None):
     """Dispatching entry for the int8-KV pool: Pallas kernel on TPU, XLA
     gather+dequant+softmax reference elsewhere (same rule as
@@ -342,27 +365,29 @@ def paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
         sem = "parallel"
         if _at.enabled():
             cached = _at.cache_get("paged_attention_int8",
-                                   _int8_tune_key(q, k_pages, interpret))
+                                   _int8_tune_key(q, k_pool, interpret))
             if cached:
                 sem = str(cached[0])
         record_dispatch("paged_attention_int8", "pallas")
         return _paged_attention_int8_pallas(
-            q, k_pages, v_pages, k_scale, v_scale, page_tables, lengths,
-            sm_scale=sm_scale, interpret=interpret, batch_semantics=sem)
+            q, k_pool, v_pool, k_scale, v_scale, page_tables, lengths,
+            layer=layer, sm_scale=sm_scale, interpret=interpret,
+            batch_semantics=sem)
     record_dispatch("paged_attention_int8", "fallback")
     return paged_attention_int8_reference(
-        q, k_pages, v_pages, k_scale, v_scale, page_tables, lengths,
-        sm_scale=sm_scale)
+        q, k_pool, v_pool, k_scale, v_scale, page_tables, lengths,
+        layer=layer, sm_scale=sm_scale)
 
 
-def _int8_tune_key(q, k_pages, interpret):
+def _int8_tune_key(q, k_pool, interpret):
     b, h, d = q.shape
-    return (b, h, d, int(k_pages.shape[0]), int(k_pages.shape[1]),
+    return (b, h, d, int(k_pool.shape[1]), int(k_pool.shape[2]),
             int(interpret))
 
 
-def tune_paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
-                              page_tables, lengths, *, interpret=None):
+def tune_paged_attention_int8(q, k_pool, v_pool, k_scale, v_scale,
+                              page_tables, lengths, *, layer,
+                              interpret=None):
     """Warmup autotune over the kernel's grid-semantics choice (the
     batch axis can run parallel or arbitrary; which wins depends on the
     page count per core) via :func:`autotune.search` under the
@@ -371,14 +396,14 @@ def tune_paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
     if interpret is None:
         interpret = _interpret_default()
     b, h, d = q.shape
-    ps = k_pages.shape[1]
+    layers, ps = k_pool.shape[0], k_pool.shape[2]
     # int8 k/v page tiles + f32 scales + online-softmax scratch per step
     vmem = 2 * ps * h * d + 2 * ps * h * 4 + h * d * 4 + 2 * h * 4
 
     def cost(cfg):
         return {"flops": 4.0 * b * h * ps * d * page_tables.shape[1],
-                "bytes": float(q.size * 4 + 2 * k_pages.size
-                               + 2 * k_scale.size * 4),
+                "bytes": float(q.size * 4 + (2 * k_pool.size
+                               + 2 * k_scale.size * 4) / layers),
                 "vmem_bytes": vmem, "mxu_underfill": False}
 
     cands = _at.generate_candidates(
@@ -386,13 +411,13 @@ def tune_paged_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
 
     def run(cfg):
         out = _paged_attention_int8_pallas(
-            q, k_pages, v_pages, k_scale, v_scale, page_tables, lengths,
-            sm_scale=1.0 / math.sqrt(d), interpret=interpret,
+            q, k_pool, v_pool, k_scale, v_scale, page_tables, lengths,
+            layer=layer, sm_scale=1.0 / math.sqrt(d), interpret=interpret,
             batch_semantics=str(cfg[0]))
         float(jnp.sum(out.astype(jnp.float32)))
 
     best, timings = _at.search(
-        "paged_attention_int8", _int8_tune_key(q, k_pages, interpret),
+        "paged_attention_int8", _int8_tune_key(q, k_pool, interpret),
         run, cands, cost=cost)
     _at.set_enabled(True)
     return best, timings

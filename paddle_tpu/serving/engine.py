@@ -16,6 +16,11 @@ path would book a tiny convert/copy compile.
 KV state is donated: each executable takes the pool arrays, writes the
 step's K/V in place (XLA aliases the buffers — the PR 7 capture
 convention), and the engine rebinds the pool to the returned arrays.
+What says the donation holds is what each program keeps in memory: the
+build records every executable's temporary, argument and aliased bytes
+(``engine.stats["program_bytes"]``, ``/healthz``,
+``pt_serve_program_bytes{program=,kind=}``).  A program that copied,
+sliced or re-laid a pool would show a pool-sized temporary there.
 
 Zero-downtime weight swap: with a ``CheckpointManager`` attached,
 :meth:`ServingEngine.maybe_reload` hot-swaps to generation N+1 between
@@ -254,6 +259,9 @@ class ServingEngine:
             self._prefill_exe: Dict[int, Any] = {}
             self._decode_exe: Dict[int, Any] = {}
             self.compiled_programs = 0
+            # program name -> {"temp", "argument", "alias"} bytes, from
+            # each executable's memory_analysis() at build
+            self.stats: Dict[str, Any] = {"program_bytes": {}}
             self._build_programs()
             self._warmup()
         self._arm_sentinel()
@@ -294,7 +302,7 @@ class ServingEngine:
         ps = cfg.page_size
         int8 = cfg.precision == "int8"
         p_struct = _struct_like(self._params)
-        k_struct = _struct_like(self.pool.k_flat)
+        k_struct = _struct_like(self.pool.k_pool)
         s_struct = _struct_like(self.pool.k_scale) if int8 else None
         i32 = np.int32
         # fp32 keeps its PR 15 program names so audit/bench baselines
@@ -308,35 +316,35 @@ class ServingEngine:
             # the scale pools are donated state exactly like the value
             # pools — the step rewrites both and the engine rebinds all
             # four (donate_argnums covers 1..4)
-            def serve_prefill(params, k_flat, v_flat, k_scale, v_scale,
+            def serve_prefill(params, k_pool, v_pool, k_scale, v_scale,
                               tokens, length, page_table):
-                return prefill_step(spec, params, k_flat, v_flat, tokens,
+                return prefill_step(spec, params, k_pool, v_pool, tokens,
                                     length, page_table, page_size=ps,
                                     k_scale=k_scale, v_scale=v_scale)
 
-            def serve_decode(params, k_flat, v_flat, k_scale, v_scale,
+            def serve_decode(params, k_pool, v_pool, k_scale, v_scale,
                              tokens, positions, page_tables):
-                return decode_step(spec, params, k_flat, v_flat, tokens,
+                return decode_step(spec, params, k_pool, v_pool, tokens,
                                    positions, page_tables, page_size=ps,
                                    k_scale=k_scale, v_scale=v_scale)
 
             donate = (1, 2, 3, 4)
-            labels = ("params", "k_flat", "v_flat", "k_scale", "v_scale",
+            labels = ("params", "k_pool", "v_pool", "k_scale", "v_scale",
                       "tokens", "positions", "page_tables")
             kv_args = (k_struct, k_struct, s_struct, s_struct)
         else:
-            def serve_prefill(params, k_flat, v_flat, tokens, length,
+            def serve_prefill(params, k_pool, v_pool, tokens, length,
                               page_table):
-                return prefill_step(spec, params, k_flat, v_flat, tokens,
+                return prefill_step(spec, params, k_pool, v_pool, tokens,
                                     length, page_table, page_size=ps)
 
-            def serve_decode(params, k_flat, v_flat, tokens, positions,
+            def serve_decode(params, k_pool, v_pool, tokens, positions,
                              page_tables):
-                return decode_step(spec, params, k_flat, v_flat, tokens,
+                return decode_step(spec, params, k_pool, v_pool, tokens,
                                    positions, page_tables, page_size=ps)
 
             donate = (1, 2)
-            labels = ("params", "k_flat", "v_flat", "tokens",
+            labels = ("params", "k_pool", "v_pool", "tokens",
                       "positions", "page_tables")
             kv_args = (k_struct, k_struct)
 
@@ -363,6 +371,7 @@ class ServingEngine:
                                       args, labels=labels)
                 exe = traced.lower().compile()
             self._account_compile(name)
+            self._record_program_bytes(name, exe)
             return exe
 
         for s in cfg.prefill_buckets:
@@ -400,13 +409,39 @@ class ServingEngine:
         except Exception:
             pass
 
+    def _record_program_bytes(self, name: str, exe) -> None:
+        """Book what the compiled program keeps in memory: temporaries,
+        arguments, and the argument bytes it aliases to outputs (the
+        donated pools, when the donation reached the executable).  A
+        backend that gives no memory analysis leaves the entry out."""
+        try:
+            mem = exe.memory_analysis()
+            got = {"temp": int(mem.temp_size_in_bytes),
+                   "argument": int(mem.argument_size_in_bytes),
+                   "alias": int(mem.alias_size_in_bytes)}
+        except Exception:
+            return
+        self.stats["program_bytes"][name] = got
+        try:
+            from ..observability.metrics import get_registry
+            from ..observability.telemetry import get_telemetry
+            if get_telemetry().enabled:
+                g = get_registry().gauge(
+                    "pt_serve_program_bytes",
+                    "Bytes a compiled serve program holds, by kind",
+                    labelnames=("program", "kind"))
+                for kind, n in got.items():
+                    g.set(n, program=name, kind=kind)
+        except Exception:
+            pass
+
     def _kv_state(self):
         """The donated pool arrays in program argument order (value
         pools, plus scale pools on a quantized engine)."""
         if self.pool.scale_pages:
-            return (self.pool.k_flat, self.pool.v_flat,
+            return (self.pool.k_pool, self.pool.v_pool,
                     self.pool.k_scale, self.pool.v_scale)
-        return (self.pool.k_flat, self.pool.v_flat)
+        return (self.pool.k_pool, self.pool.v_pool)
 
     def _warmup(self) -> None:
         """Execute every program once so first-request latency pays no
@@ -424,7 +459,7 @@ class ServingEngine:
                                np.zeros((b,), np.int32),
                                np.zeros((b, maxp), np.int32))
             self.pool.swap(*state)
-        jax.block_until_ready(self.pool.k_flat)
+        jax.block_until_ready(self.pool.k_pool)
 
     def _arm_sentinel(self) -> None:
         """After this point, ANY observed compile is a request-path
@@ -612,6 +647,7 @@ class ServingEngine:
             "kv_consistent": kv_consistent,
             "unexpected_compiles": self.unexpected_compiles,
             "compiled_programs": self.compiled_programs,
+            "program_bytes": self.stats["program_bytes"],
             "precision": self.config.precision,
             "decode_buckets": list(self.config.decode_buckets),
             "prefill_buckets": list(self.config.prefill_buckets),

@@ -1,7 +1,11 @@
 """Paged KV-cache: a block pool of fixed-size pages with free-list reuse.
 
 The pool owns the device arrays the decode/prefill programs donate and
-rebind each step (``k_flat``/``v_flat``, shape ``(L, P*ps, H, D)``), a
+rebind each step (``k_pool``/``v_pool``, shape ``(L, P, ps, H*D)``:
+page-major, a token's heads side by side on the lanes — the layout the
+paged-attention kernel's page block reads, so the programs write a row
+(decode) or whole pages (prefill) into it and hand it to the kernel with
+no slice, reshape or copy between; :func:`pool_shapes`), a
 host-side free list of page ids, and a *reservation* ledger used for
 admission control: the scheduler reserves a sequence's worst-case page
 count (prompt + max_new_tokens) before prefill so a sequence admitted
@@ -26,9 +30,21 @@ from typing import Dict, List, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagePool", "KVPoolExhausted", "NULL_PAGE", "kv_page_budget"]
+__all__ = ["PagePool", "KVPoolExhausted", "NULL_PAGE", "kv_page_budget",
+           "pool_shapes"]
 
 NULL_PAGE = 0
+
+
+def pool_shapes(layers: int, pages: int, page_size: int, heads: int,
+                head_dim: int):
+    """``(value_shape, scale_shape)`` of the K/V pools: values
+    ``(L, P, ps, H*D)``, the int8 pools' per-(token, head) f32 scales
+    ``(L, P, ps, H)``.  Position ``t`` of a sequence lives at
+    ``[layer, page_table[t // ps], t % ps]`` in both — the one
+    addressing every precision, program and kernel uses."""
+    return ((layers, pages, page_size, heads * head_dim),
+            (layers, pages, page_size, heads))
 
 
 def kv_page_budget(pages: int, precision: str, head_dim: int) -> int:
@@ -59,7 +75,9 @@ class KVPoolExhausted(RuntimeError):
 
 
 class PagePool:
-    """Block-pool allocator over the serve KV arrays.
+    """Block-pool allocator over the serve KV arrays (``k_pool`` /
+    ``v_pool`` ``(L, P, ps, H*D)``, and ``k_scale`` / ``v_scale``
+    ``(L, P, ps, H)`` beside an int8 pool).
 
     Thread-safety: all bookkeeping is lock-guarded; the device arrays
     themselves are only rebound from the engine's step loop.
@@ -80,10 +98,10 @@ class PagePool:
         # "scale pages" addressed by the same page table (the scale
         # travels with the tensor — the TPU022 contract)
         self.scale_pages = bool(scale_pages)
-        shape = (layers, pages * page_size, heads, head_dim)
-        self.k_flat = jnp.zeros(shape, dtype)
-        self.v_flat = jnp.zeros(shape, dtype)
-        sshape = (layers, pages * page_size, heads)
+        shape, sshape = pool_shapes(layers, pages, page_size, heads,
+                                    head_dim)
+        self.k_pool = jnp.zeros(shape, dtype)
+        self.v_pool = jnp.zeros(shape, dtype)
         self.k_scale = jnp.zeros(sshape, jnp.float32) \
             if self.scale_pages else None
         self.v_scale = jnp.zeros(sshape, jnp.float32) \
@@ -209,11 +227,11 @@ class PagePool:
 
     # -- device state -------------------------------------------------------
 
-    def swap(self, k_flat, v_flat, k_scale=None, v_scale=None) -> None:
+    def swap(self, k_pool, v_pool, k_scale=None, v_scale=None) -> None:
         """Rebind the pools to a program's donated outputs (scale pools
         included when this is a quantized pool)."""
-        self.k_flat = k_flat
-        self.v_flat = v_flat
+        self.k_pool = k_pool
+        self.v_pool = v_pool
         if self.scale_pages:
             if k_scale is None or v_scale is None:
                 raise ValueError(
@@ -282,7 +300,7 @@ class PagePool:
         """Live-buffer attribution for the PR 14 census: the pools
         (and, for quantized pools, their scale shadows) under ``kv::``
         paths."""
-        named = {"kv::k_pages": self.k_flat, "kv::v_pages": self.v_flat}
+        named = {"kv::k_pages": self.k_pool, "kv::v_pages": self.v_pool}
         if self.scale_pages:
             named["kv::k_scales"] = self.k_scale
             named["kv::v_scales"] = self.v_scale

@@ -24,11 +24,12 @@ clamps its decode bucket ladder to >= 2 rows (see
 
 Device-trace scopes: both steps run under ``jax.named_scope`` — ``embed``,
 per layer ``layer<i>/attn_qkv``, ``layer<i>/kv_write`` (the pool
-``.at[...].set`` and the int8 scale writes), ``layer<i>/kv_read`` (the
-per-layer pool slices handed to the kernel), ``layer<i>/attn``,
+``.at[i, page, slot].set`` and the int8 scale writes), ``layer<i>/attn``,
 ``layer<i>/attn_out``, ``layer<i>/mlp``, then ``lm_head`` and ``sample``.
-Prefill writes every layer's K/V in one scatter after the stack, so its
-``kv_write`` is not under a layer.  Scopes are HLO metadata only
+The kernel takes the pools whole, so nothing stands between the write
+and the read: the ``kv_read`` scope of earlier versions has no operation
+left and is gone.  Prefill writes each layer's K/V as whole pages under
+that layer's ``kv_write`` too.  Scopes are HLO metadata only
 (``op_name``): they name the operations in a profiler trace and change
 nothing the program computes.
 """
@@ -149,39 +150,37 @@ def _mlp(spec, params, i, x, tap=None):
     return _matmul(params, f"h{i}.mlp.w2", h, tap) + params[f"h{i}.mlp.b2"]
 
 
-def _flat_dest(page_table, positions, page_size):
-    """Flat pool row for each position via its page table.
-
-    ``page_table`` rows hold page ids; position ``t`` lives at flat
-    index ``pt[t // ps] * ps + t % ps``.  Works batched (page_table
-    (B, maxp), positions (B,)) and single (maxp,)/(S,).
-    """
+def _page_slot(page_tables, positions, page_size):
+    """``(page, slot)`` of each row's position through its page table:
+    position ``t`` lives in page ``pt[b, t // ps]``, slot ``t % ps``.
+    ``page_tables`` (B, max_pages), ``positions`` (B,)."""
     page = jnp.take_along_axis(
-        page_table, (positions // page_size)[..., None], axis=-1)[..., 0] \
-        if page_table.ndim == 2 else page_table[positions // page_size]
-    return page * page_size + positions % page_size
+        page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
+    return page, positions % page_size
 
 
-def prefill_step(spec: ModelSpec, params, k_flat, v_flat,
+def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                  tokens, length, page_table, *, page_size: int,
                  k_scale=None, v_scale=None, tap=None):
     """Run one prompt (padded to a seq bucket) and seed its KV pages.
 
     Args:
-      k_flat/v_flat: donated pools ``(L, P*ps, H, D)``.
+      k_pool/v_pool: donated pools ``(L, P, ps, H*D)``
+        (:func:`.kv_cache.pool_shapes`), written a whole page at a time.
       tokens: ``(S,)`` int32, padded prompt (bucket size S).
       length: scalar int32, true prompt length (1 <= length <= S).
       page_table: ``(max_pages,)`` int32 pages owned by this sequence
-        (unused tail = 0, the reserved null page).
+        (unused tail = 0, the reserved null page); at least
+        ``ceil(S / ps)`` entries.
       page_size: static tokens-per-page (trace-time constant).
-      k_scale/v_scale: donated scale pools ``(L, P*ps, H)`` f32 when
-        the KV pool is int8 (``k_flat.dtype``); the prompt's K/V are
+      k_scale/v_scale: donated scale pools ``(L, P, ps, H)`` f32 when
+        the KV pool is int8 (``k_pool.dtype``); the prompt's K/V are
         quantized per (token, head) at write time.
       tap: optional calibration hook ``tap(site, activation)`` — only
         ever non-None in the eager PTQ harness, never in a serve trace.
 
-    Returns ``(k_flat, v_flat, next_token, logits)``, with the two
-    scale pools spliced in after ``v_flat`` when they were passed.
+    Returns ``(k_pool, v_pool, next_token, logits)``, with the two
+    scale pools spliced in after ``v_pool`` when they were passed.
     Prefill attends over the in-layer full-precision K/V (the stored
     pages are for later decode steps), matching standard PTQ serving
     stacks.
@@ -194,9 +193,29 @@ def prefill_step(spec: ModelSpec, params, k_flat, v_flat,
     pos_ids = jnp.arange(s, dtype=jnp.int32)
     # causal AND inside the true prompt: key j visible to query i iff
     # j <= i and j < length
-    mask = (pos_ids[None, :] <= pos_ids[:, None]) & (pos_ids[None, :] < length)
+    in_prompt = pos_ids < length
+    mask = (pos_ids[None, :] <= pos_ids[:, None]) & in_prompt[None, :]
     scale = 1.0 / math.sqrt(spec.head_dim)
-    ks, vs = [], []
+    quant = k_pool.dtype == jnp.int8
+    n_pages = -(-s // page_size)
+    # a page wholly past the prompt goes to the null page 0, so only
+    # pages the sequence owns are written
+    page_ids = jnp.where(
+        jnp.arange(n_pages, dtype=jnp.int32) * page_size < length,
+        page_table[:n_pages], 0)
+
+    def write(pool, layer, rows):
+        """``rows`` (S, ...) of one layer's K, V or scales into the
+        prompt's pages.  Rows past ``length`` become zeros: the kernel
+        masks those slots (``pos < length``) until the decode step that
+        reaches each one overwrites it."""
+        keep = in_prompt.reshape(s, *[1] * (rows.ndim - 1))
+        rows = jnp.where(keep, rows, 0).astype(pool.dtype)
+        rows = jnp.pad(rows, ((0, n_pages * page_size - s),)
+                       + ((0, 0),) * (rows.ndim - 1))
+        return pool.at[layer, page_ids].set(
+            rows.reshape(n_pages, page_size, *rows.shape[1:]))
+
     for i in range(spec.layers):
         with scope(f"layer{i}/attn_qkv"):
             x = _ln(h, params[f"h{i}.ln1.w"],
@@ -221,69 +240,68 @@ def prefill_step(spec: ModelSpec, params, k_flat, v_flat,
             x2 = _ln(h, params[f"h{i}.ln2.w"],
                      params[f"h{i}.ln2.b"]).astype(cdt)
             h = h + _mlp(spec, params, i, x2, tap)
-        ks.append(k)
-        vs.append(v)
+        # the layer's K/V go into this sequence's pages, a whole page at
+        # a time, and are dead after it: no (L, S, H*D) stack is held
+        with scope(f"layer{i}/kv_write"):
+            if quant:
+                k, ksc = quantize_kv(k)
+                v, vsc = quantize_kv(v)
+                k_scale = write(k_scale, i, ksc)
+                v_scale = write(v_scale, i, vsc)
+            k_pool = write(k_pool, i, k.reshape(s, spec.hidden))
+            v_pool = write(v_pool, i, v.reshape(s, spec.hidden))
+            # the next layer waits for these writes: left free, XLA's
+            # schedule puts all 2L of them after the stack and keeps
+            # every layer's K and V alive until then.  (Not the int8
+            # scale pools: their rows are small, and the chip re-lays a
+            # (.., H)-minor pool once around all its scatters, which a
+            # barrier a layer would repeat.)
+            h, k_pool, v_pool = jax.lax.optimization_barrier(
+                (h, k_pool, v_pool))
     with scope("lm_head"):
         hf = _ln(h, params["lnf.w"], params["lnf.b"]).astype(cdt)
         if tap is not None:
             tap("head", hf)
-        logits_all = hf @ params["embed"].T                    # (S, V)
-        logits = jnp.take(logits_all, length - 1, axis=0)      # (V,)
+        # only the last prompt row feeds the sampler: one row against
+        # the embedding, not an (S, V) product to pick a row from
+        last = jax.lax.dynamic_slice_in_dim(hf, length - 1, 1, axis=0)
+        logits = (last @ params["embed"].T)[0]                 # (V,)
     with scope("sample"):
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    # scatter prompt K/V into this sequence's pages; padding rows are
-    # routed to flat row 0 (inside the reserved null page, never read
-    # unmasked)
-    with scope("kv_write"):
-        dest = jnp.where(pos_ids < length,
-                         _flat_dest(page_table, pos_ids, page_size), 0)
-        k_stack = jnp.stack(ks)                                # (L, S, H, D)
-        v_stack = jnp.stack(vs)
-        if k_flat.dtype == jnp.int8:
-            kq, ksc = quantize_kv(k_stack)
-            vq, vsc = quantize_kv(v_stack)
-            k_flat = k_flat.at[:, dest].set(kq)
-            v_flat = v_flat.at[:, dest].set(vq)
-            k_scale = k_scale.at[:, dest].set(ksc)
-            v_scale = v_scale.at[:, dest].set(vsc)
-        else:
-            k_flat = k_flat.at[:, dest].set(k_stack.astype(k_flat.dtype))
-            v_flat = v_flat.at[:, dest].set(v_stack.astype(v_flat.dtype))
     if k_scale is not None:
-        return k_flat, v_flat, k_scale, v_scale, next_token, logits
-    return k_flat, v_flat, next_token, logits
+        return k_pool, v_pool, k_scale, v_scale, next_token, logits
+    return k_pool, v_pool, next_token, logits
 
 
-def decode_step(spec: ModelSpec, params, k_flat, v_flat,
+def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 tokens, positions, page_tables, *, page_size: int,
                 k_scale=None, v_scale=None, tap=None):
     """One decode step for a padded batch bucket.
 
     Args:
-      k_flat/v_flat: donated pools ``(L, P*ps, H, D)``.
+      k_pool/v_pool: donated pools ``(L, P, ps, H*D)``; each layer
+        writes the rows' new K/V at ``[layer, page, slot]`` (B rows of
+        H*D lanes) and hands the pools whole to the kernel.
       tokens: ``(B,)`` int32 current token per row.
       positions: ``(B,)`` int32 position of that token (0-based);
         padding rows point at position 0 with page_table row 0 so
         their writes land in the null page.
       page_tables: ``(B, max_pages)`` int32.
       page_size: static tokens-per-page (trace-time constant).
-      k_scale/v_scale: donated scale pools ``(L, P*ps, H)`` f32 for an
+      k_scale/v_scale: donated scale pools ``(L, P, ps, H)`` f32 for an
         int8 pool; the step's K/V quantize per (token, head) at write
         time — a pure per-row function, so row bytes never depend on
         batch neighbours (the bit-identity contract survives int8).
       tap: optional calibration hook (eager PTQ harness only).
 
-    Returns ``(k_flat, v_flat, next_tokens, logits)``, with the scale
-    pools spliced in after ``v_flat`` when they were passed.
+    Returns ``(k_pool, v_pool, next_tokens, logits)``, with the scale
+    pools spliced in after ``v_pool`` when they were passed.
     """
     b = tokens.shape[0]
-    num_pages = k_flat.shape[1] // page_size
-    quant = k_flat.dtype == jnp.int8
+    quant = k_pool.dtype == jnp.int8
     scope = jax.named_scope
-    pages_of = lambda pool: pool.reshape(num_pages, page_size,
-                                         *pool.shape[1:])
     with scope("embed"):
-        dest = _flat_dest(page_tables, positions, page_size)   # (B,)
+        page, slot = _page_slot(page_tables, positions, page_size)  # (B,)
         lengths = positions + 1
         h = params["embed"][tokens] + params["pos"][positions]
     cdt = params["embed"].dtype
@@ -297,28 +315,24 @@ def decode_step(spec: ModelSpec, params, k_flat, v_flat,
                         tap).reshape(b, spec.heads, spec.head_dim)
             v = _matmul(params, f"h{i}.attn.wv", x,
                         tap).reshape(b, spec.heads, spec.head_dim)
-        if quant:
-            with scope(f"layer{i}/kv_write"):
-                kq, ksc = quantize_kv(k)
-                vq, vsc = quantize_kv(v)
-                k_flat = k_flat.at[i, dest].set(kq)
-                v_flat = v_flat.at[i, dest].set(vq)
-                k_scale = k_scale.at[i, dest].set(ksc)
-                v_scale = v_scale.at[i, dest].set(vsc)
-            with scope(f"layer{i}/kv_read"):
-                kv = [pages_of(p[i])
-                      for p in (k_flat, v_flat, k_scale, v_scale)]
-            with scope(f"layer{i}/attn"):
-                o = paged_attention_int8(q, *kv, page_tables, lengths)
-        else:
-            with scope(f"layer{i}/kv_write"):
-                k_flat = k_flat.at[i, dest].set(k.astype(k_flat.dtype))
-                v_flat = v_flat.at[i, dest].set(v.astype(v_flat.dtype))
-            with scope(f"layer{i}/kv_read"):
-                k_pages, v_pages = pages_of(k_flat[i]), pages_of(v_flat[i])
-            with scope(f"layer{i}/attn"):
-                o = paged_attention(q, k_pages, v_pages, page_tables,
-                                    lengths)
+        with scope(f"layer{i}/kv_write"):
+            if quant:
+                k, ksc = quantize_kv(k)
+                v, vsc = quantize_kv(v)
+                k_scale = k_scale.at[i, page, slot].set(ksc)
+                v_scale = v_scale.at[i, page, slot].set(vsc)
+            k_pool = k_pool.at[i, page, slot].set(
+                k.reshape(b, spec.hidden).astype(k_pool.dtype))
+            v_pool = v_pool.at[i, page, slot].set(
+                v.reshape(b, spec.hidden).astype(v_pool.dtype))
+        with scope(f"layer{i}/attn"):
+            if quant:
+                o = paged_attention_int8(q, k_pool, v_pool, k_scale,
+                                         v_scale, page_tables, lengths,
+                                         layer=i)
+            else:
+                o = paged_attention(q, k_pool, v_pool, page_tables,
+                                    lengths, layer=i)
         with scope(f"layer{i}/attn_out"):
             h = h + _matmul(params, f"h{i}.attn.wo",
                             o.reshape(b, spec.hidden), tap)
@@ -334,5 +348,5 @@ def decode_step(spec: ModelSpec, params, k_flat, v_flat,
     with scope("sample"):
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     if k_scale is not None:
-        return k_flat, v_flat, k_scale, v_scale, next_tokens, logits
-    return k_flat, v_flat, next_tokens, logits
+        return k_pool, v_pool, k_scale, v_scale, next_tokens, logits
+    return k_pool, v_pool, next_tokens, logits

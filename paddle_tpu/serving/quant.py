@@ -40,6 +40,7 @@ import numpy as np
 
 from ..ops.quant_kernels import quantize_weight
 from ..quantization.observers import AbsmaxObserver, PerChannelAbsmaxObserver
+from .kv_cache import pool_shapes
 from .model import (ModelSpec, QUANT_WEIGHT_NAMES, decode_step, init_params,
                     prefill_step)
 
@@ -118,21 +119,21 @@ def calibrate(spec: ModelSpec, params, prompts: Sequence[Sequence[int]],
         for prompt in prompts:
             total = len(prompt) + max_new
             pages = 1 + -(-total // page_size)
-            shape = (spec.layers, pages * page_size, spec.heads,
-                     spec.head_dim)
-            k_flat = jnp.zeros(shape, jnp.float32)
-            v_flat = jnp.zeros(shape, jnp.float32)
+            shape, _ = pool_shapes(spec.layers, pages, page_size,
+                                   spec.heads, spec.head_dim)
+            k_pool = jnp.zeros(shape, jnp.float32)
+            v_pool = jnp.zeros(shape, jnp.float32)
             table = np.arange(1, pages, dtype=np.int32)
             padded = np.zeros((len(prompt),), np.int32)
             padded[:] = np.asarray(prompt, np.int32)
-            k_flat, v_flat, nxt, _ = prefill_step(
-                spec, params, k_flat, v_flat, padded,
+            k_pool, v_pool, nxt, _ = prefill_step(
+                spec, params, k_pool, v_pool, padded,
                 np.int32(len(prompt)), table, page_size=page_size, tap=tap)
             tok = np.asarray(nxt, np.int32).reshape(1)
             for j in range(max_new):
                 pos = np.asarray([len(prompt) + j], np.int32)
-                k_flat, v_flat, tok, _ = decode_step(
-                    spec, params, k_flat, v_flat, tok, pos, table[None, :],
+                k_pool, v_pool, tok, _ = decode_step(
+                    spec, params, k_pool, v_pool, tok, pos, table[None, :],
                     page_size=page_size, tap=tap)
                 tok = np.asarray(tok, np.int32)
 
@@ -265,9 +266,8 @@ def logit_divergence(spec: ModelSpec, params, prompts=None, *,
         for prompt in prompts:
             total = len(prompt) + max_new
             pages = 1 + -(-total // page_size)
-            shape = (spec.layers, pages * page_size, spec.heads,
-                     spec.head_dim)
-            sshape = shape[:-1]
+            shape, sshape = pool_shapes(spec.layers, pages, page_size,
+                                        spec.heads, spec.head_dim)
             kf = jnp.zeros(shape, jnp.float32)
             vf = jnp.zeros(shape, jnp.float32)
             kq = jnp.zeros(shape, jnp.int8)

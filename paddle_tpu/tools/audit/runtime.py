@@ -178,7 +178,7 @@ def audit_captured_step(entry, params, buffers, opt_states, rng_ctr,
     return audit_program(prog)
 
 
-_ARG_LABELS_SERVE = ("params", "k_flat", "v_flat", "tokens",
+_ARG_LABELS_SERVE = ("params", "k_pool", "v_pool", "tokens",
                      "positions", "page_tables")
 
 

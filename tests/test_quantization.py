@@ -343,26 +343,61 @@ class TestQuantKernels:
         assert "w8a16_matmul" in KERNEL_SCHEMA
         assert "paged_attention_int8" in KERNEL_SCHEMA
 
-    def test_paged_attention_int8_matches_fp32_within_quant_tol(self):
+    @staticmethod
+    def _int8_pools(layers=2):
+        """fp32 ``(L, P, ps, H*D)`` pools, their int8 twins and the
+        ``(L, P, ps, H)`` scales, a null-padded table and a part-filled
+        last page."""
         from paddle_tpu.ops import quant_kernels as qk
+        rng = np.random.RandomState(4)
+        P, ps, H, D = 5, 4, 2, 8
+        kp = rng.randn(layers, P, ps, H, D).astype(np.float32)
+        vp = rng.randn(layers, P, ps, H, D).astype(np.float32)
+        kq, ks = qk.quantize_kv(kp)
+        vq, vs = qk.quantize_kv(vp)
+        flat = (layers, P, ps, H * D)
+        qact = rng.randn(2, H, D).astype(np.float32)
+        ptab = np.array([[4, 2], [3, 0]], np.int32)
+        ln = np.array([7, 3], np.int32)
+        return (qact, kp.reshape(flat), vp.reshape(flat),
+                np.asarray(kq).reshape(flat), np.asarray(vq).reshape(flat),
+                np.asarray(ks), np.asarray(vs), ptab, ln)
+
+    @pytest.mark.parametrize("layer", [0, 1])
+    def test_paged_attention_int8_matches_fp32_within_quant_tol(self, layer):
         from paddle_tpu.ops.paged_attention import (
             paged_attention_reference, paged_attention_int8,
             paged_attention_int8_reference)
-        rng = np.random.RandomState(4)
-        P, ps, H, D = 5, 4, 2, 8
-        kp = rng.randn(P, ps, H, D).astype(np.float32)
-        vp = rng.randn(P, ps, H, D).astype(np.float32)
-        kq, ks = qk.quantize_kv(kp)
-        vq, vs = qk.quantize_kv(vp)
-        qact = rng.randn(2, H, D).astype(np.float32)
-        ptab = np.array([[0, 2], [3, 1]], np.int32)
-        ln = np.array([3, 7], np.int32)
-        o32 = np.asarray(paged_attention_reference(qact, kp, vp, ptab, ln))
+        qact, kp, vp, kq, vq, ks, vs, ptab, ln = self._int8_pools()
+        o32 = np.asarray(paged_attention_reference(qact, kp, vp, ptab, ln,
+                                                   layer=layer))
         o8 = np.asarray(paged_attention_int8_reference(
-            qact, kq, vq, ks, vs, ptab, ln))
+            qact, kq, vq, ks, vs, ptab, ln, layer=layer))
         np.testing.assert_allclose(o8, o32, atol=0.05)
         # the CPU dispatcher must be the reference bit-for-bit — the
         # serve path's numerics definition off-TPU
         o8d = np.asarray(paged_attention_int8(qact, kq, vq, ks, vs,
-                                              ptab, ln))
+                                              ptab, ln, layer=layer))
         assert np.array_equal(o8d, o8)
+
+    @pytest.mark.parametrize("layer", [0, 1])
+    def test_paged_attention_int8_kernel_reads_the_whole_pool(self, layer):
+        """The Pallas kernel (interpret mode) on the whole int8 pools and
+        one layer's scales against the XLA reference, with large finite
+        garbage in every slot past a row's length."""
+        from paddle_tpu.ops.paged_attention import (
+            paged_attention_int8, paged_attention_int8_reference)
+        qact, _, _, kq, vq, ks, vs, ptab, ln = self._int8_pools()
+        ps = kq.shape[2]
+        kq, vq, ks, vs = (a.copy() for a in (kq, vq, ks, vs))
+        for r in range(ptab.shape[0]):
+            for t in range(int(ln[r]), ptab.shape[1] * ps):
+                page, slot = ptab[r, t // ps], t % ps
+                kq[:, page, slot], vq[:, page, slot] = 127, -127
+                ks[:, page, slot], vs[:, page, slot] = 1e4, 1e4
+        ref = np.asarray(paged_attention_int8_reference(
+            qact, kq, vq, ks, vs, ptab, ln, layer=layer))
+        out = np.asarray(paged_attention_int8(
+            qact, kq, vq, ks, vs, ptab, ln, layer=layer, use_pallas=True,
+            interpret=True))
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)

@@ -128,21 +128,257 @@ def test_pool_pages_needed_and_padded_table():
 
 # -- paged attention ---------------------------------------------------------
 
-def test_paged_attention_matches_reference():
+def _paged_case(layers=3, garbage=1e4):
+    """Whole ``(L, P, ps, H*D)`` pools, page tables padded with the null
+    page, a part-filled last page per row, and large finite garbage in
+    every slot a row must not read: past its length, in the null page,
+    and in the pages of the other layers' same ids it does not own."""
     rng = np.random.RandomState(1)
     b, h, d, ps, maxp = 3, 2, 8, 4, 5
     pages = 1 + b * maxp
-    q = jnp.asarray(rng.randn(b, h, d), jnp.float32)
-    k = jnp.asarray(rng.randn(pages, ps, h, d), jnp.float32)
-    v = jnp.asarray(rng.randn(pages, ps, h, d), jnp.float32)
-    tables = jnp.asarray(
-        rng.permutation(np.arange(1, pages))[:b * maxp].reshape(b, maxp))
-    lengths = jnp.asarray([1, 7, 20], jnp.int32)
-    ref = paged_attention_reference(q, k, v, tables, lengths)
-    out = paged_attention(q, k, v, tables, lengths,
-                          use_pallas=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    lengths = np.asarray([1, 7, 18], np.int32)
+    q = rng.randn(b, h, d).astype(np.float32)
+    k = (garbage * rng.randn(layers, pages, ps, h * d)).astype(np.float32)
+    v = (garbage * rng.randn(layers, pages, ps, h * d)).astype(np.float32)
+    own = rng.permutation(np.arange(1, pages)).reshape(b, maxp)
+    tables = np.zeros((b, maxp), np.int32)          # tail: the null page
+    ctx = {}
+    for r in range(b):
+        n = int(lengths[r])
+        used = -(-n // ps)
+        tables[r, :used] = own[r, :used]
+        kc = rng.randn(layers, n, h * d).astype(np.float32)
+        vc = rng.randn(layers, n, h * d).astype(np.float32)
+        for t in range(n):
+            k[:, tables[r, t // ps], t % ps] = kc[:, t]
+            v[:, tables[r, t // ps], t % ps] = vc[:, t]
+        ctx[r] = (kc, vc)
+    return q, k, v, tables, lengths, ctx, (h, d)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_attention_whole_pool_matches_reference(layer):
+    q, k, v, tables, lengths, ctx, (h, d) = _paged_case()
+    args = [jnp.asarray(a) for a in (q, k, v, tables, lengths)]
+    ref = np.asarray(paged_attention_reference(*args, layer=layer))
+    out = np.asarray(paged_attention(*args, layer=layer, use_pallas=True,
+                                     interpret=True))
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    # and both against plain attention over each row's own context: the
+    # garbage in masked slots, the null page and other layers is not read
+    for r, (kc, vc) in ctx.items():
+        kr = kc[layer].reshape(-1, h, d)
+        vr = vc[layer].reshape(-1, h, d)
+        sc = np.einsum("hd,chd->hc", q[r], kr) / np.sqrt(d)
+        w = np.exp(sc - sc.max(-1, keepdims=True))
+        w /= w.sum(-1, keepdims=True)
+        want = np.einsum("hc,chd->hd", w, vr)
+        np.testing.assert_allclose(out[r], want, atol=2e-4, rtol=2e-4)
+
+
+def test_paged_attention_refuses_a_pool_of_the_wrong_width():
+    q, k, v, tables, lengths, _, _ = _paged_case(layers=1)
+    with pytest.raises(ValueError, match="lanes"):
+        paged_attention(jnp.asarray(q), jnp.asarray(k[..., :8]),
+                        jnp.asarray(v[..., :8]), jnp.asarray(tables),
+                        jnp.asarray(lengths), layer=0, use_pallas=True,
+                        interpret=True)
+
+
+# -- the pools' layout: prefill, decode, reuse -------------------------------
+
+def _plain_logits(params, tokens):
+    """The served decoder as one full causal forward, no cache: logits
+    of every position.  float64 numpy and nothing of jax: a compile here
+    would trip the module engine's armed sentinel."""
+    p = {n: np.asarray(a, np.float64) for n, a in params.items()}
+    s, hd = len(tokens), SPEC.head_dim
+
+    def ln(x, w, b):
+        mu = x.mean(-1, keepdims=True)
+        var = ((x - mu) ** 2).mean(-1, keepdims=True)
+        return (x - mu) / np.sqrt(var + 1e-5) * w + b
+
+    h = p["embed"][np.asarray(tokens)] + p["pos"][:s]
+    causal = np.tril(np.ones((s, s), bool))
+    for i in range(SPEC.layers):
+        x = ln(h, p[f"h{i}.ln1.w"], p[f"h{i}.ln1.b"])
+        q, k, v = ((x @ p[f"h{i}.attn.w{c}"]).reshape(s, SPEC.heads, hd)
+                   for c in "qkv")
+        att = np.einsum("ihd,jhd->hij", q, k) / np.sqrt(hd)
+        att = np.where(causal[None], att, -np.inf)
+        w = np.exp(att - att.max(-1, keepdims=True))
+        w /= w.sum(-1, keepdims=True)
+        h = h + np.einsum("hij,jhd->ihd", w, v).reshape(s, -1) \
+            @ p[f"h{i}.attn.wo"]
+        x = ln(h, p[f"h{i}.ln2.w"], p[f"h{i}.ln2.b"])
+        m = x @ p[f"h{i}.mlp.w1"] + p[f"h{i}.mlp.b1"]
+        m = 0.5 * m * (1 + np.tanh(np.sqrt(2 / np.pi)
+                                   * (m + 0.044715 * m ** 3)))
+        h = h + m @ p[f"h{i}.mlp.w2"] + p[f"h{i}.mlp.b2"]
+    return ln(h, p["lnf.w"], p["lnf.b"]) @ p["embed"].T
+
+
+def _run_through_programs(engine, prompt, steps, pages):
+    """Prefill and ``steps`` decode steps through the engine's own
+    programs and pools, the way ``engine.prefill`` / ``decode`` call
+    them; returns the logits row of each step and the tokens fed."""
+    pool, maxp = engine.pool, engine.max_pages_per_seq
+    table = pool.null_padded_table(pages, maxp)
+    n = len(prompt)
+    s = engine.prefill_bucket_for(n)
+    padded = np.zeros((s,), np.int32)
+    padded[:n] = prompt
+    *state, nxt, logits = engine._prefill_exe[s](
+        engine._params, *engine._kv_state(), padded, np.int32(n), table)
+    pool.swap(*state)
+    rows, toks = [np.asarray(logits)], [int(nxt)]
+    b = engine.config.decode_buckets[0]
+    for j in range(steps):
+        tok = np.zeros((b,), np.int32)
+        pos = np.zeros((b,), np.int32)
+        pt = np.zeros((b, maxp), np.int32)
+        tok[0], pos[0], pt[0] = toks[-1], n + j, table
+        *state, nxt, logits = engine._decode_exe[b](
+            engine._params, *engine._kv_state(), tok, pos, pt)
+        pool.swap(*state)
+        rows.append(np.asarray(logits)[0])
+        toks.append(int(np.asarray(nxt)[0]))
+    return np.stack(rows), toks
+
+
+_PS = CFG.page_size
+
+
+@pytest.mark.parametrize("n", [_PS - 1, _PS, _PS + 1,
+                               CFG.prefill_buckets[0]])
+def test_prefill_then_decode_equals_the_plain_forward(engine, n):
+    """Prompt lengths one short of a page, a whole page, one over, and
+    the bucket's edge; six decode steps, so writes cross a page edge."""
+    rng = np.random.RandomState(10 + n)
+    prompt = rng.randint(1, SPEC.vocab_size, size=n)
+    steps = 6
+    pages = engine.pool.alloc(engine.pool.pages_needed(n + steps))
+    try:
+        got, toks = _run_through_programs(engine, prompt, steps, pages)
+    finally:
+        engine.pool.free(pages)
+    full = list(prompt) + toks[:steps]
+    want = _plain_logits(engine._params, full)[n - 1:]
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+    engine.pool.check_consistency()
+
+
+def test_a_freed_page_is_reused_and_reads_only_its_new_owner(engine):
+    pool = engine.pool
+    rng = np.random.RandomState(5)
+    first = rng.randint(1, SPEC.vocab_size, size=11)
+    pages = pool.alloc(pool.pages_needed(len(first) + 4))
+    _run_through_programs(engine, first, 4, pages)
+    pool.free(pages)
+    # LIFO free list: the second sequence gets the first one's pages,
+    # still holding its K and V, and is one token shorter than a page
+    # edge so the stale slots sit right behind its own
+    second = rng.randint(1, SPEC.vocab_size, size=2 * _PS - 1)
+    again = pool.alloc(pool.pages_needed(len(second) + 4))
+    assert set(again) & set(pages)
+    try:
+        got, toks = _run_through_programs(engine, second, 4, again)
+    finally:
+        pool.free(again)
+    want = _plain_logits(engine._params,
+                         list(second) + toks[:4])[len(second) - 1:]
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+    pool.check_consistency()
+
+
+def test_prefill_writes_whole_pages_and_only_the_sequences_own(engine):
+    """Pages wholly past the prompt go to the null page; the slots of
+    the last page past the prompt are zeros; no other page changes."""
+    pool = engine.pool
+    n = _PS + 1                              # two pages, the second 1 / ps
+    pages = pool.alloc(3)
+    before = [np.asarray(a).copy() for a in (pool.k_pool, pool.v_pool)]
+    try:
+        engine.prefill(list(range(1, n + 1)),
+                       pool.null_padded_table(pages, engine.max_pages_per_seq))
+    finally:
+        pool.free(pages)
+    for was, now in zip(before, (pool.k_pool, pool.v_pool)):
+        now = np.asarray(now)
+        assert now.shape == (SPEC.layers, pool.pages, _PS, SPEC.hidden)
+        assert np.all(now[:, pages[1], 1:] == 0)         # masked tail
+        assert np.all(now[:, pages[0]] != 0) and np.all(now[:, pages[1], 0])
+        untouched = [p for p in range(1, pool.pages) if p not in pages[:2]]
+        np.testing.assert_array_equal(now[:, untouched], was[:, untouched])
+
+
+# -- what each program keeps in memory ---------------------------------------
+
+_BYTES_SPEC = ModelSpec(vocab_size=64, hidden=32, layers=2, heads=2,
+                        max_seq_len=32)
+_BYTES_ENGINES = {}
+
+
+def _bytes_engine(precision):
+    """One engine a precision, with pools far larger than anything else
+    a program touches, so a pool-sized temporary cannot hide."""
+    if precision not in _BYTES_ENGINES:
+        cfg = ServeConfig(decode_buckets=(2,), prefill_buckets=(16,),
+                          kv_pages=8192, page_size=4, max_inflight=4,
+                          max_new_tokens=4, precision=precision)
+        _BYTES_ENGINES[precision] = ServingEngine(
+            _BYTES_SPEC, init_params(_BYTES_SPEC, seed=0), cfg)
+    return _BYTES_ENGINES[precision]
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+@pytest.mark.parametrize("precision", ["fp32", "bf16", "int8"])
+def test_programs_alias_every_pool_and_hold_no_pool_sized_temporary(
+        precision, kind):
+    eng = _bytes_engine(precision)
+    state = eng._kv_state()
+    assert len(state) == (4 if precision == "int8" else 2)
+    pool_bytes = int(state[0].nbytes)
+    assert state[0].shape == (2, eng.pool.pages, 4, 32)
+    sfx = "" if precision == "fp32" else f"_{precision}"
+    name = {"prefill": "serve_prefill_s16", "decode": "serve_decode_b2"}[kind]
+    got = eng.stats["program_bytes"][name + sfx]
+    assert got == eng.healthz()["program_bytes"][name + sfx]
+    # every pool parameter is aliased to its output ...
+    assert got["alias"] == sum(int(a.nbytes) for a in state)
+    assert got["argument"] >= got["alias"]
+    # ... and nothing a tenth of one pool's size is held beside them.
+    # (XLA's CPU backend has no bf16 scatter: it widens the whole pool
+    # to f32 and back around each one, which the chip's compiler does
+    # not; tests/test_serve_chip_compile.py holds bf16 to this there.)
+    if precision != "bf16":
+        assert got["temp"] < pool_bytes / 10, (got, pool_bytes)
+    # the executable's own text says the same: one alias a pool
+    exe = (eng._prefill_exe[16] if kind == "prefill"
+           else eng._decode_exe[2])
+    header = exe.as_text().split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") \
+        == len(state), header[:300]
+
+
+def test_program_bytes_gauge_when_telemetry_is_on():
+    from paddle_tpu import observability as obs
+    assert not obs.get_telemetry().enabled
+    obs.reset_registry()
+    tel = obs.get_telemetry()
+    tel.enable(compile_watch=False)
+    try:
+        eng = ServingEngine(SPEC, init_params(SPEC, seed=0), CFG)
+        eng.close()
+        series = obs.get_registry().snapshot()[
+            "pt_serve_program_bytes"]["series"]
+        assert len(series) == 3 * eng.compiled_programs
+        assert sorted(eng.stats["program_bytes"]) == [
+            "serve_decode_b4", "serve_prefill_s16"]
+    finally:
+        tel.enabled = False
+        obs.reset_registry()
 
 
 # -- engine: zero-compile request path ---------------------------------------
@@ -583,8 +819,7 @@ def test_serve_programs_carry_scope_names():
     i32 = jnp.int32
     with aot_build_phase():      # the eager zeros compile: not an incident
         params = init_params(SPEC, seed=0)
-        pool = jnp.zeros((SPEC.layers, pages * ps, SPEC.heads,
-                          SPEC.head_dim))
+        pool = jnp.zeros((SPEC.layers, pages, ps, SPEC.hidden))
         dec = _op_names(jax.jit(
             lambda p, k, v, t, pos, pt: decode_step(
                 SPEC, p, k, v, t, pos, pt, page_size=ps)).lower(
@@ -600,12 +835,11 @@ def test_serve_programs_carry_scope_names():
         return [n for n in names if f"/{scope}/" in n + "/"]
 
     for scope in ("embed", "layer0/attn_qkv", "layer0/kv_write",
-                  "layer0/kv_read", "layer1/kv_read", "layer0/attn",
-                  "layer0/attn_out", "layer1/mlp", "lm_head", "sample"):
+                  "layer1/kv_write", "layer0/attn", "layer0/attn_out",
+                  "layer1/mlp", "lm_head", "sample"):
         assert scoped(dec, scope), scope
-    assert any(n.endswith("kv_write/scatter") for n in dec)
-    for scope in ("embed", "layer0/attn_qkv", "layer0/attn", "layer1/mlp",
-                  "lm_head", "sample", "kv_write"):
         assert scoped(pre, scope), scope
-    # prefill writes every layer's K and V in one scatter after the stack
-    assert not scoped(pre, "layer0/kv_write")
+    assert any(n.endswith("kv_write/scatter") for n in dec)
+    assert any(n.endswith("kv_write/scatter") for n in pre)
+    # the kernel takes the pools whole: nothing is left to scope kv_read
+    assert not scoped(dec, "layer0/kv_read")
