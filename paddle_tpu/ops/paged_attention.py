@@ -38,6 +38,18 @@ Shapes (the model loops layers and passes the pools whole each time):
                                 token + 1; masks padding AND the
                                 reserved null page 0 that pads short
                                 page tables)
+
+Grouped heads and windows (``_paged_attention_gqa_pallas``): a pool row
+may hold fewer heads than q has (``KVH * D`` lanes, ``H = KVH * G``):
+query head ``j`` reads KV head ``j // G``, and the ``G`` query heads of
+a KV head share one fetch of its page.  ``window=W`` makes a row see
+only its last ``W`` positions.  That kernel's grid is not ``(batch,
+pages)`` but one axis over the pages the batch really walks: row ``b``
+contributes pages ``max(0, len - W) // ps .. (len - 1) // ps`` (all of
+``0 .. (len - 1) // ps`` without a window), the rows' walks laid end to
+end in a scalar-prefetched work list (:func:`_walk`), so a slot outside
+a row's walk costs neither a grid step nor a fetch.  Equal heads without
+a window keep the ``(batch, pages)`` kernel above them.
 """
 from __future__ import annotations
 
@@ -52,21 +64,39 @@ from jax.experimental.pallas import tpu as pltpu
 from ..framework import device as _device
 from .pallas_ops import _LANES, _NEG_INF, _interpret_default
 
-__all__ = ["paged_attention", "paged_attention_reference",
+__all__ = ["paged_attention", "paged_attention_reference", "walk_pages",
            "paged_attention_int8", "paged_attention_int8_reference",
            "tune_paged_attention_int8"]
 
 
 def paged_attention_reference(q, k_pool, v_pool, page_tables, lengths,
-                              *, layer, sm_scale=None):
+                              *, layer, sm_scale=None, window=None):
     """XLA reference: gather the page window, masked softmax attention.
 
     f32 scores/accumulation regardless of operand dtype (the MXU
-    contract from :mod:`.pallas_ops`); output in ``q.dtype``.
+    contract from :mod:`.pallas_ops`); output in ``q.dtype``.  A pool
+    row of fewer heads than q's is grouped (query head ``j`` reads KV
+    head ``j // G``); ``window`` keeps a row's last ``window`` positions.
     """
     b, h, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    kvh = k_pool.shape[3] // d
+    if kvh != h or window:
+        g = h // kvh
+        k_ctx = k_pool[layer, page_tables].reshape(b, -1, kvh, d)
+        v_ctx = v_pool[layer, page_tables].reshape(b, -1, kvh, d)
+        s = jnp.einsum("bkgd,bckd->bkgc", q.reshape(b, kvh, g, d), k_ctx,
+                       preferred_element_type=jnp.float32) * sm_scale
+        pos = jnp.arange(k_ctx.shape[1], dtype=jnp.int32)[None, :]
+        mask = pos < lengths[:, None]
+        if window:
+            mask &= pos >= lengths[:, None] - window
+        s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
+        w = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgc,bckd->bkgd", w.astype(v_ctx.dtype), v_ctx,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b, h, d).astype(q.dtype)
     # (B, max_pages, ps, H*D) -> (B, C, H, D); position t sits at
     # context index t because pages fill in order
     k_ctx = k_pool[layer, page_tables].reshape(b, -1, h, d)
@@ -226,11 +256,160 @@ def _paged_attention_pallas(q, k_pool, v_pool, page_tables, lengths,
                        batch_semantics="parallel", interpret=interpret)
 
 
+
+# ---------------------------------------------------------------------------
+# grouped heads and windows: one grid axis over the pages really walked
+# ---------------------------------------------------------------------------
+
+def walk_pages(max_pages, page_size, window):
+    """Most pages one row's walk covers: all ``max_pages`` slots without
+    a window, the window's span plus the page it starts inside with one."""
+    if not window:
+        return max_pages
+    return min(max_pages, -(-window // page_size) + 1)
+
+
+def _walk(page_tables, lengths, *, ps, window, steps):
+    """The batch's walk as a work list of ``steps`` grid steps: the rows'
+    page runs laid end to end.  Returns ``(rows, pages, slots, first,
+    last)``: per step the row it belongs to, the physical page to fetch
+    and the logical page index (``-1`` past the end of the list, where
+    row and page repeat the last live step's so that nothing is
+    fetched); per row its first and last logical page."""
+    lengths = jnp.maximum(lengths, 1)
+    last = (lengths - 1) // ps
+    first = (jnp.maximum(lengths - window, 0) // ps if window
+             else jnp.zeros_like(last))
+    n = last - first + 1
+    ends = jnp.cumsum(n)
+    g = jnp.arange(steps, dtype=jnp.int32)
+    live = g < ends[-1]
+    gc = jnp.minimum(g, ends[-1] - 1)
+    rows = jnp.sum(gc[:, None] >= ends[None, :], axis=1).astype(jnp.int32)
+    slots = first[rows] + gc - (ends[rows] - n[rows])
+    pages = page_tables[rows, slots]
+    return (rows, pages.astype(jnp.int32),
+            jnp.where(live, slots, -1).astype(jnp.int32),
+            first.astype(jnp.int32), last.astype(jnp.int32))
+
+
+def _gqa_kernel(rows_ref, pages_ref, slots_ref, len_ref, first_ref,
+                last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                acc_scr, *, ps, kvh, d, sm_scale, window):
+    """One page of one row: for each KV head, its G query heads (padded
+    to a bf16 tile of rows) against the page's ``(ps, D)`` lanes on the
+    MXU, online softmax in f32."""
+    g = pl.program_id(0)
+    row = rows_ref[g]
+    slot = slots_ref[g]
+    length = len_ref[row]
+    live = slot >= 0
+
+    @pl.when(live & (slot == first_ref[row]))
+    def _init():
+        _init_scratch(m_scr, l_scr, acc_scr)
+
+    @pl.when(live)
+    def _page():
+        gp = q_ref.shape[1]
+        pos = slot * ps + jax.lax.broadcasted_iota(jnp.int32, (gp, ps), 1)
+        valid = pos < length
+        if window:
+            valid &= pos >= length - window
+        # said outright, so that a process-wide default precision cannot
+        # ask the MXU for float32 passes over bfloat16 operands
+        prec = (jax.lax.Precision.HIGHEST if k_ref.dtype == jnp.float32
+                else jax.lax.Precision.DEFAULT)
+        for j in range(kvh):
+            q = q_ref[j]                                  # (GP, D)
+            k = k_ref[:, j * d:(j + 1) * d]               # (ps, D)
+            v = v_ref[:, j * d:(j + 1) * d]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), precision=prec,
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(valid, s, _NEG_INF)              # (GP, ps)
+            m_prev, l_prev = m_scr[j], l_scr[j]           # (GP, LANES)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new[:, :1]), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[j] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[j] = m_new
+            pv = jnp.dot(p.astype(v.dtype), v, precision=prec,
+                         preferred_element_type=jnp.float32)   # (GP, D)
+            acc_scr[j] = acc_scr[j] * alpha[:, :1] + pv
+
+    @pl.when(live & (slot == last_ref[row]))
+    def _fin():
+        for j in range(kvh):
+            l = l_scr[j][:, :1]
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[j] = (acc_scr[j] / l).astype(o_ref.dtype)
+
+
+def _paged_attention_gqa_pallas(q, k_pool, v_pool, page_tables, lengths,
+                                *, layer, sm_scale, window, steps,
+                                interpret):
+    b, h, d = q.shape
+    ps = k_pool.shape[2]
+    kvh = k_pool.shape[3] // d
+    if kvh < 1 or kvh * d != k_pool.shape[3] or h % kvh:
+        raise ValueError(
+            f"pool rows hold {k_pool.shape[3]} lanes, q has {h} heads "
+            f"of {d}")
+    grp = h // kvh
+    gp = -(-grp // 16) * 16         # a bf16 tile of rows a KV head
+    walk = walk_pages(page_tables.shape[1], ps, window)
+    if steps is None:
+        steps = b * walk
+    steps = min(int(steps), b * walk)
+    rows, pages, slots, first, last = _walk(
+        page_tables, lengths, ps=ps, window=window, steps=steps)
+    qg = jnp.pad(q.reshape(b, kvh, grp, d),
+                 ((0, 0), (0, 0), (0, gp - grp), (0, 0)))
+    row_spec = pl.BlockSpec((None, kvh, gp, d),
+                            lambda g, rows, *_: (rows[g], 0, 0, 0))
+    page_spec = pl.BlockSpec(
+        (None, None, ps, kvh * d),
+        lambda g, rows, pages, *_: (layer, pages[g], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(steps,),
+        in_specs=[row_spec, page_spec, page_spec],
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((kvh, gp, _LANES), jnp.float32),
+            pltpu.VMEM((kvh, gp, _LANES), jnp.float32),
+            pltpu.VMEM((kvh, gp, d), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_gqa_kernel, ps=ps, kvh=kvh, d=d,
+                               sm_scale=sm_scale, window=window)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, gp, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_attention_window" if window else "paged_attention_gqa",
+        interpret=interpret,
+    )(rows, pages, slots, jnp.maximum(lengths, 1).astype(jnp.int32),
+      first, last, qg, k_pool, v_pool)
+    return out[:, :, :grp].reshape(b, h, d)
+
+
 def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
-                    sm_scale=None, use_pallas=None, interpret=None):
+                    sm_scale=None, window=None, steps=None,
+                    use_pallas=None, interpret=None):
     """Dispatching entry: the Pallas paged-attention kernel on TPU, the
     XLA gather+softmax reference elsewhere.  Both read layer ``layer``
     of the whole ``(L, P, ps, H*D)`` pools.
+
+    A pool row of fewer heads than q's, or ``window``, selects the
+    grouped kernel (module docstring); ``steps`` is then an upper bound
+    the caller knows on the pages the batch walks (the allocator's: no
+    two rows share a page, so at most the pool's usable pages plus one a
+    row), ``batch * walk_pages`` when not given.
 
     Off-TPU the default is the reference (interpret-mode Pallas is a
     correctness vehicle, not a fast path); pass ``use_pallas=True`` to
@@ -246,8 +425,14 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
         interpret = _interpret_default()
     if use_pallas is None:
         use_pallas = _device.pallas_dispatch()  # reference off the TPU
+    grouped = bool(window) or k_pool.shape[3] != q.shape[1] * q.shape[2]
     if use_pallas:
         record_dispatch("paged_attention", "pallas")
+        if grouped:
+            return _paged_attention_gqa_pallas(
+                q, k_pool, v_pool, page_tables, lengths, layer=layer,
+                sm_scale=sm_scale, window=window or 0, steps=steps,
+                interpret=interpret)
         return _paged_attention_pallas(q, k_pool, v_pool, page_tables,
                                        lengths, layer=layer,
                                        sm_scale=sm_scale,
@@ -255,7 +440,7 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
     record_dispatch("paged_attention", "fallback")
     return paged_attention_reference(q, k_pool, v_pool, page_tables,
                                      lengths, layer=layer,
-                                     sm_scale=sm_scale)
+                                     sm_scale=sm_scale, window=window)
 
 
 # ---------------------------------------------------------------------------

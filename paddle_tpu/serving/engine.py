@@ -121,7 +121,9 @@ class ServeConfig:
     ``kv_pages`` is denominated in fp32 pages (a byte budget): lower
     precisions scale the physical page count up at pool construction
     (:func:`.kv_cache.kv_page_budget`), which is where the int8 mode's
-    ~2x+ admission headroom comes from.
+    ~2x+ admission headroom comes from.  The pool of a model's
+    sliding-window layers is not configured: it is sized for the largest
+    decode bucket, every row holding its window plus a page.
     """
 
     decode_buckets: Tuple[int, ...] = (2, 4, 8, 16)
@@ -199,6 +201,12 @@ class ServeConfig:
         if self.precision not in PRECISIONS:
             raise ValueError(
                 f"precision {self.precision!r} not in {PRECISIONS}")
+        if self.precision == "int8" and (
+                spec.ffn == "moe" or spec.window_layers
+                or spec.n_kv_heads != spec.heads):
+            raise ValueError(
+                "int8 serving covers equal heads, full layers and the "
+                "GELU FFN only")
         return self.replace(decode_buckets=tuple(dec),
                             prefill_buckets=tuple(pre))
 
@@ -240,13 +248,26 @@ class ServingEngine:
             # the configured kv_pages is an fp32 byte budget — lower
             # precisions buy more physical pages for the same spend,
             # which is the admission-headroom win the bench measures
+            n_window = len(spec.window_layers)
+            # the sliding layers' pool: the largest bucket, every row at
+            # its most (its window's span plus a page), and the null page
+            window_pages = 1 + self.config.decode_buckets[-1] * min(
+                self.max_pages_per_seq,
+                -(-spec.window // self.config.page_size) + 1) \
+                if n_window else 0
             self.pool = PagePool(
-                layers=spec.layers,
+                layers=spec.layers - n_window,
                 pages=kv_page_budget(self.config.kv_pages, prec,
-                                     spec.head_dim),
-                page_size=self.config.page_size, heads=spec.heads,
+                                     spec.head_dim, spec.n_kv_heads),
+                page_size=self.config.page_size, heads=spec.n_kv_heads,
                 head_dim=spec.head_dim, dtype=kv_dtype,
-                scale_pages=(prec == "int8"))
+                scale_pages=(prec == "int8"), window_layers=n_window,
+                window_pages=window_pages, window=spec.window)
+            # the page table the programs take: one row of pages, or the
+            # full layers' row above the sliding layers'
+            self.table_shape = ((2, self.max_pages_per_seq) if n_window
+                                else (self.max_pages_per_seq,))
+            self._aux = None    # the last call's expert counts (L, E)
             self._params = _to_serve_device(self._prepare_params(params))
             self._weights_step = weights_step
             self._weights_lock = threading.Lock()
@@ -332,6 +353,26 @@ class ServingEngine:
             labels = ("params", "k_pool", "v_pool", "k_scale", "v_scale",
                       "tokens", "positions", "page_tables")
             kv_args = (k_struct, k_struct, s_struct, s_struct)
+        elif self.pool.window_pool is not None:
+            # the sliding layers' pools are donated state like the full
+            # layers', after them in the argument order
+            def serve_prefill(params, k_pool, v_pool, kw_pool, vw_pool,
+                              tokens, length, page_table):
+                return prefill_step(spec, params, k_pool, v_pool, tokens,
+                                    length, page_table, page_size=ps,
+                                    kw_pool=kw_pool, vw_pool=vw_pool)
+
+            def serve_decode(params, k_pool, v_pool, kw_pool, vw_pool,
+                             tokens, positions, page_tables):
+                return decode_step(spec, params, k_pool, v_pool, tokens,
+                                   positions, page_tables, page_size=ps,
+                                   kw_pool=kw_pool, vw_pool=vw_pool)
+
+            donate = (1, 2, 3, 4)
+            labels = ("params", "k_pool", "v_pool", "kw_pool", "vw_pool",
+                      "tokens", "positions", "page_tables")
+            w_struct = _struct_like(self.pool.window_pool.k_pool)
+            kv_args = (k_struct, k_struct, w_struct, w_struct)
         else:
             def serve_prefill(params, k_pool, v_pool, tokens, length,
                               page_table):
@@ -360,7 +401,7 @@ class ServingEngine:
         if _audit_rt.audit_enabled():
             aud = _audit_rt
             n_p = len(jax.tree_util.tree_leaves(p_struct))
-            n_kv = len(kv_args) * len(jax.tree_util.tree_leaves(k_struct))
+            n_kv = len(kv_args)
 
         def _compile(jitted, name, *args):
             if aud is None:
@@ -380,7 +421,7 @@ class ServingEngine:
                 p_struct, *kv_args,
                 jax.ShapeDtypeStruct((s,), i32),
                 jax.ShapeDtypeStruct((), i32),
-                jax.ShapeDtypeStruct((self.max_pages_per_seq,), i32))
+                jax.ShapeDtypeStruct(self.table_shape, i32))
 
         for b in cfg.decode_buckets:
             self._decode_exe[b] = _compile(
@@ -388,7 +429,7 @@ class ServingEngine:
                 p_struct, *kv_args,
                 jax.ShapeDtypeStruct((b,), i32),
                 jax.ShapeDtypeStruct((b,), i32),
-                jax.ShapeDtypeStruct((b, self.max_pages_per_seq), i32))
+                jax.ShapeDtypeStruct((b, *self.table_shape), i32))
 
         self.compiled_programs = len(self._prefill_exe) + len(self._decode_exe)
         logger.info(
@@ -437,28 +478,59 @@ class ServingEngine:
 
     def _kv_state(self):
         """The donated pool arrays in program argument order (value
-        pools, plus scale pools on a quantized engine)."""
-        if self.pool.scale_pages:
-            return (self.pool.k_pool, self.pool.v_pool,
-                    self.pool.k_scale, self.pool.v_scale)
-        return (self.pool.k_pool, self.pool.v_pool)
+        pools, plus scale pools on a quantized engine or the sliding
+        layers' pools)."""
+        return self.pool.state()
+
+    def _run(self, exe, params, *args):
+        """Call one program and rebind the pools to what it returns:
+        ``(token(s), logits)`` still on the device.  A model of routed
+        experts also returns its tokens per expert, kept for
+        :meth:`take_aux`."""
+        state = self._kv_state()
+        out = exe(params, *state, *args)
+        self.pool.swap(*out[:len(state)])
+        rest = out[len(state):]
+        self._aux = rest[2] if len(rest) > 2 else None
+        # what the caller reads next is copied to the host behind the
+        # program, not on demand after it: one latency a step less
+        rest[0].copy_to_host_async()
+        if self._aux is not None:
+            self._aux.copy_to_host_async()
+        return rest[0], rest[1]
+
+    def expert_counts(self) -> Optional[np.ndarray]:
+        """Tokens the last program call routed to each expert of each
+        layer, ``(L, E)`` (padding not counted); None for a model
+        without routed experts."""
+        return None if self._aux is None else np.asarray(self._aux)
+
+    def take_aux(self) -> Dict[str, int]:
+        """Counters of the last program call beside its tokens: for
+        routed experts the token-expert pairs it routed
+        (``moe_tokens_routed``, summed over layers), its busiest
+        expert's tokens summed over layers (``moe_expert_max_tokens``)
+        and how many (layer, expert) pairs got any token
+        (``moe_experts_touched``)."""
+        counts = self.expert_counts()
+        if counts is None:
+            return {}
+        return {"moe_tokens_routed": int(counts.sum()),
+                "moe_expert_max_tokens": int(counts.max(axis=1).sum()),
+                "moe_experts_touched": int(np.count_nonzero(counts))}
 
     def _warmup(self) -> None:
         """Execute every program once so first-request latency pays no
         lazy initialization, and the sentinel can be armed on a
         provably quiet path.  Warmup traffic writes only the null page."""
-        maxp = self.max_pages_per_seq
         for s, exe in self._prefill_exe.items():
-            *state, _, _ = exe(self._params, *self._kv_state(),
-                               np.zeros((s,), np.int32), np.int32(1),
-                               np.zeros((maxp,), np.int32))
-            self.pool.swap(*state)
+            self._run(exe, self._params, np.zeros((s,), np.int32),
+                      np.int32(1), np.zeros(self.table_shape, np.int32))
         for b, exe in self._decode_exe.items():
-            *state, _, _ = exe(self._params, *self._kv_state(),
-                               np.zeros((b,), np.int32),
-                               np.zeros((b,), np.int32),
-                               np.zeros((b, maxp), np.int32))
-            self.pool.swap(*state)
+            self._run(exe, self._params, np.zeros((b,), np.int32),
+                      np.zeros((b,), np.int32),
+                      np.zeros((b, *self.table_shape), np.int32))
+        self._aux = None
         jax.block_until_ready(self.pool.k_pool)
 
     def _arm_sentinel(self) -> None:
@@ -520,11 +592,23 @@ class ServingEngine:
                 page_table: np.ndarray) -> int:
         """Run one prompt; returns the first generated token.
 
+        ``page_table`` is the sequence's :attr:`RowPages.table
+        <paddle_tpu.serving.kv_cache.RowPages>` (``table_shape``).
         Three leaf spans (``observability.trace.span``): ``.prep`` is
         the padding, ``.launch`` the executable call until it returns,
         ``.fetch`` the pool rebind and the token's device-to-host copy
         (which waits for the program).  They carry the ``request_id``
         the scheduler left in ``prefill_request_id``."""
+        return self._prefill(tokens, page_table, False)[0]
+
+    def prefill_logits(self, tokens: Sequence[int],
+                       page_table: np.ndarray) -> Tuple[int, np.ndarray]:
+        """:meth:`prefill`, and the logits ``(V,)`` float32 the first
+        token was sampled from — through the same executable and cache
+        the scheduler uses, for checks against a reference."""
+        return self._prefill(tokens, page_table, True)
+
+    def _prefill(self, tokens, page_table, want_logits):
         rid = self.prefill_request_id
         with span("serve.prefill.prep", request_id=rid):
             n = len(tokens)
@@ -535,11 +619,11 @@ class ServingEngine:
             with self._weights_lock:
                 params = self._params
         with span("serve.prefill.launch", request_id=rid):
-            *state, nxt, _ = self._prefill_exe[s](
-                params, *self._kv_state(), padded, np.int32(n), table)
+            nxt, logits = self._run(self._prefill_exe[s], params, padded,
+                                    np.int32(n), table)
         with span("serve.prefill.fetch", request_id=rid):
-            self.pool.swap(*state)
-            return int(nxt)
+            return int(nxt), (np.asarray(logits, np.float32)
+                              if want_logits else None)
 
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
                page_tables: np.ndarray) -> np.ndarray:
@@ -549,24 +633,33 @@ class ServingEngine:
         their (garbage) K/V writes land in the null page.  Leaf spans
         as in :meth:`prefill`, carrying ``rows`` and ``bucket``.
         """
+        return self._decode(tokens, positions, page_tables, False)[0]
+
+    def decode_logits(self, tokens: np.ndarray, positions: np.ndarray,
+                      page_tables: np.ndarray
+                      ) -> Tuple[np.ndarray, np.ndarray]:
+        """:meth:`decode`, and the rows' logits ``(n, V)`` float32 —
+        through the same executable and cache the scheduler uses."""
+        return self._decode(tokens, positions, page_tables, True)
+
+    def _decode(self, tokens, positions, page_tables, want_logits):
         n = tokens.shape[0]
         b = self.decode_bucket_for(max(n, 1))
         with span("serve.decode.prep", rows=n, bucket=b):
-            maxp = self.max_pages_per_seq
             tok = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
-            pt = np.full((b, maxp), NULL_PAGE, np.int32)
+            pt = np.full((b, *self.table_shape), NULL_PAGE, np.int32)
             tok[:n] = tokens
             pos[:n] = positions
             pt[:n] = page_tables
             with self._weights_lock:
                 params = self._params
         with span("serve.decode.launch", rows=n, bucket=b):
-            *state, nxt, _ = self._decode_exe[b](
-                params, *self._kv_state(), tok, pos, pt)
+            nxt, logits = self._run(self._decode_exe[b], params, tok, pos,
+                                    pt)
         with span("serve.decode.fetch", rows=n, bucket=b):
-            self.pool.swap(*state)
-            return np.asarray(nxt)[:n]
+            return np.asarray(nxt)[:n], (
+                np.asarray(logits, np.float32)[:n] if want_logits else None)
 
     # -- weights ------------------------------------------------------------
 

@@ -11,6 +11,19 @@ admission control: the scheduler reserves a sequence's worst-case page
 count (prompt + max_new_tokens) before prefill so a sequence admitted
 into the batch can never stall mid-decode waiting for a page.
 
+Two kinds of layer, two lifetimes (``window_layers`` > 0): a model's
+full-attention layers keep every position for as long as the sequence
+lives, its sliding-window layers only the last ``window``.  The pool of
+the full layers then carries a second pool, ``pool.window_pool``, of the
+sliding layers' pages (its own arrays ``(Lw, Pw, ps, KVH*D)``, free list
+and reservations), and one sequence's pages of both kinds are a
+:class:`RowPages`: admission reckons both kinds (:meth:`PagePool.
+admit_row`), and before each step :meth:`RowPages.advance` returns to
+the window pool the pages that slid out of the window, so a row never
+holds more than ``window`` tokens plus one page there, whatever its
+context.  Both page tables are indexed by logical page
+(``position // ps``); a returned page's entry is the null page again.
+
 Page 0 is reserved as the **null page**: padding rows of a batch
 bucket and the unused tail of every page table point at it, so the
 programs' scatter/gather of padding lanes touch real (never-read)
@@ -25,13 +38,13 @@ provider so the memory census names the pools ``kv::k_pages`` /
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagePool", "KVPoolExhausted", "NULL_PAGE", "kv_page_budget",
-           "pool_shapes"]
+__all__ = ["PagePool", "RowPages", "KVPoolExhausted", "NULL_PAGE",
+           "kv_page_budget", "pool_shapes"]
 
 NULL_PAGE = 0
 
@@ -47,11 +60,14 @@ def pool_shapes(layers: int, pages: int, page_size: int, heads: int,
             (layers, pages, page_size, heads))
 
 
-def kv_page_budget(pages: int, precision: str, head_dim: int) -> int:
+def kv_page_budget(pages: int, precision: str, head_dim: int,
+                   kv_heads: int = 1) -> int:
     """Scale an fp32-denominated page budget to a precision's real cost.
 
     ``PT_SERVE_KV_PAGES`` is a BYTE budget expressed in fp32 pages (so
-    deployments compare precisions at identical HBM spend).  Per
+    deployments compare precisions at identical HBM spend).  A token's
+    row in a page holds ``kv_heads`` heads (the heads the pool stores:
+    fewer than the query heads under grouped-query attention); per
     (token, head) an fp32 page row costs ``4*D`` bytes; bf16 halves it;
     int8 costs ``D`` for the values plus 4 for the f32 scale riding in
     the scale pages.  The null page scales with everything else, so the
@@ -60,11 +76,11 @@ def kv_page_budget(pages: int, precision: str, head_dim: int) -> int:
     """
     if precision in ("fp32", "float32"):
         return pages
-    fp32_cost = 4.0 * head_dim
+    fp32_cost = 4.0 * head_dim * kv_heads
     if precision in ("bf16", "bfloat16"):
-        cost = 2.0 * head_dim
+        cost = 2.0 * head_dim * kv_heads
     elif precision == "int8":
-        cost = head_dim + 4.0
+        cost = (head_dim + 4.0) * kv_heads
     else:
         raise ValueError(f"unknown serve precision {precision!r}")
     return 1 + int((pages - 1) * fp32_cost / cost)
@@ -85,9 +101,25 @@ class PagePool:
 
     def __init__(self, *, layers: int, pages: int, page_size: int,
                  heads: int, head_dim: int, dtype=jnp.float32,
-                 scale_pages: bool = False):
+                 scale_pages: bool = False, window_layers: int = 0,
+                 window_pages: int = 0, window: int = 0,
+                 kind: str = "global"):
         if pages < 2:
             raise ValueError("pages must be >= 2 (page 0 is the null page)")
+        self.kind = kind            # "global" | "window": names the gauges
+        self.window = int(window)
+        # the sliding layers' pool: same page size and row, its own
+        # arrays, free list and reservations
+        self.window_pool: Optional[PagePool] = None
+        if window_layers:
+            if scale_pages:
+                raise ValueError("an int8 pool has no sliding layers")
+            if window < 1:
+                raise ValueError("window_layers need window >= 1")
+            self.window_pool = PagePool(
+                layers=window_layers, pages=window_pages,
+                page_size=page_size, heads=heads, head_dim=head_dim,
+                dtype=dtype, window=window, kind="window")
         self.layers = layers
         self.pages = pages
         self.page_size = page_size
@@ -114,6 +146,10 @@ class PagePool:
             "allocs": 0, "frees": 0, "alloc_failures": 0,
             "reserve_refusals": 0, "high_watermark": 0,
         }
+        if kind == "window":
+            # pages that slid out of a running row's window and went
+            # back to the free list, and the most one row ever held
+            self.stats.update(pages_returned=0, row_pages_max=0)
         self._register_memory_provider()
 
     # -- capacity ----------------------------------------------------------
@@ -124,6 +160,18 @@ class PagePool:
 
     def pages_needed(self, tokens: int) -> int:
         return max(1, -(-int(tokens) // self.page_size))
+
+    def window_pages_needed(self, tokens: int) -> int:
+        """Most pages of a sliding layer a row of up to ``tokens``
+        positions ever holds at once: its window's span plus the page
+        the window starts inside."""
+        return min(self.pages_needed(tokens),
+                   -(-self.window // self.page_size) + 1)
+
+    def first_window_page(self, length: int) -> int:
+        """Logical page of the oldest position a row of ``length``
+        positions still reads in a sliding layer."""
+        return max(0, int(length) - self.window) // self.page_size
 
     @property
     def free_pages(self) -> int:
@@ -147,11 +195,24 @@ class PagePool:
 
     # -- admission-control reservations ------------------------------------
 
-    def can_admit(self, n_pages: int) -> bool:
+    def can_admit(self, n_pages: int, n_window: int = 0) -> bool:
+        """Headroom for ``n_pages`` here and ``n_window`` pages of the
+        sliding layers' pool."""
+        if n_window and not self.window_pool.can_admit(n_window):
+            return False
         return self.headroom() >= n_pages
 
-    def reserve(self, n_pages: int) -> None:
-        """Promise ``n_pages`` to a sequence about to be admitted."""
+    def reserve(self, n_pages: int, n_window: int = 0) -> None:
+        """Promise ``n_pages`` (and ``n_window`` of the sliding layers'
+        pool) to a sequence about to be admitted: both or neither."""
+        if n_window:
+            self.window_pool.reserve(n_window)
+            try:
+                self.reserve(n_pages)
+            except KVPoolExhausted:
+                self.window_pool.release_reservation(n_window)
+                raise
+            return
         with self._lock:
             if len(self._free) - self._reserved < n_pages:
                 self.stats["reserve_refusals"] += 1
@@ -207,6 +268,34 @@ class PagePool:
             self.stats["frees"] += len(page_ids)
         self._gauges()
 
+    def recycle(self, page_ids: Sequence[int]) -> None:
+        """Return pages a running row no longer reads and promise as
+        many back to it: what slid out of its window is what its next
+        pages draw on, so the row's hold on the pool does not grow."""
+        self.free(page_ids)
+        with self._lock:
+            self._reserved += len(page_ids)
+            self.stats["pages_returned"] = \
+                self.stats.get("pages_returned", 0) + len(page_ids)
+        self._gauges()
+
+    def admit_row(self, prompt_len: int, max_new_tokens: int,
+                  max_pages: int) -> Optional["RowPages"]:
+        """Reserve a sequence's worst case in both kinds of layer and
+        allocate its prompt's pages; None (nothing taken) when either
+        pool lacks the headroom."""
+        total = int(prompt_len) + int(max_new_tokens)
+        worst = self.pages_needed(total)
+        worst_w = (self.window_pool.window_pages_needed(total)
+                   if self.window_pool else 0)
+        if not self.can_admit(worst, worst_w):
+            return None
+        try:
+            self.reserve(worst, worst_w)
+        except KVPoolExhausted:
+            return None
+        return RowPages(self, prompt_len, worst, worst_w, max_pages)
+
     def check_consistency(self, expect_all_free: bool = False) -> None:
         """Invariant check used by tests and the serve chaos drills:
         no duplicate/lost pages.  ``expect_all_free=True`` additionally
@@ -224,20 +313,40 @@ class PagePool:
                      f"of {self.usable_pages} pages unaccounted for")
                 assert self._reserved == 0, \
                     f"{self._reserved} pages still reserved"
+        if self.window_pool is not None:
+            self.window_pool.check_consistency(expect_all_free)
+            assert self.window_pool.stats["row_pages_max"] <= \
+                self.window_pool.window_pages_needed(1 << 62), \
+                "a row held more than its window plus a page"
 
     # -- device state -------------------------------------------------------
 
-    def swap(self, k_pool, v_pool, k_scale=None, v_scale=None) -> None:
-        """Rebind the pools to a program's donated outputs (scale pools
-        included when this is a quantized pool)."""
+    def swap(self, k_pool, v_pool, *rest) -> None:
+        """Rebind the pools to a program's donated outputs, in program
+        order: the value pools, then the scale pools of a quantized
+        pool, or the sliding layers' two pools."""
         self.k_pool = k_pool
         self.v_pool = v_pool
         if self.scale_pages:
-            if k_scale is None or v_scale is None:
+            if len(rest) != 2 or rest[0] is None or rest[1] is None:
                 raise ValueError(
                     "quantized pool swap requires k_scale and v_scale")
-            self.k_scale = k_scale
-            self.v_scale = v_scale
+            self.k_scale, self.v_scale = rest
+        elif self.window_pool is not None:
+            if len(rest) != 2:
+                raise ValueError(
+                    "swap requires the sliding layers' k and v pools")
+            self.window_pool.swap(*rest)
+
+    def state(self):
+        """The donated arrays in program argument order (see
+        :meth:`swap`)."""
+        if self.scale_pages:
+            return (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
+        if self.window_pool is not None:
+            return (self.k_pool, self.v_pool, self.window_pool.k_pool,
+                    self.window_pool.v_pool)
+        return (self.k_pool, self.v_pool)
 
     def utilization(self) -> float:
         with self._lock:
@@ -258,6 +367,8 @@ class PagePool:
                 "utilization": (self.usable_pages - free) /
                 max(1, self.usable_pages),
                 **self.stats,
+                **({"window": self.window_pool.snapshot()}
+                   if self.window_pool is not None else {}),
             }
 
     # -- observability ------------------------------------------------------
@@ -273,13 +384,23 @@ class PagePool:
             with self._lock:
                 free = len(self._free)
                 reserved = self._reserved
+            window = self.kind == "window"
             g = get_registry().gauge(
-                "pt_serve_kv_pages",
-                "Serve KV page-pool occupancy by state",
+                "pt_serve_kv_window_pages" if window
+                else "pt_serve_kv_pages",
+                "Serve KV page-pool occupancy by state"
+                + (", sliding-window layers" if window else ""),
                 labelnames=("state",))
             g.set(self.usable_pages - free, state="used")
             g.set(free, state="free")
             g.set(reserved, state="reserved")
+            if window:
+                get_registry().gauge(
+                    "pt_serve_kv_window_pages_returned",
+                    "Pages that slid out of a running row's window and "
+                    "went back to the pool").set(
+                    self.stats["pages_returned"])
+                return
             get_registry().gauge(
                 "pt_serve_kv_utilization",
                 "Fraction of usable KV pages in use").set(
@@ -300,6 +421,9 @@ class PagePool:
         """Live-buffer attribution for the PR 14 census: the pools
         (and, for quantized pools, their scale shadows) under ``kv::``
         paths."""
+        if self.kind == "window":
+            return {"kv::k_window_pages": self.k_pool,
+                    "kv::v_window_pages": self.v_pool}
         named = {"kv::k_pages": self.k_pool, "kv::v_pages": self.v_pool}
         if self.scale_pages:
             named["kv::k_scales"] = self.k_scale
@@ -315,3 +439,87 @@ class PagePool:
         row = np.full((max_pages,), NULL_PAGE, np.int32)
         row[:len(page_ids)] = np.asarray(page_ids, np.int32)
         return row
+
+
+class RowPages:
+    """One sequence's pages in both kinds of layer, and the page table
+    the programs take: ``(max_pages,)`` of the full layers' pages, or
+    ``(2, max_pages)`` with the sliding layers' below them.  Built by
+    :meth:`PagePool.admit_row` with the worst case reserved; the owner
+    calls :meth:`advance` before the step that writes position ``pos``
+    and :meth:`release` when the sequence leaves, however it leaves."""
+
+    def __init__(self, pool: PagePool, prompt_len: int, reserved: int,
+                 reserved_window: int, max_pages: int):
+        self.pool = pool
+        wpool = pool.window_pool
+        self.page_ids: List[int] = pool.alloc(
+            pool.pages_needed(prompt_len), reserved=True)
+        self.reserved_left = reserved - len(self.page_ids)
+        self.window_ids: Dict[int, int] = {}    # logical page -> page id
+        self._window_span = None    # (first, last) logical pages held
+        self.window_reserved_left = reserved_window
+        self.table = np.full((2, max_pages) if wpool else (max_pages,),
+                             NULL_PAGE, np.int32)
+        row = self.table[0] if wpool else self.table
+        if len(self.page_ids) > max_pages:
+            self.release()
+            raise ValueError(f"{len(self.page_ids)} pages exceed table "
+                             f"width {max_pages}")
+        row[:len(self.page_ids)] = self.page_ids
+        if wpool:
+            # what the first decode step (length prompt_len + 1) reads
+            self._hold_window(wpool.first_window_page(prompt_len + 1),
+                              (prompt_len - 1) // pool.page_size)
+
+    def _hold_window(self, first: int, last: int) -> int:
+        """Hold exactly the sliding layers' logical pages ``first ..
+        last``: return the older ones, allocate the missing.  Returns
+        how many went back."""
+        wpool = self.pool.window_pool
+        gone = [p for p in self.window_ids if p < first]
+        if gone:
+            wpool.recycle([self.window_ids.pop(p) for p in gone])
+            self.window_reserved_left += len(gone)
+            self.table[1, gone] = NULL_PAGE
+        for p in range(first, last + 1):
+            if p not in self.window_ids:
+                pid, = wpool.alloc(1, reserved=True)
+                self.window_reserved_left -= 1
+                self.window_ids[p] = self.table[1, p] = pid
+        wpool.stats["row_pages_max"] = max(wpool.stats["row_pages_max"],
+                                           len(self.window_ids))
+        return len(gone)
+
+    def advance(self, pos: int) -> int:
+        """Make room for position ``pos``: grow the full layers' pages
+        to cover it and slide the sliding layers' window to end at it —
+        all drawn from the admission-time reservation, so it cannot
+        fail.  Returns the window pages that went back to the pool."""
+        pool = self.pool
+        page = pos // pool.page_size
+        if page >= len(self.page_ids):
+            new = pool.alloc(page + 1 - len(self.page_ids), reserved=True)
+            row = self.table[0] if pool.window_pool else self.table
+            row[len(self.page_ids):len(self.page_ids) + len(new)] = new
+            self.page_ids += new
+            self.reserved_left -= len(new)
+        if pool.window_pool is None:
+            return 0
+        span = (pool.window_pool.first_window_page(pos + 1), page)
+        if span == self._window_span:   # most steps: the same pages
+            return 0
+        self._window_span = span
+        return self._hold_window(*span)
+
+    def release(self) -> None:
+        """Everything back: pages to the free lists, what is left of the
+        reservations released.  Safe to call twice."""
+        pool = self.pool
+        pool.free(self.page_ids)
+        pool.release_reservation(self.reserved_left)
+        self.page_ids, self.reserved_left = [], 0
+        if pool.window_pool is not None:
+            pool.window_pool.free(list(self.window_ids.values()))
+            pool.window_pool.release_reservation(self.window_reserved_left)
+            self.window_ids, self.window_reserved_left = {}, 0

@@ -24,8 +24,11 @@ clamps its decode bucket ladder to >= 2 rows (see
 
 Device-trace scopes: both steps run under ``jax.named_scope`` — ``embed``,
 per layer ``layer<i>/attn_qkv``, ``layer<i>/kv_write`` (the pool
-``.at[i, page, slot].set`` and the int8 scale writes), ``layer<i>/attn``,
-``layer<i>/attn_out``, ``layer<i>/mlp``, then ``lm_head`` and ``sample``.
+``.at[i, page, slot].set`` and the int8 scale writes), ``layer<i>/attn``
+(``layer<i>/attn_window`` / ``layer<i>/attn_global`` in a model that has
+sliding layers), ``layer<i>/attn_out``, ``layer<i>/mlp`` (or
+``layer<i>/moe_route`` and ``layer<i>/moe_experts``), then ``lm_head``
+and ``sample``.
 The kernel takes the pools whole, so nothing stands between the write
 and the read: the ``kv_read`` scope of earlier versions has no operation
 left and is gone.  Prefill writes each layer's K/V as whole pages under
@@ -37,19 +40,18 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.paged_attention import paged_attention, paged_attention_int8
 from ..ops.quant_kernels import quantize_kv, w8a16_matmul
+from . import experts as _experts
 
 __all__ = ["ModelSpec", "init_params", "prefill_step", "decode_step",
            "QUANT_WEIGHT_NAMES"]
-
-_LN_EPS = 1e-5
-
 
 def QUANT_WEIGHT_NAMES(spec: "ModelSpec"):
     """The weight matrices the int8 serve path quantizes: every
@@ -79,7 +81,26 @@ def _matmul(params, name, x, tap=None):
 
 @dataclasses.dataclass(frozen=True)
 class ModelSpec:
-    """Architecture hyperparameters of a served decoder."""
+    """Architecture of a served decoder: one block, driven by these
+    kinds and sizes.  The defaults are the GPT-2 block (layer norm,
+    learned positions, equal heads of ``hidden // heads``, GELU FFN of
+    ``ffn_mult``, tied head); every other field names a departure.
+
+    ``kv_heads`` / ``head_size``: 0 means ``heads`` / ``hidden // heads``.
+    ``norm``: ``layer`` (with bias) or ``rms``.  ``positions``:
+    ``learned`` (a table of ``max_seq_len``) or ``rotary`` (rotate-half
+    pairing, base ``rope_theta``).  ``layer_types``: per layer ``full``
+    or ``sliding`` (empty: all full); a sliding layer sees the last
+    ``window`` positions.  ``yarn_factor`` > 0 rescales the rotary
+    frequencies of the *full* layers (YaRN: ``yarn_original_len``,
+    ``yarn_beta_fast`` / ``_slow``, and ``yarn_attention_factor`` on cos
+    and sin, 0 meaning ``0.1 ln(factor) + 1``), at every length; sliding
+    layers keep the plain frequencies.  ``ffn``: ``gelu`` or ``moe``
+    (``experts`` routed SwiGLU experts of ``expert_width``,
+    ``experts_per_token`` a token, no drops: :mod:`.experts`, which
+    picks its regime by the program's row count).  ``tie_head``: logits
+    against the embedding, or an own ``head`` matrix.
+    """
 
     vocab_size: int = 256
     hidden: int = 64
@@ -87,67 +108,320 @@ class ModelSpec:
     heads: int = 4
     max_seq_len: int = 256
     ffn_mult: int = 4
+    kv_heads: int = 0
+    head_size: int = 0
+    norm: str = "layer"
+    norm_eps: float = 1e-5
+    positions: str = "learned"
+    rope_theta: float = 10000.0
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 0.0
+    layer_types: Tuple[str, ...] = ()
+    window: int = 0
+    ffn: str = "gelu"
+    experts: int = 0
+    experts_per_token: int = 0
+    expert_width: int = 0
+    tie_head: bool = True
 
     @property
     def head_dim(self) -> int:
-        return self.hidden // self.heads
+        return self.head_size or self.hidden // self.heads
+
+    @property
+    def n_kv_heads(self) -> int:
+        return self.kv_heads or self.heads
 
     def __post_init__(self):
-        if self.hidden % self.heads:
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if not self.head_size and self.hidden % self.heads:
             raise ValueError(
                 f"hidden={self.hidden} not divisible by heads={self.heads}")
+        if self.heads % self.n_kv_heads:
+            raise ValueError(f"heads={self.heads} not a multiple of "
+                             f"kv_heads={self.n_kv_heads}")
+        for name, value, allowed in (
+                ("norm", self.norm, ("layer", "rms")),
+                ("positions", self.positions, ("learned", "rotary")),
+                ("ffn", self.ffn, ("gelu", "moe"))):
+            if value not in allowed:
+                raise ValueError(f"{name}={value!r} not in {allowed}")
+        if self.layer_types:
+            if len(self.layer_types) != self.layers or \
+                    set(self.layer_types) - {"full", "sliding"}:
+                raise ValueError(
+                    f"layer_types must name {self.layers} layers, each "
+                    f"'full' or 'sliding': {self.layer_types}")
+            if "sliding" in self.layer_types and self.window < 1:
+                raise ValueError("sliding layers need window >= 1")
+        if self.ffn == "moe" and not (
+                0 < self.experts_per_token <= self.experts
+                and self.expert_width > 0):
+            raise ValueError("ffn='moe' needs experts, experts_per_token "
+                             "and expert_width")
+
+    def layer_window(self, i: int) -> int:
+        """Layer ``i``'s window in positions, 0 for a full layer."""
+        sliding = bool(self.layer_types) and self.layer_types[i] == "sliding"
+        return self.window if sliding else 0
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.layers) if self.layer_window(i))
+
+    @property
+    def global_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.layers)
+                     if not self.layer_window(i))
 
     def to_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
+        d = dataclasses.asdict(self)
+        d["layer_types"] = list(self.layer_types)
+        return d
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "ModelSpec":
-        names = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: int(v) for k, v in d.items() if k in names})
+        """Each value cast to its field's own type (ints stay ints,
+        ``norm_eps`` a float, ``layer_types`` a tuple of strings)."""
+        kinds = {f.name: type(f.default) for f in dataclasses.fields(cls)}
+        return cls(**{k: kinds[k](v) for k, v in d.items() if k in kinds})
 
 
-def init_params(spec: ModelSpec, seed: int = 0) -> Dict[str, jnp.ndarray]:
-    """Flat ``path -> array`` dict (checkpoint-manager friendly)."""
+def init_params(spec: ModelSpec, seed: int = 0,
+                dtype=jnp.float32) -> Dict[str, jnp.ndarray]:
+    """Flat ``path -> array`` dict (checkpoint-manager friendly), drawn
+    in ``dtype``: a large model is made in the precision it is served in
+    and never exists in float32."""
     rng = jax.random.PRNGKey(seed)
     p: Dict[str, jnp.ndarray] = {}
 
     def _w(key, shape, scale=0.02):
-        return (jax.random.normal(key, shape, jnp.float32) * scale)
+        return (jax.random.normal(key, shape, dtype) * scale)
 
+    def _extra(i, j):       # keys beside the GPT block's own split
+        return jax.random.fold_in(jax.random.fold_in(rng, 1000 + i), j)
+
+    hd, kvd = spec.heads * spec.head_dim, spec.n_kv_heads * spec.head_dim
     keys = jax.random.split(rng, 2 + spec.layers * 6)
     p["embed"] = _w(keys[0], (spec.vocab_size, spec.hidden))
-    p["pos"] = _w(keys[1], (spec.max_seq_len, spec.hidden))
+    if spec.positions == "learned":
+        p["pos"] = _w(keys[1], (spec.max_seq_len, spec.hidden))
+
+    def _norm(name):
+        p[name + ".w"] = jnp.ones((spec.hidden,), dtype)
+        if spec.norm == "layer":
+            p[name + ".b"] = jnp.zeros((spec.hidden,), dtype)
+
     for i in range(spec.layers):
         k = keys[2 + i * 6: 8 + i * 6]
-        ffn = spec.hidden * spec.ffn_mult
-        p[f"h{i}.ln1.w"] = jnp.ones((spec.hidden,), jnp.float32)
-        p[f"h{i}.ln1.b"] = jnp.zeros((spec.hidden,), jnp.float32)
-        p[f"h{i}.attn.wq"] = _w(k[0], (spec.hidden, spec.hidden))
-        p[f"h{i}.attn.wk"] = _w(k[1], (spec.hidden, spec.hidden))
-        p[f"h{i}.attn.wv"] = _w(k[2], (spec.hidden, spec.hidden))
-        p[f"h{i}.attn.wo"] = _w(k[3], (spec.hidden, spec.hidden))
-        p[f"h{i}.ln2.w"] = jnp.ones((spec.hidden,), jnp.float32)
-        p[f"h{i}.ln2.b"] = jnp.zeros((spec.hidden,), jnp.float32)
-        p[f"h{i}.mlp.w1"] = _w(k[4], (spec.hidden, ffn))
-        p[f"h{i}.mlp.b1"] = jnp.zeros((ffn,), jnp.float32)
-        p[f"h{i}.mlp.w2"] = _w(k[5], (ffn, spec.hidden))
-        p[f"h{i}.mlp.b2"] = jnp.zeros((spec.hidden,), jnp.float32)
-    p["lnf.w"] = jnp.ones((spec.hidden,), jnp.float32)
-    p["lnf.b"] = jnp.zeros((spec.hidden,), jnp.float32)
+        _norm(f"h{i}.ln1")
+        p[f"h{i}.attn.wq"] = _w(k[0], (spec.hidden, hd))
+        p[f"h{i}.attn.wk"] = _w(k[1], (spec.hidden, kvd))
+        p[f"h{i}.attn.wv"] = _w(k[2], (spec.hidden, kvd))
+        p[f"h{i}.attn.wo"] = _w(k[3], (hd, spec.hidden))
+        _norm(f"h{i}.ln2")
+        if spec.ffn == "moe":
+            e, f = spec.experts, spec.expert_width
+            p[f"h{i}.moe.router"] = _w(k[4], (spec.hidden, e))
+            p[f"h{i}.moe.wg"] = _w(k[5], (e, spec.hidden, f))
+            p[f"h{i}.moe.wu"] = _w(_extra(i, 0), (e, spec.hidden, f))
+            p[f"h{i}.moe.wd"] = _w(_extra(i, 1), (e, f, spec.hidden))
+        else:
+            ffn = spec.hidden * spec.ffn_mult
+            p[f"h{i}.mlp.w1"] = _w(k[4], (spec.hidden, ffn))
+            p[f"h{i}.mlp.b1"] = jnp.zeros((ffn,), dtype)
+            p[f"h{i}.mlp.w2"] = _w(k[5], (ffn, spec.hidden))
+            p[f"h{i}.mlp.b2"] = jnp.zeros((spec.hidden,), dtype)
+    _norm("lnf")
+    if not spec.tie_head:
+        p["head"] = _w(_extra(spec.layers, 0),
+                       (spec.hidden, spec.vocab_size))
     return p
 
 
-def _ln(x, w, b):
+def _norm(spec, params, name, x):
+    """Layer norm or RMS norm in float32, by the spec."""
     x32 = x.astype(jnp.float32)
+    if spec.norm == "rms":
+        ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return x32 * jax.lax.rsqrt(ms + spec.norm_eps) * params[name + ".w"]
     mu = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
-    return (x32 - mu) * jax.lax.rsqrt(var + _LN_EPS) * w + b
+    return ((x32 - mu) * jax.lax.rsqrt(var + spec.norm_eps)
+            * params[name + ".w"] + params[name + ".b"])
 
 
 def _mlp(spec, params, i, x, tap=None):
     h = _matmul(params, f"h{i}.mlp.w1", x, tap) + params[f"h{i}.mlp.b1"]
     h = jax.nn.gelu(h)
     return _matmul(params, f"h{i}.mlp.w2", h, tap) + params[f"h{i}.mlp.b2"]
+
+
+def _ffn(spec, params, i, h, tap, valid, counts):
+    """The block's second half: ``h`` plus the FFN of its normed rows —
+    the GELU MLP under ``layer<i>/mlp``, or the routed experts under
+    ``layer<i>/moe_route`` (norm, router, top-k; ``counts`` gains the
+    layer's tokens per expert) and ``layer<i>/moe_experts``."""
+    cdt = params["embed"].dtype
+    if spec.ffn != "moe":
+        with jax.named_scope(f"layer{i}/mlp"):
+            x = _norm(spec, params, f"h{i}.ln2", h).astype(cdt)
+            return h + _mlp(spec, params, i, x, tap)
+    with jax.named_scope(f"layer{i}/moe_route"):
+        x = _norm(spec, params, f"h{i}.ln2", h).astype(cdt)
+        gates, idx = _experts.route(x, params[f"h{i}.moe.router"],
+                                    spec.experts_per_token)
+        counts.append(_experts.expert_counts(idx, spec.experts, valid))
+    with jax.named_scope(f"layer{i}/moe_experts"):
+        out = _experts.experts(
+            x, gates, idx, params[f"h{i}.moe.wg"], params[f"h{i}.moe.wu"],
+            params[f"h{i}.moe.wd"], valid=valid)
+        return h + out.astype(cdt)
+
+
+def _rope_tables(spec, positions):
+    """``{layer kind: (cos, sin)}`` of ``positions`` (T,), each (T, D/2)
+    float32; ``full`` differs from ``sliding`` only under YaRN."""
+    d = spec.head_dim
+    i = np.arange(0, d, 2, dtype=np.float64) / d
+    plain = spec.rope_theta ** -i
+    freqs, factor = {"sliding": plain, "full": plain}, {}
+    if spec.yarn_factor:
+        s, l0 = spec.yarn_factor, spec.yarn_original_len
+
+        def corr(beta):
+            return (d * math.log(l0 / (2 * math.pi * beta))
+                    / (2 * math.log(spec.rope_theta)))
+
+        low = max(math.floor(corr(spec.yarn_beta_fast)), 0)
+        high = min(math.ceil(corr(spec.yarn_beta_slow)), d - 1)
+        ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3),
+                       0.0, 1.0)
+        freqs["full"] = plain / s * ramp + plain * (1.0 - ramp)
+        factor["full"] = (spec.yarn_attention_factor
+                          or 0.1 * math.log(s) + 1.0)
+    out = {}
+    for kind in ("full", "sliding"):
+        ang = (positions.astype(jnp.float32)[:, None]
+               * jnp.asarray(freqs[kind], jnp.float32)[None, :])
+        m = factor.get(kind, 1.0)
+        out[kind] = (jnp.cos(ang) * m, jnp.sin(ang) * m)
+    return out
+
+
+def _rotate(x, cos_sin):
+    """Rotary embedding, rotate-half pairing: lane ``i`` pairs with lane
+    ``i + D/2``.  ``x`` (T, heads, D); float32 inside, ``x.dtype`` out."""
+    cos, sin = (t[:, None, :] for t in cos_sin)
+    half = x.shape[-1] // 2
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _qkv(spec, params, i, h, rope, tap):
+    """Layer ``i``'s normed rows through Wq, Wk, Wv: q (T, H, D) and
+    k, v (T, KVH, D), rotated where the spec says rotary."""
+    t = h.shape[0]
+    cdt = params["embed"].dtype
+    x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
+    q = _matmul(params, f"h{i}.attn.wq", x,
+                tap).reshape(t, spec.heads, spec.head_dim)
+    k = _matmul(params, f"h{i}.attn.wk", x,
+                tap).reshape(t, spec.n_kv_heads, spec.head_dim)
+    v = _matmul(params, f"h{i}.attn.wv", x,
+                tap).reshape(t, spec.n_kv_heads, spec.head_dim)
+    if rope is not None:
+        cs = rope["sliding" if spec.layer_window(i) else "full"]
+        q, k = _rotate(q, cs), _rotate(k, cs)
+    return q, k, v
+
+
+def _attn_scope(spec, i):
+    """``layer<i>/attn`` for a model of full layers only (the name its
+    metrics read), else ``attn_window`` / ``attn_global`` by the layer."""
+    if not spec.window_layers:
+        return f"layer{i}/attn"
+    return f"layer{i}/attn_" + ("window" if spec.layer_window(i)
+                                else "global")
+
+
+_DENSE_PREFILL_MAX = 1024   # longest bucket whose (H, S, S) scores are held
+_PREFILL_BLOCK = 512        # queries and keys a block of the blocked form
+
+
+def _prefill_attention(spec, q, k, v, length, window):
+    """Causal (and windowed) attention of one padded prompt, grouped
+    heads: q (S, H, D), k / v (S, KVH, D) -> (S, H*D) float32.  Key ``u``
+    is visible to query ``p`` iff ``u <= p``, ``u < length`` and, with a
+    window, ``p - u < window``.  Up to ``_DENSE_PREFILL_MAX`` positions
+    the scores are one ``(H, S, S)`` tensor; beyond, blocks of
+    ``_PREFILL_BLOCK`` queries walk the key blocks they can see with an
+    online softmax, so nothing of size S x S exists."""
+    s, kvh, d = k.shape
+    g = spec.heads // kvh
+    scale = 1.0 / math.sqrt(d)
+    qg = q.reshape(s, kvh, g, d)
+    blk = _PREFILL_BLOCK
+
+    def visible(qpos, kpos):
+        m = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] < length)
+        if window:
+            m &= qpos[:, None] - kpos[None, :] < window
+        return m
+
+    if s <= _DENSE_PREFILL_MAX or s % blk:
+        pos = jnp.arange(s, dtype=jnp.int32)
+        # equal heads carry no group axis: a unit axis in the score
+        # tensor is one more shape for the compiler to lay out
+        scores, values = (("ihd,jhd->hij", "hij,jhd->ihd") if g == 1 else
+                          ("ikgd,jkd->kgij", "kgij,jkd->ikgd"))
+        att = jnp.einsum(scores, q if g == 1 else qg, k,
+                         preferred_element_type=jnp.float32) * scale
+        att = jnp.where(visible(pos, pos), att, -1e30)
+        w = jax.nn.softmax(att, axis=-1)
+        return jnp.einsum(values, w.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32
+                          ).reshape(s, spec.heads * d)
+
+    nb = s // blk
+    qb = qg.reshape(nb, blk, kvh, g, d)
+    kb, vb = k.reshape(nb, blk, kvh, d), v.reshape(nb, blk, kvh, d)
+    within = jnp.arange(blk, dtype=jnp.int32)
+
+    def one_block(i):
+        qi, qpos = qb[i], i * blk + within
+        lo = jnp.maximum(i * blk - window + 1, 0) // blk if window else 0
+        # a block of queries wholly past the prompt walks nothing
+        hi = jnp.where(i * blk < length, i + 1, lo)
+
+        def step(j, carry):
+            m, l, acc = carry
+            sc = jnp.einsum("qkgd,skd->kgqs", qi, kb[j],
+                            preferred_element_type=jnp.float32) * scale
+            vis = visible(qpos, j * blk + within)[None, None]
+            sc = jnp.where(vis, sc, -1e30)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+            p = jnp.where(vis, jnp.exp(sc - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            pv = jnp.einsum("kgqs,skd->kgqd", p.astype(v.dtype), vb[j],
+                            preferred_element_type=jnp.float32)
+            return (m_new, alpha * l + jnp.sum(p, axis=-1),
+                    acc * alpha[..., None] + pv)
+
+        m0 = jnp.full((kvh, g, blk), -1e30, jnp.float32)
+        _, l, acc = jax.lax.fori_loop(
+            lo, hi, step, (m0, jnp.zeros_like(m0),
+                           jnp.zeros((kvh, g, blk, d), jnp.float32)))
+        o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+        return jnp.transpose(o, (2, 0, 1, 3))              # (blk, KVH, G, D)
+
+    return jax.lax.map(one_block, jnp.arange(nb, dtype=jnp.int32)
+                       ).reshape(s, spec.heads * d)
 
 
 def _page_slot(page_tables, positions, page_size):
@@ -159,97 +433,138 @@ def _page_slot(page_tables, positions, page_size):
     return page, positions % page_size
 
 
+def _head(spec, params, hf):
+    """Logits of normed rows: against the embedding when tied, else the
+    model's own head matrix."""
+    if spec.tie_head:
+        return hf @ params["embed"].T
+    return hf @ params["head"]
+
+
+def _results(spec, pools, token, logits, counts):
+    """A step's outputs in program order: the donated pools, the sampled
+    token(s), the logits and, for routed experts, the tokens each layer
+    sent to each expert ``(L, E)``."""
+    out = tuple(p for p in pools if p is not None) + (token, logits)
+    if spec.ffn == "moe":
+        out += (jnp.stack(counts),)
+    return out
+
+
 def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                  tokens, length, page_table, *, page_size: int,
-                 k_scale=None, v_scale=None, tap=None):
+                 k_scale=None, v_scale=None, kw_pool=None, vw_pool=None,
+                 tap=None):
     """Run one prompt (padded to a seq bucket) and seed its KV pages.
 
     Args:
-      k_pool/v_pool: donated pools ``(L, P, ps, H*D)``
-        (:func:`.kv_cache.pool_shapes`), written a whole page at a time.
+      k_pool/v_pool: donated pools ``(Lg, P, ps, KVH*D)`` of the full
+        layers (:func:`.kv_cache.pool_shapes`), written a whole page at
+        a time.
       tokens: ``(S,)`` int32, padded prompt (bucket size S).
       length: scalar int32, true prompt length (1 <= length <= S).
       page_table: ``(max_pages,)`` int32 pages owned by this sequence
         (unused tail = 0, the reserved null page); at least
-        ``ceil(S / ps)`` entries.
+        ``ceil(S / ps)`` entries.  A model with sliding layers takes
+        ``(2, max_pages)``: row 0 the full layers' pages, row 1 the
+        sliding layers', indexed by logical page alike, null wherever
+        the sequence holds no page (the pages before its window).
       page_size: static tokens-per-page (trace-time constant).
       k_scale/v_scale: donated scale pools ``(L, P, ps, H)`` f32 when
         the KV pool is int8 (``k_pool.dtype``); the prompt's K/V are
         quantized per (token, head) at write time.
+      kw_pool/vw_pool: donated pools ``(Lw, Pw, ps, KVH*D)`` of the
+        sliding layers.  A prompt longer than the window writes only
+        the last ``window / ps + 1`` pages there: what a later decode
+        step can read.
       tap: optional calibration hook ``tap(site, activation)`` — only
         ever non-None in the eager PTQ harness, never in a serve trace.
 
-    Returns ``(k_pool, v_pool, next_token, logits)``, with the two
-    scale pools spliced in after ``v_pool`` when they were passed.
+    Returns ``(k_pool, v_pool, next_token, logits)``, with the scale
+    pools, then the sliding layers' pools, spliced in after ``v_pool``
+    when they were passed, and the experts' token counts ``(L, E)``
+    appended for ``ffn='moe'``.
     Prefill attends over the in-layer full-precision K/V (the stored
     pages are for later decode steps), matching standard PTQ serving
     stacks.
     """
     s = tokens.shape[0]
     scope = jax.named_scope
-    with scope("embed"):
-        h = params["embed"][tokens] + params["pos"][:s]
-    cdt = params["embed"].dtype
     pos_ids = jnp.arange(s, dtype=jnp.int32)
-    # causal AND inside the true prompt: key j visible to query i iff
-    # j <= i and j < length
+    with scope("embed"):
+        h = params["embed"][tokens]
+        if spec.positions == "learned":
+            h = h + params["pos"][:s]
+        rope = (_rope_tables(spec, pos_ids)
+                if spec.positions == "rotary" else None)
+    cdt = params["embed"].dtype
     in_prompt = pos_ids < length
-    mask = (pos_ids[None, :] <= pos_ids[:, None]) & in_prompt[None, :]
-    scale = 1.0 / math.sqrt(spec.head_dim)
     quant = k_pool.dtype == jnp.int8
     n_pages = -(-s // page_size)
-    # a page wholly past the prompt goes to the null page 0, so only
-    # pages the sequence owns are written
-    page_ids = jnp.where(
-        jnp.arange(n_pages, dtype=jnp.int32) * page_size < length,
-        page_table[:n_pages], 0)
+    tables = page_table if page_table.ndim == 2 else page_table[None]
 
-    def write(pool, layer, rows):
+    def write(pool, layer, rows, table, first, count):
         """``rows`` (S, ...) of one layer's K, V or scales into the
-        prompt's pages.  Rows past ``length`` become zeros: the kernel
-        masks those slots (``pos < length``) until the decode step that
-        reaches each one overwrites it."""
+        prompt's pages ``first .. first + count``.  Rows past ``length``
+        become zeros: the kernel masks those slots (``pos < length``)
+        until the decode step that reaches each one overwrites it.  A
+        page wholly past the prompt, or one the sequence holds no page
+        for, goes to the null page 0."""
         keep = in_prompt.reshape(s, *[1] * (rows.ndim - 1))
         rows = jnp.where(keep, rows, 0).astype(pool.dtype)
         rows = jnp.pad(rows, ((0, n_pages * page_size - s),)
                        + ((0, 0),) * (rows.ndim - 1))
+        if count < n_pages:
+            rows = jax.lax.dynamic_slice_in_dim(
+                rows, first * page_size, count * page_size)
+            table = jax.lax.dynamic_slice_in_dim(table, first, count)
+            logical = first + jnp.arange(count, dtype=jnp.int32)
+        else:
+            table = table[:n_pages]
+            logical = jnp.arange(n_pages, dtype=jnp.int32)
+        page_ids = jnp.where(logical * page_size < length, table, 0)
         return pool.at[layer, page_ids].set(
-            rows.reshape(n_pages, page_size, *rows.shape[1:]))
+            rows.reshape(count, page_size, *rows.shape[1:]))
 
+    counts = []
     for i in range(spec.layers):
+        window = spec.layer_window(i)
         with scope(f"layer{i}/attn_qkv"):
-            x = _ln(h, params[f"h{i}.ln1.w"],
-                    params[f"h{i}.ln1.b"]).astype(cdt)
-            q = _matmul(params, f"h{i}.attn.wq", x,
-                        tap).reshape(s, spec.heads, spec.head_dim)
-            k = _matmul(params, f"h{i}.attn.wk", x,
-                        tap).reshape(s, spec.heads, spec.head_dim)
-            v = _matmul(params, f"h{i}.attn.wv", x,
-                        tap).reshape(s, spec.heads, spec.head_dim)
-        with scope(f"layer{i}/attn"):
-            att = jnp.einsum("ihd,jhd->hij", q, k,
-                             preferred_element_type=jnp.float32) * scale
-            att = jnp.where(mask[None, :, :], att, -1e30)
-            w = jax.nn.softmax(att, axis=-1)
-            o = jnp.einsum("hij,jhd->ihd", w.astype(v.dtype), v,
-                           preferred_element_type=jnp.float32
-                           ).reshape(s, spec.hidden).astype(cdt)
+            q, k, v = _qkv(spec, params, i, h, rope, tap)
+        with scope(_attn_scope(spec, i)):
+            o = _prefill_attention(spec, q, k, v, length, window).astype(cdt)
         with scope(f"layer{i}/attn_out"):
             h = h + _matmul(params, f"h{i}.attn.wo", o, tap)
-        with scope(f"layer{i}/mlp"):
-            x2 = _ln(h, params[f"h{i}.ln2.w"],
-                     params[f"h{i}.ln2.b"]).astype(cdt)
-            h = h + _mlp(spec, params, i, x2, tap)
+        h = _ffn(spec, params, i, h, tap, in_prompt, counts)
         # the layer's K/V go into this sequence's pages, a whole page at
         # a time, and are dead after it: no (L, S, H*D) stack is held
         with scope(f"layer{i}/kv_write"):
+            kvd = spec.n_kv_heads * spec.head_dim
+            if window:
+                # only the pages a decode step can still read: the
+                # window's span from the first position it will see
+                count = min(n_pages, -(-window // page_size) + 1)
+                first = jnp.clip(
+                    jnp.maximum(length + 1 - window, 0) // page_size,
+                    0, n_pages - count)
+                li = spec.window_layers.index(i)
+                kw_pool = write(kw_pool, li, k.reshape(s, kvd), tables[1],
+                                first, count)
+                vw_pool = write(vw_pool, li, v.reshape(s, kvd), tables[1],
+                                first, count)
+                h, kw_pool, vw_pool = jax.lax.optimization_barrier(
+                    (h, kw_pool, vw_pool))
+                continue
+            li = spec.global_layers.index(i)
             if quant:
                 k, ksc = quantize_kv(k)
                 v, vsc = quantize_kv(v)
-                k_scale = write(k_scale, i, ksc)
-                v_scale = write(v_scale, i, vsc)
-            k_pool = write(k_pool, i, k.reshape(s, spec.hidden))
-            v_pool = write(v_pool, i, v.reshape(s, spec.hidden))
+                k_scale = write(k_scale, li, ksc, tables[0], 0, n_pages)
+                v_scale = write(v_scale, li, vsc, tables[0], 0, n_pages)
+            k_pool = write(k_pool, li, k.reshape(s, kvd), tables[0], 0,
+                           n_pages)
+            v_pool = write(v_pool, li, v.reshape(s, kvd), tables[0], 0,
+                           n_pages)
             # the next layer waits for these writes: left free, XLA's
             # schedule puts all 2L of them after the stack and keeps
             # every layer's K and V alive until then.  (Not the int8
@@ -259,94 +574,115 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
             h, k_pool, v_pool = jax.lax.optimization_barrier(
                 (h, k_pool, v_pool))
     with scope("lm_head"):
-        hf = _ln(h, params["lnf.w"], params["lnf.b"]).astype(cdt)
+        hf = _norm(spec, params, "lnf", h).astype(cdt)
         if tap is not None:
             tap("head", hf)
         # only the last prompt row feeds the sampler: one row against
         # the embedding, not an (S, V) product to pick a row from
         last = jax.lax.dynamic_slice_in_dim(hf, length - 1, 1, axis=0)
-        logits = (last @ params["embed"].T)[0]                 # (V,)
+        logits = _head(spec, params, last)[0]                  # (V,)
     with scope("sample"):
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    if k_scale is not None:
-        return k_pool, v_pool, k_scale, v_scale, next_token, logits
-    return k_pool, v_pool, next_token, logits
+    return _results(spec, (k_pool, v_pool, k_scale, v_scale, kw_pool,
+                           vw_pool), next_token, logits, counts)
 
 
 def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 tokens, positions, page_tables, *, page_size: int,
-                k_scale=None, v_scale=None, tap=None):
+                k_scale=None, v_scale=None, kw_pool=None, vw_pool=None,
+                tap=None):
     """One decode step for a padded batch bucket.
 
     Args:
-      k_pool/v_pool: donated pools ``(L, P, ps, H*D)``; each layer
-        writes the rows' new K/V at ``[layer, page, slot]`` (B rows of
-        H*D lanes) and hands the pools whole to the kernel.
+      k_pool/v_pool: donated pools ``(Lg, P, ps, KVH*D)`` of the full
+        layers; each layer writes the rows' new K/V at ``[layer, page,
+        slot]`` (B rows of KVH*D lanes) and hands the pools whole to the
+        kernel.
       tokens: ``(B,)`` int32 current token per row.
       positions: ``(B,)`` int32 position of that token (0-based);
         padding rows point at position 0 with page_table row 0 so
         their writes land in the null page.
-      page_tables: ``(B, max_pages)`` int32.
+      page_tables: ``(B, max_pages)`` int32, or ``(B, 2, max_pages)``
+        for a model with sliding layers (see :func:`prefill_step`).
       page_size: static tokens-per-page (trace-time constant).
       k_scale/v_scale: donated scale pools ``(L, P, ps, H)`` f32 for an
         int8 pool; the step's K/V quantize per (token, head) at write
         time — a pure per-row function, so row bytes never depend on
         batch neighbours (the bit-identity contract survives int8).
+      kw_pool/vw_pool: donated pools of the sliding layers; the kernel
+        walks only the pages of a row's window there.
       tap: optional calibration hook (eager PTQ harness only).
 
     Returns ``(k_pool, v_pool, next_tokens, logits)``, with the scale
-    pools spliced in after ``v_pool`` when they were passed.
+    pools, then the sliding layers' pools, spliced in after ``v_pool``
+    when they were passed, and the experts' token counts ``(L, E)`` of
+    the rows that hold a page appended for ``ffn='moe'``.
     """
     b = tokens.shape[0]
     quant = k_pool.dtype == jnp.int8
     scope = jax.named_scope
+    tables = (page_tables if page_tables.ndim == 3
+              else page_tables[:, None])
     with scope("embed"):
-        page, slot = _page_slot(page_tables, positions, page_size)  # (B,)
         lengths = positions + 1
-        h = params["embed"][tokens] + params["pos"][positions]
+        h = params["embed"][tokens]
+        if spec.positions == "learned":
+            h = h + params["pos"][positions]
+        rope = (_rope_tables(spec, positions)
+                if spec.positions == "rotary" else None)
+        # a padding row's tables are all null: it routes to no count
+        live = jnp.any(tables != 0, axis=(1, 2))
     cdt = params["embed"].dtype
+    kvd = spec.n_kv_heads * spec.head_dim
+    counts = []
     for i in range(spec.layers):
+        window = spec.layer_window(i)
+        table = tables[:, 1 if window else 0]
         with scope(f"layer{i}/attn_qkv"):
-            x = _ln(h, params[f"h{i}.ln1.w"],
-                    params[f"h{i}.ln1.b"]).astype(cdt)
-            q = _matmul(params, f"h{i}.attn.wq", x,
-                        tap).reshape(b, spec.heads, spec.head_dim)
-            k = _matmul(params, f"h{i}.attn.wk", x,
-                        tap).reshape(b, spec.heads, spec.head_dim)
-            v = _matmul(params, f"h{i}.attn.wv", x,
-                        tap).reshape(b, spec.heads, spec.head_dim)
-        with scope(f"layer{i}/kv_write"):
-            if quant:
-                k, ksc = quantize_kv(k)
-                v, vsc = quantize_kv(v)
-                k_scale = k_scale.at[i, page, slot].set(ksc)
-                v_scale = v_scale.at[i, page, slot].set(vsc)
-            k_pool = k_pool.at[i, page, slot].set(
-                k.reshape(b, spec.hidden).astype(k_pool.dtype))
-            v_pool = v_pool.at[i, page, slot].set(
-                v.reshape(b, spec.hidden).astype(v_pool.dtype))
-        with scope(f"layer{i}/attn"):
-            if quant:
-                o = paged_attention_int8(q, k_pool, v_pool, k_scale,
-                                         v_scale, page_tables, lengths,
-                                         layer=i)
-            else:
-                o = paged_attention(q, k_pool, v_pool, page_tables,
-                                    lengths, layer=i)
+            q, k, v = _qkv(spec, params, i, h, rope, tap)
+            page, slot = _page_slot(table, positions, page_size)  # (B,)
+        if window:
+            li = spec.window_layers.index(i)
+            with scope(f"layer{i}/kv_write"):
+                kw_pool = kw_pool.at[li, page, slot].set(
+                    k.reshape(b, kvd).astype(kw_pool.dtype))
+                vw_pool = vw_pool.at[li, page, slot].set(
+                    v.reshape(b, kvd).astype(vw_pool.dtype))
+            with scope(_attn_scope(spec, i)):
+                o = paged_attention(
+                    q, kw_pool, vw_pool, table, lengths, layer=li,
+                    window=window, steps=kw_pool.shape[1] - 1 + b)
+        else:
+            li = spec.global_layers.index(i)
+            with scope(f"layer{i}/kv_write"):
+                if quant:
+                    k, ksc = quantize_kv(k)
+                    v, vsc = quantize_kv(v)
+                    k_scale = k_scale.at[li, page, slot].set(ksc)
+                    v_scale = v_scale.at[li, page, slot].set(vsc)
+                k_pool = k_pool.at[li, page, slot].set(
+                    k.reshape(b, kvd).astype(k_pool.dtype))
+                v_pool = v_pool.at[li, page, slot].set(
+                    v.reshape(b, kvd).astype(v_pool.dtype))
+            with scope(_attn_scope(spec, i)):
+                if quant:
+                    o = paged_attention_int8(q, k_pool, v_pool, k_scale,
+                                             v_scale, table, lengths,
+                                             layer=li)
+                else:
+                    o = paged_attention(q, k_pool, v_pool, table, lengths,
+                                        layer=li,
+                                        steps=k_pool.shape[1] - 1 + b)
         with scope(f"layer{i}/attn_out"):
             h = h + _matmul(params, f"h{i}.attn.wo",
-                            o.reshape(b, spec.hidden), tap)
-        with scope(f"layer{i}/mlp"):
-            x2 = _ln(h, params[f"h{i}.ln2.w"],
-                     params[f"h{i}.ln2.b"]).astype(cdt)
-            h = h + _mlp(spec, params, i, x2, tap)
+                            o.reshape(b, spec.heads * spec.head_dim), tap)
+        h = _ffn(spec, params, i, h, tap, live, counts)
     with scope("lm_head"):
-        hf = _ln(h, params["lnf.w"], params["lnf.b"]).astype(cdt)
+        hf = _norm(spec, params, "lnf", h).astype(cdt)
         if tap is not None:
             tap("head", hf)
-        logits = hf @ params["embed"].T                        # (B, V)
+        logits = _head(spec, params, hf)                       # (B, V)
     with scope("sample"):
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    if k_scale is not None:
-        return k_pool, v_pool, k_scale, v_scale, next_tokens, logits
-    return k_pool, v_pool, next_tokens, logits
+    return _results(spec, (k_pool, v_pool, k_scale, v_scale, kw_pool,
+                           vw_pool), next_tokens, logits, counts)

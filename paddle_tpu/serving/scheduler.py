@@ -8,8 +8,10 @@ One scheduler tick = one *step boundary*:
     unused reservations, resolve the caller's stream),
  3. **admit** queued sequences while a decode slot AND worst-case KV
     headroom exist — admission reserves ``ceil((prompt+max_new)/ps)``
-    pages up front so an admitted sequence can never stall mid-decode
-    waiting for a page (admission control against pool headroom),
+    pages up front (and, in a model with sliding-window layers, the
+    window's span plus a page of their pool: ``PagePool.admit_row``) so
+    an admitted sequence can never stall mid-decode waiting for a page
+    (admission control against pool headroom),
  4. **decode** one token for every active row, padded to the smallest
     compiled batch bucket.
 
@@ -78,7 +80,6 @@ import numpy as np
 from ..observability.metrics import get_registry
 from ..observability.telemetry import get_telemetry
 from ..observability.trace import get_tracer, span
-from .kv_cache import KVPoolExhausted
 
 logger = logging.getLogger("paddle_tpu.serving")
 
@@ -230,17 +231,13 @@ class GenerationStream:
 class _Active:
     """Per-sequence decode state while resident in the batch."""
 
-    __slots__ = ("stream", "page_ids", "page_table", "pos", "last_token",
-                 "reserved_left")
+    __slots__ = ("stream", "pages", "pos", "last_token")
 
-    def __init__(self, stream, page_ids, page_table, pos, last_token,
-                 reserved_left):
+    def __init__(self, stream, pages, pos, last_token):
         self.stream = stream
-        self.page_ids = page_ids        # owned pages, in position order
-        self.page_table = page_table    # np (max_pages,) int32
+        self.pages = pages              # kv_cache.RowPages: pages + table
         self.pos = pos                  # position last_token will occupy
         self.last_token = last_token
-        self.reserved_left = reserved_left
 
 
 class ContinuousScheduler:
@@ -268,6 +265,17 @@ class ContinuousScheduler:
             "peak_active": 0,
             "shed": 0, "cancelled": 0, "deadline_exceeded": 0,
             "failed": 0, "drain_seconds": None, "watchdog_trips": 0,
+            # work the programs did: prompt positions prefilled, rows
+            # decoded; sliding-window pages that went back to the pool
+            # while their row ran; and, for routed experts, token-expert
+            # pairs routed, the sum over calls and layers of the busiest
+            # expert's tokens (their ratio times the expert count is the
+            # load's max over mean, a call and layer), and the (layer,
+            # expert) pairs the decode steps touched
+            "prefill_tokens": 0, "decode_tokens": 0,
+            "kv_window_pages_returned": 0,
+            "moe_tokens_routed": 0, "moe_expert_max_tokens": 0,
+            "moe_decode_experts_touched": 0,
             # seconds of the scheduler thread by phase (module docstring)
             "wait_s": 0.0, "evict_s": 0.0, "admit_host_s": 0.0,
             "prefill_s": 0.0, "decode_prep_s": 0.0, "decode_s": 0.0,
@@ -394,10 +402,7 @@ class ContinuousScheduler:
         return False
 
     def _release_locked(self, a: _Active) -> None:
-        pool = self.engine.pool
-        pool.free(a.page_ids)
-        if a.reserved_left:
-            pool.release_reservation(a.reserved_left)
+        a.pages.release()
 
     def _finish_evicted_locked(self, st: GenerationStream,
                                cause: str) -> None:
@@ -483,7 +488,7 @@ class ContinuousScheduler:
                     seated = None
                 job = self._reserve_next_locked()
                 if job is not None:
-                    st, page_ids, page_table, reserved_left = job
+                    st, pages = job
                     engine.prefill_request_id = st.request_id
                     stats["admitted"] += 1
                     st.admitted_ts = t0 = time.monotonic()
@@ -492,13 +497,12 @@ class ContinuousScheduler:
             if job is None:
                 return
             try:
-                first = engine.prefill(st.prompt, page_table)
+                first = engine.prefill(st.prompt, pages.table)
                 st.first_token_ts = st.last_token_ts = time.monotonic()
-                seated = (st, first, page_ids, page_table, reserved_left)
+                seated = (st, first, pages)
             except Exception as exc:  # resolve the caller, keep serving
                 stats["prefill_s"] += time.monotonic() - t0
-                engine.pool.free(page_ids)
-                engine.pool.release_reservation(reserved_left)
+                pages.release()
                 stats["failed"] += 1
                 self._book("pt_serve_request_failures_total",
                            kind="counter", stage="prefill")
@@ -507,16 +511,18 @@ class ContinuousScheduler:
                                  st.request_id)
 
     def _reserve_next_locked(self):
-        """Pop the head of the queue and reserve its worst-case pages:
-        ``(stream, page ids, page table, reserved pages left)``, or None
-        when nothing can be admitted now."""
-        pool = self.engine.pool
+        """Pop the head of the queue with its worst-case pages reserved
+        in every kind of layer and its prompt's pages allocated:
+        ``(stream, RowPages)``, or None when nothing can be admitted
+        now."""
         if not self._queue or \
                 len(self._active) >= self.engine.config.decode_buckets[-1]:
             return None
         st = self._queue[0]
-        worst_case = pool.pages_needed(len(st.prompt) + st.max_new_tokens)
-        if not pool.can_admit(worst_case):
+        pages = self.engine.pool.admit_row(
+            len(st.prompt), st.max_new_tokens,
+            self.engine.max_pages_per_seq)
+        if pages is None:
             # head-of-line blocking is deliberate: skipping ahead
             # would starve large requests under sustained load
             self.stats["refused_kv"] += 1
@@ -524,27 +530,30 @@ class ContinuousScheduler:
                        kind="counter", reason="kv_headroom")
             return None
         self._queue.popleft()
-        try:
-            pool.reserve(worst_case)
-        except KVPoolExhausted:
-            self.stats["refused_kv"] += 1
-            self._queue.appendleft(st)
-            return None
-        prompt_pages = pool.pages_needed(len(st.prompt))
-        page_ids = pool.alloc(prompt_pages, reserved=True)
-        page_table = pool.null_padded_table(
-            page_ids, self.engine.max_pages_per_seq)
-        return st, page_ids, page_table, worst_case - prompt_pages
+        return st, pages
 
-    def _seat_locked(self, st, first, page_ids, page_table,
-                     reserved_left) -> None:
+    def _book_engine_locked(self, decode: bool = False) -> None:
+        """What the last engine call reported beside its tokens (the
+        experts' load), into the counters."""
+        aux = self.engine.take_aux()
+        touched = aux.pop("moe_experts_touched", None)
+        if decode and touched is not None:
+            self.stats["moe_decode_experts_touched"] += touched
+        for key, value in aux.items():
+            self.stats[key] += value
+            self._book(f"pt_serve_{key}_total", kind="counter", value=value)
+
+    def _seat_locked(self, st, first, pages) -> None:
         """Book a prefilled request's first token and seat it in the
         batch (or retire it, if one token was all it asked for)."""
         st.tokens.append(first)
         self._book("pt_serve_tokens_total", kind="counter")
         self.stats["tokens_generated"] += 1
-        act = _Active(st, page_ids, page_table, pos=len(st.prompt),
-                      last_token=first, reserved_left=reserved_left)
+        self.stats["prefill_tokens"] += len(st.prompt)
+        self._book("pt_serve_prefill_tokens_total", kind="counter",
+                   value=len(st.prompt))
+        self._book_engine_locked()
+        act = _Active(st, pages, pos=len(st.prompt), last_token=first)
         if self._is_finished(act):
             self._retire_locked(act)
         else:
@@ -557,24 +566,16 @@ class ContinuousScheduler:
             return False
         stats = self.stats
         with span("serve.decode.prep") as sp:
-            pool = self.engine.pool
-            ps = self.engine.config.page_size
             # grow page tables for rows whose next write crosses a page
-            # boundary — drawn from the admission-time reservation, so
-            # this alloc cannot fail
+            # boundary, and give back what slid out of a window — drawn
+            # from the admission-time reservation, so it cannot fail
             for a in self._active:
-                need = a.pos // ps + 1
-                if need > len(a.page_ids):
-                    new = pool.alloc(need - len(a.page_ids), reserved=True)
-                    for pid in new:
-                        a.page_table[len(a.page_ids)] = pid
-                        a.page_ids.append(pid)
-                    a.reserved_left -= len(new)
+                stats["kv_window_pages_returned"] += a.pages.advance(a.pos)
             n = len(self._active)
             tokens = np.asarray([a.last_token for a in self._active],
                                 np.int32)
             positions = np.asarray([a.pos for a in self._active], np.int32)
-            tables = np.stack([a.page_table for a in self._active])
+            tables = np.stack([a.pages.table for a in self._active])
             # watchdog arms on the device call
             self._step_started = t0 = time.monotonic()
         stats["decode_prep_s"] += sp.seconds
@@ -626,6 +627,10 @@ class ContinuousScheduler:
                         "step bookkeeping failed for request %d",
                         a.stream.request_id)
             stats["tokens_generated"] += booked
+            stats["decode_tokens"] += n
+            self._book("pt_serve_decode_tokens_total", kind="counter",
+                       value=n)
+            self._book_engine_locked(decode=True)
             self._book("pt_serve_tokens_total", kind="counter",
                        value=booked)
             self._active = still
@@ -651,10 +656,7 @@ class ContinuousScheduler:
         return eos >= 0 and a.last_token == eos
 
     def _retire_locked(self, a: _Active) -> None:
-        pool = self.engine.pool
-        pool.free(a.page_ids)
-        if a.reserved_left:
-            pool.release_reservation(a.reserved_left)
+        a.pages.release()
         st = a.stream
         st._finish()
         self.stats["completed"] += 1
@@ -932,6 +934,12 @@ _METRIC_HELP = {
     "pt_serve_hang_watchdog_trips_total":
         "Hang-watchdog trips (decode step exceeded Nx rolling p99)",
     "pt_serve_tokens_total": "Tokens generated by the serve engine",
+    "pt_serve_prefill_tokens_total": "Prompt positions prefilled",
+    "pt_serve_decode_tokens_total": "Rows decoded, summed over steps",
+    "pt_serve_moe_tokens_routed_total":
+        "Token-expert pairs routed, summed over layers",
+    "pt_serve_moe_expert_max_tokens_total":
+        "Tokens of the busiest expert, summed over calls and layers",
     "pt_serve_queue_depth": "Requests waiting for admission",
     "pt_serve_active_sequences": "Sequences resident in the decode batch",
     "pt_serve_batch_occupancy":

@@ -370,3 +370,157 @@ def test_readers_on_the_recorded_chip_cut(bench):
     # the kernel is found by the name the program gave it
     assert tr.ops_per_run(r"^pallas:paged_attention\.", "serve_decode")[0] \
         == tr.ops_per_run(r"^pallas:", "serve_decode")[0] > 0.005
+
+
+# -- PR 27: the readers of the sparse grouped-query decoder's cell ------------
+
+LM_METRICS = ["moe_ms_per_decode_step", "moe_decode_roofline",
+              "moe_ms_per_prefill", "moe_prefill_roofline",
+              "attn_global_ms_per_step", "attn_window_ms_per_step",
+              "paged_attn_gqa_roofline", "expert_load_max_over_mean",
+              "serve_mfu_pct.mellum2", "attn_prefill_ms_per_run"]
+LM_MODEL = {"layers": 2, "heads": 4, "kv_heads": 2, "head_dim": 128,
+            "hidden": 256, "vocab_size": 1024, "layer_types":
+            ["sliding", "full"], "window": 8, "page_size": 4,
+            "kv_itemsize": 2, "weight_itemsize": 2, "experts": 8,
+            "experts_per_token": 2, "expert_width": 128}
+LM_PEAK = {"flops_bf16": 1e12, "hbm_bytes_per_s": 1e11}
+
+
+def lm_trace():
+    """One prefill run [1000, 6000) and two decode runs of one program.
+    The prefill's grouped matmul has lost its scope (the chip's expansion
+    of a ragged dot); a decode's experts are scoped fusions."""
+    ops = [
+        ["fusion:fusion.1", 1000, 500],             # layer0/moe_experts sort
+        ["pallas:ragged-dot-none.3", 1500, 2000],   # no scope at all
+        ["pallas:moe_gmm.7", 3500, 1000],           # layer1/moe_experts
+        ["fusion:fusion.9", 4500, 1500],            # layer0/attn_window
+    ]
+    for t in (7000, 12000):
+        ops += [["pallas:paged_attention_window.1", t, 600],
+                ["pallas:paged_attention_gqa.2", t + 600, 400],
+                ["fusion:fusion.5", t + 1000, 2000],    # layer0/moe_experts
+                ["fusion:fusion.6", t + 3000, 100]]     # layer0/moe_route
+    modules = [[f"jit_serve_prefill({PREFILL})", 1000, 5000],
+               [f"jit_serve_decode({DECODE})", 7000, 4000],
+               [f"jit_serve_decode({DECODE})", 12000, 4000]]
+    return {"planes": [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": ops},
+            {"name": "XLA Modules", "events": modules}]},
+        {"name": "/host:CPU", "lines": [{"name": "python3", "events":
+                                         [["bench:window", 0, 20000]]}]}],
+        "text": {},
+        "scopes": {
+            PREFILL: {"fusion.1": "jit(serve_prefill)/layer0/moe_experts/sort:",
+                      "moe_gmm.7":
+                      "jit(serve_prefill)/layer1/moe_experts/moe_gmm/pallas_call:",
+                      "fusion.9": "jit(serve_prefill)/layer0/attn_window/while:"},
+            DECODE: {"paged_attention_window.1":
+                     "jit(serve_decode)/layer0/attn_window/pallas_call:",
+                     "paged_attention_gqa.2":
+                     "jit(serve_decode)/layer1/attn_global/pallas_call:",
+                     "fusion.5": "jit(serve_decode)/layer0/moe_experts/dot:",
+                     "fusion.6": "jit(serve_decode)/layer0/moe_route/top_k:"}}}
+
+
+def lm_run(bench):
+    run = as_run(bench, lm_trace(), counters={
+        "occupancy_steps": 10, "moe_decode_experts_touched": 10 * 2 * 6,
+        "moe_tokens_routed": 800, "moe_expert_max_tokens": 300,
+        "prefill_tokens": 600, "decode_tokens": 400, "admitted": 3})
+    run.update(model=LM_MODEL, peak=LM_PEAK, seconds=2.0, chips=1,
+               trace_window=(0.0, 100.0),
+               # (t0, t1, rows, context, seen full, seen sliding)
+               decode_rows=[(1.0, 2.0, 3, 30, 30, 20),
+                            (3.0, 4.0, 3, 33, 33, 21),
+                            (200.0, 201.0, 9, 99, 99, 99)],   # outside
+               prefill_rows=[(5.0, 6.0, 50)])
+    return run
+
+
+def test_lm_scoped_times_count_each_operation_once(bench):
+    run = lm_run(bench)
+    assert read(bench, "moe_ms_per_decode_step", run) == \
+        pytest.approx(2000 / 1e6)
+    assert read(bench, "attn_window_ms_per_step", run) == \
+        pytest.approx(600 / 1e6)
+    assert read(bench, "attn_global_ms_per_step", run) == \
+        pytest.approx(400 / 1e6)
+    # the scoped sort and kernel, and the ragged dot that has no scope
+    assert read(bench, "moe_ms_per_prefill", run) == \
+        pytest.approx((500 + 2000 + 1000) / 1e6)
+    # the prompt's own attention, scoped by the layer's kind
+    assert read(bench, "attn_prefill_ms_per_run", run) == \
+        pytest.approx(1500 / 1e6)
+
+
+def test_lm_rooflines_are_least_time_over_measured_time(bench):
+    sys.path.insert(0, BENCH)
+    import costs_lm
+    from costs import least_seconds
+    run = lm_run(bench)
+    m = LM_MODEL
+    # decode experts: 3 rows, 6 experts touched a step and layer (counter)
+    least = 2 * least_seconds(*costs_lm.moe_decode(3, 6.0, 256, 128, 2, 2),
+                              LM_PEAK)[0]
+    assert read(bench, "moe_decode_roofline", run) == \
+        pytest.approx(100 * least * 1e3 / (2000 / 1e6))
+    least = 2 * least_seconds(*costs_lm.moe_prefill(50, 8, 256, 128, 2, 2),
+                              LM_PEAK)[0]
+    assert read(bench, "moe_prefill_roofline", run) == \
+        pytest.approx(100 * least * 1e3 / (3500 / 1e6))
+    # attention: one full layer on every position, one sliding layer on
+    # what its window holds; the mean of the two steps in the window
+    def att(seen):
+        return least_seconds(*costs_lm.paged_decode_gqa(
+            seen, 3, m["heads"], m["kv_heads"], m["head_dim"], 2),
+            LM_PEAK)[0]
+    least = (att(30) + att(20) + att(33) + att(21)) / 2
+    assert read(bench, "paged_attn_gqa_roofline", run) == \
+        pytest.approx(100 * least * 1e3 / (1000 / 1e6))
+
+
+def test_lm_counter_metrics(bench):
+    run = lm_run(bench)
+    assert read(bench, "expert_load_max_over_mean", run) == \
+        pytest.approx(8 * 300 / 800)
+    sys.path.insert(0, BENCH)
+    import costs_lm
+    # the head once a prompt (3) and once a decoded row (400), not once
+    # a prompt position
+    assert read(bench, "serve_mfu_pct.mellum2", run) == pytest.approx(
+        100 * 2 * (costs_lm.layer_params(LM_MODEL) * 1000
+                   + costs_lm.head_params(LM_MODEL) * 403) / (2.0 * 1e12))
+
+
+@pytest.mark.parametrize("name", LM_METRICS)
+def test_lm_reader_returns_none_on_a_program_without_the_marks(bench, name):
+    """The GPT serve program's trace (no experts, no window scopes, none
+    of the counters), traced and untraced: nothing to read, no value."""
+    old = as_run(bench, serve_trace(), counters={"occupancy_steps": 400})
+    old.update(model={"heads": 16, "head_dim": 64, "layers": 24,
+                      "kv_itemsize": 4}, peak=LM_PEAK, seconds=40.0,
+               trace_window=(0.0, 100.0), decode_rows=[(1.0, 2.0, 3, 30)])
+    assert read(bench, name, old) is None
+    assert read(bench, name, {"trace": None, "counters": {}}) is None
+
+
+def test_lm_metrics_have_entries_for_the_new_cell_only(bench):
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    entries = {m["name"]: m for m in spec["per_layer"]}
+    cell = "mellum2-serve-mixedctx-backlog"
+    assert cell in {w["name"] for w in spec["workloads"]}
+    for name in LM_METRICS + ["kv_window_pages_returned"]:
+        assert entries[name]["workloads"] == [cell]
+        assert entries[name]["moves"] == "serve_tokens_per_s"
+        assert os.path.exists(os.path.join(
+            BENCH, "layer_metrics", name + ".py")) or os.path.exists(
+            os.path.join(BENCH, "layer_metrics", name + ".json"))
+    tokens = next(m for m in spec["end_to_end"]
+                  if m["name"] == "serve_tokens_per_s")
+    assert cell in tokens["workloads"]
+    # the prefill's KV write is scoped `kv_write` in the new block too:
+    # the accepted reader reads it unedited
+    assert entries["prefill_kv_ms_per_run"]["workloads"][-1] == cell

@@ -153,3 +153,124 @@ def test_chip_program_keeps_the_pools_in_place(one_chip, monkeypatch,
     layer_elems = int(np.prod(state[0].shape[1:]))
     moved = _moved_pool_sized(text, layer_elems)
     assert not moved, moved
+
+
+# -- Mellum2-12B-A2.5B-Instruct's programs at its published widths ----------
+# (`benchmarks/configs/mellum2-12b-a2p5b-serve.json`): one period of its
+# layer pattern, so a program compiles in seconds; the pools, page size and
+# largest buckets are the configuration's.
+
+LM_SPEC = ModelSpec(
+    vocab_size=98304, hidden=2304, layers=4, heads=32, kv_heads=4,
+    head_size=128, max_seq_len=8192, norm="rms", norm_eps=1e-6,
+    positions="rotary", rope_theta=500000.0, yarn_factor=16.0,
+    yarn_original_len=8192, yarn_attention_factor=1.2772588722239782,
+    layer_types=("sliding", "sliding", "sliding", "full"), window=1024,
+    ffn="moe", experts=64, experts_per_token=8, expert_width=896,
+    tie_head=False)
+LM_PAGE, LM_PAGES, LM_WINDOW_PAGES = 128, 2049, 577
+# a tenth of the weights the configuration serves (12 layers, bf16): the
+# temporaries of a program do not grow with depth, its weights do
+LM_WEIGHT_BYTES = 2 * (12 * (2 * 2304 * 4096 + 2 * 2304 * 512 + 2304 * 64
+                             + 64 * 3 * 2304 * 896 + 2 * 2304)
+                       + 2 * 98304 * 2304 + 2304)
+
+
+def _compile_lm(one_chip, monkeypatch, kind, size):
+    monkeypatch.setattr(_device, "on_tpu", lambda: True)
+    bf = jnp.bfloat16
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(functools.partial(init_params, LM_SPEC, dtype=bf)))
+    full, _ = pool_shapes(1, LM_PAGES, LM_PAGE, 4, 128)
+    sliding, _ = pool_shapes(3, LM_WINDOW_PAGES, LM_PAGE, 4, 128)
+    state = [jax.ShapeDtypeStruct(s, bf, sharding=one_chip)
+             for s in (full, full, sliding, sliding)]
+    maxp = LM_SPEC.max_seq_len // LM_PAGE
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = decode_step if kind == "decode" else prefill_step
+
+    def run(params, k, v, kw, vw, a, b, c):
+        return step(LM_SPEC, params, k, v, a, b, c, page_size=LM_PAGE,
+                    kw_pool=kw, vw_pool=vw)
+
+    args = ((i32((size,)), i32((size,)), i32((size, 2, maxp)))
+            if kind == "decode" else
+            (i32((size,)), i32(()), i32((2, maxp))))
+    exe = jax.jit(run, donate_argnums=(1, 2, 3, 4)).lower(
+        params, *state, *args).compile()
+    return exe, state
+
+
+def _shapes_of(text):
+    """(dtype, dims) of every instruction result in the optimized HLO."""
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m:
+            yield (m.group(1), tuple(int(d) for d in m.group(2).split(",")
+                                     if d), m.group(3), line)
+
+
+@pytest.mark.parametrize("kind,size", [("decode", 64), ("prefill", 8192)])
+def test_chip_compiles_the_grouped_windowed_sparse_programs(
+        one_chip, monkeypatch, kind, size):
+    exe, state = _compile_lm(one_chip, monkeypatch, kind, size)
+    text = exe.as_text()
+    mem = exe.memory_analysis()
+    pool_bytes = sum(int(np.prod(s.shape)) * 2 for s in state)
+    # all four pools are donated and aliased
+    assert mem.alias_size_in_bytes >= pool_bytes
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 4
+    # temporaries under a tenth of the weights served
+    assert mem.temp_size_in_bytes < LM_WEIGHT_BYTES / 10, \
+        (mem.temp_size_in_bytes, LM_WEIGHT_BYTES)
+    expert = 64 * 2304 * 896
+    for dtype, dims, op, line in _shapes_of(text):
+        elems = int(np.prod(dims)) if dims else 1
+        # nothing shaped like expert matrices is gathered, copied or
+        # re-laid, and no (tokens, 8, hidden, width) tensor exists: the
+        # one weight-shaped result is a layer's own 64 experts
+        if dims[-2:] in ((2304, 896), (896, 2304)):
+            assert op not in ("gather", "copy", "transpose",
+                              "dynamic-slice", "scatter"), line[:200]
+            assert elems <= expert, line[:200]
+        # no (heads, S, S) score tensor at the long bucket
+        assert not (len(dims) >= 2 and dims[-1] >= 8192
+                    and dims[-2] >= 8192), line[:200]
+    if kind == "decode":
+        # the Mosaic kernel once a layer, both members of the family
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) >= LM_SPEC.layers
+        assert "paged_attention_window" in text
+        assert "paged_attention_gqa" in text
+        assert mem.temp_size_in_bytes < pool_bytes / 10
+    else:
+        # three grouped matmuls a layer, the Pallas kernel each
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) >= 3 * LM_SPEC.layers
+        assert "moe_gmm" in text and "ragged-dot" not in text
+
+
+@pytest.mark.parametrize("window,pages", [(0, LM_PAGES),
+                                          (1024, LM_WINDOW_PAGES)])
+def test_chip_compiles_the_grouped_kernel_alone(one_chip, window, pages):
+    from paddle_tpu.ops.paged_attention import _paged_attention_gqa_pallas
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def kernel(q, k, v, tables, lengths):
+        return _paged_attention_gqa_pallas(
+            q, k, v, tables, lengths, layer=0, sm_scale=128 ** -0.5,
+            window=window, steps=pages - 1 + 64, interpret=False)
+
+    exe = jax.jit(kernel).lower(
+        sds((64, 32, 128), bf), sds((1, pages, LM_PAGE, 512), bf),
+        sds((1, pages, LM_PAGE, 512), bf), sds((64, 64), jnp.int32),
+        sds((64,), jnp.int32)).compile()
+    assert "tpu_custom_call" in exe.as_text()
+    # the walk list and the padded rows: far from a pool
+    assert exe.memory_analysis().temp_size_in_bytes < 16 << 20

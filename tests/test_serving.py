@@ -178,9 +178,11 @@ def test_paged_attention_whole_pool_matches_reference(layer):
 
 def test_paged_attention_refuses_a_pool_of_the_wrong_width():
     q, k, v, tables, lengths, _, _ = _paged_case(layers=1)
+    # 12 lanes are no whole number of heads of 8 (8 lanes would be one
+    # KV head for the two query heads: a grouped pool, and served)
     with pytest.raises(ValueError, match="lanes"):
-        paged_attention(jnp.asarray(q), jnp.asarray(k[..., :8]),
-                        jnp.asarray(v[..., :8]), jnp.asarray(tables),
+        paged_attention(jnp.asarray(q), jnp.asarray(k[..., :12]),
+                        jnp.asarray(v[..., :12]), jnp.asarray(tables),
                         jnp.asarray(lengths), layer=0, use_pallas=True,
                         interpret=True)
 
@@ -221,29 +223,19 @@ def _plain_logits(params, tokens):
 
 def _run_through_programs(engine, prompt, steps, pages):
     """Prefill and ``steps`` decode steps through the engine's own
-    programs and pools, the way ``engine.prefill`` / ``decode`` call
-    them; returns the logits row of each step and the tokens fed."""
-    pool, maxp = engine.pool, engine.max_pages_per_seq
-    table = pool.null_padded_table(pages, maxp)
+    programs and pools (the public ``prefill_logits`` /
+    ``decode_logits``: the executables ``engine.prefill`` / ``decode``
+    call); returns the logits row of each step and the tokens fed."""
+    table = engine.pool.null_padded_table(pages, engine.max_pages_per_seq)
     n = len(prompt)
-    s = engine.prefill_bucket_for(n)
-    padded = np.zeros((s,), np.int32)
-    padded[:n] = prompt
-    *state, nxt, logits = engine._prefill_exe[s](
-        engine._params, *engine._kv_state(), padded, np.int32(n), table)
-    pool.swap(*state)
-    rows, toks = [np.asarray(logits)], [int(nxt)]
-    b = engine.config.decode_buckets[0]
+    nxt, logits = engine.prefill_logits(prompt, table)
+    rows, toks = [logits], [nxt]
     for j in range(steps):
-        tok = np.zeros((b,), np.int32)
-        pos = np.zeros((b,), np.int32)
-        pt = np.zeros((b, maxp), np.int32)
-        tok[0], pos[0], pt[0] = toks[-1], n + j, table
-        *state, nxt, logits = engine._decode_exe[b](
-            engine._params, *engine._kv_state(), tok, pos, pt)
-        pool.swap(*state)
-        rows.append(np.asarray(logits)[0])
-        toks.append(int(np.asarray(nxt)[0]))
+        nxt, logits = engine.decode_logits(
+            np.asarray(toks[-1:], np.int32), np.asarray([n + j], np.int32),
+            table[None])
+        rows.append(logits[0])
+        toks.append(int(nxt[0]))
     return np.stack(rows), toks
 
 
