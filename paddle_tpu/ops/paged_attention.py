@@ -15,19 +15,29 @@ first-class:
    neighbours or on which physical pages it landed in).
  - :func:`paged_attention` — dispatcher: Pallas kernel on TPU,
    reference elsewhere; selection is by platform only.
- - ``_paged_attention_pallas`` — the kernel: grid ``(batch, pages)``
-   with the per-sequence page table scalar-prefetched so each grid
-   step's ``BlockSpec`` index map *is* the page-table lookup (the page
-   gather never materialises in HBM), online-softmax accumulators in
-   VMEM scratch.  Interpret-runnable off-TPU.
+ - ``_paged_attention_pallas`` — the equal-heads kernel (fp32 / bf16
+   pools): one grid axis over the *chunks* the batch really holds, a
+   chunk being ``C`` consecutive pages of one row (``chunk_pages``: 128
+   tokens' worth).  The rows' runs of chunks lie end to end in a
+   scalar-prefetched work list (:func:`_walk`): the kernel copies a
+   chunk's pages from the pools where they lie into one ``(C * ps,
+   H*D)`` tile, a list entry ahead of the arithmetic (the page gather
+   never materialises in HBM), and a slot a row does not hold costs
+   neither a grid step nor a fetch; online-softmax accumulators in VMEM
+   scratch.  A row whose table starts with the null page 0 holds
+   nothing (what the engine pads a bucket with): its one list entry
+   writes zeros and costs neither a fetch nor the arithmetic.
+   :func:`chunk_walk` says, from the operands' shapes and dtype, what
+   this kernel walks, or that another kernel runs.  Interpret-runnable
+   off-TPU.
 
 Shapes (the model loops layers and passes the pools whole each time):
   q            (B, H, D)        one query token per sequence
   k/v_pool     (L, P, ps, H*D)  every layer's pages, P pages of ps
                                 tokens, a token's heads side by side on
-                                the lanes; the kernel's block is one
-                                ``(ps, H*D)`` page of layer ``layer``,
-                                fetched where it lies: no slice, reshape
+                                the lanes; what a kernel fetches are
+                                ``(ps, H*D)`` pages of layer ``layer``,
+                                from where they lie: no slice, reshape
                                 or copy stands between pool and kernel
   k/v_scale    (L, P, ps, H)    int8 pools only: f32 scale per (token,
                                 head), addressed like the values
@@ -43,13 +53,13 @@ Grouped heads and windows (``_paged_attention_gqa_pallas``): a pool row
 may hold fewer heads than q has (``KVH * D`` lanes, ``H = KVH * G``):
 query head ``j`` reads KV head ``j // G``, and the ``G`` query heads of
 a KV head share one fetch of its page.  ``window=W`` makes a row see
-only its last ``W`` positions.  That kernel's grid is not ``(batch,
-pages)`` but one axis over the pages the batch really walks: row ``b``
-contributes pages ``max(0, len - W) // ps .. (len - 1) // ps`` (all of
-``0 .. (len - 1) // ps`` without a window), the rows' walks laid end to
-end in a scalar-prefetched work list (:func:`_walk`), so a slot outside
-a row's walk costs neither a grid step nor a fetch.  Equal heads without
-a window keep the ``(batch, pages)`` kernel above them.
+only its last ``W`` positions.  That kernel walks the same kind of work
+list a page at a time: row ``b`` contributes pages ``max(0, len - W) //
+ps .. (len - 1) // ps`` (all of ``0 .. (len - 1) // ps`` without a
+window), on the MXU with the ``G`` query heads of a KV head as rows.
+Which of the two runs is read from the shapes (equal heads and no
+window, or not), never from a name or an option.  The int8 pool's kernel
+(``paged_attention_int8``) still has the grid ``(batch, pages)``.
 """
 from __future__ import annotations
 
@@ -65,6 +75,7 @@ from ..framework import device as _device
 from .pallas_ops import _LANES, _NEG_INF, _interpret_default
 
 __all__ = ["paged_attention", "paged_attention_reference", "walk_pages",
+           "chunk_pages", "chunk_walk", "chunks_of",
            "paged_attention_int8", "paged_attention_int8_reference",
            "tune_paged_attention_int8"]
 
@@ -125,6 +136,11 @@ def _head_selectors(h, d):
     return seg, seg.T
 
 
+def _resident(a):
+    """One block spanning the operand, fetched once."""
+    return pl.BlockSpec(a.shape, lambda *_: (0,) * a.ndim)
+
+
 def _dot(a, b):
     # selector matmuls carry f32 scores/weights: keep the MXU at full
     # f32 contract precision (the default would round them to bf16)
@@ -134,7 +150,7 @@ def _dot(a, b):
 
 def _online_softmax_page(i, length, s, v, segt, m_scr, l_scr, acc_scr,
                          *, ps, v_weight=None):
-    """One page of the online softmax, shared by both kernels. ``s`` is
+    """One page of the int8 kernel's online softmax. ``s`` is
     (ps, LANES) scores with head h in column h, ``v`` (ps, H*D) values;
     ``v_weight`` (ps, LANES) scales each (token, head) before the value
     sum (the int8 pool's per-(token, head) v scale). The running max /
@@ -170,41 +186,16 @@ def _init_scratch(m_scr, l_scr, acc_scr):
 
 
 # Mosaic has no matmul whose batch (head) dim sits in the middle of a
-# 3-D operand ("hd,phd->hp"), so the kernels work on 2-D tiles: a page is
-# (ps, H*D), the one query row per sequence is (1, H*D), the per-head
-# contraction is a VPU multiply followed by a 0/1 selector matmul that
-# sums each head's D lanes (see _head_selectors).
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref,
-                  o_ref, m_scr, l_scr, acc_scr, *, ps, max_pages, sm_scale):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        _init_scratch(m_scr, l_scr, acc_scr)
-
-    length = len_ref[b]
-
-    @pl.when(i * ps < length)
-    def _page():
-        q = q_ref[...].astype(jnp.float32)          # (1, H*D)
-        k = k_ref[...].astype(jnp.float32)          # (ps, H*D)
-        v = v_ref[...].astype(jnp.float32)
-        s = _dot(q * k, seg_ref[...]) * sm_scale     # (ps, LANES)
-        _online_softmax_page(i, length, s, v, segt_ref[...], m_scr, l_scr,
-                             acc_scr, ps=ps)
-
-    @pl.when(i == max_pages - 1)
-    def _fin():
-        _finalize(o_ref, segt_ref[...], l_scr, acc_scr)
-
-
+# 3-D operand ("hd,phd->hp"), so the equal-heads kernels work on 2-D
+# tiles: pages are (ps, H*D), the one query row per sequence is (1, H*D),
+# the per-head contraction is a VPU multiply followed by a 0/1 selector
+# matmul that sums each head's D lanes (see _head_selectors).
 def _paged_call(kernel, q, pools, layer, extra, extra_specs, page_tables,
                 lengths, *, name, batch_semantics, interpret):
-    """Shared pallas_call plumbing: q (B, H, D) enters flattened to H*D
-    lanes, the (L, P, ps, H*D) pools enter whole and the page block's
-    index map picks ``(layer, pt[b, i])``; ``extra``/``extra_specs`` are
-    the int8 kernel's scale operands."""
+    """The int8 kernel's pallas_call, grid ``(batch, pages)``: q (B, H,
+    D) enters flattened to H*D lanes, the (L, P, ps, H*D) pools enter
+    whole and the page block's index map picks ``(layer, pt[b, i])``;
+    ``extra``/``extra_specs`` are its scale operands."""
     b, h, d = q.shape
     hd = h * d
     ps = pools[0].shape[2]
@@ -219,14 +210,11 @@ def _paged_call(kernel, q, pools, layer, extra, extra_specs, page_tables,
         lambda bi, i, pt, ln: (layer, pt[bi, i], 0, 0))
     row_spec = pl.BlockSpec((None, 1, hd), lambda bi, i, pt, ln: (bi, 0, 0))
 
-    def resident(a):  # one block spanning the operand, fetched once
-        return pl.BlockSpec(a.shape, lambda bi, i, pt, ln: (0,) * a.ndim)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, page_tables.shape[1]),
         in_specs=[row_spec] + [page_spec] * len(pools) + list(extra_specs)
-        + [resident(seg), resident(segt)],
+        + [_resident(seg), _resident(segt)],
         out_specs=row_spec,
         scratch_shapes=[
             pltpu.VMEM((8, lanes), jnp.float32),
@@ -246,17 +234,6 @@ def _paged_call(kernel, q, pools, layer, extra, extra_specs, page_tables,
     return out.reshape(b, h, d)
 
 
-def _paged_attention_pallas(q, k_pool, v_pool, page_tables, lengths,
-                            *, layer, sm_scale, interpret):
-    kernel = functools.partial(_paged_kernel, ps=k_pool.shape[2],
-                               max_pages=page_tables.shape[1],
-                               sm_scale=sm_scale)
-    return _paged_call(kernel, q, (k_pool, v_pool), layer, (), (),
-                       page_tables, lengths, name="paged_attention",
-                       batch_semantics="parallel", interpret=interpret)
-
-
-
 # ---------------------------------------------------------------------------
 # grouped heads and windows: one grid axis over the pages really walked
 # ---------------------------------------------------------------------------
@@ -269,25 +246,49 @@ def walk_pages(max_pages, page_size, window):
     return min(max_pages, -(-window // page_size) + 1)
 
 
-def _walk(page_tables, lengths, *, ps, window, steps):
+def _walk(page_tables, lengths, *, ps, window, steps, chunk=1):
     """The batch's walk as a work list of ``steps`` grid steps: the rows'
-    page runs laid end to end.  Returns ``(rows, pages, slots, first,
-    last)``: per step the row it belongs to, the physical page to fetch
-    and the logical page index (``-1`` past the end of the list, where
-    row and page repeat the last live step's so that nothing is
-    fetched); per row its first and last logical page."""
+    runs of chunks (``chunk`` consecutive pages of one row; a page by
+    default) laid end to end.  Returns ``(rows, pages, slots, first,
+    last)``: per step the row it belongs to, the ``chunk`` physical pages
+    to fetch (flat, ``chunk`` a step; a slot past the row's last page is
+    the null page 0) and the logical chunk index (``-1`` past the end of
+    the list, where row and pages repeat the last live step's so that
+    nothing is fetched); per row its first and last logical chunk.  A
+    row's entries are a function of its own length and table alone."""
     lengths = jnp.maximum(lengths, 1)
-    last = (lengths - 1) // ps
-    first = (jnp.maximum(lengths - window, 0) // ps if window
+    last = (lengths - 1) // (ps * chunk)
+    first = (jnp.maximum(lengths - window, 0) // (ps * chunk) if window
              else jnp.zeros_like(last))
     n = last - first + 1
     ends = jnp.cumsum(n)
     g = jnp.arange(steps, dtype=jnp.int32)
     live = g < ends[-1]
     gc = jnp.minimum(g, ends[-1] - 1)
-    rows = jnp.sum(gc[:, None] >= ends[None, :], axis=1).astype(jnp.int32)
-    slots = first[rows] + gc - (ends[rows] - n[rows])
-    pages = page_tables[rows, slots]
+    before = gc[:, None] >= ends[None, :]          # rows wholly before g
+    rows = jnp.sum(before, axis=1).astype(jnp.int32)
+    if chunk == 1:
+        # the grouped kernels' form, kept to the letter: PR 28 had to
+        # leave their programs' optimized HLO as it was.  The form below
+        # gives the same list for a chunk of one page and is the better
+        # one on the chip; moving them onto it is ROADMAP S4's next step
+        slots = first[rows] + gc - (ends[rows] - n[rows])
+        pages = page_tables[rows, slots]
+    else:
+        # the same list with no lookup in a per-row vector: the chip's
+        # compiler takes such a gather apart into an operation a row
+        def of_row(x):
+            mine = rows[:, None] == jnp.arange(x.shape[0])[None, :]
+            return jnp.sum(jnp.where(mine, x[None, :], 0), axis=1)
+
+        slots = (of_row(first) + gc
+                 - jnp.sum(jnp.where(before, n[None, :], 0), axis=1))
+        page = (slots[:, None] * chunk
+                + jnp.arange(chunk, dtype=jnp.int32)[None, :])
+        held = page <= of_row((lengths - 1) // ps)[:, None]
+        page = jnp.minimum(page, page_tables.shape[1] - 1)
+        pages = jnp.where(held, page_tables[rows[:, None], page],
+                          0).reshape(-1)
     return (rows, pages.astype(jnp.int32),
             jnp.where(live, slots, -1).astype(jnp.int32),
             first.astype(jnp.int32), last.astype(jnp.int32))
@@ -398,6 +399,191 @@ def _paged_attention_gqa_pallas(q, k_pool, v_pool, page_tables, lengths,
     return out[:, :, :grp].reshape(b, h, d)
 
 
+# ---------------------------------------------------------------------------
+# equal heads: the same work list, several pages of one row a grid step
+# ---------------------------------------------------------------------------
+
+# tokens a grid step of the equal-heads kernel meets as one tile.  A grid
+# step costs about as much whatever it holds, and a 16-token page is a
+# sixth of what that price buys in fetch time: see PERF.md
+_CHUNK_TOKENS = 128
+
+
+def chunk_pages(page_size, max_pages):
+    """Pages of one row that a grid step of the equal-heads kernel
+    brings in: ``_CHUNK_TOKENS`` worth, at least one, no more than a row
+    can hold.  From the pool's page size alone; nothing tunes it."""
+    return max(1, min(_CHUNK_TOKENS // page_size, max_pages))
+
+
+def chunks_of(length, chunk_tokens):
+    """List entries a row of ``length`` tokens has in the equal-heads
+    kernel's work list, ``chunk_tokens`` a chunk (:func:`_walk`: one at
+    least)."""
+    return (length - 1) // chunk_tokens + 1
+
+
+def chunk_walk(q, k_pool, max_pages, *, window=None, steps=None):
+    """What :func:`paged_attention` walks for these operands, of which
+    only shapes and the pool's dtype are read (arrays or
+    ``ShapeDtypeStruct``s): ``(tokens a chunk, grid length)`` of the
+    equal-heads kernel, ``None`` where another kernel runs (a window,
+    fewer heads in the pool than q has, an int8 pool).
+
+    ``steps`` is the caller's bound on the pages the batch holds plus
+    one a row (:func:`paged_attention`); a row of ``p`` pages walks
+    ``ceil(p / C)`` chunks, so the rows together walk at most
+    ``ceil((steps - batch) / C) + batch``, and never more than
+    ``batch * ceil(max_pages / C)``."""
+    batch, h, d = q.shape
+    if window or k_pool.shape[3] != h * d or k_pool.dtype == jnp.int8:
+        return None
+    page_size = k_pool.shape[2]
+    c = chunk_pages(page_size, max_pages)
+    grid = batch * -(-max_pages // c)
+    if steps is not None:
+        grid = min(grid, -(-max(int(steps) - batch, 0) // c) + batch)
+    return c * page_size, grid
+
+
+def _chunk_kernel(rows_ref, pages_ref, slots_ref, len_ref, last_ref,
+                  layer_ref, q_ref, k_hbm, v_hbm, seg_ref, segt_ref, o_ref,
+                  k_buf, v_buf, sem, m_scr, l_scr, acc_scr, *, ps, chunk,
+                  steps, sm_scale):
+    """One chunk of one row: the pages of it the row holds copied side by
+    side into one ``(chunk * ps, H*D)`` tile of a two-deep buffer, the
+    next list entry's copies started before this chunk's arithmetic, so
+    that the selector matmuls and the rescale of the accumulator happen
+    once a chunk.  A step past the list's end starts, waits for and
+    computes nothing; nor does the entry of a row that holds nothing
+    (``len_ref`` 0), which leaves zeros."""
+    g = pl.program_id(0)
+    row = rows_ref[g]
+    slot = slots_ref[g]
+    live = slot >= 0
+    layer = layer_ref[0]
+
+    def copies(step, do):
+        """``do`` (start or wait) every copy of the pages list entry
+        ``step`` holds of its row: the tile's slots past them keep what
+        an earlier chunk left there, finite and masked by position."""
+        held = len_ref[rows_ref[step]] - slots_ref[step] * (chunk * ps)
+
+        def page(j, carry):
+            at = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            for i, (pool, tile) in enumerate(((k_hbm, k_buf),
+                                              (v_hbm, v_buf))):
+                do(pltpu.make_async_copy(
+                    pool.at[layer, pages_ref[step * chunk + j]],
+                    tile.at[step % 2, at], sem.at[i, step % 2]))
+            return carry
+
+        # a loop, not ``chunk`` copies side by side: the program is
+        # traced once a layer and a bucket, and this is most of its text
+        jax.lax.fori_loop(0, jnp.minimum(pl.cdiv(held, ps), chunk), page, 0)
+
+    @pl.when(g == 0)                # every row has a chunk: step 0 is live
+    def _first():
+        # a weight of 0 must meet a finite value in a slot never copied to
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        copies(0, lambda c: c.start())
+
+    nxt = jnp.minimum(g + 1, steps - 1)
+
+    @pl.when((g + 1 < steps) & (slots_ref[nxt] >= 0))
+    def _next():
+        copies(nxt, lambda c: c.start())
+
+    @pl.when(live & (slot == 0))
+    def _init():
+        _init_scratch(m_scr, l_scr, acc_scr)
+
+    @pl.when(live & (len_ref[row] > 0))
+    def _chunk():
+        copies(g, lambda c: c.wait())
+        q = q_ref[...].astype(jnp.float32)                  # (1, H*D)
+        k = k_buf[g % 2].astype(jnp.float32)        # (chunk * ps, H*D)
+        s = _dot(q * k, seg_ref[...]) * sm_scale    # (chunk * ps, LANES)
+        pos = slot * (chunk * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        valid = pos < len_ref[row]
+        s = jnp.where(valid, s, _NEG_INF)
+        m_prev, l_prev = m_scr[...], l_scr[...]             # (8, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new[:1]), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
+        m_scr[:] = m_new
+        # the weights and the accumulator's rescale spread over each
+        # head's lanes by one matmul: alpha rides as eight more rows
+        w = _dot(jnp.concatenate([p, alpha], axis=0), segt_ref[...])
+        pv = jnp.sum(w[:chunk * ps] * v_buf[g % 2].astype(jnp.float32),
+                     axis=0, keepdims=True)                  # (1, H*D)
+        acc_scr[:] = acc_scr[...] * w[chunk * ps:] + pv
+
+    @pl.when(live & (slot == last_ref[row]))
+    def _fin():
+        _finalize(o_ref, segt_ref[...], l_scr, acc_scr)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "chunk", "grid",
+                                             "interpret"))
+def _paged_attention_pallas(q, k_pool, v_pool, page_tables, lengths, layer,
+                            *, sm_scale, chunk, grid, interpret):
+    """Equal heads, fp32 or bf16 pools: ``grid`` steps of ``chunk`` pages
+    (:func:`chunk_walk`).  ``layer`` is an operand and the function
+    jitted, so that a program of many layers traces and lowers the
+    kernel once, not once a layer (most of a decode program's build time
+    otherwise).  The pools stay where they lie (``memory_space=ANY``)
+    and the kernel copies a chunk's pages itself: against ``chunk`` page
+    ``BlockSpec``s under Pallas' own double buffering a live step costs
+    a tenth less, a step past the list's end nothing to speak of, and a
+    slot past a row's last page is not fetched at all (PERF.md §6,
+    PR 28)."""
+    b, h, d = q.shape
+    hd = h * d
+    ps = k_pool.shape[2]
+    rows, pages, slots, _, last = _walk(
+        page_tables, lengths, ps=ps, window=0, steps=grid, chunk=chunk)
+    # no row that holds a token has the null page first in its table
+    held = jnp.where(page_tables[:, 0] == 0, 0, jnp.maximum(lengths, 1))
+    seg, segt = _head_selectors(h, d)
+    lanes = seg.shape[1]
+    row_spec = pl.BlockSpec((None, 1, hd),
+                            lambda g, rows, *_: (rows[g], 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(grid,),
+        in_specs=[row_spec, pool_spec, pool_spec, _resident(seg),
+                  _resident(segt)],
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk * ps, hd), k_pool.dtype),
+            pltpu.VMEM((2, chunk * ps, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((8, lanes), jnp.float32),
+            pltpu.VMEM((8, lanes), jnp.float32),
+            pltpu.VMEM((8, hd), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_chunk_kernel, ps=ps, chunk=chunk,
+                               steps=grid, sm_scale=sm_scale)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_attention",
+        interpret=interpret,
+    )(rows, pages, slots, held.astype(jnp.int32), last,
+      jnp.asarray(layer, jnp.int32).reshape(1), q.reshape(b, 1, hd),
+      k_pool, v_pool, seg, segt)
+    return out.reshape(b, h, d)
+
+
 def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
                     sm_scale=None, window=None, steps=None,
                     use_pallas=None, interpret=None):
@@ -406,10 +592,11 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
     of the whole ``(L, P, ps, H*D)`` pools.
 
     A pool row of fewer heads than q's, or ``window``, selects the
-    grouped kernel (module docstring); ``steps`` is then an upper bound
-    the caller knows on the pages the batch walks (the allocator's: no
-    two rows share a page, so at most the pool's usable pages plus one a
-    row), ``batch * walk_pages`` when not given.
+    grouped kernel (module docstring; :func:`chunk_walk` is the one
+    place that tells).  ``steps`` is an upper bound the caller knows on
+    the pages the batch walks (the allocator's: no two rows share a
+    page, so at most the pool's usable pages plus one a row), ``batch *
+    walk_pages`` when not given; either kernel's grid is made from it.
 
     Off-TPU the default is the reference (interpret-mode Pallas is a
     correctness vehicle, not a fast path); pass ``use_pallas=True`` to
@@ -425,18 +612,19 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
         interpret = _interpret_default()
     if use_pallas is None:
         use_pallas = _device.pallas_dispatch()  # reference off the TPU
-    grouped = bool(window) or k_pool.shape[3] != q.shape[1] * q.shape[2]
     if use_pallas:
         record_dispatch("paged_attention", "pallas")
-        if grouped:
+        walk = chunk_walk(q, k_pool, page_tables.shape[1], window=window,
+                          steps=steps)
+        if walk is None:
             return _paged_attention_gqa_pallas(
                 q, k_pool, v_pool, page_tables, lengths, layer=layer,
                 sm_scale=sm_scale, window=window or 0, steps=steps,
                 interpret=interpret)
-        return _paged_attention_pallas(q, k_pool, v_pool, page_tables,
-                                       lengths, layer=layer,
-                                       sm_scale=sm_scale,
-                                       interpret=interpret)
+        return _paged_attention_pallas(
+            q, k_pool, v_pool, page_tables, lengths, layer,
+            sm_scale=sm_scale, chunk=walk[0] // k_pool.shape[2],
+            grid=walk[1], interpret=interpret)
     record_dispatch("paged_attention", "fallback")
     return paged_attention_reference(q, k_pool, v_pool, page_tables,
                                      lengths, layer=layer,

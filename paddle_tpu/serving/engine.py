@@ -21,6 +21,10 @@ build records every executable's temporary, argument and aliased bytes
 (``engine.stats["program_bytes"]``, ``/healthz``,
 ``pt_serve_program_bytes{program=,kind=}``).  A program that copied,
 sliced or re-laid a pool would show a pool-sized temporary there.
+Beside it, ``engine.stats["paged_walk"]`` (also ``/healthz``) says what
+the equal-heads paged-attention kernel of each decode program walks:
+the tokens a grid step meets and the grid's length; the scheduler sums
+both a decode step (``paged_chunks_walked``, ``paged_grid_steps``).
 
 Zero-downtime weight swap: with a ``CheckpointManager`` attached,
 :meth:`ServingEngine.maybe_reload` hot-swaps to generation N+1 between
@@ -44,7 +48,8 @@ import numpy as np
 
 from ..observability.trace import span
 from .kv_cache import PagePool, NULL_PAGE, kv_page_budget
-from .model import ModelSpec, init_params, prefill_step, decode_step
+from .model import (ModelSpec, decode_step, decode_walk, init_params,
+                    prefill_step)
 
 PRECISIONS = ("fp32", "bf16", "int8")
 
@@ -279,10 +284,15 @@ class ServingEngine:
             self._warmed = False
             self._prefill_exe: Dict[int, Any] = {}
             self._decode_exe: Dict[int, Any] = {}
+            self._decode_walk: Dict[int, Dict[str, int]] = {}
             self.compiled_programs = 0
             # program name -> {"temp", "argument", "alias"} bytes, from
             # each executable's memory_analysis() at build
-            self.stats: Dict[str, Any] = {"program_bytes": {}}
+            # and, where decode runs the equal-heads paged-attention
+            # kernel, program name -> {"chunk_tokens", "grid_steps"}:
+            # the tokens a grid step meets and the kernel's grid length
+            self.stats: Dict[str, Any] = {"program_bytes": {},
+                                          "paged_walk": {}}
             self._build_programs()
             self._warmup()
         self._arm_sentinel()
@@ -424,6 +434,11 @@ class ServingEngine:
                 jax.ShapeDtypeStruct(self.table_shape, i32))
 
         for b in cfg.decode_buckets:
+            walk = decode_walk(spec, b, k_struct, self.max_pages_per_seq)
+            if walk is not None:
+                self._decode_walk[b] = self.stats["paged_walk"][
+                    f"serve_decode_b{b}{sfx}"] = {
+                        "chunk_tokens": walk[0], "grid_steps": walk[1]}
             self._decode_exe[b] = _compile(
                 dec_jit, f"serve_decode_b{b}{sfx}",
                 p_struct, *kv_args,
@@ -588,6 +603,12 @@ class ServingEngine:
             f"{n} active sequences exceed largest decode bucket "
             f"{self.config.decode_buckets[-1]}")
 
+    def paged_walk_for(self, n: int) -> Optional[Dict[str, int]]:
+        """What the paged-attention kernel of the program that decodes
+        ``n`` rows walks (``stats["paged_walk"]``); ``None`` where decode
+        does not run the equal-heads kernel."""
+        return self._decode_walk.get(self.decode_bucket_for(n))
+
     def prefill(self, tokens: Sequence[int],
                 page_table: np.ndarray) -> int:
         """Run one prompt; returns the first generated token.
@@ -741,6 +762,7 @@ class ServingEngine:
             "unexpected_compiles": self.unexpected_compiles,
             "compiled_programs": self.compiled_programs,
             "program_bytes": self.stats["program_bytes"],
+            "paged_walk": self.stats["paged_walk"],
             "precision": self.config.precision,
             "decode_buckets": list(self.config.decode_buckets),
             "prefill_buckets": list(self.config.prefill_buckets),

@@ -46,7 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.paged_attention import paged_attention, paged_attention_int8
+from ..ops.paged_attention import (chunk_walk, paged_attention,
+                                   paged_attention_int8)
 from ..ops.quant_kernels import quantize_kv, w8a16_matmul
 from . import experts as _experts
 
@@ -587,6 +588,27 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                            vw_pool), next_token, logits, counts)
 
 
+def decode_walk(spec: ModelSpec, batch: int, k_pool, max_pages: int):
+    """``(tokens a chunk, grid length)`` of the equal-heads paged-attention
+    kernel as :func:`decode_step` calls it on its full-attention layers
+    for a bucket of ``batch`` rows over ``k_pool`` (its shape and dtype:
+    an array or a ``ShapeDtypeStruct``); ``None`` where those layers run
+    another kernel (``ops.paged_attention.chunk_walk`` tells) or there
+    are none."""
+    if not spec.global_layers:
+        return None
+    q = jax.ShapeDtypeStruct((batch, spec.heads, spec.head_dim),
+                             k_pool.dtype)
+    return chunk_walk(q, k_pool, max_pages,
+                      steps=_pages_walked(k_pool.shape[1], batch))
+
+
+def _pages_walked(pages: int, batch: int) -> int:
+    """The allocator's bound on the pages a decode batch walks: no two
+    rows share a page, so the pool's usable pages and one a row."""
+    return pages - 1 + batch
+
+
 def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 tokens, positions, page_tables, *, page_size: int,
                 k_scale=None, v_scale=None, kw_pool=None, vw_pool=None,
@@ -651,7 +673,8 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
             with scope(_attn_scope(spec, i)):
                 o = paged_attention(
                     q, kw_pool, vw_pool, table, lengths, layer=li,
-                    window=window, steps=kw_pool.shape[1] - 1 + b)
+                    window=window,
+                    steps=_pages_walked(kw_pool.shape[1], b))
         else:
             li = spec.global_layers.index(i)
             with scope(f"layer{i}/kv_write"):
@@ -672,7 +695,8 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 else:
                     o = paged_attention(q, k_pool, v_pool, table, lengths,
                                         layer=li,
-                                        steps=k_pool.shape[1] - 1 + b)
+                                        steps=_pages_walked(
+                                            k_pool.shape[1], b))
         with scope(f"layer{i}/attn_out"):
             h = h + _matmul(params, f"h{i}.attn.wo",
                             o.reshape(b, spec.heads * spec.head_dim), tap)

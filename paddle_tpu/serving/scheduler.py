@@ -63,7 +63,9 @@ same boundaries add to ``stats`` as float sums (``wait_s``, ``evict_s``,
 ``book_s``) beside the per-request ``lock_wait_s`` (entry of
 :meth:`~ContinuousScheduler.submit` to lock held), ``queue_wait_s``
 (enqueued to its prefill entered, count ``admitted``), ``ttft_s`` and
-``tpot_s`` (count ``tpot_requests``).
+``tpot_s`` (count ``tpot_requests``).  What the decode kernel walked:
+``paged_chunks_walked`` and ``paged_grid_steps``
+(``pt_serve_paged_chunks_total{state="walked"|"grid"}``).
 """
 from __future__ import annotations
 
@@ -80,6 +82,7 @@ import numpy as np
 from ..observability.metrics import get_registry
 from ..observability.telemetry import get_telemetry
 from ..observability.trace import get_tracer, span
+from ..ops.paged_attention import chunks_of
 
 logger = logging.getLogger("paddle_tpu.serving")
 
@@ -276,6 +279,10 @@ class ContinuousScheduler:
             "kv_window_pages_returned": 0,
             "moe_tokens_routed": 0, "moe_expert_max_tokens": 0,
             "moe_decode_experts_touched": 0,
+            # the equal-heads paged-attention kernel's walk, summed over
+            # decode steps: chunks the rows' contexts fill, and the grid
+            # steps of the bucket's program (engine.stats["paged_walk"])
+            "paged_chunks_walked": 0, "paged_grid_steps": 0,
             # seconds of the scheduler thread by phase (module docstring)
             "wait_s": 0.0, "evict_s": 0.0, "admit_host_s": 0.0,
             "prefill_s": 0.0, "decode_prep_s": 0.0, "decode_s": 0.0,
@@ -286,6 +293,7 @@ class ContinuousScheduler:
         }
         self._meter_registry = None     # the registry self._meters are of
         self._meters: Dict[str, Any] = {}
+        self._walk_booked: Dict[str, int] = {}
 
     # -- submission ----------------------------------------------------------
 
@@ -576,6 +584,14 @@ class ContinuousScheduler:
                                 np.int32)
             positions = np.asarray([a.pos for a in self._active], np.int32)
             tables = np.stack([a.pages.table for a in self._active])
+            walk = self.engine.paged_walk_for(n)
+            if walk is not None:
+                # sums only: the registry follows them when a request
+                # retires (_book_walk_locked), off the step's path
+                ct = walk["chunk_tokens"]
+                stats["paged_chunks_walked"] += sum(
+                    chunks_of(a.pos + 1, ct) for a in self._active)
+                stats["paged_grid_steps"] += walk["grid_steps"]
             # watchdog arms on the device call
             self._step_started = t0 = time.monotonic()
         stats["decode_prep_s"] += sp.seconds
@@ -673,6 +689,18 @@ class ContinuousScheduler:
             self._book("pt_serve_tpot_seconds", kind="histogram",
                        value=tpot)
         self._book("pt_serve_completed_total", kind="counter")
+        self._book_walk_locked()
+
+    def _book_walk_locked(self) -> None:
+        """``pt_serve_paged_chunks_total`` up to ``stats``: called when a
+        request retires and by :meth:`snapshot`, not every decode step."""
+        for state, key in (("walked", "paged_chunks_walked"),
+                           ("grid", "paged_grid_steps")):
+            more = self.stats[key] - self._walk_booked.get(key, 0)
+            if more:
+                self._walk_booked[key] = self.stats[key]
+                self._book("pt_serve_paged_chunks_total", kind="counter",
+                           value=more, state=state)
 
     # -- loop management -----------------------------------------------------
 
@@ -856,6 +884,7 @@ class ContinuousScheduler:
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
+            self._book_walk_locked()
             occ = (self.stats["occupancy_sum"] /
                    max(1, self.stats["occupancy_steps"]))
             return {
@@ -940,6 +969,10 @@ _METRIC_HELP = {
         "Token-expert pairs routed, summed over layers",
     "pt_serve_moe_expert_max_tokens_total":
         "Tokens of the busiest expert, summed over calls and layers",
+    "pt_serve_paged_chunks_total":
+        "Equal-heads paged attention, summed over decode steps: chunks "
+        "the rows' contexts fill (walked) and grid steps of the "
+        "bucket's program (grid)",
     "pt_serve_queue_depth": "Requests waiting for admission",
     "pt_serve_active_sequences": "Sequences resident in the decode batch",
     "pt_serve_batch_occupancy":

@@ -524,3 +524,32 @@ def test_lm_metrics_have_entries_for_the_new_cell_only(bench):
     # the prefill's KV write is scoped `kv_write` in the new block too:
     # the accepted reader reads it unedited
     assert entries["prefill_kv_ms_per_run"]["workloads"][-1] == cell
+
+
+# -- the equal-heads kernel's walk (PR 28) -----------------------------------
+
+WALK_METRICS = {"paged_walk_live_pct.steady":
+                ("tpot_p50_ms", "gpt345m-serve-complete-steady"),
+                "paged_walk_live_pct.backlog":
+                ("serve_tokens_per_s", "gpt345m-serve-longprompt-backlog")}
+
+
+@pytest.mark.parametrize("name", WALK_METRICS)
+def test_walk_share_is_chunks_walked_over_grid_steps(bench, name):
+    """94 decode steps of the 32-row bucket (160 grid steps each) whose
+    rows' contexts filled 13,160 chunks; and a program that keeps no such
+    counter (the parent, or a grouped model): nothing to read."""
+    run = {"trace": None, "counters": {"paged_chunks_walked": 13160,
+                                       "paged_grid_steps": 94 * 160}}
+    assert read(bench, name, run) == pytest.approx(87.5)
+    assert read(bench, name, {"trace": None, "counters": {
+        "occupancy_steps": 400}}) is None
+    assert read(bench, name, {"trace": None, "counters": {
+        "paged_chunks_walked": 0, "paged_grid_steps": 0}}) is None
+    assert read(bench, name, {"trace": None}) is None
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    entry = next(m for m in spec["per_layer"] if m["name"] == name)
+    moves, cell = WALK_METRICS[name]
+    assert (entry["moves"], entry["workloads"]) == (moves, [cell])
+    assert entry["source"] == "program_counter"
+    assert entry["layer"] == "kernels (ops/paged_attention.py)"

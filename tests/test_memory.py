@@ -43,6 +43,9 @@ def _isolated(monkeypatch):
                 "PT_MEMORY_TOPK"):
         monkeypatch.delenv(var, raising=False)
     obs.reset()
+    # the census counts every live buffer of the process: what an earlier
+    # file of this worker left in reference cycles is not this file's
+    gc.collect()
     yield
     obs.reset()
 

@@ -48,7 +48,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(one_chip, monkeypatch, precision, kind):
+def _compile(one_chip, monkeypatch, precision, kind, *,
+             fp32_pages=FP32_PAGES, bucket=DECODE_BUCKET):
     """Lower and compile one serve program the way the engine builds it
     (same functions, same donation), for the described chip."""
     # the code under test asks "am I on the chip" to choose its kernels:
@@ -68,7 +69,7 @@ def _compile(one_chip, monkeypatch, precision, kind):
     params = jax.tree_util.tree_map(
         lambda a: sds(a, cast if cast and a.dtype == jnp.float32 else None),
         params)
-    pages = kv_page_budget(FP32_PAGES, precision, SPEC.head_dim)
+    pages = kv_page_budget(fp32_pages, precision, SPEC.head_dim)
     shape, sshape = pool_shapes(SPEC.layers, pages, PAGE_SIZE, SPEC.heads,
                                 SPEC.head_dim)
     pool = jax.ShapeDtypeStruct(shape, KV_DTYPES[precision],
@@ -91,8 +92,8 @@ def _compile(one_chip, monkeypatch, precision, kind):
 
     if kind == "decode":
         lowered = program(decode_step).lower(
-            params, *state, i32((DECODE_BUCKET,)), i32((DECODE_BUCKET,)),
-            i32((DECODE_BUCKET, maxp)))
+            params, *state, i32((bucket,)), i32((bucket,)),
+            i32((bucket, maxp)))
     else:
         lowered = program(prefill_step).lower(
             params, *state, i32((PREFILL_BUCKET,)), i32(()), i32((maxp,)))
@@ -153,6 +154,49 @@ def test_chip_program_keeps_the_pools_in_place(one_chip, monkeypatch,
     layer_elems = int(np.prod(state[0].shape[1:]))
     moved = _moved_pool_sized(text, layer_elems)
     assert not moved, moved
+
+
+@pytest.mark.parametrize("bucket", [8, 32])
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_chip_decode_program_walks_chunks_of_the_pages_held(
+        one_chip, monkeypatch, precision, bucket):
+    """gpt-345m-serve's decode programs (16 heads of 64, pages of 16,
+    1,024 fp32 pages, tables of 64): the equal-heads kernel's grid is
+    the chunk list's bound, not bucket x 64 page slots; the list is made
+    once for all layers; the temporaries stay where they were."""
+    from paddle_tpu.ops.paged_attention import chunk_walk
+    exe, state = _compile(one_chip, monkeypatch, precision, "decode",
+                          fp32_pages=1024, bucket=bucket)
+    pages = state[0].shape[1]
+    q = jax.ShapeDtypeStruct((bucket, SPEC.heads, SPEC.head_dim),
+                             state[0].dtype)
+    tokens, grid = chunk_walk(q, state[0], SPEC.max_seq_len // PAGE_SIZE,
+                              steps=pages - 1 + bucket)
+    assert tokens == 128
+    if precision == "fp32":         # 2,048 and 512 before the work list
+        assert grid == {8: 64, 32: 160}[bucket]
+    calls = [line for line in exe.as_text().splitlines()
+             if "tpu_custom_call" in line and "paged_attention" in line]
+    assert len(calls) == SPEC.layers
+    walks = set()
+    for line in calls:
+        operands = line.split("custom-call(", 1)[1].split(")", 1)[0]
+        operands = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")
+        walks.add(tuple(operands[:5]))
+        shapes = line.split("operand_layout_constraints={", 1)[1]
+        # rows, eight pages a grid step, chunk slots; lengths, last chunks
+        assert shapes.startswith(
+            f"s32[{grid}]{{0}}, s32[{8 * grid}]{{0}}, s32[{grid}]{{0}}, "
+            f"s32[{bucket}]{{0}}, s32[{bucket}]{{0}}, "), shapes[:120]
+        # and the two pools whole, for the kernel's own copies
+        pool = "%s[%s]" % ({"fp32": "f32", "bf16": "bf16"}[precision],
+                           ",".join(str(d) for d in state[0].shape))
+        assert shapes.count(pool) == 2
+    # one work list a decode step: every layer's call takes the same five
+    assert len(walks) == 1, walks
+    # 9.3 MiB (fp32) / 7.0 MiB (bf16) with the (batch, pages) kernel at
+    # this depth; the list itself is a few KiB
+    assert exe.memory_analysis().temp_size_in_bytes < 12 << 20
 
 
 # -- Mellum2-12B-A2.5B-Instruct's programs at its published widths ----------

@@ -12,6 +12,7 @@ The load-bearing guarantees under test:
    joining and leaving — produces BIT-IDENTICAL tokens to the same
    sequence decoded alone (row-independent decode math).
 """
+import gc
 import json
 import os
 import threading
@@ -310,6 +311,17 @@ def test_prefill_writes_whole_pages_and_only_the_sequences_own(engine):
 _BYTES_SPEC = ModelSpec(vocab_size=64, hidden=32, layers=2, heads=2,
                         max_seq_len=32)
 _BYTES_ENGINES = {}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_bytes_engines():
+    """Their pools must not outlive the module: another file's census of
+    live buffers (tests/test_memory.py) may run in this process next."""
+    yield
+    for eng in _BYTES_ENGINES.values():
+        eng.close()
+    _BYTES_ENGINES.clear()
+    gc.collect()            # an engine and its programs are a cycle
 
 
 def _bytes_engine(precision):
@@ -730,6 +742,59 @@ def test_counters_count_with_telemetry_off_and_registry_stays_empty(engine):
     assert s["admit_host_s"] > 0 and s["book_s"] > 0 and s["evict_s"] > 0
 
 
+def test_engine_publishes_the_equal_heads_kernels_walk(engine):
+    """Tables of 16 pages of 4 tokens: a chunk is the whole row (64
+    tokens), so the 4-row bucket's grid is 4 chunks."""
+    from paddle_tpu.ops.paged_attention import chunk_walk
+    q = jnp.zeros((4, engine.spec.heads, engine.spec.head_dim))
+    assert chunk_walk(q, engine.pool.k_pool, 16,
+                      steps=CFG.kv_pages - 1 + 4) == (64, 4)
+    # the one place that tells which kernel runs: not this one under a
+    # window, with fewer heads in the pool than q has, or on int8 pages
+    assert chunk_walk(q, engine.pool.k_pool, 16, window=8) is None
+    assert chunk_walk(jnp.zeros((4, 2 * engine.spec.heads,
+                                 engine.spec.head_dim)),
+                      engine.pool.k_pool, 16) is None
+    assert chunk_walk(q, engine.pool.k_pool.astype(jnp.int8), 16) is None
+    want = {"serve_decode_b4": {"chunk_tokens": 64, "grid_steps": 4}}
+    assert engine.stats["paged_walk"] == want
+    assert engine.healthz()["paged_walk"] == want
+    assert engine.paged_walk_for(3) == want["serve_decode_b4"]
+
+
+@pytest.mark.parametrize("chunk_tokens,per_request", [
+    # a request of 3 prompt tokens decodes at lengths 4 and 5
+    (64, 1 + 1), (4, 1 + 2), (2, 2 + 3), (1, 4 + 5)])
+def test_walk_counters_sum_the_rows_chunks_and_the_grid(
+        engine, monkeypatch, chunk_tokens, per_request):
+    from paddle_tpu.serving.scheduler import ContinuousScheduler
+    monkeypatch.setattr(engine, "_decode_walk", {
+        4: {"chunk_tokens": chunk_tokens, "grid_steps": 9}})
+    sched = ContinuousScheduler(engine)
+    streams = [sched.submit([1, 2, 3], max_new_tokens=3) for _ in range(3)]
+    sched.drain()
+    assert all(len(st.result(timeout=10.0)) == 3 for st in streams)
+    s = sched.stats
+    assert s["paged_chunks_walked"] == 3 * per_request
+    assert s["paged_grid_steps"] == 9 * s["occupancy_steps"] > 0
+    assert sched.snapshot()["paged_grid_steps"] == s["paged_grid_steps"]
+
+
+def test_walk_counters_stay_zero_where_no_program_has_the_walk(
+        engine, monkeypatch):
+    """A grouped or windowed model, an int8 pool: ``paged_walk`` is empty
+    and the scheduler counts nothing."""
+    from paddle_tpu.serving.scheduler import ContinuousScheduler
+    monkeypatch.setattr(engine, "_decode_walk", {})
+    assert engine.paged_walk_for(1) is None
+    sched = ContinuousScheduler(engine)
+    sched.submit([1, 2, 3], max_new_tokens=3)
+    sched.drain()
+    assert sched.stats["occupancy_steps"] > 0
+    assert sched.stats["paged_chunks_walked"] == 0
+    assert sched.stats["paged_grid_steps"] == 0
+
+
 def test_request_histograms_and_one_token_booking_a_step(engine):
     from paddle_tpu import observability as obs
     from paddle_tpu.serving.scheduler import ContinuousScheduler
@@ -751,6 +816,9 @@ def test_request_histograms_and_one_token_booking_a_step(engine):
             assert series["count"] == 3, name
         (tokens,) = snap["pt_serve_tokens_total"]["series"].values()
         assert tokens == sched.stats["tokens_generated"] == 15
+        assert snap["pt_serve_paged_chunks_total"]["series"] == {
+            "state=walked": sched.stats["paged_chunks_walked"],
+            "state=grid": sched.stats["paged_grid_steps"]}
         # the handles are looked up once, then kept
         assert set(sched._meters) >= {"pt_serve_tokens_total",
                                       "pt_serve_ttft_seconds"}
@@ -787,7 +855,12 @@ def test_watchdog_flight_dump_names_serve_spans(engine, monkeypatch,
         while not sched.hang_detected and time.monotonic() < deadline:
             time.sleep(0.02)
         assert sched.hang_detected
-        doc = json.load(open(tr.flight_path))
+        # the flag goes up before the dump is written: wait for the dump
+        while True:
+            doc = json.load(open(tr.flight_path))
+            if doc["reason"] != "armed" or time.monotonic() > deadline:
+                break
+            time.sleep(0.02)
         assert doc["reason"].startswith("serve-hang")
         names = {s["name"] for s in doc["spans"]}
         assert {"serve.decode.launch", "serve.prefill.launch",

@@ -342,6 +342,9 @@ def test_scheduler_counters_and_health(built):
     assert kv["window"]["pages_returned"] >= s1["kv_window_pages_returned"]
     assert engine.expert_counts() is not None
     engine.pool.check_consistency(expect_all_free=True)
+    # grouped and windowed layers walk page by page: no chunk walk
+    assert engine.stats["paged_walk"] == {} == engine.healthz()["paged_walk"]
+    assert s1["paged_chunks_walked"] == s1["paged_grid_steps"] == 0
 
 
 # -- the spec ---------------------------------------------------------------
