@@ -304,13 +304,6 @@ def bench_gpt(result, batch, recompute=True):
     n_params = sum(int(np.prod(p.shape)) for p in params.values())
     result["gpt345m_n_params"] = n_params
 
-    # the graph-level fusion pass wraps the LOSS function (not the whole
-    # step): grad-side consumption of forward intermediates would break
-    # cluster closure on the whole-step jaxpr, while wrapping loss_of
-    # lets the fused kernels' custom VJPs own the backward
-    from paddle_tpu.ops import fusion_pass as _fusion
-    _fusion.reset_stats()
-
     def train_step(params, buffers, opt_state, ids, labels):
         def loss_of(p):
             out, new_buffers = functional_call(
@@ -320,7 +313,7 @@ def bench_gpt(result, batch, recompute=True):
             return loss._data.astype(jnp.float32), new_buffers
 
         (loss, new_buffers), grads = jax.value_and_grad(
-            _fusion.wrap(loss_of), has_aux=True)(params)
+            loss_of, has_aux=True)(params)
         new_params, new_opt = opt.apply_gradients_tree(params, grads,
                                                        opt_state)
         return loss, new_params, new_buffers, new_opt
@@ -336,9 +329,6 @@ def bench_gpt(result, batch, recompute=True):
     traced = step.trace(params, buffers, opt_state, ids, labels)
     compiled = traced.lower().compile()
     result["gpt345m_compile_sec"] = round(time.perf_counter() - t0, 2)
-    # fusion block: which patterns got rewritten at trace time, and which
-    # ran the XLA mirror (only off the TPU, reason not_tpu)
-    result["fusion"] = _fusion.summary()
     # graph audit: the AOT trace above already holds the step jaxpr, so
     # the auditor costs zero extra traces here (compile-time only)
     from paddle_tpu.tools.audit import runtime as _audit
@@ -348,9 +338,7 @@ def bench_gpt(result, batch, recompute=True):
             (params, buffers, opt_state)))
         _audit.audit_program(AuditProgram(
             name="bench_gpt_step", jaxpr=traced.jaxpr, kind="capture",
-            donated=range(n_donated),
-            fusion_expected=_fusion.fusion_enabled(),
-            fusion_rewrites=result["fusion"].get("rewrites")))
+            donated=range(n_donated)))
     flops = _flops_per_step(compiled)
     result["gpt345m_flops_per_step"] = flops
     result["gpt345m_memory"] = _memory_report(compiled)
@@ -656,8 +644,7 @@ def bench_kernels(result):
                    a, w, b, interpret=interp), x),
                fwdbwd_ms(lambda a: fk.layer_norm_reference(a, w, b), x))
 
-    # -- fused-block rows: residual+LN (the fusion pass's residual_ln
-    # cluster — in-kernel add before the stats) at the same shapes ------
+    # -- residual+LN (in-kernel add before the stats) at the same shapes
     for tag, rows, d in ln_shapes:
         if SMOKE:
             rows, d = min(rows, 512), min(d, 256)
@@ -698,22 +685,6 @@ def bench_kernels(result):
            fwdbwd_ms(lambda a: mha(a, k, v, causal=True,
                                    interpret=interp), q),
            fwdbwd_ms(lambda a: mha_reference(a, k, v, causal=True), q))
-
-    # -- attention-block cluster (qk+scale+softmax+pv, the fusion
-    # pass's attention_block rewrite target) at GPT and BERT shapes ----
-    attn_shapes = [("gpt345m", 16, GPT_SEQ, True),
-                   ("bert", 12, BERT_SEQ, False)]
-    for tag, heads, seq, causal in attn_shapes:
-        if SMOKE:
-            heads, seq = min(heads, 4), min(seq, 64)
-        q2, k2, v2 = (jnp.asarray(rng.randn(1, heads, seq, 64).astype(
-            np.float32)).astype(jnp.bfloat16) for _ in range(3))
-        tune_mha(q2, k2, v2, causal=causal, interpret=interp)
-        record(f"attention_block_{tag}",
-               fwdbwd_ms(lambda a: fk.fused_attention_block(
-                   a, k2, v2, causal=causal, interpret=interp), q2),
-               fwdbwd_ms(lambda a: fk.attention_block_reference(
-                   a, k2, v2, causal=causal), q2))
 
     result["kernels"] = kernels
     result["autotune"] = at.summary()

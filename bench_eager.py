@@ -508,78 +508,6 @@ def _memory_contract(pt):
     }
 
 
-def _fusion_bench(pt):
-    """Fused-vs-unfused captured-step CPU timing plus the pass's own
-    stats. The same transformer block (LN→matmul, matmul+bias+gelu,
-    residual+LN) is captured twice — once with ``PT_FUSION_PASS=0``,
-    once rewritten. On CPU every rewritten cluster dispatches to the
-    inline XLA mirror (reason ``not_tpu``), so the fused
-    column measures the pass itself, never Pallas interpret overhead;
-    the acceptance bar is fused no slower than unfused."""
-    import numpy as np
-    import jax
-    import paddle_tpu.nn as nn
-    import paddle_tpu.nn.functional as F
-    from paddle_tpu.ops import fusion_pass as fp
-
-    class Block(nn.Layer):
-        def __init__(self):
-            super().__init__()
-            self.ln1 = nn.LayerNorm(64)
-            self.fc1 = nn.Linear(64, 128)
-            self.fc2 = nn.Linear(128, 64)
-            self.ln2 = nn.LayerNorm(64)
-
-        def forward(self, x):
-            h = self.fc2(F.gelu(self.fc1(self.ln1(x))))
-            return self.ln2(x, residual=h)
-
-    x = pt.to_tensor(
-        np.random.RandomState(0).randn(32, 64).astype(np.float32))
-
-    def timed(enabled):
-        os.environ["PT_FUSION_PASS"] = "1" if enabled else "0"
-        fp.reset_stats()
-        np.random.seed(0)
-        pt.seed(0)
-        model = Block()
-
-        @pt.jit.capture_step
-        def step(inp):
-            return model(inp)
-
-        out = step(x)  # compile (fusion pass runs inside this trace)
-        stats = fp.summary()
-        best = float("inf")
-        for _ in range(50):
-            t0 = time.perf_counter()
-            jax.block_until_ready(step(x)._data)
-            best = min(best, time.perf_counter() - t0)
-        return best, stats, np.asarray(out._data)
-
-    prev = os.environ.get("PT_FUSION_PASS")
-    try:
-        t_unfused, _, out_u = timed(False)
-        t_fused, stats, out_f = timed(True)
-    finally:
-        if prev is None:
-            os.environ.pop("PT_FUSION_PASS", None)
-        else:
-            os.environ["PT_FUSION_PASS"] = prev
-    import numpy as np2
-    diff = float(np2.max(np2.abs(out_u - out_f)))
-    return {
-        "captured_step_unfused_us": round(t_unfused * 1e6, 1),
-        "captured_step_fused_us": round(t_fused * 1e6, 1),
-        "fused_vs_unfused_ratio": round(t_fused / t_unfused, 3)
-        if t_unfused else None,
-        "rewrites": stats["rewrites"],
-        "fallbacks": stats["fallbacks"],
-        "max_abs_diff": diff,
-        "ok": bool(stats["rewrites"]) and diff <= 1e-5,
-    }
-
-
 def main():
     import jax
     import jax.numpy as jnp
@@ -628,8 +556,8 @@ def main():
     from paddle_tpu.observability.goodput import get_goodput
     gp = get_goodput().enable()
     # graph audit on for the whole bench: every capture_step compile in
-    # this file (the chain, the contract runs, the fusion A/B) gets its
-    # pre-fusion jaxpr audited at capture time — replays cost nothing
+    # this file (the chain, the contract runs) gets its
+    # jaxpr audited at capture time — replays cost nothing
     from paddle_tpu.tools.audit import runtime as audit_rt
     audit_rt.enable()
 
@@ -684,7 +612,6 @@ def main():
     res["value"] = res["tape_on"]
     res["precision"] = "fp32"
     res["capture"] = _capture_contract(pt)
-    res["fusion"] = _fusion_bench(pt)
     res["numerics_contract"] = _numerics_contract(pt)
     res["amp_contract"] = _amp_contract(pt)
     res["sdc_contract"] = _sdc_contract(pt)

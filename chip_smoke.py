@@ -151,10 +151,8 @@ def train_phase(size, rehearsal):
     import paddle_tpu as pt
     from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
     from paddle_tpu.observability.trace import get_tracer, peak_flops
-    from paddle_tpu.ops import fusion_pass
 
     tracer = get_tracer()
-    fusion_pass.reset_stats()
     pt.seed(0)
     cfg = GPTConfig(tensor_parallel=False, **size["model"])
     model = GPTForCausalLM(cfg)
@@ -190,8 +188,6 @@ def train_phase(size, rehearsal):
         (1, None, steps - 1), f"capture did not hold: {stats}"
     assert all(np.isfinite(losses)), f"non-finite loss: {losses}"
     assert losses[-1] < losses[0], f"loss did not fall: {losses}"
-    fusion = fusion_pass.summary()
-    assert fusion["traces"] >= 1, f"fusion pass never ran: {fusion}"
     spans = tracer.spans()
     assert any(s.name == "data_wait" for s in spans), \
         "tracer.phase() recorded nothing"
@@ -199,8 +195,6 @@ def train_phase(size, rehearsal):
         "no captured-step compute span"
     routes = pallas_routes()
     if not rehearsal:
-        assert not fusion["fallbacks"], \
-            f"fusion clusters fell back off Pallas: {fusion['fallbacks']}"
         assert_routes(routes, ("flash_mha", "fused_layer_norm",
                                "fused_softmax_xent"))
 
@@ -208,7 +202,7 @@ def train_phase(size, rehearsal):
              "losses": [round(x, 4) for x in losses],
              "capture": {k: stats[k] for k in ("compiles", "hits",
                                                "fallback")},
-             "fusion": fusion, "pallas_routes": routes,
+             "pallas_routes": routes,
              "spans": len(spans)}
     if not rehearsal:
         warm = float(np.median(times[1:]))

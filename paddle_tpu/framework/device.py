@@ -29,9 +29,9 @@ __all__ = [
 
 
 def on_tpu() -> bool:
-    """The one "am I on the chip" predicate. Kernel dispatch, Pallas
-    interpret mode and the fusion pass's backend choice all ask here, so
-    they cannot disagree. A backend that fails to initialize raises."""
+    """The one "am I on the chip" predicate. Kernel dispatch and Pallas
+    interpret mode both ask here, so they cannot disagree. A backend that
+    fails to initialize raises."""
     return jax.devices()[0].platform == "tpu"
 
 

@@ -243,18 +243,15 @@ class Model:
         # One fused program per step is the perf contract; the split
         # grad/apply pair exists ONLY for the step-phase tracer, which
         # needs a host boundary between backward and optimizer to time.
-        # jax.jit is lazy, so the untaken pair never compiles.  Each
-        # step is run through the graph-level fusion pass at trace time
-        # (transparent when PT_FUSION_PASS=0 or nothing matches).
-        from ..ops import fusion_pass as _fusion
-        self._train_step_jit = jax.jit(_fusion.wrap(train_step)) \
+        # jax.jit is lazy, so the untaken pair never compiles.
+        self._train_step_jit = jax.jit(train_step) \
             if opt is not None else None
-        self._grad_step_jit = jax.jit(_fusion.wrap(grad_step)) \
+        self._grad_step_jit = jax.jit(grad_step) \
             if opt is not None else None
         self._apply_step_jit = jax.jit(
             apply_step_mon if mon is not None else apply_step) \
             if opt is not None else None
-        self._eval_step_jit = jax.jit(_fusion.wrap(eval_step))
+        self._eval_step_jit = jax.jit(eval_step)
 
     def _param_arrays(self):
         return {k: p._data for k, p in self.network.named_parameters()}

@@ -146,9 +146,8 @@ class _LiveState:
 
 class _Entry:
     __slots__ = ("jitted", "struct", "traced_idx", "sg_flags", "statics",
-                 "n_leaves", "sig", "name", "ran", "fusion",
-                 "memory", "monitored", "monitor_names", "sdc",
-                 "sdc_names", "pure", "audit")
+                 "n_leaves", "sig", "name", "ran", "memory", "monitored",
+                 "monitor_names", "sdc", "sdc_names", "pure", "audit")
 
 
 class CapturedStep:
@@ -160,8 +159,7 @@ class CapturedStep:
         self._state = None
         self._fallback_reason = None
         self.stats = {"hits": 0, "misses": 0, "compiles": 0,
-                      "fallback": None, "fusion_rewrites": 0,
-                      "fusion_patterns": {}}
+                      "fallback": None}
         try:
             functools.update_wrapper(self, fn)
         except AttributeError:
@@ -482,20 +480,11 @@ class CapturedStep:
         pure.__name__ = f"captured_step({fname})"
         pure.__qualname__ = pure.__name__
 
-        # graph-level fusion: rewrite matched clusters (residual+LN,
-        # LN+matmul, attention block, matmul+bias+gelu) to block-fused
-        # kernels at trace time, before XLA ever sees the step. The wrap
-        # is a transparent passthrough when PT_FUSION_PASS=0 or nothing
-        # matches.
-        from ..ops import fusion_pass as _fusion
-
         entry = _Entry()
-        # the UN-wrapped pure fn is kept for the graph auditor: its
-        # pre-fusion jaxpr is exactly what the fusion pass matched, so
-        # the missed-fusion cross-check compares like with like
+        # kept for the graph auditor, which reads its jaxpr under PT_AUDIT=1
         entry.pure = pure
         entry.audit = None
-        entry.jitted = jax.jit(_fusion.wrap(pure), donate_argnums=(0, 1, 2))
+        entry.jitted = jax.jit(pure, donate_argnums=(0, 1, 2))
         entry.struct = struct
         entry.traced_idx = tuple(traced_idx)
         entry.sg_flags = tuple(sg_flags)
@@ -504,7 +493,6 @@ class CapturedStep:
         entry.sig = sig
         entry.name = pure.__name__
         entry.ran = False
-        entry.fusion = None
         entry.memory = None
         entry.monitored = mon is not None
         entry.monitor_names = mon_box  # resolved after the first trace
@@ -548,8 +536,6 @@ class CapturedStep:
                 entry.memory = _mm.harvest_program(
                     entry.name, call, st.params, st.buffers,
                     st.opt_states, st.rng_ctr, lrs, traced)
-            from ..ops import fusion_pass as _fusion
-            fusion_before = _fusion.summary()["rewrites"]
             with warnings.catch_warnings(), \
                     _span(f"compile:{entry.name}", cat="host"):
                 # backends without donation (cpu) warn once at compile;
@@ -563,18 +549,6 @@ class CapturedStep:
                     self._book_oom(entry, e)
                     raise
             entry.ran = True  # only after the trace actually succeeded
-            # the trace just happened inside that call: the fusion-pass
-            # rewrite delta is this entry's pattern census (part of the
-            # capture contract surfaced by bench_eager)
-            fusion_after = _fusion.summary()["rewrites"]
-            entry.fusion = {
-                k: fusion_after.get(k, 0) - fusion_before.get(k, 0)
-                for k in fusion_after
-                if fusion_after.get(k, 0) > fusion_before.get(k, 0)}
-            for k, n in entry.fusion.items():
-                self.stats["fusion_patterns"][k] = \
-                    self.stats["fusion_patterns"].get(k, 0) + n
-                self.stats["fusion_rewrites"] += n
             self.stats["compiles"] += 1
             tel = _tel()
             if not tel._watcher.installed:
@@ -584,7 +558,7 @@ class CapturedStep:
                 tel.record_compile(entry.name, f"sig={entry.sig}")
             if entry.audit is None:
                 # graph audit (tools/audit): static findings over the
-                # pre-fusion step jaxpr, harvested once per signature
+                # step jaxpr, harvested once per signature
                 # in the same compile-time window as the memory pass
                 # above — the replay hot path never pays it
                 from ..tools.audit import runtime as _audit_rt

@@ -169,8 +169,7 @@ def fused_add_layer_norm(x, residual, normalized_shape, weight=None,
 
     Thin named entry over ``layer_norm(..., residual=...)`` — the form the
     TPU016 lint rule rewrites manually-composed ``add``/``layer_norm``
-    pairs into, and the form the graph-level fusion pass recognizes
-    without needing the adjacent-eqn dataflow check to succeed.
+    pairs into.
     """
     return layer_norm(x, normalized_shape, weight=weight, bias=bias,
                       epsilon=epsilon, residual=residual, name=name)

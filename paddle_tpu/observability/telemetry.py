@@ -329,8 +329,6 @@ class TrainingTelemetry:
         self._store_generation = None
         self._capture_hits = 0
         self._capture_misses: dict = {}
-        self._fusion_rewrites: dict = {}
-        self._fusion_fallbacks: dict = {}
         self._compile_listeners: list = []
         # refresh device-memory gauges every N steps (stats read is a
         # host-side allocator query, cheap but not free)
@@ -527,14 +525,6 @@ class TrainingTelemetry:
         self._m_capture_misses = r.counter(
             "pt_capture_cache_misses_total",
             "captured-step cache misses", ("reason",))
-        self._m_fusion_rewrites = r.counter(
-            "pt_fusion_rewrites_total",
-            "fusion-pass clusters rewritten to block-fused kernels",
-            ("pattern",))
-        self._m_fusion_fallbacks = r.counter(
-            "pt_fusion_fallbacks_total",
-            "fusion-pass clusters dispatched to the XLA fallback",
-            ("pattern", "reason"))
 
     # -- step timing --------------------------------------------------------
 
@@ -774,27 +764,6 @@ class TrainingTelemetry:
         if self.enabled:
             self._m_capture_misses.inc(reason=reason)
 
-    # -- graph-level fusion pass (ops.fusion_pass) --------------------------
-
-    def fusion_rewrite(self, pattern):
-        """One jaxpr cluster rewritten to a block-fused kernel call."""
-        pattern = str(pattern)
-        self._fusion_rewrites[pattern] = \
-            self._fusion_rewrites.get(pattern, 0) + 1
-        if self.enabled:
-            self._m_fusion_rewrites.inc(pattern=pattern)
-
-    def fusion_fallback(self, pattern, reason):
-        """One rewritten cluster dispatched to the XLA fallback;
-        ``reason`` is ``not_tpu``, or ``gspmd_mesh`` on a TPU where
-        Mosaic cannot lower (``fusion_pass._backend``)."""
-        pattern, reason = str(pattern), str(reason)
-        key = f"{pattern}:{reason}"
-        self._fusion_fallbacks[key] = \
-            self._fusion_fallbacks.get(key, 0) + 1
-        if self.enabled:
-            self._m_fusion_fallbacks.inc(pattern=pattern, reason=reason)
-
     # -- compiles (called from the log filter) ------------------------------
 
     def record_compile(self, name, signature=""):
@@ -956,8 +925,6 @@ class TrainingTelemetry:
             "recompile_storms": sorted(self.sentinel.tripped()),
             "capture": {"hits": self._capture_hits,
                         "misses": dict(self._capture_misses)},
-            "fusion": {"rewrites": dict(self._fusion_rewrites),
-                       "fallbacks": dict(self._fusion_fallbacks)},
             "peak_device_memory_bytes": mem.get("peak_bytes_in_use"),
             "device_memory_bytes": mem.get("bytes_in_use"),
             "last_checkpoint_step": last_ckpt,

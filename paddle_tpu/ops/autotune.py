@@ -45,8 +45,6 @@ KERNEL_SCHEMA = {
     "flash_mha": 2,
     "fused_layer_norm": 2,
     "fused_softmax_xent": 2,
-    "fused_ln_matmul": 1,
-    "fused_matmul_bias_gelu": 1,
     "w8a16_matmul": 1,
     "paged_attention_int8": 1,
 }

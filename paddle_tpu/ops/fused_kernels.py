@@ -16,24 +16,11 @@ layernorm's stats as separate reductions.  Two kernels close that gap:
    tile loop, and the backward emits ``softmax(x) - onehot`` in one
    pass from the saved logsumexp.
 
-PR 12 adds the *block-fused* kernels targeted by the jaxpr fusion pass
-(:mod:`.fusion_pass`) — whole transformer sub-blocks as one launch:
-
- - :func:`fused_ln_matmul` — (residual +) LayerNorm + matmul epilogue
-   (+ bias): the LN output never round-trips to HBM before the MXU.
- - :func:`fused_matmul_bias_gelu` — the MLP up-projection
-   ``gelu(x @ W + b)`` with the activation applied on the accumulator.
- - :func:`fused_attention_block` — qkv-matmul + scale + softmax +
-   pv-matmul, delegating to the flash kernel in :mod:`.pallas_ops`.
-
-All run in Pallas interpret mode off-TPU (tier-1 correctness), follow
+Both run in Pallas interpret mode off-TPU (tier-1 correctness), follow
 the MXU contract from :mod:`.pallas_ops` (native-dtype operands, f32
 accumulation), and read their launch configs from the search-based
-tuner in :mod:`.autotune`.  The PR 8 kernels search static candidate
-tables; the block kernels' tuners (``tune_ln_matmul`` /
-``tune_matmul_bias_gelu``) feed :func:`autotune.generate_candidates`
-instead — the cost model *emits* the tile space from the cluster shape
-and prunes it before anything is timed.
+tuner in :mod:`.autotune` (static candidate tables, pruned by the cost
+model before anything is timed).
 """
 from __future__ import annotations
 
@@ -48,12 +35,8 @@ from .pallas_ops import _LANES, _NEG_INF, _ceil_to, _interpret_default
 
 __all__ = [
     "fused_layer_norm", "fused_softmax_xent",
-    "fused_ln_matmul", "fused_matmul_bias_gelu", "fused_attention_block",
     "layer_norm_reference", "softmax_xent_reference",
-    "ln_matmul_reference", "matmul_bias_gelu_reference",
-    "attention_block_reference",
     "tune_layer_norm", "tune_softmax_xent",
-    "tune_ln_matmul", "tune_matmul_bias_gelu",
     "LN_CANDIDATES", "XENT_CANDIDATES", "record_dispatch",
 ]
 
@@ -719,476 +702,5 @@ def tune_softmax_xent(logits, labels, *, ignore_index=-100,
         "fused_softmax_xent",
         _xent_tune_key(rows, V, logits.dtype, label_smoothing, interpret),
         run, todo, cost=_xent_cost_fn(rows, V, logits.dtype.itemsize))
-    _at.set_enabled(True)
-    return best, timings
-
-
-# ---------------------------------------------------------------------------
-# block-fused: (residual +) layernorm + matmul epilogue
-# ---------------------------------------------------------------------------
-def _lnmm_fwd_kernel(*refs, d, eps, block_rows, d_pad, has_res, has_lw,
-                     has_lb, has_mb):
-    it = iter(refs)
-    x_ref = next(it)
-    res_ref = next(it) if has_res else None
-    lw_ref = next(it) if has_lw else None
-    lb_ref = next(it) if has_lb else None
-    w_ref = next(it)
-    mb_ref = next(it) if has_mb else None
-    y_ref = next(it)
-
-    xv = x_ref[:].astype(jnp.float32)
-    if has_res:
-        xv = xv + res_ref[:].astype(jnp.float32)
-    if d_pad != d:
-        colmask = jax.lax.broadcasted_iota(
-            jnp.int32, (block_rows, d_pad), 1) < d
-        xm = jnp.where(colmask, xv, 0.0)
-    else:
-        colmask, xm = None, xv
-    s1 = jnp.sum(xm, axis=-1, keepdims=True)
-    s2 = jnp.sum(xm * xm, axis=-1, keepdims=True)
-    mean = s1 / d
-    var = jnp.maximum(s2 / d - mean * mean, 0.0)
-    rstd = jax.lax.rsqrt(var + eps)
-    h = (xv - mean) * rstd
-    if has_lw:
-        h = h * lw_ref[:].astype(jnp.float32)
-    if has_lb:
-        h = h + lb_ref[:].astype(jnp.float32)
-    if colmask is not None:
-        # padded lanes must be exact zeros before they reach the MXU
-        h = jnp.where(colmask, h, 0.0)
-    acc = jax.lax.dot_general(
-        h.astype(x_ref.dtype), w_ref[:],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if has_mb:
-        acc = acc + mb_ref[:].astype(jnp.float32)
-    y_ref[:] = acc.astype(y_ref.dtype)
-
-
-def _lnmm_pallas_fwd(x, res, lw, lb, w, mb, *, d, eps, block_rows,
-                     block_n, parallel, interpret):
-    rows_p, d_pad = x.shape
-    n_pad = w.shape[1]
-    ni, nj = rows_p // block_rows, n_pad // block_n
-    has_res, has_lw = res is not None, lw is not None
-    has_lb, has_mb = lb is not None, mb is not None
-    row_spec = pl.BlockSpec((block_rows, d_pad), lambda i, j: (i, 0))
-    vec_spec = pl.BlockSpec((1, d_pad), lambda i, j: (0, 0))
-    in_specs, args = [row_spec], [x]
-    if has_res:
-        in_specs.append(row_spec)
-        args.append(res)
-    if has_lw:
-        in_specs.append(vec_spec)
-        args.append(lw)
-    if has_lb:
-        in_specs.append(vec_spec)
-        args.append(lb)
-    in_specs.append(pl.BlockSpec((d_pad, block_n), lambda i, j: (0, j)))
-    args.append(w)
-    if has_mb:
-        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, j)))
-        args.append(mb)
-    return pl.pallas_call(
-        functools.partial(_lnmm_fwd_kernel, d=d, eps=eps,
-                          block_rows=block_rows, d_pad=d_pad,
-                          has_res=has_res, has_lw=has_lw, has_lb=has_lb,
-                          has_mb=has_mb),
-        grid=(ni, nj),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_rows, block_n), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows_p, n_pad), x.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel" if parallel else "arbitrary", "arbitrary")),
-        interpret=interpret,
-        name="ln_matmul_fwd",
-    )(*args)
-
-
-_LNMM_STATICS = (6, 7, 8, 9, 10, 11)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=_LNMM_STATICS)
-def _lnmm(x, w, lw, lb, mb, res, d, eps, block_rows, block_n, parallel,
-          interpret):
-    return _lnmm_pallas_fwd(x, res, lw, lb, w, mb, d=d, eps=eps,
-                            block_rows=block_rows, block_n=block_n,
-                            parallel=parallel, interpret=interpret)
-
-
-def _lnmm_fwd(x, w, lw, lb, mb, res, d, eps, block_rows, block_n,
-              parallel, interpret):
-    y = _lnmm_pallas_fwd(x, res, lw, lb, w, mb, d=d, eps=eps,
-                         block_rows=block_rows, block_n=block_n,
-                         parallel=parallel, interpret=interpret)
-    return y, (x, w, lw, lb, mb, res)
-
-
-def _lnmm_bwd(d, eps, block_rows, block_n, parallel, interpret,
-              residuals, g):
-    x, w, lw, lb, mb, res, = residuals
-    # Recompute the LN output + stats in one kernel (flash-style: no
-    # (rows, d) activation saved); padded lanes of h are exact zeros.
-    h, mean, rstd = _ln_pallas_fwd(x, res, lw, lb, d=d, eps=eps,
-                                   block_rows=block_rows,
-                                   parallel=parallel, interpret=interpret)
-    # matmul grads are plain MXU work XLA already schedules optimally
-    dw = jax.lax.dot_general(
-        h, g, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(w.dtype)
-    dmb = (jnp.sum(g.astype(jnp.float32), axis=0,
-                   keepdims=True).astype(mb.dtype)
-           if mb is not None else None)
-    dh = jax.lax.dot_general(
-        g, w, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(x.dtype)
-    dx, dlw, dlb = _ln_pallas_bwd(x, res, lw, lb, dh, mean, rstd, d=d,
-                                  block_rows=block_rows,
-                                  interpret=interpret)
-    return (dx, dw,
-            None if lw is None else dlw.astype(lw.dtype),
-            None if lb is None else dlb.astype(lb.dtype),
-            dmb,
-            None if res is None else dx.astype(res.dtype))
-
-
-_lnmm.defvjp(_lnmm_fwd, _lnmm_bwd)
-
-
-def _lnmm_tune_key(rows, d, n, dtype, interpret):
-    return (rows, d, n, str(dtype), bool(interpret))
-
-
-def fused_ln_matmul(x, weight, ln_weight=None, ln_bias=None, bias=None,
-                    residual=None, *, epsilon=1e-5, block_rows=None,
-                    block_n=None, parallel=True, interpret=None):
-    """(residual +) LayerNorm + matmul (+ bias) as one kernel launch.
-
-    ``x`` is (rows, d), ``weight`` is (d, n); the LN output feeds the
-    MXU straight from vmem instead of round-tripping through HBM.
-    Returns (rows, n) in ``x.dtype`` (f32 accumulation throughout).
-    The backward recomputes the LN activation from ``x`` (flash-style)
-    and reuses the fused-LN backward kernel for dx/dln.
-
-    ``block_rows``/``block_n`` default to the generator-searched choice
-    when :func:`tune_ln_matmul` has cached one, else (256, 256).
-    """
-    if x.ndim != 2 or weight.ndim != 2:
-        raise ValueError(
-            f"fused_ln_matmul expects 2-D x/weight, got "
-            f"{x.shape} @ {weight.shape}")
-    if interpret is None:
-        interpret = _interpret_default()
-    rows, d = x.shape
-    n = weight.shape[1]
-    if block_rows is None and block_n is None:
-        from . import autotune as _at
-        hit = _at.cache_get("fused_ln_matmul", _lnmm_tune_key(
-            rows, d, n, x.dtype, interpret)) if _at.enabled() else None
-        if hit is not None:
-            block_rows, block_n = int(hit[0]), int(hit[1])
-            parallel = bool(hit[2])
-    block_rows = 256 if block_rows is None else int(block_rows)
-    block_n = 256 if block_n is None else int(block_n)
-    block_rows = min(block_rows, _ceil_to(rows, 8))
-    block_n = min(block_n, _ceil_to(n, _LANES))
-    d_pad = _ceil_to(d, _LANES)
-    rows_p = _ceil_to(rows, block_rows)
-    n_pad = _ceil_to(n, block_n)
-
-    xp = jnp.pad(x, ((0, rows_p - rows), (0, d_pad - d)))
-    wp = jnp.pad(weight, ((0, d_pad - d), (0, n_pad - n)))
-    lwp = lbp = mbp = rp = None
-    if ln_weight is not None:
-        lwp = jnp.pad(jnp.reshape(ln_weight, (1, d)),
-                      ((0, 0), (0, d_pad - d)))
-    if ln_bias is not None:
-        lbp = jnp.pad(jnp.reshape(ln_bias, (1, d)),
-                      ((0, 0), (0, d_pad - d)))
-    if bias is not None:
-        mbp = jnp.pad(jnp.reshape(bias, (1, n)), ((0, 0), (0, n_pad - n)))
-    if residual is not None:
-        rp = jnp.pad(residual, ((0, rows_p - rows), (0, d_pad - d)))
-    y = _lnmm(xp, wp, lwp, lbp, mbp, rp, d, float(epsilon), block_rows,
-              block_n, bool(parallel), interpret)
-    return y[:rows, :n]
-
-
-def ln_matmul_reference(x, weight, ln_weight=None, ln_bias=None,
-                        bias=None, residual=None, epsilon=1e-5):
-    """Pure-jnp reference: LN (+res) then matmul (+bias), f32 accum."""
-    h = layer_norm_reference(x, ln_weight, ln_bias, residual, epsilon)
-    y = jnp.dot(h, weight, preferred_element_type=jnp.float32)
-    if bias is not None:
-        y = y + bias.astype(jnp.float32)
-    return y.astype(x.dtype)
-
-
-# ---------------------------------------------------------------------------
-# block-fused: matmul + bias + gelu (MLP up-projection)
-# ---------------------------------------------------------------------------
-def _gelu_f32(z, approximate):
-    if approximate:
-        inner = 0.7978845608028654 * (z + 0.044715 * z * z * z)
-        return 0.5 * z * (1.0 + jnp.tanh(inner))
-    return 0.5 * z * (1.0 + jax.lax.erf(z * 0.7071067811865476))
-
-
-def _mbg_fwd_kernel(*refs, approximate, has_b):
-    it = iter(refs)
-    x_ref, w_ref = next(it), next(it)
-    b_ref = next(it) if has_b else None
-    y_ref, z_ref = next(it), next(it)
-    acc = jax.lax.dot_general(
-        x_ref[:], w_ref[:], dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if has_b:
-        acc = acc + b_ref[:].astype(jnp.float32)
-    y_ref[:] = _gelu_f32(acc, approximate).astype(y_ref.dtype)
-    z_ref[:] = acc.astype(z_ref.dtype)
-
-
-def _mbg_pallas_fwd(x, w, b, *, block_rows, block_n, approximate,
-                    parallel, interpret):
-    rows_p, k_pad = x.shape
-    n_pad = w.shape[1]
-    ni, nj = rows_p // block_rows, n_pad // block_n
-    has_b = b is not None
-    in_specs = [
-        pl.BlockSpec((block_rows, k_pad), lambda i, j: (i, 0)),
-        pl.BlockSpec((k_pad, block_n), lambda i, j: (0, j)),
-    ]
-    args = [x, w]
-    if has_b:
-        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, j)))
-        args.append(b)
-    out_spec = pl.BlockSpec((block_rows, block_n), lambda i, j: (i, j))
-    return pl.pallas_call(
-        functools.partial(_mbg_fwd_kernel, approximate=approximate,
-                          has_b=has_b),
-        grid=(ni, nj),
-        in_specs=in_specs,
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((rows_p, n_pad), x.dtype),
-                   jax.ShapeDtypeStruct((rows_p, n_pad), x.dtype)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel" if parallel else "arbitrary", "parallel")),
-        interpret=interpret,
-        name="matmul_bias_gelu_fwd",
-    )(*args)
-
-
-_MBG_STATICS = (3, 4, 5, 6, 7)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=_MBG_STATICS)
-def _mbg(x, w, b, approximate, block_rows, block_n, parallel, interpret):
-    y, _ = _mbg_pallas_fwd(x, w, b, block_rows=block_rows,
-                           block_n=block_n, approximate=approximate,
-                           parallel=parallel, interpret=interpret)
-    return y
-
-
-def _mbg_fwd(x, w, b, approximate, block_rows, block_n, parallel,
-             interpret):
-    y, z = _mbg_pallas_fwd(x, w, b, block_rows=block_rows,
-                           block_n=block_n, approximate=approximate,
-                           parallel=parallel, interpret=interpret)
-    return y, (x, w, b, z)
-
-
-def _mbg_bwd(approximate, block_rows, block_n, parallel, interpret,
-             residuals, g):
-    x, w, b, z = residuals
-    # dz from the saved pre-activation (the exact gelu' the primal used)
-    _, pull = jax.vjp(lambda t: _gelu_f32(t, approximate),
-                      z.astype(jnp.float32))
-    dz = pull(g.astype(jnp.float32))[0].astype(x.dtype)
-    dx = jax.lax.dot_general(
-        dz, w, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(x.dtype)
-    dw = jax.lax.dot_general(
-        x, dz, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(w.dtype)
-    db = (jnp.sum(dz.astype(jnp.float32), axis=0,
-                  keepdims=True).astype(b.dtype)
-          if b is not None else None)
-    return dx, dw, db
-
-
-_mbg.defvjp(_mbg_fwd, _mbg_bwd)
-
-
-def _mbg_tune_key(rows, k, n, dtype, approximate, interpret):
-    return (rows, k, n, str(dtype), bool(approximate), bool(interpret))
-
-
-def fused_matmul_bias_gelu(x, weight, bias=None, *, approximate=True,
-                           block_rows=None, block_n=None, parallel=True,
-                           interpret=None):
-    """``gelu(x @ weight + bias)`` with the activation applied on the
-    MXU accumulator — the transformer MLP up-projection as one launch.
-
-    ``x`` is (rows, k), ``weight`` is (k, n); returns (rows, n) in
-    ``x.dtype``.  The pre-activation is saved for the backward (one
-    extra (rows, n) write beats re-running the matmul).
-    """
-    if x.ndim != 2 or weight.ndim != 2:
-        raise ValueError(
-            f"fused_matmul_bias_gelu expects 2-D x/weight, got "
-            f"{x.shape} @ {weight.shape}")
-    if interpret is None:
-        interpret = _interpret_default()
-    rows, k = x.shape
-    n = weight.shape[1]
-    if block_rows is None and block_n is None:
-        from . import autotune as _at
-        hit = _at.cache_get("fused_matmul_bias_gelu", _mbg_tune_key(
-            rows, k, n, x.dtype, approximate,
-            interpret)) if _at.enabled() else None
-        if hit is not None:
-            block_rows, block_n = int(hit[0]), int(hit[1])
-            parallel = bool(hit[2])
-    block_rows = 256 if block_rows is None else int(block_rows)
-    block_n = 256 if block_n is None else int(block_n)
-    block_rows = min(block_rows, _ceil_to(rows, 8))
-    block_n = min(block_n, _ceil_to(n, _LANES))
-    k_pad = _ceil_to(k, _LANES)
-    rows_p = _ceil_to(rows, block_rows)
-    n_pad = _ceil_to(n, block_n)
-
-    xp = jnp.pad(x, ((0, rows_p - rows), (0, k_pad - k)))
-    wp = jnp.pad(weight, ((0, k_pad - k), (0, n_pad - n)))
-    bp = (jnp.pad(jnp.reshape(bias, (1, n)), ((0, 0), (0, n_pad - n)))
-          if bias is not None else None)
-    y = _mbg(xp, wp, bp, bool(approximate), block_rows, block_n,
-             bool(parallel), interpret)
-    return y[:rows, :n]
-
-
-def matmul_bias_gelu_reference(x, weight, bias=None, approximate=True):
-    """Pure-jnp reference: matmul (+bias) then gelu, f32 accum."""
-    z = jnp.dot(x, weight, preferred_element_type=jnp.float32)
-    if bias is not None:
-        z = z + bias.astype(jnp.float32)
-    return _gelu_f32(z, approximate).astype(x.dtype)
-
-
-# ---------------------------------------------------------------------------
-# block-fused: attention (qkv-matmul + scale + softmax + pv-matmul)
-# ---------------------------------------------------------------------------
-def fused_attention_block(q, k, v, *, causal=False, sm_scale=None,
-                          block_q=None, block_k=None, interpret=None):
-    """The attention score/softmax/weighted-sum cluster as one flash
-    kernel launch ((B, H, S, D) layout; see :func:`pallas_ops.mha`).
-    Exists so the fusion pass and bench address the attention-block
-    pattern through the same module as the other block kernels."""
-    from .pallas_ops import mha
-    return mha(q, k, v, causal=causal, sm_scale=sm_scale,
-               block_q=block_q, block_k=block_k, interpret=interpret)
-
-
-def attention_block_reference(q, k, v, *, causal=False, sm_scale=None):
-    """Pure-jnp reference ((B, H, S, D) layout, f32 softmax)."""
-    from .pallas_ops import mha_reference
-    return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-
-
-# ---------------------------------------------------------------------------
-# generator-backed tuners for the block kernels
-# ---------------------------------------------------------------------------
-def _block_axes(rows, n):
-    """Candidate axes for a (rows × n)-tiled block kernel: the generator
-    emits (block_rows, block_n, parallel) tuples from the cluster shape
-    instead of reading a static table."""
-    return [("tile", min(rows, 1024), 8), ("tile", min(n, 1024), _LANES),
-            ("choice", (1, 0))]
-
-
-def _block_cost_fn(rows, d, n, itemsize):
-    """Cost estimate for the (rows, d) @ (d, n) block kernels.  A
-    per-launch overhead term breaks the roofline tie between tile
-    sizes (same total work) so the generator's ordering prefers fewer,
-    larger launches within the vmem budget."""
-    d_pad = _ceil_to(d, _LANES)
-    flops = 2.0 * rows * d * n
-    bytes_ = float(rows * d + d * n + rows * n) * itemsize
-
-    def cost(cfg):
-        br = min(int(cfg[0]), _ceil_to(rows, 8))
-        bn = min(int(cfg[1]), _ceil_to(n, _LANES))
-        n_launch = (_ceil_to(rows, br) // br) * (_ceil_to(n, bn) // bn)
-        vmem = (br * d_pad * (itemsize + _F32)   # x tile + f32 copy
-                + d_pad * bn * itemsize          # weight tile
-                + br * bn * (itemsize + _F32)    # out tile + accumulator
-                + 3 * d_pad * _F32 + 2 * br * _F32)
-        return {"flops": flops,
-                "bytes": bytes_ + n_launch * 16384.0,
-                "vmem_bytes": vmem,
-                "mxu_underfill": br < 8 or bn < _LANES}
-    return cost
-
-
-def tune_ln_matmul(x, weight, ln_weight=None, ln_bias=None, bias=None,
-                   residual=None, *, epsilon=1e-5, interpret=None):
-    """Generate + search launch configs for :func:`fused_ln_matmul` at
-    this (rows, d, n, dtype) and cache the winner.  Unlike the PR 8
-    tuners there is no candidate table: :func:`autotune.
-    generate_candidates` emits the (block_rows, block_n, parallel)
-    space from the cluster shape and prunes it through the cost model
-    before timing.  Returns (best, timings)."""
-    from . import autotune as _at
-
-    if interpret is None:
-        interpret = _interpret_default()
-    rows, d = x.shape
-    n = weight.shape[1]
-    cost = _block_cost_fn(rows, d, n, x.dtype.itemsize)
-    cands = _at.generate_candidates(_block_axes(rows, n), cost,
-                                    max_candidates=8)
-    state = {"x": x}
-
-    def run(cfg):
-        out = fused_ln_matmul(state["x"], weight, ln_weight, ln_bias,
-                              bias, residual, epsilon=epsilon,
-                              block_rows=cfg[0], block_n=cfg[1],
-                              parallel=bool(cfg[2]), interpret=interpret)
-        float(jnp.sum(out.astype(jnp.float32)))
-
-    best, timings = _at.search(
-        "fused_ln_matmul",
-        _lnmm_tune_key(rows, d, n, x.dtype, interpret),
-        run, cands, cost=cost)
-    _at.set_enabled(True)
-    return best, timings
-
-
-def tune_matmul_bias_gelu(x, weight, bias=None, *, approximate=True,
-                          interpret=None):
-    """Generate + search launch configs for
-    :func:`fused_matmul_bias_gelu` (see :func:`tune_ln_matmul`)."""
-    from . import autotune as _at
-
-    if interpret is None:
-        interpret = _interpret_default()
-    rows, k = x.shape
-    n = weight.shape[1]
-    cost = _block_cost_fn(rows, k, n, x.dtype.itemsize)
-    cands = _at.generate_candidates(_block_axes(rows, n), cost,
-                                    max_candidates=8)
-
-    def run(cfg):
-        out = fused_matmul_bias_gelu(
-            x, weight, bias, approximate=approximate, block_rows=cfg[0],
-            block_n=cfg[1], parallel=bool(cfg[2]), interpret=interpret)
-        float(jnp.sum(out.astype(jnp.float32)))
-
-    best, timings = _at.search(
-        "fused_matmul_bias_gelu",
-        _mbg_tune_key(rows, k, n, x.dtype, approximate, interpret),
-        run, cands, cost=cost)
     _at.set_enabled(True)
     return best, timings

@@ -19,8 +19,11 @@ Three layers, matching the house kernel conventions
  - **w8a16_matmul** — activations in 16/32-bit, weights int8, f32 MXU
    accumulation, per-out-channel scale applied in the epilogue (AFTER
    the dot — the AUD006 dequant-placement contract: the int8→wide
-   convert feeds exactly one ``dot_general``).  Pallas kernel on TPU, a
-   bit-defined XLA mirror elsewhere so CPU tier-1 proves the numerics.
+   convert feeds exactly one ``dot_general``).  Pallas kernel on TPU, an
+   XLA mirror elsewhere so CPU tier-1 proves the numerics: the kernel in
+   interpret mode is bit-identical to the mirror on its padded tile, and
+   within the re-association of a K-term f32 sum of the mirror at the
+   unpadded width (XLA's CPU dot orders a narrow product differently).
  - **autotune** — :func:`tune_w8a16_matmul` routes (block_m, block_n)
    through :mod:`.autotune` ``search`` with a ``KERNEL_SCHEMA`` entry,
    same as the other fused kernels.

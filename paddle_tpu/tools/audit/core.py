@@ -5,7 +5,7 @@ tpu-lint (``tools/lint``) reads Python source; this package reads the
 captured training steps (``jit/capture``) and AOT-served program
 families (``serving/engine``).  The hazards it hunts (implicit
 reshards, AMP precision leaks, undonated state buffers, request-path
-host transfers, missed fusion clusters) are invisible at the AST layer
+host transfers, misplaced dequantizes) are invisible at the AST layer
 because the compiler, not the source, decides them.
 
 The machinery deliberately mirrors tpu-lint's conventions so one
@@ -87,22 +87,17 @@ class AuditProgram:
     ``donated`` is the set of flat invar indices the caller donates
     (``jit(..., donate_argnums=...)`` resolved to leaf positions);
     ``arg_names`` optionally names those flat invars (pytree key paths)
-    for readable donation findings; ``fusion_expected`` +
-    ``fusion_rewrites`` let the missed-fusion rule compare what the
-    fusion pass *should* have claimed against what it actually
-    rewrote; ``memory`` is the PR-14 ``memory_analysis`` block
-    (per-kind bytes) harvested beside the program, used to weight
-    donation findings against the real argument footprint.
+    for readable donation findings; ``memory`` is the PR-14
+    ``memory_analysis`` block (per-kind bytes) harvested beside the
+    program, used to weight donation findings against the real argument
+    footprint.
     """
 
-    __slots__ = ("name", "jaxpr", "kind", "donated", "arg_names",
-                 "fusion_expected", "fusion_rewrites", "memory")
+    __slots__ = ("name", "jaxpr", "kind", "donated", "arg_names", "memory")
 
     def __init__(self, name: str, jaxpr: Any, kind: str = "generic",
                  donated: Sequence[int] = (),
                  arg_names: Optional[Sequence[str]] = None,
-                 fusion_expected: bool = False,
-                 fusion_rewrites: Optional[Dict[str, int]] = None,
                  memory: Optional[Dict[str, Any]] = None):
         if kind not in ("capture", "serve", "generic"):
             raise ValueError(f"unknown program kind: {kind!r}")
@@ -111,8 +106,6 @@ class AuditProgram:
         self.kind = kind
         self.donated = frozenset(int(i) for i in donated)
         self.arg_names = list(arg_names) if arg_names is not None else None
-        self.fusion_expected = bool(fusion_expected)
-        self.fusion_rewrites = dict(fusion_rewrites or {})
         self.memory = dict(memory) if memory else None
 
     def arg_name(self, i: int) -> str:
@@ -158,8 +151,7 @@ def walk_jaxprs(closed, max_depth: int = 8):
 
 
 class GraphView:
-    """Producer/consumer index over one jaxpr level (the audit-side
-    sibling of ``fusion_pass._Graph``, without the match helpers)."""
+    """Producer/consumer index over one jaxpr level."""
 
     OUT = -1
 

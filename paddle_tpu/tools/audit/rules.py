@@ -25,9 +25,6 @@ AUD003  undonated-buffer     a large argument with a same-shaped
 AUD004  host-transfer        callbacks/infeed/outfeed in the program —
                              the IR-level complement of TPU019; an
                              error on the serving request path
-AUD005  missed-fusion        clusters the fusion pass should have
-                             claimed but did not, with the blocking
-                             escape named (``fusion_pass.match_report``)
 AUD006  dequant-placement    an int8→float dequantize whose result
                              reaches more than one ``dot_general`` —
                              XLA must materialize the full-precision
@@ -552,51 +549,4 @@ class DequantPlacement(Rule):
                              "of them; dequantize per dot (one convert "
                              "per use) so the upcast fuses into the "
                              "dot's operand read")))
-        return findings
-
-
-# ---------------------------------------------------------------------------
-# AUD005 — missed fusion
-# ---------------------------------------------------------------------------
-@register
-class MissedFusion(Rule):
-    id = "AUD005"
-    name = "missed-fusion"
-    rationale = ("a cluster the fusion pass matches but never rewrote "
-                 "is a silent perf cliff: either the pass was skipped "
-                 "for this program, or one escaping value broke "
-                 "closure — the blocking eqn is named either way")
-
-    def check(self, prog: AuditProgram) -> List[Finding]:
-        if not prog.fusion_expected:
-            return []
-        from ...ops import fusion_pass
-        jaxpr = getattr(prog.jaxpr, "jaxpr", prog.jaxpr)
-        # top level only, exactly the scope wrap() rewrites — counting
-        # sub-jaxpr clusters would indict the pass for remat bodies it
-        # never claims by design
-        clusters, near = fusion_pass.match_report(jaxpr)
-        eligible = Counter(cl.pattern for cl in clusters)
-        findings = []
-        for pattern in sorted(eligible):
-            n, done = eligible[pattern], prog.fusion_rewrites.get(pattern, 0)
-            if done < n:
-                findings.append(Finding(
-                    rule=self.id, severity="warning", program=prog.name,
-                    provenance=f"missed[{pattern}]",
-                    message=(f"{n - done} fusable {pattern} cluster(s) "
-                             f"matched but only {done} rewritten — the "
-                             "fusion pass fell back or was bypassed for "
-                             "this program")))
-        for cl, blocker in near:
-            if eligible.get(cl.pattern, 0) > 0:
-                # the pattern does fuse elsewhere in this program; the
-                # leftover partial matches are recompute copies the
-                # pass skips by design
-                continue
-            findings.append(Finding(
-                rule=self.id, severity="warning", program=prog.name,
-                provenance=f"nearmiss[{cl.pattern}]",
-                message=(f"cluster matched {cl.pattern} but failed "
-                         f"closure: {blocker}")))
         return findings

@@ -5,8 +5,8 @@ signature when off.  Enabled (``PT_AUDIT=1`` read lazily, or
 :func:`enable` programmatically — bench does the latter), it runs at
 the two points where the framework already pays a compile:
 
- - ``jit/capture`` first replay: the captured step's *pre-fusion*
-   jaxpr is re-traced and audited once per signature, right after the
+ - ``jit/capture`` first replay: the captured step's jaxpr is
+   audited once per signature, right after the
    FLOPs/memory harvests that share the same compile-time window.  The
    replay hot path never pays anything — the 1-compile contract the
    bench capture block pins is untouched.
@@ -150,12 +150,10 @@ def _flat_arg_names(args, labels) -> List[str]:
 
 def audit_captured_step(entry, params, buffers, opt_states, rng_ctr,
                         lrs, traced) -> List[Finding]:
-    """Audit one captured step at compile time: re-trace the PRE-fusion
-    pure function (what ``fusion_pass.wrap`` itself matched, so the
-    missed-fusion cross-check compares like with like) and run the
-    rules.  One extra trace, zero compiles, zero steady-state cost."""
+    """Audit one captured step at compile time: take the pure function's
+    jaxpr (jax answers from the trace ``jit`` has just made of it) and
+    run the rules.  Zero compiles, zero steady-state cost."""
     import jax
-    from ...ops import fusion_pass
     pure = getattr(entry, "pure", None)
     if pure is None:
         return []
@@ -168,8 +166,6 @@ def audit_captured_step(entry, params, buffers, opt_states, rng_ctr,
             name=entry.name, jaxpr=closed, kind="capture",
             donated=range(n_donated),
             arg_names=_flat_arg_names(args, _ARG_LABELS_CAPTURE),
-            fusion_expected=fusion_pass.fusion_enabled(),
-            fusion_rewrites=entry.fusion,
             memory=entry.memory)
     except Exception:
         logger.debug("captured-step audit trace failed for %s",
@@ -199,5 +195,5 @@ def audit_serve_trace(name: str, closed, n_params: int,
     prog = AuditProgram(
         name=name, jaxpr=closed, kind="serve",
         donated=range(n_params, n_params + n_kv),
-        arg_names=names, fusion_expected=False)
+        arg_names=names)
     return audit_program(prog)

@@ -832,12 +832,9 @@ class UnfusedResidualNorm(Rule):
     name = "manually-composed-fusable-sequence"
     rationale = ("a residual add composed inline with a layer norm "
                  "(`ln(x + attn)`) materializes the sum as a separate HBM "
-                 "round-trip and hides the pair from call sites that "
-                 "bypass the jaxpr fusion pass; layer_norm and "
-                 "nn.LayerNorm take residual= (fused_add_layer_norm is "
-                 "the named form), which feeds the fused_layer_norm "
-                 "kernel's in-kernel add and is also what the graph-level "
-                 "fusion pass recognizes as one residual_ln cluster")
+                 "round-trip; layer_norm and nn.LayerNorm take residual= "
+                 "(fused_add_layer_norm is the named form), which feeds "
+                 "the fused_layer_norm kernel's in-kernel add")
 
     # model-layer code where fusable sequences get hand-written; ops/
     # and the lint tool itself stay free to compose primitives
@@ -881,8 +878,7 @@ class UnfusedResidualNorm(Rule):
                        f"residual add composed inline with {name}(); "
                        f"pass the addend as residual= (or call "
                        f"fused_add_layer_norm) so the add+LN pair runs "
-                       f"as one fused kernel and the fusion pass sees "
-                       f"one residual_ln cluster")
+                       f"as one fused kernel")
 
 
 @register

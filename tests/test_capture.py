@@ -313,3 +313,234 @@ def test_traced_first_call_lowers_the_step_once():
     assert seen.count("jaxpr_trace_duration") == 1, seen
     assert seen.count("jaxpr_to_mlir_module_duration") == 1, seen
     assert seen.count("backend_compile_duration") == 1, seen
+
+
+# -- one walk of the tape (PR 29) -------------------------------------------------
+
+def _walk_counted_step(amp=False, optimizers=1):
+    """A captured train step whose body counts how often Python runs it."""
+    pt.seed(3)
+    model = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 4))
+    if amp:
+        pt.amp.decorate(model, level="O2", dtype="bfloat16")
+    params = model.parameters()
+    if optimizers == 2:     # weights and biases under an optimizer each
+        groups = [[p for p in params if len(p.shape) == 2],
+                  [p for p in params if len(p.shape) == 1]]
+    else:
+        groups = [params]
+    opts = [pt.optimizer.AdamW(learning_rate=1e-2, parameters=g,
+                               multi_precision=amp) for g in groups]
+    ce = nn.CrossEntropyLoss()
+    walks = []
+
+    @pt.jit.capture_step
+    def step(x, y):
+        walks.append(1)
+        loss = ce(model(x), y)
+        loss.backward()
+        for o in opts:
+            o.step()
+            o.clear_grad()
+        return loss
+
+    return step, model, walks
+
+
+def _xy(n=4, stop_gradient=True):
+    rng = np.random.RandomState(n)
+    x = pt.to_tensor(rng.randn(n, 8).astype(np.float32),
+                     stop_gradient=stop_gradient)
+    return x, pt.to_tensor(rng.randint(0, 4, (n,)))
+
+
+WALK_CASES = {
+    # name: (amp, monitors on, optimizers, second signature)
+    "fp32": (False, "", 1, None),
+    "amp_o2": (True, "", 1, None),
+    "numerics": (False, "numerics", 1, None),
+    "sdc": (False, "sdc", 1, None),
+    "numerics_sdc": (False, "numerics sdc", 1, None),
+    "memory": (False, "memory", 1, None),
+    "tracer": (False, "tracer", 1, None),
+    "two_optimizers": (False, "", 2, None),
+    "amp_o2_every_monitor": (True, "numerics sdc memory tracer", 1,
+                             "batch_shape"),
+    "batch_shape": (False, "", 1, "batch_shape"),
+    "input_dtype": (False, "", 1, "dtype"),
+    "stop_gradient_flip": (False, "", 1, "stop_gradient"),
+    "train_eval_flip": (False, "", 1, "eval"),
+}
+
+
+@pytest.fixture
+def monitors_off():
+    from paddle_tpu.observability import memory, numerics, sdc
+    from paddle_tpu.observability.trace import reset_tracer
+
+    def reset():
+        numerics.reset_monitor()
+        sdc.reset_monitor()
+        memory.reset_memory_monitor()
+        reset_tracer()
+
+    reset()
+    yield
+    reset()
+
+
+def _monitors_on(which):
+    from paddle_tpu.observability import memory, numerics, sdc
+    from paddle_tpu.observability.trace import get_tracer
+    if "numerics" in which:
+        numerics.get_monitor().enable(cadence=1)
+    if "sdc" in which:
+        sdc.get_monitor().enable(cadence=1, halt=False, rank=0)
+    if "memory" in which:
+        memory.get_memory_monitor().enable()
+    if "tracer" in which:
+        get_tracer().enable()
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_step_body_is_walked_once(case, monitors_off):
+    """The user's body runs once on the first call of a signature and
+    never on a replay: the step is traced once (a graph pass used to walk
+    it, find nothing to rewrite, and walk it again)."""
+    amp, monitors, optimizers, second = WALK_CASES[case]
+    _monitors_on(monitors)
+    step, model, walks = _walk_counted_step(amp, optimizers)
+    x, y = _xy()
+    losses = [float(step(x, y)) for _ in range(3)]
+    assert len(walks) == 1
+    assert step.stats == {"hits": 2, "misses": 1, "compiles": 1,
+                          "fallback": None}
+    assert np.all(np.isfinite(losses)) and losses[2] < losses[0]
+    if second is None:
+        return
+    if second == "batch_shape":
+        x2, y2 = _xy(6)
+    elif second == "dtype":
+        x2, y2 = x.astype("bfloat16"), y
+    elif second == "stop_gradient":
+        x2, y2 = _xy(stop_gradient=False)
+    else:
+        model.eval()
+        x2, y2 = x, y
+    step(x2, y2)
+    step(x2, y2)
+    assert len(walks) == 2
+    if second == "eval":
+        model.train()
+    step(x, y)              # the first signature's entry still replays
+    assert len(walks) == 2
+    assert step.stats["compiles"] == 2 and step.stats["hits"] == 4
+
+
+def test_forward_only_body_and_a_static_argument_are_walked_once():
+    """No optimizer, no backward; a Python scalar among the arguments is
+    part of the signature: a new value is a new entry, walked once."""
+    pt.seed(3)
+    model = nn.Linear(8, 4)
+    walks = []
+
+    @pt.jit.capture_step
+    def fwd(x, scale=1.0):
+        walks.append(1)
+        return model(x) * scale
+
+    x, _ = _xy()
+    a = [fwd(x).numpy() for _ in range(3)]
+    assert len(walks) == 1
+    b = [fwd(x, scale=2.0).numpy() for _ in range(2)]
+    assert len(walks) == 2
+    np.testing.assert_allclose(b[1], 2.0 * a[2], rtol=1e-6)
+    fwd(x)
+    assert len(walks) == 2 and fwd.stats["compiles"] == 2
+
+
+def test_the_graph_audit_reads_the_trace_the_step_made(monitors_off):
+    """`PT_AUDIT=1` asks `jax.make_jaxpr` for the entry's pure function
+    at the first call of a signature; jax answers from the trace
+    `jax.jit` has just made of the same function, so the audit reads the
+    step's jaxpr and the body is still walked once."""
+    from paddle_tpu.tools.audit import runtime as audit_rt
+    audit_rt.reset()
+    audit_rt.enable(True)
+    try:
+        step, _, walks = _walk_counted_step()
+        x, y = _xy()
+        for _ in range(3):
+            step(x, y)
+        assert audit_rt.snapshot()["programs"] == ["captured_step(step)"]
+        assert len(walks) == 1
+        assert step.stats["compiles"] == 1
+    finally:
+        audit_rt.enable(False)
+        audit_rt.reset()
+
+
+# four steps of benchmarks/configs/tiny-train.json's two-layer GPT (batch 4
+# x 128, AdamW 1e-3, seed 29), recorded at the parent of PR 29 (4a129d2) on
+# this container's CPU, where the graph pass on and off gave the same bits
+GPT_GOLDEN = {
+    "fp32": ["0x1.bc15c40000000p+2", "0x1.be30e80000000p+2",
+             "0x1.bcd34c0000000p+2", "0x1.bd894e0000000p+2"],
+    "amp_o2": ["0x1.bc17300000000p+2", "0x1.be2eda0000000p+2",
+               "0x1.bcd4480000000p+2", "0x1.bd88180000000p+2"],
+}
+# a golden crosses CPUs: the same tree under --xla_cpu_max_isa=AVX2 / SSE4_2
+# or single-threaded Eigen moved fp32 by 3e-7 and bf16 by 2e-5 (relative);
+# a missed update, a dropped layer or another loss scale move the second
+# step by 1e-3 and more
+GPT_GOLDEN_RTOL = {"fp32": 2e-6, "amp_o2": 1e-4}
+
+
+def _tiny_gpt_losses(amp, steps=4):
+    import json
+    import os
+    from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "tiny-train.json")) as f:
+        spec = json.load(f)["model"]
+    pt.seed(29)
+    model = GPTForCausalLM(GPTConfig(**spec))
+    if amp:
+        pt.amp.decorate(model, level="O2", dtype="bfloat16")
+    opt = pt.optimizer.AdamW(learning_rate=1e-3,
+                             parameters=model.parameters(),
+                             multi_precision=amp)
+    ce = nn.CrossEntropyLoss()
+
+    @pt.jit.capture_step
+    def step(ids, labels):
+        loss = ce(model(ids), labels)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    rng = np.random.default_rng(29)
+    out = []
+    for _ in range(steps):
+        ids = rng.integers(0, spec["vocab_size"], (4, 128)).astype(np.int64)
+        labels = rng.integers(0, spec["vocab_size"],
+                              (4, 128)).astype(np.int64)
+        out.append(float(step(pt.to_tensor(ids), pt.to_tensor(labels))))
+    assert step.stats["compiles"] == 1 and step.stats["fallback"] is None
+    return out
+
+
+@pytest.mark.parametrize("monitored", ["plain", "monitored"])
+@pytest.mark.parametrize("precision", ["fp32", "amp_o2"])
+def test_tiny_gpt_loss_trajectory_is_the_parents(precision, monitored,
+                                                 monitors_off):
+    # the numerics and SDC monitors add outputs to the step, never
+    # arithmetic to the loss: the same goldens hold with them on
+    if monitored == "monitored":
+        _monitors_on("numerics sdc")
+    got = _tiny_gpt_losses(precision == "amp_o2")
+    want = [float.fromhex(h) for h in GPT_GOLDEN[precision]]
+    np.testing.assert_allclose(got, want, rtol=GPT_GOLDEN_RTOL[precision],
+                               atol=0)

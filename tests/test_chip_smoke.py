@@ -56,12 +56,12 @@ def test_rehearsal_runs_every_phase_and_says_it_is_not_a_chip_run():
 
 
 def test_a_failing_phase_fails_the_run():
-    # PT_FUSION_PASS=0 switches the fusion pass off: the captured loop
-    # still trains, but "the pass ran" no longer holds — the train phase
-    # must fail and nothing after it may run or report ok
-    res = _run("--rehearse-on-cpu", PT_FUSION_PASS="0")
+    # PT_CAPTURE=0 switches capture off: the loop still trains, eagerly,
+    # but "one compile, every other step a replay" no longer holds — the
+    # train phase must fail and nothing after it may run or report ok
+    res = _run("--rehearse-on-cpu", PT_CAPTURE="0")
     assert res.returncode != 0
-    assert "fusion pass never ran" in res.stderr
+    assert "capture did not hold" in res.stderr
     assert '"phase": "serve"' not in res.stdout
     assert '"ok": true' not in res.stdout
 

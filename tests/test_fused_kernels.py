@@ -107,6 +107,27 @@ class TestFusedLayerNorm:
                 np.asarray(out), np.asarray(fk.layer_norm_reference(x)),
                 **FWD_TOL)
 
+    def test_residual_grad_parity_bf16(self):
+        # the in-kernel add: bf16 in and out, f32 stats and gradients
+        # accumulated in f32, against the reference on the same inputs
+        bf = jnp.bfloat16
+        x, res = _rand((48, 256), 0).astype(bf), _rand((48, 256), 3).astype(bf)
+        w, b = _rand((256,), 1).astype(bf), _rand((256,), 2).astype(bf)
+
+        def f(fn):
+            return lambda x, w, b, r: jnp.sum(jnp.sin(
+                fn(x, w, b, residual=r).astype(jnp.float32)))
+
+        g1 = jax.grad(f(lambda *a, **k: fk.fused_layer_norm(
+            *a, **k, interpret=True)), argnums=(0, 1, 2, 3))(x, w, b, res)
+        g2 = jax.grad(f(fk.layer_norm_reference),
+                      argnums=(0, 1, 2, 3))(x, w, b, res)
+        for got, want in zip(g1, g2):
+            assert got.dtype == bf
+            np.testing.assert_allclose(
+                np.asarray(got.astype(jnp.float32)),
+                np.asarray(want.astype(jnp.float32)), rtol=5e-2, atol=5e-2)
+
 
 # ---------------------------------------------------------------------------
 # fused softmax cross-entropy parity
@@ -352,6 +373,131 @@ class TestDispatch:
 # ---------------------------------------------------------------------------
 # autotuner: search, pruning, persistence, cross-process reload
 # ---------------------------------------------------------------------------
+    @pytest.mark.parametrize("what", ["forward", "gradient"])
+    @pytest.mark.parametrize("entry", ["functional", "module"])
+    def test_fused_add_layer_norm_is_layer_norm_of_the_sum(
+            self, entry, what, monkeypatch, fresh_metrics):
+        """`F.fused_add_layer_norm(x, r)` and `nn.LayerNorm()(x,
+        residual=r)` on the kernel's route (the add inside the kernel)
+        against `F.layer_norm(x + r)` composed off it."""
+        import paddle_tpu as pt
+        import paddle_tpu.nn.functional as F
+        import paddle_tpu.framework.flags as flags
+
+        rng = np.random.RandomState(0)
+        arrays = [rng.randn(4, 16, 96).astype(np.float32),
+                  rng.randn(4, 16, 96).astype(np.float32),
+                  rng.randn(96).astype(np.float32),
+                  rng.randn(96).astype(np.float32)]
+
+        def run(fn):
+            x, r, w, b = (pt.to_tensor(a, stop_gradient=False)
+                          for a in arrays)
+            out, leaves = fn(x, r, w, b)
+            (out * out).sum().backward()
+            return [out.numpy()] if what == "forward" else \
+                [t.grad.numpy() for t in leaves]
+
+        def composed(x, r, w, b):
+            return F.layer_norm(x + r, 96, w, b), (x, r, w, b)
+
+        def fused(x, r, w, b):
+            if entry == "functional":
+                return F.fused_add_layer_norm(x, r, 96, w, b), (x, r, w, b)
+            ln = pt.nn.LayerNorm(96)
+            ln.weight.set_value(w)
+            ln.bias.set_value(b)
+            return ln(x, residual=r), (x, r, ln.weight, ln.bias)
+
+        prev = flags.get_flags("use_pallas_kernels")
+        try:
+            flags.set_flags({"use_pallas_kernels": False})
+            want = run(composed)
+        finally:
+            flags.set_flags(prev)
+        _force_cpu_dispatch(monkeypatch)
+        got = run(fused)
+        c = fresh_metrics.counter("pt_pallas_calls_total",
+                                  labelnames=("kernel", "path"))
+        assert c.value(kernel="fused_layer_norm", path="pallas") == 1
+        assert c.value(kernel="fused_layer_norm", path="fallback") == 1
+        for g, w_ in zip(got, want):
+            np.testing.assert_allclose(g, w_, **GRAD_TOL)
+
+    @pytest.mark.parametrize("size", ["tiny_fp32", "tiny_amp_o2",
+                                      "gpt345m_widths_fp32",
+                                      "gpt345m_widths_amp_o2"])
+    def test_gpt_step_for_the_chip_holds_the_kernels(self, size,
+                                                     monkeypatch):
+        """The captured GPT step traced as the chip would get it: every
+        layer norm, every attention and the loss are already Pallas calls
+        and no normalisation is left in XLA's hands, so a graph pass had
+        no cluster to rewrite there.  Each op's forward is in the trace
+        twice: the forward walk, and the tape's lazy `jax.vjp` of the op
+        at `loss.backward()`."""
+        import json
+        import collections
+        import paddle_tpu as pt
+        from paddle_tpu.framework import device
+        from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
+        monkeypatch.setattr(device, "on_tpu", lambda: True)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        name = "gpt-345m" if size.startswith("gpt345m") else "tiny-train"
+        with open(os.path.join(root, "benchmarks", "configs",
+                               name + ".json")) as f:
+            spec = json.load(f)["model"]
+        # flash attention is dispatched from flash_min_seq = 512 tokens up
+        seq = 1024 if size.startswith("gpt345m") else 512
+        spec = dict(spec, num_layers=2, max_position_embeddings=seq)
+        amp = size.endswith("amp_o2")
+        pt.seed(0)
+        model = GPTForCausalLM(GPTConfig(**spec))
+        if amp:
+            pt.amp.decorate(model, level="O2", dtype="bfloat16")
+        opt = pt.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters(),
+                                 multi_precision=amp)
+        ce = pt.nn.CrossEntropyLoss()
+
+        @pt.jit.capture_step
+        def step(ids, labels):
+            loss = ce(model(ids), labels)
+            loss.backward()
+            opt.step()
+            opt.clear_grad()
+            return loss
+
+        ids = pt.to_tensor(np.zeros((2, seq), np.int64))
+        leaves, struct = step._flatten((ids, ids), {})
+        entry = step._compile((ids, ids), {},
+                              step._signature(leaves, struct))
+        st = step._state
+        closed = jax.make_jaxpr(entry.pure)(
+            st.params, st.buffers, st.opt_states, st.rng_ctr, [1e-3],
+            [ids._data, ids._data])
+        kernels, outside = collections.Counter(), collections.Counter()
+
+        def walk(jaxpr):
+            for e in jaxpr.eqns:
+                if e.primitive.name == "pallas_call":
+                    kernels[e.params["name"]] += 1
+                    continue            # a kernel's body is the kernel's
+                outside[e.primitive.name] += 1
+                for v in e.params.values():
+                    for x in (v if isinstance(v, (list, tuple)) else [v]):
+                        x = getattr(x, "jaxpr", x)
+                        if hasattr(x, "eqns"):
+                            walk(x)
+
+        walk(closed.jaxpr)
+        L = spec["num_layers"]
+        assert kernels == {
+            "layer_norm_fwd": 2 * (2 * L + 1), "layer_norm_bwd": 2 * L + 1,
+            "flash_fwd": 2 * L, "flash_bwd_dq": L, "flash_bwd_dkv": L,
+            "softmax_xent_fwd": 2, "softmax_xent_bwd": 1}, dict(kernels)
+        assert not outside["rsqrt"] and not outside["reduce_max"], outside
+
+
 class TestAutotuneSearch:
 
     def test_layer_norm_search_times_three_plus_candidates(self):
@@ -522,3 +668,105 @@ class TestAutotunePersistence:
         assert b["timed"] == 0      # reloaded, nothing re-searched
         assert b["hits"] == 1 and b["misses"] == 0
         assert b["best"] == a["best"]
+
+
+# ---------------------------------------------------------------------------
+# autotuner: generated candidates, prune-before-time, schema bump
+# ---------------------------------------------------------------------------
+class TestCandidateGeneration:
+
+    @staticmethod
+    def _axes():
+        return [("tile", 512, 8), ("tile", 512, 128), ("choice", (1, 0))]
+
+    @staticmethod
+    def _cost(cfg):
+        br, bn, _par = cfg
+        return {"flops": 1e6, "bytes": float(br * bn),
+                "vmem_bytes": float(br * bn * 4),
+                "mxu_underfill": br < 8 or bn < 128}
+
+    def test_generates_from_axes_and_prunes(self):
+        limit = 256 * 1024
+        cands = at.generate_candidates(self._axes(), self._cost,
+                                       vmem_limit=limit,
+                                       max_candidates=5)
+        assert 1 <= len(cands) <= 5
+        for br, bn, par in cands:
+            # every survivor is axis-derived (aligned pow-2 walk) and
+            # inside the vmem budget
+            assert br in (8, 16, 32, 64, 128, 256, 512)
+            assert bn in (128, 256, 512)
+            assert par in (1, 0)
+            assert br * bn * 4 <= limit
+
+    def test_all_generated_pruned_raises(self):
+        with pytest.raises(RuntimeError):
+            at.generate_candidates(self._axes(), self._cost, vmem_limit=1)
+
+    def test_search_never_times_pruned_configs(self):
+        cands = at.generate_candidates(self._axes(), self._cost,
+                                       vmem_limit=64 * 1024,
+                                       max_candidates=32)
+        timed = []
+
+        def run(cfg):
+            timed.append(cfg)
+            assert self._cost(cfg)["vmem_bytes"] <= 64 * 1024
+
+        at.search("fused_layer_norm", ("gen", 1), run, cands,
+                  cost=self._cost, vmem_limit=64 * 1024,
+                  warmup=0, iters=1)
+        assert timed and all(c[0] * c[1] * 4 <= 64 * 1024 for c in timed)
+
+
+class TestSchemaBump:
+
+    @pytest.fixture(autouse=True)
+    def _restore_schema(self):
+        orig = dict(at.KERNEL_SCHEMA)
+        yield
+        at.KERNEL_SCHEMA.clear()
+        at.KERNEL_SCHEMA.update(orig)
+
+    def test_bump_invalidates_then_reloads_without_research(self, tmp_path):
+        key = (64, 96, 64, "float32", True)
+        path = str(tmp_path / "tune.json")
+        timed = []
+
+        def run(cfg):
+            timed.append(cfg)
+
+        def cost(cfg):
+            return {"flops": 1.0, "bytes": 1.0, "vmem_bytes": 0.0}
+
+        cands = [(128, 128, 1), (256, 256, 1)]
+        os.environ["PT_AUTOTUNE_CACHE"] = path
+        try:
+            at.search("fused_layer_norm", key, run, cands, cost=cost,
+                      warmup=0, iters=1)
+            n_first = len(timed)
+            assert n_first >= 2        # both survivors timed
+
+            # a kernel-layout change bumps the schema: every entry
+            # written under the old version becomes invisible
+            at.bump_schema("fused_layer_norm")
+            assert at.cache_get("fused_layer_norm", key) is None
+            at.cache_clear()
+            at.load_cache(path)        # stale entries dropped on load
+            assert at.cache_get("fused_layer_norm", key) is None
+
+            # re-search under the new schema, then reload in a clean
+            # cache: the bumped entry answers without re-searching
+            at.search("fused_layer_norm", key, run, cands, cost=cost,
+                      warmup=0, iters=1)
+            n_second = len(timed)
+            assert n_second > n_first
+            at.cache_clear()
+            at.load_cache(path)
+            _, timings = at.search("fused_layer_norm", key, run, cands,
+                                   cost=cost, warmup=0, iters=1)
+            assert timings == {}       # pure cache hit across the bump
+            assert len(timed) == n_second
+        finally:
+            os.environ.pop("PT_AUTOTUNE_CACHE", None)

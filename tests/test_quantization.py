@@ -316,6 +316,12 @@ class TestQuantKernels:
         assert np.all(np.abs(got - x @ w) <= bound + 1e-5)
 
     def test_w8a16_pallas_interpret_bit_identical_to_mirror(self):
+        """Bit-identical to the mirror on the tile the kernel computes
+        (m and n padded to the 8 x 128 block).  Against the mirror at the
+        unpadded width only the order of the K-term f32 sum may differ:
+        XLA's CPU dot emitter sums a product narrower than a vector lane
+        in another order (at n=8 it is the mirror that leaves the
+        ascending fused-multiply-add order, the kernel keeps it)."""
         import jax.numpy as jnp
         from paddle_tpu.ops import quant_kernels as qk
         x, w = self._wx()          # m=5, n=8: both block pads exercised
@@ -323,8 +329,18 @@ class TestQuantKernels:
         out_p = np.asarray(qk.w8a16_matmul(jnp.asarray(x), q, s,
                                            use_pallas=True,
                                            interpret=True))
+        assert out_p.shape == (5, 8)
+        xp = jnp.pad(jnp.asarray(x), ((0, 3), (0, 0)))
+        qp, sp = jnp.pad(q, ((0, 0), (0, 120))), jnp.pad(s, (0, 120))
+        tile = np.asarray(qk.w8a16_matmul_reference(xp, qp, sp))
+        assert np.array_equal(out_p, tile[:5, :8])
+        assert not tile[5:].any() and not tile[:, 8:].any()
         out_r = np.asarray(qk.w8a16_matmul_reference(jnp.asarray(x), q, s))
-        assert np.array_equal(out_p, out_r)
+        # each side is within K * 2^-24 of the exact sum of |x_k w_k|
+        k = x.shape[1]
+        bound = 2 * k * 2.0 ** -24 * (
+            np.abs(x) @ np.abs(np.asarray(q, np.float32))) * np.asarray(s)
+        assert np.all(np.abs(out_p - out_r) <= bound)
 
     def test_w8a16_bf16_activations(self):
         import jax.numpy as jnp
