@@ -1,94 +1,150 @@
-"""Microbench the flash kernel on the real chip: fwd and fwd+bwd at the
-GPT-345M shape, vs XLA attention, at several block configs.
-Usage: python exp/bench_flash.py
+"""The flash kernels alone on the chip, at the GPT-345M train shape
+(8, 16, 1024, 64) bf16: device ms a call of `flash_fwd`, `flash_bwd_dq`
+and `flash_bwd_dkv` from the profiler's trace, for a sweep of
+(block_q, block_k), causal and not, with the cell's dropout and without;
+beside them the same numbers of another tree's `ops/pallas_ops.py`
+(`--against <file>`: the parent's, unpacked where .gitignore lists it)
+and how far the two trees' outputs and gradients lie apart.
+
+    chiprun -- python exp/bench_flash.py --against .chipwork/parent_pallas_ops.py
+
+Writes `chiprun_out/bench_flash.json` and prints it.
 """
+import argparse
+import importlib.util
 import json
-import time
+import os
+import re
+import shutil
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.ops.pallas_ops import mha
+import paddle_tpu.ops.pallas_ops as ours
+import trace_reduce
 
 B, H, S, D = 8, 16, 1024, 64
-rng = np.random.RandomState(0)
-q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)).astype(jnp.bfloat16)
-k = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)).astype(jnp.bfloat16)
-v = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)).astype(jnp.bfloat16)
+ITERS = 10
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+SWEEP = ((512, 512), (256, 512), (512, 256), (256, 256), (1024, 512),
+         (1024, 256), (128, 512), (384, 384))
 
 
-def _chain(fn, q0, k0, v0, iters):
-    """Serially-dependent chain of fn calls (outputs threaded forward,
-    so every call has fresh inputs) ending in a host readback, which is
-    the fence."""
-    qq = q0
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(qq, k0, v0)
-        first = out[0] if isinstance(out, tuple) else out
-        qq = (first.astype(jnp.float32) * 1e-3).astype(q0.dtype).reshape(
-            q0.shape)
-    float(jnp.sum(qq.astype(jnp.float32)))  # sync
-    return time.perf_counter() - t0
+def load_tree(path):
+    """Another tree's pallas_ops.py as a sibling module of ours (its
+    relative imports resolve to this tree's package)."""
+    spec = importlib.util.spec_from_file_location(
+        "paddle_tpu.ops.pallas_ops_other", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def timeit(fn, q0, k0, v0, iters=40):
-    _chain(fn, q0, k0, v0, 2)  # warm
-    t_short = _chain(fn, q0, k0, v0, 5)
-    t_long = _chain(fn, q0, k0, v0, 5 + iters)
-    return (t_long - t_short) / iters * 1000
+def inputs(seed=0):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
+                 .astype(jnp.bfloat16) for _ in range(3))
 
 
-def xla_attn(q, k, v):
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * (D ** -0.5)
-    mask = jnp.tril(jnp.ones((S, S), bool))
-    s = jnp.where(mask, s, -1e9)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+def grad_fn(mod, causal, p_drop, blocks):
+    def loss(q, k, v, seed):
+        out = mod.mha(q, k, v, causal=causal, dropout_p=p_drop, seed=seed,
+                      block_q=blocks[0], block_k=blocks[1])
+        return (out.astype(jnp.float32) ** 2).sum(), out
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
 
 
-results = {}
-for name, fn in [
-    ("xla", jax.jit(xla_attn)),
-    ("flash512_512", jax.jit(lambda a, b_, c: mha(a, b_, c, causal=True,
-                                                  block_q=512, block_k=512))),
-    ("flash1024_256", jax.jit(lambda a, b_, c: mha(
-        a, b_, c, causal=True, block_q=1024, block_k=256))),
-    ("flash1024_512", jax.jit(lambda a, b_, c: mha(
-        a, b_, c, causal=True, block_q=1024, block_k=512))),
-    ("flash256_512", jax.jit(lambda a, b_, c: mha(
-        a, b_, c, causal=True, block_q=256, block_k=512))),
-]:
+def kernel_ms(fn, args):
+    """Device ms a call of each kernel over ITERS traced calls."""
+    jax.block_until_ready(fn(*args))
+    tmp = tempfile.mkdtemp(prefix="flash_trace_")
     try:
-        results[f"{name}_fwd_ms"] = round(timeit(fn, q, k, v), 3)
-    except Exception as e:
-        results[f"{name}_fwd_ms"] = str(e)[:120]
+        jax.profiler.start_trace(tmp)
+        for _ in range(ITERS):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        raw = trace_reduce.load_xplane(
+            trace_reduce.find_xplane(tmp),
+            keep_lines=lambda plane, line: plane.startswith("/device:"))
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+    events = trace_reduce.line_events(trace_reduce.device_planes(raw)[0][1],
+                                      trace_reduce.OPS_LINE)
+    ms = {"names": sorted({e[0] for e in events if "flash" in e[0]})}
+    for name in KERNELS:
+        pat = re.compile(r"^pallas:\w*" + name + r"(_|\.|$)")
+        ms[name] = sum(e[2] for e in events if pat.match(e[0])) / ITERS / 1e6
+    ms["all"] = sum(ms[name] for name in KERNELS)
+    return ms
 
-for name, fn in [
-    ("xla", xla_attn),
-    ("flash512_512", lambda a, b_, c: mha(a, b_, c, causal=True,
-                                          block_q=512, block_k=512)),
-    ("flash1024_256", lambda a, b_, c: mha(a, b_, c, causal=True,
-                                           block_q=1024, block_k=256)),
-    ("flash1024_512", lambda a, b_, c: mha(a, b_, c, causal=True,
-                                           block_q=1024, block_k=512)),
-    ("flash256_512", lambda a, b_, c: mha(a, b_, c, causal=True,
-                                          block_q=256, block_k=512)),
-]:
-    def loss(a, b_, c, fn=fn):
-        return fn(a, b_, c).astype(jnp.float32).sum()
-    # one compile per attention variant is the point of the benchmark
-    g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))  # tpu-lint: disable=TPU001
-    try:
-        results[f"{name}_fwdbwd_ms"] = round(timeit(g, q, k, v), 3)
-    except Exception as e:
-        results[f"{name}_fwdbwd_ms"] = str(e)[:120]
 
-# correctness cross-check on-chip
-o_flash = mha(q, k, v, causal=True)
-o_xla = xla_attn(q, k, v)
-results["max_abs_diff"] = float(jnp.max(jnp.abs(
-    o_flash.astype(jnp.float32) - o_xla.astype(jnp.float32))))
-print(json.dumps(results))
+def ulps_apart(a, b):
+    """max |a - b| in bf16 ulps of the largest element of b."""
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    top = float(np.abs(b).max())
+    ulp = 2.0 ** (np.floor(np.log2(top)) - 7)
+    return float(np.abs(a - b).max() / ulp)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--against", default=None)
+    ap.add_argument("--blocks", default=None,
+                    help="bq:bk,... in place of the sweep")
+    args = ap.parse_args()
+    assert jax.devices()[0].platform == "tpu", jax.devices()
+    sweep = SWEEP if not args.blocks else tuple(
+        tuple(int(x) for x in pair.split(":"))
+        for pair in args.blocks.split(","))
+    q, k, v = inputs()
+    seed = jnp.asarray(1.2345, jnp.float32)
+    res = {"device": jax.devices()[0].device_kind, "shape": [B, H, S, D],
+           "defaults": [ours._BLOCK_Q, ours._BLOCK_K], "ours": {},
+           "other": {}, "apart_ulps": {}}
+    trees = [("ours", ours, sweep + ((None, None),))]
+    if args.against:
+        trees.append(("other", load_tree(args.against), ((None, None),)))
+    for tag, mod, pairs in trees:
+        for blocks in pairs:
+            for causal in (True, False):
+                for p_drop in (0.1, 0.0):
+                    if p_drop == 0.0 and blocks != (None, None):
+                        continue
+                    key = "%s/%s/causal=%d/drop=%s" % (
+                        blocks[0], blocks[1], causal, p_drop)
+                    try:
+                        res[tag][key] = kernel_ms(
+                            grad_fn(mod, causal, p_drop, blocks),
+                            (q, k, v, seed))
+                    except Exception as e:   # a tile Mosaic refuses
+                        res[tag][key] = str(e)[:200]
+                    print(tag, key, res[tag][key], flush=True)
+    if args.against:
+        other = trees[1][1]
+        for causal in (True, False):
+            for p_drop in (0.1, 0.0):
+                (ga, oa) = grad_fn(ours, causal, p_drop, (None, None))(
+                    q, k, v, seed)
+                (gb, ob) = grad_fn(other, causal, p_drop, (None, None))(
+                    q, k, v, seed)
+                res["apart_ulps"]["causal=%d/drop=%s" % (causal, p_drop)] = {
+                    n: ulps_apart(a, b) for n, a, b in zip(
+                        ("out", "dq", "dk", "dv"), (oa,) + ga, (ob,) + gb)}
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "bench_flash.json"),
+              "w") as f:
+        json.dump(res, f, indent=1)
+    print(json.dumps(res))
+
+
+if __name__ == "__main__":
+    main()

@@ -365,11 +365,15 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                   and _flags.flag("use_pallas_kernels")
                   and _device.pallas_dispatch())
     eff_drop = dropout_p if training else 0.0
-    from ...ops.fused_kernels import record_dispatch as _record
+    from ...ops.fused_kernels import (record_dispatch as _record,
+                                      record_flash_chunks)
     if use_pallas:
-        from ...ops.pallas_ops import flash_attention as _fa
+        from ...ops.pallas_ops import flash_attention as _fa, mha_chunks
         out = _fa(q, k_, v, causal=is_causal, dropout_p=eff_drop)
         _record("flash_mha", "pallas")
+        record_flash_chunks(*mha_chunks(
+            q.shape[1], k_.shape[1], q.shape[3], q._data.dtype,
+            causal=is_causal))
         return out
     _record("flash_mha", "fallback")
 

@@ -44,24 +44,47 @@ __all__ = [
 # ---------------------------------------------------------------------------
 # dispatch observability
 # ---------------------------------------------------------------------------
-def record_dispatch(kernel: str, path: str):
-    """Count one dispatch decision: ``path`` is ``pallas`` (fused kernel
-    taken) or ``fallback`` (XLA path). Fed by the nn.functional dispatch
-    layer; never raises. Looked up per call (not cached) so a registry
-    reset doesn't strand increments on a stale counter — dispatch
-    decisions are trace-time events, not hot-loop work. Inert while
-    telemetry is off (the registry must stay empty then)."""
+def _trace_time_counter(name: str, help_: str, labelnames):
+    """The registry's counter ``name``, or None while telemetry is off
+    (the registry must stay empty then) or the registry cannot be had.
+    Looked up per call (not cached) so a registry reset doesn't strand
+    increments on a stale counter — dispatch decisions are trace-time
+    events, not hot-loop work."""
     try:
         from ..observability.metrics import get_registry
         from ..observability.telemetry import get_telemetry
         if not get_telemetry().enabled:
-            return
-        get_registry().counter(
-            "pt_pallas_calls_total",
-            "Kernel dispatch decisions by path (pallas|fallback)",
-            labelnames=("kernel", "path")).inc(kernel=kernel, path=path)
+            return None
+        return get_registry().counter(name, help_, labelnames=labelnames)
     except Exception:
-        pass
+        return None
+
+
+def record_dispatch(kernel: str, path: str):
+    """Count one dispatch decision: ``path`` is ``pallas`` (fused kernel
+    taken) or ``fallback`` (XLA path). Fed by the nn.functional dispatch
+    layer; never raises."""
+    c = _trace_time_counter(
+        "pt_pallas_calls_total",
+        "Kernel dispatch decisions by path (pallas|fallback)",
+        ("kernel", "path"))
+    if c is not None:
+        c.inc(kernel=kernel, path=path)
+
+
+def record_flash_chunks(visited: int, total: int):
+    """Beside a ``flash_mha`` dispatch to Pallas, how far its causal
+    skip engages: the (block_q, block_k) tiles of the call's padded
+    sq x skv square the kernels compute (``state="visited"``) and all of
+    them (``state="total"``), from the call's static plan
+    (:func:`pallas_ops.mha_chunks`)."""
+    c = _trace_time_counter(
+        "pt_flash_chunks_total",
+        "Tiles of the attention square the flash kernels compute "
+        "(visited) of all of them (total), by dispatch", ("state",))
+    if c is not None:
+        c.inc(visited, state="visited")
+        c.inc(total, state="total")
 
 
 # ---------------------------------------------------------------------------

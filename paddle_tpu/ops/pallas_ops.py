@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,15 @@ __all__ = ["flash_attention", "mha", "mha_reference"]
 
 _NEG_INF = -1e30
 _LANES = 128
+# mha's default tile (block_q, block_k). On a v5e at (8, 16, 1024, 64)
+# bf16, causal, dropout 0.1, forward + dQ + dK/dV in ms a call (my chip
+# run, PR 30, exp/bench_flash.py): 512/512 0.62 + 0.62 + 0.82 = 2.05,
+# 256/512 2.33, 512/256 2.40, 1024/512 2.60 (the diagonal skips nothing
+# there), 256/256 2.68, 384/384 2.81, 128/512 2.95; the parent's kernels
+# at their 1024/512 3.07. A tile costs a fixed ~0.3 us beside its
+# elements' work, so a finer diagonal loses more than it skips.
+_BLOCK_Q = 512
+_BLOCK_K = 512
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -45,24 +55,28 @@ def _tile_keep_mask(seed, bh, qi, ki, block_q, block_k, p_drop):
     kernels regenerate the identical mask for a tile without ever
     materialising the (S, S) mask in HBM — the same trick the
     reference's vendored flashattn uses with its Philox offsets
-    (``third_party/flashattn``). Plain vector int ops, so it runs the
-    same on real TPU and in interpret mode (pltpu.prng_* has no
-    interpret-mode lowering).
+    (``third_party/flashattn``) — and any tiling of the square gives an
+    element the same bit. Plain vector int ops, so it runs the same on
+    real TPU and in interpret mode (pltpu.prng_* has no interpret-mode
+    lowering).
     """
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
     def _i32(x):  # uint32 constant -> wrapped int32
         return jnp.int32(x - (1 << 32) if x >= (1 << 31) else x)
 
-    h = rows * _i32(0x0001_93E9) + cols  # row-major element id, wraps
-    h = h ^ seed ^ (bh * _i32(0x9E37_79B1))
+    # row-major element id, wraps: the row's term is made on a column
+    # and the key's on a row, so the tile pays one add for the pair
+    rows = (qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0)) * _i32(0x0001_93E9)
+    cols = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1)
+    h = (rows + cols) ^ (seed ^ (bh * _i32(0x9E37_79B1)))
     for mult in (_i32(0x85EB_CA6B), _i32(0xC2B2_AE35)):
         h = h * mult
         h = h ^ jax.lax.shift_right_logical(h, 15)
-    u24 = jax.lax.shift_right_logical(h, 8)  # uniform in [0, 2^24)
-    return u24 >= jnp.int32(int(p_drop * (1 << 24)))
+    # keep where the top 24 bits, uniform in [0, 2^24), reach the
+    # threshold: one unsigned compare of the whole word
+    return jax.lax.bitcast_convert_type(h, jnp.uint32) >= jnp.uint32(
+        int(p_drop * (1 << 24)) << 8)
 
 
 def _interpret_default() -> bool:
@@ -97,9 +111,8 @@ def _split_refs(refs, p_drop, has_lens, has_shift=False):
     return seed_ref, lens_ref, shift_ref, refs[i:]
 
 
-def _key_mask(lens_ref, shift_ref, b, qi, ki, block_q, block_k, q_len,
-              kv_len, causal):
-    """Validity mask for one (block_q, block_k) tile.
+def _offset_limit(lens_ref, shift_ref, b, q_len, kv_len):
+    """(causal diagonal offset, first key past the valid ones) of a call.
 
     Fixed-length: keys < kv_len, causal diagonal offset kv_len - q_len
     (end-aligned cross attention). Varlen (lens_ref set): keys < lens[b]
@@ -108,95 +121,226 @@ def _key_mask(lens_ref, shift_ref, b, qi, ki, block_q, block_k, q_len,
     overrides the causal diagonal offset — ring attention's per-step
     (my_rank - src_rank) * block shift.
     """
-    shape = (block_q, block_k)
-    kcol = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    off, limit = kv_len - q_len, kv_len
     if lens_ref is not None:
-        mask = kcol < lens_ref[b]
-        off = 0
-    else:
-        mask = kcol < kv_len
-        off = kv_len - q_len
+        off, limit = 0, lens_ref[b]
     if shift_ref is not None:
         off = shift_ref[0]
+    return off, limit
+
+
+def _key_mask(off, limit, qi, ki, block_q, block_k, causal, ends=True):
+    """Validity mask for one (block_q, block_k) tile: key column <
+    ``limit`` (``ends``: the keys may end inside a tile) and, causal,
+    column <= row + ``off`` (`_offset_limit`). The tile's place is in
+    the scalars the tile-local iotas are compared with."""
+    shape = (block_q, block_k)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask = kcol < limit - ki * block_k if ends else None
     if causal:
-        qrow = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        mask = jnp.logical_and(mask, kcol <= qrow + off)
+        qrow = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        diag = kcol - qrow <= off + qi * block_q - ki * block_k
+        mask = diag if mask is None else jnp.logical_and(mask, diag)
     return mask
+
+
+# -- which chunks a block meets ----------------------------------------------
+# A grid step holds one block of queries (one block of keys in dK/dV) and
+# walks the other operand in chunks. These two functions say which: the
+# chunks every element of which is attended take no mask, the chunks that
+# the diagonal or the end of the keys crosses take `_key_mask`, the rest
+# are not visited. They take Python ints (the static plan, the tests) and
+# traced scalars (the kernels) alike.
+
+def _static(*xs):
+    return all(isinstance(x, int) for x in xs)
+
+
+def _clip(x, lo, hi):
+    return min(max(x, lo), hi) if _static(x, lo, hi) else jnp.clip(x, lo, hi)
+
+
+def _floordiv(x, n):  # x >= 0
+    return x // n if _static(x) else jax.lax.div(x, jnp.int32(n))
+
+
+def _select(cond, a, b):
+    return (a if cond else b) if isinstance(cond, bool) \
+        else jnp.where(cond, a, b)
+
+
+def _kv_chunk_range(row0, rows, off, limit, chunk, n_chunks, causal):
+    """(full, stop) for the query rows [row0, row0 + rows): key chunks
+    [0, full) lie wholly under the diagonal and inside ``limit``, chunks
+    [full, stop) are crossed by one of them, none from ``stop`` on holds
+    an attended key."""
+    end = n_chunks * chunk
+    every, some = limit, limit      # keys every row / the last row sees
+    if causal:
+        every = _clip(row0 + off + 1, 0, limit)
+        some = _clip(row0 + rows + off, 0, limit)
+    every, some = _clip(every, 0, end), _clip(some, 0, end)
+    return _floordiv(every, chunk), _floordiv(some + chunk - 1, chunk)
+
+
+def _q_chunk_range(col0, cols, off, limit, chunk, n_chunks, causal):
+    """(first, full) for the key columns [col0, col0 + cols): no query
+    chunk before ``first`` attends them, chunks [first, full) are crossed
+    by the diagonal or the end of the keys, chunks [full, n_chunks)
+    attend every one."""
+    end = n_chunks * chunk
+    first = full = 0
+    if causal:
+        first = _floordiv(_clip(col0 - off, 0, end), chunk)
+        full = _floordiv(_clip(col0 + cols - 1 - off, 0, end) + chunk - 1,
+                         chunk)
+    full = _select(col0 + cols > limit, n_chunks, full)
+    first = _select(col0 >= limit, n_chunks, first)
+    return first, full
+
+
+def _loop(lo, hi, body):
+    """``body(j)`` for j in [lo, hi) as a loop inside the kernel (its
+    text once, whatever the trip count: an unrolled walk cost PR 28 40 s
+    of tracing); a range that is empty statically traces nothing."""
+    if _static(lo, hi) and lo >= hi:
+        return
+    jax.lax.fori_loop(lo, hi, lambda j, c: (body(j), c)[1], 0)
+
+
+def _in_span(lo, hi, base, per_span):
+    """A range of the whole operand's chunks as the part of it inside the
+    resident span [base, base + per_span), in the span's own numbering."""
+    return _clip(lo - base, 0, per_span), _clip(hi - base, 0, per_span)
+
+
+def _chunk_rows(j, chunk, n_chunks):
+    """The rows of chunk ``j`` of a resident span."""
+    if n_chunks == 1:
+        return slice(None)
+    return pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+
+
+# VMEM the resident operands of a grid step may hold, both pipeline
+# buffers counted: of the 16 MiB a v5e kernel is given, the inner tile's
+# float32 temporaries and the block's own operands need the rest.
+_RESIDENT_BYTES = 6 << 20
+
+
+def _resident_span(rows, chunk, row_bytes):
+    """Rows of the walked operand a grid step keeps in VMEM: all of them
+    where they fit `_RESIDENT_BYTES`, else the equal super-blocks (whole
+    chunks) of the fewest that do. ``row_bytes``: one row of every
+    resident operand."""
+    fit = max(_RESIDENT_BYTES // (2 * row_bytes) // chunk, 1) * chunk
+    n_super = -(-rows // fit)
+    return _ceil_to(-(-rows // n_super), chunk)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(*refs, causal, sm_scale, block_q, block_k, q_len, kv_len,
-                p_drop, has_lens, has_shift):
+# What a tile's vector work is made of, measured on the chip (PERF.md §6,
+# PR 30), decides the form of all three kernels:
+#   * scores and statistics are in log2 units: the scale carries log2(e),
+#     exp2 is what the hardware has, and lse goes out in natural units;
+#   * a row's running maximum and sum stay lane-replicated (block_q, 128)
+#     values: no (rows, 1) column is sliced out of or broadcast back into
+#     one, and the sum folds its lanes once, at the end;
+#   * dropout's 1 / (1 - r) is no multiply on the tile: the forward puts
+#     it on the output rows, the backward into the exponent.
+
+_LOG2E = math.log2(math.e)
+
+
+def _lanes(x, n):
+    """A lane-replicated (rows, 128) value as wide as an (rows, n) tile."""
+    return x if n == _LANES else jnp.tile(x, (1, n // _LANES))
+
+
+def _fold_lanes(x):
+    """(rows, n) -> (rows, 128): the sum of the tile's lane tiles, so that
+    a row's sum is the sum of the result's lanes."""
+    out = x[:, :_LANES]
+    for r in range(1, x.shape[1] // _LANES):
+        out = out + x[:, r * _LANES:(r + 1) * _LANES]
+    return out
+
+
+def _fwd_kernel(*refs, causal, sm_scale, block_q, block_k, n_k, q_len,
+                kv_len, p_drop, has_lens, has_shift, ends):
     seed_ref, lens_ref, shift_ref, (q_ref, k_ref, v_ref, o_ref, lse_ref,
                                     m_scr, l_scr, acc_scr) = _split_refs(
         refs, p_drop, has_lens, has_shift)
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    sb = pl.program_id(2)
+    per_span = k_ref.shape[1] // block_k
+    base = sb * per_span
 
-    @pl.when(ki == 0)
+    @pl.when(sb == 0)
     def _init():
         m_scr[:] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def _compute():
+    off, limit = _offset_limit(lens_ref, shift_ref, b, q_len, kv_len)
+
+    def chunk(j, masked):
+        at = _chunk_rows(j, block_k, per_span)
         # MXU contract: feed bf16 operands, accumulate fp32 via
         # preferred_element_type — an fp32 .astype before the dot would
         # run the MXU in fp32 mode at ~1/4 throughput (this exact
         # mistake cost 56% of the r03 GPT step, profile 2026-07-30)
-        s = jax.lax.dot_general(q_ref[0], k_ref[0],
+        s = jax.lax.dot_general(q_ref[0], k_ref[0, at, :],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * sm_scale
+        s = s * (sm_scale * _LOG2E)
+        mask = _key_mask(off, limit, qi, base + j, block_q, block_k, causal,
+                         ends) if masked else None
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
 
-        mask = _key_mask(lens_ref, shift_ref, b, qi, ki, block_q,
-                         block_k, q_len, kv_len, causal)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
+        # a masked score leaves exp2 as 0 under any maximum but _NEG_INF
+        # itself; what a row gathers before its first key, the first
+        # key's alpha = 0 wipes, and `_finalize` a row that never saw one
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2(s - _lanes(m_new, block_k))
+        alpha = jnp.exp2(m_prev - m_new)
         # l accumulates the UNdropped row sum (softmax denominator);
         # dropout applies to the numerator only: out = (p∘M/(1-r)) @ v / l
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l_scr[:] = l_scr[:] * alpha + _fold_lanes(p)
         if p_drop > 0.0:
-            keep = _tile_keep_mask(seed_ref[0], b, qi, ki, block_q, block_k,
-                                   p_drop)
-            p = jnp.where(keep, p / (1.0 - p_drop), 0.0)
-        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+            p = jnp.where(_tile_keep_mask(seed_ref[0], b, qi, base + j,
+                                          block_q, block_k, p_drop), p, 0.0)
+        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, at, :],
                                  (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[:] = acc_scr[:] * _lanes(alpha, acc_scr.shape[1]) + pv
+        m_scr[:] = m_new
 
-    if causal:
-        # Blocks fully above the diagonal have nothing to attend to.
-        _off = shift_ref[0] if shift_ref is not None else kv_len - q_len
+    # Chunks above the diagonal have nothing to attend to, chunks wholly
+    # under it nothing to mask.
+    full, stop = _in_span(*_kv_chunk_range(
+        qi * block_q, block_q, off, limit, block_k, n_k, causal),
+        base, per_span)
+    _loop(0, full, lambda j: chunk(j, False))
+    _loop(full, stop, lambda j: chunk(j, True))
 
-        @pl.when(qi * block_q + block_q - 1 + _off >= ki * block_k)
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(ki == nk - 1)
+    @pl.when(sb == pl.num_programs(2) - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        # a row that saw no key holds what its masked chunks gathered
+        m = m_scr[:, :1]
+        seen = m != _NEG_INF
+        l = jnp.sum(l_scr[:], axis=-1, keepdims=True)
+        o_ref[0] = (acc_scr[:] * jnp.where(
+            seen, (1.0 / (1.0 - p_drop)) / l, 0.0)).astype(o_ref.dtype)
         # stats ride a trailing-singleton dim: block (block_q, 1) keeps the
         # TPU (8,128) tiling rule satisfied (block (1, block_q) on a 2-D
         # (BH, S) stats array does not lower on real hardware)
-        lse_ref[0] = m_scr[:, :1] + jnp.log(l_safe)
+        lse_ref[0] = jnp.where(seen, (m + jnp.log2(l)) * (1.0 / _LOG2E),
+                               _NEG_INF)
 
 
 def _seed_spec_args(seed, p_drop, lens, shift=None):
@@ -214,27 +358,36 @@ def _seed_spec_args(seed, p_drop, lens, shift=None):
     return specs, args
 
 
+# The kernels' callers are jitted on the static plan: a model's layers
+# share one trace and one lowering of a kernel's text (24 layers x 4
+# calls of it were 14 s of the train cell's set-up), and the forward the
+# tape's vjp traces again is, to XLA, the call the forward pass made.
+_PLAN_STATICS = ("causal", "sm_scale", "block_q", "block_k", "kv_span",
+                 "q_len", "kv_len", "p_drop", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_PLAN_STATICS)
 def _fwd(q, k, v, seed, lens, shift, *, causal, sm_scale, block_q,
-         block_k, q_len, kv_len, p_drop, interpret):
+         block_k, kv_span, q_len, kv_len, p_drop, interpret):
     bh, sq, d = q.shape
     skv = k.shape[1]
-    nq, nk = sq // block_q, skv // block_k
     kernel = functools.partial(
         _fwd_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, q_len=q_len, kv_len=kv_len, p_drop=p_drop,
-        has_lens=lens is not None, has_shift=shift is not None)
+        block_k=block_k, n_k=skv // block_k, q_len=q_len, kv_len=kv_len,
+        p_drop=p_drop, has_lens=lens is not None,
+        has_shift=shift is not None, ends=lens is not None or kv_len != skv)
     seed_specs, seed_args = _seed_spec_args(seed, p_drop, lens, shift)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
+        grid=(bh, sq // block_q, skv // kv_span),
         in_specs=seed_specs + [
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
+            pl.BlockSpec((1, kv_span, d), lambda b, i, s: (b, s, 0)),
+            pl.BlockSpec((1, kv_span, d), lambda b, i, s: (b, s, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, s: (b, i, 0)),
         ],
         out_shape=[
             _sds((bh, sq, d), q.dtype, q),
@@ -256,128 +409,141 @@ def _fwd(q, k, v, seed, lens, shift, *, causal, sm_scale, block_q,
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _bwd_dq_kernel(*refs, causal, sm_scale, block_q, block_k,
-                   q_len, kv_len, p_drop, has_lens, has_shift):
+def _log2_stats(lse_ref, delta_ref, at, p_drop):
+    """The (rows, 1) lse and delta of a block as the backward tiles take
+    them: lse in log2 units less log2(1 / (1 - r)), so that exp2(s - lse)
+    is p / (1 - r), and delta times (1 - r) to match:
+    dS = p∘(dP∘M / (1 - r) - delta) = (p / (1 - r))∘(dP∘M - (1 - r) delta)."""
+    keep = 1.0 - p_drop
+    return (lse_ref[0, at, :] * _LOG2E + math.log2(keep),
+            delta_ref[0, at, :] * keep)
+
+
+def _bwd_dq_kernel(*refs, causal, sm_scale, block_q, block_k, n_k,
+                   q_len, kv_len, p_drop, has_lens, has_shift, ends):
     seed_ref, lens_ref, shift_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref,
                                     delta_ref, dq_ref,
                                     dq_scr) = _split_refs(
         refs, p_drop, has_lens, has_shift)
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    sb = pl.program_id(2)
+    per_span = k_ref.shape[1] // block_k
+    base = sb * per_span
 
-    @pl.when(ki == 0)
+    @pl.when(sb == 0)
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    def _compute():
+    off, limit = _offset_limit(lens_ref, shift_ref, b, q_len, kv_len)
+    lse, delta = _log2_stats(lse_ref, delta_ref, slice(None), p_drop)
+
+    def chunk(j, masked):
+        at = _chunk_rows(j, block_k, per_span)
         # bf16 operands into every dot; fp32 only for accumulators and
         # the softmax math (see the fwd kernel's MXU-contract note)
-        s = jax.lax.dot_general(q_ref[0], k_ref[0],
+        s = jax.lax.dot_general(q_ref[0], k_ref[0, at, :],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * sm_scale
-        mask = _key_mask(lens_ref, shift_ref, b, qi, ki, block_q,
-                         block_k, q_len, kv_len, causal)
-        p = jnp.exp(s - lse_ref[0])
-        p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
+        p = jnp.exp2(s * (sm_scale * _LOG2E) - lse)
+        mask = _key_mask(off, limit, qi, base + j, block_q, block_k, causal,
+                         ends) if masked else None
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0, at, :],
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if p_drop > 0.0:
             # gradient flows only through kept elements (dp ∘ M/(1-r));
             # delta = rowsum(do∘out) already reflects the dropped forward
-            keep = _tile_keep_mask(seed_ref[0], b, qi, ki, block_q, block_k,
-                                   p_drop)
-            dp = jnp.where(keep, dp / (1.0 - p_drop), 0.0)
-        ds = p * (dp - delta_ref[0])
-        dq_scr[:] = dq_scr[:] + sm_scale * jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            dp = jnp.where(_tile_keep_mask(seed_ref[0], b, qi, base + j,
+                                           block_q, block_k, p_drop),
+                           dp, 0.0)
+        ds = p * (dp - delta)
+        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+            ds.astype(k_ref.dtype), k_ref[0, at, :],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    if causal:
-        _off = shift_ref[0] if shift_ref is not None else kv_len - q_len
+    full, stop = _in_span(*_kv_chunk_range(
+        qi * block_q, block_q, off, limit, block_k, n_k, causal),
+        base, per_span)
+    _loop(0, full, lambda j: chunk(j, False))
+    _loop(full, stop, lambda j: chunk(j, True))
 
-        @pl.when(qi * block_q + block_q - 1 + _off >= ki * block_k)
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(ki == nk - 1)
+    @pl.when(sb == pl.num_programs(2) - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[:] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, causal, sm_scale, block_q, block_k, q_len,
-                    kv_len, p_drop, has_lens, has_shift):
+def _bwd_dkv_kernel(*refs, causal, sm_scale, block_q, block_k, n_q, q_len,
+                    kv_len, p_drop, has_lens, has_shift, ends):
     seed_ref, lens_ref, shift_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref,
                                     delta_ref, dk_ref, dv_ref, dk_scr,
                                     dv_scr) = _split_refs(
         refs, p_drop, has_lens, has_shift)
     b = pl.program_id(0)
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    sb = pl.program_id(2)
+    per_span = q_ref.shape[1] // block_q
+    base = sb * per_span
 
-    @pl.when(qi == 0)
+    @pl.when(sb == 0)
     def _init():
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    def _compute():
+    off, limit = _offset_limit(lens_ref, shift_ref, b, q_len, kv_len)
+
+    def chunk(i, masked):
+        at = _chunk_rows(i, block_q, per_span)
+        q, do = q_ref[0, at, :], do_ref[0, at, :]
+        lse, delta = _log2_stats(lse_ref, delta_ref, at, p_drop)
         # bf16 operands into every dot (see the fwd kernel's MXU note)
-        s = jax.lax.dot_general(q_ref[0], k_ref[0],
-                                (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * sm_scale
-        mask = _key_mask(lens_ref, shift_ref, b, qi, ki, block_q,
-                         block_k, q_len, kv_len, causal)
-        p = jnp.exp(s - lse_ref[0])
-        p = jnp.where(mask, p, 0.0)
+        p = jnp.exp2(s * (sm_scale * _LOG2E) - lse)     # p / (1 - r)
+        mask = _key_mask(off, limit, base + i, ki, block_q, block_k, causal,
+                         ends) if masked else None
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        p_tilde = p
         if p_drop > 0.0:
-            keep = _tile_keep_mask(seed_ref[0], b, qi, ki, block_q, block_k,
-                                   p_drop)
-            inv = 1.0 / (1.0 - p_drop)
-            p_tilde = jnp.where(keep, p * inv, 0.0)
-        else:
-            p_tilde = p
+            keep = _tile_keep_mask(seed_ref[0], b, base + i, ki, block_q,
+                                   block_k, p_drop)
+            p_tilde = jnp.where(keep, p, 0.0)
+            dp = jnp.where(keep, dp, 0.0)
         # dv += p̃^T @ do (dropped probabilities fed the forward output)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p_tilde.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+            p_tilde.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if p_drop > 0.0:
-            dp = jnp.where(keep, dp * inv, 0.0)
-        ds = p * (dp - delta_ref[0])
+        ds = p * (dp - delta)
         # dk += ds^T @ q
-        dk_scr[:] = dk_scr[:] + sm_scale * jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        _off = shift_ref[0] if shift_ref is not None else kv_len - q_len
+    # Query chunks before the diagonal never attend this block of keys,
+    # chunks wholly past it attend all of it.
+    first, full = _in_span(*_q_chunk_range(
+        ki * block_k, block_k, off, limit, block_q, n_q, causal),
+        base, per_span)
+    _loop(first, full, lambda i: chunk(i, True))
+    _loop(full, per_span, lambda i: chunk(i, False))
 
-        @pl.when(qi * block_q + block_q - 1 + _off >= ki * block_k)
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(qi == nq - 1)
+    @pl.when(sb == pl.num_programs(2) - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_PLAN_STATICS + ("q_span",))
 def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
-         block_q, block_k, q_len, kv_len, p_drop, interpret, dlse=None):
+         block_q, block_k, kv_span, q_span, q_len, kv_len, p_drop,
+         interpret, dlse=None):
     bh, sq, d = q.shape
     skv = k.shape[1]
-    nq, nk = sq // block_q, skv // block_k
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                     axis=-1, keepdims=True)  # (bh, sq, 1)
     if dlse is not None:
@@ -385,24 +551,24 @@ def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
         # vector: ds = p∘(dp - (delta - dlse))
         delta = delta - dlse.astype(jnp.float32)
     seed_specs, seed_args = _seed_spec_args(seed, p_drop, lens, shift)
-    has_lens = lens is not None
-    has_shift = shift is not None
+    common = dict(causal=causal, sm_scale=sm_scale, block_q=block_q,
+                  block_k=block_k, q_len=q_len, kv_len=kv_len,
+                  p_drop=p_drop, has_lens=lens is not None,
+                  has_shift=shift is not None,
+                  ends=lens is not None or kv_len != skv)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k, q_len=q_len,
-                          kv_len=kv_len, p_drop=p_drop, has_lens=has_lens,
-                          has_shift=has_shift),
-        grid=(bh, nq, nk),
+        functools.partial(_bwd_dq_kernel, n_k=skv // block_k, **common),
+        grid=(bh, sq // block_q, skv // kv_span),
         in_specs=seed_specs + [
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
+            pl.BlockSpec((1, kv_span, d), lambda b, i, s: (b, s, 0)),
+            pl.BlockSpec((1, kv_span, d), lambda b, i, s: (b, s, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, s: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, s: (b, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
         out_shape=_sds((bh, sq, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -412,22 +578,19 @@ def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
     )(*seed_args, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k, q_len=q_len,
-                          kv_len=kv_len, p_drop=p_drop, has_lens=has_lens,
-                          has_shift=has_shift),
-        grid=(bh, nk, nq),
+        functools.partial(_bwd_dkv_kernel, n_q=sq // block_q, **common),
+        grid=(bh, skv // block_k, sq // q_span),
         in_specs=seed_specs + [
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, q_span, d), lambda b, j, s: (b, s, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, s: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, s: (b, j, 0)),
+            pl.BlockSpec((1, q_span, d), lambda b, j, s: (b, s, 0)),
+            pl.BlockSpec((1, q_span, 1), lambda b, j, s: (b, s, 0)),
+            pl.BlockSpec((1, q_span, 1), lambda b, j, s: (b, s, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, s: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, s: (b, j, 0)),
         ],
         out_shape=[
             _sds((bh, skv, d), k.dtype, k),
@@ -448,39 +611,45 @@ def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
 # ---------------------------------------------------------------------------
 # custom_vjp wrapper on padded (BH, S, D) arrays
 # ---------------------------------------------------------------------------
+class _Plan(NamedTuple):
+    """What a call's three kernels are built from, all static: the tile
+    (block_q, block_k), the rows of K and V (of Q and dO in dK/dV) a grid
+    step holds (`_resident_span`), the unpadded lengths."""
+    causal: bool
+    sm_scale: float
+    block_q: int
+    block_k: int
+    kv_span: int
+    q_span: int
+    q_len: int
+    kv_len: int
+    p_drop: float
+    interpret: bool
+
+    def fwd(self):
+        kw = self._asdict()
+        del kw["q_span"]
+        return kw
+
+
 # seed / lens / shift are float32 (bitcast to int32 inside): custom_vjp
 # needs a float cotangent slot for every traced arg, and the per-step
 # dropout seed must be traced (a python int would retrace the train step
 # every step). lens/shift=None are allowed: None is a static pytree.
-_STATICS = (6, 7, 8, 9, 10, 11, 12, 13)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _flash(q, k, v, seed, lens, shift, plan):
+    return _fwd(q, k, v, seed, lens, shift, **plan.fwd())[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=_STATICS)
-def _flash(q, k, v, seed, lens, shift, causal, sm_scale, block_q, block_k,
-           q_len, kv_len, p_drop, interpret):
-    out, _ = _fwd(q, k, v, seed, lens, shift, causal=causal,
-                  sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-                  q_len=q_len, kv_len=kv_len, p_drop=p_drop,
-                  interpret=interpret)
-    return out
-
-
-def _flash_fwd(q, k, v, seed, lens, shift, causal, sm_scale, block_q,
-               block_k, q_len, kv_len, p_drop, interpret):
-    out, lse = _fwd(q, k, v, seed, lens, shift, causal=causal,
-                    sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-                    q_len=q_len, kv_len=kv_len, p_drop=p_drop,
-                    interpret=interpret)
+def _flash_fwd(q, k, v, seed, lens, shift, plan):
+    out, lse = _fwd(q, k, v, seed, lens, shift, **plan.fwd())
     return out, (q, k, v, seed, lens, shift, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, q_len, kv_len, p_drop,
-               interpret, res, do, dlse=None):
+def _flash_bwd(plan, res, do, dlse=None):
     q, k, v, seed, lens, shift, out, lse = res
-    dq, dk, dv = _bwd(q, k, v, out, lse, do, seed, lens, shift,
-                      causal=causal, sm_scale=sm_scale, block_q=block_q,
-                      block_k=block_k, q_len=q_len, kv_len=kv_len,
-                      p_drop=p_drop, interpret=interpret, dlse=dlse)
+    dq, dk, dv = _bwd(q, k, v, out, lse, do, seed, lens, shift, dlse=dlse,
+                      **plan._asdict())
     return (dq, dk, dv, jnp.zeros((), jnp.float32),
             None if lens is None else jnp.zeros_like(lens),
             None if shift is None else jnp.zeros_like(shift))
@@ -489,32 +658,22 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_len, kv_len, p_drop,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=_STATICS)
-def _flash_lse(q, k, v, seed, lens, shift, causal, sm_scale, block_q,
-               block_k, q_len, kv_len, p_drop, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _flash_lse(q, k, v, seed, lens, shift, plan):
     """(out, lse) variant for online-merge consumers (ring attention):
     the lse output is itself differentiable (d lse/d s = p folds into the
     backward delta vector)."""
-    return _fwd(q, k, v, seed, lens, shift, causal=causal,
-                sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-                q_len=q_len, kv_len=kv_len, p_drop=p_drop,
-                interpret=interpret)
+    return _fwd(q, k, v, seed, lens, shift, **plan.fwd())
 
 
-def _flash_lse_fwd(q, k, v, seed, lens, shift, causal, sm_scale, block_q,
-                   block_k, q_len, kv_len, p_drop, interpret):
-    out, lse = _fwd(q, k, v, seed, lens, shift, causal=causal,
-                    sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-                    q_len=q_len, kv_len=kv_len, p_drop=p_drop,
-                    interpret=interpret)
+def _flash_lse_fwd(q, k, v, seed, lens, shift, plan):
+    out, lse = _fwd(q, k, v, seed, lens, shift, **plan.fwd())
     return (out, lse), (q, k, v, seed, lens, shift, out, lse)
 
 
-def _flash_lse_bwd(causal, sm_scale, block_q, block_k, q_len, kv_len,
-                   p_drop, interpret, res, cots):
+def _flash_lse_bwd(plan, res, cots):
     do, dlse = cots
-    return _flash_bwd(causal, sm_scale, block_q, block_k, q_len, kv_len,
-                      p_drop, interpret, res, do, dlse=dlse)
+    return _flash_bwd(plan, res, do, dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -523,9 +682,59 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
-def _mha_tune_key(q, k, causal, interpret):
-    return (q.shape[2], k.shape[2], q.shape[3], str(q.dtype), bool(causal),
-            bool(interpret))
+def _mha_tune_key(sq, skv, d, dtype, causal, interpret):
+    return (sq, skv, d, str(jnp.dtype(dtype)), bool(causal), bool(interpret))
+
+
+def _fit_blocks(block_q, block_k, sq, skv):
+    """A tile no larger than the call: whole sublanes of queries, whole
+    lane tiles of keys (a row's running statistics are replicated over
+    the 128 lanes; keys past ``skv`` are padding, and masked)."""
+    return (min(int(block_q), _ceil_to(sq, 8)),
+            _ceil_to(min(int(block_k), skv), _LANES))
+
+
+def _mha_plan(sq, skv, d, dtype, *, causal, sm_scale=None, block_q=None,
+              block_k=None, p_drop=0.0, interpret=None):
+    """The `_Plan` of a call on (.., sq, d) queries and (.., skv, d) keys,
+    and the lengths its operands are padded to."""
+    if interpret is None:
+        interpret = _interpret_default()
+    if block_q is None and block_k is None:
+        from . import autotune as _at
+        hit = _at.cache_get("flash_mha", _mha_tune_key(
+            sq, skv, d, dtype, causal, interpret)) if _at.enabled() else None
+        if hit is not None:
+            block_q, block_k = hit
+    # explicitly passed blocks always win
+    block_q, block_k = _fit_blocks(
+        _BLOCK_Q if block_q is None else block_q,
+        _BLOCK_K if block_k is None else block_k, sq, skv)
+    row = 2 * _ceil_to(d, _LANES) * jnp.dtype(dtype).itemsize
+    # forward and dQ keep K and V in VMEM, dK/dV keeps Q, dO and the two
+    # (rows, 1) float32 statistics, which fill a lane tile a row
+    kv_span = _resident_span(_ceil_to(skv, block_k), block_k, row)
+    q_span = _resident_span(_ceil_to(sq, block_q), block_q,
+                            row + 2 * _LANES * 4)
+    plan = _Plan(bool(causal),
+                 1.0 / math.sqrt(d) if sm_scale is None else sm_scale,
+                 block_q, block_k, kv_span, q_span, sq, skv, float(p_drop),
+                 bool(interpret))
+    return plan, _ceil_to(sq, q_span), _ceil_to(skv, kv_span)
+
+
+def mha_chunks(sq, skv, d, dtype, *, causal):
+    """(visited, total): the (block_q, block_k) tiles of the padded
+    sq x skv square that `mha` computes for this shape with the blocks it
+    would choose, and all of them. The forward and dQ kernels walk them a
+    block of queries at a time, dK/dV a block of keys at a time."""
+    plan, sq_p, skv_p = _mha_plan(sq, skv, d, dtype, causal=causal)
+    n_q, n_k = sq_p // plan.block_q, skv_p // plan.block_k
+    visited = sum(
+        _kv_chunk_range(i * plan.block_q, plan.block_q, skv - sq, skv,
+                        plan.block_k, n_k, plan.causal)[1]
+        for i in range(n_q))
+    return visited, n_q * n_k
 
 
 def mha(q, k, v, *, causal=False, sm_scale=None, block_q=None, block_k=None,
@@ -542,33 +751,21 @@ def mha(q, k, v, *, causal=False, sm_scale=None, block_q=None, block_k=None,
     reference's flash_attn dropout path, ``flash_attn_kernel.cu``);
     ``seed`` is a traced f32 scalar that must change per training step.
 
-    ``block_q``/``block_k`` default to an autotuned choice when
-    :func:`tune_mha` has cached one for this (seq, d, dtype, causal) key
-    (ref ``paddle/phi/kernels/autotune/``), else 128/128.
+    ``block_q``/``block_k`` are the tile one pass of the softmax works
+    on: a grid step holds ``block_q`` queries and walks the keys in
+    chunks of ``block_k`` (dK/dV: ``block_k`` keys, the queries in chunks
+    of ``block_q``), under ``causal`` only the chunks at or below the
+    diagonal. They default to an autotuned choice when :func:`tune_mha`
+    has cached one for this (seq, d, dtype, causal) key (ref
+    ``paddle/phi/kernels/autotune/``), else to `_BLOCK_Q`/`_BLOCK_K`.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     b, h, sq, d = q.shape
     skv = k.shape[2]
-    if block_q is None and block_k is None:
-        from . import autotune as _at
-        hit = _at.cache_get("flash_mha", _mha_tune_key(
-            q, k, causal, interpret)) if _at.enabled() else None
-        if hit is not None:
-            block_q, block_k = hit
-    # explicitly passed blocks always win. Default: big q/k blocks —
-    # on v5e the per-grid-step revisit overhead dominates below ~512,
-    # measured 2026-07-30 at (8,16,1024,64): fwd+bwd 11.4ms at 128/128
-    # vs 3.2ms at 1024/512 (exp/bench_flash.py)
-    block_q = 1024 if block_q is None else block_q
-    block_k = 512 if block_k is None else block_k
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    block_q = min(block_q, _ceil_to(sq, 8))
-    block_k = min(block_k, _ceil_to(skv, 8))
-    sq_p, skv_p = _ceil_to(sq, block_q), _ceil_to(skv, block_k)
+    plan, sq_p, skv_p = _mha_plan(
+        sq, skv, d, q.dtype, causal=causal, sm_scale=sm_scale,
+        block_q=block_q, block_k=block_k, p_drop=dropout_p,
+        interpret=interpret)
     d_p = _ceil_to(d, _LANES)
-    p_drop = float(dropout_p)
     if seed is None:
         seed = jnp.zeros((), jnp.float32)
     else:
@@ -596,13 +793,10 @@ def mha(q, k, v, *, causal=False, sm_scale=None, block_q=None, block_k=None,
 
     qp, kp, vp = prep(q, sq_p), prep(k, skv_p), prep(v, skv_p)
     if return_lse:
-        out, lse = _flash_lse(qp, kp, vp, seed, lens, shift, causal,
-                              sm_scale, block_q, block_k, sq, skv, p_drop,
-                              interpret)
+        out, lse = _flash_lse(qp, kp, vp, seed, lens, shift, plan)
         return (out[:, :sq, :d].reshape(b, h, sq, d),
                 lse[:, :sq, 0].reshape(b, h, sq))
-    out = _flash(qp, kp, vp, seed, lens, shift, causal, sm_scale, block_q,
-                 block_k, sq, skv, p_drop, interpret)
+    out = _flash(qp, kp, vp, seed, lens, shift, plan)
     return out[:, :sq, :d].reshape(b, h, sq, d)
 
 
@@ -623,20 +817,26 @@ def _mha_cost_fn(b, h, sq, skv, d, itemsize):
         4.0 * b * h * (sq + skv) * d * itemsize
 
     def cost(cfg):
-        bq = min(int(cfg[0]), _ceil_to(sq, 8))
-        bk = min(int(cfg[1]), _ceil_to(skv, 8))
-        # per-grid-step tiles: q/o in native dtype + f32 acc, k/v
-        # blocks, and the (bq, 128) m/l scratch rows
-        vmem = (2 * bq * d_p * itemsize + bq * d_p * 4
-                + 2 * bk * d_p * itemsize + 2 * bq * _LANES * 4)
+        bq, bk = _fit_blocks(cfg[0], cfg[1], sq, skv)
+        # what the largest of the three kernels (dK/dV) holds a grid
+        # step: the resident span of Q, dO and their statistics in both
+        # pipeline buffers, its own K and V blocks in and dK and dV out
+        # likewise, two float32 accumulators, and the inner tile's
+        # float32 temporaries (scores, probabilities, dP, dS and the
+        # dropout hash: some six tiles live at once)
+        q_span = _resident_span(_ceil_to(sq, bq), bq,
+                                2 * d_p * itemsize + 2 * _LANES * 4)
+        vmem = (2 * q_span * (2 * d_p * itemsize + 2 * _LANES * 4)
+                + 2 * 4 * bk * d_p * itemsize + 2 * bk * d_p * 4
+                + 6 * bq * bk * 4)
         return {"flops": flops, "bytes": bytes_, "vmem_bytes": vmem,
                 "mxu_underfill": min(bq, bk) < 8}
     return cost
 
 
 def tune_mha(q, k, v, *, causal=False, interpret=None,
-             candidates=((128, 128), (256, 256), (512, 256), (512, 512),
-                         (1024, 256), (1024, 512))):
+             candidates=((128, 128), (256, 128), (128, 256), (256, 256),
+                         (256, 512), (512, 256), (512, 512), (1024, 512))):
     """Warmup autotune for :func:`mha`: candidate (block_q, block_k)
     configs are pruned by the cost-model roofline (vmem overflow / MXU
     underfill rejected before timing — see :func:`autotune.search`),
@@ -652,7 +852,7 @@ def tune_mha(q, k, v, *, causal=False, interpret=None,
     skv = k.shape[2]
     seen, todo = set(), []
     for bq, bk in candidates:
-        clamped = (min(bq, _ceil_to(sq, 8)), min(bk, _ceil_to(skv, 8)))
+        clamped = _fit_blocks(bq, bk, sq, skv)
         if clamped not in seen:
             seen.add(clamped)
             todo.append(clamped)
@@ -668,8 +868,8 @@ def tune_mha(q, k, v, *, causal=False, interpret=None,
         float(jnp.sum(state["q"].astype(jnp.float32)))
 
     best, timings = _at.search(
-        "flash_mha", _mha_tune_key(q, k, causal, interpret), run, todo,
-        cost=_mha_cost_fn(b, h, sq, skv, d, q.dtype.itemsize))
+        "flash_mha", _mha_tune_key(sq, skv, d, q.dtype, causal, interpret),
+        run, todo, cost=_mha_cost_fn(b, h, sq, skv, d, q.dtype.itemsize))
     # explicit tuning is intent: turn cache consumption on (still
     # switch-offable via incubate.autotune.set_config kernel.enable=False)
     _at.set_enabled(True)

@@ -553,3 +553,26 @@ def test_walk_share_is_chunks_walked_over_grid_steps(bench, name):
     assert (entry["moves"], entry["workloads"]) == (moves, [cell])
     assert entry["source"] == "program_counter"
     assert entry["layer"] == "kernels (ops/paged_attention.py)"
+
+
+def test_flash_chunks_visited_is_read_from_the_registry(bench):
+    """24 causal layers at the train cell's shape book 3 of 4 tiles
+    each; a program without the counter (the parent): nothing to read."""
+    from paddle_tpu.ops.fused_kernels import record_flash_chunks
+    name = "flash_chunks_visited_pct"
+    run = {"trace": None, "counters": {"steps": 153}}
+    obs.reset()
+    try:
+        assert read(bench, name, run) is None
+        obs.get_telemetry().enable(compile_watch=False)
+        assert read(bench, name, run) is None
+        for _ in range(24):
+            record_flash_chunks(3, 4)
+        assert read(bench, name, run) == pytest.approx(75.0)
+    finally:
+        obs.reset()
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    assert spec["per_layer"][-1] == {
+        "name": name, "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "kernels (ops/pallas_ops.py)",
+        "moves": "train_tokens_per_s", "workloads": ["gpt345m-train-s1024"]}

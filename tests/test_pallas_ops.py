@@ -119,6 +119,256 @@ def test_framework_entry_tensor_layout():
         atol=2e-3, rtol=2e-3)
 
 
+def _grads_and_out(fn, q, k, v):
+    def loss(q, k, v):
+        o = fn(q, k, v)
+        return jnp.sum(o * jnp.cos(o)), o
+    (_, o), g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                           has_aux=True))(q, k, v)
+    return (o,) + g
+
+
+# the default tile, (128, 128) and (256, 128); a sequence padded to the
+# tile, one of two tiles and the train cell's; self attention and
+# end-aligned cross attention whose queries are no multiple of a tile
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (None, None)])
+@pytest.mark.parametrize("sq,skv", [(192, 192), (100, 192), (512, 512),
+                                    (320, 512), (1024, 1024), (832, 1024)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_walk_matches_reference(causal, sq, skv, blocks):
+    """Forward and all three gradients of the chunk walk (loop bounds on
+    the diagonal, a mask only on the chunks it crosses) against the
+    dense reference."""
+    q, k, v = (_rand((1, 1, s, 64), 40 + i)
+               for i, s in enumerate([sq, skv, skv]))
+    got = _grads_and_out(
+        lambda q, k, v: mha(q, k, v, causal=causal, interpret=True,
+                            block_q=blocks[0], block_k=blocks[1]), q, k, v)
+    want = _grads_and_out(
+        lambda q, k, v: mha_reference(q, k, v, causal=causal), q, k, v)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=5e-5, rtol=5e-5)
+
+
+def _dense_mask(sq_p, skv_p, off, limit, causal):
+    rows = np.arange(sq_p)[:, None]
+    cols = np.arange(skv_p)[None, :]
+    mask = np.broadcast_to(cols < limit, (sq_p, skv_p))
+    if causal:
+        mask = mask & (cols <= rows + off)
+    return mask
+
+
+class TestChunkRanges:
+    """The two pure functions the kernels take their loop bounds from
+    (`_kv_chunk_range` for a block of queries in flash_fwd and
+    flash_bwd_dq, `_q_chunk_range` for a block of keys in
+    flash_bwd_dkv)."""
+
+    def test_against_a_dense_mask(self):
+        from paddle_tpu.ops.pallas_ops import (_kv_chunk_range,
+                                               _q_chunk_range)
+        rng = np.random.RandomState(0)
+        for _ in range(300):
+            bq, bk = (int(rng.choice([8, 16, 24, 64])) for _ in range(2))
+            n_q, n_k = (int(rng.randint(1, 9)) for _ in range(2))
+            sq_p, skv_p = n_q * bq, n_k * bk
+            causal = bool(rng.randint(2))
+            limit = int(rng.randint(0, skv_p + 1))
+            off = int(rng.randint(-sq_p - 4, skv_p + 5))
+            mask = _dense_mask(sq_p, skv_p, off, limit, causal)
+            tiles = mask.reshape(n_q, bq, n_k, bk)
+            some = tiles.any(axis=(1, 3))       # (n_q, n_k)
+            every = tiles.all(axis=(1, 3))
+            for i in range(n_q):
+                full, stop = _kv_chunk_range(i * bq, bq, off, limit, bk,
+                                             n_k, causal)
+                assert 0 <= full <= stop <= n_k
+                assert every[i, :full].all()        # no mask needed there
+                assert not some[i, stop:].any()     # nothing lost
+                # and nothing visited in vain, nothing masked in vain
+                assert all(some[i, j] and not every[i, j]
+                           for j in range(full, stop))
+            for j in range(n_k):
+                first, full = _q_chunk_range(j * bk, bk, off, limit, bq,
+                                             n_q, causal)
+                assert 0 <= first <= n_q and 0 <= full <= n_q
+                assert not some[:first, j].any()
+                assert every[max(first, full):, j].all()
+                assert all(some[i, j] for i in range(first, full))
+
+    def test_traced_scalars_give_the_same(self):
+        from paddle_tpu.ops.pallas_ops import (_kv_chunk_range,
+                                               _q_chunk_range)
+        for fn in (_kv_chunk_range, _q_chunk_range):
+            traced = jax.jit(lambda a, off, lim: fn(a, 16, off, lim, 8, 12,
+                                                    True))
+            for a, off, lim in [(0, 0, 96), (32, -40, 96), (48, 7, 50),
+                                (80, 200, 96), (16, -200, 0)]:
+                assert tuple(int(x) for x in traced(a, off, lim)) == \
+                    tuple(fn(a, 16, off, lim, 8, 12, True))
+
+    def test_the_train_cell_visits_three_of_four_tiles(self):
+        """The issue sized the skip at 56-62.5 % of 128- or 256-wide
+        tiles; on the chip a tile under 512 x 512 loses more to its own
+        fixed cost than the finer diagonal saves (PERF.md, PR 30), so the
+        default tile visits 3 of the cell's 4, and (256, 256) 10 of 16."""
+        from paddle_tpu.ops.pallas_ops import (_kv_chunk_range, _mha_plan,
+                                               mha_chunks)
+        visited, total = mha_chunks(1024, 1024, 64, jnp.bfloat16,
+                                    causal=True)
+        assert (visited, total) == (3, 4)
+        assert mha_chunks(1024, 1024, 64, jnp.bfloat16,
+                          causal=False) == (total, total)
+        plan, sq_p, skv_p = _mha_plan(1024, 1024, 64, jnp.bfloat16,
+                                      causal=True, block_q=256, block_k=256)
+        assert sum(_kv_chunk_range(i * 256, 256, 0, 1024, 256, 4, True)[1]
+                   for i in range(4)) == 10
+        assert (plan.block_q, plan.block_k, sq_p, skv_p) == (256, 256, 1024,
+                                                             1024)
+
+    def test_a_tile_holds_whole_lane_tiles_of_keys(self):
+        """block_k is rounded up to the 128 lanes a row's running
+        statistics are replicated over; the keys are padded to it and the
+        padding is masked."""
+        from paddle_tpu.ops.pallas_ops import _mha_plan
+        for skv, block_k, want in [(100, None, 128), (1024, 64, 128),
+                                   (1024, 200, 256), (300, None, 384),
+                                   (4096, None, 512)]:
+            plan, _, skv_p = _mha_plan(64, skv, 64, jnp.float32,
+                                       causal=False, block_k=block_k,
+                                       block_q=64)
+            assert plan.block_k == want and skv_p % want == 0
+
+    def test_the_kernels_call_them(self, monkeypatch):
+        import paddle_tpu.ops.pallas_ops as po
+        seen = []
+        for name in ("_kv_chunk_range", "_q_chunk_range"):
+            real = getattr(po, name)
+            monkeypatch.setattr(
+                po, name, lambda *a, _real=real, _name=name:
+                (seen.append(_name), _real(*a))[1])
+        q = _rand((1, 1, 128, 64), 0)
+        jax.grad(lambda q: mha(q, q, q, causal=True, interpret=True,
+                               block_q=64, block_k=64).sum())(q)
+        # forward (twice: primal and the vjp's), dQ; dK/dV
+        assert seen.count("_kv_chunk_range") >= 2
+        assert seen.count("_q_chunk_range") == 1
+
+    def test_resident_span(self):
+        from paddle_tpu.ops.pallas_ops import _resident_span, _RESIDENT_BYTES
+        # the train cell: K and V of a (1024, 128) bf16 head stay whole
+        assert _resident_span(1024, 256, 2 * 128 * 2) == 1024
+        for rows, chunk, row_bytes in [(8192, 256, 512), (8192, 256, 1536),
+                                       (65536, 512, 1024), (24, 8, 1 << 30)]:
+            span = _resident_span(rows, chunk, row_bytes)
+            assert span % chunk == 0 and span <= rows
+            assert span == chunk or 2 * span * row_bytes <= _RESIDENT_BYTES
+            # equal super-blocks: no more padding than a chunk each
+            n_super = -(-rows // span)
+            assert n_super * span - rows < n_super * chunk
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_super_blocks_match_one_span(causal):
+    """A walked operand that does not fit VMEM whole is held a
+    super-block at a time, the grid's last axis over them: the same
+    numbers as one span."""
+    from paddle_tpu.ops.pallas_ops import _flash, _mha_plan
+    q, k, v = (_rand((2, s, 128), 50 + i) for i, s in
+               enumerate([256, 512, 512]))
+    plan, sq_p, skv_p = _mha_plan(256, 512, 128, q.dtype, causal=causal,
+                                  block_q=64, block_k=128, interpret=True)
+    assert (plan.q_span, plan.kv_span, sq_p, skv_p) == (256, 512, 256, 512)
+    seed = jnp.zeros((), jnp.float32)
+
+    def run(plan):
+        return _grads_and_out(
+            lambda q, k, v: _flash(q, k, v, seed, None, None, plan), q, k, v)
+
+    for a, b_ in zip(run(plan._replace(q_span=128, kv_span=256)), run(plan)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("shift", [-256, -1, 0, 256])
+def test_flash_traced_shift_with_lse_cotangent(shift):
+    """Ring attention's call: a traced diagonal shift (a source rank
+    ahead: all masked, zeros and lse = _NEG_INF; behind: nothing
+    masked), (out, lse) returned and both differentiated."""
+    from paddle_tpu.ops.pallas_ops import _NEG_INF
+    s, d = 256, 64
+    q, k, v = (_rand((1, 2, s, d), 60 + i) for i in range(3))
+    w = _rand((1, 2, s), 63)
+
+    def dense(q, k, v, shift):
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+        rows = jnp.arange(s)[:, None]
+        cols = jnp.arange(s)[None, :]
+        mask = cols <= rows + shift
+        logits = jnp.where(mask, logits, -jnp.inf)
+        m = jnp.max(logits, axis=-1, keepdims=True)
+        m = jnp.where(jnp.isfinite(m), m, 0.0)
+        p = jnp.where(mask, jnp.exp(logits - m), 0.0)
+        l = p.sum(-1, keepdims=True)
+        seen = l[..., 0] > 0
+        out = jnp.einsum("bhqk,bhkd->bhqd", p / jnp.where(l > 0, l, 1.0), v)
+        lse = jnp.where(seen, m[..., 0] + jnp.log(jnp.where(l > 0, l, 1.0)
+                                                  [..., 0]), _NEG_INF)
+        return out, lse
+
+    def loss(fn):
+        def f(q, k, v, shift):
+            out, lse = fn(q, k, v, shift)
+            live = lse > _NEG_INF / 2
+            return jnp.sum(out * jnp.cos(out)) + jnp.sum(
+                jnp.where(live, lse * w, 0.0)), (out, lse)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    kernel = lambda q, k, v, shift: mha(
+        q, k, v, causal=True, causal_shift=shift, return_lse=True,
+        interpret=True, block_q=128, block_k=64)
+    (_, (out, lse)), g = loss(kernel)(q, k, v, jnp.int32(shift))
+    (_, (out_r, lse_r)), g_r = loss(dense)(q, k, v, jnp.int32(shift))
+    for a, b_ in zip((out, lse) + g, (out_r, lse_r) + g_r):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=5e-5, rtol=5e-5)
+    if shift == -256:
+        assert not np.asarray(out).any()
+        assert (np.asarray(lse) == _NEG_INF).all()
+        assert not any(np.asarray(x).any() for x in g)
+
+
+def test_sdpa_dispatch_records_the_walk(monkeypatch):
+    """Beside `path=pallas`, the dispatch books how far the causal skip
+    engages: at the train cell's shape 3 of 4 tiles."""
+    import paddle_tpu as pt
+    import paddle_tpu.nn.functional.common as C
+    import paddle_tpu.ops.pallas_ops as po
+    from paddle_tpu.framework import device
+    from paddle_tpu.observability.metrics import get_registry, reset_registry
+    from paddle_tpu.observability.telemetry import get_telemetry
+    tel = get_telemetry()
+    prev, tel.enabled = tel.enabled, True
+    reset_registry()
+    try:
+        monkeypatch.setattr(device, "on_tpu", lambda: True)
+        real_fa = po.flash_attention
+        monkeypatch.setattr(
+            po, "flash_attention",
+            lambda q, k, v, **kw: real_fa(q, k, v, **dict(kw, interpret=True)))
+        x = pt.to_tensor(np.ones((1, 1024, 1, 64), np.float32))
+        C.scaled_dot_product_attention(x, x, x, is_causal=True)
+        c = get_registry().counter("pt_flash_chunks_total",
+                                   labelnames=("state",))
+        assert (c.value(state="visited"), c.value(state="total")) == (3, 4)
+    finally:
+        reset_registry()
+        tel.enabled = prev
+
+
 class TestKernelAutotune:
     """Kernel-config autotune (ref: paddle/phi/kernels/autotune/): warmup
     timing picks a block config, the cache feeds later (traced) calls."""
@@ -219,6 +469,42 @@ class TestFlashDropout:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        atol=3e-4, rtol=3e-4)
 
+    def test_mask_is_the_elements_not_the_tiles(self):
+        """Bit for bit the murmur hash of the element's global (bh, row,
+        column), whatever tile regenerates it."""
+        from paddle_tpu.ops.pallas_ops import _tile_keep_mask
+        seed, bh, n = 0x1234567, 5, 256
+        with np.errstate(over="ignore"):
+            rows = np.arange(n, dtype=np.uint32)[:, None]
+            cols = np.arange(n, dtype=np.uint32)[None, :]
+            h = rows * np.uint32(0x193E9) + cols
+            h = h ^ np.uint32(seed) ^ (np.uint32(bh) * np.uint32(0x9E3779B1))
+            for mult in (0x85EBCA6B, 0xC2B2AE35):
+                h = h * np.uint32(mult)
+                h = h ^ (h >> np.uint32(15))
+            want = (h >> np.uint32(8)).astype(np.int64) >= int(
+                self.PD * (1 << 24))
+        for bq, bk in [(256, 256), (128, 128), (64, 256), (256, 32)]:
+            got = np.block([[np.asarray(_tile_keep_mask(
+                jnp.int32(seed), jnp.int32(bh), jnp.int32(qi), jnp.int32(ki),
+                bq, bk, self.PD)) for ki in range(n // bk)]
+                for qi in range(n // bq)])
+            assert (got == want).all(), (bq, bk)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_any_tiling_drops_the_same_elements(self, causal):
+        """Output and gradients at (128, 128) equal those at the default
+        tile to float32 round-off."""
+        q, k, v, seed = self._setup(h=1, s=512)
+        runs = [_grads_and_out(
+            lambda q, k, v: mha(q, k, v, causal=causal, dropout_p=0.4,
+                                seed=seed, interpret=True, block_q=bq,
+                                block_k=bk), q, k, v)
+            for bq, bk in [(128, 128), (None, None)]]
+        for a, b_ in zip(*runs):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=2e-5, rtol=2e-5)
+
     def test_keep_fraction_and_seed_sensitivity(self):
         from paddle_tpu.ops.pallas_ops import _tile_keep_mask
         s32 = jnp.int32(12345)
@@ -313,6 +599,26 @@ class TestVarlen:
         for a, b_ in zip(g, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        atol=3e-4, rtol=3e-4)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_a_row_shorter_than_a_chunk(self, causal):
+        """Rows of 256, 70 and 1 valid keys under (128, 128) tiles (a
+        block_k of 64 is rounded up to the lanes): the walk stops at the
+        row's last chunk and masks only that one."""
+        q, k, v = (_rand((3, 1, 256, 64), i) for i in range(3))
+        lens = np.array([256, 70, 1], np.int32)
+        valid = (jnp.arange(256)[None, :] < jnp.asarray(lens)[:, None])[
+            :, None, :, None]
+        got = _grads_and_out(
+            lambda q, k, v: valid * mha(
+                q, k, v, seq_lens=lens, causal=causal, interpret=True,
+                block_q=128, block_k=64), q, k, v)
+        want = _grads_and_out(
+            lambda q, k, v: valid * self._ref_padded(q, k, v, lens, causal),
+            q, k, v)
+        for a, b_ in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=5e-5, rtol=5e-5)
 
     @pytest.mark.slow
     def test_unpadded_api_packed_layout(self):

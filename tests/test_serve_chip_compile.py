@@ -318,3 +318,61 @@ def test_chip_compiles_the_grouped_kernel_alone(one_chip, window, pages):
     assert "tpu_custom_call" in exe.as_text()
     # the walk list and the padded rows: far from a pool
     assert exe.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+# -- the train cell's flash kernels (same file: one process loads the library) --
+
+FLASH_CALLS = [
+    dict(),                                     # the train cell's call
+    dict(causal=False),
+    dict(dropout_p=0.0),
+    dict(s=8192, b=1),                          # K and V still whole in VMEM
+    dict(s=1000, dropout_p=0.0),                # padded to the tile, masked
+    dict(dtype=jnp.float32, dropout_p=0.0),
+    dict(lens=True),
+    dict(shift=True, dropout_p=0.0),
+]
+
+
+@pytest.mark.parametrize("call", FLASH_CALLS, ids=lambda c: ",".join(
+    f"{k}={getattr(v, '__name__', v)}" for k, v in c.items()) or "train")
+def test_chip_compiles_the_flash_kernels(one_chip, call):
+    """Forward and the three gradients of `mha` at the GPT-345M train
+    shape (8, 16, 1024, 64) bf16 causal with dropout, and the calls other
+    code makes of it (ring attention's traced shift with an lse
+    cotangent, varlen, a padded and a long sequence): three Mosaic
+    kernels, named as the benchmark's readers find them, on operands of
+    (batch x heads, sequence, 128 lanes)."""
+    from paddle_tpu.ops.pallas_ops import mha
+    c = dict(dict(causal=True, dropout_p=0.1, s=1024, b=8,
+                  dtype=jnp.bfloat16, lens=False, shift=False), **call)
+    b, s, dt = c["b"], c["s"], c["dtype"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grads(q, k, v, seed, lens, shift):
+        def loss(q, k, v):
+            r = mha(q, k, v, causal=c["causal"], dropout_p=c["dropout_p"],
+                    seed=seed, interpret=False,
+                    seq_lens=lens if c["lens"] else None,
+                    causal_shift=shift if c["shift"] else None,
+                    return_lse=c["shift"])
+            if c["shift"]:
+                return r[0].astype(jnp.float32).sum() + r[1].sum()
+            return r.astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    x = sds((b, 16, s, 64), dt)
+    # the suite's float32 default precision is no bf16 kernel's (Mosaic:
+    # "Bad lhs type"); the cells run jax's own default
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(grads).lower(
+            x, x, x, sds((), jnp.float32), sds((b,), jnp.int32),
+            sds((), jnp.int32)).compile().as_text()
+    calls = re.findall(r"%(\w*flash_\w+?)_*\.\d+ = [^\n]*custom-call", text)
+    assert sorted(n.split("flash_")[1] for n in calls) == [
+        "bwd_dkv", "bwd_dq", "fwd"], calls
+    s_p = -(-s // 512) * 512
+    name = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dt]
+    assert f"{name}[{b * 16},{s_p},128]" in text
