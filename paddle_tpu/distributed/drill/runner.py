@@ -2494,26 +2494,41 @@ def run_serve_chaos_drill(root, *, max_new=8, storm_requests=6,
         except OSError as e:       # the SIGKILL resets these sockets
             out.append(("conn-error", str(e), None))
 
+    def _until_active(base, at_least, n, body, out, desc):
+        """Send ``n`` requests and wait until /healthz shows ``at_least``
+        of them decoding.  The whole batch of a model this small comes
+        and goes in a fraction of a second, between two looks of a poll
+        on a busy machine: when every request has been answered and none
+        was seen, the batch is sent again.  Returns every thread
+        started."""
+        def _start():
+            batch = [threading.Thread(target=_fire, daemon=True,
+                                      args=(base, body(i), out))
+                     for i in range(n)]
+            for t in batch:
+                t.start()
+            return batch
+
+        started = _start()
+
+        def _look():
+            _status, health = _healthz(base)
+            if (health.get("active_sequences", 0) or 0) >= at_least:
+                return True
+            if not any(t.is_alive() for t in started):
+                started.extend(_start())
+            return None
+
+        wait_until(_look, gen_timeout / 4, desc=desc, max_delay=0.05)
+        return started
+
+    def _long(i):
+        return {"tokens": prompts[i % len(prompts)], "max_new_tokens": 48}
+
     doomed = []
-    threads = [
-        threading.Thread(
-            target=_fire, daemon=True,
-            args=(base1,
-                  {"tokens": prompts[i % len(prompts)],
-                   "max_new_tokens": 48},
-                  doomed))
-        for i in range(6)
-    ]
-    for t in threads:
-        t.start()
-
-    def _busy():
-        _status, health = _healthz(base1)
-        snap = health.get("active_sequences", 0) or 0
-        return True if snap > 0 else None
-
-    wait_until(_busy, gen_timeout / 4,
-               desc="generation 1 to show active decode sequences")
+    threads = _until_active(
+        base1, 1, 6, _long, doomed,
+        "generation 1 to show active decode sequences")
     p1.kill()
     rc1 = p1.wait(timeout=30)
     _LIVE.discard(p1)
@@ -2607,25 +2622,9 @@ def run_serve_chaos_drill(root, *, max_new=8, storm_requests=6,
         # model can otherwise finish before the first check
         import socket as _socket
         blocked = []
-        blockers = [
-            threading.Thread(
-                target=_fire, daemon=True,
-                args=(base2,
-                      {"tokens": prompts[i % len(prompts)],
-                       "max_new_tokens": 48},
-                      blocked))
-            for i in range(4)
-        ]
-        for t in blockers:
-            t.start()
-
-        def _batch_busy():
-            _s, health = _healthz(base2)
-            return True if (health.get("active_sequences", 0) or 0) \
-                >= 2 else None
-
-        wait_until(_batch_busy, gen_timeout / 4,
-                   desc="blocker requests to fill the decode batch")
+        blockers = _until_active(
+            base2, 2, 4, _long, blocked,
+            "blocker requests to fill the decode batch")
         payload = json.dumps({"tokens": prompts[2],
                               "max_new_tokens": 48}).encode()
         for _ in range(3):          # three callers walk away mid-decode

@@ -74,18 +74,22 @@ from jax.experimental.pallas import tpu as pltpu
 from ..framework import device as _device
 from .pallas_ops import _LANES, _NEG_INF, _interpret_default
 
-__all__ = ["paged_attention", "paged_attention_reference", "walk_pages",
+__all__ = ["paged_attention", "paged_attention_reference",
+           "paged_attention_diff", "paged_attention_diff_reference",
+           "walk_pages",
            "chunk_pages", "chunk_walk", "chunks_of",
            "paged_attention_int8", "paged_attention_int8_reference",
            "tune_paged_attention_int8"]
 
 
 def paged_attention_reference(q, k_pool, v_pool, page_tables, lengths,
-                              *, layer, sm_scale=None, window=None):
+                              *, layer, sm_scale=None, window=None,
+                              out_dtype=None):
     """XLA reference: gather the page window, masked softmax attention.
 
     f32 scores/accumulation regardless of operand dtype (the MXU
-    contract from :mod:`.pallas_ops`); output in ``q.dtype``.  A pool
+    contract from :mod:`.pallas_ops`); output in ``q.dtype``, or
+    ``out_dtype``.  A pool
     row of fewer heads than q's is grouped (query head ``j`` reads KV
     head ``j // G``); ``window`` keeps a row's last ``window`` positions.
     """
@@ -107,7 +111,7 @@ def paged_attention_reference(q, k_pool, v_pool, page_tables, lengths,
         w = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgc,bckd->bkgd", w.astype(v_ctx.dtype), v_ctx,
                        preferred_element_type=jnp.float32)
-        return o.reshape(b, h, d).astype(q.dtype)
+        return o.reshape(b, h, d).astype(out_dtype or q.dtype)
     # (B, max_pages, ps, H*D) -> (B, C, H, D); position t sits at
     # context index t because pages fill in order
     k_ctx = k_pool[layer, page_tables].reshape(b, -1, h, d)
@@ -350,7 +354,7 @@ def _gqa_kernel(rows_ref, pages_ref, slots_ref, len_ref, first_ref,
 
 def _paged_attention_gqa_pallas(q, k_pool, v_pool, page_tables, lengths,
                                 *, layer, sm_scale, window, steps,
-                                interpret):
+                                interpret, out_dtype=None):
     b, h, d = q.shape
     ps = k_pool.shape[2]
     kvh = k_pool.shape[3] // d
@@ -389,7 +393,7 @@ def _paged_attention_gqa_pallas(q, k_pool, v_pool, page_tables, lengths,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, gp, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, gp, d), out_dtype or q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_attention_window" if window else "paged_attention_gqa",
@@ -586,7 +590,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, page_tables, lengths, layer,
 
 def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
                     sm_scale=None, window=None, steps=None,
-                    use_pallas=None, interpret=None):
+                    use_pallas=None, interpret=None, out_dtype=None):
     """Dispatching entry: the Pallas paged-attention kernel on TPU, the
     XLA gather+softmax reference elsewhere.  Both read layer ``layer``
     of the whole ``(L, P, ps, H*D)`` pools.
@@ -597,6 +601,8 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
     the pages the batch walks (the allocator's: no two rows share a
     page, so at most the pool's usable pages plus one a row), ``batch *
     walk_pages`` when not given; either kernel's grid is made from it.
+    ``out_dtype`` (the grouped kernel and the reference) is the result's
+    dtype where ``q.dtype`` is too coarse for what follows.
 
     Off-TPU the default is the reference (interpret-mode Pallas is a
     correctness vehicle, not a fast path); pass ``use_pallas=True`` to
@@ -620,7 +626,9 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
             return _paged_attention_gqa_pallas(
                 q, k_pool, v_pool, page_tables, lengths, layer=layer,
                 sm_scale=sm_scale, window=window or 0, steps=steps,
-                interpret=interpret)
+                interpret=interpret, out_dtype=out_dtype)
+        if out_dtype is not None:
+            raise ValueError("out_dtype: the grouped kernel's only")
         return _paged_attention_pallas(
             q, k_pool, v_pool, page_tables, lengths, layer,
             sm_scale=sm_scale, chunk=walk[0] // k_pool.shape[2],
@@ -628,7 +636,68 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
     record_dispatch("paged_attention", "fallback")
     return paged_attention_reference(q, k_pool, v_pool, page_tables,
                                      lengths, layer=layer,
-                                     sm_scale=sm_scale, window=window)
+                                     sm_scale=sm_scale, window=window,
+                                     out_dtype=out_dtype)
+
+
+# ---------------------------------------------------------------------------
+# differential attention: a K head of D lanes against a V of 2D
+# ---------------------------------------------------------------------------
+# Heads in order on the pool's lanes (arXiv:2410.05258 as Phi-4-mini-flash
+# lays it out): K heads 2m and 2m+1 are the pair (k1_m, k2_m), V heads 2m
+# and 2m+1 side by side one V_m of 2D lanes, and the four query heads
+# 4m .. 4m+3 read KV pair m: head h a plain softmax over K head
+# ``2m + h % 2``, weighing V_m.  A KV pair is therefore one "head" of 2D
+# lanes to the grouped kernel above, with four query heads as its rows,
+# once each query head is laid on the D lanes of the K head it reads and
+# zeros on the other D: the contraction over 2D lanes then meets only
+# that head.  No kernel of its own, and each K and V page is fetched once
+# a KV pair, from where it lies.
+
+def _diff_queries(q):
+    """q (B, H, D) -> (B, H, 2D): head h on lanes ``(h % 2) * D ..``, zeros
+    on the other half."""
+    b, h, d = q.shape
+    qq = q.reshape(b, h // 2, 2, d)
+    zero = jnp.zeros((b, h // 2, d), q.dtype)
+    return jnp.stack([jnp.concatenate([qq[:, :, 0], zero], axis=-1),
+                      jnp.concatenate([zero, qq[:, :, 1]], axis=-1)],
+                     axis=2).reshape(b, h, 2 * d)
+
+
+def paged_attention_diff(q, k_pool, v_pool, page_tables, lengths, *, layer,
+                         window=None, steps=None, use_pallas=None,
+                         interpret=None):
+    """A_h of differential attention for one query token a row: q (B, H,
+    D) against pools of ``H / 2`` heads of D lanes; returns (B, H, 2D)
+    float32 (the two halves of a pair are subtracted next, so the
+    kernel's result is not rounded to the pool's dtype first).  Runs
+    :func:`paged_attention` on pairs of 2D lanes (comment above)."""
+    return paged_attention(
+        _diff_queries(q), k_pool, v_pool, page_tables, lengths, layer=layer,
+        sm_scale=1.0 / math.sqrt(q.shape[-1]), window=window, steps=steps,
+        use_pallas=use_pallas, interpret=interpret, out_dtype=jnp.float32)
+
+
+def paged_attention_diff_reference(q, k_pool, v_pool, page_tables, lengths,
+                                   *, layer, window=None):
+    """XLA twin of :func:`paged_attention_diff`, written from the layer's
+    definition: every query head against its own K head of D lanes."""
+    b, h, d = q.shape
+    k_ctx = k_pool[layer, page_tables].reshape(b, -1, h // 2, d)
+    v_ctx = v_pool[layer, page_tables].reshape(b, -1, h // 4, 2 * d)
+    heads = jnp.arange(h)
+    s = jnp.einsum("bhd,bchd->bhc", q, k_ctx[:, :, 2 * (heads // 4)
+                                             + heads % 2],
+                   preferred_element_type=jnp.float32) / math.sqrt(d)
+    pos = jnp.arange(k_ctx.shape[1], dtype=jnp.int32)[None, :]
+    mask = pos < lengths[:, None]
+    if window:
+        mask &= pos >= lengths[:, None] - window
+    w = jax.nn.softmax(jnp.where(mask[:, None, :], s, _NEG_INF), axis=-1)
+    return jnp.einsum("bhc,bchd->bhd", w.astype(v_ctx.dtype),
+                      v_ctx[:, :, heads // 4],
+                      preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------

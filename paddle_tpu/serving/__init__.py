@@ -18,7 +18,10 @@ Quick start::
     ServeHTTPServer(load_engine("/tmp/m")).start()
 
 Module map: :mod:`.model` (pure serve-side decoder fns over paged KV),
-:mod:`.kv_cache` (block-pool page allocator + admission reservations),
+:mod:`.experts` (routed SwiGLU experts), :mod:`.ssm` (selective
+state-space mixer: the scan over a prompt, the one-token update),
+:mod:`.kv_cache` (block-pool page allocator, state slots + admission
+reservations),
 :mod:`.engine` (AOT program ladder, compile sentinel, weight swap),
 :mod:`.scheduler` (continuous batching), :mod:`.http` (front end).
 """

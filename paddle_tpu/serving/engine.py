@@ -207,7 +207,8 @@ class ServeConfig:
             raise ValueError(
                 f"precision {self.precision!r} not in {PRECISIONS}")
         if self.precision == "int8" and (
-                spec.ffn == "moe" or spec.window_layers
+                spec.ffn != "gelu" or spec.diff_attn
+                or len(spec.global_layers) != spec.layers
                 or spec.n_kv_heads != spec.heads):
             raise ValueError(
                 "int8 serving covers equal heads, full layers and the "
@@ -260,18 +261,24 @@ class ServingEngine:
                 self.max_pages_per_seq,
                 -(-spec.window // self.config.page_size) + 1) \
                 if n_window else 0
+            # the state-space layers' slots: one a row of the largest
+            # bucket, and the null slot
             self.pool = PagePool(
-                layers=spec.layers - n_window,
+                layers=len(spec.global_layers),
                 pages=kv_page_budget(self.config.kv_pages, prec,
                                      spec.head_dim, spec.n_kv_heads),
                 page_size=self.config.page_size, heads=spec.n_kv_heads,
                 head_dim=spec.head_dim, dtype=kv_dtype,
                 scale_pages=(prec == "int8"), window_layers=n_window,
-                window_pages=window_pages, window=spec.window)
+                window_pages=window_pages, window=spec.window,
+                state_layers=len(spec.ssm_layers),
+                state_slots=1 + self.config.decode_buckets[-1],
+                state_shape=(spec.ssm_inner, spec.ssm_state,
+                             spec.ssm_conv))
             # the page table the programs take: one row of pages, or the
-            # full layers' row above the sliding layers'
-            self.table_shape = ((2, self.max_pages_per_seq) if n_window
-                                else (self.max_pages_per_seq,))
+            # full layers' row above the sliding layers' (above the row
+            # that holds the state slot)
+            self.table_shape = self.pool.table_shape(self.max_pages_per_seq)
             self._aux = None    # the last call's expert counts (L, E)
             self._params = _to_serve_device(self._prepare_params(params))
             self._weights_step = weights_step
@@ -343,7 +350,32 @@ class ServingEngine:
         # the two jitted functions are named like the programs they build
         # (serve_prefill_s<S> / serve_decode_b<B>), so jax's compile log,
         # the compile watcher and profiler traces all say "serve_*"
-        if int8:
+        if self.pool.state_slots is not None:
+            # every pool the model has is donated state, in the order of
+            # PagePool.state(): the full layers', the sliding layers'
+            # where there are any, the state-space layers' two last
+            names = ("k_pool", "v_pool") + (
+                ("kw_pool", "vw_pool") if self.pool.window_pool else ()
+            ) + ("conv_pool", "ssm_pool")
+            n_state = len(names)
+
+            def serve_prefill(params, *args):
+                k_pool, v_pool, *rest = args[:n_state]
+                return prefill_step(spec, params, k_pool, v_pool,
+                                    *args[n_state:], page_size=ps,
+                                    **dict(zip(names[2:], rest)))
+
+            def serve_decode(params, *args):
+                k_pool, v_pool, *rest = args[:n_state]
+                return decode_step(spec, params, k_pool, v_pool,
+                                   *args[n_state:], page_size=ps,
+                                   **dict(zip(names[2:], rest)))
+
+            donate = tuple(range(1, 1 + n_state))
+            labels = ("params",) + names + ("tokens", "positions",
+                                            "page_tables")
+            kv_args = tuple(_struct_like(a) for a in self.pool.state())
+        elif int8:
             # the scale pools are donated state exactly like the value
             # pools — the step rewrites both and the engine rebinds all
             # four (donate_argnums covers 1..4)

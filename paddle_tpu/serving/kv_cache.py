@@ -24,6 +24,16 @@ holds more than ``window`` tokens plus one page there, whatever its
 context.  Both page tables are indexed by logical page
 (``position // ps``); a returned page's entry is the null page again.
 
+A third kind of holding (``state_layers`` > 0): a model's state-space
+layers keep, a sequence, a state that does not grow — ``pool.state_slots``,
+a :class:`StateSlots` of the layers' convolution tails and SSM states
+with one **slot** a running row.  A row's slot rides in a third row of
+its page table (``table[2, 0]``), so the programs' arguments stay a
+table a row; admission takes a slot beside the pages or refuses
+(:attr:`PagePool.last_refusal` says which kind was short), release gives
+it back, and its contents are never read again: the next prefill writes
+the slot whole.  Slot 0 is the null slot of padding rows.
+
 Page 0 is reserved as the **null page**: padding rows of a batch
 bucket and the unused tail of every page table point at it, so the
 programs' scatter/gather of padding lanes touch real (never-read)
@@ -43,8 +53,8 @@ from typing import Dict, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagePool", "RowPages", "KVPoolExhausted", "NULL_PAGE",
-           "kv_page_budget", "pool_shapes"]
+__all__ = ["PagePool", "RowPages", "StateSlots", "KVPoolExhausted",
+           "NULL_PAGE", "kv_page_budget", "pool_shapes"]
 
 NULL_PAGE = 0
 
@@ -90,6 +100,87 @@ class KVPoolExhausted(RuntimeError):
     """Raised when an alloc/reserve exceeds pool headroom."""
 
 
+class StateSlots:
+    """The recurrent state of a model's state-space layers, a slot a
+    running row: ``conv`` ``(Ls, slots, d_conv - 1, N)`` (each layer's
+    last inputs, in the cache's dtype) and ``ssm`` ``(Ls, slots, R, N)``
+    float32 (channels on the lanes: :mod:`.ssm`).  Slot 0 is the null
+    slot; a LIFO free list of the others, lock-guarded like the pages."""
+
+    def __init__(self, *, layers: int, slots: int, inner: int, state: int,
+                 conv: int, dtype=jnp.float32):
+        if slots < 2:
+            raise ValueError("slots must be >= 2 (slot 0 is the null slot)")
+        self.layers, self.slots = layers, slots
+        self.conv = jnp.zeros((layers, slots, conv - 1, inner), dtype)
+        self.ssm = jnp.zeros((layers, slots, state, inner), jnp.float32)
+        self._lock = threading.Lock()
+        self._free: List[int] = list(range(slots - 1, 0, -1))
+        self.stats = {"takes": 0, "releases": 0, "refusals": 0,
+                      "high_watermark": 0}
+
+    @property
+    def held(self) -> int:
+        with self._lock:
+            return self.slots - 1 - len(self._free)
+
+    def take(self) -> Optional[int]:
+        """A free slot, or None (counted as a refusal)."""
+        with self._lock:
+            if not self._free:
+                self.stats["refusals"] += 1
+                return None
+            slot = self._free.pop()
+            self.stats["takes"] += 1
+            self.stats["high_watermark"] = max(
+                self.stats["high_watermark"],
+                self.slots - 1 - len(self._free))
+        self._gauges()
+        return slot
+
+    def release(self, slot: int) -> None:
+        with self._lock:
+            if not 0 < slot < self.slots:
+                raise ValueError(f"slot {slot} out of range")
+            if slot in self._free:
+                raise ValueError(f"double release of slot {slot}")
+            self._free.append(slot)
+            self.stats["releases"] += 1
+        self._gauges()
+
+    def check_consistency(self, expect_all_free: bool = False) -> None:
+        with self._lock:
+            assert len(set(self._free)) == len(self._free), "dup free slots"
+            assert all(0 < s < self.slots for s in self._free)
+            if expect_all_free:
+                assert len(self._free) == self.slots - 1, \
+                    (f"slot leak: {self.slots - 1 - len(self._free)} of "
+                     f"{self.slots - 1} state slots unaccounted for")
+
+    def snapshot(self) -> Dict[str, int]:
+        with self._lock:
+            free = len(self._free)
+        return {"slots": self.slots, "held": self.slots - 1 - free,
+                "free": free, **self.stats}
+
+    def _gauges(self) -> None:
+        try:
+            from ..observability.metrics import get_registry
+            from ..observability.telemetry import get_telemetry
+            if not get_telemetry().enabled:
+                return
+            snap = self.snapshot()
+            g = get_registry().gauge(
+                "pt_serve_state_slots",
+                "Serve state slots (state-space layers) by state",
+                labelnames=("state",))
+            g.set(snap["held"], state="used")
+            g.set(snap["free"], state="free")
+            g.set(snap["high_watermark"], state="high_watermark")
+        except Exception:
+            pass
+
+
 class PagePool:
     """Block-pool allocator over the serve KV arrays (``k_pool`` /
     ``v_pool`` ``(L, P, ps, H*D)``, and ``k_scale`` / ``v_scale``
@@ -103,11 +194,25 @@ class PagePool:
                  heads: int, head_dim: int, dtype=jnp.float32,
                  scale_pages: bool = False, window_layers: int = 0,
                  window_pages: int = 0, window: int = 0,
-                 kind: str = "global"):
+                 kind: str = "global", state_layers: int = 0,
+                 state_slots: int = 0, state_shape=(0, 0, 0)):
         if pages < 2:
             raise ValueError("pages must be >= 2 (page 0 is the null page)")
         self.kind = kind            # "global" | "window": names the gauges
         self.window = int(window)
+        # the state-space layers' slots: ``state_shape`` is (inner
+        # channels, state size, convolution width)
+        self.state_slots: Optional[StateSlots] = None
+        if state_layers:
+            if scale_pages:
+                raise ValueError("an int8 pool has no state-space layers")
+            inner, state, conv = state_shape
+            self.state_slots = StateSlots(
+                layers=state_layers, slots=state_slots, inner=inner,
+                state=state, conv=conv, dtype=dtype)
+        # which kind of holding the last refused admission lacked:
+        # "kv" (pages of either pool) or "state" (a slot)
+        self.last_refusal: Optional[str] = None
         # the sliding layers' pool: same page size and row, its own
         # arrays, free list and reservations
         self.window_pool: Optional[PagePool] = None
@@ -281,20 +386,33 @@ class PagePool:
 
     def admit_row(self, prompt_len: int, max_new_tokens: int,
                   max_pages: int) -> Optional["RowPages"]:
-        """Reserve a sequence's worst case in both kinds of layer and
-        allocate its prompt's pages; None (nothing taken) when either
-        pool lacks the headroom."""
+        """Reserve a sequence's worst case in both kinds of layer, take
+        its state slot where the model has state-space layers, and
+        allocate its prompt's pages; None (nothing taken, and
+        ``last_refusal`` says which kind was short) when any lacks the
+        headroom."""
         total = int(prompt_len) + int(max_new_tokens)
         worst = self.pages_needed(total)
         worst_w = (self.window_pool.window_pages_needed(total)
                    if self.window_pool else 0)
+        self.last_refusal = "kv"
         if not self.can_admit(worst, worst_w):
             return None
         try:
             self.reserve(worst, worst_w)
         except KVPoolExhausted:
             return None
-        return RowPages(self, prompt_len, worst, worst_w, max_pages)
+        slot = None
+        if self.state_slots is not None:
+            slot = self.state_slots.take()
+            if slot is None:
+                self.last_refusal = "state"
+                self.release_reservation(worst)
+                if worst_w:
+                    self.window_pool.release_reservation(worst_w)
+                return None
+        self.last_refusal = None
+        return RowPages(self, prompt_len, worst, worst_w, max_pages, slot)
 
     def check_consistency(self, expect_all_free: bool = False) -> None:
         """Invariant check used by tests and the serve chaos drills:
@@ -318,15 +436,22 @@ class PagePool:
             assert self.window_pool.stats["row_pages_max"] <= \
                 self.window_pool.window_pages_needed(1 << 62), \
                 "a row held more than its window plus a page"
+        if self.state_slots is not None:
+            self.state_slots.check_consistency(expect_all_free)
 
     # -- device state -------------------------------------------------------
 
     def swap(self, k_pool, v_pool, *rest) -> None:
         """Rebind the pools to a program's donated outputs, in program
         order: the value pools, then the scale pools of a quantized
-        pool, or the sliding layers' two pools."""
+        pool, or the sliding layers' two pools, then the state-space
+        layers' two."""
         self.k_pool = k_pool
         self.v_pool = v_pool
+        if self.state_slots is not None:
+            if len(rest) < 2:
+                raise ValueError("swap requires the state pools")
+            *rest, self.state_slots.conv, self.state_slots.ssm = rest
         if self.scale_pages:
             if len(rest) != 2 or rest[0] is None or rest[1] is None:
                 raise ValueError(
@@ -341,12 +466,14 @@ class PagePool:
     def state(self):
         """The donated arrays in program argument order (see
         :meth:`swap`)."""
+        state = (self.k_pool, self.v_pool)
         if self.scale_pages:
-            return (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
+            state += (self.k_scale, self.v_scale)
         if self.window_pool is not None:
-            return (self.k_pool, self.v_pool, self.window_pool.k_pool,
-                    self.window_pool.v_pool)
-        return (self.k_pool, self.v_pool)
+            state += (self.window_pool.k_pool, self.window_pool.v_pool)
+        if self.state_slots is not None:
+            state += (self.state_slots.conv, self.state_slots.ssm)
+        return state
 
     def utilization(self) -> float:
         with self._lock:
@@ -369,6 +496,8 @@ class PagePool:
                 **self.stats,
                 **({"window": self.window_pool.snapshot()}
                    if self.window_pool is not None else {}),
+                **({"state": self.state_slots.snapshot()}
+                   if self.state_slots is not None else {}),
             }
 
     # -- observability ------------------------------------------------------
@@ -428,7 +557,18 @@ class PagePool:
         if self.scale_pages:
             named["kv::k_scales"] = self.k_scale
             named["kv::v_scales"] = self.v_scale
+        if self.state_slots is not None:
+            named["kv::ssm_conv_state"] = self.state_slots.conv
+            named["kv::ssm_state"] = self.state_slots.ssm
         return named
+
+    def table_shape(self, max_pages: int):
+        """Shape of one row's page table as the programs take it: a row
+        of the table a kind of holding (class docstring of
+        :class:`RowPages`)."""
+        if self.state_slots is not None:
+            return (3, max_pages)
+        return (2, max_pages) if self.window_pool else (max_pages,)
 
     def null_padded_table(self, page_ids: Sequence[int],
                           max_pages: int) -> np.ndarray:
@@ -442,16 +582,20 @@ class PagePool:
 
 
 class RowPages:
-    """One sequence's pages in both kinds of layer, and the page table
-    the programs take: ``(max_pages,)`` of the full layers' pages, or
-    ``(2, max_pages)`` with the sliding layers' below them.  Built by
+    """One sequence's pages in both kinds of layer and its state slot,
+    and the page table the programs take: ``(max_pages,)`` of the full
+    layers' pages, or ``(2, max_pages)`` with the sliding layers' below
+    them, or ``(3, max_pages)`` with the slot first in a third row.
+    Built by
     :meth:`PagePool.admit_row` with the worst case reserved; the owner
     calls :meth:`advance` before the step that writes position ``pos``
     and :meth:`release` when the sequence leaves, however it leaves."""
 
     def __init__(self, pool: PagePool, prompt_len: int, reserved: int,
-                 reserved_window: int, max_pages: int):
+                 reserved_window: int, max_pages: int,
+                 slot: Optional[int] = None):
         self.pool = pool
+        self.slot = slot
         wpool = pool.window_pool
         self.page_ids: List[int] = pool.alloc(
             pool.pages_needed(prompt_len), reserved=True)
@@ -459,9 +603,11 @@ class RowPages:
         self.window_ids: Dict[int, int] = {}    # logical page -> page id
         self._window_span = None    # (first, last) logical pages held
         self.window_reserved_left = reserved_window
-        self.table = np.full((2, max_pages) if wpool else (max_pages,),
-                             NULL_PAGE, np.int32)
-        row = self.table[0] if wpool else self.table
+        self.table = np.full(pool.table_shape(max_pages), NULL_PAGE,
+                             np.int32)
+        row = self.table[0] if self.table.ndim == 2 else self.table
+        if slot is not None:
+            self.table[2, 0] = slot
         if len(self.page_ids) > max_pages:
             self.release()
             raise ValueError(f"{len(self.page_ids)} pages exceed table "
@@ -500,7 +646,7 @@ class RowPages:
         page = pos // pool.page_size
         if page >= len(self.page_ids):
             new = pool.alloc(page + 1 - len(self.page_ids), reserved=True)
-            row = self.table[0] if pool.window_pool else self.table
+            row = self.table[0] if self.table.ndim == 2 else self.table
             row[len(self.page_ids):len(self.page_ids) + len(new)] = new
             self.page_ids += new
             self.reserved_left -= len(new)
@@ -514,8 +660,11 @@ class RowPages:
 
     def release(self) -> None:
         """Everything back: pages to the free lists, what is left of the
-        reservations released.  Safe to call twice."""
+        reservations released, the state slot.  Safe to call twice."""
         pool = self.pool
+        if self.slot is not None:
+            pool.state_slots.release(self.slot)
+            self.slot = None
         pool.free(self.page_ids)
         pool.release_reservation(self.reserved_left)
         self.page_ids, self.reserved_left = [], 0

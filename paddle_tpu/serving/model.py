@@ -26,9 +26,12 @@ Device-trace scopes: both steps run under ``jax.named_scope`` — ``embed``,
 per layer ``layer<i>/attn_qkv``, ``layer<i>/kv_write`` (the pool
 ``.at[i, page, slot].set`` and the int8 scale writes), ``layer<i>/attn``
 (``layer<i>/attn_window`` / ``layer<i>/attn_global`` in a model that has
-sliding layers), ``layer<i>/attn_out``, ``layer<i>/mlp`` (or
-``layer<i>/moe_route`` and ``layer<i>/moe_experts``), then ``lm_head``
-and ``sample``.
+sliding layers; ``layer<i>/attn_cross`` in a layer that reads another
+layer's pages), ``layer<i>/attn_out``, ``layer<i>/mlp`` (or
+``layer<i>/moe_route`` and ``layer<i>/moe_experts``); a state-space layer
+is ``layer<i>/ssm`` (projections, convolution, the scan or the one-token
+update, gate) and ``layer<i>/state_write`` (its rows' slots), a gated
+memory unit ``layer<i>/gmu``; then ``lm_head`` and ``sample``.
 The kernel takes the pools whole, so nothing stands between the write
 and the read: the ``kv_read`` scope of earlier versions has no operation
 left and is gone.  Prefill writes each layer's K/V as whole pages under
@@ -47,9 +50,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.paged_attention import (chunk_walk, paged_attention,
+                                   paged_attention_diff,
                                    paged_attention_int8)
 from ..ops.quant_kernels import quantize_kv, w8a16_matmul
 from . import experts as _experts
+from . import ssm as _ssm
 
 __all__ = ["ModelSpec", "init_params", "prefill_step", "decode_step",
            "QUANT_WEIGHT_NAMES"]
@@ -80,6 +85,9 @@ def _matmul(params, name, x, tap=None):
     return x @ params[name]
 
 
+LAYER_KINDS = ("full", "sliding", "ssm", "gmu", "cross")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelSpec:
     """Architecture of a served decoder: one block, driven by these
@@ -89,15 +97,27 @@ class ModelSpec:
 
     ``kv_heads`` / ``head_size``: 0 means ``heads`` / ``hidden // heads``.
     ``norm``: ``layer`` (with bias) or ``rms``.  ``positions``:
-    ``learned`` (a table of ``max_seq_len``) or ``rotary`` (rotate-half
-    pairing, base ``rope_theta``).  ``layer_types``: per layer ``full``
-    or ``sliding`` (empty: all full); a sliding layer sees the last
-    ``window`` positions.  ``yarn_factor`` > 0 rescales the rotary
-    frequencies of the *full* layers (YaRN: ``yarn_original_len``,
+    ``learned`` (a table of ``max_seq_len``), ``rotary`` (rotate-half
+    pairing, base ``rope_theta``) or ``none``.  ``layer_types``: per
+    layer its mixer (empty: all full) — ``full`` or ``sliding`` attention
+    (a sliding layer sees the last ``window`` positions), ``ssm`` (a
+    selective state-space layer, :mod:`.ssm`: ``ssm_inner`` channels,
+    ``ssm_state``, ``ssm_conv``, ``ssm_dt_rank``), ``gmu`` (a gated memory
+    unit on the output ``y`` of the nearest ``ssm`` layer before it; no
+    state of its own) or ``cross`` (attention with a query of its own
+    over the K and V of the nearest ``full`` layer before it; no cache of
+    its own).  ``gmu`` and ``cross`` layers come last, after a ``full``
+    layer: a prompt's prefill runs them, and that layer's query side, on
+    its last position only, since nothing later reads them elsewhere.
+    ``attn_bias``: biases on the attention projections.  ``diff_attn``:
+    differential attention (arXiv:2410.05258; :func:`_differential`).
+    ``yarn_factor`` > 0 rescales the rotary frequencies of the *full*
+    layers (YaRN: ``yarn_original_len``,
     ``yarn_beta_fast`` / ``_slow``, and ``yarn_attention_factor`` on cos
     and sin, 0 meaning ``0.1 ln(factor) + 1``), at every length; sliding
-    layers keep the plain frequencies.  ``ffn``: ``gelu`` or ``moe``
-    (``experts`` routed SwiGLU experts of ``expert_width``,
+    layers keep the plain frequencies.  ``ffn``: ``gelu``, ``swiglu``
+    (gate and up in one matrix of ``2 * ffn_mult * hidden``, no biases)
+    or ``moe`` (``experts`` routed SwiGLU experts of ``expert_width``,
     ``experts_per_token`` a token, no drops: :mod:`.experts`, which
     picks its regime by the program's row count).  ``tie_head``: logits
     against the embedding, or an own ``head`` matrix.
@@ -127,6 +147,12 @@ class ModelSpec:
     experts_per_token: int = 0
     expert_width: int = 0
     tie_head: bool = True
+    attn_bias: bool = False
+    diff_attn: bool = False
+    ssm_inner: int = 0
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -146,37 +172,80 @@ class ModelSpec:
                              f"kv_heads={self.n_kv_heads}")
         for name, value, allowed in (
                 ("norm", self.norm, ("layer", "rms")),
-                ("positions", self.positions, ("learned", "rotary")),
-                ("ffn", self.ffn, ("gelu", "moe"))):
+                ("positions", self.positions, ("learned", "rotary",
+                                               "none")),
+                ("ffn", self.ffn, ("gelu", "swiglu", "moe"))):
             if value not in allowed:
                 raise ValueError(f"{name}={value!r} not in {allowed}")
         if self.layer_types:
             if len(self.layer_types) != self.layers or \
-                    set(self.layer_types) - {"full", "sliding"}:
+                    set(self.layer_types) - set(LAYER_KINDS):
                 raise ValueError(
-                    f"layer_types must name {self.layers} layers, each "
-                    f"'full' or 'sliding': {self.layer_types}")
+                    f"layer_types must name {self.layers} layers, each of "
+                    f"{LAYER_KINDS}: {self.layer_types}")
             if "sliding" in self.layer_types and self.window < 1:
                 raise ValueError("sliding layers need window >= 1")
+            if self.ssm_layers and not (
+                    self.ssm_inner > 0 and self.ssm_dt_rank > 0
+                    and self.ssm_state > 0 and self.ssm_conv > 1):
+                raise ValueError("ssm layers need ssm_inner, ssm_state, "
+                                 "ssm_conv and ssm_dt_rank")
+            tail = self.tail_start
+            if set(self.layer_types[:tail]) & {"gmu", "cross"} or (
+                    tail < self.layers and (
+                        tail == 0 or self.layer_types[tail - 1] != "full"
+                        or ("gmu" in self.layer_types[tail:]
+                            and not self.ssm_layers))):
+                raise ValueError(
+                    "gmu and cross layers come last, after a full layer "
+                    f"(and a gmu after an ssm layer): {self.layer_types}")
+        if self.diff_attn and (self.heads % 4 or self.n_kv_heads % 2
+                               or self.heads != 2 * self.n_kv_heads):
+            raise ValueError("diff_attn pairs the query heads and the KV "
+                             "heads, two query pairs a KV pair")
         if self.ffn == "moe" and not (
                 0 < self.experts_per_token <= self.experts
                 and self.expert_width > 0):
             raise ValueError("ffn='moe' needs experts, experts_per_token "
                              "and expert_width")
 
+    def layer_kind(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else "full"
+
     def layer_window(self, i: int) -> int:
-        """Layer ``i``'s window in positions, 0 for a full layer."""
-        sliding = bool(self.layer_types) and self.layer_types[i] == "sliding"
-        return self.window if sliding else 0
+        """Layer ``i``'s window in positions, 0 for any other kind."""
+        return self.window if self.layer_kind(i) == "sliding" else 0
+
+    def _of_kind(self, kind: str) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.layers)
+                     if self.layer_kind(i) == kind)
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i in range(self.layers) if self.layer_window(i))
+        return self._of_kind("sliding")
 
     @property
     def global_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i in range(self.layers)
-                     if not self.layer_window(i))
+        """The layers that own pages of the full layers' pool."""
+        return self._of_kind("full")
+
+    @property
+    def ssm_layers(self) -> Tuple[int, ...]:
+        """The layers that own a place in a row's state slot."""
+        return self._of_kind("ssm")
+
+    @property
+    def cross_layers(self) -> Tuple[int, ...]:
+        return self._of_kind("cross")
+
+    @property
+    def tail_start(self) -> int:
+        """First layer of the trailing run that keeps nothing from token
+        to token (``gmu`` / ``cross``); ``layers`` where there is none."""
+        i = self.layers
+        while i and self.layer_kind(i - 1) in ("gmu", "cross"):
+            i -= 1
+        return i
 
     def to_dict(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
@@ -216,15 +285,58 @@ def init_params(spec: ModelSpec, seed: int = 0,
         if spec.norm == "layer":
             p[name + ".b"] = jnp.zeros((spec.hidden,), dtype)
 
+    def _attention(i, k, own_kv):
+        """Layer ``i``'s attention weights; a cross layer has no K and V
+        of its own.  Differential attention's output is a pair of V heads
+        a pair of query heads: as wide as q."""
+        names = [("q", hd)] + [("k", kvd), ("v", kvd)] * own_kv
+        for j, (n, width) in enumerate(names):
+            p[f"h{i}.attn.w{n}"] = _w(k[j], (spec.hidden, width))
+        p[f"h{i}.attn.wo"] = _w(k[3], (hd, spec.hidden))
+        if spec.attn_bias:
+            for n, width in names + [("o", spec.hidden)]:
+                p[f"h{i}.attn.b{n}"] = jnp.zeros((width,), dtype)
+        if spec.diff_attn:
+            for j, n in enumerate(("lq1", "lk1", "lq2", "lk2")):
+                p[f"h{i}.attn.{n}"] = _w(_extra(i, 3 + j),
+                                         (spec.head_dim,), 0.1)
+            p[f"h{i}.attn.subln.w"] = jnp.ones((2 * spec.head_dim,), dtype)
+
+    def _ssm(i, k):
+        """A selective state-space layer (:mod:`.ssm`), the published
+        initialisers: ``A = -(1 .. d_state)`` a channel, ``D = 1``, and a
+        bias that starts delta log-uniform in [0.001, 0.1]."""
+        n, r, rank = spec.ssm_inner, spec.ssm_state, spec.ssm_dt_rank
+        p[f"h{i}.ssm.win"] = _w(k[0], (spec.hidden, 2 * n))
+        p[f"h{i}.ssm.conv.w"] = _w(k[1], (spec.ssm_conv, n), 0.2)
+        p[f"h{i}.ssm.conv.b"] = jnp.zeros((n,), dtype)
+        p[f"h{i}.ssm.wx"] = _w(k[2], (n, rank + 2 * r))
+        p[f"h{i}.ssm.wdt"] = _w(k[3], (rank, n))
+        dt = jnp.exp(jax.random.uniform(
+            _extra(i, 2), (n,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        p[f"h{i}.ssm.bdt"] = (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+        p[f"h{i}.ssm.A_log"] = jnp.broadcast_to(jnp.log(jnp.arange(
+            1, r + 1, dtype=jnp.float32))[:, None], (r, n)).astype(dtype)
+        p[f"h{i}.ssm.D"] = jnp.ones((n,), dtype)
+        p[f"h{i}.ssm.wout"] = _w(_extra(i, 7), (n, spec.hidden))
+
     for i in range(spec.layers):
         k = keys[2 + i * 6: 8 + i * 6]
         _norm(f"h{i}.ln1")
-        p[f"h{i}.attn.wq"] = _w(k[0], (spec.hidden, hd))
-        p[f"h{i}.attn.wk"] = _w(k[1], (spec.hidden, kvd))
-        p[f"h{i}.attn.wv"] = _w(k[2], (spec.hidden, kvd))
-        p[f"h{i}.attn.wo"] = _w(k[3], (hd, spec.hidden))
+        kind = spec.layer_kind(i)
+        if kind == "ssm":
+            _ssm(i, k)
+        elif kind == "gmu":
+            p[f"h{i}.gmu.win"] = _w(k[0], (spec.hidden, spec.ssm_inner))
+            p[f"h{i}.gmu.wout"] = _w(k[1], (spec.ssm_inner, spec.hidden))
+        else:
+            _attention(i, k, kind != "cross")
         _norm(f"h{i}.ln2")
-        if spec.ffn == "moe":
+        if spec.ffn == "swiglu":
+            ffn = spec.hidden * spec.ffn_mult
+            p[f"h{i}.mlp.wgu"] = _w(k[4], (spec.hidden, 2 * ffn))
+            p[f"h{i}.mlp.wd"] = _w(k[5], (ffn, spec.hidden))
+        elif spec.ffn == "moe":
             e, f = spec.experts, spec.expert_width
             p[f"h{i}.moe.router"] = _w(k[4], (spec.hidden, e))
             p[f"h{i}.moe.wg"] = _w(k[5], (e, spec.hidden, f))
@@ -256,6 +368,11 @@ def _norm(spec, params, name, x):
 
 
 def _mlp(spec, params, i, x, tap=None):
+    if spec.ffn == "swiglu":
+        gate, up = jnp.split(_matmul(params, f"h{i}.mlp.wgu", x, tap), 2,
+                             axis=-1)
+        act = up.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+        return _matmul(params, f"h{i}.mlp.wd", act.astype(x.dtype), tap)
     h = _matmul(params, f"h{i}.mlp.w1", x, tap) + params[f"h{i}.mlp.b1"]
     h = jax.nn.gelu(h)
     return _matmul(params, f"h{i}.mlp.w2", h, tap) + params[f"h{i}.mlp.b2"]
@@ -263,9 +380,9 @@ def _mlp(spec, params, i, x, tap=None):
 
 def _ffn(spec, params, i, h, tap, valid, counts):
     """The block's second half: ``h`` plus the FFN of its normed rows —
-    the GELU MLP under ``layer<i>/mlp``, or the routed experts under
-    ``layer<i>/moe_route`` (norm, router, top-k; ``counts`` gains the
-    layer's tokens per expert) and ``layer<i>/moe_experts``."""
+    the GELU or SwiGLU MLP under ``layer<i>/mlp``, or the routed experts
+    under ``layer<i>/moe_route`` (norm, router, top-k; ``counts`` gains
+    the layer's tokens per expert) and ``layer<i>/moe_experts``."""
     cdt = params["embed"].dtype
     if spec.ffn != "moe":
         with jax.named_scope(f"layer{i}/mlp"):
@@ -324,18 +441,23 @@ def _rotate(x, cos_sin):
                            axis=-1).astype(x.dtype)
 
 
+def _project(spec, params, i, x, which, heads, tap=None):
+    """Normed rows ``x`` through layer ``i``'s W<which> (and its bias,
+    where the spec has them): (T, heads, D)."""
+    y = _matmul(params, f"h{i}.attn.w{which}", x, tap)
+    if spec.attn_bias:
+        y = y + params[f"h{i}.attn.b{which}"]
+    return y.reshape(x.shape[0], heads, spec.head_dim)
+
+
 def _qkv(spec, params, i, h, rope, tap):
     """Layer ``i``'s normed rows through Wq, Wk, Wv: q (T, H, D) and
     k, v (T, KVH, D), rotated where the spec says rotary."""
-    t = h.shape[0]
     cdt = params["embed"].dtype
     x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
-    q = _matmul(params, f"h{i}.attn.wq", x,
-                tap).reshape(t, spec.heads, spec.head_dim)
-    k = _matmul(params, f"h{i}.attn.wk", x,
-                tap).reshape(t, spec.n_kv_heads, spec.head_dim)
-    v = _matmul(params, f"h{i}.attn.wv", x,
-                tap).reshape(t, spec.n_kv_heads, spec.head_dim)
+    q = _project(spec, params, i, x, "q", spec.heads, tap)
+    k = _project(spec, params, i, x, "k", spec.n_kv_heads, tap)
+    v = _project(spec, params, i, x, "v", spec.n_kv_heads, tap)
     if rope is not None:
         cs = rope["sliding" if spec.layer_window(i) else "full"]
         q, k = _rotate(q, cs), _rotate(k, cs)
@@ -364,6 +486,7 @@ def _prefill_attention(spec, q, k, v, length, window):
     ``_PREFILL_BLOCK`` queries walk the key blocks they can see with an
     online softmax, so nothing of size S x S exists."""
     s, kvh, d = k.shape
+    dv = v.shape[-1]            # wider than d under differential attention
     g = spec.heads // kvh
     scale = 1.0 / math.sqrt(d)
     qg = q.reshape(s, kvh, g, d)
@@ -387,11 +510,11 @@ def _prefill_attention(spec, q, k, v, length, window):
         w = jax.nn.softmax(att, axis=-1)
         return jnp.einsum(values, w.astype(v.dtype), v,
                           preferred_element_type=jnp.float32
-                          ).reshape(s, spec.heads * d)
+                          ).reshape(s, spec.heads * dv)
 
     nb = s // blk
     qb = qg.reshape(nb, blk, kvh, g, d)
-    kb, vb = k.reshape(nb, blk, kvh, d), v.reshape(nb, blk, kvh, d)
+    kb, vb = k.reshape(nb, blk, kvh, d), v.reshape(nb, blk, kvh, dv)
     within = jnp.arange(blk, dtype=jnp.int32)
 
     def one_block(i):
@@ -417,12 +540,86 @@ def _prefill_attention(spec, q, k, v, length, window):
         m0 = jnp.full((kvh, g, blk), -1e30, jnp.float32)
         _, l, acc = jax.lax.fori_loop(
             lo, hi, step, (m0, jnp.zeros_like(m0),
-                           jnp.zeros((kvh, g, blk, d), jnp.float32)))
+                           jnp.zeros((kvh, g, blk, dv), jnp.float32)))
         o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
         return jnp.transpose(o, (2, 0, 1, 3))              # (blk, KVH, G, D)
 
     return jax.lax.map(one_block, jnp.arange(nb, dtype=jnp.int32)
-                       ).reshape(s, spec.heads * d)
+                       ).reshape(s, spec.heads * dv)
+
+
+# Differential attention (arXiv:2410.05258).  Heads of D in order: query
+# heads 2j, 2j+1 are the pair (q1_j, q2_j), K heads 2m, 2m+1 the pair
+# (k1_m, k2_m), V heads 2m, 2m+1 side by side one V_m of 2D lanes, and
+# query pair j reads KV pair j // 2.  So query head h takes a plain
+# softmax over K head ``2 (h // 4) + h % 2`` and weighs V pair ``h // 4``
+# with it: A_h (2D lanes).  The layer's output is, a query pair,
+# ``(1 - l0) RMSNorm(A_2j - l A_2j+1)``.
+
+def _diff_prefill_attention(spec, q, k, v, length, window):
+    """A_h of every position of one padded prompt, (S, H, 2D) float32:
+    :func:`_prefill_attention` with the query heads regrouped by the K
+    head they read (two each) and each K head's V pair beside it."""
+    s, kvh, d = k.shape
+    pairs = kvh // 2
+    by_k = jnp.transpose(q.reshape(s, pairs, 2, 2, d), (0, 1, 3, 2, 4))
+    vv = jnp.repeat(v.reshape(s, pairs, 2 * d), 2, axis=1)
+    att = _prefill_attention(spec, by_k.reshape(s, spec.heads, d), k, vv,
+                             length, window)
+    att = jnp.transpose(att.reshape(s, pairs, 2, 2, 2 * d), (0, 1, 3, 2, 4))
+    return att.reshape(s, spec.heads, 2 * d)
+
+
+def _last_row_attention(spec, q, k, v, length):
+    """The prompt's last position alone: q (1, H, D) of position
+    ``length - 1`` over k, v (S, KVH, D), all ``length`` of them visible.
+    (1, H, D) float32, or A_h (1, H, 2D) under differential attention."""
+    s, kvh, d = k.shape
+    heads = np.arange(spec.heads)
+    if spec.diff_attn:
+        k_of, v = 2 * (heads // 4) + heads % 2, v.reshape(s, kvh // 2, 2 * d)
+        v_of = heads // 4
+    else:
+        k_of = v_of = heads // (spec.heads // kvh)
+    att = jnp.einsum("hd,shd->hs", q[0], k[:, k_of],
+                     preferred_element_type=jnp.float32) / math.sqrt(d)
+    seen = jnp.arange(s, dtype=jnp.int32)[None, :] < length
+    w = jax.nn.softmax(jnp.where(seen, att, -1e30), axis=-1)
+    return jnp.einsum("hs,shd->hd", w.astype(v.dtype), v[:, v_of],
+                      preferred_element_type=jnp.float32)[None]
+
+
+def _differential(spec, params, i, att):
+    """``att`` (T, H, 2D) float32, A_h of each query head, to the layer's
+    (T, H*D) float32 before Wo: ``(1 - l0) RMSNorm(A_2j - l A_2j+1)`` with
+    ``l = exp(lq1 . lk1) - exp(lq2 . lk2) + l0`` and the depth's
+    ``l0 = 0.8 - 0.6 exp(-0.3 i)``."""
+    t, d = att.shape[0], spec.head_dim
+
+    def vec(name):
+        return params[f"h{i}.attn.{name}"].astype(jnp.float32)
+
+    lam0 = 0.8 - 0.6 * math.exp(-0.3 * i)
+    lam = (jnp.exp(jnp.sum(vec("lq1") * vec("lk1")))
+           - jnp.exp(jnp.sum(vec("lq2") * vec("lk2"))) + lam0)
+    pair = att.reshape(t, spec.heads // 2, 2, 2 * d)
+    o = pair[:, :, 0] - lam * pair[:, :, 1]
+    o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                          + spec.norm_eps) * vec("subln.w")
+    return ((1.0 - lam0) * o).reshape(t, spec.heads * d)
+
+
+def _attn_out(spec, params, i, h, att, tap=None):
+    """``h`` plus Wo of the attention's rows ``att`` ((T, H*D) or (T, H,
+    D); A_h (T, H, 2D) under differential attention), and its bias."""
+    cdt = params["embed"].dtype
+    if spec.diff_attn:
+        att = _differential(spec, params, i, att)
+    att = att.reshape(att.shape[0], spec.heads * spec.head_dim)
+    o = _matmul(params, f"h{i}.attn.wo", att.astype(cdt), tap)
+    if spec.attn_bias:
+        o = o + params[f"h{i}.attn.bo"]
+    return h + o
 
 
 def _page_slot(page_tables, positions, page_size):
@@ -455,7 +652,7 @@ def _results(spec, pools, token, logits, counts):
 def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                  tokens, length, page_table, *, page_size: int,
                  k_scale=None, v_scale=None, kw_pool=None, vw_pool=None,
-                 tap=None):
+                 conv_pool=None, ssm_pool=None, tap=None):
     """Run one prompt (padded to a seq bucket) and seed its KV pages.
 
     Args:
@@ -478,13 +675,19 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
         sliding layers.  A prompt longer than the window writes only
         the last ``window / ps + 1`` pages there: what a later decode
         step can read.
+      conv_pool/ssm_pool: donated state pools of the ``ssm`` layers
+        (:class:`.kv_cache.StateSlots`): ``(Ls, slots, d_conv - 1, N)``
+        and ``(Ls, slots, R, N)`` float32.  The page table then has a
+        third row whose first entry is the sequence's slot; the scan
+        starts from zero and the slot is overwritten whole with the
+        state after ``length`` tokens.
       tap: optional calibration hook ``tap(site, activation)`` — only
         ever non-None in the eager PTQ harness, never in a serve trace.
 
     Returns ``(k_pool, v_pool, next_token, logits)``, with the scale
-    pools, then the sliding layers' pools, spliced in after ``v_pool``
-    when they were passed, and the experts' token counts ``(L, E)``
-    appended for ``ffn='moe'``.
+    pools, then the sliding layers' pools, then the state pools, spliced
+    in after ``v_pool`` when they were passed, and the experts' token
+    counts ``(L, E)`` appended for ``ffn='moe'``.
     Prefill attends over the in-layer full-precision K/V (the stored
     pages are for later decode steps), matching standard PTQ serving
     stacks.
@@ -527,65 +730,132 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
         return pool.at[layer, page_ids].set(
             rows.reshape(count, page_size, *rows.shape[1:]))
 
+    def write_kv(i, k, v):
+        """Layer ``i``'s K and V (S, KVH, D) into this sequence's pages,
+        a whole page at a time; they are dead after it, so no (L, S,
+        H*D) stack is held."""
+        nonlocal k_pool, v_pool, k_scale, v_scale, kw_pool, vw_pool
+        kvd = spec.n_kv_heads * spec.head_dim
+        window = spec.layer_window(i)
+        if window:
+            # only the pages a decode step can still read: the
+            # window's span from the first position it will see
+            count = min(n_pages, -(-window // page_size) + 1)
+            first = jnp.clip(
+                jnp.maximum(length + 1 - window, 0) // page_size,
+                0, n_pages - count)
+            li = spec.window_layers.index(i)
+            kw_pool = write(kw_pool, li, k.reshape(s, kvd), tables[1],
+                            first, count)
+            vw_pool = write(vw_pool, li, v.reshape(s, kvd), tables[1],
+                            first, count)
+            return
+        li = spec.global_layers.index(i)
+        if quant:
+            k, ksc = quantize_kv(k)
+            v, vsc = quantize_kv(v)
+            k_scale = write(k_scale, li, ksc, tables[0], 0, n_pages)
+            v_scale = write(v_scale, li, vsc, tables[0], 0, n_pages)
+        k_pool = write(k_pool, li, k.reshape(s, kvd), tables[0], 0,
+                       n_pages)
+        v_pool = write(v_pool, li, v.reshape(s, kvd), tables[0], 0,
+                       n_pages)
+
     counts = []
+    tail = spec.tail_start      # layers from here on keep nothing
+    memory = shared = None      # an ssm layer's y, a full layer's (k, v)
     for i in range(spec.layers):
+        kind = spec.layer_kind(i)
+        if kind == "ssm":
+            with scope(f"layer{i}/ssm"):
+                x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
+                y, z, conv, state = _ssm.prefill(params, f"h{i}.ssm", x,
+                                                 length)
+                # what a gated memory unit of the tail reads: its own
+                # position's y, and the tail runs on the last one only
+                memory = jax.lax.dynamic_slice_in_dim(y, length - 1, 1)
+                h = h + _ssm.gate_out(params, f"h{i}.ssm", y, z)
+            h = _ffn(spec, params, i, h, tap, in_prompt, counts)
+            with scope(f"layer{i}/state_write"):
+                # the whole slot: what a released row left there is
+                # never read
+                li, slot = spec.ssm_layers.index(i), tables[2, 0]
+                conv_pool = conv_pool.at[li, slot].set(
+                    conv.astype(conv_pool.dtype))
+                ssm_pool = ssm_pool.at[li, slot].set(state)
+                h, conv_pool, ssm_pool = jax.lax.optimization_barrier(
+                    (h, conv_pool, ssm_pool))
+            continue
+        if i >= tail - 1 and tail < spec.layers:
+            # the decoder's tail, on the prompt's last position only: the
+            # full layer before it projects K and V for every position
+            # (the cache every cross layer reads) and its query for one
+            with scope(f"layer{i}/attn_qkv" if kind != "gmu"
+                       else f"layer{i}/gmu"):
+                x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
+                if kind == "full":
+                    shared = (_project(spec, params, i, x, "k",
+                                       spec.n_kv_heads, tap),
+                              _project(spec, params, i, x, "v",
+                                       spec.n_kv_heads, tap))
+                    x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1)
+                    h = jax.lax.dynamic_slice_in_dim(h, length - 1, 1)
+                if kind == "gmu":
+                    h = h + _ssm.gate_out(
+                        params, f"h{i}.gmu", memory,
+                        _matmul(params, f"h{i}.gmu.win", x, tap))
+                else:
+                    q = _project(spec, params, i, x, "q", spec.heads, tap)
+            if kind != "gmu":
+                with scope(f"layer{i}/attn_cross" if kind == "cross"
+                           else _attn_scope(spec, i)):
+                    att = _last_row_attention(spec, q, *shared, length)
+                with scope(f"layer{i}/attn_out"):
+                    h = _attn_out(spec, params, i, h, att, tap)
+            h = _ffn(spec, params, i, h, tap, jnp.ones((1,), bool), counts)
+            if kind == "full":
+                with scope(f"layer{i}/kv_write"):
+                    write_kv(i, *shared)
+            continue
         window = spec.layer_window(i)
         with scope(f"layer{i}/attn_qkv"):
             q, k, v = _qkv(spec, params, i, h, rope, tap)
         with scope(_attn_scope(spec, i)):
-            o = _prefill_attention(spec, q, k, v, length, window).astype(cdt)
+            if spec.diff_attn:
+                o = _diff_prefill_attention(spec, q, k, v, length, window)
+            else:
+                o = _prefill_attention(spec, q, k, v, length, window)
         with scope(f"layer{i}/attn_out"):
-            h = h + _matmul(params, f"h{i}.attn.wo", o, tap)
+            h = _attn_out(spec, params, i, h, o, tap)
         h = _ffn(spec, params, i, h, tap, in_prompt, counts)
-        # the layer's K/V go into this sequence's pages, a whole page at
-        # a time, and are dead after it: no (L, S, H*D) stack is held
+        # the next layer waits for these writes: left free, XLA's
+        # schedule puts all 2L of them after the stack and keeps
+        # every layer's K and V alive until then.  (Not the int8
+        # scale pools: their rows are small, and the chip re-lays a
+        # (.., H)-minor pool once around all its scatters, which a
+        # barrier a layer would repeat.)
         with scope(f"layer{i}/kv_write"):
-            kvd = spec.n_kv_heads * spec.head_dim
+            write_kv(i, k, v)
             if window:
-                # only the pages a decode step can still read: the
-                # window's span from the first position it will see
-                count = min(n_pages, -(-window // page_size) + 1)
-                first = jnp.clip(
-                    jnp.maximum(length + 1 - window, 0) // page_size,
-                    0, n_pages - count)
-                li = spec.window_layers.index(i)
-                kw_pool = write(kw_pool, li, k.reshape(s, kvd), tables[1],
-                                first, count)
-                vw_pool = write(vw_pool, li, v.reshape(s, kvd), tables[1],
-                                first, count)
                 h, kw_pool, vw_pool = jax.lax.optimization_barrier(
                     (h, kw_pool, vw_pool))
-                continue
-            li = spec.global_layers.index(i)
-            if quant:
-                k, ksc = quantize_kv(k)
-                v, vsc = quantize_kv(v)
-                k_scale = write(k_scale, li, ksc, tables[0], 0, n_pages)
-                v_scale = write(v_scale, li, vsc, tables[0], 0, n_pages)
-            k_pool = write(k_pool, li, k.reshape(s, kvd), tables[0], 0,
-                           n_pages)
-            v_pool = write(v_pool, li, v.reshape(s, kvd), tables[0], 0,
-                           n_pages)
-            # the next layer waits for these writes: left free, XLA's
-            # schedule puts all 2L of them after the stack and keeps
-            # every layer's K and V alive until then.  (Not the int8
-            # scale pools: their rows are small, and the chip re-lays a
-            # (.., H)-minor pool once around all its scatters, which a
-            # barrier a layer would repeat.)
-            h, k_pool, v_pool = jax.lax.optimization_barrier(
-                (h, k_pool, v_pool))
+            else:
+                h, k_pool, v_pool = jax.lax.optimization_barrier(
+                    (h, k_pool, v_pool))
     with scope("lm_head"):
         hf = _norm(spec, params, "lnf", h).astype(cdt)
         if tap is not None:
             tap("head", hf)
         # only the last prompt row feeds the sampler: one row against
         # the embedding, not an (S, V) product to pick a row from
-        last = jax.lax.dynamic_slice_in_dim(hf, length - 1, 1, axis=0)
+        last = hf if tail < spec.layers else \
+            jax.lax.dynamic_slice_in_dim(hf, length - 1, 1, axis=0)
         logits = _head(spec, params, last)[0]                  # (V,)
     with scope("sample"):
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return _results(spec, (k_pool, v_pool, k_scale, v_scale, kw_pool,
-                           vw_pool), next_token, logits, counts)
+                           vw_pool, conv_pool, ssm_pool), next_token,
+                    logits, counts)
 
 
 def decode_walk(spec: ModelSpec, batch: int, k_pool, max_pages: int):
@@ -612,7 +882,7 @@ def _pages_walked(pages: int, batch: int) -> int:
 def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 tokens, positions, page_tables, *, page_size: int,
                 k_scale=None, v_scale=None, kw_pool=None, vw_pool=None,
-                tap=None):
+                conv_pool=None, ssm_pool=None, tap=None):
     """One decode step for a padded batch bucket.
 
     Args:
@@ -633,6 +903,9 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
         batch neighbours (the bit-identity contract survives int8).
       kw_pool/vw_pool: donated pools of the sliding layers; the kernel
         walks only the pages of a row's window there.
+      conv_pool/ssm_pool: donated state pools of the ``ssm`` layers; each
+        reads its rows' slots (``page_tables[:, 2, 0]``; a padding row's
+        is the null slot 0) and writes them back in place.
       tap: optional calibration hook (eager PTQ harness only).
 
     Returns ``(k_pool, v_pool, next_tokens, logits)``, with the scale
@@ -656,10 +929,51 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
         live = jnp.any(tables != 0, axis=(1, 2))
     cdt = params["embed"].dtype
     kvd = spec.n_kv_heads * spec.head_dim
+    def attend(q, k_pool, v_pool, table, li, window=None):
+        """The rows' paged read of layer ``li`` of these pools."""
+        read = paged_attention_diff if spec.diff_attn else paged_attention
+        return read(q, k_pool, v_pool, table, lengths, layer=li,
+                    window=window,
+                    steps=_pages_walked(k_pool.shape[1], b))
+
     counts = []
+    memory = None               # the nearest ssm layer's y, (B, N) float32
+    shared = None               # the nearest full layer's place in k_pool
     for i in range(spec.layers):
+        kind = spec.layer_kind(i)
+        if kind in ("ssm", "gmu"):
+            with scope(f"layer{i}/{kind}"):
+                x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
+                if kind == "gmu":
+                    h = h + _ssm.gate_out(
+                        params, f"h{i}.gmu", memory,
+                        _matmul(params, f"h{i}.gmu.win", x, tap))
+                else:
+                    li, slots = spec.ssm_layers.index(i), tables[:, 2, 0]
+                    memory, z, conv, state = _ssm.decode(
+                        params, f"h{i}.ssm", x, conv_pool[li, slots],
+                        ssm_pool[li, slots])
+                    h = h + _ssm.gate_out(params, f"h{i}.ssm", memory, z)
+            if kind == "ssm":
+                with scope(f"layer{i}/state_write"):
+                    conv_pool = conv_pool.at[li, slots].set(
+                        conv.astype(conv_pool.dtype))
+                    ssm_pool = ssm_pool.at[li, slots].set(state)
+            h = _ffn(spec, params, i, h, tap, live, counts)
+            continue
         window = spec.layer_window(i)
         table = tables[:, 1 if window else 0]
+        if kind == "cross":
+            # a query of its own over the pages of the full layer before
+            with scope(f"layer{i}/attn_qkv"):
+                x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
+                q = _project(spec, params, i, x, "q", spec.heads, tap)
+            with scope(f"layer{i}/attn_cross"):
+                o = attend(q, k_pool, v_pool, table, shared)
+            with scope(f"layer{i}/attn_out"):
+                h = _attn_out(spec, params, i, h, o, tap)
+            h = _ffn(spec, params, i, h, tap, live, counts)
+            continue
         with scope(f"layer{i}/attn_qkv"):
             q, k, v = _qkv(spec, params, i, h, rope, tap)
             page, slot = _page_slot(table, positions, page_size)  # (B,)
@@ -671,12 +985,9 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 vw_pool = vw_pool.at[li, page, slot].set(
                     v.reshape(b, kvd).astype(vw_pool.dtype))
             with scope(_attn_scope(spec, i)):
-                o = paged_attention(
-                    q, kw_pool, vw_pool, table, lengths, layer=li,
-                    window=window,
-                    steps=_pages_walked(kw_pool.shape[1], b))
+                o = attend(q, kw_pool, vw_pool, table, li, window)
         else:
-            li = spec.global_layers.index(i)
+            li = shared = spec.global_layers.index(i)
             with scope(f"layer{i}/kv_write"):
                 if quant:
                     k, ksc = quantize_kv(k)
@@ -693,13 +1004,9 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                                              v_scale, table, lengths,
                                              layer=li)
                 else:
-                    o = paged_attention(q, k_pool, v_pool, table, lengths,
-                                        layer=li,
-                                        steps=_pages_walked(
-                                            k_pool.shape[1], b))
+                    o = attend(q, k_pool, v_pool, table, li)
         with scope(f"layer{i}/attn_out"):
-            h = h + _matmul(params, f"h{i}.attn.wo",
-                            o.reshape(b, spec.heads * spec.head_dim), tap)
+            h = _attn_out(spec, params, i, h, o, tap)
         h = _ffn(spec, params, i, h, tap, live, counts)
     with scope("lm_head"):
         hf = _norm(spec, params, "lnf", h).astype(cdt)
@@ -709,4 +1016,5 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
     with scope("sample"):
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return _results(spec, (k_pool, v_pool, k_scale, v_scale, kw_pool,
-                           vw_pool), next_tokens, logits, counts)
+                           vw_pool, conv_pool, ssm_pool), next_tokens,
+                    logits, counts)

@@ -9,9 +9,10 @@ One scheduler tick = one *step boundary*:
  3. **admit** queued sequences while a decode slot AND worst-case KV
     headroom exist — admission reserves ``ceil((prompt+max_new)/ps)``
     pages up front (and, in a model with sliding-window layers, the
-    window's span plus a page of their pool: ``PagePool.admit_row``) so
-    an admitted sequence can never stall mid-decode waiting for a page
-    (admission control against pool headroom),
+    window's span plus a page of their pool; in one with state-space
+    layers, a state slot: ``PagePool.admit_row``) so an admitted
+    sequence can never stall mid-decode waiting for a page (admission
+    control against pool headroom),
  4. **decode** one token for every active row, padded to the smallest
     compiled batch bucket.
 
@@ -65,7 +66,12 @@ same boundaries add to ``stats`` as float sums (``wait_s``, ``evict_s``,
 (enqueued to its prefill entered, count ``admitted``), ``ttft_s`` and
 ``tpot_s`` (count ``tpot_requests``).  What the decode kernel walked:
 ``paged_chunks_walked`` and ``paged_grid_steps``
-(``pt_serve_paged_chunks_total{state="walked"|"grid"}``).
+(``pt_serve_paged_chunks_total{state="walked"|"grid"}``).  A model with
+state-space layers: ``state_slots_held`` (now) and
+``state_slots_held_max``, ``refused_state`` (admissions refused for want
+of a slot, beside ``refused_kv``), ``ssm_tokens_scanned`` (prompt
+positions through the scan) and, with cross layers, ``shared_kv_reads``
+(decode steps times the layers that read the shared full layer's pages).
 """
 from __future__ import annotations
 
@@ -283,6 +289,12 @@ class ContinuousScheduler:
             # decode steps: chunks the rows' contexts fill, and the grid
             # steps of the bucket's program (engine.stats["paged_walk"])
             "paged_chunks_walked": 0, "paged_grid_steps": 0,
+            # state-space layers: slots held now and at most, admissions
+            # refused for want of one, prompt positions scanned; cross
+            # layers: decode steps x layers reading the shared pages
+            "state_slots_held": 0, "state_slots_held_max": 0,
+            "refused_state": 0, "ssm_tokens_scanned": 0,
+            "shared_kv_reads": 0,
             # seconds of the scheduler thread by phase (module docstring)
             "wait_s": 0.0, "evict_s": 0.0, "admit_host_s": 0.0,
             "prefill_s": 0.0, "decode_prep_s": 0.0, "decode_s": 0.0,
@@ -291,6 +303,11 @@ class ContinuousScheduler:
             "lock_wait_s": 0.0, "queue_wait_s": 0.0, "admitted": 0,
             "ttft_s": 0.0, "tpot_s": 0.0, "tpot_requests": 0,
         }
+        spec = engine.spec
+        self._scans = bool(spec.ssm_layers)
+        # layers that read the shared full layer's pages in a decode step
+        self._shared_readers = (len(spec.cross_layers) + 1
+                                if spec.cross_layers else 0)
         self._meter_registry = None     # the registry self._meters are of
         self._meters: Dict[str, Any] = {}
         self._walk_booked: Dict[str, int] = {}
@@ -533,9 +550,10 @@ class ContinuousScheduler:
         if pages is None:
             # head-of-line blocking is deliberate: skipping ahead
             # would starve large requests under sustained load
-            self.stats["refused_kv"] += 1
-            self._book("pt_serve_admission_refusals_total",
-                       kind="counter", reason="kv_headroom")
+            short = self.engine.pool.last_refusal == "state"
+            self.stats["refused_state" if short else "refused_kv"] += 1
+            self._book("pt_serve_admission_refusals_total", kind="counter",
+                       reason="state_slots" if short else "kv_headroom")
             return None
         self._queue.popleft()
         return st, pages
@@ -560,6 +578,10 @@ class ContinuousScheduler:
         self.stats["prefill_tokens"] += len(st.prompt)
         self._book("pt_serve_prefill_tokens_total", kind="counter",
                    value=len(st.prompt))
+        if self._scans:
+            self.stats["ssm_tokens_scanned"] += len(st.prompt)
+            self._book("pt_serve_ssm_tokens_scanned_total", kind="counter",
+                       value=len(st.prompt))
         self._book_engine_locked()
         act = _Active(st, pages, pos=len(st.prompt), last_token=first)
         if self._is_finished(act):
@@ -644,6 +666,7 @@ class ContinuousScheduler:
                         a.stream.request_id)
             stats["tokens_generated"] += booked
             stats["decode_tokens"] += n
+            stats["shared_kv_reads"] += self._shared_readers
             self._book("pt_serve_decode_tokens_total", kind="counter",
                        value=n)
             self._book_engine_locked(decode=True)
@@ -907,6 +930,11 @@ class ContinuousScheduler:
             }
 
     def _gauges_locked(self) -> None:
+        slots = self.engine.pool.state_slots
+        if slots is not None:
+            snap = slots.snapshot()
+            self.stats["state_slots_held"] = snap["held"]
+            self.stats["state_slots_held_max"] = snap["high_watermark"]
         self._book("pt_serve_queue_depth", kind="gauge",
                    value=len(self._queue))
         self._book("pt_serve_active_sequences", kind="gauge",
@@ -946,7 +974,8 @@ _METRIC_HELP = {
     "pt_serve_requests_total": "Requests accepted by the serve scheduler",
     "pt_serve_completed_total": "Requests completed",
     "pt_serve_admission_refusals_total":
-        "Admissions refused, by reason (inflight_cap|kv_headroom)",
+        "Admissions refused, by reason "
+        "(inflight_cap|kv_headroom|state_slots)",
     "pt_serve_shed_total":
         "Requests shed at admission, by reason "
         "(deadline_infeasible|queue_full|draining)",
@@ -965,6 +994,8 @@ _METRIC_HELP = {
     "pt_serve_tokens_total": "Tokens generated by the serve engine",
     "pt_serve_prefill_tokens_total": "Prompt positions prefilled",
     "pt_serve_decode_tokens_total": "Rows decoded, summed over steps",
+    "pt_serve_ssm_tokens_scanned_total":
+        "Prompt positions through the state-space layers' scan",
     "pt_serve_moe_tokens_routed_total":
         "Token-expert pairs routed, summed over layers",
     "pt_serve_moe_expert_max_tokens_total":

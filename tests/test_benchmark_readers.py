@@ -512,8 +512,13 @@ def test_lm_metrics_have_entries_for_the_new_cell_only(bench):
     entries = {m["name"]: m for m in spec["per_layer"]}
     cell = "mellum2-serve-mixedctx-backlog"
     assert cell in {w["name"] for w in spec["workloads"]}
+    # the readers that are blind to the model also list PR 31's cell, put
+    # at the end of their lists; the experts' and Mellum's own do not
+    shared = {"attn_global_ms_per_step", "attn_window_ms_per_step",
+              "attn_prefill_ms_per_run", "kv_window_pages_returned"}
     for name in LM_METRICS + ["kv_window_pages_returned"]:
-        assert entries[name]["workloads"] == [cell]
+        assert entries[name]["workloads"] == [cell] + (
+            ["phi4flash-serve-reasoning-backlog"] if name in shared else [])
         assert entries[name]["moves"] == "serve_tokens_per_s"
         assert os.path.exists(os.path.join(
             BENCH, "layer_metrics", name + ".py")) or os.path.exists(
@@ -524,6 +529,65 @@ def test_lm_metrics_have_entries_for_the_new_cell_only(bench):
     # the prefill's KV write is scoped `kv_write` in the new block too:
     # the accepted reader reads it unedited
     assert entries["prefill_kv_ms_per_run"]["workloads"][-1] == cell
+
+
+HYBRID_METRICS = ["ssm_ms_per_decode_step", "ssm_ms_per_prefill",
+                  "gmu_ms_per_decode_step", "attn_cross_ms_per_step",
+                  "paged_attn_diff_roofline", "ssm_decode_roofline",
+                  "ssm_scan_roofline", "state_slots_held_max",
+                  "serve_mfu_pct.phi4flash"]
+
+
+def test_hybrid_metrics_have_entries_for_their_cell_and_read_nothing_elsewhere(
+        bench):
+    """PR 31's nine metrics: entries after everything that was there, a
+    reader each, and nothing read (no exception) from a run of a program
+    that has no such scope, counter or model fact: the parent's, or
+    another configuration's."""
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    cell = "phi4flash-serve-reasoning-backlog"
+    assert spec["workloads"][-1]["name"] == cell
+    assert spec["workloads"][-1]["chips"] == 1
+    assert spec["configs"][-1]["name"] == spec["workloads"][-1]["config"]
+    assert spec["configs"][-1]["reduced"] == []
+    assert [m["name"] for m in spec["per_layer"][-9:]] == HYBRID_METRICS
+    for m in spec["per_layer"][-9:]:
+        assert m["workloads"] == [cell]
+        assert m["moves"] == "serve_tokens_per_s"
+    empty = {"trace": None, "values": {}, "counters": {}, "spans": {},
+             "peak": None, "model": {}}
+    other = dict(empty, counters={"prefill_tokens": 9, "admitted": 1,
+                                  "decode_tokens": 3},
+                 model=LM_MODEL, seconds=4.0, chips=1,
+                 peak={"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9})
+    for name in HYBRID_METRICS:
+        assert bench.read_layer_metric(name, dict(empty)) is None, name
+        assert bench.read_layer_metric(name, dict(other)) is None, name
+
+
+def test_hybrid_mfu_reads_the_counters(bench):
+    """2 x parameters x positions over the window and the peak, at the
+    published widths: a window of 1,000 prompt positions in 2 prompts and
+    60,000 decoded rows."""
+    import importlib
+    costs = importlib.import_module("costs_hybrid")
+    ref = importlib.import_module("reference.phi4flash_serve")
+    m = {"layers": 32, "heads": 40, "kv_heads": 20, "head_dim": 64,
+         "hidden": 2560, "ffn": 10240, "vocab_size": 200064,
+         "ssm_inner": 5120, "ssm_state": 16, "ssm_conv": 4,
+         "ssm_dt_rank": 160, "weight_itemsize": 2, "kv_itemsize": 2,
+         "layer_types": [{"mamba": "ssm"}.get(k, k)
+                         for k in ref.layer_kinds(32)]}
+    run = {"trace": None, "model": m, "seconds": 40.0, "chips": 1,
+           "peak": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9},
+           "counters": {"prefill_tokens": 1000, "admitted": 2,
+                        "decode_tokens": 60000}}
+    every, once, row = costs.position_params(m)
+    want = 100.0 * 2 * (every * 1000 + once * 2 + row * 60000) / (
+        40.0 * 197e12)
+    assert read(bench, "serve_mfu_pct.phi4flash", run) == \
+        pytest.approx(want)
+    assert 5.0 < want < 7.0
 
 
 # -- the equal-heads kernel's walk (PR 28) -----------------------------------
@@ -572,7 +636,7 @@ def test_flash_chunks_visited_is_read_from_the_registry(bench):
     finally:
         obs.reset()
     spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    assert spec["per_layer"][-1] == {
+    assert next(m for m in spec["per_layer"] if m["name"] == name) == {
         "name": name, "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "kernels (ops/pallas_ops.py)",
         "moves": "train_tokens_per_s", "workloads": ["gpt345m-train-s1024"]}

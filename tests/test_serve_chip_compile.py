@@ -320,6 +320,114 @@ def test_chip_compiles_the_grouped_kernel_alone(one_chip, window, pages):
     assert exe.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
+# -- Phi-4-mini-flash-reasoning's programs at its published widths ------------
+# (`benchmarks/configs/phi4-mini-flash-serve.json`): eight layers with every
+# kind of mixer (state-space and sliding x2, state-space, full, gated memory
+# unit, cross), so a program compiles in seconds; the pools, slots, page size
+# and largest buckets are the configuration's.
+
+PHI_SPEC = ModelSpec(
+    vocab_size=200064, hidden=2560, layers=8, heads=40, kv_heads=20,
+    max_seq_len=4096, positions="none", ffn="swiglu", window=512,
+    layer_types=("ssm", "sliding", "ssm", "sliding", "ssm", "full", "gmu",
+                 "cross"),
+    attn_bias=True, diff_attn=True, ssm_inner=5120, ssm_state=16,
+    ssm_conv=4, ssm_dt_rank=160)
+PHI_PAGE, PHI_PAGES, PHI_WINDOW_PAGES, PHI_SLOTS = 128, 2049, 321, 65
+
+
+def _compile_phi(one_chip, monkeypatch, kind, size):
+    monkeypatch.setattr(_device, "on_tpu", lambda: True)
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(init_params, PHI_SPEC, dtype=bf)))
+    full, _ = pool_shapes(1, PHI_PAGES, PHI_PAGE, 20, 64)
+    sliding, _ = pool_shapes(2, PHI_WINDOW_PAGES, PHI_PAGE, 20, 64)
+    state = [sds(full, bf), sds(full, bf), sds(sliding, bf),
+             sds(sliding, bf), sds((3, PHI_SLOTS, 3, 5120), bf),
+             sds((3, PHI_SLOTS, 16, 5120), jnp.float32)]
+    maxp = PHI_SPEC.max_seq_len // PHI_PAGE
+    step = decode_step if kind == "decode" else prefill_step
+
+    def run(params, k, v, kw, vw, conv, ssm, a, b, c):
+        return step(PHI_SPEC, params, k, v, a, b, c, page_size=PHI_PAGE,
+                    kw_pool=kw, vw_pool=vw, conv_pool=conv, ssm_pool=ssm)
+
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    args = ((i32((size,)), i32((size,)), i32((size, 3, maxp)))
+            if kind == "decode" else
+            (i32((size,)), i32(()), i32((3, maxp))))
+    exe = jax.jit(run, donate_argnums=tuple(range(1, 7))).lower(
+        params, *state, *args).compile()
+    return exe, state
+
+
+@pytest.mark.parametrize("kind,size", [("decode", 64), ("prefill", 2048)])
+def test_chip_compiles_the_hybrid_programs(one_chip, monkeypatch, kind,
+                                           size):
+    """Pages of two pools and the state slots donated and aliased, no
+    pool copied or re-laid, the differential read through the grouped
+    kernel on KV pairs, the scan a Mosaic kernel."""
+    exe, state = _compile_phi(one_chip, monkeypatch, kind, size)
+    text = exe.as_text()
+    mem = exe.memory_analysis()
+    pool_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                     for s in state)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 6
+    # temporaries under one K pool (the 2048 bucket's activations are a
+    # third of it), a decode step's under a tenth
+    k_pool = int(np.prod(state[0].shape)) * 2
+    assert mem.temp_size_in_bytes < k_pool / (10 if kind == "decode" else 1)
+    layer = PHI_PAGE * 1280                 # one page of one layer
+    for dtype, dims, op, line in _shapes_of(text):
+        # nothing of a pool's size is copied, sliced or re-laid
+        if op in ("copy", "transpose", "dynamic-slice", "slice", "pad") \
+                and dims and dims[-1] == 1280 and len(dims) >= 3:
+            assert int(np.prod(dims)) < 256 * layer, line[:200]
+        # the state never lies (5120, 16): sixteen lanes of 128
+        assert dims[-2:] != (5120, 16) or op == "parameter", line[:200]
+    if kind == "decode":
+        assert "paged_attention_window" in text
+        assert "paged_attention_gqa" in text
+        assert "ssm_scan" not in text
+        # two sliding layers, the full layer and the cross layer
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) == 4
+    else:
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) == 3      # the scan, a layer
+        assert "ssm_scan" in text
+        # no (heads, S, S) scores at the long bucket (no width is 2048)
+        for dtype, dims, op, line in _shapes_of(text):
+            assert dims[-2:] != (2048, 2048), line[:200]
+
+
+@pytest.mark.parametrize("positions", [256, 2048])
+def test_chip_compiles_the_scan_kernel_alone(one_chip, positions):
+    from paddle_tpu.ops.selective_scan import _selective_scan_pallas
+    f32 = jnp.float32
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, f32, sharding=one_chip)
+
+    exe = jax.jit(functools.partial(_selective_scan_pallas,
+                                    interpret=False)).lower(
+        sds((positions, 5120)), sds((positions, 5120)),
+        sds((positions, 16)), sds((positions, 16)),
+        sds((16, 5120))).compile()
+    assert "tpu_custom_call" in exe.as_text()
+    # B and C spread over a tile's lanes, and nothing (S, 16, 5120)
+    assert exe.memory_analysis().temp_size_in_bytes < \
+        3 * positions * 16 * 128 * 4
+
+
 # -- the train cell's flash kernels (same file: one process loads the library) --
 
 FLASH_CALLS = [
