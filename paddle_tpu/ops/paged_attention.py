@@ -52,13 +52,16 @@ Shapes (the model loops layers and passes the pools whole each time):
 Grouped heads and windows (``_paged_attention_gqa_pallas``): a pool row
 may hold fewer heads than q has (``KVH * D`` lanes, ``H = KVH * G``):
 query head ``j`` reads KV head ``j // G``, and the ``G`` query heads of
-a KV head share one fetch of its page.  ``window=W`` makes a row see
-only its last ``W`` positions.  That kernel walks the same kind of work
-list a page at a time: row ``b`` contributes pages ``max(0, len - W) //
-ps .. (len - 1) // ps`` (all of ``0 .. (len - 1) // ps`` without a
-window), on the MXU with the ``G`` query heads of a KV head as rows.
-Which of the two runs is read from the shapes (equal heads and no
-window, or not), never from a name or an option.  The int8 pool's kernel
+a KV head share one fetch of its pages.  ``window=W`` makes a row see
+only its last ``W`` positions.  That kernel walks the same work list of
+chunks, copied by hand two deep the same way; its chunk is counted in
+bytes (``gqa_chunk_pages``), and row ``b`` contributes the chunks of its
+pages ``max(0, len - W) // ps .. (len - 1) // ps`` (all of ``0 .. (len -
+1) // ps`` without a window), the first of them starting at the page the
+window starts in; the arithmetic is on the MXU with the ``G`` query
+heads of a KV head as rows, one online-softmax update a chunk.  Which of
+the two runs is read from the shapes (equal heads and no window, or
+not), never from a name or an option.  The int8 pool's kernel
 (``paged_attention_int8``) still has the grid ``(batch, pages)``.
 """
 from __future__ import annotations
@@ -68,6 +71,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -239,7 +243,7 @@ def _paged_call(kernel, q, pools, layer, extra, extra_specs, page_tables,
 
 
 # ---------------------------------------------------------------------------
-# grouped heads and windows: one grid axis over the pages really walked
+# grouped heads and windows: one grid axis over the chunks really walked
 # ---------------------------------------------------------------------------
 
 def walk_pages(max_pages, page_size, window):
@@ -250,19 +254,26 @@ def walk_pages(max_pages, page_size, window):
     return min(max_pages, -(-window // page_size) + 1)
 
 
-def _walk(page_tables, lengths, *, ps, window, steps, chunk=1):
+def _walk(page_tables, lengths, *, ps, window, steps, chunk, shift=None):
     """The batch's walk as a work list of ``steps`` grid steps: the rows'
-    runs of chunks (``chunk`` consecutive pages of one row; a page by
-    default) laid end to end.  Returns ``(rows, pages, slots, first,
-    last)``: per step the row it belongs to, the ``chunk`` physical pages
-    to fetch (flat, ``chunk`` a step; a slot past the row's last page is
-    the null page 0) and the logical chunk index (``-1`` past the end of
-    the list, where row and pages repeat the last live step's so that
-    nothing is fetched); per row its first and last logical chunk.  A
-    row's entries are a function of its own length and table alone."""
+    runs of chunks (``chunk`` consecutive pages of one row) laid end to
+    end.  Returns ``(rows, pages, slots, first, last)``: per step the row
+    it belongs to, the ``chunk`` physical pages to fetch (flat, ``chunk``
+    a step; a slot past the row's last page is the null page 0) and the
+    logical chunk index (``-1`` past the end of the list, where row and
+    pages repeat the last live step's so that nothing is fetched); per
+    row its first and last logical chunk.  ``shift`` (pages a row) moves
+    a row's chunks along its table: chunk ``c`` of row ``b`` is its pages
+    ``c * chunk + shift[b] ..``, which is how a windowed row's run starts
+    at the page its window starts in (:func:`_window_shift`), wherever
+    that lies.  A row's entries are a function of its own length and
+    table alone.  No lookup in a per-row vector: the chip's compiler
+    takes such a gather apart into an operation a row."""
     lengths = jnp.maximum(lengths, 1)
-    last = (lengths - 1) // (ps * chunk)
-    first = (jnp.maximum(lengths - window, 0) // (ps * chunk) if window
+    # a row's tokens counted from where its chunks start
+    reach = lengths if shift is None else lengths - shift * ps
+    last = (reach - 1) // (ps * chunk)
+    first = (jnp.maximum(reach - window, 0) // (ps * chunk) if window
              else jnp.zeros_like(last))
     n = last - first + 1
     ends = jnp.cumsum(n)
@@ -271,68 +282,132 @@ def _walk(page_tables, lengths, *, ps, window, steps, chunk=1):
     gc = jnp.minimum(g, ends[-1] - 1)
     before = gc[:, None] >= ends[None, :]          # rows wholly before g
     rows = jnp.sum(before, axis=1).astype(jnp.int32)
-    if chunk == 1:
-        # the grouped kernels' form, kept to the letter: PR 28 had to
-        # leave their programs' optimized HLO as it was.  The form below
-        # gives the same list for a chunk of one page and is the better
-        # one on the chip; moving them onto it is ROADMAP S4's next step
-        slots = first[rows] + gc - (ends[rows] - n[rows])
-        pages = page_tables[rows, slots]
-    else:
-        # the same list with no lookup in a per-row vector: the chip's
-        # compiler takes such a gather apart into an operation a row
-        def of_row(x):
-            mine = rows[:, None] == jnp.arange(x.shape[0])[None, :]
-            return jnp.sum(jnp.where(mine, x[None, :], 0), axis=1)
 
-        slots = (of_row(first) + gc
-                 - jnp.sum(jnp.where(before, n[None, :], 0), axis=1))
-        page = (slots[:, None] * chunk
-                + jnp.arange(chunk, dtype=jnp.int32)[None, :])
-        held = page <= of_row((lengths - 1) // ps)[:, None]
-        page = jnp.minimum(page, page_tables.shape[1] - 1)
-        pages = jnp.where(held, page_tables[rows[:, None], page],
-                          0).reshape(-1)
+    def of_row(x):
+        mine = rows[:, None] == jnp.arange(x.shape[0])[None, :]
+        return jnp.sum(jnp.where(mine, x[None, :], 0), axis=1)
+
+    slots = (of_row(first) + gc
+             - jnp.sum(jnp.where(before, n[None, :], 0), axis=1))
+    page = (slots[:, None] * chunk
+            + jnp.arange(chunk, dtype=jnp.int32)[None, :])
+    if shift is not None:
+        page = page + of_row(shift)[:, None]
+    held = page <= of_row((lengths - 1) // ps)[:, None]
+    page = jnp.minimum(page, page_tables.shape[1] - 1)
+    pages = jnp.where(held, page_tables[rows[:, None], page],
+                      0).reshape(-1)
     return (rows, pages.astype(jnp.int32),
             jnp.where(live, slots, -1).astype(jnp.int32),
             first.astype(jnp.int32), last.astype(jnp.int32))
 
 
+def _window_shift(lengths, *, ps, window, chunk):
+    """Pages by which each row's chunks are moved along its table so that
+    one of them starts at the page the row's window starts in: a window
+    of ``w`` pages is then ``ceil(w / chunk)`` list entries and as many
+    fetches as it has pages, not what a fixed grid of chunks would cut
+    it into."""
+    return (jnp.maximum(jnp.maximum(lengths, 1) - window, 0) // ps
+            % chunk).astype(jnp.int32)
+
+
+# K and V bytes a grid step of the grouped kernels brings in: a chunk is
+# as many of a row's pages as fit (``gqa_chunk_pages``).  A grid step
+# costs ~1.3 us beside what it holds, so a step wants to hold much, and a
+# window wants to be one step; past a few pages the time a page stops
+# falling, and the two-deep tiles are twice this of the 16 MiB a kernel
+# may hold in VMEM: see PERF.md (PR 32) for the sweep on the chip
+_GQA_CHUNK_BYTES = 7 << 19
+
+
+def gqa_chunk_pages(k_pool, walk):
+    """Pages of one row that a grid step of the grouped kernels brings
+    in: ``_GQA_CHUNK_BYTES`` of K and V, at least one page, no more than
+    a row's walk (``walk_pages``).  From the pool's shape and dtype
+    alone; nothing tunes it."""
+    page_bytes = 2 * k_pool.shape[2] * k_pool.shape[3] \
+        * jnp.dtype(k_pool.dtype).itemsize
+    return max(1, min(_GQA_CHUNK_BYTES // page_bytes, walk))
+
+
 def _gqa_kernel(rows_ref, pages_ref, slots_ref, len_ref, first_ref,
-                last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                acc_scr, *, ps, kvh, d, sm_scale, window):
-    """One page of one row: for each KV head, its G query heads (padded
-    to a bf16 tile of rows) against the page's ``(ps, D)`` lanes on the
-    MXU, online softmax in f32."""
+                last_ref, shift_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+                k_buf, v_buf, sem, m_scr, l_scr, acc_scr, *, ps, chunk,
+                steps, kvh, d, sm_scale, window):
+    """One chunk of one row: the pages of it the row holds copied side by
+    side into one ``(chunk * ps, KVH*D)`` tile of a two-deep buffer, the
+    next list entry's copies started before this chunk's arithmetic;
+    then for each KV head its G query heads (padded to a bf16 tile of
+    rows) against the chunk's ``(chunk * ps, D)`` lanes on the MXU, one
+    online-softmax update in f32 a chunk.  A step past the list's end
+    starts, waits for and computes nothing; nor does the entry of a row
+    that holds nothing (``len_ref`` 0), which leaves zeros."""
     g = pl.program_id(0)
     row = rows_ref[g]
     slot = slots_ref[g]
-    length = len_ref[row]
     live = slot >= 0
+    layer = layer_ref[0]
+
+    def origin(step):
+        """The position list entry ``step``'s tile starts at."""
+        return (slots_ref[step] * chunk + shift_ref[rows_ref[step]]) * ps
+
+    def copies(step, do):
+        """``do`` (start or wait) every copy of the pages list entry
+        ``step`` holds of its row: the tile's slots past them keep what
+        an earlier chunk left there, finite and masked by position."""
+        held = len_ref[rows_ref[step]] - origin(step)
+
+        def page(j, carry):
+            at = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            for i, (pool, tile) in enumerate(((k_hbm, k_buf),
+                                              (v_hbm, v_buf))):
+                do(pltpu.make_async_copy(
+                    pool.at[layer, pages_ref[step * chunk + j]],
+                    tile.at[step % 2, at], sem.at[i, step % 2]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(pl.cdiv(held, ps), chunk), page, 0)
+
+    @pl.when(g == 0)                # every row has a chunk: step 0 is live
+    def _first():
+        # a weight of 0 must meet a finite value in a slot never copied to
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        copies(0, lambda c: c.start())
+
+    nxt = jnp.minimum(g + 1, steps - 1)
+
+    @pl.when((g + 1 < steps) & (slots_ref[nxt] >= 0))
+    def _next():
+        copies(nxt, lambda c: c.start())
 
     @pl.when(live & (slot == first_ref[row]))
     def _init():
         _init_scratch(m_scr, l_scr, acc_scr)
 
-    @pl.when(live)
-    def _page():
+    @pl.when(live & (len_ref[row] > 0))
+    def _chunk():
+        copies(g, lambda c: c.wait())
+        length = len_ref[row]
         gp = q_ref.shape[1]
-        pos = slot * ps + jax.lax.broadcasted_iota(jnp.int32, (gp, ps), 1)
+        pos = origin(g) + jax.lax.broadcasted_iota(
+            jnp.int32, (gp, chunk * ps), 1)
         valid = pos < length
         if window:
             valid &= pos >= length - window
         # said outright, so that a process-wide default precision cannot
         # ask the MXU for float32 passes over bfloat16 operands
-        prec = (jax.lax.Precision.HIGHEST if k_ref.dtype == jnp.float32
+        prec = (jax.lax.Precision.HIGHEST if k_buf.dtype == jnp.float32
                 else jax.lax.Precision.DEFAULT)
         for j in range(kvh):
             q = q_ref[j]                                  # (GP, D)
-            k = k_ref[:, j * d:(j + 1) * d]               # (ps, D)
-            v = v_ref[:, j * d:(j + 1) * d]
+            k = k_buf[g % 2, :, j * d:(j + 1) * d]        # (chunk * ps, D)
+            v = v_buf[g % 2, :, j * d:(j + 1) * d]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), precision=prec,
                 preferred_element_type=jnp.float32) * sm_scale
-            s = jnp.where(valid, s, _NEG_INF)              # (GP, ps)
+            s = jnp.where(valid, s, _NEG_INF)         # (GP, chunk * ps)
             m_prev, l_prev = m_scr[j], l_scr[j]           # (GP, LANES)
             m_new = jnp.maximum(m_prev,
                                 jnp.max(s, axis=1, keepdims=True))
@@ -352,9 +427,17 @@ def _gqa_kernel(rows_ref, pages_ref, slots_ref, len_ref, first_ref,
             o_ref[j] = (acc_scr[j] / l).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "window", "chunk", "grid", "interpret", "out_dtype"))
 def _paged_attention_gqa_pallas(q, k_pool, v_pool, page_tables, lengths,
-                                *, layer, sm_scale, window, steps,
+                                layer, *, sm_scale, window, chunk, grid,
                                 interpret, out_dtype=None):
+    """Grouped heads and windows, fp32 or bf16 pools: ``grid`` steps of
+    ``chunk`` pages (:func:`chunk_walk`).  As in the equal-heads kernel
+    below, ``layer`` is an operand and the function jitted (a program
+    traces and lowers the kernel once a pool, a window and a bucket, and
+    XLA makes the layers' equal lists once), and the pools stay where
+    they lie for the kernel's own copies."""
     b, h, d = q.shape
     ps = k_pool.shape[2]
     kvh = k_pool.shape[3] // d
@@ -364,32 +447,37 @@ def _paged_attention_gqa_pallas(q, k_pool, v_pool, page_tables, lengths,
             f"of {d}")
     grp = h // kvh
     gp = -(-grp // 16) * 16         # a bf16 tile of rows a KV head
-    walk = walk_pages(page_tables.shape[1], ps, window)
-    if steps is None:
-        steps = b * walk
-    steps = min(int(steps), b * walk)
+    shift = (_window_shift(lengths, ps=ps, window=window, chunk=chunk)
+             if window else None)
     rows, pages, slots, first, last = _walk(
-        page_tables, lengths, ps=ps, window=window, steps=steps)
+        page_tables, lengths, ps=ps, window=window, steps=grid,
+        chunk=chunk, shift=shift)
+    # a row that holds a token holds a page (not the table's first, once
+    # its window has left that behind)
+    held = jnp.where(jnp.all(page_tables == 0, axis=1), 0,
+                     jnp.maximum(lengths, 1))
     qg = jnp.pad(q.reshape(b, kvh, grp, d),
                  ((0, 0), (0, 0), (0, gp - grp), (0, 0)))
     row_spec = pl.BlockSpec((None, kvh, gp, d),
                             lambda g, rows, *_: (rows[g], 0, 0, 0))
-    page_spec = pl.BlockSpec(
-        (None, None, ps, kvh * d),
-        lambda g, rows, pages, *_: (layer, pages[g], 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(steps,),
-        in_specs=[row_spec, page_spec, page_spec],
+        num_scalar_prefetch=8,
+        grid=(grid,),
+        in_specs=[row_spec, pool_spec, pool_spec],
         out_specs=row_spec,
         scratch_shapes=[
+            pltpu.VMEM((2, chunk * ps, kvh * d), k_pool.dtype),
+            pltpu.VMEM((2, chunk * ps, kvh * d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((kvh, gp, _LANES), jnp.float32),
             pltpu.VMEM((kvh, gp, _LANES), jnp.float32),
             pltpu.VMEM((kvh, gp, d), jnp.float32),
         ],
     )
-    kernel = functools.partial(_gqa_kernel, ps=ps, kvh=kvh, d=d,
-                               sm_scale=sm_scale, window=window)
+    kernel = functools.partial(_gqa_kernel, ps=ps, chunk=chunk, steps=grid,
+                               kvh=kvh, d=d, sm_scale=sm_scale,
+                               window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -398,8 +486,9 @@ def _paged_attention_gqa_pallas(q, k_pool, v_pool, page_tables, lengths,
             dimension_semantics=("arbitrary",)),
         name="paged_attention_window" if window else "paged_attention_gqa",
         interpret=interpret,
-    )(rows, pages, slots, jnp.maximum(lengths, 1).astype(jnp.int32),
-      first, last, qg, k_pool, v_pool)
+    )(rows, pages, slots, held.astype(jnp.int32), first, last,
+      jnp.zeros_like(last) if shift is None else shift,
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool, v_pool)
     return out[:, :, :grp].reshape(b, h, d)
 
 
@@ -420,31 +509,45 @@ def chunk_pages(page_size, max_pages):
     return max(1, min(_CHUNK_TOKENS // page_size, max_pages))
 
 
-def chunks_of(length, chunk_tokens):
-    """List entries a row of ``length`` tokens has in the equal-heads
-    kernel's work list, ``chunk_tokens`` a chunk (:func:`_walk`: one at
-    least)."""
-    return (length - 1) // chunk_tokens + 1
+def chunks_of(length, chunk_tokens, *, page_size=None, window=0):
+    """List entries a row of ``length`` tokens (an int, or an array of
+    rows) has in a work list of ``chunk_tokens`` a chunk (:func:`_walk`:
+    one at least); with a ``window``, of the pages from the one its
+    window starts in."""
+    last = length - 1
+    if not window:
+        return last // chunk_tokens + 1
+    first = np.maximum(length - window, 0) // page_size
+    return (last // page_size - first) // (chunk_tokens // page_size) + 1
+
+
+def _grouped(q, k_pool, window):
+    """Whether the grouped kernels read these operands: a window, or
+    fewer heads in the pool than q has."""
+    return bool(window) or k_pool.shape[3] != q.shape[1] * q.shape[2]
 
 
 def chunk_walk(q, k_pool, max_pages, *, window=None, steps=None):
     """What :func:`paged_attention` walks for these operands, of which
     only shapes and the pool's dtype are read (arrays or
     ``ShapeDtypeStruct``s): ``(tokens a chunk, grid length)`` of the
-    equal-heads kernel, ``None`` where another kernel runs (a window,
-    fewer heads in the pool than q has, an int8 pool).
+    equal-heads kernel, or of the grouped kernels (a window, fewer heads
+    in the pool than q has), whose chunk is ``gqa_chunk_pages``;
+    ``None`` for an int8 pool, which another kernel reads.
 
     ``steps`` is the caller's bound on the pages the batch holds plus
-    one a row (:func:`paged_attention`); a row of ``p`` pages walks
-    ``ceil(p / C)`` chunks, so the rows together walk at most
+    one a row (:func:`paged_attention`); a row that walks ``p`` pages
+    walks ``ceil(p / C)`` chunks, so the rows together walk at most
     ``ceil((steps - batch) / C) + batch``, and never more than
-    ``batch * ceil(max_pages / C)``."""
+    ``batch * ceil(walk_pages / C)``."""
     batch, h, d = q.shape
-    if window or k_pool.shape[3] != h * d or k_pool.dtype == jnp.int8:
+    if k_pool.dtype == jnp.int8:
         return None
     page_size = k_pool.shape[2]
-    c = chunk_pages(page_size, max_pages)
-    grid = batch * -(-max_pages // c)
+    walk = walk_pages(max_pages, page_size, window)
+    c = (gqa_chunk_pages(k_pool, walk) if _grouped(q, k_pool, window)
+         else chunk_pages(page_size, max_pages))
+    grid = batch * -(-walk // c)
     if steps is not None:
         grid = min(grid, -(-max(int(steps) - batch, 0) // c) + batch)
     return c * page_size, grid
@@ -597,10 +700,11 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
 
     A pool row of fewer heads than q's, or ``window``, selects the
     grouped kernel (module docstring; :func:`chunk_walk` is the one
-    place that tells).  ``steps`` is an upper bound the caller knows on
-    the pages the batch walks (the allocator's: no two rows share a
-    page, so at most the pool's usable pages plus one a row), ``batch *
-    walk_pages`` when not given; either kernel's grid is made from it.
+    place that tells what either walks).  ``steps`` is an upper bound
+    the caller knows on the pages the batch walks (the allocator's: no
+    two rows share a page, so at most the pool's usable pages plus one
+    a row), ``batch * walk_pages`` when not given; either kernel's grid
+    is made from it.
     ``out_dtype`` (the grouped kernel and the reference) is the result's
     dtype where ``q.dtype`` is too coarse for what follows.
 
@@ -623,16 +727,18 @@ def paged_attention(q, k_pool, v_pool, page_tables, lengths, *, layer,
         walk = chunk_walk(q, k_pool, page_tables.shape[1], window=window,
                           steps=steps)
         if walk is None:
+            raise ValueError("an int8 pool is paged_attention_int8's")
+        chunk, grid = walk[0] // k_pool.shape[2], walk[1]
+        if _grouped(q, k_pool, window):
             return _paged_attention_gqa_pallas(
-                q, k_pool, v_pool, page_tables, lengths, layer=layer,
-                sm_scale=sm_scale, window=window or 0, steps=steps,
-                interpret=interpret, out_dtype=out_dtype)
+                q, k_pool, v_pool, page_tables, lengths, layer,
+                sm_scale=sm_scale, window=window or 0, chunk=chunk,
+                grid=grid, interpret=interpret, out_dtype=out_dtype)
         if out_dtype is not None:
             raise ValueError("out_dtype: the grouped kernel's only")
         return _paged_attention_pallas(
             q, k_pool, v_pool, page_tables, lengths, layer,
-            sm_scale=sm_scale, chunk=walk[0] // k_pool.shape[2],
-            grid=walk[1], interpret=interpret)
+            sm_scale=sm_scale, chunk=chunk, grid=grid, interpret=interpret)
     record_dispatch("paged_attention", "fallback")
     return paged_attention_reference(q, k_pool, v_pool, page_tables,
                                      lengths, layer=layer,

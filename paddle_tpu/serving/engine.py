@@ -22,9 +22,11 @@ build records every executable's temporary, argument and aliased bytes
 ``pt_serve_program_bytes{program=,kind=}``).  A program that copied,
 sliced or re-laid a pool would show a pool-sized temporary there.
 Beside it, ``engine.stats["paged_walk"]`` (also ``/healthz``) says what
-the equal-heads paged-attention kernel of each decode program walks:
-the tokens a grid step meets and the grid's length; the scheduler sums
-both a decode step (``paged_chunks_walked``, ``paged_grid_steps``).
+the paged-attention kernels of each decode program walk: the tokens a
+grid step meets and the grid's length, of the work list over the full
+layers' pool and (``"window"``) of the one over the sliding layers'; the
+scheduler sums both a decode step and a list (``paged_chunks_walked``,
+``paged_grid_steps``).
 
 Zero-downtime weight swap: with a ``CheckpointManager`` attached,
 :meth:`ServingEngine.maybe_reload` hot-swaps to generation N+1 between
@@ -291,13 +293,14 @@ class ServingEngine:
             self._warmed = False
             self._prefill_exe: Dict[int, Any] = {}
             self._decode_exe: Dict[int, Any] = {}
-            self._decode_walk: Dict[int, Dict[str, int]] = {}
+            self._decode_walk: Dict[int, Dict[str, Any]] = {}
             self.compiled_programs = 0
             # program name -> {"temp", "argument", "alias"} bytes, from
             # each executable's memory_analysis() at build
-            # and, where decode runs the equal-heads paged-attention
-            # kernel, program name -> {"chunk_tokens", "grid_steps"}:
-            # the tokens a grid step meets and the kernel's grid length
+            # and program name -> {"chunk_tokens", "grid_steps"}, the
+            # tokens a grid step of decode's paged-attention kernel meets
+            # and its grid length (model.decode_walk: the sliding
+            # layers' list under "window"; no entry for an int8 pool)
             self.stats: Dict[str, Any] = {"program_bytes": {},
                                           "paged_walk": {}}
             self._build_programs()
@@ -466,11 +469,13 @@ class ServingEngine:
                 jax.ShapeDtypeStruct(self.table_shape, i32))
 
         for b in cfg.decode_buckets:
-            walk = decode_walk(spec, b, k_struct, self.max_pages_per_seq)
+            walk = decode_walk(
+                spec, b, k_struct, self.max_pages_per_seq,
+                _struct_like(self.pool.window_pool.k_pool)
+                if self.pool.window_pool is not None else None)
             if walk is not None:
                 self._decode_walk[b] = self.stats["paged_walk"][
-                    f"serve_decode_b{b}{sfx}"] = {
-                        "chunk_tokens": walk[0], "grid_steps": walk[1]}
+                    f"serve_decode_b{b}{sfx}"] = walk
             self._decode_exe[b] = _compile(
                 dec_jit, f"serve_decode_b{b}{sfx}",
                 p_struct, *kv_args,
@@ -636,9 +641,9 @@ class ServingEngine:
             f"{self.config.decode_buckets[-1]}")
 
     def paged_walk_for(self, n: int) -> Optional[Dict[str, int]]:
-        """What the paged-attention kernel of the program that decodes
-        ``n`` rows walks (``stats["paged_walk"]``); ``None`` where decode
-        does not run the equal-heads kernel."""
+        """What the paged-attention kernels of the program that decodes
+        ``n`` rows walk (``stats["paged_walk"]``); ``None`` where decode
+        walks no work list (an int8 pool)."""
         return self._decode_walk.get(self.decode_bucket_for(n))
 
     def prefill(self, tokens: Sequence[int],

@@ -858,19 +858,36 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                     logits, counts)
 
 
-def decode_walk(spec: ModelSpec, batch: int, k_pool, max_pages: int):
-    """``(tokens a chunk, grid length)`` of the equal-heads paged-attention
-    kernel as :func:`decode_step` calls it on its full-attention layers
-    for a bucket of ``batch`` rows over ``k_pool`` (its shape and dtype:
-    an array or a ``ShapeDtypeStruct``); ``None`` where those layers run
-    another kernel (``ops.paged_attention.chunk_walk`` tells) or there
-    are none."""
-    if not spec.global_layers:
-        return None
-    q = jax.ShapeDtypeStruct((batch, spec.heads, spec.head_dim),
-                             k_pool.dtype)
-    return chunk_walk(q, k_pool, max_pages,
-                      steps=_pages_walked(k_pool.shape[1], batch))
+def decode_walk(spec: ModelSpec, batch: int, k_pool, max_pages: int,
+                kw_pool=None):
+    """What the paged-attention kernels of :func:`decode_step` walk for a
+    bucket of ``batch`` rows over ``k_pool`` and, for a model with
+    sliding layers, ``kw_pool`` (shapes and dtypes: arrays or
+    ``ShapeDtypeStruct``s): ``{"chunk_tokens", "grid_steps"}`` of the
+    work list over the full layers' pool (one list, however many layers
+    read it) and, under ``"window"``, the same two of the list over the
+    sliding layers' pool beside the window's ``"tokens"``.  ``None``
+    where no list is walked (``ops.paged_attention.chunk_walk`` tells:
+    an int8 pool)."""
+    heads, lanes = spec.heads, spec.head_dim
+    if spec.diff_attn:              # a KV pair a head of 2D lanes
+        lanes *= 2
+
+    def walk(pool, window=None):
+        found = chunk_walk(
+            jax.ShapeDtypeStruct((batch, heads, lanes), pool.dtype), pool,
+            max_pages, window=window,
+            steps=_pages_walked(pool.shape[1], batch))
+        return found and {"chunk_tokens": found[0], "grid_steps": found[1]}
+
+    out = {}
+    if spec.global_layers:
+        out.update(walk(k_pool) or {})
+    if spec.window_layers and kw_pool is not None:
+        sliding = walk(kw_pool, spec.window)
+        if sliding:
+            out["window"] = dict(sliding, tokens=spec.window)
+    return out or None
 
 
 def _pages_walked(pages: int, batch: int) -> int:

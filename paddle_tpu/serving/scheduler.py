@@ -64,8 +64,9 @@ same boundaries add to ``stats`` as float sums (``wait_s``, ``evict_s``,
 ``book_s``) beside the per-request ``lock_wait_s`` (entry of
 :meth:`~ContinuousScheduler.submit` to lock held), ``queue_wait_s``
 (enqueued to its prefill entered, count ``admitted``), ``ttft_s`` and
-``tpot_s`` (count ``tpot_requests``).  What the decode kernel walked:
-``paged_chunks_walked`` and ``paged_grid_steps``
+``tpot_s`` (count ``tpot_requests``).  What the decode kernels walked,
+a work list the program has (the full layers' pool, the sliding
+layers'): ``paged_chunks_walked`` and ``paged_grid_steps``
 (``pt_serve_paged_chunks_total{state="walked"|"grid"}``).  A model with
 state-space layers: ``state_slots_held`` (now) and
 ``state_slots_held_max``, ``refused_state`` (admissions refused for want
@@ -285,9 +286,10 @@ class ContinuousScheduler:
             "kv_window_pages_returned": 0,
             "moe_tokens_routed": 0, "moe_expert_max_tokens": 0,
             "moe_decode_experts_touched": 0,
-            # the equal-heads paged-attention kernel's walk, summed over
-            # decode steps: chunks the rows' contexts fill, and the grid
-            # steps of the bucket's program (engine.stats["paged_walk"])
+            # the paged-attention kernels' walks, summed over decode
+            # steps and the program's work lists: chunks the rows'
+            # contexts (or windows) fill, and the lists' grid steps in
+            # the bucket's program (engine.stats["paged_walk"])
             "paged_chunks_walked": 0, "paged_grid_steps": 0,
             # state-space layers: slots held now and at most, admissions
             # refused for want of one, prompt positions scanned; cross
@@ -608,12 +610,17 @@ class ContinuousScheduler:
             tables = np.stack([a.pages.table for a in self._active])
             walk = self.engine.paged_walk_for(n)
             if walk is not None:
-                # sums only: the registry follows them when a request
-                # retires (_book_walk_locked), off the step's path
-                ct = walk["chunk_tokens"]
-                stats["paged_chunks_walked"] += sum(
-                    chunks_of(a.pos + 1, ct) for a in self._active)
-                stats["paged_grid_steps"] += walk["grid_steps"]
+                # sums only, a list the program walks: the registry
+                # follows them when a request retires
+                # (_book_walk_locked), off the step's path
+                lengths = positions + 1
+                for found in (walk, walk.get("window")):
+                    if found and "grid_steps" in found:
+                        stats["paged_chunks_walked"] += int(chunks_of(
+                            lengths, found["chunk_tokens"],
+                            page_size=self.engine.config.page_size,
+                            window=found.get("tokens", 0)).sum())
+                        stats["paged_grid_steps"] += found["grid_steps"]
             # watchdog arms on the device call
             self._step_started = t0 = time.monotonic()
         stats["decode_prep_s"] += sp.seconds
@@ -1001,9 +1008,9 @@ _METRIC_HELP = {
     "pt_serve_moe_expert_max_tokens_total":
         "Tokens of the busiest expert, summed over calls and layers",
     "pt_serve_paged_chunks_total":
-        "Equal-heads paged attention, summed over decode steps: chunks "
-        "the rows' contexts fill (walked) and grid steps of the "
-        "bucket's program (grid)",
+        "Paged attention, summed over decode steps and the program's "
+        "work lists: chunks the rows' contexts or windows fill (walked) "
+        "and grid steps of the bucket's program (grid)",
     "pt_serve_queue_depth": "Requests waiting for admission",
     "pt_serve_active_sequences": "Sequences resident in the decode batch",
     "pt_serve_batch_occupancy":

@@ -540,7 +540,8 @@ HYBRID_METRICS = ["ssm_ms_per_decode_step", "ssm_ms_per_prefill",
 
 def test_hybrid_metrics_have_entries_for_their_cell_and_read_nothing_elsewhere(
         bench):
-    """PR 31's nine metrics: entries after everything that was there, a
+    """PR 31's nine metrics: entries after everything that was there
+    (PR 32's one after them), a
     reader each, and nothing read (no exception) from a run of a program
     that has no such scope, counter or model fact: the parent's, or
     another configuration's."""
@@ -550,8 +551,9 @@ def test_hybrid_metrics_have_entries_for_their_cell_and_read_nothing_elsewhere(
     assert spec["workloads"][-1]["chips"] == 1
     assert spec["configs"][-1]["name"] == spec["workloads"][-1]["config"]
     assert spec["configs"][-1]["reduced"] == []
-    assert [m["name"] for m in spec["per_layer"][-9:]] == HYBRID_METRICS
-    for m in spec["per_layer"][-9:]:
+    assert [m["name"] for m in spec["per_layer"][-10:-1]] == HYBRID_METRICS
+    assert spec["per_layer"][-1]["name"] == "paged_walk_live_pct.grouped"
+    for m in spec["per_layer"][-10:-1]:
         assert m["workloads"] == [cell]
         assert m["moves"] == "serve_tokens_per_s"
     empty = {"trace": None, "values": {}, "counters": {}, "spans": {},
@@ -593,16 +595,21 @@ def test_hybrid_mfu_reads_the_counters(bench):
 # -- the equal-heads kernel's walk (PR 28) -----------------------------------
 
 WALK_METRICS = {"paged_walk_live_pct.steady":
-                ("tpot_p50_ms", "gpt345m-serve-complete-steady"),
+                ("tpot_p50_ms", ["gpt345m-serve-complete-steady"]),
                 "paged_walk_live_pct.backlog":
-                ("serve_tokens_per_s", "gpt345m-serve-longprompt-backlog")}
+                ("serve_tokens_per_s", ["gpt345m-serve-longprompt-backlog"]),
+                # the grouped kernels' two lists (PR 32)
+                "paged_walk_live_pct.grouped":
+                ("serve_tokens_per_s", ["mellum2-serve-mixedctx-backlog",
+                                        "phi4flash-serve-reasoning-backlog"])}
 
 
 @pytest.mark.parametrize("name", WALK_METRICS)
 def test_walk_share_is_chunks_walked_over_grid_steps(bench, name):
     """94 decode steps of the 32-row bucket (160 grid steps each) whose
     rows' contexts filled 13,160 chunks; and a program that keeps no such
-    counter (the parent, or a grouped model): nothing to read."""
+    counter, or leaves it zero (the parent of PR 28; for a grouped model
+    the parent of PR 32): nothing to read."""
     run = {"trace": None, "counters": {"paged_chunks_walked": 13160,
                                        "paged_grid_steps": 94 * 160}}
     assert read(bench, name, run) == pytest.approx(87.5)
@@ -613,8 +620,7 @@ def test_walk_share_is_chunks_walked_over_grid_steps(bench, name):
     assert read(bench, name, {"trace": None}) is None
     spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     entry = next(m for m in spec["per_layer"] if m["name"] == name)
-    moves, cell = WALK_METRICS[name]
-    assert (entry["moves"], entry["workloads"]) == (moves, [cell])
+    assert (entry["moves"], entry["workloads"]) == WALK_METRICS[name]
     assert entry["source"] == "program_counter"
     assert entry["layer"] == "kernels (ops/paged_attention.py)"
 

@@ -184,14 +184,16 @@ def test_chunk_list_lists_exactly_the_pages_each_row_reads(lengths, want):
 
 
 def test_chunk_length_one_is_the_page_list():
-    """The grouped kernels' lists: ``chunk`` defaults to a page."""
+    """One form of the list for every kernel: a chunk of one page is the
+    rows' pages in order."""
     tables = np.arange(1, 1 + 2 * MAXP, dtype=np.int32).reshape(2, MAXP)
-    args = (jnp.asarray(tables), jnp.asarray([40, 17], jnp.int32))
-    by_default = pa._walk(*args, ps=PS, window=0, steps=8)
-    as_chunks = pa._walk(*args, ps=PS, window=0, steps=8, chunk=1)
-    for a, b in zip(by_default, as_chunks):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert list(np.asarray(by_default[1])[:5]) == [1, 2, 3, 25, 26]
+    rows, pages, slots, first, last = (np.asarray(a) for a in pa._walk(
+        jnp.asarray(tables), jnp.asarray([40, 17], jnp.int32), ps=PS,
+        window=0, steps=8, chunk=1))
+    assert list(pages[:5]) == [1, 2, 3, 25, 26]
+    assert list(rows[:5]) == [0, 0, 0, 1, 1]
+    assert list(slots) == [0, 1, 2, 0, 1, -1, -1, -1]
+    assert list(first) == [0, 0] and list(last) == [2, 1]
 
 
 @pytest.mark.parametrize("page_size,max_pages,want", [

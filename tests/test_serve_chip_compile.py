@@ -300,21 +300,26 @@ def test_chip_compiles_the_grouped_windowed_sparse_programs(
 @pytest.mark.parametrize("window,pages", [(0, LM_PAGES),
                                           (1024, LM_WINDOW_PAGES)])
 def test_chip_compiles_the_grouped_kernel_alone(one_chip, window, pages):
-    from paddle_tpu.ops.paged_attention import _paged_attention_gqa_pallas
+    from paddle_tpu.ops.paged_attention import (
+        _paged_attention_gqa_pallas, chunk_walk)
     bf = jnp.bfloat16
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def kernel(q, k, v, tables, lengths):
+    q, pool = sds((64, 32, 128), bf), sds((1, pages, LM_PAGE, 512), bf)
+    tokens, grid = chunk_walk(q, pool, 64, window=window,
+                              steps=pages - 1 + 64)
+
+    def kernel(q, k, v, tables, lengths, layer):
         return _paged_attention_gqa_pallas(
-            q, k, v, tables, lengths, layer=0, sm_scale=128 ** -0.5,
-            window=window, steps=pages - 1 + 64, interpret=False)
+            q, k, v, tables, lengths, layer, sm_scale=128 ** -0.5,
+            window=window, chunk=tokens // LM_PAGE, grid=grid,
+            interpret=False)
 
     exe = jax.jit(kernel).lower(
-        sds((64, 32, 128), bf), sds((1, pages, LM_PAGE, 512), bf),
-        sds((1, pages, LM_PAGE, 512), bf), sds((64, 64), jnp.int32),
-        sds((64,), jnp.int32)).compile()
+        q, pool, pool, sds((64, 64), jnp.int32), sds((64,), jnp.int32),
+        sds((), jnp.int32)).compile()
     assert "tpu_custom_call" in exe.as_text()
     # the walk list and the padded rows: far from a pool
     assert exe.memory_analysis().temp_size_in_bytes < 16 << 20
@@ -407,6 +412,77 @@ def test_chip_compiles_the_hybrid_programs(one_chip, monkeypatch, kind,
         # no (heads, S, S) scores at the long bucket (no width is 2048)
         for dtype, dims, op, line in _shapes_of(text):
             assert dims[-2:] != (2048, 2048), line[:200]
+
+
+def _paged_calls(text):
+    """(kernel name, operand names, operand shapes) of every grouped
+    paged-attention call in the optimized HLO."""
+    found = []
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line or "paged_attention" not in line:
+            continue
+        name = re.search(r"%(paged_attention_\w+?)[.\d]* = ", line).group(1)
+        operands = line.split("custom-call(", 1)[1].split(")", 1)[0]
+        operands = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")
+        shapes = line.split("operand_layout_constraints={", 1)[1]
+        found.append((name, [o.split(" ")[-1] for o in operands], shapes))
+    return found
+
+
+# name -> (compile, spec, calls a kernel, the parent's temporaries: 12.35
+# and 16.63 MB with a page a grid step)
+GROUPED_PROGRAMS = {
+    "mellum": (_compile_lm, LM_SPEC,
+               {"paged_attention_gqa": 1, "paged_attention_window": 3},
+               12 << 20),
+    "phi": (_compile_phi, PHI_SPEC,
+            {"paged_attention_gqa": 2, "paged_attention_window": 2},
+            16 << 20)}
+
+
+@pytest.mark.parametrize("model", GROUPED_PROGRAMS)
+def test_chip_decode_programs_walk_chunks_of_both_pools(one_chip,
+                                                        monkeypatch, model):
+    """The 64-row decode programs of the two served models: each grouped
+    kernel under its name on a grid of chunks (`chunk_walk`'s, not the
+    pages' 2,112 / 2,048 and 576 / 320), one work list a pool whichever
+    of its layers reads it, the layer a scalar operand of one traced
+    kernel, the pools whole for the kernel's own copies, the two-deep
+    tiles well inside the scoped VMEM, temporaries under the parent's."""
+    from paddle_tpu.ops.paged_attention import chunk_walk, walk_pages
+    build, spec, want, parent_temp = GROUPED_PROGRAMS[model]
+    exe, state = build(one_chip, monkeypatch, "decode", 64)
+    page, heads, d = state[0].shape[2], spec.heads, spec.head_dim
+    if spec.diff_attn:
+        d *= 2
+    maxp = spec.max_seq_len // page
+    calls = _paged_calls(exe.as_text())
+    assert {n: sum(1 for c in calls if c[0] == n) for n in want} == want
+    lists = {}
+    for name, operands, shapes in calls:
+        window = spec.window if name.endswith("window") else 0
+        pool = state[2 if window else 0]
+        tokens, grid = chunk_walk(
+            jax.ShapeDtypeStruct((64, heads, d), pool.dtype), pool, maxp,
+            window=window, steps=pool.shape[1] - 1 + 64)
+        chunk = tokens // page
+        # a step's K and V between 2 and 3.5 MiB, twice that in VMEM
+        tile = 2 * tokens * pool.shape[3] * 2
+        assert 2 << 20 <= tile <= 7 << 19
+        assert grid <= 64 * -(-walk_pages(maxp, page, window) // chunk)
+        assert grid < (pool.shape[1] - 1 + 64) / chunk + 64
+        # rows, chunk pages a step, slots; lengths, first and last chunk,
+        # the window's shift; the layer; q; the pools whole
+        whole = "bf16[%s]{3,2,1,0}" % ",".join(str(x) for x in pool.shape)
+        assert shapes.startswith(
+            f"s32[{grid}]{{0}}, s32[{chunk * grid}]{{0}}, s32[{grid}]{{0}}, "
+            + "s32[64]{0}, " * 4 + "s32[1]{0}, "
+            + f"bf16[64,{pool.shape[3] // 128},16,128]{{3,2,1,0}}, "
+            + f"{whole}, {whole}"), shapes[:300]
+        lists.setdefault(name, set()).add(tuple(operands[:7]))
+    # one list a pool: every layer's call takes the same seven
+    assert all(len(v) == 1 for v in lists.values()), lists
+    assert exe.memory_analysis().temp_size_in_bytes < parent_temp
 
 
 @pytest.mark.parametrize("positions", [256, 2048])

@@ -749,12 +749,14 @@ def test_engine_publishes_the_equal_heads_kernels_walk(engine):
     q = jnp.zeros((4, engine.spec.heads, engine.spec.head_dim))
     assert chunk_walk(q, engine.pool.k_pool, 16,
                       steps=CFG.kv_pages - 1 + 4) == (64, 4)
-    # the one place that tells which kernel runs: not this one under a
-    # window, with fewer heads in the pool than q has, or on int8 pages
-    assert chunk_walk(q, engine.pool.k_pool, 16, window=8) is None
+    # the one place that tells what a kernel walks: under a window (of 8
+    # tokens: the 3 pages it can touch, one chunk) or with fewer heads in
+    # the pool than q has the grouped kernels' chunks, which at this size
+    # are a row's whole walk; nothing on int8 pages
+    assert chunk_walk(q, engine.pool.k_pool, 16, window=8) == (12, 4)
     assert chunk_walk(jnp.zeros((4, 2 * engine.spec.heads,
                                  engine.spec.head_dim)),
-                      engine.pool.k_pool, 16) is None
+                      engine.pool.k_pool, 16) == (64, 4)
     assert chunk_walk(q, engine.pool.k_pool.astype(jnp.int8), 16) is None
     want = {"serve_decode_b4": {"chunk_tokens": 64, "grid_steps": 4}}
     assert engine.stats["paged_walk"] == want
@@ -780,10 +782,36 @@ def test_walk_counters_sum_the_rows_chunks_and_the_grid(
     assert sched.snapshot()["paged_grid_steps"] == s["paged_grid_steps"]
 
 
+@pytest.mark.parametrize("window,chunk_tokens,per_request", [
+    # page 4: a request of 3 prompt tokens decodes at lengths 4 and 5,
+    # which a window of 2 sees from pages 0 and 0, a window of 1 from
+    # pages 0 and 1: (full list, 64 a chunk) + (window list)
+    (2, 4, (1 + 1) + (1 + 2)), (1, 4, (1 + 1) + (1 + 1)),
+    (2, 8, (1 + 1) + (1 + 1)), (64, 4, (1 + 1) + (1 + 2))])
+def test_walk_counters_add_the_sliding_layers_list(
+        engine, monkeypatch, window, chunk_tokens, per_request):
+    """A model with sliding layers walks two lists a decode step: each
+    adds its rows' chunks (a window's from the page it starts in) and its
+    grid."""
+    from paddle_tpu.serving.scheduler import ContinuousScheduler
+    monkeypatch.setattr(engine, "_decode_walk", {
+        4: {"chunk_tokens": 64, "grid_steps": 9,
+            "window": {"tokens": window, "chunk_tokens": chunk_tokens,
+                       "grid_steps": 5}}})
+    sched = ContinuousScheduler(engine)
+    streams = [sched.submit([1, 2, 3], max_new_tokens=3) for _ in range(3)]
+    sched.drain()
+    assert all(len(st.result(timeout=10.0)) == 3 for st in streams)
+    s = sched.stats
+    assert s["paged_chunks_walked"] == 3 * per_request
+    assert s["paged_grid_steps"] == (9 + 5) * s["occupancy_steps"] > 0
+    assert s["paged_chunks_walked"] <= s["paged_grid_steps"]
+
+
 def test_walk_counters_stay_zero_where_no_program_has_the_walk(
         engine, monkeypatch):
-    """A grouped or windowed model, an int8 pool: ``paged_walk`` is empty
-    and the scheduler counts nothing."""
+    """An int8 pool: ``paged_walk`` is empty and the scheduler counts
+    nothing."""
     from paddle_tpu.serving.scheduler import ContinuousScheduler
     monkeypatch.setattr(engine, "_decode_walk", {})
     assert engine.paged_walk_for(1) is None

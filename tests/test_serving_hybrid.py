@@ -424,6 +424,34 @@ def test_the_scheduler_counts_slots_scans_and_shared_reads(built):
     engine.pool.check_consistency(expect_all_free=True)
 
 
+def test_the_scheduler_counts_the_chunks_of_both_work_lists(built):
+    """Differential layers read KV pairs through the grouped kernels: a
+    decode program walks one list over the full layer's pool (the cross
+    layers read the same one) and one over the sliding layers'."""
+    from paddle_tpu.ops.paged_attention import chunks_of
+    engine, _, spec = built
+    sched = engine.scheduler
+    walk = engine.stats["paged_walk"]
+    assert walk == engine.healthz()["paged_walk"]
+    assert len(walk) == len(engine.config.decode_buckets)
+    before = dict(sched.stats)
+    prompts = [[5, 9, 2, 7, 1, 1, 3], [3, 4], [8] * 20]
+    out = engine.generate(prompts, max_new_tokens=5)
+    found = engine.paged_walk_for(len(prompts))
+    assert found["window"]["tokens"] == WINDOW == spec.window
+    d = {k: sched.stats[k] - before[k] for k in (
+        "paged_chunks_walked", "paged_grid_steps", "occupancy_steps")}
+    assert d["paged_chunks_walked"] == sum(
+        chunks_of(n, found["chunk_tokens"])
+        + chunks_of(n, found["window"]["chunk_tokens"], page_size=PS,
+                    window=WINDOW)
+        for p, o in zip(prompts, out)
+        for n in range(len(p) + 1, len(p) + len(o))) > 0
+    assert d["paged_grid_steps"] == d["occupancy_steps"] * (
+        found["grid_steps"] + found["window"]["grid_steps"])
+    assert d["paged_chunks_walked"] <= d["paged_grid_steps"]
+
+
 def test_a_request_waits_for_a_slot_and_is_counted(built):
     engine, _, _ = built
     sched, slots = engine.scheduler, engine.pool.state_slots

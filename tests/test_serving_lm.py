@@ -342,9 +342,33 @@ def test_scheduler_counters_and_health(built):
     assert kv["window"]["pages_returned"] >= s1["kv_window_pages_returned"]
     assert engine.expert_counts() is not None
     engine.pool.check_consistency(expect_all_free=True)
-    # grouped and windowed layers walk page by page: no chunk walk
-    assert engine.stats["paged_walk"] == {} == engine.healthz()["paged_walk"]
-    assert s1["paged_chunks_walked"] == s1["paged_grid_steps"] == 0
+    # the grouped kernels' two work lists, a decode program: the full
+    # layers' pool and the sliding layers', counted a step and a list
+    walk = engine.stats["paged_walk"]
+    assert walk == engine.healthz()["paged_walk"]
+    assert len(walk) == len(engine.config.decode_buckets)
+    assert all(name.startswith("serve_decode_b") for name in walk)
+    for program in walk.values():
+        assert program["window"]["tokens"] == WINDOW
+        for found in (program, program["window"]):
+            assert found["chunk_tokens"] % PS == 0 < found["grid_steps"]
+        # a window of 8 tokens touches 3 pages of 4: one chunk at most
+        assert program["window"]["chunk_tokens"] <= 3 * PS
+    from paddle_tpu.ops.paged_attention import chunks_of
+    found = engine.paged_walk_for(len(prompts))
+    assert found in walk.values()
+    want = sum(
+        chunks_of(n, found["chunk_tokens"])
+        + chunks_of(n, found["window"]["chunk_tokens"], page_size=PS,
+                    window=WINDOW)
+        for p, o in zip(prompts, out)
+        for n in range(len(p) + 1, len(p) + len(o)))
+    walked = s1["paged_chunks_walked"] - s0["paged_chunks_walked"]
+    grid = s1["paged_grid_steps"] - s0["paged_grid_steps"]
+    assert walked == want > 0
+    assert walked <= grid
+    assert grid == (s1["occupancy_steps"] - s0["occupancy_steps"]) * (
+        found["grid_steps"] + found["window"]["grid_steps"])
 
 
 # -- the spec ---------------------------------------------------------------
