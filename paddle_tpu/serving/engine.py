@@ -276,7 +276,9 @@ class ServingEngine:
                 state_layers=len(spec.ssm_layers),
                 state_slots=1 + self.config.decode_buckets[-1],
                 state_shape=(spec.ssm_inner, spec.ssm_state,
-                             spec.ssm_conv))
+                             spec.ssm_conv),
+                index_dim=(spec.index_head_size if spec.sparse_topk
+                           else 0))
             # the page table the programs take: one row of pages, or the
             # full layers' row above the sliding layers' (above the row
             # that holds the state slot)
@@ -294,6 +296,7 @@ class ServingEngine:
             self._prefill_exe: Dict[int, Any] = {}
             self._decode_exe: Dict[int, Any] = {}
             self._decode_walk: Dict[int, Dict[str, Any]] = {}
+            self._selection_exe = None      # learned sparse attention's
             self.compiled_programs = 0
             # program name -> {"temp", "argument", "alias"} bytes, from
             # each executable's memory_analysis() at build
@@ -353,13 +356,17 @@ class ServingEngine:
         # the two jitted functions are named like the programs they build
         # (serve_prefill_s<S> / serve_decode_b<B>), so jax's compile log,
         # the compile watcher and profiler traces all say "serve_*"
-        if self.pool.state_slots is not None:
+        if self.pool.state_slots is not None \
+                or self.pool.index_pool is not None:
             # every pool the model has is donated state, in the order of
-            # PagePool.state(): the full layers', the sliding layers'
-            # where there are any, the state-space layers' two last
+            # PagePool.state(): the full layers', the index pool, the
+            # sliding layers' where there are any, the state-space
+            # layers' two last
             names = ("k_pool", "v_pool") + (
-                ("kw_pool", "vw_pool") if self.pool.window_pool else ()
-            ) + ("conv_pool", "ssm_pool")
+                ("index_pool",) if self.pool.index_pool is not None else ()
+            ) + (("kw_pool", "vw_pool") if self.pool.window_pool else ()
+                 ) + (("conv_pool", "ssm_pool")
+                      if self.pool.state_slots is not None else ())
             n_state = len(names)
 
             def serve_prefill(params, *args):
@@ -368,11 +375,14 @@ class ServingEngine:
                                     *args[n_state:], page_size=ps,
                                     **dict(zip(names[2:], rest)))
 
-            def serve_decode(params, *args):
+            def serve_decode(params, *args, **kw):
                 k_pool, v_pool, *rest = args[:n_state]
                 return decode_step(spec, params, k_pool, v_pool,
                                    *args[n_state:], page_size=ps,
-                                   **dict(zip(names[2:], rest)))
+                                   **dict(zip(names[2:], rest)), **kw)
+
+            def serve_decode_selection(params, *args):
+                return serve_decode(params, *args, selection=True)
 
             donate = tuple(range(1, 1 + n_state))
             labels = ("params",) + names + ("tokens", "positions",
@@ -483,7 +493,21 @@ class ServingEngine:
                 jax.ShapeDtypeStruct((b,), i32),
                 jax.ShapeDtypeStruct((b, *self.table_shape), i32))
 
-        self.compiled_programs = len(self._prefill_exe) + len(self._decode_exe)
+        if spec.sparse_topk:
+            # the largest bucket's step once more, with what its sparse
+            # layers scored and selected among its outputs: a check's
+            # program (decode_selection), not the scheduler's
+            b = cfg.decode_buckets[-1]
+            self._selection_exe = _compile(
+                jax.jit(serve_decode_selection, donate_argnums=donate),
+                f"serve_decode_b{b}{sfx}_selection", p_struct, *kv_args,
+                jax.ShapeDtypeStruct((b,), i32),
+                jax.ShapeDtypeStruct((b,), i32),
+                jax.ShapeDtypeStruct((b, *self.table_shape), i32))
+
+        self.compiled_programs = (len(self._prefill_exe)
+                                  + len(self._decode_exe)
+                                  + (self._selection_exe is not None))
         logger.info(
             "serve programs compiled: %d prefill buckets %s, %d decode "
             "buckets %s", len(self._prefill_exe),
@@ -536,9 +560,9 @@ class ServingEngine:
 
     def _run(self, exe, params, *args):
         """Call one program and rebind the pools to what it returns:
-        ``(token(s), logits)`` still on the device.  A model of routed
-        experts also returns its tokens per expert, kept for
-        :meth:`take_aux`."""
+        ``(token(s), logits)`` still on the device (then what the
+        selection program adds).  A model of routed experts also returns
+        its tokens per expert, kept for :meth:`take_aux`."""
         state = self._kv_state()
         out = exe(params, *state, *args)
         self.pool.swap(*out[:len(state)])
@@ -549,7 +573,7 @@ class ServingEngine:
         rest[0].copy_to_host_async()
         if self._aux is not None:
             self._aux.copy_to_host_async()
-        return rest[0], rest[1]
+        return (rest[0], rest[1], *rest[3:])
 
     def expert_counts(self) -> Optional[np.ndarray]:
         """Tokens the last program call routed to each expert of each
@@ -578,10 +602,13 @@ class ServingEngine:
         for s, exe in self._prefill_exe.items():
             self._run(exe, self._params, np.zeros((s,), np.int32),
                       np.int32(1), np.zeros(self.table_shape, np.int32))
-        for b, exe in self._decode_exe.items():
-            self._run(exe, self._params, np.zeros((b,), np.int32),
-                      np.zeros((b,), np.int32),
-                      np.zeros((b, *self.table_shape), np.int32))
+        last = self.config.decode_buckets[-1]
+        for b, exe in [*self._decode_exe.items(),
+                       (last, self._selection_exe)]:
+            if exe is not None:
+                self._run(exe, self._params, np.zeros((b,), np.int32),
+                          np.zeros((b,), np.int32),
+                          np.zeros((b, *self.table_shape), np.int32))
         self._aux = None
         jax.block_until_ready(self.pool.k_pool)
 
@@ -700,9 +727,29 @@ class ServingEngine:
         through the same executable and cache the scheduler uses."""
         return self._decode(tokens, positions, page_tables, True)
 
-    def _decode(self, tokens, positions, page_tables, want_logits):
+    def decode_selection(self, tokens: np.ndarray, positions: np.ndarray,
+                         page_tables: np.ndarray):
+        """:meth:`decode_logits` of a model of learned sparse attention
+        through the selection program: the step of the largest bucket
+        (fewer rows are padded to it) that also returns what every sparse
+        layer decided.  ``(next tokens (n,), logits (n, V), positions (L,
+        n, topk) int32, scores [L x (n, max_pages * ps) float32])``: a
+        row's first ``min(length, topk)`` positions count, its scores up
+        to its length.  A step writes each row's K, V and indexer key of
+        ``positions`` and nothing else, so called after :meth:`decode` or
+        :meth:`decode_logits` with the same arguments it leaves the pools
+        as they were: the way to hold this program to the scheduler's."""
         n = tokens.shape[0]
-        b = self.decode_bucket_for(max(n, 1))
+        nxt, logits, chosen, *scores = self._decode(
+            tokens, positions, page_tables, True, self._selection_exe)
+        return (nxt, logits, np.asarray(chosen)[:, :n],
+                [np.asarray(a)[:n] for a in scores])
+
+    def _decode(self, tokens, positions, page_tables, want_logits,
+                selection_exe=None):
+        n = tokens.shape[0]
+        b = (self.config.decode_buckets[-1] if selection_exe is not None
+             else self.decode_bucket_for(max(n, 1)))
         with span("serve.decode.prep", rows=n, bucket=b):
             tok = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
@@ -713,11 +760,11 @@ class ServingEngine:
             with self._weights_lock:
                 params = self._params
         with span("serve.decode.launch", rows=n, bucket=b):
-            nxt, logits = self._run(self._decode_exe[b], params, tok, pos,
-                                    pt)
+            nxt, logits, *more = self._run(
+                selection_exe or self._decode_exe[b], params, tok, pos, pt)
         with span("serve.decode.fetch", rows=n, bucket=b):
-            return np.asarray(nxt)[:n], (
-                np.asarray(logits, np.float32)[:n] if want_logits else None)
+            return (np.asarray(nxt)[:n], np.asarray(logits, np.float32)[:n]
+                    if want_logits else None, *more)
 
     # -- weights ------------------------------------------------------------
 

@@ -11,7 +11,8 @@ chosen statically by the program's token count:
    matmul over the ``E`` groups (:func:`grouped_matmul`: each pair's
    FLOPs once), and the results are unsorted and combined.  Tokens past
    the prompt (``valid`` false) sort behind every group and cost
-   nothing.
+   nothing.  A program of more than ``SLAB_TOKENS`` tokens does this a
+   slab of its tokens at a time, so that the sorted pairs stay small.
  - **dense** (few rows, decode): every row goes through every expert and
    the router's weights zero what was not chosen.  It reads each expert
    matrix exactly once, gathers none, and its ``E / k``-fold redundant
@@ -41,6 +42,12 @@ __all__ = ["route", "experts", "moe_ffn", "expert_counts",
 # to the largest decode bucket anyone builds today, so every decode
 # program is dense and every prefill program grouped.
 DENSE_MAX_TOKENS = 128
+
+# Most tokens the grouped regime sorts at once: a longer program runs it a
+# slab of its tokens at a time (the sorted pairs of 32,768 tokens are
+# 262,144 rows, 1 GB a copy in bfloat16 at 2,048 lanes).  By the row count
+# alone, and what the longest program had before there were slabs.
+SLAB_TOKENS = 8192
 
 
 def route(y, router, top_k):
@@ -154,7 +161,16 @@ def experts(y, gates, idx, wg, wu, wd, *, dense=None, valid=None):
         dense = y.shape[0] <= DENSE_MAX_TOKENS
     if dense:
         return _dense(y, gates, idx, wg, wu, wd)
-    return _grouped(y, gates, idx, wg, wu, wd, valid)
+    slabs = -(-y.shape[0] // SLAB_TOKENS)
+    if slabs == 1 or y.shape[0] % slabs:
+        return _grouped(y, gates, idx, wg, wu, wd, valid)
+    if valid is None:
+        valid = jnp.ones(y.shape[:1], bool)
+    out = jax.lax.map(
+        lambda a: _grouped(*a[:3], wg, wu, wd, a[3]),
+        tuple(a.reshape(slabs, -1, *a.shape[1:])
+              for a in (y, gates, idx, valid)))
+    return out.reshape(y.shape[0], -1)
 
 
 def moe_ffn(y, router, wg, wu, wd, *, top_k, dense=None, valid=None):

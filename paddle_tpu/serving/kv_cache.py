@@ -34,6 +34,13 @@ table a row; admission takes a slot beside the pages or refuses
 it back, and its contents are never read again: the next prefill writes
 the slot whole.  Slot 0 is the null slot of padding rows.
 
+A third payload a page (``index_dim`` > 0): a model of learned sparse
+attention keeps, beside K and V, its indexer's key of every token —
+``pool.index_pool`` ``(L, P, index_dim, ps)``, a page the keys of its
+``ps`` tokens transposed (:mod:`..ops.paged_sparse`).  It is page for
+page with the K and V pools: the one table a row addresses all three,
+and a page allocated, reserved or returned is a page of all three.
+
 Page 0 is reserved as the **null page**: padding rows of a batch
 bucket and the unused tail of every page table point at it, so the
 programs' scatter/gather of padding lanes touch real (never-read)
@@ -195,7 +202,8 @@ class PagePool:
                  scale_pages: bool = False, window_layers: int = 0,
                  window_pages: int = 0, window: int = 0,
                  kind: str = "global", state_layers: int = 0,
-                 state_slots: int = 0, state_shape=(0, 0, 0)):
+                 state_slots: int = 0, state_shape=(0, 0, 0),
+                 index_dim: int = 0):
         if pages < 2:
             raise ValueError("pages must be >= 2 (page 0 is the null page)")
         self.kind = kind            # "global" | "window": names the gauges
@@ -243,6 +251,13 @@ class PagePool:
             if self.scale_pages else None
         self.v_scale = jnp.zeros(sshape, jnp.float32) \
             if self.scale_pages else None
+        # the indexer's keys (learned sparse attention), page for page
+        # with K and V: a page is (index_dim, page_size)
+        if index_dim and scale_pages:
+            raise ValueError("an int8 pool has no index pool")
+        self.index_pool = jnp.zeros(
+            (layers, pages, index_dim, page_size), dtype) \
+            if index_dim else None
         self._lock = threading.Lock()
         # LIFO free list: hot pages get reused while still cache/HBM warm
         self._free: List[int] = list(range(pages - 1, 0, -1))
@@ -444,10 +459,14 @@ class PagePool:
     def swap(self, k_pool, v_pool, *rest) -> None:
         """Rebind the pools to a program's donated outputs, in program
         order: the value pools, then the scale pools of a quantized
-        pool, or the sliding layers' two pools, then the state-space
-        layers' two."""
+        pool, or the index pool, or the sliding layers' two pools, then
+        the state-space layers' two."""
         self.k_pool = k_pool
         self.v_pool = v_pool
+        if self.index_pool is not None:
+            if not rest:
+                raise ValueError("swap requires the index pool")
+            self.index_pool, *rest = rest
         if self.state_slots is not None:
             if len(rest) < 2:
                 raise ValueError("swap requires the state pools")
@@ -469,6 +488,8 @@ class PagePool:
         state = (self.k_pool, self.v_pool)
         if self.scale_pages:
             state += (self.k_scale, self.v_scale)
+        if self.index_pool is not None:
+            state += (self.index_pool,)
         if self.window_pool is not None:
             state += (self.window_pool.k_pool, self.window_pool.v_pool)
         if self.state_slots is not None:
@@ -487,6 +508,9 @@ class PagePool:
                 "pages": self.pages,
                 "dtype": np.dtype(self.dtype).name,
                 "scale_pages": self.scale_pages,
+                # pages of the index pool: the same pages, a third array
+                "index_pages": (self.pages if self.index_pool is not None
+                                else 0),
                 "usable_pages": self.usable_pages,
                 "free_pages": free,
                 "used_pages": self.usable_pages - free,
@@ -557,6 +581,8 @@ class PagePool:
         if self.scale_pages:
             named["kv::k_scales"] = self.k_scale
             named["kv::v_scales"] = self.v_scale
+        if self.index_pool is not None:
+            named["kv::index_pages"] = self.index_pool
         if self.state_slots is not None:
             named["kv::ssm_conv_state"] = self.state_slots.conv
             named["kv::ssm_state"] = self.state_slots.ssm

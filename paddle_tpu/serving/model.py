@@ -31,7 +31,12 @@ layer's pages), ``layer<i>/attn_out``, ``layer<i>/mlp`` (or
 ``layer<i>/moe_route`` and ``layer<i>/moe_experts``); a state-space layer
 is ``layer<i>/ssm`` (projections, convolution, the scan or the one-token
 update, gate) and ``layer<i>/state_write`` (its rows' slots), a gated
-memory unit ``layer<i>/gmu``; then ``lm_head`` and ``sample``.
+memory unit ``layer<i>/gmu``; a layer of learned sparse attention
+(``sparse_topk``) is ``layer<i>/index_write`` (the indexer's key into the
+index pool), ``layer<i>/indexer`` (the indexer's queries and the scores
+against the row's cached keys, or a block of the prompt's),
+``layer<i>/select`` (the exact top-k) and ``layer<i>/attn_sparse``; then
+``lm_head`` and ``sample``.
 The kernel takes the pools whole, so nothing stands between the write
 and the read: the ``kv_read`` scope of earlier versions has no operation
 left and is gone.  Prefill writes each layer's K/V as whole pages under
@@ -52,6 +57,8 @@ import numpy as np
 from ..ops.paged_attention import (chunk_walk, paged_attention,
                                    paged_attention_diff,
                                    paged_attention_int8)
+from ..ops.paged_sparse import (index_scores, paged_attention_sparse,
+                                paged_index_scores, select_tokens, topk_mask)
 from ..ops.quant_kernels import quantize_kv, w8a16_matmul
 from . import experts as _experts
 from . import ssm as _ssm
@@ -120,7 +127,15 @@ class ModelSpec:
     or ``moe`` (``experts`` routed SwiGLU experts of ``expert_width``,
     ``experts_per_token`` a token, no drops: :mod:`.experts`, which
     picks its regime by the program's row count).  ``tie_head``: logits
-    against the embedding, or an own ``head`` matrix.
+    against the embedding, or an own ``head`` matrix.  ``qk_norm``: an RMS
+    norm a head (weights of ``head_dim``) on q and on k before the rotary.
+    ``sparse_topk`` > 0: every attention layer is learned sparse attention
+    (:mod:`..ops.paged_sparse`): an indexer of ``index_heads`` query heads
+    and one key head of ``index_head_size`` lanes (the key through a layer
+    norm, both rotated on all their lanes) scores every visible key, and
+    the layer attends over the ``sparse_topk`` best alone (all of them up
+    to that many).  Such a layer is still kind ``full``: it owns pages of
+    the full layers' pool, and a page of the index pool beside each.
     """
 
     vocab_size: int = 256
@@ -153,6 +168,10 @@ class ModelSpec:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_dt_rank: int = 0
+    qk_norm: bool = False
+    sparse_topk: int = 0
+    index_heads: int = 0
+    index_head_size: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -208,6 +227,15 @@ class ModelSpec:
                 and self.expert_width > 0):
             raise ValueError("ffn='moe' needs experts, experts_per_token "
                              "and expert_width")
+        if self.sparse_topk and not (
+                self.index_heads > 0 and self.index_head_size > 0
+                and self.index_head_size % 2 == 0
+                and self.sparse_topk <= self.max_seq_len
+                and len(self.global_layers) == self.layers
+                and not self.diff_attn):
+            raise ValueError(
+                "sparse_topk needs index_heads and an even index_head_size, "
+                "at most max_seq_len, full layers only and no diff_attn")
 
     def layer_kind(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "full"
@@ -296,6 +324,19 @@ def init_params(spec: ModelSpec, seed: int = 0,
         if spec.attn_bias:
             for n, width in names + [("o", spec.hidden)]:
                 p[f"h{i}.attn.b{n}"] = jnp.zeros((width,), dtype)
+        if spec.qk_norm:
+            for j, n in enumerate(("qnorm", "knorm")):
+                p[f"h{i}.attn.{n}.w"] = 1 + _w(_extra(i, 8 + j),
+                                               (spec.head_dim,), 0.1)
+        if spec.sparse_topk:
+            # the indexer: its query heads, its one key head (through a
+            # layer norm with scale and bias), a weight a query head
+            n, di = spec.index_heads, spec.index_head_size
+            p[f"h{i}.idx.wq"] = _w(_extra(i, 10), (spec.hidden, n * di))
+            p[f"h{i}.idx.wk"] = _w(_extra(i, 11), (spec.hidden, di))
+            p[f"h{i}.idx.ww"] = _w(_extra(i, 12), (spec.hidden, n))
+            p[f"h{i}.idx.knorm.w"] = 1 + _w(_extra(i, 13), (di,), 0.1)
+            p[f"h{i}.idx.knorm.b"] = _w(_extra(i, 14), (di,), 0.1)
         if spec.diff_attn:
             for j, n in enumerate(("lq1", "lk1", "lq2", "lk2")):
                 p[f"h{i}.attn.{n}"] = _w(_extra(i, 3 + j),
@@ -421,8 +462,12 @@ def _rope_tables(spec, positions):
         freqs["full"] = plain / s * ramp + plain * (1.0 - ramp)
         factor["full"] = (spec.yarn_attention_factor
                           or 0.1 * math.log(s) + 1.0)
+    if spec.sparse_topk:        # the indexer's lanes, the plain frequencies
+        di = spec.index_head_size
+        freqs["index"] = spec.rope_theta ** -(
+            np.arange(0, di, 2, dtype=np.float64) / di)
     out = {}
-    for kind in ("full", "sliding"):
+    for kind in ("full", "sliding") + ("index",) * bool(spec.sparse_topk):
         ang = (positions.astype(jnp.float32)[:, None]
                * jnp.asarray(freqs[kind], jnp.float32)[None, :])
         m = factor.get(kind, 1.0)
@@ -458,15 +503,47 @@ def _qkv(spec, params, i, h, rope, tap):
     q = _project(spec, params, i, x, "q", spec.heads, tap)
     k = _project(spec, params, i, x, "k", spec.n_kv_heads, tap)
     v = _project(spec, params, i, x, "v", spec.n_kv_heads, tap)
+    if spec.qk_norm:
+        q = _head_norm(spec, params[f"h{i}.attn.qnorm.w"], q)
+        k = _head_norm(spec, params[f"h{i}.attn.knorm.w"], k)
     if rope is not None:
         cs = rope["sliding" if spec.layer_window(i) else "full"]
         q, k = _rotate(q, cs), _rotate(k, cs)
     return q, k, v
 
 
+def _head_norm(spec, w, x):
+    """RMS norm over each head's lanes, float32 inside, ``x.dtype`` out."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + spec.norm_eps) * w).astype(x.dtype)
+
+
+def _index_key(spec, params, i, x, rope):
+    """The indexer's key of normed rows ``x``: ``rot(LayerNorm(x Wk))``,
+    (T, DI) in ``x.dtype``."""
+    k = (x @ params[f"h{i}.idx.wk"]).astype(jnp.float32)
+    mu = jnp.mean(k, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(k - mu), axis=-1, keepdims=True)
+    k = ((k - mu) * jax.lax.rsqrt(var + spec.norm_eps)
+         * params[f"h{i}.idx.knorm.w"] + params[f"h{i}.idx.knorm.b"])
+    return _rotate(k.astype(x.dtype)[:, None, :], rope["index"])[:, 0]
+
+
+def _index_queries(spec, params, i, x, rope):
+    """The indexer's queries ``rot(x Wq)`` (T, J, DI) in ``x.dtype`` and
+    their weights ``x Ww / sqrt(J DI)`` (T, J) float32."""
+    n, di = spec.index_heads, spec.index_head_size
+    q = (x @ params[f"h{i}.idx.wq"]).reshape(x.shape[0], n, di)
+    w = (x @ params[f"h{i}.idx.ww"]).astype(jnp.float32) / math.sqrt(n * di)
+    return _rotate(q, rope["index"]), w
+
+
 def _attn_scope(spec, i):
     """``layer<i>/attn`` for a model of full layers only (the name its
     metrics read), else ``attn_window`` / ``attn_global`` by the layer."""
+    if spec.sparse_topk:
+        return f"layer{i}/attn_sparse"
     if not spec.window_layers:
         return f"layer{i}/attn"
     return f"layer{i}/attn_" + ("window" if spec.layer_window(i)
@@ -477,14 +554,16 @@ _DENSE_PREFILL_MAX = 1024   # longest bucket whose (H, S, S) scores are held
 _PREFILL_BLOCK = 512        # queries and keys a block of the blocked form
 
 
-def _prefill_attention(spec, q, k, v, length, window):
+def _prefill_attention(spec, q, k, v, length, window, select=None):
     """Causal (and windowed) attention of one padded prompt, grouped
     heads: q (S, H, D), k / v (S, KVH, D) -> (S, H*D) float32.  Key ``u``
     is visible to query ``p`` iff ``u <= p``, ``u < length`` and, with a
     window, ``p - u < window``.  Up to ``_DENSE_PREFILL_MAX`` positions
     the scores are one ``(H, S, S)`` tensor; beyond, blocks of
     ``_PREFILL_BLOCK`` queries walk the key blocks they can see with an
-    online softmax, so nothing of size S x S exists."""
+    online softmax, so nothing of size S x S exists.  ``select(qpos)``,
+    where given, is the keys each of those queries keeps, ``(len(qpos),
+    S)`` bool (a sparse layer's selection): a block of queries asks once."""
     s, kvh, d = k.shape
     dv = v.shape[-1]            # wider than d under differential attention
     g = spec.heads // kvh
@@ -506,7 +585,10 @@ def _prefill_attention(spec, q, k, v, length, window):
                           ("ikgd,jkd->kgij", "kgij,jkd->ikgd"))
         att = jnp.einsum(scores, q if g == 1 else qg, k,
                          preferred_element_type=jnp.float32) * scale
-        att = jnp.where(visible(pos, pos), att, -1e30)
+        seen = visible(pos, pos)
+        if select is not None:
+            seen &= select(pos)
+        att = jnp.where(seen, att, -1e30)
         w = jax.nn.softmax(att, axis=-1)
         return jnp.einsum(values, w.astype(v.dtype), v,
                           preferred_element_type=jnp.float32
@@ -522,12 +604,17 @@ def _prefill_attention(spec, q, k, v, length, window):
         lo = jnp.maximum(i * blk - window + 1, 0) // blk if window else 0
         # a block of queries wholly past the prompt walks nothing
         hi = jnp.where(i * blk < length, i + 1, lo)
+        kept = None if select is None else select(qpos)
 
         def step(j, carry):
             m, l, acc = carry
             sc = jnp.einsum("qkgd,skd->kgqs", qi, kb[j],
                             preferred_element_type=jnp.float32) * scale
-            vis = visible(qpos, j * blk + within)[None, None]
+            vis = visible(qpos, j * blk + within)
+            if kept is not None:
+                vis &= jax.lax.dynamic_slice_in_dim(kept, j * blk, blk,
+                                                    axis=1)
+            vis = vis[None, None]
             sc = jnp.where(vis, sc, -1e30)
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
             p = jnp.where(vis, jnp.exp(sc - m_new[..., None]), 0.0)
@@ -546,6 +633,38 @@ def _prefill_attention(spec, q, k, v, length, window):
 
     return jax.lax.map(one_block, jnp.arange(nb, dtype=jnp.int32)
                        ).reshape(s, spec.heads * dv)
+
+
+def _prefill_selection(spec, qi, wi, ki, length):
+    """``select`` of :func:`_prefill_attention` for a layer of learned
+    sparse attention: for a block of query positions ``qpos`` the indexer's
+    scores against every key of the prompt, and of the keys a query sees
+    (``u <= p``, ``u < length``) the ``sparse_topk`` best, exactly.  A
+    block whose queries all see at most ``sparse_topk`` keys keeps what
+    it sees and scores nothing."""
+    s = ki.shape[0]
+    kpos = jnp.arange(s, dtype=jnp.int32)
+
+    def select(qpos):
+        seen = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] < length)
+
+        def best(_):
+            with jax.named_scope("indexer"):
+                scores = index_scores(
+                    jax.lax.dynamic_slice_in_dim(qi, qpos[0], qpos.shape[0]),
+                    jax.lax.dynamic_slice_in_dim(wi, qpos[0], qpos.shape[0]),
+                    ki, last=jnp.minimum(qpos[-1], length - 1))
+            with jax.named_scope("select"):
+                return topk_mask(scores, seen, jnp.full(
+                    qpos.shape, spec.sparse_topk, jnp.int32))
+
+        if qpos.shape[0] == s:      # one block: the whole (short) prompt
+            return best(None)
+        return jax.lax.cond(
+            (qpos[-1] >= spec.sparse_topk) & (qpos[0] < length),
+            best, lambda _: seen, None)
+
+    return select
 
 
 # Differential attention (arXiv:2410.05258).  Heads of D in order: query
@@ -639,20 +758,28 @@ def _head(spec, params, hf):
     return hf @ params["head"]
 
 
-def _results(spec, pools, token, logits, counts):
+def _results(spec, pools, token, logits, counts, selected=()):
     """A step's outputs in program order: the donated pools, the sampled
     token(s), the logits and, for routed experts, the tokens each layer
-    sent to each expert ``(L, E)``."""
+    sent to each expert ``(L, E)``; a decode step of learned sparse
+    attention asked for its ``selection`` then gives the positions each
+    layer selected for each row ``(L, B, topk)`` and, a layer, the scores
+    it selected them by ``(B, max_pages * ps)`` float32 (what lies past a
+    row's length is not written): what the layer decided, for a check to
+    read."""
     out = tuple(p for p in pools if p is not None) + (token, logits)
     if spec.ffn == "moe":
         out += (jnp.stack(counts),)
+    if selected:
+        out += (jnp.stack([pos for pos, _ in selected]),
+                *[scores for _, scores in selected])
     return out
 
 
 def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                  tokens, length, page_table, *, page_size: int,
-                 k_scale=None, v_scale=None, kw_pool=None, vw_pool=None,
-                 conv_pool=None, ssm_pool=None, tap=None):
+                 k_scale=None, v_scale=None, index_pool=None, kw_pool=None,
+                 vw_pool=None, conv_pool=None, ssm_pool=None, tap=None):
     """Run one prompt (padded to a seq bucket) and seed its KV pages.
 
     Args:
@@ -671,6 +798,9 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
       k_scale/v_scale: donated scale pools ``(L, P, ps, H)`` f32 when
         the KV pool is int8 (``k_pool.dtype``); the prompt's K/V are
         quantized per (token, head) at write time.
+      index_pool: donated pool ``(Lg, P, DI, ps)`` of the indexer's keys
+        (learned sparse attention), page for page with ``k_pool``: a page
+        is the keys of its ``ps`` tokens, transposed.
       kw_pool/vw_pool: donated pools ``(Lw, Pw, ps, KVH*D)`` of the
         sliding layers.  A prompt longer than the window writes only
         the last ``window / ps + 1`` pages there: what a later decode
@@ -685,7 +815,8 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
         ever non-None in the eager PTQ harness, never in a serve trace.
 
     Returns ``(k_pool, v_pool, next_token, logits)``, with the scale
-    pools, then the sliding layers' pools, then the state pools, spliced
+    pools, then the index pool, then the sliding layers' pools, then the
+    state pools, spliced
     in after ``v_pool`` when they were passed, and the experts' token
     counts ``(L, E)`` appended for ``ffn='moe'``.
     Prefill attends over the in-layer full-precision K/V (the stored
@@ -707,9 +838,10 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
     n_pages = -(-s // page_size)
     tables = page_table if page_table.ndim == 2 else page_table[None]
 
-    def write(pool, layer, rows, table, first, count):
+    def write(pool, layer, rows, table, first, count, transposed=False):
         """``rows`` (S, ...) of one layer's K, V or scales into the
-        prompt's pages ``first .. first + count``.  Rows past ``length``
+        prompt's pages ``first .. first + count`` (``transposed``: a page
+        is its tokens' rows side by side, the index pool's).  Rows past ``length``
         become zeros: the kernel masks those slots (``pos < length``)
         until the decode step that reaches each one overwrites it.  A
         page wholly past the prompt, or one the sequence holds no page
@@ -727,8 +859,10 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
             table = table[:n_pages]
             logical = jnp.arange(n_pages, dtype=jnp.int32)
         page_ids = jnp.where(logical * page_size < length, table, 0)
-        return pool.at[layer, page_ids].set(
-            rows.reshape(count, page_size, *rows.shape[1:]))
+        pages = rows.reshape(count, page_size, *rows.shape[1:])
+        if transposed:
+            pages = jnp.swapaxes(pages, 1, 2)
+        return pool.at[layer, page_ids].set(pages)
 
     def write_kv(i, k, v):
         """Layer ``i``'s K and V (S, KVH, D) into this sequence's pages,
@@ -820,14 +954,31 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
         window = spec.layer_window(i)
         with scope(f"layer{i}/attn_qkv"):
             q, k, v = _qkv(spec, params, i, h, rope, tap)
+        select = None
+        if spec.sparse_topk:
+            with scope(f"layer{i}/indexer"):
+                x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
+                ki = _index_key(spec, params, i, x, rope)
+                select = _prefill_selection(
+                    spec, *_index_queries(spec, params, i, x, rope), ki,
+                    length)
         with scope(_attn_scope(spec, i)):
             if spec.diff_attn:
                 o = _diff_prefill_attention(spec, q, k, v, length, window)
+            elif select is not None:
+                o = _prefill_attention(spec, q, k, v, length, window,
+                                       select=select)
             else:
                 o = _prefill_attention(spec, q, k, v, length, window)
         with scope(f"layer{i}/attn_out"):
             h = _attn_out(spec, params, i, h, o, tap)
         h = _ffn(spec, params, i, h, tap, in_prompt, counts)
+        if spec.sparse_topk:
+            with scope(f"layer{i}/index_write"):
+                index_pool = write(
+                    index_pool, spec.global_layers.index(i), ki, tables[0],
+                    0, n_pages, transposed=True)
+                h, index_pool = jax.lax.optimization_barrier((h, index_pool))
         # the next layer waits for these writes: left free, XLA's
         # schedule puts all 2L of them after the stack and keeps
         # every layer's K and V alive until then.  (Not the int8
@@ -853,9 +1004,9 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
         logits = _head(spec, params, last)[0]                  # (V,)
     with scope("sample"):
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return _results(spec, (k_pool, v_pool, k_scale, v_scale, kw_pool,
-                           vw_pool, conv_pool, ssm_pool), next_token,
-                    logits, counts)
+    return _results(spec, (k_pool, v_pool, k_scale, v_scale, index_pool,
+                           kw_pool, vw_pool, conv_pool, ssm_pool),
+                    next_token, logits, counts)
 
 
 def decode_walk(spec: ModelSpec, batch: int, k_pool, max_pages: int,
@@ -898,8 +1049,9 @@ def _pages_walked(pages: int, batch: int) -> int:
 
 def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 tokens, positions, page_tables, *, page_size: int,
-                k_scale=None, v_scale=None, kw_pool=None, vw_pool=None,
-                conv_pool=None, ssm_pool=None, tap=None):
+                k_scale=None, v_scale=None, index_pool=None, kw_pool=None,
+                vw_pool=None, conv_pool=None, ssm_pool=None, tap=None,
+                selection: bool = False):
     """One decode step for a padded batch bucket.
 
     Args:
@@ -918,15 +1070,24 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
         int8 pool; the step's K/V quantize per (token, head) at write
         time — a pure per-row function, so row bytes never depend on
         batch neighbours (the bit-identity contract survives int8).
+      index_pool: donated pool of the indexer's keys (learned sparse
+        attention): each layer writes the rows' new key at ``[layer, page,
+        :, slot]``, scores every cached key of each row, selects, and
+        attends over what it selected.
       kw_pool/vw_pool: donated pools of the sliding layers; the kernel
         walks only the pages of a row's window there.
       conv_pool/ssm_pool: donated state pools of the ``ssm`` layers; each
         reads its rows' slots (``page_tables[:, 2, 0]``; a padding row's
         is the null slot 0) and writes them back in place.
       tap: optional calibration hook (eager PTQ harness only).
+      selection: the same step, its outputs followed by what every layer
+        of learned sparse attention scored and selected (:func:`_results`).
+        The step a server runs does not carry them: five more outputs,
+        16 MB of them, were 0.25 - 0.34 ms of a 16 ms step on a v5e.
 
     Returns ``(k_pool, v_pool, next_tokens, logits)``, with the scale
-    pools, then the sliding layers' pools, spliced in after ``v_pool``
+    pools, then the index pool, then the sliding layers' pools, spliced
+    in after ``v_pool``
     when they were passed, and the experts' token counts ``(L, E)`` of
     the rows that hold a page appended for ``ffn='moe'``.
     """
@@ -954,6 +1115,7 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                     steps=_pages_walked(k_pool.shape[1], b))
 
     counts = []
+    selected = []               # a sparse layer's (positions, scores)
     memory = None               # the nearest ssm layer's y, (B, N) float32
     shared = None               # the nearest full layer's place in k_pool
     for i in range(spec.layers):
@@ -1015,11 +1177,32 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                     k.reshape(b, kvd).astype(k_pool.dtype))
                 v_pool = v_pool.at[li, page, slot].set(
                     v.reshape(b, kvd).astype(v_pool.dtype))
+            if spec.sparse_topk:
+                with scope(f"layer{i}/index_write"):
+                    x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
+                    index_pool = index_pool.at[li, page, :, slot].set(
+                        _index_key(spec, params, i, x, rope
+                                   ).astype(index_pool.dtype))
+                with scope(f"layer{i}/indexer"):
+                    scores = paged_index_scores(
+                        *_index_queries(spec, params, i, x, rope),
+                        index_pool, table, lengths, layer=li,
+                        steps=_pages_walked(k_pool.shape[1], b))
+                with scope(f"layer{i}/select"):
+                    chosen, addresses, listed = select_tokens(
+                        scores, lengths, table, topk=spec.sparse_topk,
+                        page_size=page_size, pool_pages=k_pool.shape[1])
+                    selected.append((chosen, scores))
             with scope(_attn_scope(spec, i)):
                 if quant:
                     o = paged_attention_int8(q, k_pool, v_pool, k_scale,
                                              v_scale, table, lengths,
                                              layer=li)
+                elif spec.sparse_topk:
+                    # a padding row lists nothing
+                    o = paged_attention_sparse(
+                        q, k_pool, v_pool, addresses,
+                        jnp.where(live, listed, 0), layer=li)
                 else:
                     o = attend(q, k_pool, v_pool, table, li)
         with scope(f"layer{i}/attn_out"):
@@ -1032,6 +1215,7 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
         logits = _head(spec, params, hf)                       # (B, V)
     with scope("sample"):
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return _results(spec, (k_pool, v_pool, k_scale, v_scale, kw_pool,
-                           vw_pool, conv_pool, ssm_pool), next_tokens,
-                    logits, counts)
+    return _results(spec, (k_pool, v_pool, k_scale, v_scale, index_pool,
+                           kw_pool, vw_pool, conv_pool, ssm_pool),
+                    next_tokens, logits, counts,
+                    selected if selection else ())

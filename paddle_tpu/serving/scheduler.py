@@ -73,6 +73,11 @@ state-space layers: ``state_slots_held`` (now) and
 of a slot, beside ``refused_kv``), ``ssm_tokens_scanned`` (prompt
 positions through the scan) and, with cross layers, ``shared_kv_reads``
 (decode steps times the layers that read the shared full layer's pages).
+Learned sparse attention: ``sparse_tokens_scored`` (a decode step adds, a
+sparse layer, the sum of its rows' contexts: every cached indexer key a
+query is scored against) and ``sparse_tokens_selected`` (the sum of
+``min(context, topk)``: what the layer then attends over), exported as
+``pt_serve_sparse_tokens_total{kind="scored"|"selected"}``.
 """
 from __future__ import annotations
 
@@ -297,6 +302,9 @@ class ContinuousScheduler:
             "state_slots_held": 0, "state_slots_held_max": 0,
             "refused_state": 0, "ssm_tokens_scanned": 0,
             "shared_kv_reads": 0,
+            # learned sparse attention, summed over decode steps and
+            # sparse layers: cached keys scored, and tokens selected
+            "sparse_tokens_scored": 0, "sparse_tokens_selected": 0,
             # seconds of the scheduler thread by phase (module docstring)
             "wait_s": 0.0, "evict_s": 0.0, "admit_host_s": 0.0,
             "prefill_s": 0.0, "decode_prep_s": 0.0, "decode_s": 0.0,
@@ -310,6 +318,9 @@ class ContinuousScheduler:
         # layers that read the shared full layer's pages in a decode step
         self._shared_readers = (len(spec.cross_layers) + 1
                                 if spec.cross_layers else 0)
+        # learned sparse attention: (layers, topk), or None
+        self._sparse = ((len(spec.global_layers), spec.sparse_topk)
+                        if spec.sparse_topk else None)
         self._meter_registry = None     # the registry self._meters are of
         self._meters: Dict[str, Any] = {}
         self._walk_booked: Dict[str, int] = {}
@@ -608,6 +619,13 @@ class ContinuousScheduler:
                                 np.int32)
             positions = np.asarray([a.pos for a in self._active], np.int32)
             tables = np.stack([a.pages.table for a in self._active])
+            if self._sparse is not None:
+                # sums only, as the walks' below
+                layers, topk = self._sparse
+                stats["sparse_tokens_scored"] += layers * int(
+                    positions.sum() + n)
+                stats["sparse_tokens_selected"] += layers * int(
+                    np.minimum(positions + 1, topk).sum())
             walk = self.engine.paged_walk_for(n)
             if walk is not None:
                 # sums only, a list the program walks: the registry
@@ -722,15 +740,21 @@ class ContinuousScheduler:
         self._book_walk_locked()
 
     def _book_walk_locked(self) -> None:
-        """``pt_serve_paged_chunks_total`` up to ``stats``: called when a
-        request retires and by :meth:`snapshot`, not every decode step."""
-        for state, key in (("walked", "paged_chunks_walked"),
-                           ("grid", "paged_grid_steps")):
+        """``pt_serve_paged_chunks_total`` and ``pt_serve_sparse_tokens_
+        total`` up to ``stats``: called when a request retires and by
+        :meth:`snapshot`, not every decode step."""
+        chunks, tokens = ("pt_serve_paged_chunks_total",
+                          "pt_serve_sparse_tokens_total")
+        for metric, labels, key in (
+                (chunks, {"state": "walked"}, "paged_chunks_walked"),
+                (chunks, {"state": "grid"}, "paged_grid_steps"),
+                (tokens, {"kind": "scored"}, "sparse_tokens_scored"),
+                (tokens, {"kind": "selected"}, "sparse_tokens_selected")):
             more = self.stats[key] - self._walk_booked.get(key, 0)
             if more:
                 self._walk_booked[key] = self.stats[key]
-                self._book("pt_serve_paged_chunks_total", kind="counter",
-                           value=more, state=state)
+                self._book(metric, kind="counter", value=more,
+                           labels=labels)
 
     # -- loop management -----------------------------------------------------
 
@@ -948,10 +972,12 @@ class ContinuousScheduler:
                    value=len(self._active))
 
     def _book(self, name: str, *, kind: str, value: float = 1.0,
-              **labels) -> None:
+              labels: Optional[Dict[str, str]] = None, **more) -> None:
         """Metric booking; inert while telemetry is off (the registry
         must stay empty then).  The registry's instruments are looked
-        up once each and kept, until the registry itself is replaced."""
+        up once each and kept, until the registry itself is replaced.
+        Labels are keywords, or ``labels`` where one is named ``kind``."""
+        labels = {**(labels or {}), **more}
         try:
             if not get_telemetry().enabled:
                 return
@@ -1011,6 +1037,10 @@ _METRIC_HELP = {
         "Paged attention, summed over decode steps and the program's "
         "work lists: chunks the rows' contexts or windows fill (walked) "
         "and grid steps of the bucket's program (grid)",
+    "pt_serve_sparse_tokens_total":
+        "Learned sparse attention, summed over decode steps and sparse "
+        "layers: cached indexer keys scored (scored) and tokens the "
+        "layers attended over (selected)",
     "pt_serve_queue_depth": "Requests waiting for admission",
     "pt_serve_active_sequences": "Sequences resident in the decode batch",
     "pt_serve_batch_occupancy":
