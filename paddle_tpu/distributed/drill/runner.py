@@ -2621,26 +2621,28 @@ def run_serve_chaos_drill(root, *, max_new=8, storm_requests=6,
         # or decoding) when the handler's socket watch looks — a tiny
         # model can otherwise finish before the first check
         import socket as _socket
-        blocked = []
-        blockers = _until_active(
-            base2, 2, 4, _long, blocked,
-            "blocker requests to fill the decode batch")
         payload = json.dumps({"tokens": prompts[2],
                               "max_new_tokens": 48}).encode()
-        for _ in range(3):          # three callers walk away mid-decode
-            s = _socket.create_connection((h2, port2), timeout=5.0)
-            s.sendall(b"POST /v1/generate HTTP/1.1\r\n"
-                      b"Host: drill\r\n"
-                      b"Content-Type: application/json\r\n"
-                      + f"Content-Length: {len(payload)}\r\n\r\n"
-                      .encode() + payload)
-            s.close()
-        for t in blockers:
-            t.join(timeout=request_timeout)
-        if any(status != 200 for status, _b, _h in blocked):
-            raise DrillFailure(
-                f"blocker requests failed during the disconnect leg: "
-                f"{[(s, b) for s, b, _h in blocked]}")
+
+        def _walk_away():
+            blocked = []
+            blockers = _until_active(
+                base2, 2, 4, _long, blocked,
+                "blocker requests to fill the decode batch")
+            for _ in range(3):      # three callers walk away mid-decode
+                s = _socket.create_connection((h2, port2), timeout=5.0)
+                s.sendall(b"POST /v1/generate HTTP/1.1\r\n"
+                          b"Host: drill\r\n"
+                          b"Content-Type: application/json\r\n"
+                          + f"Content-Length: {len(payload)}\r\n\r\n"
+                          .encode() + payload)
+                s.close()
+            for t in blockers:
+                t.join(timeout=request_timeout)
+            if any(status != 200 for status, _b, _h in blocked):
+                raise DrillFailure(
+                    f"blocker requests failed during the disconnect leg: "
+                    f"{[(s, b) for s, b, _h in blocked]}")
 
         def _disconnect_seen():
             _s, mb = _http_get(base2 + "/metrics", timeout=5.0)
@@ -2649,7 +2651,16 @@ def run_serve_chaos_drill(root, *, max_new=8, storm_requests=6,
                               cause="disconnect")
             return True if v else None
 
-        wait_until(_disconnect_seen, gen_timeout / 4,
+        def _walked_away_and_seen():
+            # the handler looks at its socket every 50 ms, and a model
+            # this small can answer all seven requests inside one look:
+            # the callers then walk away again
+            if _disconnect_seen():
+                return True
+            _walk_away()
+            return _disconnect_seen()
+
+        wait_until(_walked_away_and_seen, gen_timeout / 4,
                    desc="disconnected client to be cancelled")
 
         def _pool_quiet():

@@ -57,8 +57,8 @@ PRECISIONS = ("fp32", "bf16", "int8")
 
 logger = logging.getLogger("paddle_tpu.serving")
 
-__all__ = ["ServeConfig", "ServingEngine", "save_served_model",
-           "load_engine", "SERVE_CONFIG_NAME"]
+__all__ = ["ServeConfig", "ServingEngine", "DecodeStep",
+           "save_served_model", "load_engine", "SERVE_CONFIG_NAME"]
 
 SERVE_CONFIG_NAME = "serve_config.json"
 
@@ -88,6 +88,67 @@ def aot_build_phase():
     finally:
         with _AOT_BUILD_LOCK:
             _AOT_BUILD_DEPTH -= 1
+
+
+def _aux_counters(counts) -> Dict[str, int]:
+    """Expert counts ``(L, E)`` of one program call as the scheduler's
+    counters (:meth:`ServingEngine.take_aux`); ``{}`` for None."""
+    if counts is None:
+        return {}
+    counts = np.asarray(counts)
+    return {"moe_tokens_routed": int(counts.sum()),
+            "moe_expert_max_tokens": int(counts.max(axis=1).sum()),
+            "moe_experts_touched": int(np.count_nonzero(counts))}
+
+
+class DecodeStep:
+    """One launched decode step: what :meth:`ServingEngine.decode` hands
+    back without waiting for the device.
+
+    The step's tokens are a device array whose copy to the host started
+    behind the program.  :meth:`read` (and ``np.asarray(step)``, and
+    indexing or iterating it) waits for them, inside a
+    ``serve.decode.fetch`` span, and gives the ``(rows,)`` int32 tokens
+    of the rows the call was made with, in the call's order; the second
+    read is free.  Whoever made the call may read it, once the call has
+    returned, from any thread; the scheduler reads a step after it has
+    launched the next one (``engine.decode_from``).  ``bucket`` is the
+    program's batch, ``slots`` where in it each of the call's rows sat,
+    and :meth:`take_aux` what this step's routed experts counted."""
+
+    __slots__ = ("rows", "bucket", "slots", "_tokens", "_aux", "_host")
+
+    def __init__(self, tokens, aux, slots: np.ndarray, bucket: int):
+        self.rows = int(slots.shape[0])
+        self.bucket = bucket
+        self.slots = slots
+        self._tokens = tokens       # (bucket,) on the device
+        self._aux = aux             # (L, E) on the device, or None
+        self._host: Optional[np.ndarray] = None
+
+    def read(self) -> np.ndarray:
+        if self._host is None:
+            with span("serve.decode.fetch", rows=self.rows,
+                      bucket=self.bucket):
+                self._host = np.asarray(self._tokens)[self.slots]
+        return self._host
+
+    def take_aux(self) -> Dict[str, int]:
+        """:meth:`ServingEngine.take_aux` of this step (waits for it)."""
+        return _aux_counters(self._aux)
+
+    def __array__(self, dtype=None, copy=None):
+        out = self.read()
+        return out if dtype is None else out.astype(dtype)
+
+    def __len__(self) -> int:
+        return self.rows
+
+    def __iter__(self):
+        return iter(self.read())
+
+    def __getitem__(self, index):
+        return self.read()[index]
 
 
 def _env_int(name: str, default: int) -> int:
@@ -292,6 +353,11 @@ class ServingEngine:
             # prefill spans (set by the scheduler's one thread, under
             # its lock; None for a direct caller)
             self.prefill_request_id: Optional[int] = None
+            # the same hand-over for the next :meth:`decode` call alone:
+            # ``(step, keep)`` says its rows are rows ``keep`` of that
+            # :class:`DecodeStep`'s call and take their tokens from it,
+            # on the device
+            self.decode_from: Optional[Tuple[DecodeStep, np.ndarray]] = None
             self._warmed = False
             self._prefill_exe: Dict[int, Any] = {}
             self._decode_exe: Dict[int, Any] = {}
@@ -582,18 +648,14 @@ class ServingEngine:
         return None if self._aux is None else np.asarray(self._aux)
 
     def take_aux(self) -> Dict[str, int]:
-        """Counters of the last program call beside its tokens: for
+        """Counters of the last program call beside its tokens (a decode
+        step's own are its :meth:`DecodeStep.take_aux`): for
         routed experts the token-expert pairs it routed
         (``moe_tokens_routed``, summed over layers), its busiest
         expert's tokens summed over layers (``moe_expert_max_tokens``)
         and how many (layer, expert) pairs got any token
         (``moe_experts_touched``)."""
-        counts = self.expert_counts()
-        if counts is None:
-            return {}
-        return {"moe_tokens_routed": int(counts.sum()),
-                "moe_expert_max_tokens": int(counts.max(axis=1).sum()),
-                "moe_experts_touched": int(np.count_nonzero(counts))}
+        return _aux_counters(self._aux)
 
     def _warmup(self) -> None:
         """Execute every program once so first-request latency pays no
@@ -711,14 +773,36 @@ class ServingEngine:
                               if want_logits else None)
 
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
-               page_tables: np.ndarray) -> np.ndarray:
-        """One decode step over ``n`` active rows, padded to a bucket.
+               page_tables: np.ndarray) -> DecodeStep:
+        """Launch one decode step over ``n`` active rows, padded to a
+        bucket, and return without waiting for it: a :class:`DecodeStep`
+        that resolves to the rows' ``(n,)`` next tokens when asked
+        (``np.asarray(engine.decode(...))`` is the synchronous call).
 
         Padding rows carry position 0 + the all-null page table, so
         their (garbage) K/V writes land in the null page.  Leaf spans
-        as in :meth:`prefill`, carrying ``rows`` and ``bucket``.
-        """
-        return self._decode(tokens, positions, page_tables, False)[0]
+        as in :meth:`prefill`, carrying ``rows`` and ``bucket``:
+        ``.prep`` and ``.launch`` here, ``.fetch`` where the step is
+        read.
+
+        With ``decode_from = (step, keep)`` set (this call takes it),
+        the rows are rows ``keep`` of ``step``'s call, each in the slot
+        it had there, and their tokens are ``step``'s output as the
+        device array it is: ``tokens`` is then not read (the scheduler
+        passes the last ones it has seen).  Slots of ``step``'s bucket
+        no row keeps are padding.  No program differs: an executable
+        takes a device vector as it takes a host one."""
+        carry, self.decode_from = self.decode_from, None
+        if carry is None:
+            n = tokens.shape[0]
+            b, slots, feed = self.decode_bucket_for(max(n, 1)), \
+                np.arange(n), None
+        else:
+            b, slots, feed = carry[0].bucket, carry[0].slots[carry[1]], \
+                carry[0]._tokens
+        nxt, *_ = self._launch(self._decode_exe[b], b, slots, tokens,
+                               positions, page_tables, feed)
+        return DecodeStep(nxt, self._aux, slots, b)
 
     def decode_logits(self, tokens: np.ndarray, positions: np.ndarray,
                       page_tables: np.ndarray
@@ -745,23 +829,35 @@ class ServingEngine:
         return (nxt, logits, np.asarray(chosen)[:, :n],
                 [np.asarray(a)[:n] for a in scores])
 
-    def _decode(self, tokens, positions, page_tables, want_logits,
-                selection_exe=None):
-        n = tokens.shape[0]
-        b = (self.config.decode_buckets[-1] if selection_exe is not None
-             else self.decode_bucket_for(max(n, 1)))
+    def _launch(self, exe, b, slots, tokens, positions, page_tables,
+                feed=None):
+        """Pad the rows into ``slots`` of a batch of ``b`` and call
+        ``exe``: what :meth:`_run` returns.  ``feed`` is a whole batch's
+        token vector on the device, taken in place of ``tokens``."""
+        n = slots.shape[0]
         with span("serve.decode.prep", rows=n, bucket=b):
-            tok = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
             pt = np.full((b, *self.table_shape), NULL_PAGE, np.int32)
-            tok[:n] = tokens
-            pos[:n] = positions
-            pt[:n] = page_tables
+            pos[slots] = positions
+            pt[slots] = page_tables
+            tok = feed
+            if tok is None:
+                tok = np.zeros((b,), np.int32)
+                tok[slots] = tokens
             with self._weights_lock:
                 params = self._params
         with span("serve.decode.launch", rows=n, bucket=b):
-            nxt, logits, *more = self._run(
-                selection_exe or self._decode_exe[b], params, tok, pos, pt)
+            return self._run(exe, params, tok, pos, pt)
+
+    def _decode(self, tokens, positions, page_tables, want_logits,
+                selection_exe=None):
+        """A decode step read at once (the checks' calls)."""
+        n = tokens.shape[0]
+        b = (self.config.decode_buckets[-1] if selection_exe is not None
+             else self.decode_bucket_for(max(n, 1)))
+        nxt, logits, *more = self._launch(
+            selection_exe or self._decode_exe[b], b, np.arange(n), tokens,
+            positions, page_tables)
         with span("serve.decode.fetch", rows=n, bucket=b):
             return (np.asarray(nxt)[:n], np.asarray(logits, np.float32)[:n]
                     if want_logits else None, *more)

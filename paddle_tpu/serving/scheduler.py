@@ -4,17 +4,74 @@ One scheduler tick = one *step boundary*:
 
  1. **evict** cancelled and deadline-expired sequences (free pages,
     release reservations, resolve the caller's stream with the error),
- 2. **retire** sequences that finished last step (free pages, release
-    unused reservations, resolve the caller's stream),
- 3. **admit** queued sequences while a decode slot AND worst-case KV
+ 2. **admit** queued sequences while a decode slot AND worst-case KV
     headroom exist — admission reserves ``ceil((prompt+max_new)/ps)``
     pages up front (and, in a model with sliding-window layers, the
     window's span plus a page of their pool; in one with state-space
     layers, a state slot: ``PagePool.admit_row``) so an admitted
     sequence can never stall mid-decode waiting for a page (admission
     control against pool headroom),
- 4. **decode** one token for every active row, padded to the smallest
-    compiled batch bucket.
+ 3. **launch** the next decode step for every active row, padded to a
+    compiled batch bucket,
+ 4. **read and book** the step before it: append its tokens, **retire**
+    the sequences it finished (free pages, release unused
+    reservations, resolve the caller's stream).
+
+**One decode step in flight beyond the one being read** (launch-ahead;
+how the scheduler runs, for every model).  ``engine.decode`` returns a
+:class:`~paddle_tpu.serving.engine.DecodeStep` without waiting, and the
+host needs a step's tokens for nothing that decides the next launch:
+decoding is greedy with the argmax on the device, the next positions are
+the last plus one, the page tables grow from the admission-time
+reservation, a row that ends by count is known a step ahead.  So step
+k+1 is launched with step k's token vector *as the device array it is*
+(``engine.decode_from``), each row in the slot it had in step k, and
+only then is step k read (its copy to the host started at its launch).
+The device always has a program queued behind the one it runs.
+
+ - *Stable slots.*  A row that ends by count is left out of the next
+   launch as a padding slot in place (its pages and slot go back when
+   its last step is booked); the rows are compacted (one step launched
+   from the host's tokens, after a read) only when a smaller bucket
+   then fits them.  What ``engine.decode``
+   is called with, and what the counters count, are the live rows.
+ - *What falls back to a step launched after a read* (counted
+   ``launch="sync"``): the first step of a batch; the step after an
+   **admission** — ``engine.prefill`` is synchronous and queued behind
+   the step in flight, so when it returns that step's tokens are on the
+   host: they are booked, the request is seated, and every row's token
+   comes from the host; an admission that lacks pages or a slot which
+   rows ending in the unread step hold books that step *before* its
+   prefill (returning them at their last launch, so that the prefill
+   runs behind it, kept the device busier and the GPT backlog cell
+   1 % slower: PERF.md §6, PR 35); a **bucket that can shrink**.  An
+   eviction, a cancel or a weights reload needs none: the row becomes a
+   hole, the next launch takes the parameters it finds.
+ - *``eos_id``* is learnt a step late: the row has been launched once
+   more by then.  That step's token for it is dropped, its K/V (and
+   state-slot) write lands in the row's own reservation, and its pages
+   and slot go back when the ``eos_id`` is booked; whatever takes them
+   next is written by a program launched later, which the device runs
+   later.  The same holds for the pages of an evicted row and for the
+   window pages ``RowPages.advance`` hands back while a step is in
+   flight.  The streams are exactly a synchronous loop's.
+ - *Who owns what* (D7).  The scheduler thread (or, with no loop
+   running, whoever calls :meth:`ContinuousScheduler.step`) alone
+   launches and reads: the batch ``_active`` and the one unread step
+   ``_flight`` (handle, rows, launch time: one small object) are its
+   own.  The lock is held for the host's part of a step and released
+   between ``step()`` calls, with a step in flight; ``submit`` touches
+   the queue only; ``cancel`` takes a seated row out of the batch and
+   frees its pages at once (its token of the unread step is dropped
+   when that step is booked).  The watchdog's ``_step_started`` is the
+   oldest unread step's launch; ``_step_times`` / ``_step_ewma`` (the
+   shed ETA) take the step *period* (a read to the next read, or a
+   launch to its read where nothing was ahead of it).  A program that
+   fails surfaces at the read and fails the rows of every unread step,
+   pages returned.  ``drain``, ``drain_gracefully``, ``stop`` and (with
+   no loop running) ``snapshot`` read the last step before they
+   conclude, and the loop does not sleep on an empty queue and batch
+   while a step is unread.
 
 Sequences join and leave a *running* batch only at these boundaries,
 and the decode math is row-independent (see
@@ -57,17 +114,25 @@ engine calls),
 ``serve.prefill.prep`` / ``.launch`` / ``.fetch`` and
 ``serve.decode.prep`` / ``.launch`` / ``.fetch`` (the last six but the
 page-table half of ``decode.prep`` inside :mod:`.engine`), and
-``serve.book`` (token append, retirement, gauges).  Inside a profiler
-session they are ``pt:serve.*`` events on the device trace's clock; the
+``serve.book`` (token append, retirement, gauges).  A steady step's
+order on the thread: ``decode.prep`` (tables, batch), ``decode.prep``
+(the engine's padding), ``decode.launch`` of step k+1, ``book`` (rows
+ending by count leave), ``decode.fetch`` of step k, ``book``.  Inside
+a profiler session they are ``pt:serve.*`` events on the device trace's
+clock; the
 same boundaries add to ``stats`` as float sums (``wait_s``, ``evict_s``,
 ``admit_host_s``, ``prefill_s``, ``decode_prep_s``, ``decode_s``,
-``book_s``) beside the per-request ``lock_wait_s`` (entry of
+``book_s``; ``decode_s`` is the thread's time in ``engine.decode`` and
+in the reads) beside the per-request ``lock_wait_s`` (entry of
 :meth:`~ContinuousScheduler.submit` to lock held), ``queue_wait_s``
 (enqueued to its prefill entered, count ``admitted``), ``ttft_s`` and
 ``tpot_s`` (count ``tpot_requests``).  What the decode kernels walked,
 a work list the program has (the full layers' pool, the sliding
 layers'): ``paged_chunks_walked`` and ``paged_grid_steps``
-(``pt_serve_paged_chunks_total{state="walked"|"grid"}``).  A model with
+(``pt_serve_paged_chunks_total{state="walked"|"grid"}``).  How often
+launch-ahead engages: ``decode_steps_ahead`` of ``occupancy_steps``
+(booked steps launched before the step before them was read;
+``pt_serve_decode_steps_total{launch="ahead"|"sync"}``).  A model with
 state-space layers: ``state_slots_held`` (now) and
 ``state_slots_held_max``, ``refused_state`` (admissions refused for want
 of a slot, beside ``refused_kv``), ``ssm_tokens_scanned`` (prompt
@@ -243,16 +308,45 @@ class GenerationStream:
         self._done.set()
 
 
+# _reserve_next_locked: book the unread step, then ask again
+_READ_FIRST = object()
+
+
 class _Active:
     """Per-sequence decode state while resident in the batch."""
 
-    __slots__ = ("stream", "pages", "pos", "last_token")
+    __slots__ = ("stream", "pages", "pos", "last_token", "launched",
+                 "index")
 
     def __init__(self, stream, pages, pos, last_token):
         self.stream = stream
         self.pages = pages              # kv_cache.RowPages: pages + table
-        self.pos = pos                  # position last_token will occupy
-        self.last_token = last_token
+        self.pos = pos                  # position the next launch writes
+        self.last_token = last_token    # the last one read
+        # tokens the stream has once every launched step is booked: the
+        # row leaves the batch at the launch that makes it max_new_tokens
+        self.launched = 1
+        self.index = 0                  # its row in the last step's call
+
+    @property
+    def ending(self) -> bool:
+        """Its last step is launched (it ends by count) and not booked."""
+        return (self.launched >= self.stream.max_new_tokens
+                and not self.stream.done())
+
+
+class _Flight:
+    """The one decode step launched and not yet read (D7): the engine's
+    handle, the rows the call was made with in its order, when it was
+    launched, and whether that was before the step before it was read."""
+
+    __slots__ = ("step", "rows", "launched_ts", "ahead")
+
+    def __init__(self, step, rows, launched_ts, ahead):
+        self.step = step                # engine.DecodeStep
+        self.rows = rows
+        self.launched_ts = launched_ts
+        self.ahead = ahead
 
 
 class ContinuousScheduler:
@@ -261,7 +355,8 @@ class ContinuousScheduler:
     def __init__(self, engine):
         self.engine = engine
         self._queue: deque = deque()
-        self._active: List[_Active] = []
+        self._active: List[_Active] = []    # rows of the next launch
+        self._flight: Optional[_Flight] = None  # the step not yet read
         self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)
         self._stop = threading.Event()
@@ -270,13 +365,18 @@ class ContinuousScheduler:
         self._draining = False
         self.hang_detected = False
         self._watchdog_thread: Optional[threading.Thread] = None
-        self._step_started: Optional[float] = None  # in-flight decode t0
-        self._step_times: deque = deque(maxlen=256)  # rolling wall times
+        # launch of the oldest unread step (what the watchdog watches)
+        self._step_started: Optional[float] = None
+        self._step_read: float = 0.0                 # the last read's end
+        self._step_times: deque = deque(maxlen=256)  # rolling step periods
         self._step_ewma: Optional[float] = None      # sec per decode step
         self.stats = {
             "submitted": 0, "completed": 0, "refused_inflight": 0,
             "refused_kv": 0, "steps": 0, "tokens_generated": 0,
             "occupancy_sum": 0.0, "occupancy_steps": 0,
+            # of those steps, the ones launched before the step before
+            # them was read (the rest followed a read: "sync")
+            "decode_steps_ahead": 0,
             "peak_active": 0,
             "shed": 0, "cancelled": 0, "deadline_exceeded": 0,
             "failed": 0, "drain_seconds": None, "watchdog_trips": 0,
@@ -355,7 +455,7 @@ class ContinuousScheduler:
                 self._shed_locked("draining")
                 raise RequestShed("engine draining — admission closed",
                                   reason="draining")
-            inflight = len(self._queue) + len(self._active)
+            inflight = len(self._queue) + len(self._seated_locked())
             if inflight >= cfg.max_inflight:
                 self.stats["refused_inflight"] += 1
                 self._book("pt_serve_admission_refusals_total",
@@ -421,8 +521,12 @@ class ContinuousScheduler:
 
     def cancel(self, request_id: int, cause: str = "client") -> bool:
         """Evict a request wherever it is.  Taking the scheduler lock
-        IS the step boundary — decode holds it — so an active row is
-        removed between steps, never mid-kernel."""
+        IS the step boundary (the host's part of a step holds it), so a
+        seated row leaves between launches.  The step in flight may
+        still hold it: its pages go back at once all the same (what
+        takes them next is written by a program launched later, which
+        the device runs later), and its token of that step is dropped
+        when the step is booked."""
         with self._cv:
             for st in self._queue:
                 if st.request_id == request_id:
@@ -430,14 +534,27 @@ class ContinuousScheduler:
                     self._finish_evicted_locked(st, cause)
                     self._gauges_locked()
                     return True
-            for a in self._active:
+            for a in self._seated_locked():
                 if a.stream.request_id == request_id:
-                    self._active.remove(a)
-                    self._release_locked(a)
-                    self._finish_evicted_locked(a.stream, cause)
+                    self._drop_locked(a, cause)
                     self._gauges_locked()
                     return True
         return False
+
+    def _seated_locked(self) -> List[_Active]:
+        """Every row that holds pages: the batch, and the rows whose
+        last step is the one in flight."""
+        if self._flight is None:
+            return list(self._active)
+        return self._active + [a for a in self._flight.rows if a.ending]
+
+    def _drop_locked(self, a: _Active, cause: str) -> None:
+        """Evict a seated row: out of the batch, pages back, the stream
+        resolved with ``cause``'s error."""
+        if a in self._active:
+            self._active.remove(a)
+        self._release_locked(a)
+        self._finish_evicted_locked(a.stream, cause)
 
     def _release_locked(self, a: _Active) -> None:
         a.pages.release()
@@ -471,19 +588,17 @@ class ContinuousScheduler:
         """Deadline sweep at the step boundary: queued AND active."""
         self._expire_queue_locked()
         now = time.monotonic()
-        expired = [a for a in self._active
-                   if a.stream.deadline is not None
-                   and now >= a.stream.deadline]
-        for a in expired:
-            self._active.remove(a)
-            self._release_locked(a)
-            self._finish_evicted_locked(a.stream, "deadline")
+        for a in self._seated_locked():
+            if a.stream.deadline is not None and now >= a.stream.deadline:
+                self._drop_locked(a, "deadline")
 
     # -- the step loop -------------------------------------------------------
 
     def step(self) -> bool:
-        """One step boundary: evict / retire / admit / decode.  Returns
-        whether any work was done."""
+        """One step boundary: evict / admit / launch the next decode
+        step / read and book the one before it.  Returns whether any
+        work was done.  It may return with a step in flight (launched,
+        unread), which the next call reads."""
         stats = self.stats
         # the loop gives the lock up between steps, which is when a
         # submitter gets in; taking it back counts as waiting
@@ -502,7 +617,6 @@ class ContinuousScheduler:
             self._admit_locked()
             worked = self._decode_locked()
             with span("serve.book") as sp:
-                stats["steps"] += 1 if worked else 0
                 self._gauges_locked()
             stats["book_s"] += sp.seconds
             return worked or bool(self._queue)
@@ -525,7 +639,7 @@ class ContinuousScheduler:
                     self._seat_locked(*seated)
                     seated = None
                 job = self._reserve_next_locked()
-                if job is not None:
+                if job is not None and job is not _READ_FIRST:
                     st, pages = job
                     engine.prefill_request_id = st.request_id
                     stats["admitted"] += 1
@@ -534,6 +648,9 @@ class ContinuousScheduler:
             stats["admit_host_s"] += sp.seconds
             if job is None:
                 return
+            if job is _READ_FIRST:
+                self._read_locked()
+                continue
             try:
                 first = engine.prefill(st.prompt, pages.table)
                 st.first_token_ts = st.last_token_ts = time.monotonic()
@@ -547,12 +664,19 @@ class ContinuousScheduler:
                 st._finish(error=exc)
                 logger.exception("prefill failed for request %d",
                                  st.request_id)
+            if self._flight is not None:
+                # the prefill ran behind the step in flight and is read:
+                # that step's tokens are on the host.  Booked before the
+                # request is seated, so the next launch is an ordinary
+                # one, every row's token from the host
+                self._read_locked()
 
     def _reserve_next_locked(self):
         """Pop the head of the queue with its worst-case pages reserved
         in every kind of layer and its prompt's pages allocated:
         ``(stream, RowPages)``, or None when nothing can be admitted
-        now."""
+        now, or ``_READ_FIRST`` when it lacks pages or a slot that rows
+        ending in the unread step hold (booking it returns them)."""
         if not self._queue or \
                 len(self._active) >= self.engine.config.decode_buckets[-1]:
             return None
@@ -561,6 +685,9 @@ class ContinuousScheduler:
             len(st.prompt), st.max_new_tokens,
             self.engine.max_pages_per_seq)
         if pages is None:
+            if self._flight is not None and any(
+                    a.ending for a in self._flight.rows):
+                return _READ_FIRST
             # head-of-line blocking is deliberate: skipping ahead
             # would starve large requests under sustained load
             short = self.engine.pool.last_refusal == "state"
@@ -571,10 +698,11 @@ class ContinuousScheduler:
         self._queue.popleft()
         return st, pages
 
-    def _book_engine_locked(self, decode: bool = False) -> None:
-        """What the last engine call reported beside its tokens (the
-        experts' load), into the counters."""
-        aux = self.engine.take_aux()
+    def _book_aux_locked(self, aux: Dict[str, int],
+                         decode: bool = False) -> None:
+        """What an engine call reported beside its tokens (the experts'
+        load: ``engine.take_aux()`` of a prefill, a decode step's own),
+        into the counters."""
         touched = aux.pop("moe_experts_touched", None)
         if decode and touched is not None:
             self.stats["moe_decode_experts_touched"] += touched
@@ -595,7 +723,7 @@ class ContinuousScheduler:
             self.stats["ssm_tokens_scanned"] += len(st.prompt)
             self._book("pt_serve_ssm_tokens_scanned_total", kind="counter",
                        value=len(st.prompt))
-        self._book_engine_locked()
+        self._book_aux_locked(self.engine.take_aux())
         act = _Active(st, pages, pos=len(st.prompt), last_token=first)
         if self._is_finished(act):
             self._retire_locked(act)
@@ -605,20 +733,36 @@ class ContinuousScheduler:
                 self.stats["peak_active"], len(self._active))
 
     def _decode_locked(self) -> bool:
+        stats = self.stats
+        flight = self._flight
+        if flight is not None and (
+                not self._active or self.engine.decode_bucket_for(
+                    len(self._active)) != flight.step.bucket):
+            # nothing follows the unread step, or compacted the rows fit
+            # a smaller program: read it, then launch from the host's
+            # tokens, every row in the first slots
+            self._read_locked()
+            flight = None
+            if not self._active:
+                return True
         if not self._active:
             return False
-        stats = self.stats
         with span("serve.decode.prep") as sp:
             # grow page tables for rows whose next write crosses a page
             # boundary, and give back what slid out of a window — drawn
-            # from the admission-time reservation, so it cannot fail
-            for a in self._active:
+            # from the admission-time reservation, so it cannot fail.
+            # (A page handed back while a step that reads it is in
+            # flight is safe: whatever takes it is written by a program
+            # launched later.)
+            rows = self._active
+            for a in rows:
                 stats["kv_window_pages_returned"] += a.pages.advance(a.pos)
-            n = len(self._active)
-            tokens = np.asarray([a.last_token for a in self._active],
-                                np.int32)
-            positions = np.asarray([a.pos for a in self._active], np.int32)
-            tables = np.stack([a.pages.table for a in self._active])
+            n = len(rows)
+            # the last tokens read: one step old, and not what the
+            # program takes, where the rows follow an unread step
+            tokens = np.asarray([a.last_token for a in rows], np.int32)
+            positions = np.asarray([a.pos for a in rows], np.int32)
+            tables = np.stack([a.pages.table for a in rows])
             if self._sparse is not None:
                 # sums only, as the walks' below
                 layers, topk = self._sparse
@@ -639,45 +783,93 @@ class ContinuousScheduler:
                             page_size=self.engine.config.page_size,
                             window=found.get("tokens", 0)).sum())
                         stats["paged_grid_steps"] += found["grid_steps"]
-            # watchdog arms on the device call
-            self._step_started = t0 = time.monotonic()
+            if flight is not None:
+                # the rows sit where they sat in the unread step and
+                # take its tokens on the device
+                self.engine.decode_from = (flight.step, np.asarray(
+                    [a.index for a in rows], np.intp))
+            # the watchdog watches the oldest unread step
+            t0 = time.monotonic()
+            if self._step_started is None:
+                self._step_started = t0
         stats["decode_prep_s"] += sp.seconds
         try:
-            nxt = self.engine.decode(tokens, positions, tables)
-            now = time.monotonic()
+            step = self.engine.decode(tokens, positions, tables)
         except Exception as exc:
-            # a failed device step fails every resident request — with
-            # their pages RETURNED — and the loop keeps serving the
-            # queue; one poisoned batch must not wedge the engine
-            self._step_started = None
+            # a failed launch fails every resident request — with their
+            # pages RETURNED — and the loop keeps serving the queue; one
+            # poisoned batch must not wedge the engine
+            self.engine.decode_from = None
             stats["decode_s"] += time.monotonic() - t0
             self._fail_batch_locked(exc)
             return True
         with span("serve.book") as sp:
-            self._step_started = None
-            dt = now - t0
-            stats["decode_s"] += dt
+            stats["decode_s"] += time.monotonic() - t0
+            stats["steps"] += 1
+            still = []
+            for i, a in enumerate(rows):
+                a.index = i
+                a.pos += 1
+                a.launched += 1
+                if a.launched < a.stream.max_new_tokens:
+                    still.append(a)     # else it ends by count: a hole
+            self._active = still
+            launched = _Flight(step, rows, t0, ahead=flight is not None)
+        stats["book_s"] += sp.seconds
+        if flight is None:
+            self._flight = launched
+        else:
+            self._read_locked(behind=launched)
+        return True
+
+    def _read_locked(self, behind: Optional[_Flight] = None) -> None:
+        """Read the step in flight and book its tokens; ``behind`` is
+        the step just launched behind it, the one in flight from here.
+        A row whose stream is resolved by now (evicted, or retired by an
+        ``eos_id`` a step before) has its token dropped."""
+        stats = self.stats
+        flight, self._flight = self._flight, behind
+        t0 = time.monotonic()
+        try:
+            nxt = flight.step.read()
+            now = time.monotonic()
+        except Exception as exc:
+            # a program that failed surfaces here, and with it every
+            # step launched after it: all resident rows fail
+            stats["decode_s"] += time.monotonic() - t0
+            self._fail_batch_locked(exc, flight.rows)
+            return
+        with span("serve.book") as sp:
+            stats["decode_s"] += now - t0
+            self._step_started = (None if behind is None
+                                  else behind.launched_ts)
+            # the step period: from its launch, or from the read before
+            # it where it was launched ahead, to its tokens on the host
+            dt = now - max(flight.launched_ts, self._step_read)
+            self._step_read = now
             self._step_times.append(dt)
             self._step_ewma = (dt if self._step_ewma is None
                                else 0.2 * dt + 0.8 * self._step_ewma)
-            bucket = self.engine.decode_bucket_for(n)
+            n, bucket = len(flight.rows), flight.step.bucket
             stats["occupancy_sum"] += n / bucket
             stats["occupancy_steps"] += 1
+            stats["decode_steps_ahead"] += flight.ahead
             self._book("pt_serve_batch_occupancy", kind="gauge",
                        value=n / bucket)
-            still = []
-            booked = 0
-            for a, t in zip(self._active, nxt):
+            self._book("pt_serve_decode_steps_total", kind="counter",
+                       launch="ahead" if flight.ahead else "sync")
+            booked, left = 0, False
+            for a, t in zip(flight.rows, nxt):
+                if a.stream.done():
+                    continue
                 try:
-                    a.pos += 1
                     a.last_token = int(t)
                     a.stream.tokens.append(int(t))
                     a.stream.last_token_ts = now
                     booked += 1
                     if self._is_finished(a):
                         self._retire_locked(a)
-                    else:
-                        still.append(a)
+                        left = True
                 except Exception as exc:
                     # per-row isolation: this request fails alone; its
                     # neighbours keep decoding and its pages come back
@@ -686,31 +878,44 @@ class ContinuousScheduler:
                     self._book("pt_serve_request_failures_total",
                                kind="counter", stage="step")
                     a.stream._finish(error=exc)
+                    left = True
                     logger.exception(
                         "step bookkeeping failed for request %d",
                         a.stream.request_id)
+            if left:
+                # an eos_id's row may sit in the step behind this one
+                # too: that token is dropped when that step is booked
+                self._active = [a for a in self._active
+                                if not a.stream.done()]
             stats["tokens_generated"] += booked
             stats["decode_tokens"] += n
             stats["shared_kv_reads"] += self._shared_readers
             self._book("pt_serve_decode_tokens_total", kind="counter",
                        value=n)
-            self._book_engine_locked(decode=True)
+            self._book_aux_locked(flight.step.take_aux(), decode=True)
             self._book("pt_serve_tokens_total", kind="counter",
                        value=booked)
-            self._active = still
         stats["book_s"] += sp.seconds
-        return True
 
-    def _fail_batch_locked(self, exc: BaseException) -> None:
-        for a in self._active:
+    def _fail_batch_locked(self, exc: BaseException,
+                           also: Sequence[_Active] = ()) -> None:
+        """Fail every resident request (and the unresolved among
+        ``also``, the rows of a step being read), pages returned, and
+        forget the step in flight: what was launched behind a failed
+        program is not read."""
+        rows = {id(a): a for a in self._seated_locked()}
+        rows.update((id(a), a) for a in also if not a.stream.done())
+        for a in rows.values():
             self._release_locked(a)
             self.stats["failed"] += 1
             self._book("pt_serve_request_failures_total",
                        kind="counter", stage="decode")
             a.stream._finish(error=exc)
-        logger.exception("decode step failed; %d requests failed, pages "
-                         "released", len(self._active))
+        logger.error("decode step failed; %d requests failed, pages "
+                     "released", len(rows), exc_info=exc)
         self._active = []
+        self._flight = None
+        self._step_started = None
 
     def _is_finished(self, a: _Active) -> bool:
         st = a.stream
@@ -783,12 +988,21 @@ class ContinuousScheduler:
         if w is not None:
             w.join(timeout)
             self._watchdog_thread = None
+        # the loop is gone: the step it left in flight is read here (a
+        # loop that did not end within `timeout` still holds the lock)
+        if self._lock.acquire(timeout=timeout):
+            try:
+                if self._flight is not None:
+                    self._read_locked()
+            finally:
+                self._lock.release()
 
     def _loop(self) -> None:
         while not self._stop.is_set():
             with span("serve.wait") as sp:
                 with self._cv:
-                    while (not self._queue and not self._active
+                    # a step in flight is work: it is read, not slept on
+                    while (self._idle_locked()
                            and not self._stop.is_set()):
                         self._cv.wait(0.05)
             self.stats["wait_s"] += sp.seconds  # this thread's key alone
@@ -803,17 +1017,25 @@ class ContinuousScheduler:
     def drain(self) -> None:
         """Block until queue and batch are empty.  Steps inline when no
         background loop is running (synchronous/generate mode)."""
-        if self._thread is not None and self._thread.is_alive():
+        if self._loop_alive():
             while True:
                 with self._lock:
-                    if not self._queue and not self._active:
+                    if self._idle_locked():
                         return
                 time.sleep(0.002)
         while True:
             with self._lock:
-                if not self._queue and not self._active:
+                if self._idle_locked():
                     return
             self.step()
+
+    def _idle_locked(self) -> bool:
+        """Nothing queued, seated or in flight."""
+        return (not self._queue and not self._active
+                and self._flight is None)
+
+    def _loop_alive(self) -> bool:
+        return self._thread is not None and self._thread.is_alive()
 
     # -- graceful drain (SIGTERM lifecycle) ----------------------------------
 
@@ -838,11 +1060,10 @@ class ContinuousScheduler:
         self.begin_drain()
         if budget_s is None:
             budget_s = float(getattr(self.engine.config, "drain_s", 10.0))
-        loop_running = (self._thread is not None
-                        and self._thread.is_alive())
+        loop_running = self._loop_alive()
         while time.monotonic() - t0 < budget_s:
             with self._lock:
-                if not self._queue and not self._active:
+                if self._idle_locked():
                     break
             if loop_running:
                 time.sleep(0.01)
@@ -850,16 +1071,17 @@ class ContinuousScheduler:
                 self.step()
         clean = True
         with self._cv:
+            if self._flight is not None:
+                # what the last launched step finished is not cut short
+                self._read_locked()
             leftovers = list(self._queue)
             self._queue.clear()
             for st in leftovers:
                 clean = False
                 self._finish_evicted_locked(st, "drain")
-            for a in list(self._active):
+            for a in self._seated_locked():
                 clean = False
-                self._active.remove(a)
-                self._release_locked(a)
-                self._finish_evicted_locked(a.stream, "drain")
+                self._drop_locked(a, "drain")
             self._gauges_locked()
         dur = time.monotonic() - t0
         self.stats["drain_seconds"] = dur
@@ -909,17 +1131,21 @@ class ContinuousScheduler:
 
     def _trip_watchdog(self, mode: str, stuck: float,
                        threshold: float) -> None:
-        """The in-flight decode step is hung (NOT merely loaded: the
-        threshold tracks the rolling p99).  Runs WITHOUT the scheduler
-        lock — the hung step is holding it."""
+        """The oldest unread decode step is hung (NOT merely loaded: the
+        threshold tracks the rolling p99 of the step period).  Runs
+        WITHOUT the scheduler lock — the hung step's launch or read is
+        holding it."""
         self.hang_detected = True
         self.stats["watchdog_trips"] += 1
         try:
-            rids = [a.stream.request_id for a in list(self._active)]
+            flight = self._flight
+            rids = [a.stream.request_id for a in
+                    (flight.rows if flight is not None
+                     else list(self._active))]
         except Exception:
             rids = []
         logger.error(
-            "serve hang watchdog tripped: decode step in flight for "
+            "serve hang watchdog tripped: decode step unread for "
             "%.3fs (threshold %.3fs); active batch %s",
             stuck, threshold, rids)
         self._book("pt_serve_hang_watchdog_trips_total", kind="counter")
@@ -938,12 +1164,17 @@ class ContinuousScheduler:
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
+            if self._flight is not None and not self._loop_alive():
+                # stepped inline, the caller owns the batch: the counters
+                # then hold the last step too (a running loop reads it
+                # within a step)
+                self._read_locked()
             self._book_walk_locked()
             occ = (self.stats["occupancy_sum"] /
                    max(1, self.stats["occupancy_steps"]))
             return {
                 "queue_depth": len(self._queue),
-                "active_sequences": len(self._active),
+                "active_sequences": len(self._seated_locked()),
                 "batch_occupancy_mean": occ,
                 "draining": self._draining,
                 "hang_detected": self.hang_detected,
@@ -969,7 +1200,7 @@ class ContinuousScheduler:
         self._book("pt_serve_queue_depth", kind="gauge",
                    value=len(self._queue))
         self._book("pt_serve_active_sequences", kind="gauge",
-                   value=len(self._active))
+                   value=len(self._seated_locked()))
 
     def _book(self, name: str, *, kind: str, value: float = 1.0,
               labels: Optional[Dict[str, str]] = None, **more) -> None:
@@ -1045,6 +1276,9 @@ _METRIC_HELP = {
     "pt_serve_active_sequences": "Sequences resident in the decode batch",
     "pt_serve_batch_occupancy":
         "Active rows / decode bucket size of the last step",
+    "pt_serve_decode_steps_total":
+        "Decode steps booked, by launch: before the step before was read "
+        "(ahead) or after (sync)",
     "pt_serve_request_latency_seconds":
         "End-to-end request latency (entry of submit to last token)",
     "pt_serve_queue_wait_seconds":

@@ -557,9 +557,10 @@ def test_hybrid_metrics_have_entries_for_their_cell_and_read_nothing_elsewhere(
     assert spec["workloads"][-2]["chips"] == 1
     assert spec["configs"][-2]["name"] == spec["workloads"][-2]["config"]
     assert spec["configs"][-2]["reduced"] == []
-    hybrid = spec["per_layer"][-16:-7]      # then PR 32's one, PR 34's 6
+    # then PR 32's one, PR 34's 6, PR 35's 2
+    hybrid = spec["per_layer"][-18:-9]
     assert [m["name"] for m in hybrid] == HYBRID_METRICS
-    assert spec["per_layer"][-7]["name"] == "paged_walk_live_pct.grouped"
+    assert spec["per_layer"][-9]["name"] == "paged_walk_live_pct.grouped"
     for m in hybrid:
         assert m["workloads"] == [cell]
         assert m["moves"] == "serve_tokens_per_s"
@@ -653,3 +654,41 @@ def test_flash_chunks_visited_is_read_from_the_registry(bench):
         "name": name, "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "kernels (ops/pallas_ops.py)",
         "moves": "train_tokens_per_s", "workloads": ["gpt345m-train-s1024"]}
+
+
+AHEAD_METRICS = {
+    "decode_ahead_pct.backlog": ("serve_tokens_per_s", [
+        "gpt345m-serve-longprompt-backlog", "mellum2-serve-mixedctx-backlog",
+        "phi4flash-serve-reasoning-backlog",
+        "keyevl2-serve-longctx-reasoning-backlog"]),
+    "decode_ahead_pct.steady": ("tpot_p50_ms",
+                                ["gpt345m-serve-complete-steady"]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(AHEAD_METRICS))
+def test_decode_ahead_pct_with_the_counter(bench, name):
+    """2,780 of 2,800 steps launched before the one before was read."""
+    run = {"trace": None, "counters": {"decode_steps_ahead": 2780,
+                                       "occupancy_steps": 2800}}
+    assert read(bench, name, run) == pytest.approx(100 * 2780 / 2800)
+    run["counters"]["decode_steps_ahead"] = 0       # every step after a read
+    assert read(bench, name, run) == 0.0
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    moves, cells = AHEAD_METRICS[name]
+    assert next(m for m in spec["per_layer"] if m["name"] == name) == {
+        "name": name, "unit": "%", "better": "higher",
+        "source": "program_counter",
+        "layer": "scheduler (serving/scheduler.py)", "moves": moves,
+        "workloads": cells}
+
+
+@pytest.mark.parametrize("name", sorted(AHEAD_METRICS))
+def test_decode_ahead_pct_without_the_counter(bench, name):
+    """A program that has no such counter (the parent), or a window
+    without a decode step: nothing to read, and nothing raised."""
+    assert read(bench, name, {"trace": None, "counters": {
+        "occupancy_steps": 2800}}) is None
+    assert read(bench, name, {"trace": None, "counters": {
+        "decode_steps_ahead": 0, "occupancy_steps": 0}}) is None
+    assert read(bench, name, {"trace": None}) is None
