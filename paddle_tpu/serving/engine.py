@@ -114,14 +114,20 @@ class DecodeStep:
     returned, from any thread; the scheduler reads a step after it has
     launched the next one (``engine.decode_from``).  ``bucket`` is the
     program's batch, ``slots`` where in it each of the call's rows sat,
-    and :meth:`take_aux` what this step's routed experts counted."""
+    ``seq`` the engine's number of the program call that launched it
+    (:attr:`ServingEngine.launches`; the ``launch=`` of its spans, the
+    ``fetch`` of its read among them), and :meth:`take_aux` what this
+    step's routed experts counted."""
 
-    __slots__ = ("rows", "bucket", "slots", "_tokens", "_aux", "_host")
+    __slots__ = ("rows", "bucket", "slots", "seq", "_tokens", "_aux",
+                 "_host")
 
-    def __init__(self, tokens, aux, slots: np.ndarray, bucket: int):
+    def __init__(self, tokens, aux, slots: np.ndarray, bucket: int,
+                 seq: int = 0):
         self.rows = int(slots.shape[0])
         self.bucket = bucket
         self.slots = slots
+        self.seq = seq
         self._tokens = tokens       # (bucket,) on the device
         self._aux = aux             # (L, E) on the device, or None
         self._host: Optional[np.ndarray] = None
@@ -129,7 +135,7 @@ class DecodeStep:
     def read(self) -> np.ndarray:
         if self._host is None:
             with span("serve.decode.fetch", rows=self.rows,
-                      bucket=self.bucket):
+                      bucket=self.bucket, launch=self.seq):
                 self._host = np.asarray(self._tokens)[self.slots]
         return self._host
 
@@ -358,6 +364,9 @@ class ServingEngine:
             # :class:`DecodeStep`'s call and take their tokens from it,
             # on the device
             self.decode_from: Optional[Tuple[DecodeStep, np.ndarray]] = None
+            # program calls so far (:attr:`launches`): the warm-up's and
+            # the checks' count too
+            self._launches = 0
             self._warmed = False
             self._prefill_exe: Dict[int, Any] = {}
             self._decode_exe: Dict[int, Any] = {}
@@ -629,6 +638,7 @@ class ServingEngine:
         ``(token(s), logits)`` still on the device (then what the
         selection program adds).  A model of routed experts also returns
         its tokens per expert, kept for :meth:`take_aux`."""
+        self._launches += 1
         state = self._kv_state()
         out = exe(params, *state, *args)
         self.pool.swap(*out[:len(state)])
@@ -640,6 +650,15 @@ class ServingEngine:
         if self._aux is not None:
             self._aux.copy_to_host_async()
         return (rest[0], rest[1], *rest[3:])
+
+    @property
+    def launches(self) -> int:
+        """Program calls so far: the number of the last one launched.
+        Every call is numbered, whoever makes it; its spans carry the
+        number as ``launch=``, a decode step keeps it as
+        :attr:`DecodeStep.seq`, and right after :meth:`prefill` returns
+        it is that prefill's."""
+        return self._launches
 
     def expert_counts(self) -> Optional[np.ndarray]:
         """Tokens the last program call routed to each expert of each
@@ -745,7 +764,8 @@ class ServingEngine:
         the padding, ``.launch`` the executable call until it returns,
         ``.fetch`` the pool rebind and the token's device-to-host copy
         (which waits for the program).  They carry the ``request_id``
-        the scheduler left in ``prefill_request_id``."""
+        the scheduler left in ``prefill_request_id`` and ``launch``, the
+        call's number (:attr:`launches`)."""
         return self._prefill(tokens, page_table, False)[0]
 
     def prefill_logits(self, tokens: Sequence[int],
@@ -756,8 +776,8 @@ class ServingEngine:
         return self._prefill(tokens, page_table, True)
 
     def _prefill(self, tokens, page_table, want_logits):
-        rid = self.prefill_request_id
-        with span("serve.prefill.prep", request_id=rid):
+        rid, seq = self.prefill_request_id, self._launches + 1
+        with span("serve.prefill.prep", request_id=rid, launch=seq):
             n = len(tokens)
             s = self.prefill_bucket_for(n)
             padded = np.zeros((s,), np.int32)
@@ -765,10 +785,10 @@ class ServingEngine:
             table = np.asarray(page_table, np.int32)
             with self._weights_lock:
                 params = self._params
-        with span("serve.prefill.launch", request_id=rid):
+        with span("serve.prefill.launch", request_id=rid, launch=seq):
             nxt, logits = self._run(self._prefill_exe[s], params, padded,
                                     np.int32(n), table)
-        with span("serve.prefill.fetch", request_id=rid):
+        with span("serve.prefill.fetch", request_id=rid, launch=seq):
             return int(nxt), (np.asarray(logits, np.float32)
                               if want_logits else None)
 
@@ -781,9 +801,9 @@ class ServingEngine:
 
         Padding rows carry position 0 + the all-null page table, so
         their (garbage) K/V writes land in the null page.  Leaf spans
-        as in :meth:`prefill`, carrying ``rows`` and ``bucket``:
-        ``.prep`` and ``.launch`` here, ``.fetch`` where the step is
-        read.
+        as in :meth:`prefill`, carrying ``rows``, ``bucket`` and
+        ``launch``: ``.prep`` and ``.launch`` here, ``.fetch`` where the
+        step is read, under the number of the launch it waits for.
 
         With ``decode_from = (step, keep)`` set (this call takes it),
         the rows are rows ``keep`` of ``step``'s call, each in the slot
@@ -802,7 +822,7 @@ class ServingEngine:
                 carry[0]._tokens
         nxt, *_ = self._launch(self._decode_exe[b], b, slots, tokens,
                                positions, page_tables, feed)
-        return DecodeStep(nxt, self._aux, slots, b)
+        return DecodeStep(nxt, self._aux, slots, b, self._launches)
 
     def decode_logits(self, tokens: np.ndarray, positions: np.ndarray,
                       page_tables: np.ndarray
@@ -834,8 +854,8 @@ class ServingEngine:
         """Pad the rows into ``slots`` of a batch of ``b`` and call
         ``exe``: what :meth:`_run` returns.  ``feed`` is a whole batch's
         token vector on the device, taken in place of ``tokens``."""
-        n = slots.shape[0]
-        with span("serve.decode.prep", rows=n, bucket=b):
+        n, seq = slots.shape[0], self._launches + 1
+        with span("serve.decode.prep", rows=n, bucket=b, launch=seq):
             pos = np.zeros((b,), np.int32)
             pt = np.full((b, *self.table_shape), NULL_PAGE, np.int32)
             pos[slots] = positions
@@ -846,7 +866,7 @@ class ServingEngine:
                 tok[slots] = tokens
             with self._weights_lock:
                 params = self._params
-        with span("serve.decode.launch", rows=n, bucket=b):
+        with span("serve.decode.launch", rows=n, bucket=b, launch=seq):
             return self._run(exe, params, tok, pos, pt)
 
     def _decode(self, tokens, positions, page_tables, want_logits,
@@ -858,7 +878,8 @@ class ServingEngine:
         nxt, logits, *more = self._launch(
             selection_exe or self._decode_exe[b], b, np.arange(n), tokens,
             positions, page_tables)
-        with span("serve.decode.fetch", rows=n, bucket=b):
+        with span("serve.decode.fetch", rows=n, bucket=b,
+                  launch=self._launches):
             return (np.asarray(nxt)[:n], np.asarray(logits, np.float32)[:n]
                     if want_logits else None, *more)
 

@@ -231,6 +231,7 @@ class ServeHTTPServer:
                         "request_id": stream.request_id,
                         "latency_ms": wall * 1e3,
                         "ttft_ms": stream.ttft * 1e3,
+                        "token_gap_max_ms": stream.token_gap_max * 1e3,
                         "weights_step": engine.weights_step,
                     })
                 elif isinstance(err, DeadlineExceeded):

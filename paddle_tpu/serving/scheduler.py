@@ -64,9 +64,10 @@ The device always has a program queued behind the one it runs.
    the queue only; ``cancel`` takes a seated row out of the batch and
    frees its pages at once (its token of the unread step is dropped
    when that step is booked).  The watchdog's ``_step_started`` is the
-   oldest unread step's launch; ``_step_times`` / ``_step_ewma`` (the
-   shed ETA) take the step *period* (a read to the next read, or a
-   launch to its read where nothing was ahead of it).  A program that
+   oldest unread step's launch; the step log's decode records and
+   ``_step_ewma`` (the shed ETA) take the step *period* (a read to the
+   next read, or a launch to its read where nothing was ahead of it).
+   A program that
    fails surfaces at the read and fails the rows of every unread step,
    pages returned.  ``drain``, ``drain_gracefully``, ``stop`` and (with
    no loop running) ``snapshot`` read the last step before they
@@ -114,10 +115,11 @@ engine calls),
 ``serve.prefill.prep`` / ``.launch`` / ``.fetch`` and
 ``serve.decode.prep`` / ``.launch`` / ``.fetch`` (the last six but the
 page-table half of ``decode.prep`` inside :mod:`.engine`), and
-``serve.book`` (token append, retirement, gauges).  A steady step's
-order on the thread: ``decode.prep`` (tables, batch), ``decode.prep``
-(the engine's padding), ``decode.launch`` of step k+1, ``book`` (rows
-ending by count leave), ``decode.fetch`` of step k, ``book``.  Inside
+``serve.book`` (token append, retirement, the registry's sync).  A
+steady step's order on the thread: ``decode.prep`` (tables, batch),
+``decode.prep`` (the engine's padding), ``decode.launch`` of step k+1,
+``book`` (rows ending by count leave), ``decode.fetch`` of step k,
+``book``.  Inside
 a profiler session they are ``pt:serve.*`` events on the device trace's
 clock; the
 same boundaries add to ``stats`` as float sums (``wait_s``, ``evict_s``,
@@ -133,8 +135,8 @@ layers'): ``paged_chunks_walked`` and ``paged_grid_steps``
 launch-ahead engages: ``decode_steps_ahead`` of ``occupancy_steps``
 (booked steps launched before the step before them was read;
 ``pt_serve_decode_steps_total{launch="ahead"|"sync"}``).  A model with
-state-space layers: ``state_slots_held`` (now) and
-``state_slots_held_max``, ``refused_state`` (admissions refused for want
+state-space layers: ``state_slots_held`` and ``state_slots_held_max``
+(the pool's own numbers as of the last sync, below), ``refused_state`` (admissions refused for want
 of a slot, beside ``refused_kv``), ``ssm_tokens_scanned`` (prompt
 positions through the scan) and, with cross layers, ``shared_kv_reads``
 (decode steps times the layers that read the shared full layer's pages).
@@ -143,6 +145,52 @@ sparse layer, the sum of its rows' contexts: every cached indexer key a
 query is scored against) and ``sparse_tokens_selected`` (the sum of
 ``min(context, topk)``: what the layer then attends over), exported as
 ``pt_serve_sparse_tokens_total{kind="scored"|"selected"}``.
+
+**A record a launched program** (:class:`StepLog`, always on): the
+scheduler keeps the last 4,096 program calls it made, one tuple each,
+from the time stamps the thread takes anyway.  A decode step, written
+when it is booked: ``(seq, "decode", bucket, rows, ahead, launched_ts,
+read_ts, period_s)``; a prefill, written when ``engine.prefill``
+returns: ``(seq, "prefill", bucket, prompt_len, request_id, call_ts,
+first_token_ts, seated_rows)``.  ``seq`` is the engine's number of the
+call (``launch=`` on its ``serve.*`` spans), ``period_s`` the step
+period above, ``seated_rows`` the rows that held a seat (in the batch or
+in the unread step) when the prefill was called and so waited behind
+it.  A launch or a read that fails leaves no record.
+:meth:`ContinuousScheduler.step_log` hands out copies; ``_step_ewma``,
+the watchdog's p99 and ``/healthz`` ``step_period_p50_s`` / ``_p99_s``
+read its decode records, and a tripped watchdog puts the last 64 into
+its flight dump (``extra["serve_steps"]``), tracer on or off.  Counters
+from the same entries: ``step_period_s`` over ``steps_timed`` is the
+mean step period, with no wait for arrivals in it and no prefill: a step
+whose read waited behind a prefill (an admission with a step in flight:
+``engine.prefill`` returns after both) has the prefill's time in its
+period on the host's clock, so it is booked and logged as it is and left
+out of these two (and out of ``steps_slow``); ``steps_slow`` counts the
+timed steps whose period exceeded 1.5 x ``_step_ewma`` as it stood before
+them (a median hides that tail: a stall, and the first step of a batch,
+launched from the host's tokens, where launch, program and the token's
+way back come to that much; the average holds the periods read behind a
+prefill too, so for the half dozen steps after a long prompt it stands
+high and a stall there is not counted); ``prefill_row_stall_s`` (the sum over
+prefills of the call's duration times ``seated_rows``: row-seconds spent
+behind another request's prompt, the rest of the step in flight among
+them) over ``prefill_runs`` (the prefills that returned a first token) is
+``/healthz`` ``prefill_row_stall_mean_s``, what one admission costs the
+rows already seated; and, a request, ``token_gap_max_s`` (count
+``tpot_requests``): the largest gap between two consecutive tokens of
+it, summed when it retires, whenever the gap happened.
+
+**The registry follows ``stats``, off the step's path.**  A decode step
+that launches, reads and retires nothing books no instrument: every
+``pt_serve_*_total`` counter that ``stats`` holds and the gauges are
+brought up to it (:meth:`ContinuousScheduler._sync_registry_locked`) in
+:meth:`snapshot` (``/healthz``), when the loop stops, and from ``step()``
+and the loop's idle pass once 0.25 s have passed since the last: a scrape
+lags a quarter second at most, under any traffic.
+The histograms are booked at retirement; events whose label ``stats``
+does not keep (a shed's reason, an eviction's cause, a failure's stage)
+where they happen.
 """
 from __future__ import annotations
 
@@ -217,7 +265,8 @@ class GenerationStream:
     ``arrived_ts`` (entry of ``submit()``, before the scheduler lock),
     ``submitted_ts`` (enqueued, the lock held), ``admitted_ts`` (its
     prefill entered), ``first_token_ts``, ``last_token_ts``,
-    ``finished_ts``."""
+    ``finished_ts``.  ``token_gap_max`` is the largest gap so far between
+    two consecutive tokens (0.0 until the second)."""
 
     _ids = itertools.count()
 
@@ -235,6 +284,7 @@ class GenerationStream:
         self.first_token_ts: Optional[float] = None
         self.last_token_ts: Optional[float] = None
         self.finished_ts: Optional[float] = None
+        self.token_gap_max = 0.0
         self.deadline = deadline        # absolute time.monotonic(), or None
         self.cancel_cause: Optional[str] = None
         self._done = threading.Event()
@@ -349,6 +399,56 @@ class _Flight:
         self.ahead = ahead
 
 
+class StepLog:
+    """The last ``capacity`` program calls the scheduler made, oldest
+    first: one tuple a call (module docstring), appended by the
+    scheduler's thread and read by anyone (an append and a copy of a
+    deque are atomic)."""
+
+    DECODE = ("seq", "kind", "bucket", "rows", "ahead", "launched_ts",
+              "read_ts", "period_s")
+    PREFILL = ("seq", "kind", "bucket", "prompt_len", "request_id",
+               "call_ts", "first_token_ts", "seated_rows")
+
+    __slots__ = ("_ring", "append")
+
+    def __init__(self, capacity: int = 4096):
+        self._ring: deque = deque(maxlen=capacity)
+        self.append = self._ring.append
+
+    def __len__(self) -> int:
+        return len(self._ring)
+
+    def last(self, n: Optional[int] = None) -> List[tuple]:
+        """The newest ``n`` records (all of them for None), oldest
+        first."""
+        records = list(self._ring)
+        return records if n is None else records[-n:] if n > 0 else []
+
+    def periods(self, n: Optional[int] = None) -> List[float]:
+        """``period_s`` of the newest ``n`` decode records, oldest
+        first (the walk stops at the ``n``-th: the watchdog polls this)."""
+        out: List[float] = []
+        for r in reversed(self._ring.copy()):
+            if r[1] == "decode":
+                out.append(r[7])
+                if len(out) == n:
+                    break
+        out.reverse()
+        return out
+
+    def as_dicts(self, n: Optional[int] = None) -> List[Dict[str, Any]]:
+        """:meth:`last` with the fields named (JSON-ready: a dump)."""
+        return [dict(zip(self.DECODE if r[1] == "decode" else self.PREFILL,
+                         r)) for r in self.last(n)]
+
+
+# the watchdog's p99 is over this many of the newest step periods
+_WATCHDOG_STEPS = 256
+# the registry is brought up to `stats` at least this often (seconds)
+_SYNC_EVERY_S = 0.25
+
+
 class ContinuousScheduler:
     """Admission + step loop; owns the queue and the active batch."""
 
@@ -368,7 +468,7 @@ class ContinuousScheduler:
         # launch of the oldest unread step (what the watchdog watches)
         self._step_started: Optional[float] = None
         self._step_read: float = 0.0                 # the last read's end
-        self._step_times: deque = deque(maxlen=256)  # rolling step periods
+        self._log = StepLog()       # every program call, newest 4,096
         self._step_ewma: Optional[float] = None      # sec per decode step
         self.stats = {
             "submitted": 0, "completed": 0, "refused_inflight": 0,
@@ -412,6 +512,14 @@ class ContinuousScheduler:
             # seconds of requests' waits, and what to divide them by
             "lock_wait_s": 0.0, "queue_wait_s": 0.0, "admitted": 0,
             "ttft_s": 0.0, "tpot_s": 0.0, "tpot_requests": 0,
+            # the step log's sums (module docstring): timed steps'
+            # periods, their number, and how many ran over 1.5 x
+            # `_step_ewma` as it stood before them;
+            # prefills that gave a token and the row-seconds seated rows
+            # waited behind them; requests' largest token gaps
+            "step_period_s": 0.0, "steps_timed": 0, "steps_slow": 0,
+            "prefill_runs": 0, "prefill_row_stall_s": 0.0,
+            "token_gap_max_s": 0.0,
         }
         spec = engine.spec
         self._scans = bool(spec.ssm_layers)
@@ -423,7 +531,10 @@ class ContinuousScheduler:
                         if spec.sparse_topk else None)
         self._meter_registry = None     # the registry self._meters are of
         self._meters: Dict[str, Any] = {}
-        self._walk_booked: Dict[str, int] = {}
+        # what the registry's counters hold of `stats`, and when the
+        # next sync is due (0.0: at the end of the first step)
+        self._synced: Dict[str, float] = {}     # by the `stats` key
+        self._sync_at = 0.0
 
     # -- submission ----------------------------------------------------------
 
@@ -458,8 +569,6 @@ class ContinuousScheduler:
             inflight = len(self._queue) + len(self._seated_locked())
             if inflight >= cfg.max_inflight:
                 self.stats["refused_inflight"] += 1
-                self._book("pt_serve_admission_refusals_total",
-                           kind="counter", reason="inflight_cap")
                 raise EngineSaturated(
                     f"{inflight} requests in flight (cap "
                     f"{cfg.max_inflight})")
@@ -488,8 +597,6 @@ class ContinuousScheduler:
             st._sched = self
             self._queue.append(st)
             self.stats["submitted"] += 1
-            self._book("pt_serve_requests_total", kind="counter")
-            self._gauges_locked()
             self._cv.notify()
         return st
 
@@ -532,12 +639,10 @@ class ContinuousScheduler:
                 if st.request_id == request_id:
                     self._queue.remove(st)
                     self._finish_evicted_locked(st, cause)
-                    self._gauges_locked()
                     return True
             for a in self._seated_locked():
                 if a.stream.request_id == request_id:
                     self._drop_locked(a, cause)
-                    self._gauges_locked()
                     return True
         return False
 
@@ -564,7 +669,6 @@ class ContinuousScheduler:
         st.cancel_cause = cause
         if cause == "deadline":
             self.stats["deadline_exceeded"] += 1
-            self._book("pt_serve_deadline_exceeded_total", kind="counter")
             err: BaseException = DeadlineExceeded(
                 f"request {st.request_id} missed its deadline after "
                 f"{len(st.tokens)}/{st.max_new_tokens} tokens")
@@ -576,18 +680,18 @@ class ContinuousScheduler:
         self._book("pt_serve_cancelled_total", kind="counter", cause=cause)
         st._finish(error=err)
 
-    def _expire_queue_locked(self) -> None:
-        now = time.monotonic()
+    def _expire_queue_locked(self, now: Optional[float] = None) -> None:
+        if now is None:
+            now = time.monotonic()
         expired = [st for st in self._queue
                    if st.deadline is not None and now >= st.deadline]
         for st in expired:
             self._queue.remove(st)
             self._finish_evicted_locked(st, "deadline")
 
-    def _evict_expired_locked(self) -> None:
+    def _evict_expired_locked(self, now: float) -> None:
         """Deadline sweep at the step boundary: queued AND active."""
-        self._expire_queue_locked()
-        now = time.monotonic()
+        self._expire_queue_locked(now)
         for a in self._seated_locked():
             if a.stream.deadline is not None and now >= a.stream.deadline:
                 self._drop_locked(a, "deadline")
@@ -609,16 +713,20 @@ class ContinuousScheduler:
             self._lock.acquire()
         try:
             stats["wait_s"] += sp.seconds
+            now = time.monotonic()
             with span("serve.evict") as sp:
-                self._evict_expired_locked()
+                self._evict_expired_locked(now)
             stats["evict_s"] += sp.seconds
             # draining closes submit(), not the internal queue: every
             # request accepted before SIGTERM still owes a response
             self._admit_locked()
             worked = self._decode_locked()
-            with span("serve.book") as sp:
-                self._gauges_locked()
-            stats["book_s"] += sp.seconds
+            if now >= self._sync_at:
+                # a quarter second has passed: the registry catches up
+                # with `stats`
+                with span("serve.book") as sp:
+                    self._sync_registry_locked(now)
+                stats["book_s"] += sp.seconds
             return worked or bool(self._queue)
         finally:
             self._lock.release()
@@ -643,6 +751,8 @@ class ContinuousScheduler:
                     st, pages = job
                     engine.prefill_request_id = st.request_id
                     stats["admitted"] += 1
+                    # the rows that wait behind this prompt
+                    waiting = len(self._seated_locked())
                     st.admitted_ts = t0 = time.monotonic()
                     stats["queue_wait_s"] += t0 - st.submitted_ts
             stats["admit_host_s"] += sp.seconds
@@ -653,8 +763,14 @@ class ContinuousScheduler:
                 continue
             try:
                 first = engine.prefill(st.prompt, pages.table)
-                st.first_token_ts = st.last_token_ts = time.monotonic()
+                st.first_token_ts = st.last_token_ts = t1 = time.monotonic()
                 seated = (st, first, pages)
+                stats["prefill_runs"] += 1
+                stats["prefill_row_stall_s"] += (t1 - t0) * waiting
+                self._log.append((
+                    engine.launches, "prefill",
+                    engine.prefill_bucket_for(len(st.prompt)),
+                    len(st.prompt), st.request_id, t0, t1, waiting))
             except Exception as exc:  # resolve the caller, keep serving
                 stats["prefill_s"] += time.monotonic() - t0
                 pages.release()
@@ -668,8 +784,9 @@ class ContinuousScheduler:
                 # the prefill ran behind the step in flight and is read:
                 # that step's tokens are on the host.  Booked before the
                 # request is seated, so the next launch is an ordinary
-                # one, every row's token from the host
-                self._read_locked()
+                # one, every row's token from the host.  Its period on
+                # this clock holds the prefill: not one to time a step by
+                self._read_locked(timed=False)
 
     def _reserve_next_locked(self):
         """Pop the head of the queue with its worst-case pages reserved
@@ -692,8 +809,6 @@ class ContinuousScheduler:
             # would starve large requests under sustained load
             short = self.engine.pool.last_refusal == "state"
             self.stats["refused_state" if short else "refused_kv"] += 1
-            self._book("pt_serve_admission_refusals_total", kind="counter",
-                       reason="state_slots" if short else "kv_headroom")
             return None
         self._queue.popleft()
         return st, pages
@@ -708,21 +823,15 @@ class ContinuousScheduler:
             self.stats["moe_decode_experts_touched"] += touched
         for key, value in aux.items():
             self.stats[key] += value
-            self._book(f"pt_serve_{key}_total", kind="counter", value=value)
 
     def _seat_locked(self, st, first, pages) -> None:
         """Book a prefilled request's first token and seat it in the
         batch (or retire it, if one token was all it asked for)."""
         st.tokens.append(first)
-        self._book("pt_serve_tokens_total", kind="counter")
         self.stats["tokens_generated"] += 1
         self.stats["prefill_tokens"] += len(st.prompt)
-        self._book("pt_serve_prefill_tokens_total", kind="counter",
-                   value=len(st.prompt))
         if self._scans:
             self.stats["ssm_tokens_scanned"] += len(st.prompt)
-            self._book("pt_serve_ssm_tokens_scanned_total", kind="counter",
-                       value=len(st.prompt))
         self._book_aux_locked(self.engine.take_aux())
         act = _Active(st, pages, pos=len(st.prompt), last_token=first)
         if self._is_finished(act):
@@ -773,8 +882,8 @@ class ContinuousScheduler:
             walk = self.engine.paged_walk_for(n)
             if walk is not None:
                 # sums only, a list the program walks: the registry
-                # follows them when a request retires
-                # (_book_walk_locked), off the step's path
+                # follows them off the step's path
+                # (_sync_registry_locked)
                 lengths = positions + 1
                 for found in (walk, walk.get("window")):
                     if found and "grid_steps" in found:
@@ -822,11 +931,16 @@ class ContinuousScheduler:
             self._read_locked(behind=launched)
         return True
 
-    def _read_locked(self, behind: Optional[_Flight] = None) -> None:
+    def _read_locked(self, behind: Optional[_Flight] = None,
+                     timed: bool = True) -> None:
         """Read the step in flight and book its tokens; ``behind`` is
         the step just launched behind it, the one in flight from here.
         A row whose stream is resolved by now (evicted, or retired by an
-        ``eos_id`` a step before) has its token dropped."""
+        ``eos_id`` a step before) has its token dropped.  ``timed`` is
+        False where the read waited behind a prefill: the step's period
+        then holds the prefill's time (the log, ``_step_ewma`` and the
+        watchdog take it as it is, as ever) and stays out of
+        ``step_period_s`` / ``steps_timed`` / ``steps_slow``."""
         stats = self.stats
         flight, self._flight = self._flight, behind
         t0 = time.monotonic()
@@ -847,25 +961,30 @@ class ContinuousScheduler:
             # it where it was launched ahead, to its tokens on the host
             dt = now - max(flight.launched_ts, self._step_read)
             self._step_read = now
-            self._step_times.append(dt)
-            self._step_ewma = (dt if self._step_ewma is None
-                               else 0.2 * dt + 0.8 * self._step_ewma)
             n, bucket = len(flight.rows), flight.step.bucket
+            self._log.append((flight.step.seq, "decode", bucket, n,
+                              flight.ahead, flight.launched_ts, now, dt))
+            ewma = self._step_ewma
+            self._step_ewma = dt if ewma is None else 0.2 * dt + 0.8 * ewma
+            if timed:
+                # slow against the average as it stood before the step
+                stats["steps_slow"] += ewma is not None and dt > 1.5 * ewma
+                stats["step_period_s"] += dt
+                stats["steps_timed"] += 1
             stats["occupancy_sum"] += n / bucket
             stats["occupancy_steps"] += 1
             stats["decode_steps_ahead"] += flight.ahead
-            self._book("pt_serve_batch_occupancy", kind="gauge",
-                       value=n / bucket)
-            self._book("pt_serve_decode_steps_total", kind="counter",
-                       launch="ahead" if flight.ahead else "sync")
             booked, left = 0, False
             for a, t in zip(flight.rows, nxt):
                 if a.stream.done():
                     continue
                 try:
                     a.last_token = int(t)
-                    a.stream.tokens.append(int(t))
-                    a.stream.last_token_ts = now
+                    st = a.stream
+                    st.tokens.append(int(t))
+                    if now - st.last_token_ts > st.token_gap_max:
+                        st.token_gap_max = now - st.last_token_ts
+                    st.last_token_ts = now
                     booked += 1
                     if self._is_finished(a):
                         self._retire_locked(a)
@@ -890,11 +1009,7 @@ class ContinuousScheduler:
             stats["tokens_generated"] += booked
             stats["decode_tokens"] += n
             stats["shared_kv_reads"] += self._shared_readers
-            self._book("pt_serve_decode_tokens_total", kind="counter",
-                       value=n)
             self._book_aux_locked(flight.step.take_aux(), decode=True)
-            self._book("pt_serve_tokens_total", kind="counter",
-                       value=booked)
         stats["book_s"] += sp.seconds
 
     def _fail_batch_locked(self, exc: BaseException,
@@ -939,27 +1054,35 @@ class ContinuousScheduler:
         if tpot is not None:
             self.stats["tpot_s"] += tpot
             self.stats["tpot_requests"] += 1
+            self.stats["token_gap_max_s"] += st.token_gap_max
             self._book("pt_serve_tpot_seconds", kind="histogram",
                        value=tpot)
-        self._book("pt_serve_completed_total", kind="counter")
-        self._book_walk_locked()
+            self._book("pt_serve_token_gap_max_seconds", kind="histogram",
+                       value=st.token_gap_max)
 
-    def _book_walk_locked(self) -> None:
-        """``pt_serve_paged_chunks_total`` and ``pt_serve_sparse_tokens_
-        total`` up to ``stats``: called when a request retires and by
-        :meth:`snapshot`, not every decode step."""
-        chunks, tokens = ("pt_serve_paged_chunks_total",
-                          "pt_serve_sparse_tokens_total")
-        for metric, labels, key in (
-                (chunks, {"state": "walked"}, "paged_chunks_walked"),
-                (chunks, {"state": "grid"}, "paged_grid_steps"),
-                (tokens, {"kind": "scored"}, "sparse_tokens_scored"),
-                (tokens, {"kind": "selected"}, "sparse_tokens_selected")):
-            more = self.stats[key] - self._walk_booked.get(key, 0)
+    def _sync_registry_locked(self, now: float) -> None:
+        """Every counter of :data:`_SYNCED` and the gauges up to
+        ``stats`` (module docstring: when, and why not a step)."""
+        self._sync_at = now + _SYNC_EVERY_S
+        stats = self.stats
+        slots = self.engine.pool.state_slots
+        if slots is not None:
+            snap = slots.snapshot()
+            stats["state_slots_held"] = snap["held"]
+            stats["state_slots_held_max"] = snap["high_watermark"]
+        if self._registry() is None:
+            return
+        synced = self._synced
+        for name, labels, key, less in _SYNCED:
+            total = stats[key] - (stats[less] if less else 0)
+            more = total - synced.get(key, 0)
             if more:
-                self._walk_booked[key] = self.stats[key]
-                self._book(metric, kind="counter", value=more,
-                           labels=labels)
+                synced[key] = total
+                self._book(name, kind="counter", value=more, labels=labels)
+        self._book("pt_serve_queue_depth", kind="gauge",
+                   value=len(self._queue))
+        self._book("pt_serve_active_sequences", kind="gauge",
+                   value=len(self._seated_locked()))
 
     # -- loop management -----------------------------------------------------
 
@@ -994,6 +1117,7 @@ class ContinuousScheduler:
             try:
                 if self._flight is not None:
                     self._read_locked()
+                self._sync_registry_locked(time.monotonic())
             finally:
                 self._lock.release()
 
@@ -1005,6 +1129,9 @@ class ContinuousScheduler:
                     while (self._idle_locked()
                            and not self._stop.is_set()):
                         self._cv.wait(0.05)
+                        now = time.monotonic()
+                        if now >= self._sync_at:
+                            self._sync_registry_locked(now)
             self.stats["wait_s"] += sp.seconds  # this thread's key alone
             if self._stop.is_set():
                 return
@@ -1082,7 +1209,7 @@ class ContinuousScheduler:
             for a in self._seated_locked():
                 clean = False
                 self._drop_locked(a, "drain")
-            self._gauges_locked()
+            self._sync_registry_locked(time.monotonic())
         dur = time.monotonic() - t0
         self.stats["drain_seconds"] = dur
         self._book("pt_serve_drain_seconds", kind="gauge", value=dur)
@@ -1121,7 +1248,7 @@ class ContinuousScheduler:
             started = self._step_started
             if started is None:
                 continue
-            times = list(self._step_times)
+            times = self._log.periods(_WATCHDOG_STEPS)
             p99 = float(np.percentile(times, 99)) if times else None
             threshold = max(floor, factor * p99) if p99 else floor
             stuck = time.monotonic() - started
@@ -1150,9 +1277,13 @@ class ContinuousScheduler:
             stuck, threshold, rids)
         self._book("pt_serve_hang_watchdog_trips_total", kind="counter")
         try:
+            # what ran before the hang, whether or not the tracer's ring
+            # holds spans: the last program calls (time.monotonic stamps)
             get_tracer().flight_dump(
                 reason="serve-hang rid=%s stuck=%.3fs" %
-                (",".join(map(str, rids)) or "-", stuck))
+                (",".join(map(str, rids)) or "-", stuck),
+                extra={"serve_steps": self._log.as_dicts(64),
+                       "serve_step_unread_since": self._step_started})
         except Exception:
             pass
         if mode == "exit":
@@ -1169,9 +1300,12 @@ class ContinuousScheduler:
                 # then hold the last step too (a running loop reads it
                 # within a step)
                 self._read_locked()
-            self._book_walk_locked()
+            self._sync_registry_locked(time.monotonic())
             occ = (self.stats["occupancy_sum"] /
                    max(1, self.stats["occupancy_steps"]))
+            periods = self._log.periods()
+            p50, p99 = (map(float, np.percentile(periods, (50, 99)))
+                        if periods else (None, None))
             return {
                 "queue_depth": len(self._queue),
                 "active_sequences": len(self._seated_locked()),
@@ -1179,6 +1313,9 @@ class ContinuousScheduler:
                 "draining": self._draining,
                 "hang_detected": self.hang_detected,
                 "decode_step_ewma_s": self._step_ewma,
+                # over the step log's decode records (the last 4,096
+                # program calls): None before the first booked step
+                "step_period_p50_s": p50, "step_period_p99_s": p99,
                 # running means over the process's life, seconds
                 "lock_wait_mean_s": _mean(self.stats, "lock_wait_s",
                                           "submitted"),
@@ -1187,34 +1324,46 @@ class ContinuousScheduler:
                 "ttft_mean_s": _mean(self.stats, "ttft_s", "admitted"),
                 "tpot_mean_s": _mean(self.stats, "tpot_s",
                                      "tpot_requests"),
+                "token_gap_max_mean_s": _mean(self.stats, "token_gap_max_s",
+                                              "tpot_requests"),
+                # row-seconds that seated rows waited, a prefill
+                "prefill_row_stall_mean_s": _mean(
+                    self.stats, "prefill_row_stall_s", "prefill_runs"),
                 **{k: v for k, v in self.stats.items()
                    if k not in ("occupancy_sum",)},
             }
 
-    def _gauges_locked(self) -> None:
-        slots = self.engine.pool.state_slots
-        if slots is not None:
-            snap = slots.snapshot()
-            self.stats["state_slots_held"] = snap["held"]
-            self.stats["state_slots_held_max"] = snap["high_watermark"]
-        self._book("pt_serve_queue_depth", kind="gauge",
-                   value=len(self._queue))
-        self._book("pt_serve_active_sequences", kind="gauge",
-                   value=len(self._seated_locked()))
+    def step_log(self, last_n: Optional[int] = None) -> List[tuple]:
+        """Copies of the newest ``last_n`` records of the step log (all
+        4,096 at most for None), oldest first: module docstring."""
+        return self._log.last(last_n)
+
+    def _registry(self):
+        """The registry to book into, or None while telemetry is off
+        (it must stay empty then).  Instruments are looked up once each
+        and kept, until the registry itself is replaced: a new one is
+        brought up to ``stats`` whole by the next sync."""
+        try:
+            if not get_telemetry().enabled:
+                return None
+            reg = get_registry()
+        except Exception:
+            return None
+        if reg is not self._meter_registry:
+            self._meter_registry, self._meters = reg, {}
+            self._synced = {}
+        return reg
 
     def _book(self, name: str, *, kind: str, value: float = 1.0,
               labels: Optional[Dict[str, str]] = None, **more) -> None:
-        """Metric booking; inert while telemetry is off (the registry
-        must stay empty then).  The registry's instruments are looked
-        up once each and kept, until the registry itself is replaced.
-        Labels are keywords, or ``labels`` where one is named ``kind``."""
+        """Metric booking; inert while telemetry is off
+        (:meth:`_registry`).  Labels are keywords, or ``labels`` where
+        one is named ``kind``."""
         labels = {**(labels or {}), **more}
         try:
-            if not get_telemetry().enabled:
+            reg = self._registry()
+            if reg is None:
                 return
-            reg = get_registry()
-            if reg is not self._meter_registry:
-                self._meter_registry, self._meters = reg, {}
             m = self._meters.get(name)
             if m is None:
                 m = self._meters[name] = getattr(reg, kind)(
@@ -1234,6 +1383,39 @@ def _mean(stats, total, count):
     return stats[total] / stats[count] if stats[count] else None
 
 
+# counters the registry holds of `stats`, brought up to it by
+# _sync_registry_locked: (instrument, labels, the key, a key to take off)
+_SYNCED = (
+    ("pt_serve_requests_total", None, "submitted", None),
+    ("pt_serve_completed_total", None, "completed", None),
+    ("pt_serve_admission_refusals_total", {"reason": "inflight_cap"},
+     "refused_inflight", None),
+    ("pt_serve_admission_refusals_total", {"reason": "kv_headroom"},
+     "refused_kv", None),
+    ("pt_serve_admission_refusals_total", {"reason": "state_slots"},
+     "refused_state", None),
+    ("pt_serve_tokens_total", None, "tokens_generated", None),
+    ("pt_serve_prefill_tokens_total", None, "prefill_tokens", None),
+    ("pt_serve_decode_tokens_total", None, "decode_tokens", None),
+    ("pt_serve_decode_steps_total", {"launch": "ahead"},
+     "decode_steps_ahead", None),
+    ("pt_serve_decode_steps_total", {"launch": "sync"},
+     "occupancy_steps", "decode_steps_ahead"),
+    ("pt_serve_ssm_tokens_scanned_total", None, "ssm_tokens_scanned", None),
+    ("pt_serve_moe_tokens_routed_total", None, "moe_tokens_routed", None),
+    ("pt_serve_moe_expert_max_tokens_total", None, "moe_expert_max_tokens",
+     None),
+    ("pt_serve_paged_chunks_total", {"state": "walked"},
+     "paged_chunks_walked", None),
+    ("pt_serve_paged_chunks_total", {"state": "grid"},
+     "paged_grid_steps", None),
+    ("pt_serve_sparse_tokens_total", {"kind": "scored"},
+     "sparse_tokens_scored", None),
+    ("pt_serve_sparse_tokens_total", {"kind": "selected"},
+     "sparse_tokens_selected", None),
+)
+
+
 _METRIC_HELP = {
     "pt_serve_requests_total": "Requests accepted by the serve scheduler",
     "pt_serve_completed_total": "Requests completed",
@@ -1246,8 +1428,6 @@ _METRIC_HELP = {
     "pt_serve_cancelled_total":
         "Requests evicted before completing, by cause "
         "(client|timeout|deadline|disconnect|drain)",
-    "pt_serve_deadline_exceeded_total":
-        "Requests that missed their deadline (shed or evicted)",
     "pt_serve_drain_seconds":
         "Wall time of the last graceful drain",
     "pt_serve_request_failures_total":
@@ -1274,8 +1454,6 @@ _METRIC_HELP = {
         "layers attended over (selected)",
     "pt_serve_queue_depth": "Requests waiting for admission",
     "pt_serve_active_sequences": "Sequences resident in the decode batch",
-    "pt_serve_batch_occupancy":
-        "Active rows / decode bucket size of the last step",
     "pt_serve_decode_steps_total":
         "Decode steps booked, by launch: before the step before was read "
         "(ahead) or after (sync)",
@@ -1287,4 +1465,6 @@ _METRIC_HELP = {
         "Time to first token (entry of submit to the prefill's token)",
     "pt_serve_tpot_seconds":
         "Mean time per output token after the first, per request",
+    "pt_serve_token_gap_max_seconds":
+        "Largest gap between two consecutive tokens, per request",
 }

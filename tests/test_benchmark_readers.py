@@ -557,10 +557,10 @@ def test_hybrid_metrics_have_entries_for_their_cell_and_read_nothing_elsewhere(
     assert spec["workloads"][-2]["chips"] == 1
     assert spec["configs"][-2]["name"] == spec["workloads"][-2]["config"]
     assert spec["configs"][-2]["reduced"] == []
-    # then PR 32's one, PR 34's 6, PR 35's 2
-    hybrid = spec["per_layer"][-18:-9]
+    # then PR 32's one, PR 34's 6, PR 35's 2, PR 36's 10
+    hybrid = spec["per_layer"][-28:-19]
     assert [m["name"] for m in hybrid] == HYBRID_METRICS
-    assert spec["per_layer"][-9]["name"] == "paged_walk_live_pct.grouped"
+    assert spec["per_layer"][-19]["name"] == "paged_walk_live_pct.grouped"
     for m in hybrid:
         assert m["workloads"] == [cell]
         assert m["moves"] == "serve_tokens_per_s"
@@ -692,3 +692,345 @@ def test_decode_ahead_pct_without_the_counter(bench, name):
     assert read(bench, name, {"trace": None, "counters": {
         "decode_steps_ahead": 0, "occupancy_steps": 0}}) is None
     assert read(bench, name, {"trace": None}) is None
+
+
+# -- the serving programs' runs and the step log's counters (PR 36) -----------
+
+STEP_COUNTERS = {
+    # a window of 1,000 booked steps, 900 of them timed (100 were read
+    # behind a prefill), 18 of those slow; 40 prefills; 50 retirements
+    "occupancy_steps": 1000, "steps_timed": 900, "step_period_s": 1.98,
+    "steps_slow": 18, "prefill_runs": 40, "prefill_row_stall_s": 3.5,
+    "decode_tokens": 7000, "token_gap_max_s": 0.6, "tpot_requests": 50}
+STEP_METRICS = {
+    # name: (unit, source, layer, moves, cells, value on STEP_COUNTERS
+    #        and serve_trace())
+    "decode_period_ms_mean": ("ms", "program_counter", "model", 2.2),
+    "slow_steps_pct": ("%", "program_counter", "sched", 2.0),
+    "decode_device_ms_p50": ("ms", "device_trace", "model", 0.004),
+    "token_gap_max_ms_mean": ("ms", "program_counter", "sched", 12.0),
+}
+LAYERS = {"model": "model step (serving/engine.py, serving/model.py)",
+          "sched": "scheduler (serving/scheduler.py)"}
+STEADY = ["gpt345m-serve-complete-steady"]
+BACKLOG = ["gpt345m-serve-longprompt-backlog",
+           "mellum2-serve-mixedctx-backlog",
+           "phi4flash-serve-reasoning-backlog",
+           "keyevl2-serve-longctx-reasoning-backlog"]
+SPLIT = [(f"{name}.{cell}", name, cell) for name in sorted(STEP_METRICS)
+         for cell in ("steady", "backlog")]
+
+
+def windowed(trace, t0, t1):
+    """`trace` with the benchmark's window span moved to [t0, t1)."""
+    for line in trace["planes"][1]["lines"]:
+        line["events"] = [e for e in line["events"]
+                          if e[0] != "bench:window"]
+        line["events"].append(["bench:window", t0, t1 - t0])
+    return trace
+
+
+def only(trace, kind):
+    """`trace` without the runs of the other kind of program."""
+    line = trace["planes"][0]["lines"][1]
+    line["events"] = [e for e in line["events"] if kind in e[0]]
+    return trace
+
+
+@pytest.mark.parametrize("full, name, cell", SPLIT)
+def test_step_metric_on_a_hand_made_run(bench, full, name, cell):
+    unit, source, layer, want = STEP_METRICS[name]
+    run = as_run(bench, serve_trace(), dict(STEP_COUNTERS))
+    assert read(bench, full, run) == pytest.approx(want)
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    assert next(m for m in spec["per_layer"] if m["name"] == full) == {
+        "name": full, "unit": unit, "better": "lower", "source": source,
+        "layer": LAYERS[layer],
+        "moves": "tpot_p50_ms" if cell == "steady" else "serve_tokens_per_s",
+        # a worst gap is summed at retirement: not in the cell whose
+        # window may retire nobody, and whose answers outlast the ramp
+        "workloads": STEADY if cell == "steady" else
+        BACKLOG[:3] if name == "token_gap_max_ms_mean" else BACKLOG}
+
+
+def test_prefill_share_and_stall_on_a_hand_made_run(bench):
+    run = as_run(bench, serve_trace(), dict(STEP_COUNTERS))
+    # one prefill run of 4000 ns beside two decode runs of 4000
+    assert read(bench, "prefill_device_share_pct", run) == \
+        pytest.approx(100 / 3)
+    assert read(bench, "prefill_stall_ms_per_token", run) == \
+        pytest.approx(0.5)
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    by = {m["name"]: m for m in spec["per_layer"]}
+    assert by["prefill_device_share_pct"] == {
+        "name": "prefill_device_share_pct", "unit": "%", "better": "lower",
+        "source": "device_trace", "layer": LAYERS["model"],
+        "moves": "serve_tokens_per_s", "workloads": BACKLOG[:3]}
+    assert by["prefill_stall_ms_per_token"] == {
+        "name": "prefill_stall_ms_per_token", "unit": "ms",
+        "better": "lower", "source": "program_counter",
+        "layer": LAYERS["sched"], "moves": "serve_tokens_per_s",
+        "workloads": BACKLOG}
+    # every new metric has its reader, and the file only gained them
+    assert [m["name"] for m in spec["per_layer"][-10:]] == [
+        "decode_period_ms_mean.steady", "decode_period_ms_mean.backlog",
+        "slow_steps_pct.steady", "slow_steps_pct.backlog",
+        "decode_device_ms_p50.steady", "decode_device_ms_p50.backlog",
+        "prefill_device_share_pct", "prefill_stall_ms_per_token",
+        "token_gap_max_ms_mean.steady", "token_gap_max_ms_mean.backlog"]
+
+
+@pytest.mark.parametrize("case, t0, t1, kind, share, p50", [
+    # the whole 20 us
+    ("whole", 0, 20000, None, 100 / 3, 0.004),
+    # prompts only, tokens only
+    ("prefill_only", 0, 20000, "prefill", 100.0, None),
+    ("decode_only", 0, 20000, "decode", 0.0, 0.004),
+    # [2000, 12000) cuts the prefill run to 3000 ns and the second decode
+    # run to 1000; the median is over the one decode run that is whole
+    ("cut_edges", 2000, 12000, None, 37.5, 0.004),
+    # [7000, 9000) lies inside the first decode run: 2000 ns of it
+    ("inside_a_run", 7000, 9000, None, 0.0, 0.002),
+])
+def test_serve_runs_are_cut_to_the_traced_window(bench, case, t0, t1, kind,
+                                                 share, p50):
+    trace = windowed(serve_trace(), t0, t1)
+    if kind:
+        trace = only(trace, kind)
+    run = as_run(bench, trace, dict(STEP_COUNTERS))
+    assert read(bench, "prefill_device_share_pct", run) == \
+        pytest.approx(share)
+    for cell in ("steady", "backlog"):
+        got = read(bench, f"decode_device_ms_p50.{cell}", run)
+        assert got == (None if p50 is None else pytest.approx(p50)), case
+    import step_trace
+    runs = step_trace.serve_runs(run["trace"])
+    assert all(t0 <= s < e <= t1 for _, s, e, _ in runs)
+    assert [whole for *_, whole in runs] == {
+        "cut_edges": [False, True, False], "inside_a_run": [False]}.get(
+            case, [True] * len(runs))
+
+
+NEW_READERS = [full for full, _, _ in SPLIT] + [
+    "prefill_device_share_pct", "prefill_stall_ms_per_token"]
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_step_metric_has_nothing_to_read_without_the_marks(bench, name):
+    """The parent's counters (no step log), a train trace, a run that
+    was not traced, a window in which nothing was counted: None, and
+    nothing raised."""
+    parent = {k: v for k, v in STEP_COUNTERS.items()
+              if k in ("occupancy_steps", "decode_tokens", "tpot_requests")}
+    assert read(bench, name, as_run(bench, serve_trace(), parent)) is None
+    assert read(bench, name, as_run(bench, serve_trace())) is None
+    assert read(bench, name, {"trace": None}) is None
+    # a window in which no step was booked: the counters give nothing,
+    # the trace what it holds
+    empty = dict.fromkeys(STEP_COUNTERS, 0)
+    assert read(bench, name, as_run(bench, serve_trace(), empty)) == (
+        pytest.approx(100 / 3) if name == "prefill_device_share_pct"
+        else pytest.approx(0.004) if "device" in name else None)
+    trace = only(serve_trace(), "no such program")
+    trace["planes"][0]["lines"][1]["events"] = [
+        ["jit_train_step(333)", 1000, 14000]]
+    train = as_run(bench, trace, dict(STEP_COUNTERS))
+    if "device" in name:
+        assert read(bench, name, train) is None
+        assert read(bench, name, {"trace": None,
+                                  "counters": dict(STEP_COUNTERS)}) is None
+
+
+def _feed_periods(periods, untimed=()):
+    """The scheduler's own booking of decode steps with the given
+    periods (seconds): `_read_locked` on stub steps under a stub clock.
+    Steps at the indices `untimed` are read as behind a prefill."""
+    import types
+    import numpy as np
+    from paddle_tpu.serving import ModelSpec
+    from paddle_tpu.serving import scheduler as sched_mod
+    engine = types.SimpleNamespace(
+        spec=ModelSpec(vocab_size=64, hidden=32, layers=2, heads=2,
+                       max_seq_len=64),
+        pool=types.SimpleNamespace(state_slots=None))
+    sched = sched_mod.ContinuousScheduler(engine)
+    clock = [100.0]
+    real = sched_mod.time
+    sched_mod.time = types.SimpleNamespace(monotonic=lambda: clock[0])
+    try:
+        for k, dt in enumerate(periods):
+            step = types.SimpleNamespace(
+                seq=k + 1, bucket=8, take_aux=dict,
+                read=lambda: np.zeros((0,), np.int32))
+            sched._flight = sched_mod._Flight(step, [], clock[0], False)
+            clock[0] += dt
+            with sched._lock:
+                sched._read_locked(timed=k not in untimed)
+    finally:
+        sched_mod.time = real
+    return sched
+
+
+def test_steps_slow_against_a_hand_fed_sequence_of_periods(bench):
+    # ms; the moving average of every period (0.2 new + 0.8 old) as it
+    # stood before each step, one compare:
+    #   10   -> none yet                      not slow
+    #   10   -> 10                            not
+    #   16   -> 10       16 > 15              slow
+    #   14   -> 11.2     14 < 16.8            not
+    #   40   -> 11.76    (behind a prefill: averaged, not timed or judged)
+    #   17.7 -> 17.408   17.7 < 26.1          not: the prefill hides it
+    #   12   -> 17.4664                       not
+    #   30   -> 16.37312 30 > 24.56           slow
+    ms = [10, 10, 16, 14, 40, 17.7, 12, 30]
+    sched = _feed_periods([m / 1e3 for m in ms], untimed={4})
+    s = sched.stats
+    assert (s["occupancy_steps"], s["steps_timed"], s["steps_slow"]) == \
+        (8, 7, 2)
+    assert s["step_period_s"] == pytest.approx((sum(ms) - 40) / 1e3)
+    assert not hasattr(sched, "_period_ewma")   # one average, the ETA's
+    # the shed ETA's average and the log take all eight as they are
+    ewma = None
+    for m in ms:
+        ewma = m / 1e3 if ewma is None else 0.2 * m / 1e3 + 0.8 * ewma
+    assert sched._step_ewma == pytest.approx(ewma)
+    assert [r[7] for r in sched.step_log()] == pytest.approx(
+        [m / 1e3 for m in ms])
+    run = {"trace": None, "counters": dict(s)}
+    for cell in ("steady", "backlog"):
+        assert read(bench, f"slow_steps_pct.{cell}", run) == \
+            pytest.approx(100 * 2 / 7)
+        assert read(bench, f"decode_period_ms_mean.{cell}", run) == \
+            pytest.approx((sum(ms) - 40) / 7)
+
+
+def step_spans():
+    """`serve_trace()`'s three runs with launches as a scheduler that
+    launches ahead would leave them: the second decode step launched
+    while the first runs, and fetched after it."""
+    trace = serve_trace(spans=False)
+    trace["planes"][1]["lines"][0]["events"] += [
+        ["pt:serve.prefill.launch", 700, 500],
+        ["pt:serve.prefill.fetch", 1200, 3900],
+        ["pt:serve.decode.launch", 5800, 400],
+        ["pt:serve.decode.launch", 6300, 400],      # ahead of the fetch
+        ["pt:serve.decode.fetch", 6800, 3300],
+        ["pt:serve.decode.fetch", 10200, 4900]]
+    return trace
+
+
+def test_join_steps_pairs_launches_and_runs_in_order(bench, tmp_path):
+    import step_trace
+    path = str(tmp_path / "cut.json")
+    json.dump(step_spans(), open(path, "w"))
+    spans, runs = step_trace.load(path)
+    assert [r[0] for r in runs] == ["prefill", "decode", "decode"]
+    rows = step_trace.join_steps(spans, runs)
+    assert [(r["kind"], r["launch_ns"], r["device_ns"], r["idle_before_ns"],
+             r["fetch_end_ns"]) for r in rows] == [
+        ("prefill", (700, 1200), (1000, 5000), None, 5100),
+        ("decode", (5800, 6200), (6000, 10000), 1000, 10100),
+        ("decode", (6300, 6700), (11000, 15000), 1000, 15100)]
+    text = step_trace.table(rows)
+    assert len(text) == 4 and "prefill" in text[1] and "decode" in text[3]
+    # with the arguments a profile keeps, a fetch is found by its number
+    numbered = [(k, w, s, e, {"launch": 7 + i // 2} if k == "decode"
+                 else {"launch": 3, "request_id": 42})
+                for i, (k, w, s, e, _) in enumerate(spans)]
+    assert [sp[4].get("launch") for sp in numbered] == [3, 3, 8, 8, 9, 9]
+    numbered[3], numbered[4] = (     # launch 8, launch 9, fetch 8, fetch 9
+        numbered[3][:4] + ({"launch": 9, "bucket": 8},),
+        numbered[4][:4] + ({"launch": 8},))
+    numbered[2] = numbered[2][:4] + ({"launch": 8, "bucket": 8},)
+    rows = step_trace.join_steps(numbered, runs)
+    assert [(r["launch"], r["bucket"], r["request_id"], r["fetch_end_ns"])
+            for r in rows] == [(3, None, 42, 5100), (8, 8, None, 10100),
+                               (9, 8, None, 15100)]
+
+
+def test_join_steps_refuses_what_does_not_fit(bench, monkeypatch):
+    import step_trace
+    # the hand-made trace is 20 us long: its clocks agree to 100 ns
+    monkeypatch.setattr(step_trace, "SKEW_NS", 100)
+    spans, runs = [], []
+    trace = step_spans()
+    for name, s, d in trace["planes"][1]["lines"][0]["events"]:
+        m = step_trace._SPAN.match(name)
+        if m:
+            spans.append((m.group(1), m.group(2), s, s + d, {}))
+    spans.sort(key=lambda sp: sp[2])
+    runs = [("prefill", 1000, 5000), ("decode", 6000, 10000),
+            ("decode", 11000, 15000)]
+    assert len(step_trace.join_steps(spans, runs)) == 3
+    # a run launched before the trace began is dropped, not joined
+    assert len(step_trace.join_steps(
+        spans, [("decode", 100, 600)] + runs)) == 3
+    # a launch whose run the trace no longer holds gets no row
+    assert len(step_trace.join_steps(spans, runs[:2])) == 2
+    with pytest.raises(ValueError, match="kinds|is a"):
+        step_trace.join_steps(spans, [runs[1], runs[0], runs[2]])
+    late = [sp if sp[:2] != ("decode", "launch") or sp[2] != 6300
+            else ("decode", "launch", 11500, 11900, {}) for sp in spans]
+    with pytest.raises(ValueError, match="before its launch"):
+        step_trace.join_steps(sorted(late, key=lambda sp: sp[2]), runs)
+    with pytest.raises(ValueError, match="no pt:serve"):
+        step_trace.join_steps([], runs)
+    # inside the clocks' skew a run may seem to start before its launch
+    early = [("prefill", 650, 5000)] + runs[1:]
+    assert step_trace.join_steps(spans, early)[0]["device_ns"] == (650, 5000)
+    # further before the first launch than that, it was launched before
+    # the trace began
+    rows = step_trace.join_steps(spans[1:], [("decode", 5600, 10000),
+                                              runs[2]])
+    assert [r["device_ns"] for r in rows] == [(11000, 15000)]
+    assert rows[0]["idle_before_ns"] == 1000
+
+
+@pytest.fixture(scope="module")
+def ahead_cut():
+    """The recorded cut of a traced run since the scheduler launches
+    ahead (GPT backlog cell, my chip run, PR 36), and what plain loops
+    over it gave."""
+    data = os.path.join(BENCH, "testdata")
+    return (json.load(open(os.path.join(data, "serve_ahead_trace.json"))),
+            json.load(open(os.path.join(data,
+                                        "serve_ahead_trace.expect.json"))))
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_step_metric_on_the_recorded_cut(bench, ahead_cut, name):
+    trace, want = ahead_cut
+    run = as_run(bench, trace, dict(want["counters"]))
+    assert (run["trace"].t0, run["trace"].t1) == (want["t0_ns"],
+                                                  want["t1_ns"])
+    assert read(bench, name, run) == pytest.approx(
+        want[name.split(".")[0]], rel=1e-9)
+    # the parent's counters beside the same trace: nothing to read
+    parent = {k: v for k, v in want["counters"].items()
+              if k in ("occupancy_steps", "decode_tokens", "tpot_requests",
+                       "decode_steps_ahead")}
+    assert read(bench, name, as_run(bench, trace, parent)) is None
+
+
+def test_the_recorded_cut_joins_launch_for_launch(bench, ahead_cut):
+    import step_trace
+    _, want = ahead_cut
+    spans, runs = step_trace.load(os.path.join(
+        BENCH, "testdata", "serve_ahead_trace.json"))
+    assert len(runs) == want["prefill_device_runs"] + want["decode_device_runs"]
+    rows = step_trace.join_steps(spans, runs)
+    assert len(rows) == want["launch_spans"] == len(runs)
+    assert sum(r["kind"] == "prefill" for r in rows) == want["prefill_device_runs"]
+    assert sum(r["device_ns"][1] - r["device_ns"][0] for r in rows
+               if r["kind"] == "decode") == want["decode_device_ns"]
+    # steps launched ahead (their launch span opens while the step
+    # before still runs) and steps launched after a read, both in it
+    ahead = [b["launch_ns"][0] < a["device_ns"][1]
+             for a, b in zip(rows, rows[1:])
+             if a["kind"] == b["kind"] == "decode"]
+    assert any(ahead) and not all(ahead)
+    # a read comes after its run; every row but the last few has one
+    assert all(r["fetch_end_ns"] > r["device_ns"][1] for r in rows
+               if r["fetch_end_ns"] is not None)
+    assert sum(r["fetch_end_ns"] is None for r in rows) <= 2
+    assert len(step_trace.table(rows)) == len(rows) + 1

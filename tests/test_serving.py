@@ -569,12 +569,20 @@ def test_http_end_to_end():
         assert status == 200
         assert len(out["tokens"]) == 4
         assert out["latency_ms"] >= 0
+        # the worst gap between two of its tokens lies inside its answer
+        assert 0 < out["token_gap_max_ms"] <= \
+            out["latency_ms"] - out["ttft_ms"]
         # parity with the in-process path
         assert out["tokens"] == eng.generate([[1, 2, 3]],
                                              max_new_tokens=4)[0]
 
         with urllib.request.urlopen(base + "/metrics", timeout=30) as r:
             assert r.status == 200
+        with urllib.request.urlopen(base + "/healthz", timeout=30) as r:
+            health = json.loads(r.read())
+        assert 0 < health["step_period_p50_s"] <= health["step_period_p99_s"]
+        assert health["token_gap_max_mean_s"] > 0
+        assert health["steps_timed"] == health["occupancy_steps"] > 0
 
         with pytest.raises(urllib.error.HTTPError) as ei:
             _post(base + "/v1/generate", {"tokens": "nope"})
@@ -836,6 +844,7 @@ def test_request_histograms_and_one_token_booking_a_step(engine):
                    for _ in range(3)]
         sched.drain()
         assert all(len(st.result(timeout=10.0)) == 5 for st in streams)
+        sched.snapshot()        # the counters follow `stats` from here
         snap = obs.get_registry().snapshot()
         for name in ("pt_serve_request_latency_seconds",
                      "pt_serve_queue_wait_seconds", "pt_serve_ttft_seconds",

@@ -416,9 +416,9 @@ def test_the_scheduler_counts_slots_scans_and_shared_reads(built):
     # the full layer and the two cross layers read its pool every step
     assert d["shared_kv_reads"] == 3 * d["steps"] > 0
     assert d["refused_state"] == 0
+    health = engine.healthz()   # `stats` mirrors the slots from a sync on
     assert sched.stats["state_slots_held"] == 0
     assert sched.stats["state_slots_held_max"] >= 3
-    health = engine.healthz()
     assert health["kv"]["state"]["held"] == 0 and health["kv_consistent"]
     assert health["state_slots_held_max"] >= 3
     engine.pool.check_consistency(expect_all_free=True)

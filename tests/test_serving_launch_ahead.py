@@ -368,7 +368,8 @@ def test_the_loop_reads_its_last_step_and_the_watchdog_sees_the_oldest(
         while sched._flight is not None and time.monotonic() < deadline:
             time.sleep(0.005)
         assert sched._flight is None and sched._step_started is None
-        assert sched._step_ewma is not None and len(sched._step_times) >= 5
+        decodes = [r for r in sched.step_log() if r[1] == "decode"]
+        assert sched._step_ewma is not None and len(decodes) >= 5
     finally:
         sched.stop(timeout=10.0)
     assert sched.stats["tokens_generated"] == 18
@@ -435,3 +436,330 @@ def test_streams_equal_the_synchronous_loop_for_every_kind_of_model(
         _baseline(engine)
     finally:
         engine.close()
+
+
+# -- the step log (PR 36) ------------------------------------------------------
+
+def _decodes(sched):
+    return [r for r in sched.step_log() if r[1] == "decode"]
+
+
+def test_the_step_log_keeps_one_record_a_launched_program(engine):
+    """Two prefills, a step launched after a read, two launched ahead,
+    then a prefill queued behind the unread step: what each record
+    holds, and which steps the period counters time."""
+    sched = ContinuousScheduler(engine)
+    n0 = engine.launches
+    a = sched.submit(PROMPTS[0], max_new_tokens=10)
+    b = sched.submit(PROMPTS[1], max_new_tokens=10)
+    for _ in range(3):
+        sched.step()
+    pa, pb, d1, d2 = sched.step_log()
+    assert pa[:5] == (n0 + 1, "prefill", 16, len(PROMPTS[0]), a.request_id)
+    assert pb[:5] == (n0 + 2, "prefill", 16, len(PROMPTS[1]), b.request_id)
+    assert (pa[5], pa[6]) == (a.admitted_ts, a.first_token_ts)
+    assert (pa[7], pb[7]) == (0, 1)     # b's prompt ran with a seated
+    # (seq, kind, bucket, rows, ahead): the first step follows a read
+    assert d1[:5] == (n0 + 3, "decode", 2, 2, False)
+    assert d2[:5] == (n0 + 4, "decode", 2, 2, True)
+    assert d1[7] == pytest.approx(d1[6] - d1[5])    # launch to read
+    assert d2[7] == pytest.approx(d2[6] - d1[6])    # read to read
+    assert d2[5] < d1[6] < d2[6]        # launched before d1 was read
+    assert sched._flight.step.seq == n0 + 5 == engine.launches
+    c = sched.submit(PROMPTS[2], max_new_tokens=6)
+    sched.step()            # c's prefill behind the unread step, then its read
+    pc, d3 = sched.step_log()[4:6]
+    assert pc[:5] == (n0 + 6, "prefill", 16, len(PROMPTS[2]), c.request_id)
+    assert pc[7] == 2                   # a and b waited behind c's prompt
+    assert d3[:5] == (n0 + 5, "decode", 2, 2, True)
+    assert d3[6] >= pc[6] and d3[7] >= pc[6] - pc[5]    # it holds the prefill
+    s = sched.stats
+    assert s["occupancy_steps"] == 3    # d4 is launched (c seated), unread
+    assert s["steps_timed"] == 2 and s["steps_slow"] == 0
+    assert s["step_period_s"] == pytest.approx(d1[7] + d2[7])
+    assert s["prefill_runs"] == 3
+    assert s["prefill_row_stall_s"] == pytest.approx(
+        (pb[6] - pb[5]) + 2 * (pc[6] - pc[5]))
+    sched.drain()
+    for st, p, n in ((a, PROMPTS[0], 10), (b, PROMPTS[1], 10),
+                     (c, PROMPTS[2], 6)):
+        assert st.result(timeout=5.0) == _solo(engine, p, n)
+    log = sched.step_log()
+    assert sorted(r[0] for r in log if r[1] == "decode") == \
+        [r[0] for r in log if r[1] == "decode"]
+    assert len({r[0] for r in log}) == len(log) == 3 + s["occupancy_steps"]
+    assert s["steps_timed"] == s["occupancy_steps"] - 1
+    # copies: the caller's list is its own
+    last = sched.step_log(2)
+    assert last == log[-2:] and sched.step_log(0) == []
+    last.clear()
+    assert sched.step_log(2) == log[-2:]
+    h = sched.snapshot()
+    periods = [r[7] for r in _decodes(sched)]
+    assert h["step_period_p50_s"] == pytest.approx(np.percentile(periods, 50))
+    assert h["step_period_p99_s"] == pytest.approx(np.percentile(periods, 99))
+    # what one admission cost the rows already seated, row-seconds
+    assert h["prefill_runs"] == 3 and h["prefill_row_stall_mean_s"] == \
+        pytest.approx(h["prefill_row_stall_s"] / 3)
+    assert ContinuousScheduler(engine).snapshot()[
+        "prefill_row_stall_mean_s"] is None
+    _baseline(engine)
+
+
+def test_a_failed_launch_read_or_prefill_leaves_no_record(engine,
+                                                          monkeypatch):
+    sched = ContinuousScheduler(engine)
+    st = sched.submit(PROMPTS[0], max_new_tokens=8)
+    sched.step()                            # prefill, first step launched
+    assert [r[1] for r in sched.step_log()] == ["prefill"]
+    orig = engine.decode
+
+    def poisoned(*args):
+        step = orig(*args)
+        return _Poisoned(step._tokens, step._aux, step.slots, step.bucket,
+                         step.seq)
+
+    monkeypatch.setattr(engine, "decode", poisoned)
+    sched.step()            # launches the poisoned step, reads the good one
+    assert [r[1] for r in sched.step_log()] == ["prefill", "decode"]
+    sched.step()            # launches behind it, then reads it: boom
+    with pytest.raises(RuntimeError, match="device poison"):
+        st.result(timeout=1.0)
+    assert [r[1] for r in sched.step_log()] == ["prefill", "decode"]
+    assert sched.stats["occupancy_steps"] == sched.stats["steps_timed"] == 1
+
+    def refuses(*args):
+        raise RuntimeError("no launch")
+
+    monkeypatch.setattr(engine, "decode", refuses)
+    st = sched.submit(PROMPTS[1], max_new_tokens=4)
+    sched.step()                            # prefill, then the launch fails
+    with pytest.raises(RuntimeError, match="no launch"):
+        st.result(timeout=1.0)
+    assert [r[1] for r in sched.step_log()] == ["prefill", "decode",
+                                                 "prefill"]
+    monkeypatch.undo()
+    monkeypatch.setattr(engine, "prefill", refuses)
+    st = sched.submit(PROMPTS[2], max_new_tokens=4)
+    sched.step()
+    with pytest.raises(RuntimeError, match="no launch"):
+        st.result(timeout=1.0)
+    assert len(sched.step_log()) == 3 and sched.stats["prefill_runs"] == 2
+    assert sched.stats["failed"] == 3
+    _baseline(engine)
+
+
+def test_the_step_log_is_bounded_at_its_capacity(engine):
+    from paddle_tpu.serving.scheduler import StepLog
+    assert ContinuousScheduler(engine)._log._ring.maxlen == 4096
+    log = StepLog(capacity=8)
+    for k in range(20):
+        kind = "prefill" if k % 5 == 0 else "decode"
+        log.append((k, kind, 2, 1, False, 0.0, 1.0, float(k)))
+    assert len(log) == 8 and [r[0] for r in log.last()] == list(range(12, 20))
+    assert log.periods() == [12.0, 13.0, 14.0, 16.0, 17.0, 18.0, 19.0]
+    assert log.periods(3) == [17.0, 18.0, 19.0]
+    assert [r[0] for r in log.last(3)] == [17, 18, 19]
+    named = log.as_dicts(6)
+    assert named[-1] == dict(zip(StepLog.DECODE, log.last(1)[0]))
+    assert named[1]["kind"] == "prefill" and "request_id" in named[1]
+    json.dumps(named)                   # a dump can hold it as it is
+
+
+def test_ewma_watchdog_and_shed_eta_read_the_log(engine):
+    """The moving average, the watchdog's sample and the shed ETA are
+    what they were when ``_step_times`` fed them: every booked step's
+    period, a step read behind a prefill as it is."""
+    from paddle_tpu.serving import scheduler as sched_mod
+    sched = ContinuousScheduler(engine)
+    streams = [sched.submit(p, max_new_tokens=12) for p in PROMPTS[:3]]
+    for _ in range(5):
+        sched.step()
+    streams.append(sched.submit(PROMPTS[3], max_new_tokens=12))
+    sched.drain()
+    assert all(len(st.result(timeout=5.0)) == 12 for st in streams)
+    periods = [r[7] for r in _decodes(sched)]
+    assert len(periods) == sched.stats["occupancy_steps"] > \
+        sched.stats["steps_timed"]
+    ewma = None
+    for dt in periods:
+        ewma = dt if ewma is None else 0.2 * dt + 0.8 * ewma
+    assert sched._step_ewma == pytest.approx(ewma)
+    assert sched._log.periods(sched_mod._WATCHDOG_STEPS) == periods
+    queued = sched.submit(PROMPTS[0], max_new_tokens=7)
+    with sched._lock:
+        assert sched._backlog_eta_locked() == pytest.approx(
+            ewma * 7 / CFG.decode_buckets[-1])
+        assert sched._completion_eta_locked(5) == pytest.approx(
+            ewma * 7 / CFG.decode_buckets[-1] + ewma * 6)
+    assert queued.cancel() is True
+    # the watchdog's sample: the newest 256 periods, prefills skipped
+    log = sched_mod.StepLog()
+    for k in range(300):
+        log.append((2 * k, "decode", 2, 2, True, 0.0, 0.0, float(k)))
+        log.append((2 * k + 1, "prefill", 16, 3, k, 0.0, 0.0, 1))
+    assert log.periods(sched_mod._WATCHDOG_STEPS) == [
+        float(k) for k in range(44, 300)]
+    _baseline(engine)
+
+
+def test_the_watchdogs_dump_holds_the_last_records_with_the_tracer_off(
+        engine, tmp_path):
+    from paddle_tpu.observability.trace import get_tracer, reset_tracer
+    reset_tracer()
+    tr = get_tracer().enable(flight_dir=str(tmp_path))
+    tr.enabled = False                  # armed, and no span is kept
+    try:
+        sched = ContinuousScheduler(engine)
+        for _ in range(2):
+            streams = [sched.submit(p, max_new_tokens=40)
+                       for p in PROMPTS[:4]]
+            sched.drain()
+            assert all(len(st.result(timeout=5.0)) == 40 for st in streams)
+        assert len(sched.step_log()) == 2 * (4 + 39) and tr.spans() == []
+        sched._trip_watchdog("on", 1.5, 1.0)
+        doc = json.load(open(tr.flight_path))
+        assert doc["reason"].startswith("serve-hang") and doc["spans"] == []
+        steps = doc["extra"]["serve_steps"]
+        assert len(steps) == 64
+        assert steps == json.loads(json.dumps(sched._log.as_dicts(64)))
+        assert steps[-1]["kind"] == "decode"
+        assert steps[-1]["seq"] == sched.step_log(1)[0][0]
+        assert {"launched_ts", "read_ts", "period_s", "ahead", "rows",
+                "bucket"} <= set(steps[-1])
+        assert any(r["kind"] == "prefill" and r["seated_rows"] == 3
+                   for r in steps)
+        assert sched.hang_detected and sched.stats["watchdog_trips"] == 1
+    finally:
+        reset_tracer()
+    _baseline(engine)
+
+
+def test_token_gap_max_is_the_gap_the_stamps_show(engine, monkeypatch):
+    """A request whose neighbour is prefilled in the middle of its
+    answer: its largest gap is the one around that prefill, as the
+    log's read stamps have it."""
+    sched = ContinuousScheduler(engine)
+    a = sched.submit(PROMPTS[0], max_new_tokens=10)
+    for _ in range(4):
+        sched.step()
+    assert a.token_gap_max > 0.0 and len(a.tokens) == 4
+    orig = engine.prefill
+
+    def slow_prefill(tokens, table):
+        time.sleep(0.05)
+        return orig(tokens, table)
+
+    monkeypatch.setattr(engine, "prefill", slow_prefill)
+    c = sched.submit(PROMPTS[2], max_new_tokens=2)
+    sched.drain()
+    monkeypatch.undo()
+    assert a.result(timeout=5.0) == _solo(engine, PROMPTS[0], 10)
+    stamps = [a.first_token_ts] + [r[6] for r in _decodes(sched)][:9]
+    assert stamps[-1] == a.last_token_ts
+    gaps = np.diff(stamps)
+    assert a.token_gap_max == pytest.approx(gaps.max()) and \
+        a.token_gap_max >= 0.05
+    assert int(gaps.argmax()) == 3          # the token after c's prefill
+    assert c.token_gap_max == pytest.approx(c.last_token_ts
+                                            - c.first_token_ts)
+    s = sched.snapshot()
+    assert s["tpot_requests"] == 2
+    assert s["token_gap_max_s"] == pytest.approx(a.token_gap_max
+                                                 + c.token_gap_max)
+    assert s["token_gap_max_mean_s"] == pytest.approx(
+        s["token_gap_max_s"] / 2)
+    one = sched.submit(PROMPTS[1], max_new_tokens=1)
+    sched.drain()
+    assert one.token_gap_max == 0.0 and sched.stats["tpot_requests"] == 2
+    _baseline(engine)
+
+
+def _registry_values():
+    from paddle_tpu import observability as obs
+    snap = obs.get_registry().snapshot()
+    return {(name, series): value for name, entry in snap.items()
+            if name.startswith("pt_serve_")
+            for series, value in entry["series"].items()}
+
+
+def _synced_values(sched):
+    from paddle_tpu.serving.scheduler import _SYNCED
+    out = {}
+    for name, labels, key, less in _SYNCED:
+        total = sched.stats[key] - (sched.stats[less] if less else 0)
+        if total:
+            series = ",".join(f"{k}={v}" for k, v in (labels or {}).items())
+            out[(name, series)] = total
+    return out
+
+
+def test_the_registry_follows_stats_off_the_steps_path(engine):
+    """No instrument is booked by a step that retires nothing; the
+    counters are brought up to ``stats`` by ``snapshot()`` and a
+    quarter second after the last time, a retirement or none."""
+    from paddle_tpu import observability as obs
+    tel = obs.get_telemetry()
+    was = tel.enabled       # a runner's build_engine may have left it on
+    obs.reset_registry()
+    tel.enable(compile_watch=False)
+    try:
+        pool = engine.pool
+        # room for the first two requests; the third waits for the second's
+        held = pool.alloc(pool.snapshot()["free_pages"] - 9)
+        try:
+            sched = ContinuousScheduler(engine)
+            long = sched.submit(PROMPTS[0], max_new_tokens=20)
+            short = sched.submit(PROMPTS[1], max_new_tokens=3)
+            queued = sched.submit(PROMPTS[3], max_new_tokens=4)
+            sched.step()        # the first step syncs: nothing before it did
+            mine = lambda: {k: v for k, v in _registry_values().items()
+                            if k[0] in {n for n, *_ in _synced_values(sched)}}
+            first = mine()
+            assert first[("pt_serve_requests_total", "")] == 3
+            assert first[("pt_serve_admission_refusals_total",
+                          "reason=kv_headroom")] == 1
+            sched.step()        # launches, reads, retires nothing
+            assert sched.stats["tokens_generated"] > \
+                first[("pt_serve_tokens_total", "")]
+            assert mine() == first
+            assert _registry_values()[("pt_serve_queue_depth", "")] == 1
+            sched.step()        # short's last token: a retirement books
+            #                     its histograms and no counter (the third
+            #                     request takes its pages)
+            assert short.done() and mine() == first
+            gaps = lambda: [v["count"] for v in obs.get_registry().snapshot()[
+                "pt_serve_token_gap_max_seconds"]["series"].values()]
+            assert gaps() == [1]
+            sched.step()
+            assert mine() == first
+            time.sleep(0.26)
+            sched.step()        # the cadence
+            assert mine() == _synced_values(sched)
+            assert mine()[("pt_serve_completed_total", "")] == 1
+            sched.step()
+            assert mine() != _synced_values(sched)
+            sched.snapshot()
+            assert mine() == _synced_values(sched)
+            sched.drain()
+            assert long.done() and queued.done()
+            assert mine() != _synced_values(sched)
+            sched.snapshot()
+            got = _registry_values()
+            assert mine() == _synced_values(sched)
+            assert got[("pt_serve_decode_steps_total", "launch=ahead")] \
+                == sched.stats["decode_steps_ahead"]
+            assert got[("pt_serve_decode_steps_total", "launch=sync")] \
+                == (sched.stats["occupancy_steps"]
+                    - sched.stats["decode_steps_ahead"])
+            assert got[("pt_serve_queue_depth", "")] == 0
+            assert got[("pt_serve_active_sequences", "")] == 0
+            assert not any(name == "pt_serve_batch_occupancy"
+                           for name, _ in got)
+            assert gaps() == [3]
+        finally:
+            pool.free(held)
+    finally:
+        tel.enabled = was       # the module's engine keeps its sentinel
+        obs.reset_registry()
+    _baseline(engine)

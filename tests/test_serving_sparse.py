@@ -671,9 +671,9 @@ def test_the_new_metrics_are_the_cells_and_read_nothing_elsewhere():
     assert (mix["new_tokens"]["lo"], mix["new_tokens"]["hi"]) == (2048, 8192)
     assert (mix["block"], mix["trace_s"]) == (8, 4.0)
     assert spec["configs"][-1]["reduced"] == ["num_hidden_layers"]
-    # PR 35's two after them
-    assert [m["name"] for m in spec["per_layer"][-8:-2]] == NEW_METRICS
-    for m in spec["per_layer"][-8:-2]:
+    # PR 35's two and PR 36's ten after them
+    assert [m["name"] for m in spec["per_layer"][-18:-12]] == NEW_METRICS
+    for m in spec["per_layer"][-18:-12]:
         assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_tokens_per_s"
     empty = {"trace": None, "values": {}, "counters": {}, "spans": {},

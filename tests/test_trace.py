@@ -648,3 +648,118 @@ def test_every_pallas_call_names_its_kernel():
     src = open(pa.__file__).read()
     assert 'name="paged_attention"' in src
     assert 'name="paged_attention_int8"' in src
+
+
+# -- the serving spans' launch numbers (PR 36) --------------------------------
+
+class _Recorded:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps what each
+    span would hand the profiler, in the order the spans open."""
+
+    seen: list = []
+
+    def __init__(self, name, **ids):
+        self.seen.append((name, ids))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture()
+def serve_spans(monkeypatch):
+    """(engine, spans): a tiny ServingEngine, and every ``pt:serve.*``
+    span the code under test opens as (name, arguments)."""
+    from paddle_tpu.observability import trace as trace_mod
+    from paddle_tpu.serving import (ModelSpec, ServeConfig, ServingEngine,
+                                    init_params)
+    spec = ModelSpec(vocab_size=64, hidden=32, layers=2, heads=2,
+                     max_seq_len=64)
+    cfg = ServeConfig(decode_buckets=(2, 4), prefill_buckets=(16,),
+                      kv_pages=64, page_size=4, max_new_tokens=8)
+    engine = ServingEngine(spec, init_params(spec, seed=0), cfg)
+    _Recorded.seen = []
+    monkeypatch.setattr(trace_mod, "_annotation", _Recorded)
+    try:
+        yield engine, _Recorded.seen
+    finally:
+        monkeypatch.undo()
+        engine.close()
+
+
+def _by_launch(spans, prefix):
+    out = {}
+    for name, ids in spans:
+        if name.startswith("pt:serve." + prefix) and ids:
+            out.setdefault(ids["launch"], []).append(
+                name.rsplit(".", 1)[1])
+    return out
+
+
+def test_the_six_engine_spans_carry_the_number_of_their_launch(serve_spans):
+    engine, spans = serve_spans
+    sched = engine.scheduler
+    n0 = engine.launches
+    a = sched.submit([1, 2, 3], max_new_tokens=5)
+    b = sched.submit([4, 5], max_new_tokens=5)
+    sched.drain()
+    assert len(a.result(timeout=5.0)) == len(b.result(timeout=5.0)) == 5
+    # the engine's six; the scheduler's own half of `decode.prep` (tables
+    # and batch, before the engine is called) has no argument
+    engine_spans = [(n, ids) for n, ids in spans if ids and n.startswith(
+        ("pt:serve.prefill.", "pt:serve.decode."))]
+    assert {n for n, ids in spans if not ids} == {
+        "pt:serve.wait", "pt:serve.evict", "pt:serve.admit", "pt:serve.book",
+        "pt:serve.decode.prep"}
+    assert all("launch" in ids for _, ids in engine_spans)
+    # a prefill's three spans share its number, beside the request's id
+    prefills = _by_launch(spans, "prefill.")
+    assert prefills == {n0 + 1: ["prep", "launch", "fetch"],
+                        n0 + 2: ["prep", "launch", "fetch"]}
+    rid = {ids["launch"]: ids["request_id"] for n, ids in engine_spans
+           if n.startswith("pt:serve.prefill.")}
+    assert rid == {n0 + 1: a.request_id, n0 + 2: b.request_id}
+    # a decode step's prep, launch and fetch share its number, beside
+    # rows and bucket; the numbers are the step log's
+    decodes = _by_launch(spans, "decode.")
+    assert sorted(decodes) == list(range(n0 + 3, n0 + 7))
+    assert all(sorted(v) == ["fetch", "launch", "prep"]
+               for v in decodes.values())
+    assert all({"rows", "bucket", "launch"} <= set(ids)
+               for n, ids in engine_spans if n.startswith("pt:serve.decode."))
+    log = sched.step_log()
+    assert [r[0] for r in log] == list(range(n0 + 1, n0 + 7))
+    assert engine.launches == n0 + 6
+
+
+def test_a_fetch_carries_the_launch_it_waits_for(serve_spans):
+    """Launched ahead, step k+1's launch span opens before step k's
+    fetch: the fetch still names k."""
+    engine, spans = serve_spans
+    sched = engine.scheduler
+    sched.submit([1, 2, 3], max_new_tokens=6)
+    sched.drain()
+    order = [(n.rsplit(".", 1)[1], ids["launch"]) for n, ids in spans
+             if n in ("pt:serve.decode.launch", "pt:serve.decode.fetch")]
+    first = order[0][1]
+    assert order[:6] == [("launch", first), ("launch", first + 1),
+                         ("fetch", first), ("launch", first + 2),
+                         ("fetch", first + 1), ("launch", first + 3)]
+    assert order[-1] == ("fetch", first + 4)
+    # the checks' calls are numbered too, and read at once
+    n0, at = engine.launches, len(spans)
+    row = engine.pool.admit_row(2, 2, engine.max_pages_per_seq)
+    try:
+        tok, _ = engine.prefill_logits([7, 8], row.table)
+        row.advance(2)
+        engine.decode_logits(np.asarray([tok], np.int32),
+                             np.asarray([2], np.int32), row.table[None])
+    finally:
+        row.release()
+    assert engine.launches == n0 + 2
+    assert [(n, ids["launch"]) for n, ids in spans[at:]] == [
+        ("pt:serve.prefill.prep", n0 + 1), ("pt:serve.prefill.launch", n0 + 1),
+        ("pt:serve.prefill.fetch", n0 + 1), ("pt:serve.decode.prep", n0 + 2),
+        ("pt:serve.decode.launch", n0 + 2), ("pt:serve.decode.fetch", n0 + 2)]
