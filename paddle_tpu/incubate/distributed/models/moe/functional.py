@@ -21,21 +21,21 @@ __all__ = ["top1_gating", "top2_gating", "dispatch", "combine",
            "dropless_moe"]
 
 
-def dropless_moe(x, router, wg, wu, wd, *, top_k, dense=None):
+def dropless_moe(x, router, wg, wu, wd, *, top_k, form=None):
     """Top-``k`` routed SwiGLU experts with no capacity and no dropped
     token — the serving path's expert layer, handed over to
     :mod:`paddle_tpu.serving.experts` (softmax router in float32,
     renormalised top-k; sort by expert + grouped matmuls where tokens
-    are many, every expert streamed once where rows are few).  The
-    capacity-dropping one-hot dispatch below stays what ``MoELayer``
+    are many, the chosen experts streamed once each where rows are few).
+    The capacity-dropping one-hot dispatch below stays what ``MoELayer``
     trains with.  ``x`` (T, hidden); ``router`` (hidden, E); ``wg`` /
-    ``wu`` (E, hidden, width); ``wd`` (E, width, hidden).  ``dense``
-    None leaves the regime to the token count (``experts.
-    DENSE_MAX_TOKENS``).  Returns (T, hidden) f32.
+    ``wu`` (E, hidden, width); ``wd`` (E, width, hidden).  ``form``
+    None leaves the form to the token count (``experts.
+    STREAM_MAX_TOKENS``); every form differentiates.  Returns (T, hidden) f32.
     """
     from paddle_tpu.serving import experts as _experts
     out, _ = _experts.moe_ffn(x, router, wg, wu, wd, top_k=top_k,
-                              dense=dense)
+                              form=form)
     return out
 
 

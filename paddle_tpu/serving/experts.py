@@ -3,25 +3,35 @@
 ``route`` is a softmax router in float32 over all ``E`` experts, the
 ``k`` largest, renormalised (``norm_topk_prob``).  No capacity: every
 token reaches all ``k`` of its experts.  ``experts`` then computes
-``sum_e g_e * (silu(y Wg_e) * (y Wu_e)) Wd_e`` in one of two regimes,
-chosen statically by the program's token count:
+``sum_e g_e * (silu(y Wg_e) * (y Wu_e)) Wd_e`` in one of two forms,
+chosen statically by the program's row count:
 
- - **grouped** (many tokens, prefill): the ``T * k`` token-expert pairs
-   are sorted by expert, each of the three projections is one grouped
-   matmul over the ``E`` groups (:func:`grouped_matmul`: each pair's
-   FLOPs once), and the results are unsorted and combined.  Tokens past
-   the prompt (``valid`` false) sort behind every group and cost
-   nothing.  A program of more than ``SLAB_TOKENS`` tokens does this a
-   slab of its tokens at a time, so that the sorted pairs stay small.
- - **dense** (few rows, decode): every row goes through every expert and
-   the router's weights zero what was not chosen.  It reads each expert
-   matrix exactly once, gathers none, and its ``E / k``-fold redundant
-   arithmetic stays under the time the weights take to stream while the
-   rows are few (on a v5e: about 240 rows, 197 TFLOP/s over 819 GB/s).
+ - **grouped** (more than ``STREAM_MAX_TOKENS`` tokens, prefill): the
+   ``T * k`` token-expert pairs are sorted by expert, each of the three
+   projections is one grouped matmul over the ``E`` groups
+   (:func:`grouped_matmul`: each pair's FLOPs once), and the results are
+   unsorted and combined.  Tokens past the prompt (``valid`` false) sort
+   behind every group and cost nothing.  A program of more than
+   ``SLAB_TOKENS`` tokens does this a slab of its tokens at a time, so
+   that the sorted pairs stay small.
+ - **streamed** (few rows, decode): the experts that at least one valid
+   row chose are listed, and a Pallas kernel (:func:`paddle_tpu.ops.
+   moe_decode.moe_decode`) streams those experts' matrices once each and
+   no others, every row through each; the router's weights zero what a
+   row did not choose.  Its ``E / k``-fold redundant arithmetic stays
+   under the time the weights take to stream while the rows are few (on
+   a v5e: about 240 rows, 197 TFLOP/s over 819 GB/s).  Where the rows
+   touch every expert it streams what the dense form below does, as fast
+   (0.4 % apart at Mellum's 64 rows on a v5e: PERF.md, PR 37), so there
+   is no third form by shape.  Booked on ``pt_pallas_calls_total{kernel=
+   "moe_decode"}``.
 
-Neither regime gathers weights: no ``(tokens, k, hidden, width)``
-tensor exists.  Weights: ``router`` (hidden, E), ``wg`` / ``wu``
-(E, hidden, width), ``wd`` (E, width, hidden).
+The **dense** form -- every row through every expert in XLA's fused
+contractions, the unchosen ones weighted zero -- is the streamed form's
+arithmetic off the TPU (booked ``fallback``) and its gradient.  No form
+gathers weights: no ``(tokens, k, hidden, width)`` tensor exists.
+Weights: ``router`` (hidden, E), ``wg`` / ``wu`` (E, hidden, width),
+``wd`` (E, width, hidden).
 
 The Paddle-parity ``MoELayer`` (``incubate/distributed/models/moe``)
 keeps its capacity-dropping one-hot dispatch for training;
@@ -30,18 +40,19 @@ keeps its capacity-dropping one-hot dispatch for training;
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
 
 __all__ = ["route", "experts", "moe_ffn", "expert_counts",
-           "grouped_matmul", "DENSE_MAX_TOKENS"]
+           "grouped_matmul", "STREAM_MAX_TOKENS"]
 
-# A program of at most this many rows takes the dense regime, a larger
+# A program of at most this many rows takes the streamed form, a larger
 # one the grouped: under the v5e's 240 rows (module docstring) and equal
 # to the largest decode bucket anyone builds today, so every decode
-# program is dense and every prefill program grouped.
-DENSE_MAX_TOKENS = 128
+# program is streamed and every prefill program grouped.
+STREAM_MAX_TOKENS = 128
 
 # Most tokens the grouped regime sorts at once: a longer program runs it a
 # slab of its tokens at a time (the sorted pairs of 32,768 tokens are
@@ -70,11 +81,16 @@ def expert_counts(idx, n_experts, valid=None):
     return jnp.sum(hit, axis=(0, 1))
 
 
-def _dense(y, gates, idx, wg, wu, wd):
-    e = wg.shape[0]
-    # (T, E) router weight of each expert for each row, zero if unchosen
+def _row_weights(gates, idx, e, valid=None):
+    """(T, E) router weight of each expert for each row, zero where the
+    row did not choose it or is not ``valid``."""
     weight = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32)
                      * gates[..., None], axis=1)
+    return weight if valid is None else weight * valid[:, None]
+
+
+def _dense(y, weight, wg, wu, wd):
+    e = wg.shape[0]
     yb = jnp.broadcast_to(y[None], (e,) + y.shape)
     a = jnp.einsum("eth,ehf->etf", yb, wg,
                    preferred_element_type=jnp.float32)
@@ -151,16 +167,65 @@ def _grouped(y, gates, idx, wg, wu, wd, valid):
     return jnp.sum(out * gates[..., None], axis=1)
 
 
-def experts(y, gates, idx, wg, wu, wd, *, dense=None, valid=None):
+def _touched(idx, e, valid):
+    """The experts a valid row chose, ascending, the last of them repeated
+    past their number: ``(ids (E,) int32, n (1,) int32)``."""
+    hit = expert_counts(idx, e, valid) > 0
+    n = jnp.sum(hit.astype(jnp.int32))
+    ids = jnp.argsort(jnp.logical_not(hit), stable=True).astype(jnp.int32)
+    ids = jnp.where(jnp.arange(e) < n, ids, ids[jnp.maximum(n - 1, 0)])
+    return ids, n.reshape(1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _streamed_call(interpret, y, weight, ids, n, wg, wu, wd):
+    from ..ops.moe_decode import moe_decode
+    return moe_decode(y, weight, ids, n, wg, wu, wd, interpret=interpret)
+
+
+def _streamed_fwd(interpret, y, weight, ids, n, wg, wu, wd):
+    return (_streamed_call(interpret, y, weight, ids, n, wg, wu, wd),
+            (y, weight, wg, wu, wd))
+
+
+def _streamed_bwd(interpret, res, ct):
+    # the dense form's gradient: an untouched expert's weights are zero
+    # in ``weight`` and get the zero cotangent either way
+    dy, dweight, dwg, dwu, dwd = jax.vjp(_dense, *res)[1](ct)
+    return dy, dweight, None, None, dwg, dwu, dwd
+
+
+_streamed_call.defvjp(_streamed_fwd, _streamed_bwd)
+
+
+def _streamed(y, gates, idx, wg, wu, wd, valid):
+    from ..framework import device as _device
+    from ..ops.fused_kernels import record_dispatch
+    from ..ops.pallas_ops import _interpret_default
+    e = wg.shape[0]
+    weight = _row_weights(gates, idx, e, valid)
+    if not _device.pallas_dispatch():
+        record_dispatch("moe_decode", "fallback")
+        return _dense(y, weight, wg, wu, wd)
+    record_dispatch("moe_decode", "pallas")
+    ids, n = _touched(idx, e, valid)
+    return _streamed_call(_interpret_default(), y, weight, ids, n, wg, wu,
+                          wd)
+
+
+def experts(y, gates, idx, wg, wu, wd, *, form=None, valid=None):
     """The experts' weighted sum for rows ``y`` (T, hidden), float32.
-    The regime (module docstring) follows the static row count, dense up
-    to ``DENSE_MAX_TOKENS``; ``dense`` forces one (tests).  ``valid``
-    (T,) bool marks the rows that matter (the others' results are
-    unspecified)."""
-    if dense is None:
-        dense = y.shape[0] <= DENSE_MAX_TOKENS
-    if dense:
-        return _dense(y, gates, idx, wg, wu, wd)
+    The form (module docstring) follows the static row count, streamed up
+    to ``STREAM_MAX_TOKENS``; ``form`` forces one (``"dense"`` too:
+    tests).  ``valid`` (T,) bool marks the rows that matter (the others'
+    results are unspecified)."""
+    e = wg.shape[0]
+    if form is None:
+        form = "streamed" if y.shape[0] <= STREAM_MAX_TOKENS else "grouped"
+    if form == "dense":
+        return _dense(y, _row_weights(gates, idx, e), wg, wu, wd)
+    if form == "streamed":
+        return _streamed(y, gates, idx, wg, wu, wd, valid)
     slabs = -(-y.shape[0] // SLAB_TOKENS)
     if slabs == 1 or y.shape[0] % slabs:
         return _grouped(y, gates, idx, wg, wu, wd, valid)
@@ -173,8 +238,8 @@ def experts(y, gates, idx, wg, wu, wd, *, dense=None, valid=None):
     return out.reshape(y.shape[0], -1)
 
 
-def moe_ffn(y, router, wg, wu, wd, *, top_k, dense=None, valid=None):
+def moe_ffn(y, router, wg, wu, wd, *, top_k, form=None, valid=None):
     """Route and compute: ``(out (T, hidden) f32, counts (E,) int32)``."""
     gates, idx = route(y, router, top_k)
-    out = experts(y, gates, idx, wg, wu, wd, dense=dense, valid=valid)
+    out = experts(y, gates, idx, wg, wu, wd, form=form, valid=valid)
     return out, expert_counts(idx, wg.shape[0], valid)

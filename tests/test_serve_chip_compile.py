@@ -560,3 +560,40 @@ def test_chip_compiles_the_flash_kernels(one_chip, call):
     s_p = -(-s // 512) * 512
     name = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dt]
     assert f"{name}[{b * 16},{s_p},128]" in text
+
+
+# -- the decode experts' kernel (same file: one process loads the library) --
+
+@pytest.mark.parametrize("hidden,width,experts,rows", [
+    (2048, 768, 128, 8), (2048, 768, 128, 24),      # Keye's buckets
+    (2304, 896, 64, 8), (2304, 896, 64, 32)])       # Mellum's streamed ones
+def test_chip_compiles_the_decode_experts_kernel(one_chip, monkeypatch,
+                                                 hidden, width, experts,
+                                                 rows):
+    """The streamed form at the served widths in bf16: one Mosaic kernel
+    named `moe_decode`, a whole expert a slot, nothing of an expert's
+    size in HBM beside the weights."""
+    from paddle_tpu.ops import moe_decode
+    from paddle_tpu.serving import experts as ex
+    monkeypatch.setattr(_device, "on_tpu", lambda: True)
+    monkeypatch.setattr(_device, "pallas_dispatch", lambda: True)
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(y, router, wg, wu, wd, valid):
+        return ex.moe_ffn(y, router, wg, wu, wd, top_k=8, form="streamed",
+                          valid=valid)
+
+    with jax.default_matmul_precision("default"):
+        exe = jax.jit(step).lower(
+            sds((rows, hidden)), sds((hidden, experts)),
+            sds((experts, hidden, width)), sds((experts, hidden, width)),
+            sds((experts, width, hidden)), sds((rows,), jnp.bool_)
+        ).compile()
+    calls = re.findall(r"%(\w*moe_decode\w*?)_*\.\d+ = [^\n]*custom-call",
+                       exe.as_text())
+    assert len(calls) == 1, calls
+    assert moe_decode.width_tile(hidden, width, 2) == width
+    assert exe.memory_analysis().temp_size_in_bytes < hidden * width * 2

@@ -409,7 +409,7 @@ def test_streams_equal_the_synchronous_loop_for_every_kind_of_model(
     if name == "tiny-mellum-serve":
         # prefill programs that sort and group, as the served ones do
         from paddle_tpu.serving import experts
-        monkeypatch.setattr(experts, "DENSE_MAX_TOKENS", 8)
+        monkeypatch.setattr(experts, "STREAM_MAX_TOKENS", 8)
     with aot_build_phase():     # the weights' jit is no request's compile
         engine = _load("runners", cfg["runner"]).build_engine(cfg, 3)[0]
     try:

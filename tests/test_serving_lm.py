@@ -56,13 +56,13 @@ def _load(kind, name):
 @pytest.fixture(scope="module", autouse=True)
 def grouped_regime_at_toy_sizes():
     """The toy's prefill buckets (16 to 64 rows) are far under
-    ``experts.DENSE_MAX_TOKENS``: lower it for this module so that its
+    ``experts.STREAM_MAX_TOKENS``: lower it for this module so that its
     prefill programs sort and group, as the served ones do, and its
-    decode programs (2 and 4 rows) stay dense."""
+    decode programs (2 and 4 rows) stay streamed."""
     from paddle_tpu.serving import experts
-    kept, experts.DENSE_MAX_TOKENS = experts.DENSE_MAX_TOKENS, 4
+    kept, experts.STREAM_MAX_TOKENS = experts.STREAM_MAX_TOKENS, 4
     yield
-    experts.DENSE_MAX_TOKENS = kept
+    experts.STREAM_MAX_TOKENS = kept
 
 
 @pytest.fixture(scope="module")
