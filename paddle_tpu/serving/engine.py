@@ -344,6 +344,8 @@ class ServingEngine:
                 state_slots=1 + self.config.decode_buckets[-1],
                 state_shape=(spec.ssm_inner, spec.ssm_state,
                              spec.ssm_conv),
+                state_conv_channels=spec.ssm_conv_channels,
+                state_heads=spec.ssm_heads,
                 index_dim=(spec.index_head_size if spec.sparse_topk
                            else 0))
             # the page table the programs take: one row of pages, or the

@@ -109,18 +109,28 @@ class KVPoolExhausted(RuntimeError):
 
 class StateSlots:
     """The recurrent state of a model's state-space layers, a slot a
-    running row: ``conv`` ``(Ls, slots, d_conv - 1, N)`` (each layer's
-    last inputs, in the cache's dtype) and ``ssm`` ``(Ls, slots, R, N)``
-    float32 (channels on the lanes: :mod:`.ssm`).  Slot 0 is the null
-    slot; a LIFO free list of the others, lock-guarded like the pages."""
+    running row: ``conv`` ``(Ls, slots, d_conv - 1, C)`` (each layer's
+    last inputs, in the cache's dtype; ``C`` is ``conv_channels``, or
+    ``inner`` where that is 0) and ``ssm`` float32, ``(Ls, slots, R, N)``
+    (Mamba-1: channels on the lanes) or, with ``heads`` (Mamba-2),
+    ``(Ls, slots, heads, N / heads, R)`` (:mod:`.ssm`).  Slot 0 is the
+    null slot; a LIFO free list of the others, lock-guarded like the
+    pages.  ``slot_bytes``: what one slot holds over all the layers."""
 
     def __init__(self, *, layers: int, slots: int, inner: int, state: int,
-                 conv: int, dtype=jnp.float32):
+                 conv: int, conv_channels: int = 0, heads: int = 0,
+                 dtype=jnp.float32):
         if slots < 2:
             raise ValueError("slots must be >= 2 (slot 0 is the null slot)")
         self.layers, self.slots = layers, slots
-        self.conv = jnp.zeros((layers, slots, conv - 1, inner), dtype)
-        self.ssm = jnp.zeros((layers, slots, state, inner), jnp.float32)
+        self.conv = jnp.zeros(
+            (layers, slots, conv - 1, conv_channels or inner), dtype)
+        per_slot = ((heads, inner // heads, state) if heads
+                    else (state, inner))
+        self.ssm = jnp.zeros((layers, slots, *per_slot), jnp.float32)
+        self.slot_bytes = layers * (
+            int(np.prod(self.conv.shape[2:])) * self.conv.dtype.itemsize
+            + int(np.prod(per_slot)) * 4)
         self._lock = threading.Lock()
         self._free: List[int] = list(range(slots - 1, 0, -1))
         self.stats = {"takes": 0, "releases": 0, "refusals": 0,
@@ -167,8 +177,10 @@ class StateSlots:
     def snapshot(self) -> Dict[str, int]:
         with self._lock:
             free = len(self._free)
-        return {"slots": self.slots, "held": self.slots - 1 - free,
-                "free": free, **self.stats}
+        held = self.slots - 1 - free
+        return {"slots": self.slots, "held": held, "free": free,
+                "slot_bytes": self.slot_bytes,
+                "held_bytes": held * self.slot_bytes, **self.stats}
 
     def _gauges(self) -> None:
         try:
@@ -184,6 +196,12 @@ class StateSlots:
             g.set(snap["held"], state="used")
             g.set(snap["free"], state="free")
             g.set(snap["high_watermark"], state="high_watermark")
+            b = get_registry().gauge(
+                "pt_serve_state_bytes",
+                "Bytes of the state slots (state-space layers), held by "
+                "running rows or free", labelnames=("state",))
+            b.set(snap["held_bytes"], state="held")
+            b.set(snap["free"] * self.slot_bytes, state="free")
         except Exception:
             pass
 
@@ -203,13 +221,15 @@ class PagePool:
                  window_pages: int = 0, window: int = 0,
                  kind: str = "global", state_layers: int = 0,
                  state_slots: int = 0, state_shape=(0, 0, 0),
+                 state_conv_channels: int = 0, state_heads: int = 0,
                  index_dim: int = 0):
         if pages < 2:
             raise ValueError("pages must be >= 2 (page 0 is the null page)")
         self.kind = kind            # "global" | "window": names the gauges
         self.window = int(window)
         # the state-space layers' slots: ``state_shape`` is (inner
-        # channels, state size, convolution width)
+        # channels, state size, convolution width), beside the
+        # convolution's channels and the heads of a Mamba-2 state
         self.state_slots: Optional[StateSlots] = None
         if state_layers:
             if scale_pages:
@@ -217,7 +237,8 @@ class PagePool:
             inner, state, conv = state_shape
             self.state_slots = StateSlots(
                 layers=state_layers, slots=state_slots, inner=inner,
-                state=state, conv=conv, dtype=dtype)
+                state=state, conv=conv, conv_channels=state_conv_channels,
+                heads=state_heads, dtype=dtype)
         # which kind of holding the last refused admission lacked:
         # "kv" (pages of either pool) or "state" (a slot)
         self.last_refusal: Optional[str] = None

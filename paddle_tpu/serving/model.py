@@ -35,8 +35,13 @@ memory unit ``layer<i>/gmu``; a layer of learned sparse attention
 (``sparse_topk``) is ``layer<i>/index_write`` (the indexer's key into the
 index pool), ``layer<i>/indexer`` (the indexer's queries and the scores
 against the row's cached keys, or a block of the prompt's),
-``layer<i>/select`` (the exact top-k) and ``layer<i>/attn_sparse``; then
-``lm_head`` and ``sample``.
+``layer<i>/select`` (the exact top-k) and ``layer<i>/attn_sparse``; a
+``parallel`` layer (Falcon-H1) is ``attn_qkv``, ``kv_write``,
+``attn_global``, ``attn_out`` (the attention branch), ``ssm`` (the Mamba-2
+branch: in-projection, convolution, delta, the chunked scan or the
+one-token update of the rows' states where they lie, gated norm,
+out-projection, and the two branches summed), ``state_write`` and
+``mlp``; then ``lm_head`` and ``sample``.
 The kernel takes the pools whole, so nothing stands between the write
 and the read: the ``kv_read`` scope of earlier versions has no operation
 left and is gone.  Prefill writes each layer's K/V as whole pages under
@@ -92,7 +97,7 @@ def _matmul(params, name, x, tap=None):
     return x @ params[name]
 
 
-LAYER_KINDS = ("full", "sliding", "ssm", "gmu", "cross")
+LAYER_KINDS = ("full", "sliding", "ssm", "gmu", "cross", "parallel")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,6 +141,20 @@ class ModelSpec:
     the layer attends over the ``sparse_topk`` best alone (all of them up
     to that many).  Such a layer is still kind ``full``: it owns pages of
     the full layers' pool, and a page of the index pool beside each.
+    ``parallel``: full attention and a Mamba-2 mixer (:func:`.ssm.
+    mamba2_prefill`: ``ssm_heads`` heads of ``ssm_inner / ssm_heads``
+    channels, a decay a head, B and C shared by ``ssm_groups`` groups,
+    prompts in chunks of ``ssm_chunk``) read the same normed input and
+    their outputs are summed into the residual (Falcon-H1); such a layer
+    owns pages of the full layers' pool *and* a place in a row's state
+    slot.  ``ffn_width``: the dense FFN's width where it is no whole
+    multiple of ``hidden`` (0: ``hidden * ffn_mult``).  The µP multipliers
+    (1, or empty, for a model without them): ``embed_mult`` on the
+    embedding, ``attn_out_mult`` on the attention branch's output (its
+    input's is 1 where the model has one), ``key_mult`` on k, ``ssm_in_mult`` /
+    ``ssm_out_mult`` on the Mamba-2 branch's, ``ssm_mults`` on the five
+    segments of its in-projection (z, x, B, C, dt), ``mlp_mults`` on the
+    SwiGLU's gate and on its down projection, ``head_mult`` on the logits.
     """
 
     vocab_size: int = 256
@@ -172,17 +191,43 @@ class ModelSpec:
     sparse_topk: int = 0
     index_heads: int = 0
     index_head_size: int = 0
+    ffn_width: int = 0
+    ssm_heads: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
+    embed_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    key_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_out_mult: float = 1.0
+    ssm_mults: Tuple[float, ...] = ()
+    mlp_mults: Tuple[float, ...] = ()
+    head_mult: float = 1.0
 
     @property
     def head_dim(self) -> int:
         return self.head_size or self.hidden // self.heads
 
     @property
+    def ffn_dim(self) -> int:
+        """The dense FFN's width."""
+        return self.ffn_width or self.hidden * self.ffn_mult
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """Channels of the state-space mixer's convolution: its inner
+        channels (Mamba-1), or x, B and C side by side (Mamba-2)."""
+        if self.ssm_heads:
+            return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+        return self.ssm_inner
+
+    @property
     def n_kv_heads(self) -> int:
         return self.kv_heads or self.heads
 
     def __post_init__(self):
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        for name in ("layer_types", "ssm_mults", "mlp_mults"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
         if not self.head_size and self.hidden % self.heads:
             raise ValueError(
                 f"hidden={self.hidden} not divisible by heads={self.heads}")
@@ -204,11 +249,24 @@ class ModelSpec:
                     f"{LAYER_KINDS}: {self.layer_types}")
             if "sliding" in self.layer_types and self.window < 1:
                 raise ValueError("sliding layers need window >= 1")
-            if self.ssm_layers and not (
+            if self._of_kind("ssm") and (self.ssm_heads or not (
                     self.ssm_inner > 0 and self.ssm_dt_rank > 0
-                    and self.ssm_state > 0 and self.ssm_conv > 1):
-                raise ValueError("ssm layers need ssm_inner, ssm_state, "
-                                 "ssm_conv and ssm_dt_rank")
+                    and self.ssm_state > 0 and self.ssm_conv > 1)):
+                raise ValueError("ssm layers (Mamba-1) need ssm_inner, "
+                                 "ssm_state, ssm_conv and ssm_dt_rank, "
+                                 "and no ssm_heads")
+            if self._of_kind("parallel") and not (
+                    self.ssm_heads > 0 and self.ssm_state > 0
+                    and self.ssm_conv > 1 and self.ssm_chunk > 0
+                    and self.ssm_inner % self.ssm_heads == 0
+                    and self.ssm_heads % self.ssm_groups == 0
+                    and len(self.ssm_mults) == 5
+                    and not (self.diff_attn or self.sparse_topk)):
+                raise ValueError(
+                    "parallel layers (Mamba-2) need ssm_heads dividing "
+                    "ssm_inner, ssm_groups dividing ssm_heads, ssm_state, "
+                    "ssm_conv, ssm_chunk, five ssm_mults and "
+                    "plain attention")
             tail = self.tail_start
             if set(self.layer_types[:tail]) & {"gmu", "cross"} or (
                     tail < self.layers and (
@@ -255,12 +313,14 @@ class ModelSpec:
     @property
     def global_layers(self) -> Tuple[int, ...]:
         """The layers that own pages of the full layers' pool."""
-        return self._of_kind("full")
+        return tuple(i for i in range(self.layers)
+                     if self.layer_kind(i) in ("full", "parallel"))
 
     @property
     def ssm_layers(self) -> Tuple[int, ...]:
         """The layers that own a place in a row's state slot."""
-        return self._of_kind("ssm")
+        return tuple(i for i in range(self.layers)
+                     if self.layer_kind(i) in ("ssm", "parallel"))
 
     @property
     def cross_layers(self) -> Tuple[int, ...]:
@@ -361,6 +421,26 @@ def init_params(spec: ModelSpec, seed: int = 0,
         p[f"h{i}.ssm.D"] = jnp.ones((n,), dtype)
         p[f"h{i}.ssm.wout"] = _w(_extra(i, 7), (n, spec.hidden))
 
+    def _mamba2(i):
+        """A Mamba-2 mixer (:func:`.ssm.mamba2_prefill`): ``A = -(1 ..
+        heads)``, ``D = 1``, a bias that starts delta log-uniform in
+        [0.001, 0.1] a head, the gated norm's weight 1."""
+        h, n = spec.ssm_heads, spec.ssm_inner
+        width = n + spec.ssm_conv_channels + h
+        p[f"h{i}.ssm.win"] = _w(_extra(i, 20), (spec.hidden, width))
+        p[f"h{i}.ssm.conv.w"] = _w(_extra(i, 21),
+                                   (spec.ssm_conv, spec.ssm_conv_channels),
+                                   0.2)
+        p[f"h{i}.ssm.conv.b"] = jnp.zeros((spec.ssm_conv_channels,), dtype)
+        dt = jnp.exp(jax.random.uniform(
+            _extra(i, 22), (h,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        p[f"h{i}.ssm.dt_bias"] = (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+        p[f"h{i}.ssm.A_log"] = jnp.log(
+            jnp.arange(1, h + 1, dtype=jnp.float32)).astype(dtype)
+        p[f"h{i}.ssm.D"] = jnp.ones((h,), dtype)
+        p[f"h{i}.ssm.norm.w"] = jnp.ones((n,), dtype)
+        p[f"h{i}.ssm.wout"] = _w(_extra(i, 23), (n, spec.hidden))
+
     for i in range(spec.layers):
         k = keys[2 + i * 6: 8 + i * 6]
         _norm(f"h{i}.ln1")
@@ -372,9 +452,11 @@ def init_params(spec: ModelSpec, seed: int = 0,
             p[f"h{i}.gmu.wout"] = _w(k[1], (spec.ssm_inner, spec.hidden))
         else:
             _attention(i, k, kind != "cross")
+        if kind == "parallel":
+            _mamba2(i)
         _norm(f"h{i}.ln2")
         if spec.ffn == "swiglu":
-            ffn = spec.hidden * spec.ffn_mult
+            ffn = spec.ffn_dim
             p[f"h{i}.mlp.wgu"] = _w(k[4], (spec.hidden, 2 * ffn))
             p[f"h{i}.mlp.wd"] = _w(k[5], (ffn, spec.hidden))
         elif spec.ffn == "moe":
@@ -384,7 +466,7 @@ def init_params(spec: ModelSpec, seed: int = 0,
             p[f"h{i}.moe.wu"] = _w(_extra(i, 0), (e, spec.hidden, f))
             p[f"h{i}.moe.wd"] = _w(_extra(i, 1), (e, f, spec.hidden))
         else:
-            ffn = spec.hidden * spec.ffn_mult
+            ffn = spec.ffn_dim
             p[f"h{i}.mlp.w1"] = _w(k[4], (spec.hidden, ffn))
             p[f"h{i}.mlp.b1"] = jnp.zeros((ffn,), dtype)
             p[f"h{i}.mlp.w2"] = _w(k[5], (ffn, spec.hidden))
@@ -412,8 +494,15 @@ def _mlp(spec, params, i, x, tap=None):
     if spec.ffn == "swiglu":
         gate, up = jnp.split(_matmul(params, f"h{i}.mlp.wgu", x, tap), 2,
                              axis=-1)
-        act = up.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
-        return _matmul(params, f"h{i}.mlp.wd", act.astype(x.dtype), tap)
+        gate = gate.astype(jnp.float32)
+        if spec.mlp_mults:
+            gate = gate * spec.mlp_mults[0]
+        act = up.astype(jnp.float32) * jax.nn.silu(gate)
+        out = _matmul(params, f"h{i}.mlp.wd", act.astype(x.dtype), tap)
+        if spec.mlp_mults:
+            out = (out.astype(jnp.float32) * spec.mlp_mults[1]
+                   ).astype(out.dtype)
+        return out
     h = _matmul(params, f"h{i}.mlp.w1", x, tap) + params[f"h{i}.mlp.b1"]
     h = jax.nn.gelu(h)
     return _matmul(params, f"h{i}.mlp.w2", h, tap) + params[f"h{i}.mlp.b2"]
@@ -495,14 +584,20 @@ def _project(spec, params, i, x, which, heads, tap=None):
     return y.reshape(x.shape[0], heads, spec.head_dim)
 
 
-def _qkv(spec, params, i, h, rope, tap):
+def _qkv(spec, params, i, h, rope, tap, n=None):
     """Layer ``i``'s normed rows through Wq, Wk, Wv: q (T, H, D) and
-    k, v (T, KVH, D), rotated where the spec says rotary."""
+    k, v (T, KVH, D), rotated where the spec says rotary.  ``n``: the
+    normed rows float32, where the caller has them (a ``parallel`` layer's
+    two branches read the same)."""
     cdt = params["embed"].dtype
-    x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
+    if n is None:
+        n = _norm(spec, params, f"h{i}.ln1", h)
+    x = n.astype(cdt)
     q = _project(spec, params, i, x, "q", spec.heads, tap)
     k = _project(spec, params, i, x, "k", spec.n_kv_heads, tap)
     v = _project(spec, params, i, x, "v", spec.n_kv_heads, tap)
+    if spec.key_mult != 1.0:
+        k = (k.astype(jnp.float32) * spec.key_mult).astype(k.dtype)
     if spec.qk_norm:
         q = _head_norm(spec, params[f"h{i}.attn.qnorm.w"], q)
         k = _head_norm(spec, params[f"h{i}.attn.knorm.w"], k)
@@ -544,7 +639,7 @@ def _attn_scope(spec, i):
     metrics read), else ``attn_window`` / ``attn_global`` by the layer."""
     if spec.sparse_topk:
         return f"layer{i}/attn_sparse"
-    if not spec.window_layers:
+    if not spec.window_layers and "parallel" not in spec.layer_types:
         return f"layer{i}/attn"
     return f"layer{i}/attn_" + ("window" if spec.layer_window(i)
                                 else "global")
@@ -730,15 +825,29 @@ def _differential(spec, params, i, att):
 
 def _attn_out(spec, params, i, h, att, tap=None):
     """``h`` plus Wo of the attention's rows ``att`` ((T, H*D) or (T, H,
-    D); A_h (T, H, 2D) under differential attention), and its bias."""
+    D); A_h (T, H, 2D) under differential attention), and its bias.  In
+    a ``parallel`` layer the branch alone, ``attn_out_mult`` times Wo of
+    ``att``, float32: the caller sums the two branches into ``h``."""
     cdt = params["embed"].dtype
     if spec.diff_attn:
         att = _differential(spec, params, i, att)
     att = att.reshape(att.shape[0], spec.heads * spec.head_dim)
+    if spec.layer_kind(i) == "parallel":
+        o = jnp.dot(att.astype(cdt), params[f"h{i}.attn.wo"],
+                    preferred_element_type=jnp.float32)
+        return o * spec.attn_out_mult if spec.attn_out_mult != 1.0 else o
     o = _matmul(params, f"h{i}.attn.wo", att.astype(cdt), tap)
     if spec.attn_bias:
         o = o + params[f"h{i}.attn.bo"]
     return h + o
+
+
+def _embed(spec, params, tokens):
+    """The tokens' rows of the embedding, times ``embed_mult``."""
+    h = params["embed"][tokens]
+    if spec.embed_mult != 1.0:
+        h = (h.astype(jnp.float32) * spec.embed_mult).astype(h.dtype)
+    return h
 
 
 def _page_slot(page_tables, positions, page_size):
@@ -753,9 +862,8 @@ def _page_slot(page_tables, positions, page_size):
 def _head(spec, params, hf):
     """Logits of normed rows: against the embedding when tied, else the
     model's own head matrix."""
-    if spec.tie_head:
-        return hf @ params["embed"].T
-    return hf @ params["head"]
+    logits = hf @ (params["embed"].T if spec.tie_head else params["head"])
+    return logits * spec.head_mult if spec.head_mult != 1.0 else logits
 
 
 def _results(spec, pools, token, logits, counts, selected=()):
@@ -805,9 +913,10 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
         sliding layers.  A prompt longer than the window writes only
         the last ``window / ps + 1`` pages there: what a later decode
         step can read.
-      conv_pool/ssm_pool: donated state pools of the ``ssm`` layers
-        (:class:`.kv_cache.StateSlots`): ``(Ls, slots, d_conv - 1, N)``
-        and ``(Ls, slots, R, N)`` float32.  The page table then has a
+      conv_pool/ssm_pool: donated state pools of the ``ssm`` (and
+        ``parallel``) layers (:class:`.kv_cache.StateSlots`): ``(Ls,
+        slots, d_conv - 1, C)`` and ``(Ls, slots, R, N)`` float32, or
+        ``(Ls, slots, H, P, R)`` for Mamba-2.  The page table then has a
         third row whose first entry is the sequence's slot; the scan
         starts from zero and the slot is overwritten whole with the
         state after ``length`` tokens.
@@ -827,7 +936,7 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
     scope = jax.named_scope
     pos_ids = jnp.arange(s, dtype=jnp.int32)
     with scope("embed"):
-        h = params["embed"][tokens]
+        h = _embed(spec, params, tokens)
         if spec.positions == "learned":
             h = h + params["pos"][:s]
         rope = (_rope_tables(spec, pos_ids)
@@ -895,6 +1004,20 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
         v_pool = write(v_pool, li, v.reshape(s, kvd), tables[0], 0,
                        n_pages)
 
+    def write_state(i, h, conv, state):
+        """Layer ``i``'s convolution tail and state into the prompt's
+        slot, under ``layer<i>/state_write``: the whole slot (what a
+        released row left there is never read)."""
+        nonlocal conv_pool, ssm_pool
+        with scope(f"layer{i}/state_write"):
+            li, slot = spec.ssm_layers.index(i), tables[2, 0]
+            conv_pool = conv_pool.at[li, slot].set(
+                conv.astype(conv_pool.dtype))
+            ssm_pool = ssm_pool.at[li, slot].set(state)
+            h, conv_pool, ssm_pool = jax.lax.optimization_barrier(
+                (h, conv_pool, ssm_pool))
+        return h
+
     counts = []
     tail = spec.tail_start      # layers from here on keep nothing
     memory = shared = None      # an ssm layer's y, a full layer's (k, v)
@@ -910,15 +1033,7 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                 memory = jax.lax.dynamic_slice_in_dim(y, length - 1, 1)
                 h = h + _ssm.gate_out(params, f"h{i}.ssm", y, z)
             h = _ffn(spec, params, i, h, tap, in_prompt, counts)
-            with scope(f"layer{i}/state_write"):
-                # the whole slot: what a released row left there is
-                # never read
-                li, slot = spec.ssm_layers.index(i), tables[2, 0]
-                conv_pool = conv_pool.at[li, slot].set(
-                    conv.astype(conv_pool.dtype))
-                ssm_pool = ssm_pool.at[li, slot].set(state)
-                h, conv_pool, ssm_pool = jax.lax.optimization_barrier(
-                    (h, conv_pool, ssm_pool))
+            h = write_state(i, h, conv, state)
             continue
         if i >= tail - 1 and tail < spec.layers:
             # the decoder's tail, on the prompt's last position only: the
@@ -952,8 +1067,11 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                     write_kv(i, *shared)
             continue
         window = spec.layer_window(i)
+        parallel = kind == "parallel"
         with scope(f"layer{i}/attn_qkv"):
-            q, k, v = _qkv(spec, params, i, h, rope, tap)
+            # a parallel layer's two branches read one normed input
+            n = _norm(spec, params, f"h{i}.ln1", h) if parallel else None
+            q, k, v = _qkv(spec, params, i, h, rope, tap, n=n)
         select = None
         if spec.sparse_topk:
             with scope(f"layer{i}/indexer"):
@@ -971,8 +1089,15 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
             else:
                 o = _prefill_attention(spec, q, k, v, length, window)
         with scope(f"layer{i}/attn_out"):
-            h = _attn_out(spec, params, i, h, o, tap)
-        h = _ffn(spec, params, i, h, tap, in_prompt, counts)
+            o = _attn_out(spec, params, i, h, o, tap)
+        if parallel:
+            with scope(f"layer{i}/ssm"):
+                mixed, conv, state = _ssm.mamba2_prefill(
+                    spec, params, f"h{i}.ssm", n, length)
+                o = (h.astype(jnp.float32) + o + mixed).astype(cdt)
+        h = _ffn(spec, params, i, o, tap, in_prompt, counts)
+        if parallel:
+            h = write_state(i, h, conv, state)
         if spec.sparse_topk:
             with scope(f"layer{i}/index_write"):
                 index_pool = write(
@@ -1076,9 +1201,10 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
         attends over what it selected.
       kw_pool/vw_pool: donated pools of the sliding layers; the kernel
         walks only the pages of a row's window there.
-      conv_pool/ssm_pool: donated state pools of the ``ssm`` layers; each
-        reads its rows' slots (``page_tables[:, 2, 0]``; a padding row's
-        is the null slot 0) and writes them back in place.
+      conv_pool/ssm_pool: donated state pools of the ``ssm`` (and
+        ``parallel``) layers; each reads its rows' slots
+        (``page_tables[:, 2, 0]``; a padding row's is the null slot 0) and
+        writes them back in place.
       tap: optional calibration hook (eager PTQ harness only).
       selection: the same step, its outputs followed by what every layer
         of learned sparse attention scored and selected (:func:`_results`).
@@ -1098,7 +1224,7 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
               else page_tables[:, None])
     with scope("embed"):
         lengths = positions + 1
-        h = params["embed"][tokens]
+        h = _embed(spec, params, tokens)
         if spec.positions == "learned":
             h = h + params["pos"][positions]
         rope = (_rope_tables(spec, positions)
@@ -1153,8 +1279,11 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 h = _attn_out(spec, params, i, h, o, tap)
             h = _ffn(spec, params, i, h, tap, live, counts)
             continue
+        parallel = kind == "parallel"
         with scope(f"layer{i}/attn_qkv"):
-            q, k, v = _qkv(spec, params, i, h, rope, tap)
+            # a parallel layer's two branches read one normed input
+            n = _norm(spec, params, f"h{i}.ln1", h) if parallel else None
+            q, k, v = _qkv(spec, params, i, h, rope, tap, n=n)
             page, slot = _page_slot(table, positions, page_size)  # (B,)
         if window:
             li = spec.window_layers.index(i)
@@ -1206,8 +1335,19 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 else:
                     o = attend(q, k_pool, v_pool, table, li)
         with scope(f"layer{i}/attn_out"):
-            h = _attn_out(spec, params, i, h, o, tap)
-        h = _ffn(spec, params, i, h, tap, live, counts)
+            o = _attn_out(spec, params, i, h, o, tap)
+        if parallel:
+            si, slots = spec.ssm_layers.index(i), tables[:, 2, 0]
+            with scope(f"layer{i}/ssm"):
+                # the rows' states are updated where they lie (ssm.py)
+                mixed, conv, ssm_pool = _ssm.mamba2_decode(
+                    spec, params, f"h{i}.ssm", n, conv_pool[si, slots],
+                    ssm_pool, si, slots)
+                o = (h.astype(jnp.float32) + o + mixed).astype(cdt)
+            with scope(f"layer{i}/state_write"):
+                conv_pool = conv_pool.at[si, slots].set(
+                    conv.astype(conv_pool.dtype))
+        h = _ffn(spec, params, i, o, tap, live, counts)
     with scope("lm_head"):
         hf = _norm(spec, params, "lnf", h).astype(cdt)
         if tap is not None:

@@ -139,7 +139,10 @@ state-space layers: ``state_slots_held`` and ``state_slots_held_max``
 (the pool's own numbers as of the last sync, below), ``refused_state`` (admissions refused for want
 of a slot, beside ``refused_kv``), ``ssm_tokens_scanned`` (prompt
 positions through the scan) and, with cross layers, ``shared_kv_reads``
-(decode steps times the layers that read the shared full layer's pages).
+(decode steps times the layers that read the shared full layer's pages);
+with Mamba-2 layers ``ssd_chunks_scanned`` (a prefill adds ``ceil(prompt
+/ ssm_chunk)`` times those layers: the chunks of the chunked scan;
+``pt_serve_ssd_chunks_scanned_total``).
 Learned sparse attention: ``sparse_tokens_scored`` (a decode step adds, a
 sparse layer, the sum of its rows' contexts: every cached indexer key a
 query is scored against) and ``sparse_tokens_selected`` (the sum of
@@ -502,6 +505,9 @@ class ContinuousScheduler:
             "state_slots_held": 0, "state_slots_held_max": 0,
             "refused_state": 0, "ssm_tokens_scanned": 0,
             "shared_kv_reads": 0,
+            # Mamba-2 layers: chunks of the prompts' chunked scan, summed
+            # over the layers
+            "ssd_chunks_scanned": 0,
             # learned sparse attention, summed over decode steps and
             # sparse layers: cached keys scored, and tokens selected
             "sparse_tokens_scored": 0, "sparse_tokens_selected": 0,
@@ -523,6 +529,9 @@ class ContinuousScheduler:
         }
         spec = engine.spec
         self._scans = bool(spec.ssm_layers)
+        # Mamba-2 layers: (layers, chunk) of the chunked scan, or None
+        self._ssd = ((len(spec.ssm_layers), spec.ssm_chunk)
+                     if spec.ssm_heads and spec.ssm_layers else None)
         # layers that read the shared full layer's pages in a decode step
         self._shared_readers = (len(spec.cross_layers) + 1
                                 if spec.cross_layers else 0)
@@ -832,6 +841,10 @@ class ContinuousScheduler:
         self.stats["prefill_tokens"] += len(st.prompt)
         if self._scans:
             self.stats["ssm_tokens_scanned"] += len(st.prompt)
+        if self._ssd:
+            layers, chunk = self._ssd
+            self.stats["ssd_chunks_scanned"] += \
+                layers * -(-len(st.prompt) // chunk)
         self._book_aux_locked(self.engine.take_aux())
         act = _Active(st, pages, pos=len(st.prompt), last_token=first)
         if self._is_finished(act):
@@ -1402,6 +1415,7 @@ _SYNCED = (
     ("pt_serve_decode_steps_total", {"launch": "sync"},
      "occupancy_steps", "decode_steps_ahead"),
     ("pt_serve_ssm_tokens_scanned_total", None, "ssm_tokens_scanned", None),
+    ("pt_serve_ssd_chunks_scanned_total", None, "ssd_chunks_scanned", None),
     ("pt_serve_moe_tokens_routed_total", None, "moe_tokens_routed", None),
     ("pt_serve_moe_expert_max_tokens_total", None, "moe_expert_max_tokens",
      None),
@@ -1440,6 +1454,9 @@ _METRIC_HELP = {
     "pt_serve_decode_tokens_total": "Rows decoded, summed over steps",
     "pt_serve_ssm_tokens_scanned_total":
         "Prompt positions through the state-space layers' scan",
+    "pt_serve_ssd_chunks_scanned_total":
+        "Chunks of the Mamba-2 layers' chunked scan over prompts, summed "
+        "over the layers",
     "pt_serve_moe_tokens_routed_total":
         "Token-expert pairs routed, summed over layers",
     "pt_serve_moe_expert_max_tokens_total":

@@ -372,6 +372,9 @@ def test_readers_on_the_recorded_chip_cut(bench):
         == tr.ops_per_run(r"^pallas:", "serve_decode")[0] > 0.005
 
 
+# PR 38's cell, appended to the lists of the readers that read its runner
+FALCON = "falconh1-serve-longform-backlog"
+
 # -- PR 27: the readers of the sparse grouped-query decoder's cell ------------
 
 LM_METRICS = ["moe_ms_per_decode_step", "moe_decode_roofline",
@@ -520,11 +523,14 @@ def test_lm_metrics_have_entries_for_the_new_cell_only(bench):
     # (not the prefill's two: a 4 s trace of that cell seldom holds one)
     experts = {"moe_ms_per_decode_step", "moe_decode_roofline",
                "expert_load_max_over_mean"}
+    # and the grouped kernel's two PR 38's cell (Falcon-H1's full layers)
+    falcon = {"attn_global_ms_per_step", "paged_attn_gqa_roofline"}
     for name in LM_METRICS + ["kv_window_pages_returned"]:
         assert entries[name]["workloads"] == [cell] + (
             ["phi4flash-serve-reasoning-backlog"] if name in shared else []
         ) + (["keyevl2-serve-longctx-reasoning-backlog"]
-             if name in experts else [])
+             if name in experts else []) + (
+            [FALCON] if name in falcon else [])
         assert entries[name]["moves"] == "serve_tokens_per_s"
         assert os.path.exists(os.path.join(
             BENCH, "layer_metrics", name + ".py")) or os.path.exists(
@@ -553,16 +559,21 @@ def test_hybrid_metrics_have_entries_for_their_cell_and_read_nothing_elsewhere(
     another configuration's."""
     spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     cell = "phi4flash-serve-reasoning-backlog"
-    assert spec["workloads"][-2]["name"] == cell      # PR 34's after it
-    assert spec["workloads"][-2]["chips"] == 1
-    assert spec["configs"][-2]["name"] == spec["workloads"][-2]["config"]
-    assert spec["configs"][-2]["reduced"] == []
-    # then PR 32's one, PR 34's 6, PR 35's 2, PR 36's 10
-    hybrid = spec["per_layer"][-28:-19]
+    assert spec["workloads"][-3]["name"] == cell  # PR 34's, PR 38's after
+    assert spec["workloads"][-3]["chips"] == 1
+    assert spec["configs"][-3]["name"] == spec["workloads"][-3]["config"]
+    assert spec["configs"][-3]["reduced"] == []
+    # then PR 32's one, PR 34's 6, PR 35's 2, PR 36's 10, PR 38's 3
+    hybrid = spec["per_layer"][-31:-22]
     assert [m["name"] for m in hybrid] == HYBRID_METRICS
-    assert spec["per_layer"][-19]["name"] == "paged_walk_live_pct.grouped"
+    assert spec["per_layer"][-22]["name"] == "paged_walk_live_pct.grouped"
+    # the scopes' and the slots' readers are blind to the mixer's kind:
+    # PR 38's Mamba-2 cell is appended to theirs
+    blind = {"ssm_ms_per_decode_step", "ssm_ms_per_prefill",
+             "state_slots_held_max"}
     for m in hybrid:
-        assert m["workloads"] == [cell]
+        assert m["workloads"] == [cell] + (
+            [FALCON] if m["name"] in blind else [])
         assert m["moves"] == "serve_tokens_per_s"
     empty = {"trace": None, "values": {}, "counters": {}, "spans": {},
              "peak": None, "model": {}}
@@ -609,7 +620,8 @@ WALK_METRICS = {"paged_walk_live_pct.steady":
                 # the grouped kernels' two lists (PR 32)
                 "paged_walk_live_pct.grouped":
                 ("serve_tokens_per_s", ["mellum2-serve-mixedctx-backlog",
-                                        "phi4flash-serve-reasoning-backlog"])}
+                                        "phi4flash-serve-reasoning-backlog",
+                                        "falconh1-serve-longform-backlog"])}
 
 
 @pytest.mark.parametrize("name", WALK_METRICS)
@@ -660,7 +672,8 @@ AHEAD_METRICS = {
     "decode_ahead_pct.backlog": ("serve_tokens_per_s", [
         "gpt345m-serve-longprompt-backlog", "mellum2-serve-mixedctx-backlog",
         "phi4flash-serve-reasoning-backlog",
-        "keyevl2-serve-longctx-reasoning-backlog"]),
+        "keyevl2-serve-longctx-reasoning-backlog",
+        "falconh1-serve-longform-backlog"]),
     "decode_ahead_pct.steady": ("tpot_p50_ms",
                                 ["gpt345m-serve-complete-steady"]),
 }
@@ -750,7 +763,8 @@ def test_step_metric_on_a_hand_made_run(bench, full, name, cell):
         # a worst gap is summed at retirement: not in the cell whose
         # window may retire nobody, and whose answers outlast the ramp
         "workloads": STEADY if cell == "steady" else
-        BACKLOG[:3] if name == "token_gap_max_ms_mean" else BACKLOG}
+        BACKLOG[:3] if name == "token_gap_max_ms_mean" else
+        BACKLOG + [FALCON]}
 
 
 def test_prefill_share_and_stall_on_a_hand_made_run(bench):
@@ -765,14 +779,15 @@ def test_prefill_share_and_stall_on_a_hand_made_run(bench):
     assert by["prefill_device_share_pct"] == {
         "name": "prefill_device_share_pct", "unit": "%", "better": "lower",
         "source": "device_trace", "layer": LAYERS["model"],
-        "moves": "serve_tokens_per_s", "workloads": BACKLOG[:3]}
+        "moves": "serve_tokens_per_s", "workloads": BACKLOG[:3] + [FALCON]}
     assert by["prefill_stall_ms_per_token"] == {
         "name": "prefill_stall_ms_per_token", "unit": "ms",
         "better": "lower", "source": "program_counter",
         "layer": LAYERS["sched"], "moves": "serve_tokens_per_s",
-        "workloads": BACKLOG}
+        "workloads": BACKLOG + [FALCON]}
     # every new metric has its reader, and the file only gained them
-    assert [m["name"] for m in spec["per_layer"][-10:]] == [
+    # (PR 38's three after them)
+    assert [m["name"] for m in spec["per_layer"][-13:-3]] == [
         "decode_period_ms_mean.steady", "decode_period_ms_mean.backlog",
         "slow_steps_pct.steady", "slow_steps_pct.backlog",
         "decode_device_ms_p50.steady", "decode_device_ms_p50.backlog",

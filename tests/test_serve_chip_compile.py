@@ -597,3 +597,79 @@ def test_chip_compiles_the_decode_experts_kernel(one_chip, monkeypatch,
     assert len(calls) == 1, calls
     assert moe_decode.width_tile(hidden, width, 2) == width
     assert exe.memory_analysis().temp_size_in_bytes < hidden * width * 2
+
+
+# -- Falcon-H1-34B's programs at its published widths ---------------------------
+# (`benchmarks/configs/falcon-h1-34b-serve.json`): two of its parallel layers
+# (a Mamba-2 mixer beside grouped-query attention), so a program compiles in
+# seconds; the pool's pages, the slots, page size and largest buckets are the
+# configuration's.
+
+FALCON_SPEC = ModelSpec(
+    vocab_size=261120, hidden=5120, layers=2, heads=20, kv_heads=4,
+    head_size=128, max_seq_len=6144, ffn="swiglu", ffn_width=21504,
+    norm="rms", positions="rotary", rope_theta=1e11,
+    layer_types=("parallel", "parallel"), tie_head=False, ssm_inner=4096,
+    ssm_heads=32, ssm_groups=2, ssm_state=256, ssm_conv=4, ssm_chunk=128,
+    embed_mult=5.656854249492381, attn_out_mult=0.0375,
+    key_mult=0.011048543456039804, ssm_in_mult=0.25,
+    ssm_out_mult=0.08838834764831845,
+    ssm_mults=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+               0.3535533905932738),
+    mlp_mults=(0.1767766952966369, 0.011160714285714284),
+    head_mult=0.0078125)
+FALCON_PAGE, FALCON_PAGES, FALCON_SLOTS = 128, 3073, 65
+
+
+@pytest.mark.parametrize("kind,size", [("decode", 64), ("prefill", 4096)])
+def test_chip_compiles_the_parallel_mamba2_programs(one_chip, monkeypatch,
+                                                    kind, size):
+    """Pages and the Mamba-2 slots donated and aliased; a decode step
+    updates its rows' states where they lie (a gather of them would have
+    the compiler re-lay the whole slot pool, 1.09 GB a layer at four
+    layers: `mini-gather`); the grouped kernel reads 5 query heads a KV
+    head; the prefill's chunked scan is XLA's, in under a GB of
+    temporaries at the longest bucket."""
+    monkeypatch.setattr(_device, "on_tpu", lambda: True)
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(init_params, FALCON_SPEC, dtype=bf)))
+    full, _ = pool_shapes(2, FALCON_PAGES, FALCON_PAGE, 4, 128)
+    state = [sds(full, bf), sds(full, bf),
+             sds((2, FALCON_SLOTS, 3, 5120), bf),
+             sds((2, FALCON_SLOTS, 32, 128, 256), jnp.float32)]
+    maxp = FALCON_SPEC.max_seq_len // FALCON_PAGE
+    step = decode_step if kind == "decode" else prefill_step
+
+    def run(params, k, v, conv, ssm, a, b, c):
+        return step(FALCON_SPEC, params, k, v, a, b, c,
+                    page_size=FALCON_PAGE, conv_pool=conv, ssm_pool=ssm)
+
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    args = ((i32((size,)), i32((size,)), i32((size, 3, maxp)))
+            if kind == "decode" else
+            (i32((size,)), i32(()), i32((3, maxp))))
+    exe = jax.jit(run, donate_argnums=(1, 2, 3, 4)).lower(
+        params, *state, *args).compile()
+    text = exe.as_text()
+    mem = exe.memory_analysis()
+    held = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in state)
+    assert mem.alias_size_in_bytes >= held
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 4
+    assert "mini-gather" not in text
+    slots = int(np.prod(state[3].shape)) * 4
+    if kind == "decode":
+        # a step's temporaries: no slot pool, no layer of it
+        assert mem.temp_size_in_bytes < slots / 20
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) == 2
+        assert "paged_attention_gqa" in text
+    else:
+        assert mem.temp_size_in_bytes < 1 << 30
+        assert "tpu_custom_call" not in text

@@ -657,11 +657,12 @@ def test_the_new_metrics_are_the_cells_and_read_nothing_elsewhere():
     counter or model fact: the parent's, or another configuration's."""
     run_py = _load(".", "run")
     spec = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    assert spec["workloads"][-1] == {
+    # PR 38's cell after it
+    assert spec["workloads"][-2] == {
         "name": CELL, "config": "keye-vl2-30b-a3b-serve",
         "traffic": "longctx-reasoning-backlog", "chips": 1,
-        "why": spec["workloads"][-1]["why"]}
-    assert len(spec["workloads"][-1]["why"]) <= 200
+        "why": spec["workloads"][-2]["why"]}
+    assert len(spec["workloads"][-2]["why"]) <= 200
     # the traffic ISSUE 34 fixed: no lever pulled
     mix = json.load(open(os.path.join(
         BENCH, "traffic", "longctx-reasoning-backlog.json")))
@@ -670,10 +671,10 @@ def test_the_new_metrics_are_the_cells_and_read_nothing_elsewhere():
     assert (mix["prompt_len"]["lo"], mix["prompt_len"]["hi"]) == (8192, 32768)
     assert (mix["new_tokens"]["lo"], mix["new_tokens"]["hi"]) == (2048, 8192)
     assert (mix["block"], mix["trace_s"]) == (8, 4.0)
-    assert spec["configs"][-1]["reduced"] == ["num_hidden_layers"]
-    # PR 35's two and PR 36's ten after them
-    assert [m["name"] for m in spec["per_layer"][-18:-12]] == NEW_METRICS
-    for m in spec["per_layer"][-18:-12]:
+    assert spec["configs"][-2]["reduced"] == ["num_hidden_layers"]
+    # PR 35's two, PR 36's ten and PR 38's three after them
+    assert [m["name"] for m in spec["per_layer"][-21:-15]] == NEW_METRICS
+    for m in spec["per_layer"][-21:-15]:
         assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_tokens_per_s"
     empty = {"trace": None, "values": {}, "counters": {}, "spans": {},
