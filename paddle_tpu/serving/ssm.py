@@ -48,7 +48,10 @@ scan is matmuls (:func:`chunked_scan`): within a chunk of
 ``C B^T`` weighed by their decay, each chunk hands its end state to the
 next, and only that hand-over — ``chunks`` steps over the state — is a
 loop.  :func:`mamba2_prefill` / :func:`mamba2_decode` are the whole
-branch, out-projection and multiplier included.
+branch, out-projection and multiplier included.  A decode step's update
+of the rows' states, where they lie in the slot pool, is
+:func:`paddle_tpu.ops.ssd_update.ssd_state_update`: the Pallas kernel
+``ssd_state_update`` on a TPU, its twin (a loop over the rows) elsewhere.
 """
 from __future__ import annotations
 
@@ -57,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.selective_scan import selective_scan
+from ..ops.ssd_update import ssd_state_update
 
 __all__ = ["prefill", "decode", "gate_out", "chunked_scan",
            "mamba2_prefill", "mamba2_decode"]
@@ -264,32 +268,20 @@ def mamba2_decode(spec, params, name, n, tail, pool, layer, slots):
     state slots ``(Ls, slots, H, P, N)`` float32 of which the rows' are
     ``pool[layer, slots]``.  Returns ``(out (B, hidden) float32, tail,
     pool)`` with the token taken in, each row's state updated where it
-    lies: a loop over the rows slices a slot out, updates it
-    (elementwise, float32) and writes it back.  A gather of the rows'
-    slots is no such thing on the chip: its compiler re-lays the whole
-    pool into lane tiles first, a copy of every layer's slots a layer
-    (1.09 GB at Falcon-H1's widths)."""
+    lies (float32) by :func:`paddle_tpu.ops.ssd_update.ssd_state_update`:
+    on the chip the Pallas kernel ``ssd_state_update``, which streams each
+    row's slot through VMEM and writes it back in place; elsewhere its
+    twin, a loop over the rows that slices a slot out, updates it and
+    writes it back.  Neither gathers the rows' slots: on the chip its
+    compiler would re-lay the whole pool into lane tiles first, a copy of
+    every layer's slots a layer (1.09 GB at Falcon-H1's widths)."""
     w = params[name + ".conv.w"].astype(jnp.float32)
     z, xbc, dt = _mamba2_in(spec, params, name, n)
     window = jnp.concatenate([tail.astype(xbc.dtype), xbc[:, None]], axis=1)
     conv = jnp.sum(window.astype(jnp.float32) * w[None], axis=1)
     x, bmat, cmat, delta = _mamba2_parts(spec, params, name, conv, dt)
-    b, h, p = x.shape
-    g = spec.ssm_groups
-    decay = jnp.exp(delta * _decay2(params, name)).reshape(b, g, h // g)
-    inp = (delta[..., None] * x).reshape(b, g, h // g, p)
-    shape = (1, 1) + pool.shape[2:]
-
-    def row(r, carry):
-        pool, y = carry
-        at = (layer, slots[r], 0, 0, 0)
-        s = jax.lax.dynamic_slice(pool, at, shape).reshape(g, h // g, p, -1)
-        s = (decay[r][..., None, None] * s
-             + inp[r][..., None] * bmat[r][:, None, None, :])
-        y = y.at[r].set(jnp.sum(s * cmat[r][:, None, None, :], axis=-1
-                                ).reshape(h, p))
-        return jax.lax.dynamic_update_slice(pool, s.reshape(shape), at), y
-
-    pool, y = jax.lax.fori_loop(0, b, row, (pool, jnp.zeros_like(x)))
+    y, pool = ssd_state_update(pool, layer, slots,
+                               jnp.exp(delta * _decay2(params, name)),
+                               delta[..., None] * x, bmat, cmat)
     y = y + params[name + ".D"].astype(jnp.float32)[:, None] * x
     return _mamba2_out(spec, params, name, y, z), window[:, 1:], pool

@@ -625,11 +625,12 @@ FALCON_PAGE, FALCON_PAGES, FALCON_SLOTS = 128, 3073, 65
 def test_chip_compiles_the_parallel_mamba2_programs(one_chip, monkeypatch,
                                                     kind, size):
     """Pages and the Mamba-2 slots donated and aliased; a decode step
-    updates its rows' states where they lie (a gather of them would have
-    the compiler re-lay the whole slot pool, 1.09 GB a layer at four
-    layers: `mini-gather`); the grouped kernel reads 5 query heads a KV
-    head; the prefill's chunked scan is XLA's, in under a GB of
-    temporaries at the longest bucket."""
+    updates its rows' states where they lie, the kernel `ssd_state_update`
+    a layer (a gather of them would have the compiler re-lay the whole
+    slot pool, 1.09 GB a layer at four layers: `mini-gather`; a copy of
+    the pool beside the kernel would show in the temporaries); the grouped
+    kernel reads 5 query heads a KV head; the prefill's chunked scan is
+    XLA's, in under a GB of temporaries at the longest bucket."""
     monkeypatch.setattr(_device, "on_tpu", lambda: True)
     bf = jnp.bfloat16
 
@@ -667,9 +668,10 @@ def test_chip_compiles_the_parallel_mamba2_programs(one_chip, monkeypatch,
     if kind == "decode":
         # a step's temporaries: no slot pool, no layer of it
         assert mem.temp_size_in_bytes < slots / 20
-        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                              text)) == 2
-        assert "paged_attention_gqa" in text
+        calls = re.findall(r"%(\w+?)_*\.\d+ = [^\n]*custom_call_target="
+                           r"\"tpu_custom_call\"", text)
+        assert sorted(calls) == ["paged_attention_gqa"] * 2 + [
+            "ssd_state_update"] * 2, calls
     else:
         assert mem.temp_size_in_bytes < 1 << 30
         assert "tpu_custom_call" not in text

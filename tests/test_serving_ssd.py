@@ -13,6 +13,10 @@ for speed (chunks of 16, pages of 4, buckets to 64): hidden 64, 10 query /
  - the chunked scan against the sequential recurrence, state and y;
  - rows of unlike lengths in one decode bucket bit-identical to each row
    alone; a slot released and taken again;
+ - the decode state update's Pallas kernel (interpret mode) against its
+   twin, the loop over the rows: ``y``, the rows' slots, slots no row owns,
+   padding rows on the null slot, the whole Mamba-2 branch, the dispatch
+   counter;
  - ``ModelSpec``'s explicit FFN width and ``StateSlots``' convolution
    channels; the counters and ``/healthz``; ``costs_ssd.py`` against hand
    arithmetic; the cell's rehearsal on the CPU.
@@ -258,6 +262,113 @@ def test_the_counters_and_healthz(built):
                                 labelnames=("state",)).snapshot_values()
     assert held[("held",)] == 0
     assert held[("free",)] == state["free"] * state["slot_bytes"]
+
+
+def _update_operands(rows, slots=70, layers=4, seed=0, h=4, p=16, n=32, g=2):
+    """A slot pool and one decode step's update inputs at the tiny widths;
+    row r owns slot ``taken[r]`` (none owns the null slot 0)."""
+    rng = np.random.RandomState(seed + rows)
+    pool = rng.randn(layers, slots, h, p, n).astype(np.float32)
+    taken = (rng.permutation(slots - 1)[:rows] + 1).astype(np.int32)
+    decay = rng.uniform(0.5, 1.0, (rows, h)).astype(np.float32)
+    dx = rng.randn(rows, h, p).astype(np.float32)
+    bmat, cmat = (rng.randn(rows, g, n).astype(np.float32) for _ in "bc")
+    return pool, taken, decay, dx, bmat, cmat
+
+
+def _update(pool, layer, taken, *ins, use_pallas):
+    from paddle_tpu.ops.ssd_update import ssd_state_update
+    y, out = ssd_state_update(jnp.asarray(pool), layer, jnp.asarray(taken),
+                              *map(jnp.asarray, ins), use_pallas=use_pallas,
+                              interpret=True)
+    return np.asarray(y), np.asarray(out)
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_the_state_update_kernel_is_the_loop(rows):
+    """The Pallas kernel (interpret mode) against its twin, a loop over the
+    rows, at the decode buckets 8 and 64: `y` and every slot a row owns;
+    every other slot of every layer comes out as it went in."""
+    pool, taken, *ins = _update_operands(rows)
+    y, got = _update(pool, 2, taken, *ins, use_pallas=True)
+    want_y, want = _update(pool, 2, taken, *ins, use_pallas=False)
+    np.testing.assert_allclose(y, want_y, rtol=1e-6,
+                               atol=1e-6 * np.abs(want_y).max())
+    np.testing.assert_allclose(got[2, taken], want[2, taken], rtol=1e-6,
+                               atol=1e-6 * np.abs(want[2, taken]).max())
+    assert not np.array_equal(got[2, taken], pool[2, taken])
+    untouched = np.ones(pool.shape[:2], bool)
+    untouched[2, taken] = False
+    np.testing.assert_array_equal(got[untouched], pool[untouched])
+
+
+def test_padding_rows_on_the_null_slot_leave_live_slots_alone():
+    """A bucket's padding rows point at slot 0: the live rows' slots and
+    outputs are those of the live rows updated alone, and no slot but
+    theirs and the null slot moves."""
+    pool, taken, *ins = _update_operands(8, seed=3)
+    padded = taken.copy()
+    padded[5:] = 0
+    y, got = _update(pool, 1, padded, *ins, use_pallas=True)
+    alone_y, alone = _update(pool, 1, taken[:5], *(a[:5] for a in ins),
+                             use_pallas=True)
+    np.testing.assert_array_equal(y[:5], alone_y)
+    np.testing.assert_array_equal(got[1, taken[:5]], alone[1, taken[:5]])
+    untouched = np.ones(pool.shape[:2], bool)
+    untouched[1, taken[:5]] = untouched[1, 0] = False
+    np.testing.assert_array_equal(got[untouched], pool[untouched])
+
+
+def test_mamba2_decode_takes_the_kernel_as_it_took_the_loop(config, runner,
+                                                            monkeypatch):
+    """The whole Mamba-2 branch of a decode step with the kernel forced
+    (interpret mode, as on the chip) against the twin: outputs, tails and
+    pool."""
+    from paddle_tpu.framework import device as _device
+    spec = runner.spec_from_config(config)
+    params = init_params(spec, 11)
+    rng = np.random.RandomState(11)
+    rows = 8
+    pool, taken, *_ = _update_operands(rows, h=spec.ssm_heads,
+                                       p=spec.ssm_inner // spec.ssm_heads,
+                                       n=spec.ssm_state)
+    n = jnp.asarray(rng.randn(rows, spec.hidden).astype(np.float32))
+    tail = jnp.asarray(rng.randn(rows, spec.ssm_conv - 1,
+                                 spec.ssm_conv_channels).astype(np.float32))
+    got = {}
+    for on_chip in (True, False):
+        monkeypatch.setattr(_device, "pallas_dispatch", lambda: on_chip)
+        got[on_chip] = [np.asarray(a) for a in ssm.mamba2_decode(
+            spec, params, "h1.ssm", n, tail, jnp.asarray(pool), 1,
+            jnp.asarray(taken))]
+    for kernel, loop in zip(got[True], got[False]):
+        np.testing.assert_allclose(kernel, loop, rtol=1e-6,
+                                   atol=1e-6 * np.abs(loop).max())
+    assert not np.array_equal(got[True][2], pool)
+
+
+@pytest.mark.parametrize("on_chip,path", [(True, "pallas"),
+                                          (False, "fallback")])
+def test_the_state_update_books_its_dispatch(monkeypatch, on_chip, path):
+    from paddle_tpu.framework import device as _device
+    from paddle_tpu.observability.metrics import get_registry, \
+        reset_registry
+    from paddle_tpu.observability.telemetry import get_telemetry
+    from paddle_tpu.ops.ssd_update import ssd_state_update
+    monkeypatch.setattr(_device, "pallas_dispatch", lambda: on_chip)
+    monkeypatch.setattr(get_telemetry(), "enabled", True)
+    pool, taken, *ins = _update_operands(8)
+    reset_registry()
+    try:
+        ssd_state_update(jnp.asarray(pool), 0, jnp.asarray(taken),
+                         *map(jnp.asarray, ins))
+        c = get_registry().counter("pt_pallas_calls_total",
+                                   labelnames=("kernel", "path"))
+        booked = {p: c.value(kernel="ssd_state_update", path=p)
+                  for p in ("pallas", "fallback")}
+    finally:
+        reset_registry()
+    assert booked == {p: int(p == path) for p in booked}
 
 
 def test_costs_ssd_by_hand(runner, config):
