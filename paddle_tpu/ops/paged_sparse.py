@@ -8,11 +8,17 @@ alone.
               ties to the lower position
     o_t     = softmax_{s in S_t}(q_t . k_s / sqrt(D)) v_s
 
-Three payloads a token a layer live in pages under one table: K and V
-``(L, P, ps, KVH*D)`` as every model has them, and the indexer's key in
+Three payloads a token a layer live in pages under one table.  K and V
+are **token-major**, one pool ``(L, P*ps, 2*KVH, D)`` in which a token is
+its KV heads' K rows and then their V rows: at Keye's widths (4 KV heads
+of 128, bfloat16) one whole ``(8, 128)`` tile, the unit a copy of a
+tiled array may move (:mod:`..serving.kv_cache`: only this model's pool
+is laid so; every other kernel walks pages).  The indexer's key lives in
 the **index pool** ``(L, P, DI, ps)``: a page is ``DI`` rows of ``ps``
 lanes (the key transposed, so that a 64-lane key wastes no lane and a
-page's scores are one ``(J, DI) x (DI, ps)`` matmul).
+page's scores are one ``(J, DI) x (DI, ps)`` matmul).  Position ``t`` of
+a row is page ``table[t // ps]`` of the index pool and token ``table[t
+// ps] * ps + t % ps`` of the K and V pool.
 
  - :func:`paged_index_scores` -- decode: one query a row against every
    cached indexer key of its row.  The Pallas kernel walks the chunk
@@ -29,16 +35,17 @@ page's scores are one ``(J, DI) x (DI, ps)`` matmul).
    token addresses ``page * ps + slot`` in position order, the mask
    turned into a list by one-hot matmuls (no sort, gather or scatter).
  - :func:`paged_attention_sparse` -- decode: grouped-query attention
-   over the listed tokens and no others.  Each listed token's K row and
-   V row (all KV heads of it) is gathered from where it lies by its
-   address; the Pallas kernel takes the gathered rows a tile at a time
-   (two deep, Pallas' own pipeline), the ``G`` query heads of a KV head
-   against the tile on the MXU with an online softmax in float32, as
-   ``_gqa_kernel`` does a chunk.  The gather is XLA's and not copies the
-   kernel starts by hand: Mosaic refuses a copy of fewer than 8 rows of
-   a tiled array ("Slice shape along dimension 1 must be aligned to
-   tiling (8)"), and in a bfloat16 pool a token's lanes interleave with
-   its neighbour's 16 bits at a time (PERF.md section 7, PR 34).
+   over the listed tokens and no others.  The Pallas kernel copies each
+   listed token (its K and V together, one tile) from the pool in HBM by
+   its address into a two-deep VMEM buffer, a grid step's copies started
+   by the step before, and attends a tile of them with an online softmax
+   in float32: every query head against all the tile's rows on the MXU,
+   each head's scores kept on its own KV head's K rows.  Nothing of the
+   pool is gathered into a copy first: each listed token's bytes cross
+   HBM once.  (A page-major pool, a token a sub-tile row of its page,
+   cannot be read so: Mosaic refuses a copy of fewer than 8 rows of a
+   tiled array, "Slice shape along dimension 1 must be aligned to tiling
+   (8)"; PERF.md section 7.)
 
 Dispatch is by platform as everywhere (:func:`framework.device.
 pallas_dispatch`), booked on ``pt_pallas_calls_total{kernel=
@@ -392,17 +399,26 @@ def select_tokens(scores, lengths, page_tables, *, topk, page_size,
 # decode: attention over the listed tokens
 # ---------------------------------------------------------------------------
 
-def paged_attention_sparse_reference(q, k_pool, v_pool, addresses, counts,
-                                     *, layer, sm_scale=None):
-    """XLA twin: gather the listed tokens' rows, masked softmax over the
-    first ``counts`` of them, grouped heads; a row that lists nothing
-    gets zeros."""
+def _listed_tokens(kv_pool, layer, addresses):
+    """The listed tokens of one layer of a token-major pool, ``(B, K,
+    2*KVH, D)``: a gather by token address, the layer inside the address
+    (a slice of one layer would be a copy of it)."""
+    layers, tokens, rows, d = kv_pool.shape
+    return kv_pool.reshape(layers * tokens, rows, d)[
+        layer * tokens + addresses]
+
+
+def paged_attention_sparse_reference(q, kv_pool, addresses, counts, *,
+                                     layer, sm_scale=None):
+    """XLA twin: gather the listed tokens, masked softmax over the first
+    ``counts`` of them, grouped heads; a row that lists nothing gets
+    zeros."""
     b, h, d = q.shape
-    kvh = k_pool.shape[3] // d
+    kvh = kv_pool.shape[2] // 2
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    k = _listed_rows(k_pool, layer, addresses).reshape(b, -1, kvh, d)
-    v = _listed_rows(v_pool, layer, addresses).reshape(b, -1, kvh, d)
+    kv = _listed_tokens(kv_pool, layer, addresses)
+    k, v = kv[:, :, :kvh], kv[:, :, kvh:]
     s = jnp.einsum("bkgd,bckd->bkgc", q.reshape(b, kvh, h // kvh, d), k,
                    preferred_element_type=jnp.float32) * sm_scale
     seen = jnp.arange(addresses.shape[1])[None, :] < counts[:, None]
@@ -413,123 +429,181 @@ def paged_attention_sparse_reference(q, k_pool, v_pool, addresses, counts,
     return jnp.where(counts[:, None, None] > 0, o, 0.0).astype(q.dtype)
 
 
-_SPARSE_TILE = 512      # listed tokens a tile of the kernel
+# listed tokens a grid step copies and attends: one layer at Keye's cell
+# shapes on a TPU v5e 0.405 / 0.354 / 0.317 / 0.332 ms at 256 / 512 /
+# 1,024 / 2,048 (exp/bench_sparse_gather.py)
+_SPARSE_TILE = 1024
+_COPIES_UNROLLED = 8    # copies started a loop iteration
 
 
-def _sparse_kernel(count_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, tile, kvh, d, sm_scale):
-    """One tile of one row's listed tokens: a KV head its G query heads
-    (padded to a bf16 tile of rows) against the tile's ``(tile, D)`` lanes
-    on the MXU, one online-softmax update in float32 a tile, as
-    ``_gqa_kernel`` does a chunk.  A tile wholly past the row's count
-    computes nothing; a row that lists nothing gets zeros."""
+def _attend_tile(q, kv, held, m_scr, l_scr, acc_scr, *, kvh, grp,
+                 sm_scale):
+    """One online-softmax update in float32 of every query head ``q``
+    ``(HP, D)`` over a tile of tokens ``kv`` ``(tile, 2*KVH, D)``, of
+    which the first ``held`` count.  All the tile's rows are one operand
+    of the MXU: a head's scores are kept on the rows of its own KV head's
+    K, and its probabilities, rolled ``KVH`` rows on, meet the same
+    token's V in the second matmul.  (A strided load of one head's rows
+    would unpack the bfloat16 pairs; the matmuls 2*KVH times wider than
+    one head's are 6.4 GFLOP a layer at Keye's shapes, 33 µs at the
+    MXU's peak.)"""
+    tile, rows, d = kv.shape
+    kv = kv.reshape(tile * rows, d)
+    prec = _mxu_precision(kv.dtype)
+    s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())), precision=prec,
+                            preferred_element_type=jnp.float32) * sm_scale
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    own = (col % rows == head // grp) & (col // rows < held)
+    s = jnp.where(own, s, _NEG_INF)
+    m_prev, l_prev = m_scr[...], l_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(own, jnp.exp(s - m_new[:, :1]), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[...] = m_new
+    pv = jnp.dot(pltpu.roll(p, kvh, 1).astype(kv.dtype), kv, precision=prec,
+                 preferred_element_type=jnp.float32)
+    acc_scr[...] = acc_scr[...] * alpha[:, :1] + pv
+
+
+def _sparse_kernel(at_ref, count_ref, q_ref, pool_hbm, o_ref, buf, sem,
+                   m_scr, l_scr, acc_scr, *, kvh, grp, sm_scale):
+    """Grid step ``(row, tile)``: the next step's listed tokens are copied
+    from the pool (HBM) into the other half of a two-deep VMEM buffer, a
+    token (its K and V, one whole tile of the pool) a copy, then this
+    step's are waited for and attended.  Tokens past a row's count are
+    not copied, and a tile wholly past it computes nothing; a row that
+    lists nothing gets zeros."""
+    tile = buf.shape[1]
+    tiles = pl.num_programs(1)
     t = pl.program_id(1)
-    count = count_ref[pl.program_id(0)]
+    step = pl.program_id(0) * tiles + t
+
+    def held(s):
+        return jnp.clip(count_ref[s // tiles] - s % tiles * tile, 0, tile)
+
+    def copy(s, j):
+        return pltpu.make_async_copy(pool_hbm.at[at_ref[s * tile + j]],
+                                     buf.at[s % 2, j], sem.at[s % 2])
+
+    def start(s):
+        n = held(s)
+
+        def unrolled(c, carry):
+            for u in range(_COPIES_UNROLLED):
+                copy(s, c * _COPIES_UNROLLED + u).start()
+            return carry
+
+        def one(j, carry):
+            copy(s, j).start()
+            return carry
+
+        jax.lax.fori_loop(0, n // _COPIES_UNROLLED, unrolled, 0)
+        jax.lax.fori_loop(n // _COPIES_UNROLLED * _COPIES_UNROLLED, n, one,
+                          0)
+
+    @pl.when(step == 0)
+    def _first():
+        # a part tile's weights past its count are zeros, and zeros times
+        # what an earlier tile left there are zeros: never uninitialized
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        start(0)
+
+    @pl.when(step + 1 < pl.num_programs(0) * tiles)
+    def _next():
+        start(step + 1)
 
     @pl.when(t == 0)
     def _init():
         _init_scratch(m_scr, l_scr, acc_scr)
 
-    @pl.when(t * tile < count)
+    n = held(step)
+
+    @pl.when(n == tile)
+    def _whole():       # one wait for the tile's bytes
+        pltpu.make_async_copy(pool_hbm.at[pl.ds(0, tile)], buf.at[step % 2],
+                              sem.at[step % 2]).wait()
+
+    @pl.when((n > 0) & (n < tile))
+    def _part():
+        def one(j, carry):
+            copy(step, j).wait()
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(n > 0)
     def _tile():
-        prec = _mxu_precision(k_ref.dtype)
-        valid = (t * tile + jax.lax.broadcasted_iota(
-            jnp.int32, (q_ref.shape[1], tile), 1)) < count
-        for j in range(kvh):
-            q = q_ref[j]                                      # (GP, D)
-            k = k_ref[:, j * d:(j + 1) * d]                   # (tile, D)
-            v = v_ref[:, j * d:(j + 1) * d]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), precision=prec,
-                preferred_element_type=jnp.float32) * sm_scale
-            s = jnp.where(valid, s, _NEG_INF)
-            m_prev, l_prev = m_scr[j], l_scr[j]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.where(valid, jnp.exp(s - m_new[:, :1]), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_scr[j] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            m_scr[j] = m_new
-            pv = jnp.dot(p.astype(v.dtype), v, precision=prec,
-                         preferred_element_type=jnp.float32)
-            acc_scr[j] = acc_scr[j] * alpha[:, :1] + pv
+        _attend_tile(q_ref[...], buf[step % 2], n, m_scr, l_scr, acc_scr,
+                     kvh=kvh, grp=grp, sm_scale=sm_scale)
 
-    @pl.when(t == pl.num_programs(1) - 1)
+    @pl.when(t == tiles - 1)
     def _fin():
-        for j in range(kvh):
-            l = l_scr[j][:, :1]
-            o_ref[j] = (acc_scr[j] / jnp.where(l == 0.0, 1.0, l)
-                        ).astype(o_ref.dtype)
-
-
-def _listed_rows(pool, layer, addresses):
-    """The listed tokens' rows of one layer, ``(B, K, KVH*D)``: a gather
-    by token address over pages and slots as one axis."""
-    layers, pages, ps, lanes = pool.shape
-    # the layer inside the address: a slice of one layer would be a copy
-    return pool.reshape(layers * pages * ps, lanes)[
-        layer * (pages * ps) + addresses]
+        l = l_scr[...][:, :1]
+        o_ref[...] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
+                      ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _paged_attention_sparse_pallas(q, k_pool, v_pool, addresses, counts,
-                                   layer, *, sm_scale, interpret):
+def _paged_attention_sparse_pallas(q, kv_pool, addresses, counts, layer, *,
+                                   sm_scale, interpret):
     b, h, d = q.shape
-    lanes = k_pool.shape[3]
-    kvh = lanes // d
-    grp = h // kvh
-    gp = -(-grp // 16) * 16         # a bf16 tile of rows a KV head
+    layers, tokens, rows, _ = kv_pool.shape
+    kvh = rows // 2
+    hp = -(-h // 16) * 16           # a bf16 tile of query rows
     listed = addresses.shape[1]
     tile = min(_SPARSE_TILE, listed)
     if listed % tile:
         raise ValueError(f"{listed} listed tokens are no whole tiles of "
                          f"{tile}")
-    qg = jnp.pad(q.reshape(b, kvh, grp, d),
-                 ((0, 0), (0, 0), (0, gp - grp), (0, 0)))
-    row_spec = pl.BlockSpec((None, kvh, gp, d),
-                            lambda i, t, *_: (i, 0, 0, 0))
-    # a tile past the row's count is the row's last live tile again: the
-    # block stays where it is and nothing is fetched for it
-    tile_spec = pl.BlockSpec(
-        (None, tile, lanes), lambda i, t, counts: (
-            i, jnp.minimum(t, jnp.maximum(counts[i] - 1, 0) // tile), 0))
+    # a token's row of the pool with its layers as one axis, in range
+    # whatever the list holds: the kernel's copies are not bounds-checked
+    at = layer * tokens + jnp.clip(addresses, 0, tokens - 1)
+    row_spec = pl.BlockSpec((None, hp, d), lambda i, t, *_: (i, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_sparse_kernel, tile=tile, kvh=kvh, d=d,
+        functools.partial(_sparse_kernel, kvh=kvh, grp=h // kvh,
                           sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, listed // tile),
-            in_specs=[row_spec, tile_spec, tile_spec],
+            in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=row_spec,
             scratch_shapes=[
-                pltpu.VMEM((kvh, gp, _LANES), jnp.float32),
-                pltpu.VMEM((kvh, gp, _LANES), jnp.float32),
-                pltpu.VMEM((kvh, gp, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, gp, d), q.dtype),
+                pltpu.VMEM((2, tile, rows, d), kv_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((hp, _LANES), jnp.float32),
+                pltpu.VMEM((hp, _LANES), jnp.float32),
+                pltpu.VMEM((hp, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, hp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            # a step starts the next one's copies: the grid runs in order
+            dimension_semantics=("arbitrary", "arbitrary"),
+            disable_bounds_checks=True),
         name="paged_attention_sparse",
         interpret=interpret,
-    )(counts.astype(jnp.int32), qg, _listed_rows(k_pool, layer, addresses),
-      _listed_rows(v_pool, layer, addresses))
-    return out[:, :, :grp].reshape(b, h, d)
+    )(at.reshape(-1).astype(jnp.int32), counts.astype(jnp.int32),
+      jnp.pad(q, ((0, 0), (0, hp - h), (0, 0))),
+      kv_pool.reshape(layers * tokens, rows, d))
+    return out[:, :h]
 
 
-def paged_attention_sparse(q, k_pool, v_pool, addresses, counts, *, layer,
+def paged_attention_sparse(q, kv_pool, addresses, counts, *, layer,
                            sm_scale=None, use_pallas=None, interpret=None):
     """Grouped-query attention of one query a row over the tokens its
-    list names and no others: ``q`` (B, H, D), pools ``(L, P, ps,
-    KVH*D)``, ``addresses`` (B, K) int32 token addresses ``page * ps +
-    slot``, ``counts`` (B,) how many of a row's entries count (0: the row
-    holds nothing and gets zeros).  Exact: an online softmax in float32
-    over every listed token."""
+    list names and no others: ``q`` (B, H, D), the token-major pool
+    ``(L, P*ps, 2*KVH, D)`` (a token's K heads, then its V heads),
+    ``addresses`` (B, K) int32 token addresses ``page * ps + slot``,
+    ``counts`` (B,) how many of a row's entries count (0: the row holds
+    nothing and gets zeros).  Exact: an online softmax in float32 over
+    every listed token."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     use_pallas, interpret = _dispatch("paged_attention_sparse", use_pallas,
                                       interpret)
     if not use_pallas:
         return paged_attention_sparse_reference(
-            q, k_pool, v_pool, addresses, counts, layer=layer,
-            sm_scale=sm_scale)
+            q, kv_pool, addresses, counts, layer=layer, sm_scale=sm_scale)
     return _paged_attention_sparse_pallas(
-        q, k_pool, v_pool, addresses, counts, layer, sm_scale=sm_scale,
+        q, kv_pool, addresses, counts, layer, sm_scale=sm_scale,
         interpret=interpret)

@@ -431,7 +431,7 @@ class ServingEngine:
         ps = cfg.page_size
         int8 = cfg.precision == "int8"
         p_struct = _struct_like(self._params)
-        k_struct = _struct_like(self.pool.k_pool)
+        k_struct = _struct_like(self.pool.state()[0])
         s_struct = _struct_like(self.pool.k_scale) if int8 else None
         i32 = np.int32
         # fp32 keeps its PR 15 program names so audit/bench baselines
@@ -444,10 +444,12 @@ class ServingEngine:
         if self.pool.state_slots is not None \
                 or self.pool.index_pool is not None:
             # every pool the model has is donated state, in the order of
-            # PagePool.state(): the full layers', the index pool, the
-            # sliding layers' where there are any, the state-space
-            # layers' two last
-            names = ("k_pool", "v_pool") + (
+            # PagePool.state(): the full layers' (one token-major pool of
+            # K and V beside an index pool), the index pool, the sliding
+            # layers' where there are any, the state-space layers' two last
+            kv = 1 if self.pool.token_major else 2
+            names = ("kv_pool",) if kv == 1 else ("k_pool", "v_pool")
+            names += (
                 ("index_pool",) if self.pool.index_pool is not None else ()
             ) + (("kw_pool", "vw_pool") if self.pool.window_pool else ()
                  ) + (("conv_pool", "ssm_pool")
@@ -455,16 +457,18 @@ class ServingEngine:
             n_state = len(names)
 
             def serve_prefill(params, *args):
-                k_pool, v_pool, *rest = args[:n_state]
+                k_pool, v_pool = (*args[:kv], None)[:2]
                 return prefill_step(spec, params, k_pool, v_pool,
                                     *args[n_state:], page_size=ps,
-                                    **dict(zip(names[2:], rest)))
+                                    **dict(zip(names[kv:],
+                                               args[kv:n_state])))
 
             def serve_decode(params, *args, **kw):
-                k_pool, v_pool, *rest = args[:n_state]
+                k_pool, v_pool = (*args[:kv], None)[:2]
                 return decode_step(spec, params, k_pool, v_pool,
                                    *args[n_state:], page_size=ps,
-                                   **dict(zip(names[2:], rest)), **kw)
+                                   **dict(zip(names[kv:], args[kv:n_state])),
+                                   **kw)
 
             def serve_decode_selection(params, *args):
                 return serve_decode(params, *args, selection=True)
@@ -702,7 +706,7 @@ class ServingEngine:
                           np.zeros((b,), np.int32),
                           np.zeros((b, *self.table_shape), np.int32))
         self._aux = None
-        jax.block_until_ready(self.pool.k_pool)
+        jax.block_until_ready(self.pool.state())
 
     def _arm_sentinel(self) -> None:
         """After this point, ANY observed compile is a request-path

@@ -38,8 +38,17 @@ A third payload a page (``index_dim`` > 0): a model of learned sparse
 attention keeps, beside K and V, its indexer's key of every token —
 ``pool.index_pool`` ``(L, P, index_dim, ps)``, a page the keys of its
 ``ps`` tokens transposed (:mod:`..ops.paged_sparse`).  It is page for
-page with the K and V pools: the one table a row addresses all three,
-and a page allocated, reserved or returned is a page of all three.
+page with K and V: the one table a row addresses both, and a page
+allocated, reserved or returned is a page of both.  Such a model's K and
+V are **token-major**, one pool ``pool.kv_pool`` ``(L, P*ps, 2*KVH, D)``
+in which a token is its K heads and then its V heads — at Keye's widths
+one ``(8, 128)`` bfloat16 tile, so that its attention kernel copies each
+token it selected straight from the pool (a copy of one row of a tiled
+page is refused).  Every other kernel walks whole pages and keeps the
+page-major pools; position ``t`` still lives in page ``table[t // ps]``,
+at token ``page * ps + t % ps`` of the pool.  ``pool.k_pool[layer,
+page]`` / ``pool.v_pool[...]`` read such a pool's pages as a page-major
+pool would hold them, gathering only the pages asked for.
 
 A latent cache (``latent``: multi-head latent attention): a token's page
 row is no heads' K and V but its latent, in ``k_pool`` ``(L, P, ps, R)``,
@@ -60,11 +69,15 @@ provider so the memory census names the pools ``kv::k_pages`` /
 """
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..ops.paged_sparse import _listed_tokens
 
 __all__ = ["PagePool", "RowPages", "StateSlots", "KVPoolExhausted",
            "NULL_PAGE", "kv_page_budget", "pool_shapes"]
@@ -73,7 +86,8 @@ NULL_PAGE = 0
 
 
 def pool_shapes(layers: int, pages: int, page_size: int, heads: int,
-                head_dim: int, latent: Tuple[int, int] = ()):
+                head_dim: int, latent: Tuple[int, int] = (),
+                token_major: bool = False):
     """``(value_shape, scale_shape)`` of the K/V pools: values
     ``(L, P, ps, H*D)``, the int8 pools' per-(token, head) f32 scales
     ``(L, P, ps, H)``.  Position ``t`` of a sequence lives at
@@ -82,7 +96,12 @@ def pool_shapes(layers: int, pages: int, page_size: int, heads: int,
     cache (multi-head latent attention: ``latent = (R, D_r)``, the
     latent's width and its rotary-free key's) has no scales and no heads:
     ``(latent_shape (L, P, ps, R), key_shape (L, P, ps, D_r))``, the
-    shapes of ``k_pool`` and ``v_pool``."""
+    shapes of ``k_pool`` and ``v_pool``.  ``token_major`` (learned sparse
+    attention, whose kernel copies a token at a time: module docstring):
+    ``((L, P*ps, 2*H, D), None)``, one pool of K and V, position ``t`` at
+    token ``page_table[t // ps] * ps + t % ps``."""
+    if token_major:
+        return (layers, pages * page_size, 2 * heads, head_dim), None
     if latent:
         return tuple((layers, pages, page_size, w) for w in latent)
     return ((layers, pages, page_size, heads * head_dim),
@@ -222,10 +241,39 @@ class StateSlots:
             pass
 
 
+@functools.partial(jax.jit, static_argnames=("page_size", "half"))
+def _read_pages(kv_pool, layer, page, *, page_size, half):
+    """K (``half`` 0) or V (1) of pages ``page`` of layers ``layer`` (index
+    arrays that broadcast) of a token-major pool, ``(..., ps, KVH*D)``: one
+    program, the layer inside the token's index, so that nothing of the
+    pool is copied (an eager slice or reshape of it would be)."""
+    heads = kv_pool.shape[2] // 2
+    got = _listed_tokens(kv_pool, layer[..., None],
+                         page[..., None] * page_size + jnp.arange(page_size))
+    got = got[..., half * heads:(half + 1) * heads, :]
+    return got.reshape(*got.shape[:-2], heads * kv_pool.shape[3])
+
+
+class _PageMajorView:
+    """K (``half`` 0) or V (1) of a token-major pool as a page-major pool
+    reads: ``view[layer, page]`` is ``(..., ps, KVH*D)``, gathered from the
+    pages asked for alone."""
+
+    def __init__(self, pool: "PagePool", half: int):
+        self._pool, self._half = pool, half
+
+    def __getitem__(self, at):
+        layer, page = (jnp.asarray(a, jnp.int32) for a in at)
+        return _read_pages(self._pool.kv_pool, layer, page,
+                           page_size=self._pool.page_size, half=self._half)
+
+
 class PagePool:
     """Block-pool allocator over the serve KV arrays (``k_pool`` /
     ``v_pool`` ``(L, P, ps, H*D)``, and ``k_scale`` / ``v_scale``
-    ``(L, P, ps, H)`` beside an int8 pool).
+    ``(L, P, ps, H)`` beside an int8 pool; beside an index pool, the one
+    token-major ``kv_pool`` ``(L, P*ps, 2*H, D)``, with ``k_pool`` /
+    ``v_pool`` its page-major views: module docstring).
 
     Thread-safety: all bookkeeping is lock-guarded; the device arrays
     themselves are only rebound from the engine's step loop.
@@ -287,18 +335,26 @@ class PagePool:
         if self.latent and (scale_pages or window_layers or index_dim):
             raise ValueError("a latent cache has no int8, sliding or "
                              "index pool")
+        if index_dim and scale_pages:
+            raise ValueError("an int8 pool has no index pool")
+        # learned sparse attention (an index pool) copies a token at a
+        # time: its K and V are one token-major pool
+        self.token_major = bool(index_dim)
         shape, sshape = pool_shapes(layers, pages, page_size, heads,
-                                    head_dim, self.latent)
-        self.k_pool = jnp.zeros(shape, dtype)
-        self.v_pool = jnp.zeros(sshape if self.latent else shape, dtype)
+                                    head_dim, self.latent, self.token_major)
+        if self.token_major:
+            self.kv_pool = jnp.zeros(shape, dtype)
+            self.k_pool, self.v_pool = (_PageMajorView(self, h)
+                                        for h in (0, 1))
+        else:
+            self.k_pool = jnp.zeros(shape, dtype)
+            self.v_pool = jnp.zeros(sshape if self.latent else shape, dtype)
         self.k_scale = jnp.zeros(sshape, jnp.float32) \
             if self.scale_pages else None
         self.v_scale = jnp.zeros(sshape, jnp.float32) \
             if self.scale_pages else None
         # the indexer's keys (learned sparse attention), page for page
         # with K and V: a page is (index_dim, page_size)
-        if index_dim and scale_pages:
-            raise ValueError("an int8 pool has no index pool")
         self.index_pool = jnp.zeros(
             (layers, pages, index_dim, page_size), dtype) \
             if index_dim else None
@@ -500,13 +556,15 @@ class PagePool:
 
     # -- device state -------------------------------------------------------
 
-    def swap(self, k_pool, v_pool, *rest) -> None:
+    def swap(self, *pools) -> None:
         """Rebind the pools to a program's donated outputs, in program
-        order: the value pools, then the scale pools of a quantized
-        pool, or the index pool, or the sliding layers' two pools, then
-        the state-space layers' two."""
-        self.k_pool = k_pool
-        self.v_pool = v_pool
+        order: the value pools (the token-major pool), then the scale
+        pools of a quantized pool, or the index pool, or the sliding
+        layers' two pools, then the state-space layers' two."""
+        if self.token_major:
+            self.kv_pool, *rest = pools
+        else:
+            self.k_pool, self.v_pool, *rest = pools
         if self.index_pool is not None:
             if not rest:
                 raise ValueError("swap requires the index pool")
@@ -529,7 +587,8 @@ class PagePool:
     def state(self):
         """The donated arrays in program argument order (see
         :meth:`swap`)."""
-        state = (self.k_pool, self.v_pool)
+        state = ((self.kv_pool,) if self.token_major
+                 else (self.k_pool, self.v_pool))
         if self.scale_pages:
             state += (self.k_scale, self.v_scale)
         if self.index_pool is not None:
@@ -551,6 +610,8 @@ class PagePool:
             return {
                 "pages": self.pages,
                 "dtype": np.dtype(self.dtype).name,
+                "layout": "token_major" if self.token_major
+                else "page_major",
                 "scale_pages": self.scale_pages,
                 # pages of the index pool: the same pages, a third array
                 "index_pages": (self.pages if self.index_pool is not None
@@ -623,7 +684,8 @@ class PagePool:
         if self.kind == "window":
             return {"kv::k_window_pages": self.k_pool,
                     "kv::v_window_pages": self.v_pool}
-        named = {"kv::k_pages": self.k_pool, "kv::v_pages": self.v_pool}
+        named = ({"kv::kv_tokens": self.kv_pool} if self.token_major else
+                 {"kv::k_pages": self.k_pool, "kv::v_pages": self.v_pool})
         if self.scale_pages:
             named["kv::k_scales"] = self.k_scale
             named["kv::v_scales"] = self.v_scale

@@ -1091,7 +1091,10 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
       k_pool/v_pool: donated pools ``(Lg, P, ps, KVH*D)`` of the full
         layers (:func:`.kv_cache.pool_shapes`), written a whole page at
         a time; of a model of latent attention, the latents ``(Lg, P, ps,
-        R)`` and their keys' rotary-free part ``(Lg, P, ps, 128)``.
+        R)`` and their keys' rotary-free part ``(Lg, P, ps, 128)``; of a
+        model of learned sparse attention, ``k_pool`` the one token-major
+        pool ``(Lg, P*ps, 2*KVH, D)`` (a token's K heads, then its V
+        heads) and ``v_pool`` None.
       tokens: ``(S,)`` int32, padded prompt (bucket size S).
       length: scalar int32, true prompt length (1 <= length <= S).
       page_table: ``(max_pages,)`` int32 pages owned by this sequence
@@ -1193,6 +1196,14 @@ def prefill_step(spec: ModelSpec, params, k_pool, v_pool,
                             first, count)
             return
         li = spec.global_layers.index(i)
+        if spec.sparse_topk:
+            # the token-major pool as pages of ps whole tokens
+            layers, tokens, rows, d = k_pool.shape
+            pages = k_pool.reshape(layers, tokens // page_size, page_size,
+                                   rows, d)
+            k_pool = write(pages, li, jnp.concatenate([k, v], axis=1),
+                           tables[0], 0, n_pages).reshape(k_pool.shape)
+            return
         if quant:
             k, ksc = quantize_kv(k)
             v, vsc = quantize_kv(v)
@@ -1376,10 +1387,12 @@ def decode_walk(spec: ModelSpec, batch: int, k_pool, max_pages: int,
     read it) and, under ``"window"``, the same two of the list over the
     sliding layers' pool beside the window's ``"tokens"``.  ``None``
     where no list is walked (``ops.paged_attention.chunk_walk`` tells:
-    an int8 pool).  A model of latent attention walks its latent cache
-    (``k_pool`` and the keys' pool beside it) by
-    ``ops.paged_mla.mla_walk``."""
+    an int8 pool; learned sparse attention reads the tokens it listed).
+    A model of latent attention walks its latent cache (``k_pool`` and
+    the keys' pool beside it) by ``ops.paged_mla.mla_walk``."""
     heads, lanes = spec.heads, spec.head_dim
+    if spec.sparse_topk:            # a list of tokens, no list of pages
+        return None
     if spec.mla_layers:             # the latent cache's own kernel
         keys = jax.ShapeDtypeStruct(
             (*k_pool.shape[:3], spec.latent_lanes[1]), k_pool.dtype)
@@ -1423,7 +1436,9 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
       k_pool/v_pool: donated pools ``(Lg, P, ps, KVH*D)`` of the full
         layers; each layer writes the rows' new K/V at ``[layer, page,
         slot]`` (B rows of KVH*D lanes) and hands the pools whole to the
-        kernel.
+        kernel.  Learned sparse attention: ``k_pool`` the token-major
+        pool (:func:`prefill_step`), written at ``[layer, page * ps +
+        slot]``, and ``v_pool`` None.
       tokens: ``(B,)`` int32 current token per row.
       positions: ``(B,)`` int32 position of that token (0-based);
         padding rows point at position 0 with page_table row 0 so
@@ -1601,10 +1616,14 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                     v, vsc = quantize_kv(v)
                     k_scale = k_scale.at[li, page, slot].set(ksc)
                     v_scale = v_scale.at[li, page, slot].set(vsc)
-                k_pool = k_pool.at[li, page, slot].set(
-                    k.reshape(b, kvd).astype(k_pool.dtype))
-                v_pool = v_pool.at[li, page, slot].set(
-                    v.reshape(b, kvd).astype(v_pool.dtype))
+                if spec.sparse_topk:    # a token's K heads, then its V's
+                    k_pool = k_pool.at[li, page * page_size + slot].set(
+                        jnp.concatenate([k, v], axis=1).astype(k_pool.dtype))
+                else:
+                    k_pool = k_pool.at[li, page, slot].set(
+                        k.reshape(b, kvd).astype(k_pool.dtype))
+                    v_pool = v_pool.at[li, page, slot].set(
+                        v.reshape(b, kvd).astype(v_pool.dtype))
             if spec.sparse_topk:
                 with scope(f"layer{i}/index_write"):
                     x = _norm(spec, params, f"h{i}.ln1", h).astype(cdt)
@@ -1615,11 +1634,11 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                     scores = paged_index_scores(
                         *_index_queries(spec, params, i, x, rope),
                         index_pool, table, lengths, layer=li,
-                        steps=_pages_walked(k_pool.shape[1], b))
+                        steps=_pages_walked(index_pool.shape[1], b))
                 with scope(f"layer{i}/select"):
                     chosen, addresses, listed = select_tokens(
                         scores, lengths, table, topk=spec.sparse_topk,
-                        page_size=page_size, pool_pages=k_pool.shape[1])
+                        page_size=page_size, pool_pages=index_pool.shape[1])
                     selected.append((chosen, scores))
             with scope(_attn_scope(spec, i)):
                 if quant:
@@ -1629,8 +1648,8 @@ def decode_step(spec: ModelSpec, params, k_pool, v_pool,
                 elif spec.sparse_topk:
                     # a padding row lists nothing
                     o = paged_attention_sparse(
-                        q, k_pool, v_pool, addresses,
-                        jnp.where(live, listed, 0), layer=li)
+                        q, k_pool, addresses, jnp.where(live, listed, 0),
+                        layer=li)
                 else:
                     o = attend(q, k_pool, v_pool, table, li)
         with scope(f"layer{i}/attn_out"):

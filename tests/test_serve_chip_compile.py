@@ -778,6 +778,76 @@ def test_chip_compiles_the_kda_and_latent_programs(one_chip, monkeypatch,
         assert set(calls) == {"gmm"}, calls
 
 
+# -- Keye-VL-2.0-30B-A3B's programs at its published widths --------------------
+# (`benchmarks/configs/keye-vl2-30b-a3b-serve.json`): two of its learned
+# sparse attention layers, so a program compiles in seconds; the pools'
+# pages, page size, the largest decode bucket and the shortest prefill
+# bucket are the configuration's.
+
+KEYE_SPEC = ModelSpec(
+    vocab_size=151936, hidden=2048, layers=2, heads=32, kv_heads=4,
+    head_size=128, max_seq_len=40960, norm="rms", norm_eps=1e-6,
+    positions="rotary", rope_theta=1e7, ffn="moe", experts=128,
+    experts_per_token=8, expert_width=768, tie_head=False, qk_norm=True,
+    sparse_topk=2048, index_heads=16, index_head_size=64)
+KEYE_PAGE, KEYE_PAGES = 128, 5121
+
+
+@pytest.mark.parametrize("kind,size", [("decode", 24), ("prefill", 8192),
+                                       ("selection", 24)])
+def test_chip_compiles_the_sparse_programs_on_a_token_major_pool(
+        one_chip, monkeypatch, kind, size):
+    """K and V one token-major pool `(L, P*ps, 8, 128)` (a token one
+    (8, 128) bfloat16 tile) beside the index pool, both donated and
+    aliased, neither padded: a decode step writes its rows' tokens, scores
+    and selects, and `paged_attention_sparse` copies the listed tokens
+    from the pool where it lies — nothing of a layer of it is copied,
+    transposed or re-laid, and its temporaries are tens of MB; the prefill
+    writes whole pages of tokens in place."""
+    monkeypatch.setattr(_device, "on_tpu", lambda: True)
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(init_params, KEYE_SPEC, dtype=bf)))
+    kv, _ = pool_shapes(2, KEYE_PAGES, KEYE_PAGE, 4, 128, token_major=True)
+    state = [sds(kv, bf), sds((2, KEYE_PAGES, 64, KEYE_PAGE), bf)]
+    maxp = KEYE_SPEC.max_seq_len // KEYE_PAGE
+    step = prefill_step if kind == "prefill" else functools.partial(
+        decode_step, selection=kind == "selection")
+
+    def run(params, kv, index, a, b, c):
+        return step(KEYE_SPEC, params, kv, None, a, b, c,
+                    page_size=KEYE_PAGE, index_pool=index)
+
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    args = ((i32((size,)), i32(()), i32((maxp,))) if kind == "prefill"
+            else (i32((size,)), i32((size,)), i32((size, maxp))))
+    exe = jax.jit(run, donate_argnums=(1, 2)).lower(
+        params, *state, *args).compile()
+    text = exe.as_text()
+    mem = exe.memory_analysis()
+    pools = [int(np.prod(s.shape)) * 2 for s in state]
+    assert mem.alias_size_in_bytes >= sum(pools)
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 2
+    assert re.search(r"bf16\[2,655488,8,128\]\{3,2,1,0:T\(8,128\)\(2,1\)\}",
+                     text)
+    assert not _moved_pool_sized(text, kv[1] * 8 * 128)
+    assert "mini-gather" not in text
+    calls = re.findall(r"%(\w+?)_*\.\d+ = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    if kind != "prefill":
+        assert mem.temp_size_in_bytes < 64 << 20
+        assert sorted(calls) == ["moe_decode"] * 2 + [
+            "paged_attention_sparse"] * 2 + ["paged_index_scores"] * 2, calls
+    else:
+        assert mem.temp_size_in_bytes < 1 << 30
+
+
 def _mxu_fusions(text):
     """(scope, result shape, tiling) of every fusion of the entry that the
     compiler tiled for the matrix unit, sorted."""

@@ -43,7 +43,7 @@ from paddle_tpu.ops.paged_attention import (  # noqa: E402
 from paddle_tpu.serving import (ModelSpec, ServeConfig,  # noqa: E402
                                 ServingEngine, init_params)
 from paddle_tpu.serving import model as serving_model  # noqa: E402
-from paddle_tpu.serving.kv_cache import PagePool  # noqa: E402
+from paddle_tpu.serving.kv_cache import PagePool, pool_shapes  # noqa: E402
 
 CELL = "keyevl2-serve-longctx-reasoning-backlog"
 NEW_METRICS = ["indexer_ms_per_step", "attn_sparse_ms_per_step",
@@ -106,6 +106,17 @@ def _pools(rng, dtype, lengths, pages=40, max_pages=6, layers=2, kvh=2, d=16,
             jnp.asarray(np.maximum(lengths, 1)), lengths > 0)
 
 
+def _token_major(k_pool, v_pool):
+    """The token-major pool `(L, P*ps, 2*KVH, D)` that holds what the
+    page-major pools `(L, P, ps, KVH*D)` hold: a token's K heads, then its
+    V heads."""
+    layers, pages, ps, lanes = k_pool.shape
+    kvh = 2
+    return jnp.concatenate(
+        [p.reshape(layers, pages * ps, kvh, lanes // kvh)
+         for p in (k_pool, v_pool)], axis=2)
+
+
 LENGTHS = [3, TOPK, TOPK + 1, 40, 0, 9]     # under, at, over topk; padding
 
 
@@ -140,9 +151,10 @@ def test_the_sparse_kernel_equals_its_xla_twin(dtype, tol):
         scores, lengths, tables, topk=TOPK, page_size=PS, pool_pages=40)
     counts = jnp.where(jnp.asarray(live), counts, 0)
     q = jnp.asarray(rng.randn(len(LENGTHS), 4, 16), dtype)
+    kv_pool = _token_major(k_pool, v_pool)
     want = sparse.paged_attention_sparse_reference(
-        q, k_pool, v_pool, addresses, counts, layer=1)
-    got = sparse.paged_attention_sparse(q, k_pool, v_pool, addresses, counts,
+        q, kv_pool, addresses, counts, layer=1)
+    got = sparse.paged_attention_sparse(q, kv_pool, addresses, counts,
                                         layer=1, use_pallas=True,
                                         interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -168,9 +180,9 @@ def test_with_topk_over_every_length_the_sparse_read_is_the_full_read():
         scores, lengths, tables, topk=48, page_size=PS, pool_pages=40)
     assert counts.tolist() == [3, 17, 40, 48]
     q = jnp.asarray(rng.randn(4, 4, 16), jnp.float32)
-    got = sparse.paged_attention_sparse(q, k_pool, v_pool, addresses, counts,
-                                        layer=0, use_pallas=True,
-                                        interpret=True)
+    got = sparse.paged_attention_sparse(q, _token_major(k_pool, v_pool),
+                                        addresses, counts, layer=0,
+                                        use_pallas=True, interpret=True)
     full = paged_attention_reference(q, k_pool, v_pool, tables, lengths,
                                      layer=0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(full), atol=2e-5)
@@ -189,6 +201,7 @@ def test_a_row_alone_among_neighbours_and_in_another_bucket_is_bit_identical(
     qi = jnp.asarray(rng.randn(n, 4, 8), jnp.float32)
     wi = jnp.asarray(rng.randn(n, 4), jnp.float32)
     q = jnp.asarray(rng.randn(n, 4, 16), jnp.float32)
+    kv_pool = _token_major(k_pool, v_pool)
 
     def through(rows):
         rows = np.asarray(rows)
@@ -200,8 +213,8 @@ def test_a_row_alone_among_neighbours_and_in_another_bucket_is_bit_identical(
             scores, ln, t, topk=TOPK, page_size=PS, pool_pages=40)
         counts = jnp.where(jnp.asarray(rows != 4), counts, 0)
         out = sparse.paged_attention_sparse(
-            q[rows], k_pool, v_pool, addresses, counts, layer=1,
-            use_pallas=True, interpret=True)
+            q[rows], kv_pool, addresses, counts, layer=1, use_pallas=True,
+            interpret=True)
         at = list(rows).index(row)
         c = int(counts[at])
         return (np.asarray(scores[at, :LENGTHS[row]]),
@@ -211,6 +224,48 @@ def test_a_row_alone_among_neighbours_and_in_another_bucket_is_bit_identical(
     for rows in ([row, 4], [4, 5, row, 1]):
         for a, b in zip(want, through(rows)):
             np.testing.assert_array_equal(a, b)
+
+
+@pytest.fixture
+def tiles_of_eight(monkeypatch):
+    """The kernel at tiles of 8 listed tokens, so that a list of 32 is
+    four tiles; retraced before and after."""
+    monkeypatch.setattr(sparse, "_SPARSE_TILE", 8)
+    sparse._paged_attention_sparse_pallas.clear_cache()
+    yield
+    sparse._paged_attention_sparse_pallas.clear_cache()
+
+
+@pytest.mark.parametrize("counts", [[0, 0, 0], [5, 1, 7], [13, 21, 30],
+                                    [32, 32, 32], [32, 0, 9]],
+                         ids=["nothing", "under_a_tile", "part_tiles",
+                              "full", "mixed"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_the_kernel_copies_the_listed_tokens_of_a_token_major_pool(
+        tiles_of_eight, counts, layer):
+    """The kernel in interpret mode against its twin: lists of 32 tokens
+    drawn over every page of a three-layer pool, counts of nothing, under
+    a tile, of part tiles and of the whole list; what lies past a count
+    is never attended (NaN there would show)."""
+    rng = np.random.RandomState(20 + layer)
+    layers, tokens = 3, 40 * PS
+    kv_pool = rng.randn(layers, tokens, 4, 16).astype(np.float32)
+    addresses = np.sort(rng.randint(1, tokens, (3, 32)), axis=1)
+    counts = np.asarray(counts, np.int32)
+    listed = np.concatenate([a[:c] for a, c in zip(addresses, counts)])
+    kv_pool[layer, np.setdiff1d(np.arange(tokens), listed)] = np.nan
+    q = jnp.asarray(rng.randn(3, 4, 16), jnp.float32)
+    args = (q, jnp.asarray(kv_pool), jnp.asarray(addresses, jnp.int32),
+            jnp.asarray(counts))
+    got = np.asarray(sparse.paged_attention_sparse(
+        *args, layer=layer, use_pallas=True, interpret=True))
+    clean = np.nan_to_num(kv_pool)
+    want = np.asarray(sparse.paged_attention_sparse_reference(
+        q, jnp.asarray(clean), *args[2:], layer=layer))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    for b, c in enumerate(counts):
+        if c == 0:
+            assert not got[b].any()
 
 
 def test_the_prefill_scores_kernel_equals_its_xla_twin_and_skips_the_unseen():
@@ -430,10 +485,10 @@ def test_the_selection_program_is_the_decode_step_and_what_it_selected(built):
         lambda *a: serving_model.decode_step(
             spec, *a, page_size=engine.config.page_size,
             index_pool=engine.pool.index_pool),
-        engine._params, engine.pool.k_pool, engine.pool.v_pool,
+        engine._params, engine.pool.kv_pool, None,
         np.zeros((2,), np.int32), np.zeros((2,), np.int32),
         np.zeros((2, engine.max_pages_per_seq), np.int32))
-    assert len(outs) == 3 + 3       # three pools, tokens, logits, counts
+    assert len(outs) == 2 + 3       # two pools, tokens, logits, counts
 
 
 # -- the pool, the counters, the costs --------------------------------------------
@@ -442,8 +497,8 @@ def test_index_pages_are_allocated_reserved_and_returned_with_the_kv_pages():
     pool = PagePool(layers=2, pages=64, page_size=PS, heads=2, head_dim=16,
                     index_dim=8)
     assert pool.index_pool.shape == (2, 64, 8, PS)
-    assert [a.shape for a in pool.state()] == [
-        (2, 64, PS, 32), (2, 64, PS, 32), (2, 64, 8, PS)]
+    assert [a.shape for a in pool.state()] == [(2, 64 * PS, 4, 16),
+                                               (2, 64, 8, PS)]
     assert pool.snapshot()["index_pages"] == 64
     assert pool.table_shape(9) == (9,)      # one table addresses all three
     rng = np.random.RandomState(13)
@@ -466,14 +521,80 @@ def test_index_pages_are_allocated_reserved_and_returned_with_the_kv_pages():
         row.release()
     pool.check_consistency(expect_all_free=True)
     # the programs' outputs go back in the order they came
-    k, v, ix = pool.state()
-    pool.swap(k, v, np.ones(ix.shape, np.float32))
+    kv, ix = pool.state()
+    pool.swap(kv, np.ones(ix.shape, np.float32))
     assert float(pool.index_pool[0, 0, 0, 0]) == 1.0
     with pytest.raises(ValueError, match="index pool"):
-        pool.swap(k, v)
+        pool.swap(kv)
     with pytest.raises(ValueError, match="int8"):
         PagePool(layers=1, pages=4, page_size=4, heads=1, head_dim=8,
                  dtype=jnp.int8, scale_pages=True, index_dim=8)
+
+
+def test_only_a_pool_beside_an_index_pool_is_token_major():
+    """Learned sparse attention's K and V are one token-major pool; every
+    other pool's shapes are as they were."""
+    assert pool_shapes(2, 10, 16, 4, 8) == ((2, 10, 16, 32), (2, 10, 16, 4))
+    assert pool_shapes(2, 10, 16, 4, 8, token_major=True) == (
+        (2, 160, 8, 8), None)
+    plain = PagePool(layers=2, pages=10, page_size=16, heads=4, head_dim=8)
+    assert not plain.token_major and plain.snapshot()["layout"] == \
+        "page_major"
+    assert [a.shape for a in plain.state()] == [(2, 10, 16, 32)] * 2
+    sparse_pool = PagePool(layers=2, pages=10, page_size=16, heads=4,
+                           head_dim=8, index_dim=8)
+    assert sparse_pool.token_major
+    assert sparse_pool.snapshot()["layout"] == "token_major"
+    assert sparse_pool.kv_pool.shape == (2, 160, 8, 8)
+
+
+def test_the_page_major_view_reads_what_page_major_pools_hold(config,
+                                                              runner):
+    """A prefill's and two decode steps' writes into the token-major pool,
+    read through `pool.k_pool[at]` / `pool.v_pool[at]`, are what the same
+    model's writes make in page-major pools: the model without its indexer,
+    over contexts no longer than topk, where the sparse layer is full
+    attention."""
+    import dataclasses
+    spec = runner.spec_from_config(config)
+    dense = dataclasses.replace(spec, sparse_topk=0, index_heads=0,
+                                index_head_size=0)
+    params = init_params(spec, 5)
+    dense_params = {k: v for k, v in params.items() if ".idx." not in k}
+    ps, pages = config["serve"]["page_size"], 12
+    pool = PagePool(layers=spec.layers, pages=pages, page_size=ps,
+                    heads=spec.n_kv_heads, head_dim=spec.head_dim,
+                    index_dim=spec.index_head_size)
+    shape, _ = pool_shapes(spec.layers, pages, ps, spec.n_kv_heads,
+                           spec.head_dim)
+    k_pool = v_pool = jnp.zeros(shape, jnp.float32)
+    table = np.asarray([7, 2, 9, 4, 0, 0, 0, 0], np.int32)
+    tokens = np.zeros((16,), np.int32)
+    tokens[:10] = np.random.RandomState(14).randint(0, 256, 10)
+    out = serving_model.prefill_step(spec, params, *pool.state()[:1], None,
+                                     tokens, np.int32(10), table,
+                                     page_size=ps, index_pool=pool.index_pool)
+    pool.swap(*out[:2])
+    k_pool, v_pool = serving_model.prefill_step(
+        dense, dense_params, k_pool, v_pool, tokens, np.int32(10), table,
+        page_size=ps)[:2]
+    token = np.asarray([out[2]], np.int32)
+    for pos in (10, 11):
+        step = (token, np.asarray([pos], np.int32), table[None])
+        out = serving_model.decode_step(spec, params, pool.kv_pool, None,
+                                        *step, page_size=ps,
+                                        index_pool=pool.index_pool)
+        pool.swap(*out[:2])
+        k_pool, v_pool = serving_model.decode_step(
+            dense, dense_params, k_pool, v_pool, *step, page_size=ps)[:2]
+        token = np.asarray(out[2], np.int32)
+    at = (np.arange(spec.layers)[:, None], table[None, :3])
+    for view, want in ((pool.k_pool, k_pool), (pool.v_pool, v_pool)):
+        got = np.asarray(view[at])
+        assert got.shape == (spec.layers, 3, ps,
+                             spec.n_kv_heads * spec.head_dim)
+        assert np.abs(got[:, :, :ps]).sum() > 0
+        np.testing.assert_allclose(got, np.asarray(want[at]), atol=1e-5)
 
 
 def test_the_scheduler_counts_what_is_scored_and_selected(built):
@@ -491,6 +612,7 @@ def test_the_scheduler_counts_what_is_scored_and_selected(built):
     snap = engine.healthz()
     assert snap["sparse_tokens_scored"] >= got["sparse_tokens_scored"]
     assert snap["kv"]["index_pages"] == snap["kv"]["pages"]
+    assert snap["kv"]["layout"] == "token_major"
 
 
 def test_the_sparse_counters_reach_the_registry_by_kind(built):
