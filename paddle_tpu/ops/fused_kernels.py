@@ -21,6 +21,13 @@ the MXU contract from :mod:`.pallas_ops` (native-dtype operands, f32
 accumulation), and read their launch configs from the search-based
 tuner in :mod:`.autotune` (static candidate tables, pruned by the cost
 model before anything is timed).
+
+Each kernel's host wrapper goes through :func:`.pallas_ops.kernel_jit`,
+as flash's do: the primal and the ``custom_vjp`` forward rule call the
+one jitted wrapper with the same static plan (the primal drops the
+statistics), so the forward the tape's deferred ``jax.vjp`` traces again
+is the same call as the forward pass's, one serialised body, and XLA
+merges the two in a captured step.
 """
 from __future__ import annotations
 
@@ -31,7 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_ops import _LANES, _NEG_INF, _ceil_to, _interpret_default
+from .pallas_ops import (_LANES, _NEG_INF, _ceil_to, _interpret_default,
+                         _trace_time_counter, kernel_jit)
 
 __all__ = [
     "fused_layer_norm", "fused_softmax_xent",
@@ -44,22 +52,6 @@ __all__ = [
 # ---------------------------------------------------------------------------
 # dispatch observability
 # ---------------------------------------------------------------------------
-def _trace_time_counter(name: str, help_: str, labelnames):
-    """The registry's counter ``name``, or None while telemetry is off
-    (the registry must stay empty then) or the registry cannot be had.
-    Looked up per call (not cached) so a registry reset doesn't strand
-    increments on a stale counter — dispatch decisions are trace-time
-    events, not hot-loop work."""
-    try:
-        from ..observability.metrics import get_registry
-        from ..observability.telemetry import get_telemetry
-        if not get_telemetry().enabled:
-            return None
-        return get_registry().counter(name, help_, labelnames=labelnames)
-    except Exception:
-        return None
-
-
 def record_dispatch(kernel: str, path: str):
     """Count one dispatch decision: ``path`` is ``pallas`` (fused kernel
     taken) or ``fallback`` (XLA path). Fed by the nn.functional dispatch
@@ -182,6 +174,8 @@ def _ln_bwd_kernel(*refs, d, block_rows, d_pad, has_res, has_w, has_b):
             db_ref[:] = db_ref[:] + jnp.sum(gv, axis=0, keepdims=True)
 
 
+@kernel_jit("layer_norm_fwd",
+            ("d", "eps", "block_rows", "parallel", "interpret"))
 def _ln_pallas_fwd(x, res, w, b, *, d, eps, block_rows, parallel, interpret):
     rows_p, d_pad = x.shape
     ni = rows_p // block_rows
@@ -219,6 +213,7 @@ def _ln_pallas_fwd(x, res, w, b, *, d, eps, block_rows, parallel, interpret):
     )(*args)
 
 
+@kernel_jit("layer_norm_bwd", ("d", "block_rows", "interpret"))
 def _ln_pallas_bwd(x, res, w, b, g, mean, rstd, *, d, block_rows,
                    interpret):
     rows_p, d_pad = x.shape
@@ -443,6 +438,11 @@ def _xent_bwd_kernel(lab_ref, x_ref, lse_ref, g_ref, dx_ref, *, V,
     dx_ref[:] = jnp.where(colmask, dx, 0.0).astype(dx_ref.dtype)
 
 
+_XENT_PLAN = ("V", "block_rows", "block_v", "ignore_index", "smoothing",
+              "interpret")
+
+
+@kernel_jit("softmax_xent_fwd", _XENT_PLAN)
 def _xent_pallas_fwd(x, lab, *, V, block_rows, block_v, ignore_index,
                      smoothing, interpret):
     rows_p, v_pad = x.shape
@@ -476,6 +476,7 @@ def _xent_pallas_fwd(x, lab, *, V, block_rows, block_v, ignore_index,
     )(lab, x)
 
 
+@kernel_jit("softmax_xent_bwd", _XENT_PLAN)
 def _xent_pallas_bwd(x, lab, lse, g, *, V, block_rows, block_v,
                      ignore_index, smoothing, interpret):
     rows_p, v_pad = x.shape

@@ -358,15 +358,58 @@ def _seed_spec_args(seed, p_drop, lens, shift=None):
     return specs, args
 
 
-# The kernels' callers are jitted on the static plan: a model's layers
-# share one trace and one lowering of a kernel's text (24 layers x 4
-# calls of it were 14 s of the train cell's set-up), and the forward the
-# tape's vjp traces again is, to XLA, the call the forward pass made.
+def _trace_time_counter(name: str, help_: str, labelnames):
+    """The registry's counter ``name``, or None while telemetry is off
+    (the registry must stay empty then) or the registry cannot be had.
+    Looked up per call (not cached) so a registry reset doesn't strand
+    increments on a stale counter — dispatch decisions and kernel
+    traces are trace-time events, not hot-loop work."""
+    try:
+        from ..observability.metrics import get_registry
+        from ..observability.telemetry import get_telemetry
+        if not get_telemetry().enabled:
+            return None
+        return get_registry().counter(name, help_, labelnames=labelnames)
+    except Exception:
+        return None
+
+
+def kernel_jit(kernel: str, static_argnames):
+    """Decorator for the host wrapper of every Pallas kernel the tape
+    differentiates: ``jax.jit`` on the wrapper's static plan
+    (``static_argnames``).
+
+    A kernel is then traced and lowered once per signature, however many
+    times a program calls it, and each call carries the one serialised
+    Mosaic body that trace made.  That is what lets XLA merge the
+    forward the tape's deferred ``jax.vjp`` traces again with the call
+    the forward pass made: a body traced at another call site carries
+    another source location, and XLA merges only byte-equal calls.
+    Each trace counts once on ``pt_pallas_traces_total{kernel=}``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def traced(*args, **kwargs):
+            c = _trace_time_counter(
+                "pt_pallas_traces_total",
+                "Pallas kernel wrappers traced, by kernel (once a "
+                "signature; every later call reuses the trace)", ("kernel",))
+            if c is not None:
+                c.inc(kernel=kernel)
+            return fn(*args, **kwargs)
+        return jax.jit(traced, static_argnames=static_argnames)
+    return wrap
+
+
+# A differentiated kernel's wrapper goes through `kernel_jit` on its
+# static plan: a model's layers share one trace and one lowering of a
+# kernel's text (24 layers x 4 calls of it were 14 s of the train cell's
+# set-up), and the forward the tape's vjp traces again is the same call
+# as the forward pass's, which XLA merges.
 _PLAN_STATICS = ("causal", "sm_scale", "block_q", "block_k", "kv_span",
                  "q_len", "kv_len", "p_drop", "interpret")
 
 
-@functools.partial(jax.jit, static_argnames=_PLAN_STATICS)
+@kernel_jit("flash_fwd", _PLAN_STATICS)
 def _fwd(q, k, v, seed, lens, shift, *, causal, sm_scale, block_q,
          block_k, kv_span, q_len, kv_len, p_drop, interpret):
     bh, sq, d = q.shape
@@ -538,7 +581,7 @@ def _bwd_dkv_kernel(*refs, causal, sm_scale, block_q, block_k, n_q, q_len,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=_PLAN_STATICS + ("q_span",))
+@kernel_jit("flash_bwd", _PLAN_STATICS + ("q_span",))
 def _bwd(q, k, v, out, lse, do, seed, lens, shift, *, causal, sm_scale,
          block_q, block_k, kv_span, q_span, q_len, kv_len, p_drop,
          interpret, dlse=None):
@@ -1112,6 +1155,11 @@ def _pk_bwd_dkv_kernel(*refs, causal, sm_scale, block_q, block_k, p_drop):
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+_PK_PLAN = ("causal", "sm_scale", "block_q", "block_k", "p_drop",
+            "interpret")
+
+
+@kernel_jit("flash_packed_fwd", _PK_PLAN)
 def _pk_fwd(q, k, v, seed, meta, *, causal, sm_scale, block_q, block_k,
             p_drop, interpret):
     """q/k/v: (H, CapQ/K, D). meta: int32 arrays (see mha_packed)."""
@@ -1160,6 +1208,7 @@ def _pk_fwd(q, k, v, seed, meta, *, causal, sm_scale, block_q, block_k,
     return out, lse
 
 
+@kernel_jit("flash_packed_bwd", _PK_PLAN)
 def _pk_bwd(q, k, v, out, lse, do, seed, meta, *, causal, sm_scale,
             block_q, block_k, p_drop, interpret):
     pos_q, ok_q, off_q, pos_k, ok_k, klo, khi, qlo, qhi = meta

@@ -428,18 +428,24 @@ class TestDispatch:
                                       "gpt345m_widths_fp32",
                                       "gpt345m_widths_amp_o2"])
     def test_gpt_step_for_the_chip_holds_the_kernels(self, size,
-                                                     monkeypatch):
+                                                     monkeypatch,
+                                                     fresh_metrics):
         """The captured GPT step traced as the chip would get it: every
         layer norm, every attention and the loss are already Pallas calls
         and no normalisation is left in XLA's hands, so a graph pass had
         no cluster to rewrite there.  Each op's forward is in the trace
         twice: the forward walk, and the tape's lazy `jax.vjp` of the op
-        at `loss.backward()`."""
+        at `loss.backward()`; both are the one jitted kernel wrapper,
+        traced once."""
         import json
         import collections
         import paddle_tpu as pt
         from paddle_tpu.framework import device
         from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
+        from paddle_tpu.ops import pallas_ops
+        for f in (fk._ln_pallas_fwd, fk._ln_pallas_bwd, fk._xent_pallas_fwd,
+                  fk._xent_pallas_bwd, pallas_ops._fwd, pallas_ops._bwd):
+            f.clear_cache()
         monkeypatch.setattr(device, "on_tpu", lambda: True)
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         name = "gpt-345m" if size.startswith("gpt345m") else "tiny-train"
@@ -496,6 +502,132 @@ class TestDispatch:
             "flash_fwd": 2 * L, "flash_bwd_dq": L, "flash_bwd_dkv": L,
             "softmax_xent_fwd": 2, "softmax_xent_bwd": 1}, dict(kernels)
         assert not outside["rsqrt"] and not outside["reduce_max"], outside
+        # ... but each twice-traced forward is the one jitted call: a
+        # kernel's wrapper is traced once a signature, whatever calls it.
+        # The layer-norm backward sees two: a sharding is part of jit's
+        # signature, and the cotangent in front of the MLP comes out of
+        # XLA's ops carrying the default mesh's, while those in front of
+        # attention and the loss come out of a kernel, carrying none
+        traces = fresh_metrics.counter("pt_pallas_traces_total",
+                                       labelnames=("kernel",))
+        got = {k: traces.value(kernel=k) for k in _TRACED}
+        assert got == {
+            "layer_norm_fwd": 1, "layer_norm_bwd": 2, "flash_fwd": 1,
+            "flash_bwd": 1, "softmax_xent_fwd": 1, "softmax_xent_bwd": 1}, got
+        calls = fresh_metrics.counter("pt_pallas_calls_total",
+                                      labelnames=("kernel", "path"))
+        assert calls.value(kernel="fused_layer_norm",
+                           path="pallas") == 2 * L + 1
+
+    @pytest.mark.parametrize("mode", ["tape", "capture_step"])
+    @pytest.mark.parametrize("case", ["layer_norm_residual_affine",
+                                      "layer_norm_plain",
+                                      "cross_entropy_ignore_smoothing"])
+    def test_kernels_are_bit_identical_without_the_jit_helper(
+            self, case, mode, monkeypatch):
+        """Forward and gradients through the tape, and one SGD step under
+        `capture_step`, read the same bits whether the layer-norm and
+        cross-entropy wrappers go through `kernel_jit` or are called
+        as the plain functions it wraps."""
+        import inspect
+        _force_cpu_dispatch(monkeypatch)
+        got = _bit_run(case, mode)
+        for name in ("_ln_pallas_fwd", "_ln_pallas_bwd", "_xent_pallas_fwd",
+                     "_xent_pallas_bwd"):
+            monkeypatch.setattr(fk, name, inspect.unwrap(getattr(fk, name)))
+        want = _bit_run(case, mode)
+        assert len(got) == len(want) >= 3
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+
+    @pytest.mark.parametrize("kernel", ["layer_norm", "softmax_xent"])
+    def test_kernel_is_traced_once_a_signature(self, kernel, monkeypatch,
+                                               fresh_metrics):
+        """Three calls through the tape and a backward trace the forward
+        wrapper once (the backward's re-traced forward is a cache hit)
+        and the backward wrapper once; a second shape traces each again."""
+        import paddle_tpu as pt
+        import paddle_tpu.nn.functional as F
+        _force_cpu_dispatch(monkeypatch)
+        for f in (fk._ln_pallas_fwd, fk._ln_pallas_bwd, fk._xent_pallas_fwd,
+                  fk._xent_pallas_bwd):
+            f.clear_cache()
+        rng = np.random.RandomState(0)
+
+        def call(rows):
+            x = pt.to_tensor(rng.randn(rows, 72).astype(np.float32),
+                             stop_gradient=False)
+            if kernel == "layer_norm":
+                return F.layer_norm(x, 72).sum()
+            lab = pt.to_tensor(rng.randint(0, 72, rows).astype(np.int64))
+            return F.cross_entropy(x, lab)
+
+        traces = fresh_metrics.counter("pt_pallas_traces_total",
+                                       labelnames=("kernel",))
+        seen = lambda: (traces.value(kernel=kernel + "_fwd"),
+                        traces.value(kernel=kernel + "_bwd"))
+        (call(24) + call(24) + call(24)).backward()
+        assert seen() == (1, 1)
+        call(40).backward()
+        assert seen() == (2, 2)
+        calls = fresh_metrics.counter("pt_pallas_calls_total",
+                                      labelnames=("kernel", "path"))
+        assert calls.value(kernel="fused_" + kernel, path="pallas") == 4
+
+
+# what `kernel_jit` books on pt_pallas_traces_total in a GPT train step
+_TRACED = ("layer_norm_fwd", "layer_norm_bwd", "flash_fwd", "flash_bwd",
+           "softmax_xent_fwd", "softmax_xent_bwd")
+
+
+def _bit_run(case, mode):
+    """Loss and gradients (``tape``), or loss and parameters after one
+    SGD step of rate 1 (``capture_step``), of a linear layer feeding the
+    fused kernel named by ``case``."""
+    import paddle_tpu as pt
+    import paddle_tpu.nn.functional as F
+    rng = np.random.RandomState(0)
+    x = pt.to_tensor(rng.randn(4, 16, 96).astype(np.float32))
+    r = pt.to_tensor(rng.randn(4, 16, 96).astype(np.float32))
+    lab = rng.randint(0, 257, size=(4, 16)).astype(np.int64)
+    lab[0, :5] = -100
+    lab = pt.to_tensor(lab)
+    pt.seed(0)
+    lin = pt.nn.Linear(96, 257 if case.startswith("cross") else 96)
+    params = list(lin.parameters())
+    if case == "layer_norm_residual_affine":
+        ln = pt.nn.LayerNorm(96)
+        ln.weight.set_value(rng.randn(96).astype(np.float32))
+        ln.bias.set_value(rng.randn(96).astype(np.float32))
+        params += [ln.weight, ln.bias]
+
+        def loss_of(x, r, lab):
+            y = ln(lin(x), residual=r)
+            return (y * y).sum()
+    elif case == "layer_norm_plain":
+        def loss_of(x, r, lab):
+            y = F.layer_norm(lin(x), 96)
+            return (y * y).sum()
+    else:
+        def loss_of(x, r, lab):
+            return F.cross_entropy(lin(x), lab, ignore_index=-100,
+                                   label_smoothing=0.1)
+    if mode == "tape":
+        loss = loss_of(x, r, lab)
+        loss.backward()
+        return [loss.numpy()] + [p.grad.numpy() for p in params]
+    opt = pt.optimizer.SGD(learning_rate=1.0, parameters=params)
+
+    @pt.jit.capture_step
+    def step(x, r, lab):
+        loss = loss_of(x, r, lab)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    loss = step(x, r, lab)
+    return [loss.numpy()] + [p.numpy() for p in params]
 
 
 class TestAutotuneSearch:

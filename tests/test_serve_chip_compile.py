@@ -877,3 +877,73 @@ def test_chip_twin_tiles_every_matmul_as_the_decode_program(one_chip,
     found = _mxu_fusions(decode.as_text())
     assert any("attn_out" in scope for scope, _, _ in found)
     assert found == _mxu_fusions(twin.as_text())
+
+
+# -- the train cell's captured step (same file: one process loads the library) --
+
+def test_chip_train_step_runs_each_kernel_forward_once(one_chip, monkeypatch):
+    """The GPT-345M train step at the cell's widths and batch (8 x 1024,
+    bf16 AMP O2, AdamW with float32 masters) and 2 layers, built as
+    `capture_step` builds it, compiled for the chip.  The tape's deferred
+    `jax.vjp` traces each op's forward a second time; each kernel's
+    wrapper is one jitted call with one serialised body, so XLA merges the
+    two and the executable runs each Mosaic forward once: 2L + 1 layer
+    norms, one cross entropy, L flash forwards."""
+    import collections
+    import json
+    import os
+    import paddle_tpu as pt
+    from paddle_tpu.distributed import mesh as _mesh
+    from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
+    monkeypatch.setattr(_device, "on_tpu", lambda: True)
+    # the default mesh would be built over the CPU's devices
+    monkeypatch.setattr(_mesh, "_GLOBAL_MESH", _mesh.build_mesh(
+        {"dp": 1}, devices=list(one_chip.device_set)))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "gpt-345m.json")) as f:
+        spec = dict(json.load(f)["model"], num_layers=2)
+    L = spec["num_layers"]
+    pt.seed(0)
+    model = GPTForCausalLM(GPTConfig(**spec))
+    pt.amp.decorate(model, level="O2", dtype="bfloat16")
+    opt = pt.optimizer.AdamW(learning_rate=1e-4,
+                             parameters=model.parameters(),
+                             multi_precision=True)
+    ce = pt.nn.CrossEntropyLoss()
+
+    @pt.jit.capture_step
+    def step(ids, labels):
+        loss = ce(model(ids), labels)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    ids = pt.to_tensor(np.zeros((8, 1024), np.int64))
+    leaves, struct = step._flatten((ids, ids), {})
+    entry = step._compile((ids, ids), {}, step._signature(leaves, struct))
+    st = step._state
+
+    def sds(a):     # the state holds a python int or two
+        return jax.ShapeDtypeStruct(np.shape(a), jnp.result_type(a),
+                                    sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        sds, (st.params, st.buffers, st.opt_states, st.rng_ctr))
+    lr = [jax.ShapeDtypeStruct((), jnp.float32, weak_type=True,
+                               sharding=one_chip)]
+    with jax.default_matmul_precision("default"):
+        text = entry.jitted.lower(*state, lr, [sds(ids._data)] * 2) \
+            .compile().as_text()
+    # a forward the tape's vjp traced apart would read `jvp_<name>_`
+    calls = collections.Counter(
+        re.sub(r"^jvp_", "", n) for n in re.findall(
+            r"%(\w+?)_*\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text))
+    assert {k: calls[k] for k in ("layer_norm_fwd", "softmax_xent_fwd",
+                                  "flash_fwd")} == {
+        "layer_norm_fwd": 2 * L + 1, "softmax_xent_fwd": 1,
+        "flash_fwd": L}, dict(calls)
+    assert calls["layer_norm_bwd"] == 2 * L + 1 and \
+        calls["softmax_xent_bwd"] == 1, dict(calls)
